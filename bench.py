@@ -1,4 +1,4 @@
-"""Headline benchmark: Llama training-step throughput + MFU on real hardware.
+"""Llama training-step throughput + MFU on one TPU chip.
 
 Prints ONE JSON line:
   {"metric": "llama_train_mfu", "value": <mfu %>, "unit": "%MFU",
@@ -8,28 +8,13 @@ The reference publishes no Llama MFU numbers (BASELINE.md) — the north-star
 target is >=40% MFU (reference: release/train_tests/benchmark/ defines only
 the harness shape). vs_baseline is measured against that 40% target.
 
-Model size auto-scales to the detected chip's HBM so the benchmark is a real
-MXU workload on one chip (the driver runs this single-chip).
+A device measurement or nothing: without a TPU, with a device kind that has
+no published peak (util/accelerators.PEAK_TFLOPS), or when a case throws,
+the script exits non-zero and prints no ``llama_train_mfu`` line.
 """
 
 import json
-import sys
 import time
-
-
-# bf16 peak TFLOP/s per chip, by device_kind substring.
-_PEAK_TFLOPS = [
-    ("v6e", 918.0), ("v6", 918.0), ("v5p", 459.0), ("v5e", 197.0),
-    ("v5", 197.0), ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
-]
-
-
-def _peak_tflops(device_kind: str) -> float:
-    dk = device_kind.lower()
-    for key, val in _PEAK_TFLOPS:
-        if key in dk:
-            return val
-    return 100.0  # unknown accelerator: conservative placeholder
 
 
 def _run_case(cfg, batch, seq, iters, warmup, dev):
@@ -49,53 +34,59 @@ def _run_case(cfg, batch, seq, iters, warmup, dev):
         bdict = {"tokens": tokens, "targets": tokens}
         for _ in range(warmup):
             state, metrics = step_fn(state, bdict)
-        float(metrics["loss"])  # host fetch: hard sync on remote devices
+        float(metrics["loss"])  # host fetch: waits for the device
         t0 = time.perf_counter()
         for _ in range(iters):
             state, metrics = step_fn(state, bdict)
         final_loss = float(metrics["loss"])
         dt = time.perf_counter() - t0
+    from ray_tpu.util.accelerators import peak_tflops
     toks_per_s = batch * seq * iters / dt
     achieved_tflops = toks_per_s * cfg.flops_per_token(seq) / 1e12
-    peak = _peak_tflops(getattr(dev, "device_kind", dev.platform))
-    return (100.0 * achieved_tflops / peak, toks_per_s,
-            achieved_tflops, final_loss)
+    return (100.0 * achieved_tflops / peak_tflops(dev.device_kind),
+            toks_per_s, achieved_tflops, final_loss)
 
 
 def main():
     import jax
-    import jax.numpy as jnp  # noqa: F401
     from ray_tpu.models import llama
+    from ray_tpu.util import jaxenv
+    from ray_tpu.util.accelerators import peak_tflops
 
+    jaxenv.setup_compile_cache()
     dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
+    device = jaxenv.describe_device(dev)
+    if device["platform"] != "tpu":
+        raise SystemExit(f"bench.py measures a TPU; jax found {device}")
+    peak = peak_tflops(device["kind"])
 
-    if on_tpu:
-        # ~1.3B params: fits one chip (params+opt state in f32 ~ 15GB is too
-        # big for v5e 16G; use bf16 params + f32 adam -> ~13GB. Use 0.8B to
-        # be safe across chip generations.)
-        # Tuned on v5e (scripts/mfu_sweep.py): 1024^2 flash blocks cut the
-        # pallas grid from 32k to 512 invocations (6.1 -> 14.6 TF/s on the
-        # kernel); full per-layer remat beats saving attention residuals
-        # (residual HBM traffic costs more than the recompute); batch 16 and
-        # 2048 blocks OOM. Round-3 sweep: bf16 logits (+0.3pt) and
-        # batch 4 x seq 4096 (+1.2pt over 8x2048; b12, b8s4096 regress).
-        # 28.9% -> 53.7% -> ~54.8% MFU overall.
-        cfg = llama.LlamaConfig(
-            vocab_size=32000, dim=2048, n_layers=16, n_heads=16,
-            n_kv_heads=16, ffn_dim=5504, max_seq_len=4096,
-            attn_impl="flash", attn_block_q=1024, attn_block_k=1024,
-            logits_dtype="bfloat16")
-        batch, seq, iters, warmup = 4, 4096, 20, 3
-    else:
-        cfg = llama.tiny(attn_impl="reference")
-        batch, seq, iters, warmup = 4, 256, 5, 1
-
+    # 0.94B params (bf16 params + f32 adam fit one 16 GB chip with room).
+    # Shapes from scripts/mfu_sweep.py on a v5e: 1024^2 flash blocks cut
+    # the pallas grid from 32k to 512 invocations; full per-layer remat
+    # beat saving attention residuals (residual HBM traffic cost more
+    # than the recompute); batch 16 and 2048 blocks ran out of memory;
+    # bf16 logits and batch 4 x seq 4096 beat 8 x 2048.
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, dim=2048, n_layers=16, n_heads=16,
+        n_kv_heads=16, ffn_dim=5504, max_seq_len=4096,
+        attn_impl="flash", attn_block_q=1024, attn_block_k=1024,
+        logits_dtype="bfloat16")
+    batch, seq, iters, warmup = 4, 4096, 20, 3
     mfu, toks_per_s, achieved_tflops, final_loss = _run_case(
         cfg, batch, seq, iters, warmup, dev)
-    peak = _peak_tflops(getattr(dev, "device_kind", dev.platform))
 
-    out = {
+    # Llama-2-7B layer shapes (dim 4096 / ffn 11008 / 32 heads / 32000
+    # vocab): small-model MFU can flatter. The full 7B train state (f32
+    # adam moments) cannot fit one 16 GB chip, so this runs 4 full-width
+    # layers — the per-chip shard of a 7B fsdp-8 run, same MXU tile
+    # shapes, FLOPs counted for this config.
+    cfg7 = llama.llama2_7b(
+        n_layers=4, attn_impl="flash",
+        attn_block_q=1024, attn_block_k=1024,
+        logits_dtype="bfloat16")
+    mfu7, tps7, tf7, _ = _run_case(cfg7, 4, 4096, 20, 3, dev)
+
+    print(json.dumps({
         "metric": "llama_train_mfu",
         "value": round(mfu, 2),
         "unit": "%MFU",
@@ -103,41 +94,17 @@ def main():
         "tokens_per_s": round(toks_per_s, 1),
         "achieved_tflops": round(achieved_tflops, 2),
         "peak_tflops": peak,
-        "device": str(getattr(dev, "device_kind", dev.platform)),
+        "device": device,
         "model_params_m": round(cfg.num_params() / 1e6, 1),
         "batch": batch, "seq": seq, "final_loss": round(final_loss, 4),
         "timed_iters": iters,
-    }
-
-    if on_tpu:
-        # TRUE Llama-2-7B layer shapes (dim 4096 / ffn 11008 / 32 heads
-        # / 32000 vocab) — the north star names 7B, and small-model MFU
-        # can flatter. The full 7B train state (f32 adam moments) can't
-        # fit one 16GB chip, so this runs 4 full-width layers: exactly
-        # the per-host shard a 7B fsdp-8 run places per chip, same MXU
-        # tile shapes, honest per-config FLOPs accounting.
-        cfg7 = llama.llama2_7b(
-            n_layers=4, attn_impl="flash",
-            attn_block_q=1024, attn_block_k=1024,
-            logits_dtype="bfloat16")
-        try:
-            mfu7, tps7, tf7, _ = _run_case(cfg7, 4, 4096, 20, 3, dev)
-            out["mfu_7b_shapes"] = round(mfu7, 2)
-            out["tokens_per_s_7b_shapes"] = round(tps7, 1)
-            out["achieved_tflops_7b_shapes"] = round(tf7, 2)
-            out["config_7b_shapes"] = ("dim4096/ffn11008/h32/vocab32k/"
-                                       "4 full-width layers, b4 s4096")
-        except Exception as e:  # noqa: BLE001 — headline still reports
-            out["mfu_7b_shapes_error"] = f"{type(e).__name__}: {e}"[:200]
-
-    print(json.dumps(out))
+        "mfu_7b_shapes": round(mfu7, 2),
+        "tokens_per_s_7b_shapes": round(tps7, 1),
+        "achieved_tflops_7b_shapes": round(tf7, 2),
+        "config_7b_shapes": ("dim4096/ffn11008/h32/vocab32k/"
+                             "4 full-width layers, b4 s4096"),
+    }))
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # never leave the driver without a JSON line
-        print(json.dumps({"metric": "llama_train_mfu", "value": 0.0,
-                          "unit": "%MFU", "vs_baseline": 0.0,
-                          "error": f"{type(e).__name__}: {e}"[:300]}))
-        sys.exit(0)
+    main()

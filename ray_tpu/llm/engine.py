@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import asyncio
 import math
+import os
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -170,8 +171,10 @@ class LLMEngine:
         jax, jnp = _jx()
         # jax is live in this process from here on: hook the compile
         # listeners now so even the cache-init compiles are spanned
-        # (idempotent; no-op under RAY_TPU_DEVMON=0)
+        # (idempotent; no-op under RAY_TPU_DEVMON=0), and let the
+        # device monitor query the backend this process now owns
         devmon.install()
+        devmon.mark_backend_live()
         if mesh is not None and getattr(cfg, "attn_impl", "auto") in (
                 "auto", "flash", "flash_interpret", "ring"):
             # Tensor-parallel serving shards the head dim via GSPMD,
@@ -184,6 +187,12 @@ class LLMEngine:
         self.cfg = cfg
         self.mesh = mesh
         self.tensor_axis = tensor_axis
+        # every 'auto' below is resolved here, once, from the platform
+        # this process runs on, and reported in `stats`
+        from ray_tpu.util import jaxenv
+        self._device = jaxenv.describe_device(
+            mesh.devices.flat[0] if mesh is not None else None)
+        self._prefill_impl = lm.resolve_prefill_impl(cfg)
         if mesh is not None:
             params = lm.shard_params_for_serving(params, mesh, cfg,
                                                  tensor_axis)
@@ -228,7 +237,6 @@ class LLMEngine:
         self._specm = specdec.spec_metrics() if self._spec else None
         self._kvm = kvcache.kvcache_metrics()
         if self._paged:
-            from ray_tpu.ops.attention import _on_tpu
             # decode attention impl: the fused block-table kernel
             # (paged_flash) vs the materialized gather view; "auto"
             # resolves by backend. Off-TPU the kernel runs through the
@@ -237,7 +245,8 @@ class LLMEngine:
             self._kv_impl = kvcache.resolve_attn_impl(kv_impl)
             self._kv_interpret = bool(
                 getattr(_cfg, "paged_attn_interpret", False)) or (
-                    self._kv_impl == "paged_flash" and not _on_tpu())
+                    self._kv_impl == "paged_flash"
+                    and self._device["platform"] != "tpu")
             # effective block size must divide every prefill bucket
             # and max_len (prefill writes land block-aligned): shrink
             # to the gcd instead of erroring on small test buckets
@@ -248,8 +257,10 @@ class LLMEngine:
             self._table_w = max_len // self._block
             per_tok = (cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
                        * 2 * jnp.dtype(cache_dtype).itemsize)
+            # the pool shards its kv heads over the tensor axis
+            tp = mesh.shape[tensor_axis] if mesh is not None else 1
             nb = kvcache.auto_pool_blocks(
-                max_slots, self._table_w, per_tok * self._block,
+                max_slots, self._table_w, per_tok * self._block // tp,
                 kv_pool_blocks)
             self._cache_len = max_len     # no growth: tables span it
             self._pool = kvcache.init_pool(cfg, nb, self._block,
@@ -262,7 +273,7 @@ class LLMEngine:
                 from jax.sharding import NamedSharding, PartitionSpec \
                     as P
                 s = NamedSharding(
-                    mesh, P(None, None, None, tensor_axis, None))
+                    mesh, P(None, None, tensor_axis, None, None))
                 self._pool = {k: jax.device_put(v, s)
                               for k, v in self._pool.items()}
             # what one decode step would have copied materializing the
@@ -328,14 +339,19 @@ class LLMEngine:
                "ttft_sum": self._ttft_sum,
                "ttft_count": self._ttft_count,
                "cache_len": self._cache_len,
-               "paged": self._paged}
+               "paged": self._paged,
+               "pid": os.getpid(),
+               "device": dict(self._device),
+               "prefill_impl": self._prefill_impl}
         if self._paged:
             out.update(block_size=self._block,
+                       pool_blocks=self._kv.num_blocks,
                        blocks_used=self._kv.used_blocks(),
                        blocks_cached=self._kv.cached_blocks(),
                        blocks_free=self._kv.free_blocks(),
                        prefix_hit_tokens=self._kv.hit_tokens_total,
                        kv_impl=self._kv_impl,
+                       kv_interpret=self._kv_interpret,
                        spec=self._spec)
         return out
 
@@ -888,7 +904,7 @@ class LLMEngine:
             self._cache, kv, slot, jnp.int32(n))
         # block_until_ready bounds the DEVICE portion of TTFT: dispatch
         # above is async, so the wall clock alone can't attribute a slow
-        # first token to compute vs queueing (round-6 SERVE_BENCH ask)
+        # first token to compute vs queueing
         logits_np = np.asarray(logits)
         jax.block_until_ready(self._cache["k"])
         r.prefill_device_s = time.monotonic() - t0
@@ -921,11 +937,7 @@ class LLMEngine:
         request ran), so reuse accounting and parity are untouched."""
         if hit == 0:
             return 0
-        from ray_tpu.ops.attention import _on_tpu
-        impl = lm._serve_attn_impl(self.cfg)
-        flashy = impl in ("flash", "flash_interpret") or (
-            impl == "auto" and _on_tpu())
-        if not flashy:
+        if self._prefill_impl not in ("flash", "flash_interpret"):
             return hit
         chunk = self.buckets[-1]
         return (hit // chunk) * chunk

@@ -7,8 +7,11 @@ system-prompt prefix skip prefill for the shared blocks). Rebuilt
 TPU-native on the engine's static-shape rules:
 
 - the POOL is one preallocated tensor pair per engine,
-  ``(layers, num_blocks, block_size, kv_heads, head_dim)`` — shapes
-  never change, so XLA compiles the paged decode step exactly once;
+  ``(layers, num_blocks, kv_heads, block_size, head_dim)`` — shapes
+  never change, so XLA compiles the paged decode step exactly once.
+  It is head-major because the decode kernel streams one head of one
+  block per grid step and the TPU compiler needs that slab to be
+  whole tiles (ops/pallas/paged_attention.py);
 - each request owns a BLOCK TABLE (fixed width ``max_len //
   block_size``) of physical block ids; decode gathers the table's
   blocks into the attention view and scatters the new token's KV back
@@ -477,9 +480,9 @@ def _jx():
 
 def init_pool(cfg, num_blocks: int, block_size: int, dtype) -> dict:
     """The pool tensors: k/v of shape
-    (layers, num_blocks, block_size, kv_heads, head_dim)."""
+    (layers, num_blocks, kv_heads, block_size, head_dim)."""
     _, jnp = _jx()
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
+    shape = (cfg.n_layers, num_blocks, cfg.n_kv_heads, block_size,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -494,8 +497,14 @@ def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
                      configured: int = 0) -> int:
     """Pool size: the explicit knob wins; otherwise worst case (every
     slot at max_len) plus one full chain of prefix-cache headroom,
-    capped by the devmon HBM headroom gauges when the backend reports
-    them (half the free HBM — the engine is not the only tenant).
+    capped at a quarter of the free HBM of the fullest local device
+    when the backend reports a capacity (devmon.hbm_snapshot; the CPU
+    backend reports none). A quarter, because the decode program
+    holds three pool-sized buffers while it runs — the donated pool
+    and two the layer scan stacks its outputs into (the compiler's
+    memory analysis at Llama-2-7B widths: 2.25 GB of pool, 4.5 GB of
+    temporaries) — and prefill needs room beside it.
+    ``block_bytes`` is what one block costs PER DEVICE.
     The cap never shrinks below ONE full-horizon request
     (table_width blocks): a max_len-sized request must be servable —
     serially — on any pool the engine auto-sizes, matching what the
@@ -503,16 +512,13 @@ def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
     if configured:
         return max(2, int(configured))
     base = slots * table_width + table_width
-    try:
-        from ray_tpu.util import devmon
-        rows = devmon.hbm_snapshot(record=False)
-        headrooms = [r["limit_bytes"] - r["used_bytes"] for r in rows
-                     if r.get("limit_bytes")]
-        if headrooms:
-            cap = int(min(headrooms) * 0.5 // max(1, block_bytes))
-            base = max(table_width, min(base, cap))
-    except Exception:   # noqa: BLE001 — sizing hint only
-        pass
+    from ray_tpu.util import devmon
+    headrooms = [r["limit"] - r["used"]
+                 for r in devmon.hbm_snapshot(record=False)
+                 if r["limit"]]
+    if headrooms:
+        cap = int(min(headrooms) // 4 // max(1, block_bytes))
+        base = max(table_width, min(base, cap))
     return base + 1     # + trash block
 
 
@@ -522,6 +528,16 @@ _JITS: dict = {}    # (op, pool geometry, dtype) -> jitted callable
 def _pool_key(pool: dict) -> tuple:
     """Cache-key component identifying one pool's compiled geometry."""
     return (tuple(pool["k"].shape), str(pool["k"].dtype))
+
+
+def _to_blocks(kv, nb: int, pool):
+    """Token-order KV (layers, nb * block, kvh, hd) cut into ``nb``
+    head-major pool blocks (layers, nb, kvh, block, hd), in the pool's
+    dtype."""
+    L, _, kvh, hd = kv.shape
+    bs = pool.shape[3]
+    return kv.reshape(L, nb, bs, kvh, hd).transpose(
+        0, 1, 3, 2, 4).astype(pool.dtype)
 
 
 def _jit(name: str, pool: dict):
@@ -542,23 +558,19 @@ def _jit(name: str, pool: dict):
     if name == "scatter_bucket":
         @partial(jax.jit, donate_argnums=(0,), static_argnames=("nb",))
         def fn(pool, kv, phys, nb):
-            L = kv["k"].shape[0]
-            bs = pool["k"].shape[2]
-            k = kv["k"].reshape(L, nb, bs, *kv["k"].shape[2:])
-            v = kv["v"].reshape(L, nb, bs, *kv["v"].shape[2:])
-            return {"k": pool["k"].at[:, phys].set(
-                        k.astype(pool["k"].dtype)),
-                    "v": pool["v"].at[:, phys].set(
-                        v.astype(pool["v"].dtype))}
+            return {key: pool[key].at[:, phys].set(
+                        _to_blocks(kv[key], nb, pool[key]))
+                    for key in ("k", "v")}
     elif name == "gather_table":
         @partial(jax.jit, static_argnames=("acc_len",))
         def fn(pool, phys, acc_len):
-            L, _, bs, kvh, hd = pool["k"].shape
+            L, _, kvh, bs, hd = pool["k"].shape
             w = phys.shape[0]
             out = {}
             for key in ("k", "v"):
-                g = pool[key][:, phys]           # (L, w, bs, kvh, hd)
-                g = g.reshape(L, w * bs, kvh, hd)
+                g = pool[key][:, phys]           # (L, w, kvh, bs, hd)
+                g = g.transpose(0, 1, 3, 2, 4).reshape(
+                    L, w * bs, kvh, hd)
                 pad = acc_len - w * bs
                 if pad > 0:
                     g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -567,14 +579,11 @@ def _jit(name: str, pool: dict):
     elif name == "scatter_table":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, acc, phys):
-            L, _, bs, kvh, hd = pool["k"].shape
+            bs = pool["k"].shape[3]
             w = phys.shape[0]
-            out = {}
-            for key in ("k", "v"):
-                a = acc[key][:, :w * bs].reshape(L, w, bs, kvh, hd)
-                out[key] = pool[key].at[:, phys].set(
-                    a.astype(pool[key].dtype))
-            return out
+            return {key: pool[key].at[:, phys].set(
+                        _to_blocks(acc[key][:, :w * bs], w, pool[key]))
+                    for key in ("k", "v")}
     elif name == "copy_block":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, src, dst):
@@ -636,12 +645,25 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
                        key, cfg, top_ps=None, top_ks=None, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
-    """One token for every slot against the paged pool. Runs
-    lm.decode_token_core — the SAME transformer body as the monolithic
-    cache — with block-table write/attend plugged in.
+    """One sampled token for every slot against the paged pool:
+    _paged_logits_core + the on-device sampler."""
+    from ray_tpu.llm.model import sample
+    logits, pool = _paged_logits_core(
+        params, pool, tables, lengths, tokens, cfg, impl=impl,
+        interpret=interpret, mesh=mesh, axis=axis)
+    return sample(logits, temps, key, top_ps, top_ks), pool
 
-    impl='gather': the attention view is materialized per layer as
-    ck[tables].reshape(b, W*bs, kvh, hd) — the gathered view holds the
+
+def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
+                       impl="gather", interpret=False, mesh=None,
+                       axis="tensor"):
+    """One decode step's (slots, vocab) f32 logits for every slot
+    against the paged pool. Runs lm.decode_logits_core — the SAME
+    transformer body as the monolithic cache — with block-table
+    write/attend plugged in.
+
+    impl='gather': the attention view is materialized per layer
+    (paged_attention.table_view) — the gathered view holds the
     same bytes in the same order as the monolithic cache, so the
     attention math (and therefore the sampled tokens) is bitwise
     identical (pinned by tests/test_zz_kvcache.py parity tests).
@@ -659,9 +681,10 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
     collectives (the gather path needs nothing: GSPMD partitions the
     plain-jnp view fine)."""
     jax, jnp = _jx()
-    from ray_tpu.llm.model import decode_token_core
+    from ray_tpu.llm.model import decode_logits_core
+    from ray_tpu.ops.pallas.paged_attention import table_view
     b = tokens.shape[0]
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     w = tables.shape[1]
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
     positions = lengths
@@ -669,13 +692,12 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
     off = positions % bs
     phys = tables[jnp.arange(b), blk]
 
-    def write(ck, cv, k, v):    # ck/cv: (num_blocks, bs, kvh, hd)
-        return (ck.at[phys, off].set(k.astype(ck.dtype)),
-                cv.at[phys, off].set(v.astype(cv.dtype)))
+    def write(ck, cv, k, v):    # ck/cv: (num_blocks, kvh, bs, hd)
+        return (ck.at[phys, :, off].set(k.astype(ck.dtype)),
+                cv.at[phys, :, off].set(v.astype(cv.dtype)))
 
     def view(ck, cv):
-        return (ck[tables].reshape(b, w * bs, kvh, hd),
-                cv[tables].reshape(b, w * bs, kvh, hd))
+        return table_view(ck, tables), table_view(cv, tables)
 
     attend = None
     if impl == "paged_flash":
@@ -690,13 +712,12 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
             qg = q.reshape(b, kvh, g, hd)
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
-                from ray_tpu.ops import shard_map
                 t = axis
-                fn = shard_map(
-                    _kernel, mesh,
+                fn = jax.shard_map(
+                    _kernel, mesh=mesh,
                     in_specs=(P(None, t, None, None),
-                              P(None, None, t, None),
-                              P(None, None, t, None), P(), P()),
+                              P(None, t, None, None),
+                              P(None, t, None, None), P(), P()),
                     out_specs=P(None, t, None, None),
                     check_vma=False)
             else:
@@ -704,10 +725,36 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
             o = fn(qg, ck, cv, tables, pos + 1)
             return o.reshape(b, cfg.n_heads * hd)
 
-    out, nk, nv = decode_token_core(
-        params, pool["k"], pool["v"], tokens, positions, temps, key,
-        cfg, write, view, top_ps, top_ks, attend)
-    return out, {"k": nk, "v": nv}
+    logits, nk, nv = decode_logits_core(
+        params, pool["k"], pool["v"], tokens, positions, cfg, write,
+        view, attend)
+    return logits, {"k": nk, "v": nv}
+
+
+def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
+                        impl="gather", interpret=False, mesh=None,
+                        axis="tensor"):
+    """The (slots, vocab) f32 logits of ONE decode step against the
+    block pool, which is left untouched (not donated) — the parity
+    entry point: the same step under impl='paged_flash' and
+    impl='gather' must agree (chip_smoke.py checks that on the chip at
+    real widths; tests/test_zz_paged_attn.py under the interpreter)."""
+    impl = resolve_attn_impl(impl)
+    key_ = ("paged_decode_logits", *_pool_key(pool), impl,
+            bool(interpret), mesh, axis)
+    fn = _JITS.get(key_)
+    if fn is None:
+        jax, _ = _jx()
+
+        @partial(jax.jit, static_argnames=("cfg",))
+        def paged_decode_logits(params, pool, tables, lengths, tokens,
+                                cfg):
+            return _paged_logits_core(
+                params, pool, tables, lengths, tokens, cfg, impl=impl,
+                interpret=interpret, mesh=mesh, axis=axis)[0]
+        fn = paged_decode_logits
+        _JITS[key_] = fn
+    return fn(params, pool, tables, lengths, tokens, cfg)
 
 
 def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
@@ -774,8 +821,9 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     token, which is the spec-decode win the bench measures."""
     jax, jnp = _jx()
     from ray_tpu.llm.model import verify_tokens_core
+    from ray_tpu.ops.pallas.paged_attention import table_view
     b, wq = tokens.shape
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     w = tables.shape[1]
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
     positions = lengths
@@ -785,12 +833,11 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     phys = jnp.take_along_axis(tables, blk, axis=1)     # (b, wq)
 
     def write(ck, cv, k, v):    # k/v: (b, wq, kvh, hd)
-        return (ck.at[phys, off].set(k.astype(ck.dtype)),
-                cv.at[phys, off].set(v.astype(cv.dtype)))
+        return (ck.at[phys, :, off].set(k.astype(ck.dtype)),
+                cv.at[phys, :, off].set(v.astype(cv.dtype)))
 
     def view(ck, cv):
-        return (ck[tables].reshape(b, w * bs, kvh, hd),
-                cv[tables].reshape(b, w * bs, kvh, hd))
+        return table_view(ck, tables), table_view(cv, tables)
 
     attend = None
     if impl == "paged_flash":
@@ -802,13 +849,12 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
             qg = q.reshape(b, wq, kvh, g, hd)
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
-                from ray_tpu.ops import shard_map
                 t = axis
-                fn = shard_map(
-                    paged_attention_verify, mesh,
+                fn = jax.shard_map(
+                    paged_attention_verify, mesh=mesh,
                     in_specs=(P(None, None, t, None, None),
-                              P(None, None, t, None),
-                              P(None, None, t, None), P(), P()),
+                              P(None, t, None, None),
+                              P(None, t, None, None), P(), P()),
                     out_specs=P(None, None, t, None, None),
                     check_vma=False)
             else:

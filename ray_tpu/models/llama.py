@@ -233,10 +233,9 @@ def _attend(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh],
     if impl == "ring" or (impl == "auto" and cp > 1):
         def f(q, k, v):
             return ring_attention(q, k, v, axis_name=axes.context)
-        from ray_tpu.ops import shard_map as _shard_map
-        return named(_shard_map(f, mesh=mesh,
-                                in_specs=(bspec, bspec, bspec),
-                                out_specs=bspec)(q, k, v))
+        return named(jax.shard_map(f, mesh=mesh,
+                                   in_specs=(bspec, bspec, bspec),
+                                   out_specs=bspec)(q, k, v))
 
     if cp > 1:
         # Explicit non-ring impl on a context-sharded mesh: run with global
@@ -255,9 +254,8 @@ def _attend(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh],
     def f(q, k, v):
         return _attention_op(q, k, v, causal=True, impl=impl, **blocks)
     # check_vma=False: pallas_call outputs carry no vma under shard_map.
-    from ray_tpu.ops import shard_map as _shard_map
-    out = _shard_map(f, mesh=mesh, in_specs=(bspec, bspec, bspec),
-                     out_specs=bspec, check_vma=False)(q, k, v)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(bspec, bspec, bspec),
+                        out_specs=bspec, check_vma=False)(q, k, v)
     return out if impl.startswith("flash") else named(out)
 
 

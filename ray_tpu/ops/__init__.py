@@ -1,24 +1,5 @@
 from ray_tpu.ops.attention import attention, mha_reference, flash_attention
 from ray_tpu.ops.ring_attention import ring_attention
 
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None):
-    """jax.shard_map across the API move: new jax exposes it at the
-    top level with ``check_vma``; older jax has
-    jax.experimental.shard_map.shard_map with ``check_rep``. An
-    AttributeError on the old side used to fail every context-parallel
-    (ring-attention) caller in this environment."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kw)
-
-
 __all__ = ["attention", "mha_reference", "flash_attention",
-           "ring_attention", "shard_map"]
+           "ring_attention"]

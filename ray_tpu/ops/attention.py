@@ -129,10 +129,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """Whether this process's JAX backend is a TPU. A backend that
+    fails to initialise raises here: an ``auto`` path is chosen from
+    the platform, never from a caught error."""
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, *, causal: bool = True,

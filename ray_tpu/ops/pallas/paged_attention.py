@@ -20,6 +20,15 @@ the masked softmax for the same summation order within a block, so the
 two impls agree to f32 rounding (and bitwise on integer-valued
 constructions; see tests/test_zz_paged_attn.py).
 
+The pool is HEAD-MAJOR — one layer is ``(num_blocks, kv_heads,
+block_size, head_dim)`` — so the block one grid step streams,
+``(block_size, head_dim)`` for one head of one physical block, is a
+whole number of TPU tiles (the TPU compiler refuses a block whose
+second-minor dim is a size-1 slice of a longer one, which a
+token-major pool forced). ``table_view`` is the one place that turns
+this layout back into the contiguous ``(slots, len, kv_heads,
+head_dim)`` view the gather path and the references attend over.
+
 Grid: ``(slots, kv_heads, table_width)`` with the table-walk dimension
 sequential ("arbitrary"). Blocks past a slot's last live block are
 clamped to the last live one in the index_map — reads stay inside
@@ -40,11 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; accept both (same
-# shim as flash_attention.py).
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 NEG_INF = -1e30
 LANES = 128  # m/l scratch are broadcast along the lane dim
@@ -73,8 +77,8 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j <= last)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)         # (g, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)         # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) / jnp.sqrt(
@@ -109,7 +113,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     """Single-token decode attention straight through block tables.
 
     q: (slots, kv_heads, group, head_dim) — grouped queries, one token
-    per slot; k_pool/v_pool: (num_blocks, block_size, kv_heads,
+    per slot; k_pool/v_pool: (num_blocks, kv_heads, block_size,
     head_dim) — ONE layer of the engine pool; tables: (slots, width)
     int32 physical block ids (trash-padded); lengths: (slots,) int32
     valid positions per slot INCLUDING the current token (>= 1).
@@ -118,7 +122,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     no gathered view.
     """
     b, kvh, g, hd = q.shape
-    nb, bs, kvh_p, hd_p = k_pool.shape
+    nb, kvh_p, bs, hd_p = k_pool.shape
     if (kvh_p, hd_p) != (kvh, hd):
         raise ValueError(
             f"pool heads/dim {(kvh_p, hd_p)} != query {(kvh, hd)}")
@@ -132,15 +136,15 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         # reads never leave blocks the slot owns, and the pipeliner
         # skips re-fetching the same block on consecutive steps
         last = _last_block(ln[b_], bs) // bs
-        return (t[b_, jnp.minimum(j, last)], 0, h_, 0)
+        return (t[b_, jnp.minimum(j, last)], h_, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, w),
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), _qmap),
-            pl.BlockSpec((1, bs, 1, hd), _kvmap),
-            pl.BlockSpec((1, bs, 1, hd), _kvmap),
+            pl.BlockSpec((1, 1, bs, hd), _kvmap),
+            pl.BlockSpec((1, 1, bs, hd), _kvmap),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd), _qmap),
         scratch_shapes=[
@@ -154,11 +158,23 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool,
       v_pool)
+
+
+def table_view(pool, tables):
+    """One layer of the head-major pool seen through block tables as
+    the contiguous attention view: (num_blocks, kv_heads, block_size,
+    head_dim) x (slots, width) -> (slots, width * block_size, kv_heads,
+    head_dim). The same bytes in token order, so attention over the
+    view is bitwise what the monolithic cache computes."""
+    b, w = tables.shape
+    _, kvh, bs, hd = pool.shape
+    g = pool[tables]                        # (b, w, kvh, bs, hd)
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, w * bs, kvh, hd)
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, lengths):
@@ -166,15 +182,13 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths):
     ``_gqa_attend_cached`` runs on the gathered view) — the parity
     target the kernel is tested against, and the debug tool for
     bisecting a kernel/table discrepancy on device."""
-    b, kvh, g, hd = q.shape
-    _, bs, _, _ = k_pool.shape
-    w = tables.shape[1]
-    vk = k_pool[tables].reshape(b, w * bs, kvh, hd)
-    vv = v_pool[tables].reshape(b, w * bs, kvh, hd)
+    hd = q.shape[-1]
+    vk = table_view(k_pool, tables)
+    vv = table_view(v_pool, tables)
     qf = q.astype(jnp.float32)
     scores = jnp.einsum("bkgd,blkd->bkgl", qf,
                         vk.astype(jnp.float32)) / jnp.sqrt(hd)
-    mask = jnp.arange(w * bs)[None] < lengths[:, None]
+    mask = jnp.arange(vk.shape[1])[None] < lengths[:, None]
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bkgl,blkd->bkgd", probs,
@@ -204,15 +218,13 @@ def paged_attention_verify(q, k_pool, v_pool, tables, lengths):
     (NEG_INF then softmax) keeps pool bytes beyond each query's mask
     bitwise-irrelevant, so verify rows reproduce sequential decode's
     attention exactly."""
-    b, wq, kvh, g, hd = q.shape
-    _, bs, _, _ = k_pool.shape
-    w = tables.shape[1]
-    vk = k_pool[tables].reshape(b, w * bs, kvh, hd)
-    vv = v_pool[tables].reshape(b, w * bs, kvh, hd)
+    hd = q.shape[-1]
+    vk = table_view(k_pool, tables)
+    vv = table_view(v_pool, tables)
     qf = q.astype(jnp.float32)
     scores = jnp.einsum("bwkgd,blkd->bwkgl", qf,
                         vk.astype(jnp.float32)) / jnp.sqrt(hd)
-    mask = (jnp.arange(w * bs)[None, None]
+    mask = (jnp.arange(vk.shape[1])[None, None]
             < lengths[:, :, None])                  # (b, wq, w*bs)
     scores = jnp.where(mask[:, :, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -25,17 +25,6 @@ from jax import lax
 _NEG = -1e30
 
 
-def _axis_size(axis_name) -> int:
-    """Static mapped-axis size across the jax API move: new jax has
-    lax.axis_size; older jax exposes it through core.axis_frame
-    (which returns the bare size there)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-    fr = core.axis_frame(axis_name)
-    return int(getattr(fr, "size", fr))
-
-
 def _chunk_attn(q, k, v, q_off, k_off, causal, scale):
     """One ring step: q local block vs one visiting kv chunk.
 
@@ -71,7 +60,7 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = True,
     traffic); expansion happens per-chunk inside _chunk_attn."""
     b, sq, h, d = q.shape
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     sk = k.shape[1]
     q_off = idx * sq

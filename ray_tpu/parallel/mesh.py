@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig, MeshAxes
+from ray_tpu.util import devmon
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +64,8 @@ class MeshSpec:
 def make_mesh(spec: MeshSpec = MeshSpec(),
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     devices = list(devices if devices is not None else jax.devices())
+    # this process now holds a backend: the device monitor may query it
+    devmon.mark_backend_live()
     sizes = spec.resolve(len(devices))
     names = tuple(sizes.keys())
     shape = tuple(sizes.values())
@@ -98,8 +101,8 @@ def make_train_step(cfg, mesh: Mesh,
                     model=llama):
     """Returns (init_fn(rng) -> TrainState, step_fn(state, batch) ->
     (state, metrics)). Both jitted with GSPMD sharding: params per
-    model.param_shardings, batch over (data+fsdp, context), opt state
-    sharded like params by propagation.
+    model.param_shardings, batch over (data+fsdp, context), the
+    optimizer's per-parameter state sharded like its parameter.
 
     ``model`` is any module exposing the model-family protocol
     (init_params / param_shardings / loss_fn) — ray_tpu.models.llama
@@ -116,7 +119,12 @@ def make_train_step(cfg, mesh: Mesh,
     def init_fn(rng) -> TrainState:
         params = jax.lax.with_sharding_constraint(
             model.init_params(rng, cfg), pshard)
-        opt_state = opt.init(params)
+        # moments start as zeros, which nothing ties to the params'
+        # sharding: left to propagation they come out replicated (and
+        # the first step then compiles twice, once per input layout)
+        opt_state = optax.tree_map_params(
+            opt, jax.lax.with_sharding_constraint, opt.init(params),
+            pshard)
         return TrainState(params, opt_state, jnp.zeros((), jnp.int32))
 
     @jax.jit

@@ -470,9 +470,23 @@ class ControlService:
     async def _health_loop(self):
         period = self.config.health_check_period_s
         threshold = period * self.config.health_check_failure_threshold
+        last_tick = time.monotonic()
         while True:
             await asyncio.sleep(period)
             now = time.monotonic()
+            # A heartbeat only counts once this loop has run its
+            # handler. When the loop itself wakes late — the process or
+            # the whole machine stood still (a TPU runtime pinning its
+            # host memory froze a v5e host for over five seconds while
+            # a replica started) — the beats sent meanwhile are still
+            # queued behind this tick, so the time lost is nobody's
+            # silence: credit it to every node instead of declaring
+            # them, and every actor on them, dead.
+            late = now - last_tick - period
+            last_tick = now
+            if late > period:
+                for n in self.nodes.values():
+                    n.last_heartbeat += late
             if self._recover_deadline and now > self._recover_deadline:
                 self._after_recovery_sweep()
             for n in list(self.nodes.values()):

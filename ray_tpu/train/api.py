@@ -676,12 +676,6 @@ def ensure_jax_distributed() -> bool:
             f"the jax.distributed env route needs all three")
     import jax
 
-    # The TPU plugin can ignore JAX_PLATFORMS from the env; pin the
-    # platform via the config API before the backend initializes so
-    # CPU-mesh groups (tests, multi-process CPU) stay off the chip.
-    plat = os.environ.get("JAX_PLATFORMS", "")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     jax.distributed.initialize(
         coordinator_address=os.environ["JAX_COORDINATOR_ADDRESS"],
         num_processes=int(os.environ["JAX_NUM_PROCESSES"]),

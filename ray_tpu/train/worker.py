@@ -100,9 +100,7 @@ class TrainWorker:
         from ray_tpu.train import api as train_api
 
         # Idempotent: a no-op if the train_fn (or a prior call) already
-        # joined — jax.distributed.initialize raises on double-init. The
-        # helper also pins JAX_PLATFORMS via the config API (the TPU
-        # plugin can ignore the env var).
+        # joined — jax.distributed.initialize raises on double-init.
         return train_api.ensure_jax_distributed()
 
     def start_train_fn(self, fn_payload: bytes,

@@ -131,33 +131,26 @@ def detect_resources() -> Dict[str, float]:
     return out
 
 
-# Dense peak TFLOPs per TPU generation (bf16 matmul) — the single
-# source of truth shared by the goodput ledger's train_mfu gauge
-# (util/goodput.py) and scripts/mfu_sweep.py. Keys are substrings
-# matched case-insensitively against jax's device_kind (e.g.
-# "TPU v5 lite" -> v5, handled by the explicit v5e/v5p entries first).
-PEAK_TFLOPS = {"v5e": 197.0, "v5p": 459.0, "v6": 918.0, "v4": 275.0}
-
-_WARNED_KINDS: set = set()
+# Dense peak TFLOP/s per chip (bf16 matmul), from Google Cloud's TPU
+# documentation — the one table behind every MFU number (bench.py, the
+# goodput ledger's train_mfu gauge, scripts/mfu_sweep.py). Keys are
+# substrings matched case-insensitively against jax's device_kind;
+# a v5e chip reports itself as "TPU v5 lite".
+PEAK_TFLOPS = {"v5 lite": 197.0, "v5e": 197.0, "v5p": 459.0,
+               "v6": 918.0, "v4": 275.0}
 
 
 def peak_tflops(kind: str) -> float:
-    """Peak dense TFLOPs for a device kind (substring match). An
-    unknown kind WARNS (once per kind) instead of silently assuming
-    v5e's 197 — a wrong denominator makes every MFU number quietly
-    wrong, which is worse than a noisy default."""
-    import sys
+    """Peak dense TFLOP/s for a device kind (substring match). A kind
+    that is not in the table RAISES: a guessed denominator makes every
+    MFU number derived from it quietly wrong."""
     low = (kind or "").lower()
     for k, v in PEAK_TFLOPS.items():
         if k in low:
             return v
-    if low not in _WARNED_KINDS:
-        _WARNED_KINDS.add(low)
-        print(f"[ray_tpu] unknown device kind {kind!r} for peak "
-              f"TFLOPs — assuming v5e's 197.0; MFU numbers derived "
-              f"from it are suspect (add the generation to "
-              f"util/accelerators.PEAK_TFLOPS)", file=sys.stderr)
-    return 197.0
+    raise ValueError(
+        f"no published peak TFLOP/s for device kind {kind!r}; add it "
+        f"to util/accelerators.PEAK_TFLOPS with its source")
 
 
 def detect_labels() -> Dict[str, str]:

@@ -97,9 +97,29 @@ _PEAK: Dict[str, int] = {}
 
 _DEVICE_LABEL: Optional[str] = None
 
+# Whether THIS process has initialised a JAX backend. A chip belongs
+# to one process at a time, and asking jax for its devices initialises
+# the backend — a worker that merely imported jax would claim the chip
+# (or block on the process that holds it) just to be observable. So
+# nothing here touches a device until the process has gone live on its
+# own: the engine and the mesh builder say so (mark_backend_live), and
+# so does the first backend compile the listener sees.
+_BACKEND_LIVE = False
+
 
 def enabled() -> bool:
     return _ENABLED
+
+
+def mark_backend_live() -> None:
+    """Called by code that has just put work on a device (LLMEngine,
+    make_mesh): from here on the monitor may query the backend."""
+    global _BACKEND_LIVE
+    _BACKEND_LIVE = True
+
+
+def backend_live() -> bool:
+    return _BACKEND_LIVE
 
 
 def devmon_metrics() -> dict:
@@ -279,6 +299,7 @@ def _on_duration(name: str, dur: float, **_kw) -> None:
         # a hit instead of double-recording a phantom recompile
         _TLS.cache_hit = True
     elif name == BACKEND_COMPILE_EVENT:
+        mark_backend_live()     # only a live backend compiles
         hit = getattr(_TLS, "cache_hit", False)
         _TLS.cache_hit = False
         record_compile(_take_pending_fn(), dur, cache_hit=hit)
@@ -351,10 +372,9 @@ def hbm_snapshot(record: bool = True) -> List[dict]:
     device_hbm_* gauges and (by default) records a "device"/"hbm"
     event per device so the head-aggregated timeline carries them to
     `/devices` and ``ray-tpu devices``. Returns the rows. Safe to call
-    on any backend; no-op (empty) when devmon is off or jax is not
-    imported."""
-    import sys
-    if not _ENABLED or "jax" not in sys.modules:
+    on any backend; no-op (empty) when devmon is off or this process
+    has not initialised a backend itself (see _BACKEND_LIVE)."""
+    if not _ENABLED or not _BACKEND_LIVE:
         return []
     import jax
     m = devmon_metrics()
@@ -402,17 +422,13 @@ def hbm_snapshot(record: bool = True) -> List[dict]:
 def _default_device_label() -> str:
     global _DEVICE_LABEL
     if _DEVICE_LABEL is None:
-        import sys
-        if "jax" not in sys.modules:
+        if not _BACKEND_LIVE:
             # bare index, not "dev:0": to_chrome prefixes lanes with
             # "dev:" itself, and a double prefix would split one
             # device's duty lane from its post-jax "cpu:0" windows
             return "0"
         import jax
-        try:
-            _DEVICE_LABEL = _device_label(jax.local_devices()[0])
-        except Exception:  # noqa: BLE001 — backend init failure
-            return "0"
+        _DEVICE_LABEL = _device_label(jax.local_devices()[0])
     return _DEVICE_LABEL
 
 

@@ -153,17 +153,23 @@ def set_model_flops(flops_per_step: float, *,
 
 def _peak() -> Optional[float]:
     """Peak TFLOPs, resolved once: explicit registration wins, else the
-    local jax device kind (guarded — no backend means no MFU gauge)."""
+    local jax device kind — asked only once this process has itself
+    initialised a backend (devmon.backend_live): a chip belongs to one
+    process, and the ledger must not claim it to compute a gauge. A
+    device kind without a published peak (the CPU backend) means no
+    MFU gauge."""
     global _PEAK_TFLOPS, _PEAK_RESOLVED
     if _PEAK_RESOLVED:
         return _PEAK_TFLOPS
+    from ray_tpu.util import devmon
+    if not devmon.backend_live():
+        return None
     _PEAK_RESOLVED = True
+    import jax
+    from ray_tpu.util.accelerators import peak_tflops as _pt
     try:
-        import jax
-        from ray_tpu.util.accelerators import peak_tflops as _pt
-        kind = getattr(jax.devices()[0], "device_kind", "")
-        _PEAK_TFLOPS = _pt(kind) if kind else None
-    except Exception:   # noqa: BLE001
+        _PEAK_TFLOPS = _pt(jax.devices()[0].device_kind)
+    except ValueError:
         _PEAK_TFLOPS = None
     return _PEAK_TFLOPS
 

@@ -14,11 +14,15 @@ import jax.numpy as jnp
 sys.path.insert(0, ".")
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.parallel import mesh as pmesh  # noqa: E402
+from ray_tpu.util import jaxenv  # noqa: E402
 from ray_tpu.util.accelerators import peak_tflops  # noqa: E402
+
+jaxenv.setup_compile_cache()
 
 
 def run_variant(name, cfg, batch, iters=10, warmup=3):
     dev = jax.devices()[0]
+    peak = peak_tflops(dev.device_kind)     # an unknown chip is an error
     seq = cfg.max_seq_len
     try:
         spec = pmesh.MeshSpec(data=1, fsdp=1, tensor=1, context=1)
@@ -40,7 +44,7 @@ def run_variant(name, cfg, batch, iters=10, warmup=3):
             dt = time.perf_counter() - t0
         toks = batch * seq * iters / dt
         tf = toks * cfg.flops_per_token(seq) / 1e12
-        mfu = 100.0 * tf / peak_tflops(getattr(dev, "device_kind", "v5e"))
+        mfu = 100.0 * tf / peak
         print(json.dumps({"variant": name, "mfu": round(mfu, 2),
                           "tflops": round(tf, 1),
                           "toks_per_s": round(toks, 0),

@@ -194,7 +194,7 @@ def main():
         try:
             with open(path) as f:
                 bench = json.load(f)
-        except Exception:
+        except FileNotFoundError:
             bench = {}
         bench[key] = row
         with open(path, "w") as f:

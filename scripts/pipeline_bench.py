@@ -12,6 +12,11 @@ actor executes) over the real shm channels, driven by the compiled
 Prints progress to stderr and ONE JSON line to stdout; also writes
 PIPELINE_BENCH.json.
 
+CPU ONLY (run it with JAX_PLATFORMS=cpu): the stage processes are
+forked from a parent that has already imported JAX, and each of them
+jits on its own. A chip belongs to one process at a time, so on a TPU
+host the stages would fight the parent and each other for it.
+
 Two stage-compute models, because this container has ONE host core:
 
   **device-time stages** (the headline): stage compute blocks the host

@@ -182,8 +182,11 @@ def main():
     }
     print(json.dumps(row, indent=1))
     if args.write:
-        with open("SERVE_BENCH.json") as f:
-            doc = json.load(f)
+        try:
+            with open("SERVE_BENCH.json") as f:
+                doc = json.load(f)
+        except FileNotFoundError:
+            doc = {}
         doc["spec"] = row
         with open("SERVE_BENCH.json", "w") as f:
             json.dump(doc, f, indent=1)

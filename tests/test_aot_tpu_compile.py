@@ -1,0 +1,95 @@
+"""The Pallas kernels of the main path, compiled ahead of time for a
+described TPU v5e chip, at the widths chip_smoke.py runs them.
+
+No chip is attached under pytest: the TPU compiler installed here
+compiles for a device that is only described, and raises what the
+chip's compiler would raise. Interpret mode (every other kernel test)
+checks the arithmetic but not the tiling: the paged-decode kernel passed
+every interpret-mode test while the compiler refused it at every shape.
+
+(The name sorts first on purpose: tier-1 is cut by a clock, and a file
+the clock never reaches guards nothing.)
+"""
+
+import os
+import sys
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.ops.pallas import paged_attention as pa  # noqa: E402
+
+A = sys.modules["ray_tpu.ops.attention"]    # the package re-exports a fn
+
+HD = 128        # head_dim of every Llama-2/3 width
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device; the persistent compile cache is off
+    around these compiles (an entry written for a described device
+    cannot be read back without one, and warns on the next run)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash(q, k, v, **kw):
+    return A.flash_attention(q, k, v, causal=True, **kw)
+
+
+def _flash_bwd(q, k, v):
+    def loss(q, k, v):
+        return _flash(q, k, v, block_q=1024, block_k=1024).astype(
+            jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _paged_case(kv_heads, group):
+    slots, blocks, block, width = 8, 2305, 16, 256      # 8 x 4096 tokens
+    return pa.paged_attention, (
+        ((slots, kv_heads, group, HD), jnp.bfloat16),
+        ((blocks, kv_heads, block, HD), jnp.bfloat16),
+        ((blocks, kv_heads, block, HD), jnp.bfloat16),
+        ((slots, width), jnp.int32), ((slots,), jnp.int32))
+
+
+_TRAIN = ((4, 4096, 32, HD), jnp.bfloat16)      # batch 4 x seq 4096, 7B
+CASES = {
+    "flash_fwd": (lambda q, k, v: _flash(q, k, v, block_q=1024,
+                                         block_k=1024),
+                  (_TRAIN, _TRAIN, _TRAIN)),
+    "flash_bwd": (_flash_bwd, (_TRAIN, _TRAIN, _TRAIN)),
+    # one 512-token chunk at position 1024 of a 4608-row accumulator
+    "flash_prefill_q_offset": (
+        lambda q, k, v: _flash(q, k, v, q_offset=1024),
+        (((1, 512, 32, HD), jnp.bfloat16),
+         ((1, 4608, 32, HD), jnp.bfloat16),
+         ((1, 4608, 32, HD), jnp.bfloat16))),
+    "paged_decode_g1": _paged_case(kv_heads=32, group=1),    # Llama-2-7B
+    "paged_decode_g4": _paged_case(kv_heads=8, group=4),     # Llama-3-8B
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # the kernel itself, not an XLA rewrite of it
+    assert "tpu_custom_call" in compiled.as_text()

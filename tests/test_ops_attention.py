@@ -10,7 +10,6 @@ from ray_tpu.ops import attention  # package attr may be the dispatcher fn
 import sys
 A = sys.modules["ray_tpu.ops.attention"]
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.ops import shard_map
 
 
 def _rand_qkv(key, b=2, s=256, h=4, kvh=None, d=64, dtype=jnp.float32):
@@ -97,7 +96,7 @@ def test_ring_attention_matches_reference(causal):
     ref = A.mha_reference(q, k, v, causal=causal)
 
     spec = P(None, "context", None, None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="context",
                                        causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
@@ -113,7 +112,7 @@ def test_ring_attention_grad():
     spec = P(None, "context", None, None)
 
     def ring_loss(q, k, v):
-        f = shard_map(
+        f = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="context"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return jnp.sum(f(q, k, v) ** 2)

@@ -162,6 +162,7 @@ def test_hbm_snapshot_cpu_fallback_aggregates_live_arrays():
     import jax.numpy as jnp
     _reset()
     arr = jnp.ones((4096,), jnp.float32)      # 16 KB held live
+    devmon.mark_backend_live()      # this process put work on a device
     rows = devmon.hbm_snapshot()
     assert rows, "no local devices snapshotted"
     by_dev = {r["device"]: r for r in rows}

@@ -221,8 +221,11 @@ def test_mfu_gauge_from_registered_flops():
     from ray_tpu.util.accelerators import peak_tflops
     assert peak_tflops("TPU v5e") == 197.0
     assert peak_tflops("TPU v5p") == 459.0
-    # unknown kinds warn (once) and fall back rather than crash
-    assert peak_tflops("TPU v99") == 197.0
+    # a v5e chip's device_kind, as jax reports it
+    assert peak_tflops("TPU v5 lite") == 197.0
+    # an unknown kind is an error, never a guessed denominator
+    with pytest.raises(ValueError, match="TPU v99"):
+        peak_tflops("TPU v99")
 
 
 # --- straggler detection -----------------------------------------------------

@@ -58,9 +58,9 @@ def _rand_case(seed, *, b, w, bs, kvh, g, hd, nb):
     """Random q/pool + disjoint per-slot block tables."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, kvh, g, hd)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd))
+    k = jnp.asarray(rng.normal(size=(nb, kvh, bs, hd))
                     .astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd))
+    v = jnp.asarray(rng.normal(size=(nb, kvh, bs, hd))
                     .astype(np.float32))
     tables = jnp.asarray(
         (1 + np.arange(b * w)).reshape(b, w).astype(np.int32))
@@ -112,8 +112,8 @@ def test_kernel_bitwise_on_integer_pow2_construction():
     q = jnp.asarray(rng.normal(size=(b, kvh, g, hd))
                     .astype(np.float32))
     nb = 1 + b * w
-    k = jnp.ones((nb, bs, kvh, hd), jnp.float32)
-    v = jnp.asarray(rng.integers(-8, 8, size=(nb, bs, kvh, hd))
+    k = jnp.ones((nb, kvh, bs, hd), jnp.float32)
+    v = jnp.asarray(rng.integers(-8, 8, size=(nb, kvh, bs, hd))
                     .astype(np.float32))
     tables = jnp.asarray(
         (1 + np.arange(b * w)).reshape(b, w).astype(np.int32))
@@ -139,8 +139,8 @@ def test_kernel_cow_forked_tables_diverge_mid_decode():
     q = jnp.asarray(np.broadcast_to(
         rng.normal(size=(1, kvh, g, hd)).astype(np.float32),
         (b, kvh, g, hd)).copy())
-    k = rng.normal(size=(nb, bs, kvh, hd)).astype(np.float32)
-    v = rng.normal(size=(nb, bs, kvh, hd)).astype(np.float32)
+    k = rng.normal(size=(nb, kvh, bs, hd)).astype(np.float32)
+    v = rng.normal(size=(nb, kvh, bs, hd)).astype(np.float32)
     shared = np.asarray([[1, 2, 3, kc.TRASH]] * 2, np.int32)
     length = 20                                 # pos 19 in block 3
     lengths = jnp.asarray([length, length], jnp.int32)
@@ -151,8 +151,8 @@ def test_kernel_cow_forked_tables_diverge_mid_decode():
 
     # COW: clone phys 3 -> 4, repoint the fork, diverge position 19
     k[4], v[4] = k[3], v[3]
-    k[4, 19 % bs] += 1.0
-    v[4, 19 % bs] -= 1.0
+    k[4, :, 19 % bs] += 1.0
+    v[4, :, 19 % bs] -= 1.0
     forked = shared.copy()
     forked[1, 2] = 4
     after = np.asarray(pa.paged_attention(
@@ -207,6 +207,32 @@ def test_config_knobs_drive_engine_impl(tiny_model, monkeypatch):
 
 
 # --- decode-path parity through the engine ----------------------------
+
+
+def test_decode_step_logits_kernel_matches_gather(tiny_model):
+    """kvcache.paged_decode_logits — the parity entry chip_smoke.py
+    runs on the chip at real widths — here under the interpreter: one
+    decode step through the whole model gives the same logits whether
+    attention walks the block table or gathers the view, at a length
+    of one, inside a block, and on a block edge."""
+    cfg, params = tiny_model
+    bs, w = 8, 4
+    pool = kc.init_pool(cfg, 1 + 3 * w, bs, jnp.float32)
+    pool = {k: jax.random.normal(jax.random.PRNGKey(i), v.shape)
+            for i, (k, v) in enumerate(pool.items())}
+    tables = jnp.asarray(
+        (1 + np.arange(3 * w)).reshape(3, w).astype(np.int32))
+    positions = jnp.asarray([0, 12, 23], jnp.int32)
+    tokens = jnp.asarray([3, 9, 27], jnp.int32)
+    want = kc.paged_decode_logits(params, pool, tables, positions,
+                                  tokens, cfg, impl="gather")
+    got = kc.paged_decode_logits(params, pool, tables, positions,
+                                 tokens, cfg, impl="paged_flash",
+                                 interpret=True)
+    assert want.shape == (3, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
 
 
 def test_engine_kernel_impl_matches_gather_impl(tiny_model):
