@@ -20,6 +20,8 @@ device steps run on an executor thread to keep the event loop live.
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import logging
 import math
 import os
 import time
@@ -31,7 +33,25 @@ import numpy as np
 
 from ray_tpu.llm import kvcache, model as lm, spec as specdec
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.util import devmon, tracing
+from ray_tpu.util import devmon, events, tracing
+
+logger = logging.getLogger("ray_tpu.llm.engine")
+
+# The scheduler loop's phases. Each is one FLAT leaf span
+# (``engine.<phase>`` in the profiler's host plane) and one histogram
+# (``llm_loop_<phase>_s``); none encloses another, and what lies
+# between two of them is thread hops and other coroutines.
+PHASES = ("admit.alloc", "prefill.dispatch", "prefill.wait",
+          "prefill.sample", "decode.prepare", "decode.dispatch",
+          "decode.readback", "verify.prepare", "verify.dispatch",
+          "verify.readback", "verify.accept", "emit", "yield", "idle")
+# a phase (other than idle) over this long leaves a slow_phase event
+SLOW_PHASE_S = 1.0
+
+
+def _loop_key(phase: str) -> str:
+    """engine_metrics() key of a phase's histogram."""
+    return "loop_" + phase.replace(".", "_")
 
 
 def _jx():
@@ -62,6 +82,25 @@ def engine_metrics() -> dict:
       llm_ttft_wall_s    submit -> first token, wall clock
       llm_tpot_s         decode wall time per output token
       llm_batch_size     active decode slots per step block
+      llm_stream_lag_s   a streamed token's wait between the loop's
+                         emit and its generate_stream consumer
+
+    The scheduler loop, timed and counted where the work is done (the
+    sums and counts are what the benchmark's per-layer metrics read):
+
+      llm_loop_<phase>_s       one per PHASES entry: seconds per span
+      llm_decode_gap_s         previous block's read-back end -> this
+                               block's dispatch, while a request went
+                               on decoding: what a decoding slot stalls
+                               between blocks
+      llm_decode_gap_admit_s   of that gap, the part inside
+                               engine.admit.* and engine.prefill.*
+      llm_decode_block_steps   decode steps per block
+      llm_decode_slot_steps    slots x steps per block
+      llm_decode_ctx_tokens    positions attended per block, summed
+                               over its slots and steps
+      llm_prefill_tokens       prompt tokens run through a prefill
+                               forward per admit (prefix hits excluded)
 
     HBM attribution (the engine half of util/devmon.py's device plane):
 
@@ -69,7 +108,48 @@ def engine_metrics() -> dict:
       llm_kv_cache_headroom_bytes  growth left before max_len capacity
     """
     from ray_tpu.util import metrics as m
+    seconds = (.0001, .00025, .0005, .001, .0025, .005, .01, .025, .05,
+               .1, .25, .5, 1, 2.5, 10)
+    loop = {
+        _loop_key(p): m.Histogram(
+            f"llm_{_loop_key(p)}_s",
+            f"Seconds per engine.{p} span of the scheduler loop",
+            boundaries=seconds)
+        for p in PHASES}
     return {
+        **loop,
+        "gap": m.Histogram(
+            "llm_decode_gap_s",
+            "Previous decode block's read-back end to this block's "
+            "dispatch, observed when a request of the previous block "
+            "is still decoding", boundaries=seconds),
+        "gap_admit": m.Histogram(
+            "llm_decode_gap_admit_s",
+            "The part of llm_decode_gap_s spent inside engine.admit.* "
+            "and engine.prefill.* spans", boundaries=seconds),
+        "block_steps": m.Histogram(
+            "llm_decode_block_steps", "Decode steps per block",
+            boundaries=(1, 2, 4, 8, 16, 32)),
+        "slot_steps": m.Histogram(
+            "llm_decode_slot_steps",
+            "Active slots times decode steps per block",
+            boundaries=(1, 4, 16, 64, 256, 1024)),
+        "ctx_tokens": m.Histogram(
+            "llm_decode_ctx_tokens",
+            "Context positions attended per decode block, summed over "
+            "its active slots and steps",
+            boundaries=(64, 256, 1024, 4096, 16384, 65536, 262144,
+                        1048576)),
+        "prefill_tokens": m.Histogram(
+            "llm_prefill_tokens",
+            "Prompt tokens run through a prefill forward per admitted "
+            "request (prefix-cache hits excluded)",
+            boundaries=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096)),
+        "stream_lag": m.Histogram(
+            "llm_stream_lag_s",
+            "Wait of a streamed token between the scheduler loop's "
+            "emit and its generate_stream consumer",
+            boundaries=seconds),
         "queue": m.Histogram(
             "llm_queue_s",
             "Wait from request submission to slot admission"),
@@ -276,12 +356,6 @@ class LLMEngine:
                     mesh, P(None, None, tensor_axis, None, None))
                 self._pool = {k: jax.device_put(v, s)
                               for k, v in self._pool.items()}
-            # what one decode step would have copied materializing the
-            # gathered (slots, table_w * block) view, per layer and
-            # k+v — the bytes the fused kernel keeps out of HBM
-            self._gather_step_bytes = (
-                max_slots * self._table_w
-                * kvcache.pool_block_bytes(self._pool))
             self._kv = kvcache.KVBlockManager(
                 nb, self._block, table_width=self._table_w,
                 prefix_cache=prefix_cache, metrics=self._kvm)
@@ -315,6 +389,14 @@ class LLMEngine:
         # histograms, pushed to the head from worker processes); the
         # scalar counters below feed the legacy `stats` surface.
         self._m = engine_metrics()
+        self._phases = {p: ("engine." + p, self._m[_loop_key(p)])
+                        for p in PHASES}
+        # the stall between two decode blocks: where the last read-back
+        # ended (None when no request carried over), the admit/prefill
+        # seconds since, and the last block's device interval
+        self._gap_from: Optional[float] = None
+        self._gap_admit = 0.0
+        self._dev_span = (0.0, 0.0)
         self._kv_account()
         self._requests = 0
         self._tokens_generated = 0
@@ -363,6 +445,28 @@ class LLMEngine:
             return kvcache.pool_block_bytes(self._pool) / self._block
         n = self._cache["k"].nbytes + self._cache["v"].nbytes
         return n / float(self.max_slots * self._cache_len)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One leaf span of the scheduler loop (a PHASES entry): the
+        ``engine.<name>`` annotation, its ``llm_loop_*`` histogram and
+        the stamps, all from tracing.phase's one pair of clock reads.
+        Admission and prefill time also accrues to the decode gap it
+        sits in, and a phase over SLOW_PHASE_S names itself."""
+        with tracing.phase(*self._phases[name]) as ph:
+            yield ph
+        if name.startswith(("admit.", "prefill.")):
+            self._gap_admit += ph.dur
+        if ph.dur > SLOW_PHASE_S and name != "idle":
+            active = sum(r is not None for r in self._slots)
+            waiting = self._waiting.qsize() + (
+                len(self._blocked) if self._paged else 0)
+            events.record("engine", "slow_phase", phase=name,
+                          dur=ph.dur, active=active, waiting=waiting,
+                          pid=os.getpid())
+            logger.warning(
+                "engine.%s took %.2f s (%d active, %d waiting)",
+                name, ph.dur, active, waiting)
 
     def _kv_account(self) -> None:
         """Publish the engine's explicit KV HBM attribution. Paged:
@@ -460,7 +564,9 @@ class LLMEngine:
                 return
             if isinstance(t, BaseException):
                 raise t
-            yield t
+            tok, t_put = t
+            self._m["stream_lag"].observe(time.monotonic() - t_put)
+            yield tok
 
     async def generate_prefilled(self, tokens, prefilled: dict,
                                  **kw) -> dict:
@@ -583,6 +689,7 @@ class LLMEngine:
 
     async def _run(self):
         loop = asyncio.get_running_loop()
+        phase = self._phase
         try:
             while not self._stopped:
                 # 1) admit waiting requests into free slots (prefill) —
@@ -602,23 +709,24 @@ class LLMEngine:
                         # pressure — overload parks the ADMIT instead
                         # (FIFO; a parked head-of-line also blocks the
                         # queue behind it, preserving arrival order)
-                        try:
-                            alloc = self._kv.alloc_seq(
-                                r.seq, r.tokens, r.max_new_tokens)
-                        except kvcache.BlockPoolExhausted as e:
-                            self._fail(r, None, e)
-                            continue
-                        if alloc is None:
-                            self._blocked.appendleft(r)
-                            break
-                        r.kv_alloc = alloc
-                        r.prefix_hit = alloc["hit_tokens"]
-                        # publish live-bytes/headroom NOW: a wave of
-                        # long decodes would otherwise report
-                        # init-time gauges until the first finish —
-                        # exactly the overload window the gauges
-                        # exist for
-                        self._kv_account()
+                        with phase("admit.alloc"):
+                            try:
+                                alloc = self._kv.alloc_seq(
+                                    r.seq, r.tokens, r.max_new_tokens)
+                            except kvcache.BlockPoolExhausted as e:
+                                self._fail(r, None, e)
+                                continue
+                            if alloc is None:
+                                self._blocked.appendleft(r)
+                                break
+                            r.kv_alloc = alloc
+                            r.prefix_hit = alloc["hit_tokens"]
+                            # publish live-bytes/headroom NOW: a wave
+                            # of long decodes would otherwise report
+                            # init-time gauges until the first finish
+                            # — exactly the overload window the gauges
+                            # exist for
+                            self._kv_account()
                     try:
                         tok = await loop.run_in_executor(
                             None, self._admit_sync, slot, r)
@@ -640,7 +748,8 @@ class LLMEngine:
                         # prefill path turned into a silent stall)
                         self._fail(r, slot, e)
                         raise
-                    self._emit_token(r, tok, slot)
+                    with phase("emit"):
+                        self._emit_token(r, tok, slot)
                 # deadline-cancel active slots at the block boundary:
                 # the slot is reclaimed NOW (the next admit pass refills
                 # it) instead of decoding to max_new_tokens for a client
@@ -653,16 +762,19 @@ class LLMEngine:
                 active = [i for i, r in enumerate(self._slots)
                           if r is not None]
                 if not active:
+                    self._gap_from = None   # nobody is stalled by it
                     if self._paged and self._blocked:
                         # pool-parked admits with nothing running can
                         # only be waiting on eviction — re-try shortly
                         # instead of parking on the (possibly empty)
                         # waiting queue forever
-                        await asyncio.sleep(0.01)
+                        with phase("idle"):
+                            await asyncio.sleep(0.01)
                         continue
                     if self._waiting.empty():
                         # idle: park until work arrives
-                        r = await self._waiting.get()
+                        with phase("idle"):
+                            r = await self._waiting.get()
                         self._waiting.put_nowait(r)
                     continue
                 # 2a) speculative verify round (engine spec mode): ask
@@ -679,25 +791,28 @@ class LLMEngine:
                 # adversarial-prompt overhead.
                 drafts: dict = {}
                 if self._spec:
-                    for i in active:
-                        r = self._slots[i]
-                        if r.drafter is None:
-                            continue
-                        # leave room for the bonus token and never
-                        # draft past the request's horizon
-                        budget = min(
-                            self._spec_k,
-                            r.max_new_tokens - len(r.out) - 1,
-                            self._cache_len - len(r.tokens)
-                            - len(r.out) - 1)
-                        if budget < 1:
-                            continue
-                        d = r.drafter.propose(r.tokens + r.out, budget)
-                        if d:
-                            drafts[i] = d
+                    with phase("verify.prepare"):
+                        for i in active:
+                            r = self._slots[i]
+                            if r.drafter is None:
+                                continue
+                            # leave room for the bonus token and never
+                            # draft past the request's horizon
+                            budget = min(
+                                self._spec_k,
+                                r.max_new_tokens - len(r.out) - 1,
+                                self._cache_len - len(r.tokens)
+                                - len(r.out) - 1)
+                            if budget < 1:
+                                continue
+                            d = r.drafter.propose(r.tokens + r.out,
+                                                  budget)
+                            if d:
+                                drafts[i] = d
                 if drafts:
                     await self._spec_round(loop, active, drafts)
-                    await asyncio.sleep(0)
+                    with phase("yield"):
+                        await asyncio.sleep(0)
                     continue
                 # 2) a BLOCK of decode steps for every active slot, one
                 # host sync per block. Sampling is on-device
@@ -708,77 +823,57 @@ class LLMEngine:
                 # steps (discarded at emit, slot freed at the sync) —
                 # the batch's throughput is worth more than the waste,
                 # and headroom bounds below keep its cache writes legal.
-                block = self.steps_per_sync
-                for i in active:
-                    r = self._slots[i]
-                    block = min(block,
-                                r.max_new_tokens - len(r.out),
-                                self._cache_len - len(r.tokens)
-                                - len(r.out))
-                block = 1 << (max(1, block).bit_length() - 1)  # pow2 dn
-                tokens = np.zeros((self.max_slots,), np.int32)
-                temps = np.zeros((self.max_slots,), np.float32)
-                top_ps = np.ones((self.max_slots,), np.float32)
-                top_ks = np.zeros((self.max_slots,), np.int32)
-                for i in active:
-                    tokens[i] = self._slots[i].out[-1]
-                    temps[i] = self._slots[i].temperature
-                    top_ps[i] = self._slots[i].top_p
-                    top_ks[i] = self._slots[i].top_k
-                member_traces = sorted(
-                    {self._slots[i].trace.trace_id
-                     for i in active
-                     if self._slots[i] is not None
-                     and self._slots[i].trace is not None})
-                first_ctx = next(
-                    (self._slots[i].trace for i in active
-                     if self._slots[i] is not None
-                     and self._slots[i].trace is not None), None)
-                t_dec = time.monotonic()
-                t_dec_wall = time.time()
+                with phase("decode.prepare"):
+                    block = self.steps_per_sync
+                    for i in active:
+                        r = self._slots[i]
+                        block = min(block,
+                                    r.max_new_tokens - len(r.out),
+                                    self._cache_len - len(r.tokens)
+                                    - len(r.out))
+                    # pow2, rounded down
+                    block = 1 << (max(1, block).bit_length() - 1)
+                    tokens = np.zeros((self.max_slots,), np.int32)
+                    temps = np.zeros((self.max_slots,), np.float32)
+                    top_ps = np.ones((self.max_slots,), np.float32)
+                    top_ks = np.zeros((self.max_slots,), np.int32)
+                    for i in active:
+                        tokens[i] = self._slots[i].out[-1]
+                        temps[i] = self._slots[i].temperature
+                        top_ps[i] = self._slots[i].top_p
+                        top_ks[i] = self._slots[i].top_k
+                    member_traces, first_ctx = self._members(active)
                 out = await loop.run_in_executor(
                     None, self._decode_sync, tokens, temps, top_ps,
                     top_ks, block, first_ctx)
-                # the block belongs to every member trace; the
-                # EXEMPLAR can only name one — use the SAME member
-                # whose context was bound inside _decode_sync, so
-                # following the exemplar (`ray-tpu trace <id>`) shows
-                # any decode-path compile span stamped during this
-                # block, not a sibling's waterfall
-                ex = first_ctx.trace_id if first_ctx is not None \
-                    else None
-                self._m["batch"].observe(len(active), exemplar=ex)
-                self._m["tpot"].observe(
-                    (time.monotonic() - t_dec) / block, exemplar=ex)
-                # one span per decode BLOCK, linked to every member
-                # trace: the block is shared compute, so it belongs to
-                # all of them rather than to one (each member's
-                # waterfall pulls it in via the links). The span also
-                # names the attention impl the block ran and the HBM
-                # copy bytes the fused kernel avoided — the trace
-                # answers "which decode path was this" directly.
-                kv_impl = self._kv_impl if self._paged else "monolithic"
-                avoided = (block * self._gather_step_bytes
-                           if self._paged
-                           and self._kv_impl == "paged_flash" else 0)
-                tracing.record_batch_span(
-                    "engine", "decode", member_traces,
-                    t_dec_wall, time.time(), block=block,
-                    slots=len(active), kv_impl=kv_impl,
-                    gather_bytes_avoided=avoided)
-                # the same interval is a device-compute window (the
-                # decode block is block_until_ready-bounded by the
-                # host transfer of its sampled tokens)
-                devmon.record_device_window(
-                    "decode", t_dec_wall, time.time(),
-                    trace=ex or "")
-                for step in range(block):
-                    for i in active:
-                        r = self._slots[i]
-                        if r is None:  # finished earlier in this block
-                            continue
-                        self._emit_token(r, int(out[step, i]), i)
-                await asyncio.sleep(0)
+                # the work of the block just read back, counted here:
+                # steps, slot-steps, and the positions each step
+                # attended (prompt + emitted so far, one more a step).
+                # A slot that hits eos mid-block still ran its steps.
+                n = len(active)
+                self._m["block_steps"].observe(block)
+                self._m["slot_steps"].observe(n * block)
+                self._m["ctx_tokens"].observe(
+                    block * sum(len(self._slots[i].tokens)
+                                + len(self._slots[i].out)
+                                for i in active)
+                    + n * block * (block - 1) // 2)
+                self._record_block(n, block, member_traces, first_ctx,
+                                   block=block)
+                with phase("emit"):
+                    for step in range(block):
+                        for i in active:
+                            r = self._slots[i]
+                            if r is None:  # finished earlier this block
+                                continue
+                            self._emit_token(r, int(out[step, i]), i)
+                # whoever is still in its slot waits for the next
+                # block from the moment this one was read back
+                self._gap_from = self._dev_span[1] if any(
+                    self._slots[i] is not None for i in active) else None
+                self._gap_admit = 0.0
+                with phase("yield"):
+                    await asyncio.sleep(0)
         except BaseException as e:  # noqa: BLE001 — fail all requests
             for i, r in enumerate(self._slots):
                 if r is not None:
@@ -792,6 +887,40 @@ class LLMEngine:
             for i, r in enumerate(self._slots):
                 if r is not None:
                     self._finish(r, i)
+
+    def _members(self, active: List[int]):
+        """(sorted trace ids of the batch's traced requests, the first
+        such request's context)."""
+        ctxs = [self._slots[i].trace for i in active
+                if self._slots[i] is not None
+                and self._slots[i].trace is not None]
+        return (sorted({c.trace_id for c in ctxs}),
+                ctxs[0] if ctxs else None)
+
+    def _record_block(self, slots: int, tokens_per_slot: float,
+                      member_traces: List[str], first_ctx, **attrs):
+        """What one decode block (or verify round) leaves behind, all
+        from the ONE interval its executor phases stamped (dispatch
+        start to read-back end): the batch-size and TPOT observations,
+        one span linked to every member trace (the block is shared
+        compute, so each member's waterfall pulls it in via the
+        links; it names the attention impl the block ran), and the
+        same interval as a device-compute window for the duty-cycle
+        estimator. The EXEMPLAR can only name one trace: the member
+        whose context was bound on the executor thread, so following
+        it (`ray-tpu trace <id>`) shows any decode-path compile span
+        stamped during this block, not a sibling's waterfall."""
+        t0, t1 = self._dev_span
+        ex = first_ctx.trace_id if first_ctx is not None else None
+        self._m["batch"].observe(slots, exemplar=ex)
+        self._m["tpot"].observe((t1 - t0) / tokens_per_slot,
+                                exemplar=ex)
+        w0, w1 = tracing.wall(t0), tracing.wall(t1)
+        tracing.record_batch_span(
+            "engine", "decode", member_traces, w0, w1, slots=slots,
+            kv_impl=self._kv_impl if self._paged else "monolithic",
+            **attrs)
+        devmon.record_device_window("decode", w0, w1, trace=ex or "")
 
     def _admit_sync(self, slot: int, r: _Request) -> int:
         """Prefill entry (executor thread): binds the request's trace
@@ -834,7 +963,7 @@ class LLMEngine:
         (paged). Returns the first sampled token. Remotely-prefilled
         requests skip the forward pass: their shipped KV is written
         straight into the slot."""
-        jax, jnp = _jx()
+        _, jnp = _jx()
         n = len(r.tokens)
         r.admitted_at = time.monotonic()
         self._m["queue"].observe(r.admitted_at - r.submitted)
@@ -865,52 +994,61 @@ class LLMEngine:
             need = max(need, pad_to)
         if need > self._cache_len:
             self._grow_cache(need)
-        if r.prefilled is not None:
-            p = r.prefilled
-            r.prefilled = None          # free the host copy after write
-            take = self._take_handoff
-            t0 = time.monotonic()
-            kv_k = jnp.asarray(take(p["k"]))
-            kv_v = jnp.asarray(take(p["v"]))
-            r.handoff_bytes = int(kv_k.nbytes + kv_v.nbytes)
-            self._kvm["handoff_bytes"].inc(r.handoff_bytes)
-            padw = pad_to - kv_k.shape[1]
-            if padw > 0:
-                widths = ((0, 0), (0, padw), (0, 0), (0, 0))
-                kv_k = jnp.pad(kv_k, widths)
-                kv_v = jnp.pad(kv_v, widths)
-            kv = {"k": kv_k, "v": kv_v}
+        with self._phase("prefill.dispatch") as disp:
+            if r.prefilled is not None:
+                # device TTFT for a disaggregated request is the
+                # handoff resolution + cache write on THIS engine (the
+                # prefill forward ran on the remote tier)
+                p = r.prefilled
+                r.prefilled = None      # free the host copy after write
+                take = self._take_handoff
+                kv_k = jnp.asarray(take(p["k"]))
+                kv_v = jnp.asarray(take(p["v"]))
+                r.handoff_bytes = int(kv_k.nbytes + kv_v.nbytes)
+                self._kvm["handoff_bytes"].inc(r.handoff_bytes)
+                padw = pad_to - kv_k.shape[1]
+                if padw > 0:
+                    widths = ((0, 0), (0, padw), (0, 0), (0, 0))
+                    kv_k = jnp.pad(kv_k, widths)
+                    kv_v = jnp.pad(kv_v, widths)
+                kv = {"k": kv_k, "v": kv_v}
+                logits, ran = take(p["logits"]), 0
+            elif n <= self.buckets[-1]:
+                b = self._bucket_for(n)
+                padded = lm.pad_prompt(r.tokens, b)
+                logits, kv = lm.prefill(self.params, jnp.asarray(padded),
+                                        jnp.int32(n), self.cfg,
+                                        self._cache_len)
+                ran = n
+            else:
+                logits, kv = self._chunked_prefill(r.tokens)
+                ran = n
             self._cache = lm.write_prefill_to_cache(
                 self._cache, kv, slot, jnp.int32(n))
-            logits_np = np.asarray(take(p["logits"]))
-            # device TTFT for a disaggregated request is the handoff
-            # resolution + cache write on THIS engine (the prefill
-            # forward ran on the remote tier)
-            jax.block_until_ready(self._cache["k"])
-            r.prefill_device_s = time.monotonic() - t0
-            self._record_prefill_span(r)
-            self._slots[slot] = r
-            return self._sample_one(logits_np, r)
-        t0 = time.monotonic()
-        if n <= self.buckets[-1]:
-            b = self._bucket_for(n)
-            padded = lm.pad_prompt(r.tokens, b)
-            logits, kv = lm.prefill(self.params, jnp.asarray(padded),
-                                    jnp.int32(n), self.cfg,
-                                    self._cache_len)
-        else:
-            logits, kv = self._chunked_prefill(r.tokens)
-        self._cache = lm.write_prefill_to_cache(
-            self._cache, kv, slot, jnp.int32(n))
-        # block_until_ready bounds the DEVICE portion of TTFT: dispatch
-        # above is async, so the wall clock alone can't attribute a slow
-        # first token to compute vs queueing
-        logits_np = np.asarray(logits)
-        jax.block_until_ready(self._cache["k"])
-        r.prefill_device_s = time.monotonic() - t0
-        self._record_prefill_span(r)
+        return self._first_token(slot, r, disp, logits,
+                                 self._cache["k"], ran)
+
+    def _first_token(self, slot: int, r: _Request, disp, logits,
+                     written, ran: int) -> int:
+        """The end of every admit path: wait for the prefill the
+        ``disp`` phase dispatched (its logits, and ``written``, the
+        cache it wrote), then sample the first token on the host.
+        Dispatch is async, so the wall clock alone cannot attribute a
+        slow first token to compute or to queueing: dispatch start to
+        wait end bounds the DEVICE portion of TTFT. ``ran`` is how
+        many prompt tokens went through a prefill forward."""
+        jax, _ = _jx()
+        with self._phase("prefill.wait") as wait:
+            logits_np = np.asarray(logits)
+            jax.block_until_ready(written)
+        if ran:
+            self._m["prefill_tokens"].observe(ran)
+        r.kv_written = True
+        r.prefill_device_s = wait.t1 - disp.t0
+        self._record_prefill_span(r, disp.t0, wait.t1)
         self._slots[slot] = r
-        return self._sample_one(logits_np, r)
+        with self._phase("prefill.sample"):
+            return self._sample_one(logits_np, r)
 
     def _acc_len(self) -> int:
         """Accumulator length for block-table prefill: the full table
@@ -950,60 +1088,58 @@ class LLMEngine:
         monolithic path), and prefix-hit / long-prompt chunked prefill
         (gather cached prefix blocks, run lm.prefill_chunk on the
         suffix only — the prefix's device time is ~eliminated)."""
-        jax, jnp = _jx()
+        _, jnp = _jx()
         n = len(r.tokens)
         table = r.kv_alloc["table"]
         hit = r.prefix_hit
         B = self._block
         self._tables[slot] = table
-        t0 = time.monotonic()
-        if r.prefilled is not None:
-            p = r.prefilled
-            r.prefilled = None
-            take = self._take_handoff
-            k_np = np.asarray(take(p["k"]))
-            v_np = np.asarray(take(p["v"]))
-            logits_np = np.asarray(take(p["logits"]))
-            r.handoff_bytes = int(k_np.nbytes + v_np.nbytes)
-            self._kvm["handoff_bytes"].inc(r.handoff_bytes)
-            acc_len = self._acc_len()
-            pad = acc_len - k_np.shape[1]
-            widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-            acc = {"k": jnp.asarray(np.pad(k_np, widths)),
-                   "v": jnp.asarray(np.pad(v_np, widths))}
-            # shared prefix blocks (a hit makes the shipped bytes for
-            # them redundant) and beyond-horizon slots write to trash
-            targets = table.copy()
-            targets[:hit // B] = kvcache.TRASH
-            self._pool = kvcache.scatter_table(self._pool, acc,
-                                               jnp.asarray(targets))
-        elif hit == 0 and n <= self.buckets[-1]:
-            # cache-cold short prompt: the SAME lm.prefill forward the
-            # monolithic engine runs (bitwise parity), padded only to
-            # its bucket; pad-garbage blocks redirect to trash via the
-            # table's unallocated tail
-            b = self._bucket_for(n)
-            padded = lm.pad_prompt(r.tokens, b)
-            logits, kv = lm.prefill(self.params, jnp.asarray(padded),
-                                    jnp.int32(n), self.cfg, b)
-            nb = b // B
-            phys = np.full((nb,), kvcache.TRASH, np.int32)
-            phys[:min(nb, self._table_w)] = table[:min(nb,
-                                                       self._table_w)]
-            self._pool = kvcache.scatter_bucket(
-                self._pool, kv, jnp.asarray(phys), nb)
-            logits_np = np.asarray(logits)
-        else:
-            logits_np = self._prefill_into_blocks(r, table, hit)
-        jax.block_until_ready(self._pool["k"])
-        r.kv_written = True
-        r.prefill_device_s = time.monotonic() - t0
-        self._record_prefill_span(r)
-        self._slots[slot] = r
-        return self._sample_one(logits_np, r)
+        with self._phase("prefill.dispatch") as disp:
+            if r.prefilled is not None:
+                p = r.prefilled
+                r.prefilled = None
+                take = self._take_handoff
+                k_np = np.asarray(take(p["k"]))
+                v_np = np.asarray(take(p["v"]))
+                logits, ran = take(p["logits"]), 0
+                r.handoff_bytes = int(k_np.nbytes + v_np.nbytes)
+                self._kvm["handoff_bytes"].inc(r.handoff_bytes)
+                acc_len = self._acc_len()
+                pad = acc_len - k_np.shape[1]
+                widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+                acc = {"k": jnp.asarray(np.pad(k_np, widths)),
+                       "v": jnp.asarray(np.pad(v_np, widths))}
+                # shared prefix blocks (a hit makes the shipped bytes
+                # for them redundant) and beyond-horizon slots write
+                # to trash
+                targets = table.copy()
+                targets[:hit // B] = kvcache.TRASH
+                self._pool = kvcache.scatter_table(
+                    self._pool, acc, jnp.asarray(targets))
+            elif hit == 0 and n <= self.buckets[-1]:
+                # cache-cold short prompt: the SAME lm.prefill forward
+                # the monolithic engine runs (bitwise parity), padded
+                # only to its bucket; pad-garbage blocks redirect to
+                # trash via the table's unallocated tail
+                b = self._bucket_for(n)
+                padded = lm.pad_prompt(r.tokens, b)
+                logits, kv = lm.prefill(self.params, jnp.asarray(padded),
+                                        jnp.int32(n), self.cfg, b)
+                nb = b // B
+                phys = np.full((nb,), kvcache.TRASH, np.int32)
+                phys[:min(nb, self._table_w)] = table[:min(
+                    nb, self._table_w)]
+                self._pool = kvcache.scatter_bucket(
+                    self._pool, kv, jnp.asarray(phys), nb)
+                ran = n
+            else:
+                logits = self._prefill_into_blocks(r, table, hit)
+                ran = n - self._prefill_start(hit)
+        return self._first_token(slot, r, disp, logits,
+                                 self._pool["k"], ran)
 
     def _prefill_into_blocks(self, r: _Request, table: np.ndarray,
-                             hit: int) -> np.ndarray:
+                             hit: int):
         """Prefix-hit (and long-prompt) prefill: gather the table's
         cached blocks into a contiguous accumulator, run the suffix
         through lm.prefill_chunk at the prefix offset (pieces aligned
@@ -1012,7 +1148,7 @@ class LLMEngine:
         tests pin), then scatter the NEW positions' KV back into the
         request's own blocks. Shared prefix blocks are never written
         (their scatter targets are the trash block)."""
-        jax, jnp = _jx()
+        _, jnp = _jx()
         n = len(r.tokens)
         B = self._block
         chunk = self.buckets[-1]
@@ -1034,23 +1170,24 @@ class LLMEngine:
         targets[:hit // B] = kvcache.TRASH
         self._pool = kvcache.scatter_table(self._pool, acc,
                                            jnp.asarray(targets))
-        return np.asarray(logits)
+        return logits
 
     @staticmethod
-    def _record_prefill_span(r: _Request) -> None:
+    def _record_prefill_span(r: _Request, t0: float, t1: float) -> None:
         """Engine hop, segment 2: the prefill device compute that
-        produced the first token (block_until_ready-bounded, so the
-        span is the DEVICE portion of TTFT, ending now). The same
-        interval feeds the duty-cycle estimator as a device window."""
-        now = time.time()
+        produced the first token (dispatch start to the end of the
+        wait, both stamped by their phases: the DEVICE portion of
+        TTFT). The same interval feeds the duty-cycle estimator as a
+        device window."""
+        w0, w1 = tracing.wall(t0), tracing.wall(t1)
         devmon.record_device_window(
-            "prefill", now - r.prefill_device_s, now,
+            "prefill", w0, w1,
             trace=r.trace.trace_id if r.trace is not None else "")
         if r.trace is None:
             return
         tracing.record_request_span(
-            "engine", "prefill", r.trace, r.trace.span_id,
-            now - r.prefill_device_s, now, tokens=len(r.tokens))
+            "engine", "prefill", r.trace, r.trace.span_id, w0, w1,
+            tokens=len(r.tokens))
 
     def _chunked_prefill(self, tokens: List[int]):
         """Prompts past the largest bucket stream through
@@ -1114,41 +1251,47 @@ class LLMEngine:
                      top_ps: np.ndarray, top_ks: np.ndarray,
                      block: int) -> np.ndarray:
         jax, jnp = _jx()
-        self._step += block
-        key = jax.random.fold_in(self._key, self._step)
-        # The top-p/top-k filters cost two O(V log V) vocab sorts per
-        # decode step: only pay them when some ACTIVE request enabled
-        # a filter (None compiles the plain sampler — one extra jit
-        # variant, bounded).
-        filters_on = bool((top_ps < 1.0).any() or (top_ks > 0).any())
-        tp = jnp.asarray(top_ps) if filters_on else None
-        tk = jnp.asarray(top_ks) if filters_on else None
-        if self._paged:
-            # per-slot write positions are host-derived (prompt +
-            # emitted - 1: the last emitted token's KV lands this
-            # step), matching the monolithic cache's device-side
-            # length counter by construction; empty slots write into
-            # the trash block
-            lengths = np.zeros((self.max_slots,), np.int32)
-            for i, r in enumerate(self._slots):
-                if r is not None:
-                    lengths[i] = len(r.tokens) + len(r.out) - 1
-            out, self._pool = kvcache.paged_decode_steps(
-                self.params, self._pool, jnp.asarray(self._tables),
-                jnp.asarray(lengths), jnp.asarray(tokens),
-                jnp.asarray(temps), key, self.cfg, block, tp, tk,
-                impl=self._kv_impl, interpret=self._kv_interpret,
-                mesh=self.mesh, axis=self.tensor_axis)
-            self._kvm["attn_steps"].inc(
-                block, tags={"impl": self._kv_impl})
-            if self._kv_impl == "paged_flash":
-                self._kvm["gather_avoided"].inc(
-                    block * self._gather_step_bytes)
-            return np.asarray(out)
-        out, self._cache = lm.decode_steps(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(temps), key, self.cfg, block, tp, tk)
-        return np.asarray(out)
+        with self._phase("decode.dispatch") as disp:
+            if self._gap_from is not None:
+                # a request of the last block has been waiting for
+                # this one since that block was read back
+                self._m["gap"].observe(disp.t0 - self._gap_from)
+                self._m["gap_admit"].observe(self._gap_admit)
+            self._step += block
+            key = jax.random.fold_in(self._key, self._step)
+            # The top-p/top-k filters cost two O(V log V) vocab sorts
+            # per decode step: only pay them when some ACTIVE request
+            # enabled a filter (None compiles the plain sampler — one
+            # extra jit variant, bounded).
+            filters_on = bool((top_ps < 1.0).any() or (top_ks > 0).any())
+            tp = jnp.asarray(top_ps) if filters_on else None
+            tk = jnp.asarray(top_ks) if filters_on else None
+            if self._paged:
+                # per-slot write positions are host-derived (prompt +
+                # emitted - 1: the last emitted token's KV lands this
+                # step), matching the monolithic cache's device-side
+                # length counter by construction; empty slots write
+                # into the trash block
+                lengths = np.zeros((self.max_slots,), np.int32)
+                for i, r in enumerate(self._slots):
+                    if r is not None:
+                        lengths[i] = len(r.tokens) + len(r.out) - 1
+                out, self._pool = kvcache.paged_decode_steps(
+                    self.params, self._pool, jnp.asarray(self._tables),
+                    jnp.asarray(lengths), jnp.asarray(tokens),
+                    jnp.asarray(temps), key, self.cfg, block, tp, tk,
+                    impl=self._kv_impl, interpret=self._kv_interpret,
+                    mesh=self.mesh, axis=self.tensor_axis)
+                self._kvm["attn_steps"].inc(
+                    block, tags={"impl": self._kv_impl})
+            else:
+                out, self._cache = lm.decode_steps(
+                    self.params, self._cache, jnp.asarray(tokens),
+                    jnp.asarray(temps), key, self.cfg, block, tp, tk)
+        with self._phase("decode.readback") as back:
+            out = np.asarray(out)
+        self._dev_span = (disp.t0, back.t1)
+        return out
 
     async def _spec_round(self, loop, active: List[int],
                           drafts: dict) -> None:
@@ -1160,81 +1303,68 @@ class LLMEngine:
         accept per slot (exact greedy match / rejection sampling in
         llm/spec.py), roll back the host block accounting for rejected
         tails, and emit 1..k+1 tokens per slot."""
-        w = specdec.bucket_width(
-            self._spec_buckets,
-            1 + max(len(d) for d in drafts.values()))
-        tokens_bw = np.zeros((self.max_slots, w), np.int32)
-        lengths = np.zeros((self.max_slots,), np.int32)
-        for i in active:
-            r = self._slots[i]
-            row = [r.out[-1]] + drafts.get(i, [])
-            row += [row[-1]] * (w - len(row))
-            tokens_bw[i] = row
-            lengths[i] = len(r.tokens) + len(r.out) - 1
-        member_traces = sorted(
-            {self._slots[i].trace.trace_id for i in active
-             if self._slots[i] is not None
-             and self._slots[i].trace is not None})
-        first_ctx = next(
-            (self._slots[i].trace for i in active
-             if self._slots[i] is not None
-             and self._slots[i].trace is not None), None)
-        t_dec = time.monotonic()
-        t_dec_wall = time.time()
+        with self._phase("verify.prepare"):
+            w = specdec.bucket_width(
+                self._spec_buckets,
+                1 + max(len(d) for d in drafts.values()))
+            tokens_bw = np.zeros((self.max_slots, w), np.int32)
+            lengths = np.zeros((self.max_slots,), np.int32)
+            for i in active:
+                r = self._slots[i]
+                row = [r.out[-1]] + drafts.get(i, [])
+                row += [row[-1]] * (w - len(row))
+                tokens_bw[i] = row
+                lengths[i] = len(r.tokens) + len(r.out) - 1
+            member_traces, first_ctx = self._members(active)
         logits = await loop.run_in_executor(
             None, self._verify_sync, tokens_bw, lengths, first_ctx)
-        emitted_total = 0
-        for i in active:
-            r = self._slots[i]
-            if r is None:
-                continue
-            d = drafts.get(i, [])
-            emitted, n_acc = specdec.accept_tokens(
-                logits[i, :len(d) + 1], d,
-                temperature=r.temperature, top_k=r.top_k,
-                top_p=r.top_p, rng=self._rng)
-            if d:
-                r.drafter.record(len(d), n_acc)
-                r.spec_drafted += len(d)
-                r.spec_accepted += n_acc
-                self._specm["tokens"].inc(len(d),
-                                          tags={"kind": "drafted"})
-                if n_acc:
-                    self._specm["tokens"].inc(
-                        n_acc, tags={"kind": "accepted"})
-                if len(d) > n_acc:
-                    self._specm["tokens"].inc(
-                        len(d) - n_acc, tags={"kind": "rejected"})
-                    # host-side rollback of the rejected tail. Under
-                    # the engine's full-horizon reservation this frees
-                    # no blocks (min_blocks pins the reservation —
-                    # giving promised blocks back could deadlock a
-                    # re-acquire against a newer admit); it keeps the
-                    # sequence's hash chain honest and IS the real
-                    # rollback for COW forks (tests pin both).
-                    self._kv.truncate_seq(
-                        r.seq,
-                        len(r.tokens) + len(r.out) + len(emitted),
-                        min_blocks=self._kv.blocks_needed(
-                            len(r.tokens), r.max_new_tokens))
-            emitted_total += len(emitted)
-            for t in emitted:
-                if self._slots[i] is not r:
-                    break   # finished mid-accept (eos/stop/max_new):
-                            # the tail of an accepted draft is dropped
-                self._emit_token(r, int(t), i)
-        ex = first_ctx.trace_id if first_ctx is not None else None
-        self._m["batch"].observe(len(active), exemplar=ex)
-        per_slot = max(1.0, emitted_total / max(1, len(active)))
-        self._m["tpot"].observe(
-            (time.monotonic() - t_dec) / per_slot, exemplar=ex)
-        tracing.record_batch_span(
-            "engine", "decode", member_traces,
-            t_dec_wall, time.time(), block=emitted_total,
-            slots=len(active), kv_impl=self._kv_impl,
-            gather_bytes_avoided=0, spec_k=w - 1)
-        devmon.record_device_window(
-            "decode", t_dec_wall, time.time(), trace=ex or "")
+        self._gap_from = None       # llm_decode_gap_s is the plain
+        emitted_total = 0           # block path's, not a verify round's
+        with self._phase("verify.accept"):
+            for i in active:
+                r = self._slots[i]
+                if r is None:
+                    continue
+                d = drafts.get(i, [])
+                emitted, n_acc = specdec.accept_tokens(
+                    logits[i, :len(d) + 1], d,
+                    temperature=r.temperature, top_k=r.top_k,
+                    top_p=r.top_p, rng=self._rng)
+                if d:
+                    r.drafter.record(len(d), n_acc)
+                    r.spec_drafted += len(d)
+                    r.spec_accepted += n_acc
+                    self._specm["tokens"].inc(len(d),
+                                              tags={"kind": "drafted"})
+                    if n_acc:
+                        self._specm["tokens"].inc(
+                            n_acc, tags={"kind": "accepted"})
+                    if len(d) > n_acc:
+                        self._specm["tokens"].inc(
+                            len(d) - n_acc, tags={"kind": "rejected"})
+                        # host-side rollback of the rejected tail.
+                        # Under the engine's full-horizon reservation
+                        # this frees no blocks (min_blocks pins the
+                        # reservation — giving promised blocks back
+                        # could deadlock a re-acquire against a newer
+                        # admit); it keeps the sequence's hash chain
+                        # honest and IS the real rollback for COW
+                        # forks (tests pin both).
+                        self._kv.truncate_seq(
+                            r.seq,
+                            len(r.tokens) + len(r.out) + len(emitted),
+                            min_blocks=self._kv.blocks_needed(
+                                len(r.tokens), r.max_new_tokens))
+                emitted_total += len(emitted)
+                for t in emitted:
+                    if self._slots[i] is not r:
+                        break   # finished mid-accept (eos/stop/
+                                # max_new): the tail of an accepted
+                                # draft is dropped
+                    self._emit_token(r, int(t), i)
+        self._record_block(
+            len(active), max(1.0, emitted_total / max(1, len(active))),
+            member_traces, first_ctx, block=emitted_total, spec_k=w - 1)
 
     def _verify_sync(self, tokens_bw: np.ndarray, lengths: np.ndarray,
                      trace_ctx: Optional[tracing.TraceContext] = None
@@ -1252,14 +1382,18 @@ class LLMEngine:
 
     def _verify_impl(self, tokens_bw: np.ndarray,
                      lengths: np.ndarray) -> np.ndarray:
-        jax, jnp = _jx()
-        logits, self._pool = kvcache.paged_verify_steps(
-            self.params, self._pool, jnp.asarray(self._tables),
-            jnp.asarray(lengths), jnp.asarray(tokens_bw), self.cfg,
-            impl=self._kv_impl, interpret=self._kv_interpret,
-            mesh=self.mesh, axis=self.tensor_axis)
-        self._kvm["attn_steps"].inc(1, tags={"impl": self._kv_impl})
-        return np.asarray(logits)
+        _, jnp = _jx()
+        with self._phase("verify.dispatch") as disp:
+            logits, self._pool = kvcache.paged_verify_steps(
+                self.params, self._pool, jnp.asarray(self._tables),
+                jnp.asarray(lengths), jnp.asarray(tokens_bw), self.cfg,
+                impl=self._kv_impl, interpret=self._kv_interpret,
+                mesh=self.mesh, axis=self.tensor_axis)
+            self._kvm["attn_steps"].inc(1, tags={"impl": self._kv_impl})
+        with self._phase("verify.readback") as back:
+            logits = np.asarray(logits)
+        self._dev_span = (disp.t0, back.t1)
+        return logits
 
     def _sample_one(self, logits: np.ndarray, r: _Request) -> int:
         """Host-side sampling for the FIRST token (prefill output is a
@@ -1292,7 +1426,7 @@ class LLMEngine:
         r.out.append(tok)
         self._tokens_generated += 1
         if r.stream is not None:
-            r.stream.put_nowait(tok)
+            r.stream.put_nowait((tok, time.monotonic()))
         if r.stop:
             for seq in r.stop:
                 if len(r.out) >= len(seq) and r.out[-len(seq):] == seq:

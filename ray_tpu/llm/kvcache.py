@@ -66,9 +66,6 @@ def kvcache_metrics() -> dict:
                                   block granularity (llm/pd.py)
       llm_paged_attn_steps_total  paged decode steps by attention impl
                                   ({impl}: paged_flash | gather)
-      llm_kv_gather_bytes_avoided_total
-                                  HBM bytes the fused kernel did NOT
-                                  copy materializing the gathered view
     """
     from ray_tpu.util import metrics as m
     return {
@@ -97,11 +94,6 @@ def kvcache_metrics() -> dict:
             "(paged_flash = fused block-table kernel, gather = "
             "materialized view)",
             tag_keys=("impl",)),
-        "gather_avoided": m.Counter(
-            "llm_kv_gather_bytes_avoided_total",
-            "HBM bytes the fused paged-attention kernel avoided "
-            "copying versus materializing the gathered "
-            "(slots, max_len) attention view every decode step"),
     }
 
 

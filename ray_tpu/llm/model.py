@@ -111,6 +111,12 @@ def shard_params_for_serving(params: dict, mesh: Mesh, cfg: LlamaConfig,
         params, specs, is_leaf=lambda x: isinstance(x, P))
 
 
+def _scan_layers(layer, x, xs):
+    """``lax.scan`` of a layer body over the stacked layers, under a
+    "layer" scope: a stable name in a device trace, metadata only."""
+    return lax.scan(jax.named_scope("layer")(layer), x, xs)
+
+
 def _qkv(y, lp, cfg: LlamaConfig):
     b, s = y.shape[:2]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -191,7 +197,7 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
                  @ lp["w_down"])
         return x, (k[0], v[0])
 
-    x, (ks, vs) = lax.scan(layer, x, params["layers"])
+    x, (ks, vs) = _scan_layers(layer, x, params["layers"])
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take(x[0], length - 1, axis=0)
     logits = (last @ params["lm_head"]).astype(jnp.float32)
@@ -274,8 +280,8 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
                  @ lp["w_down"])
         return x, (ak, av)
 
-    x, (nk, nv) = lax.scan(layer, x, (params["layers"],
-                                      acc["k"], acc["v"]))
+    x, (nk, nv) = _scan_layers(
+        layer, x, (params["layers"], acc["k"], acc["v"]))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take(x[0], length - 1, axis=0)
     logits = (last @ params["lm_head"]).astype(jnp.float32)
@@ -325,8 +331,8 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
                  @ lp["w_down"])
         return x, (ak, av)
 
-    x, (nk, nv) = lax.scan(layer, x, (params["layers"],
-                                      acc["k"], acc["v"]))
+    x, (nk, nv) = _scan_layers(
+        layer, x, (params["layers"], acc["k"], acc["v"]))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.take(x[0], length - 1, axis=0)
     logits = (last @ params["lm_head"]).astype(jnp.float32)
@@ -454,8 +460,8 @@ def decode_logits_core(params: dict, kcache: jax.Array,
                  @ lp["w_down"])
         return x, (ck, cv)
 
-    x, (nk, nv) = lax.scan(layer, x, (params["layers"],
-                                      kcache, vcache))
+    x, (nk, nv) = _scan_layers(
+        layer, x, (params["layers"], kcache, vcache))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, nk, nv
@@ -528,8 +534,8 @@ def verify_tokens_core(params: dict, kcache: jax.Array,
                  @ lp["w_down"])
         return x, (ck, cv)
 
-    x, (nk, nv) = lax.scan(layer, x, (params["layers"],
-                                      kcache, vcache))
+    x, (nk, nv) = _scan_layers(
+        layer, x, (params["layers"], kcache, vcache))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
     return logits, nk, nv

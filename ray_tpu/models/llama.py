@@ -299,23 +299,24 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     rope_cos, rope_sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
+    # the scopes only name the ops in a device trace (metadata)
     def layer(x, lp):
-        # attention block
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (y @ lp["wq"]).reshape(b, s, h, hd)
-        k = (y @ lp["wk"]).reshape(b, s, kvh, hd)
-        v = (y @ lp["wv"]).reshape(b, s, kvh, hd)
-        q = _rope(q, rope_cos, rope_sin)
-        k = _rope(k, rope_cos, rope_sin)
-        o = _attend(q, k, v, cfg, mesh, axes).astype(x.dtype)
-        x = x + (o.reshape(b, s, h * hd) @ lp["wo"])
-        x = act_constraint(x, P(axes.batch, axes.context, None))
-        # mlp block
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(y @ lp["w_gate"])
-        up = y @ lp["w_up"]
-        x = x + ((gate * up) @ lp["w_down"])
-        x = act_constraint(x, P(axes.batch, axes.context, None))
+        with jax.named_scope("attention"):
+            y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            q = (y @ lp["wq"]).reshape(b, s, h, hd)
+            k = (y @ lp["wk"]).reshape(b, s, kvh, hd)
+            v = (y @ lp["wv"]).reshape(b, s, kvh, hd)
+            q = _rope(q, rope_cos, rope_sin)
+            k = _rope(k, rope_cos, rope_sin)
+            o = _attend(q, k, v, cfg, mesh, axes).astype(x.dtype)
+            x = x + (o.reshape(b, s, h * hd) @ lp["wo"])
+            x = act_constraint(x, P(axes.batch, axes.context, None))
+        with jax.named_scope("mlp"):
+            y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(y @ lp["w_gate"])
+            up = y @ lp["w_up"]
+            x = x + ((gate * up) @ lp["w_down"])
+            x = act_constraint(x, P(axes.batch, axes.context, None))
         return x, None
 
     step = _remat(layer, cfg)
@@ -406,8 +407,10 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig,
                 f"ce_chunk={cfg.ce_chunk} must divide seq len {s}")
         if s > cfg.ce_chunk:
             x = forward_hidden(params, batch["tokens"], cfg, mesh, axes)
-            return fused_cross_entropy(x, params["lm_head"], batch,
-                                       cfg.ce_chunk, cfg.logits_dtype)
+            with jax.named_scope("loss"):
+                return fused_cross_entropy(x, params["lm_head"], batch,
+                                           cfg.ce_chunk, cfg.logits_dtype)
         # s == ce_chunk: one chunk IS the full logits — classic path
     logits = forward(params, batch["tokens"], cfg, mesh, axes)
-    return cross_entropy(logits, batch)
+    with jax.named_scope("loss"):
+        return cross_entropy(logits, batch)

@@ -187,6 +187,7 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     if with_lse:
         out, lse = res
@@ -345,6 +346,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, sm_scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lse, delta)
 
     dq = pl.pallas_call(
@@ -366,6 +368,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, sm_scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lse, delta)
 
     dq = (dq[:, :sq].astype(jnp.float32) * sm_scale).astype(q.dtype)

@@ -161,6 +161,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool,
       v_pool)
 

@@ -132,9 +132,11 @@ def make_train_step(cfg, mesh: Mesh,
         batch = {k: jax.lax.with_sharding_constraint(v, batch_spec)
                  for k, v in batch.items()}
         loss, grads = jax.value_and_grad(_loss)(state.params, batch)
-        updates, opt_state = opt.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):   # a name in the device trace
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.params)
+            params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         return (TrainState(params, opt_state, state.step + 1),
                 {"loss": loss, "grad_norm": gnorm, "step": state.step + 1})
 

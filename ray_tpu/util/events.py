@@ -64,10 +64,13 @@ from typing import Deque, Dict, List
 #   forensics   hang/desync diagnoses (util/forensics.py): typed
 #               collective_stall / collective_desync instants naming
 #               the culprit rank, plus autopsy/bundle markers
+#   engine      serving-engine incidents (llm/engine.py): one
+#               "slow_phase" instant when a phase of the scheduler
+#               loop lasts over a second, naming the phase
 CATEGORIES = ("trace", "collective", "train", "worker", "cgroup",
               "memory", "request", "device", "device_window",
               "pipeline", "health", "ckpt", "serve", "goodput",
-              "forensics")
+              "forensics", "engine")
 
 _DEFAULT_CAP = 65536
 # Dedicated sub-budgets: the key also names the bucket. Everything
@@ -111,7 +114,11 @@ _CATEGORY_CAPS: Dict[str, int] = {"collective": 16384, "train": 4096,
                                   # instants are rare, but a watchdog
                                   # firing every poll during a long
                                   # hang must age against itself
-                                  "forensics": 2048}
+                                  "forensics": 2048,
+                                  # a slow phase is rare by rule (over
+                                  # a second each), but a wedged device
+                                  # trips it every block
+                                  "engine": 1024}
 
 _BUFS: Dict[str, Deque[dict]] = {}
 _LOCK = threading.Lock()

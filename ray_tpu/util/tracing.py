@@ -44,6 +44,7 @@ import contextvars
 import json
 import os
 import re
+import sys
 import time
 from typing import List, Optional
 
@@ -223,6 +224,68 @@ def record_batch_span(component: str, seg: str, links: List[str],
     events.record("request", "batch", span=new_span_id(), links=links,
                   component=component, seg=seg, ts=t0, dur=t1 - t0,
                   pid=os.getpid(), **attrs)
+
+
+# --- phases: one stamp, three sinks ------------------------------------
+
+# The event buffers are wall-clock (cross-node alignment); a phase
+# stamps the monotonic clock, which the histograms use. ONE offset,
+# taken at import, converts a stamp for a record_*_span / device-window
+# record, so both ends of a record come from the same two clock reads.
+_WALL_OFFSET = time.time() - time.monotonic()
+
+
+def wall(t_mono: float) -> float:
+    """A ``time.monotonic()`` stamp on the event buffers' wall clock."""
+    return t_mono + _WALL_OFFSET
+
+
+class phase:
+    """``with tracing.phase(name, hist) as ph:`` — one leaf span of a
+    host loop, stamped ONCE (``time.monotonic()`` on entry and on exit)
+    and fed to three sinks:
+
+    - a ``jax.profiler.TraceAnnotation(name)`` over the same interval,
+      so the span lands in the profiler's host plane on the profiler's
+      clock, next to the device's. Only where jax is ALREADY imported
+      (workers that never touch a backend must not import it); with no
+      profiler session it is a TraceMe that checks one atomic;
+    - ``hist.observe(duration)`` if a histogram is given;
+    - ``ph.t0`` / ``ph.t1`` / ``ph.dur`` for the caller's own records
+      (``wall()`` converts a stamp for the wall-clock event buffers).
+
+    Phases are meant FLAT: the benchmark's trace reduction names an
+    idle gap after the ONE annotation overlapping it most, so an
+    enclosing span would win every gap and say nothing."""
+
+    __slots__ = ("name", "hist", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, hist=None):
+        self.name = name
+        self.hist = hist
+        self.t0 = self.t1 = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "phase":
+        ann = getattr(sys.modules.get("jax.profiler"),
+                      "TraceAnnotation", None)
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.hist is not None:
+            self.hist.observe(self.t1 - self.t0)
+        return False
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
 
 
 def sample_keep(trace_id: str, *, error: bool = False,

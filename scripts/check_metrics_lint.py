@@ -7,7 +7,8 @@ after importing every instrumented module):
   2. every metric carries a unit suffix — ``_s`` (seconds), ``_total``
      (monotonic count), ``_bytes`` — EXCEPT unitless gauges (a level,
      e.g. ``queue_depth``) and dimensionless count *distributions*
-     ending in ``_size`` (e.g. ``llm_batch_size``);
+     ending in ``_size``, ``_steps`` or ``_tokens`` (e.g.
+     ``llm_batch_size``, ``llm_decode_block_steps``);
   3. no duplicate names, including case-insensitive collisions (the
      registry keys by exact name, so ``Foo``/``foo`` could otherwise
      coexist and split a series);
@@ -39,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 UNIT_SUFFIXES = ("_s", "_total", "_bytes")
-COUNT_SUFFIXES = ("_size",)
+COUNT_SUFFIXES = ("_size", "_steps", "_tokens")
 
 
 def lint(registry: dict) -> list:

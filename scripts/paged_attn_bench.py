@@ -102,13 +102,7 @@ def main():
     auto, auto_toks = _decode_row(cfg, params, "auto", prompts,
                                   args.max_new, args.runs, **long_kw)
     assert auto_toks == base_toks, "auto impl moved tokens"
-    # the per-step HBM copy the kernel removes: every decode step the
-    # gather path materializes slots x table_width blocks
-    eng = _engine(cfg, params, kv_impl="gather", **long_kw)
-    avoided = eng._gather_step_bytes
-    asyncio.run(eng.stop())
     decode = {"gather": base, "auto": auto,
-              "gather_bytes_per_step": int(avoided),
               "prompt_tokens": args.long_prompt,
               "max_new": args.max_new, "slots": len(prompts)}
     print(f"# decode: {json.dumps(decode)}", file=sys.stderr)

@@ -384,14 +384,14 @@ def test_engine_kv_accounting_exemplars_and_duty_windows(tiny_model):
         eng = LLMEngine(cfg, params, max_slots=2, max_len=64,
                         prefill_buckets=(8,), cache_dtype="float32",
                         steps_per_sync=4)
-        # KV gauges live from construction; headroom reflects growth
-        # left to max_len
+        # KV gauges live from construction. The paged default prices
+        # LIVE blocks: none yet, and the whole pool is headroom
+        from ray_tpu.llm import kvcache
         kv0 = eng._m["kv_bytes"]._values[()]
         hr0 = eng._m["kv_headroom"]._values[()]
-        assert kv0 > 0
-        per_tok = eng._kv_per_token_bytes()
-        assert abs(hr0 - per_tok * eng.max_slots
-                   * (eng.max_len - eng._cache_len)) < 1.0
+        assert kv0 == 0
+        assert hr0 == kvcache.pool_block_bytes(eng._pool) \
+            * eng._kv.free_blocks() > 0
         tok = tracing.set_request_context(
             tracing.TraceContext(tid, tracing.new_span_id()))
         try:
@@ -402,6 +402,8 @@ def test_engine_kv_accounting_exemplars_and_duty_windows(tiny_model):
         return eng
 
     eng = asyncio.run(go())
+    # the finished request's full blocks stay, as prefix cache
+    assert eng._m["kv_bytes"]._values[()] > 0
     # request HBM high-watermark on the terminal engine span
     gen = [e for e in events.dump() if e.get("cat") == "request"
            and e.get("trace") == tid and e.get("seg") == "generate"]

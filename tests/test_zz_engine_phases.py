@@ -1,0 +1,292 @@
+"""The serving engine's loop on the profiler's clock: the one span
+primitive (util/tracing.phase), the flat engine.* leaf spans, and the
+work the loop counts where it is done (llm_decode_*, llm_prefill_tokens,
+llm_decode_gap_*). Counts are exact on the CPU; times are not speed
+results."""
+
+import asyncio
+import glob
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+from ray_tpu.util import events, metrics as M, tracing
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    import jax
+
+    from ray_tpu.models import llama
+    cfg = llama.tiny(vocab_size=64, dim=32, n_layers=2, n_heads=2,
+                     n_kv_heads=2, ffn_dim=64, dtype="float32",
+                     logits_dtype="float32", attn_impl="reference")
+    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _engine(tiny_model, **kw):
+    from ray_tpu.llm import LLMEngine
+    cfg, params = tiny_model
+    kw = {"max_slots": 2, "max_len": 64, "prefill_buckets": (8, 16),
+          "cache_dtype": "float32", "steps_per_sync": 4,
+          "kv_block_size": 8, **kw}
+    return LLMEngine(cfg, params, **kw)
+
+
+def _totals() -> dict:
+    """{key_sum, key_count} of every engine histogram, the way the
+    benchmark's server reads them, plus the paged step counter."""
+    from ray_tpu.llm.engine import engine_metrics
+    out = {}
+    for key, h in engine_metrics().items():
+        if hasattr(h, "boundaries"):
+            out[key + "_sum"] = sum(h._sums.values())
+            out[key + "_count"] = sum(sum(c) for c in h._counts.values())
+    steps = M._REGISTRY.get("llm_paged_attn_steps_total")
+    out["attn_steps"] = sum(steps._values.values()) if steps else 0
+    return out
+
+
+def _delta(a: dict, b: dict) -> dict:
+    return {k: b[k] - a[k] for k in b}
+
+
+# --- the primitive -----------------------------------------------------
+
+
+def test_phase_feeds_histogram_and_records_from_one_stamp():
+    h = M.Histogram("test_phase_s", "a phase", boundaries=(1, 2))
+    n0, s0 = sum(sum(c) for c in h._counts.values()), \
+        sum(h._sums.values())
+    before = time.time()
+    with tracing.phase("engine.test", h) as ph:
+        time.sleep(0.01)
+    after = time.time()
+    assert ph.t1 - ph.t0 == ph.dur >= 0.01
+    # the histogram saw exactly the stamped interval
+    assert sum(sum(c) for c in h._counts.values()) == n0 + 1
+    assert sum(h._sums.values()) - s0 == pytest.approx(ph.dur, abs=1e-12)
+    # ... and a wall-clock record made from the same stamps has the
+    # same duration and sits where the wall clock says the phase ran
+    w0, w1 = tracing.wall(ph.t0), tracing.wall(ph.t1)
+    assert w1 - w0 == pytest.approx(ph.dur, abs=1e-6)
+    assert before - 0.05 <= w0 <= w1 <= after + 0.05
+    with tracing.phase("engine.bare") as bare:     # no histogram: fine
+        pass
+    assert bare.dur >= 0
+
+
+def test_phase_passes_exceptions_and_still_observes():
+    h = M.Histogram("test_phase_exc_s", "a phase", boundaries=(1,))
+    n0 = sum(sum(c) for c in h._counts.values())
+    with pytest.raises(KeyError):
+        with tracing.phase("engine.test", h):
+            raise KeyError("x")
+    assert sum(sum(c) for c in h._counts.values()) == n0 + 1
+
+
+def test_phase_does_not_import_jax():
+    code = ("import sys\n"
+            "from ray_tpu.util import tracing\n"
+            "with tracing.phase('engine.x') as ph:\n"
+            "    pass\n"
+            "assert ph.dur >= 0 and ph._ann is None\n"
+            "assert 'jax' not in sys.modules, 'phase imported jax'\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   cwd=os.path.join(os.path.dirname(__file__), os.pardir))
+
+
+# --- work counted where it is done ---------------------------------------
+
+
+def test_decode_counts_match_their_closed_forms(tiny_model):
+    """Two known prompts, nothing stops early: every count is exact."""
+    prompts = [[3, 5, 7, 11, 13], [2, 4, 6, 8, 10, 12, 14, 16, 18]]
+    new = 11
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False)
+        before = _totals()
+        outs = await asyncio.gather(*[
+            eng.generate(p, max_new_tokens=new) for p in prompts])
+        await eng.stop()
+        return outs, _delta(before, _totals())
+
+    outs, d = asyncio.run(go())
+    assert [len(o["tokens"]) for o in outs] == [new, new]
+    generated = sum(len(o["tokens"]) for o in outs)
+    # a block's steps are the paged path's steps, one for one
+    assert d["block_steps_sum"] == d["attn_steps"] > 0
+    assert d["block_steps_count"] == d["batch_count"]
+    assert d["block_steps_sum"] / d["block_steps_count"] <= 4
+    # every token but a request's first is one step of one slot
+    assert d["slot_steps_sum"] == generated - len(prompts)
+    # output token i >= 1 of a P-token prompt attends P + i positions
+    assert d["ctx_tokens_sum"] == sum(
+        len(p) + i for p in prompts for i in range(1, new))
+    # both prompts went through a prefill forward, whole
+    assert d["prefill_tokens_sum"] == sum(len(p) for p in prompts)
+    assert d["prefill_tokens_count"] == len(prompts)
+    # the gap between blocks holds the admissions made in it
+    assert d["gap_count"] > 0
+    assert 0 <= d["gap_admit_sum"] <= d["gap_sum"]
+    assert d["gap_admit_count"] == d["gap_count"]
+
+
+def test_prefill_tokens_exclude_prefix_hits(tiny_model):
+    prompt = list(range(1, 21))     # 20 tokens: two full blocks of 8
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=True)
+        before = _totals()
+        cold = await eng.generate(prompt, max_new_tokens=4)
+        mid = _totals()
+        warm = await eng.generate(prompt, max_new_tokens=4)
+        await eng.stop()
+        return cold, warm, _delta(before, mid), _delta(mid, _totals())
+
+    cold, warm, d_cold, d_warm = asyncio.run(go())
+    assert cold["prefix_hit_tokens"] == 0
+    hit = warm["prefix_hit_tokens"]
+    assert hit == 16 and warm["tokens"] == cold["tokens"]
+    assert d_cold["prefill_tokens_sum"] == len(prompt)
+    assert d_warm["prefill_tokens_sum"] == len(prompt) - hit
+
+
+def test_every_loop_phase_is_observed_and_stream_lag_counts_tokens(
+        tiny_model):
+    from ray_tpu.llm.engine import PHASES
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False)
+        before = _totals()
+        t0 = time.monotonic()
+        got = [t async for t in eng.generate_stream(
+            [5, 6, 7], max_new_tokens=9)]
+        await asyncio.sleep(0.05)       # the loop parks: engine.idle
+        await eng.stop()
+        return got, _delta(before, _totals()), time.monotonic() - t0
+
+    got, d, wall = asyncio.run(go())
+    assert len(got) == 9
+    assert d["stream_lag_count"] == 9 and d["stream_lag_sum"] >= 0
+    for p in PHASES:
+        key = "loop_" + p.replace(".", "_")
+        if p.startswith("verify."):         # no speculative decoding here
+            assert d[key + "_count"] == 0
+        else:
+            assert d[key + "_count"] > 0, p
+    # leaf spans do not overlap, so together they fit in the wall time
+    spent = sum(v for k, v in d.items()
+                if k.startswith("loop_") and k.endswith("_sum"))
+    assert 0 < spent <= wall
+
+
+def test_spec_round_uses_the_verify_phases(tiny_model):
+    tid = "5e" * 16
+
+    async def go():
+        eng = _engine(tiny_model, spec=True, prefix_cache=False)
+        before = _totals()
+        tok = tracing.set_request_context(
+            tracing.TraceContext(tid, tracing.new_span_id()))
+        try:
+            # a repeating prompt, so the prompt-lookup drafter proposes
+            await eng.generate([1, 2, 3, 1, 2, 3, 1, 2, 3, 1, 2],
+                               max_new_tokens=12)
+        finally:
+            tracing.reset_request_context(tok)
+        await eng.stop()
+        return _delta(before, _totals())
+
+    events.clear()
+    d = asyncio.run(go())
+    rounds = d["loop_verify_dispatch_count"]
+    assert rounds > 0
+    assert d["loop_verify_readback_count"] == rounds \
+        == d["loop_verify_accept_count"]
+    assert d["loop_verify_prepare_count"] >= rounds
+    # each round left its linked span and its device window, from the
+    # round's own dispatch-to-read-back interval
+    spans = [e for e in events.dump()
+             if e.get("name") == "batch" and "spec_k" in e]
+    assert len(spans) == rounds and all(tid in e["links"] for e in spans)
+    wins = [e for e in events.dump() if e.get("cat") == "device_window"
+            and e.get("seg") == "decode"]
+    assert {(e["ts"], e["dur"]) for e in spans} \
+        <= {(e["ts"], e["dur"]) for e in wins}
+    events.clear()
+
+
+# --- the spans in the profiler's trace -----------------------------------
+
+
+def test_engine_spans_land_in_the_profiler_trace_and_do_not_nest(
+        tiny_model, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False)
+        await eng.generate([9, 8, 7], max_new_tokens=6)     # compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            await asyncio.gather(*[
+                eng.generate([3 + i, 5, 7, 9], max_new_tokens=10)
+                for i in range(3)])
+        finally:
+            jax.profiler.stop_trace()
+        await eng.stop()
+
+    asyncio.run(go())
+    files = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files
+    pd = ProfileData.from_file(files[0])
+    names, nested = set(), []
+    for plane in pd.planes:
+        for line in plane.lines:
+            spans = sorted(
+                (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                for ev in line.events if ev.name.startswith("engine."))
+            names.update(n for _, _, n in spans)
+            nested += [(a, b) for a, b in zip(spans, spans[1:])
+                       if b[0] < a[1]]
+    assert {"engine.decode.prepare", "engine.decode.dispatch",
+            "engine.decode.readback", "engine.prefill.dispatch",
+            "engine.prefill.wait", "engine.prefill.sample",
+            "engine.emit", "engine.yield"} <= names, names
+    assert not nested, nested[:3]
+
+
+# --- the record of a slow phase --------------------------------------------
+
+
+def test_a_phase_over_a_second_leaves_one_slow_phase_event(
+        tiny_model, monkeypatch):
+    from ray_tpu.llm import LLMEngine
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False)
+        await eng.generate([4, 5, 6], max_new_tokens=6)     # compile
+        events.clear()
+        slow = LLMEngine._sample_one
+
+        def sample_slowly(self, logits, r):
+            time.sleep(1.05)
+            return slow(self, logits, r)
+        monkeypatch.setattr(LLMEngine, "_sample_one", sample_slowly)
+        await eng.generate([6, 5, 4], max_new_tokens=6)
+        await eng.stop()
+
+    asyncio.run(go())
+    slow = [e for e in events.dump()
+            if e.get("cat") == "engine" and e.get("name") == "slow_phase"]
+    assert len(slow) == 1, slow
+    assert slow[0]["phase"] == "prefill.sample"
+    assert 1.05 <= slow[0]["dur"] < 5
+    assert slow[0]["active"] == 1 and slow[0]["waiting"] == 0
+    events.clear()

@@ -239,8 +239,9 @@ def test_engine_kernel_impl_matches_gather_impl(tiny_model):
     """A/B the two decode attention impls through the full engine:
     same prompts, same greedy tokens — the fused kernel replaces the
     gathered view without moving a single sampled token. Also pins the
-    new per-impl metrics: llm_paged_attn_steps_total tags the steps,
-    llm_kv_gather_bytes_avoided_total counts only for the kernel."""
+    per-impl metric (llm_paged_attn_steps_total tags the steps) and
+    the positions the engine counts as attended (llm_decode_ctx_tokens:
+    output token i >= 1 of a P-token prompt attends P + i)."""
     from ray_tpu.util import metrics as M
     cfg, params = tiny_model
     prompts = [_prompt(100 + i, 5 + 3 * i) for i in range(3)]
@@ -257,17 +258,16 @@ def test_engine_kernel_impl_matches_gather_impl(tiny_model):
 
     gather = asyncio.run(gen("gather"))
     reg = M._REGISTRY
-    avoided0 = sum(
-        reg["llm_kv_gather_bytes_avoided_total"]._values.values())
+    ctx0 = sum(reg["llm_decode_ctx_tokens"]._sums.values())
     flash = asyncio.run(gen("paged_flash"))
     assert flash == gather
     steps = reg["llm_paged_attn_steps_total"]._values
     assert any("paged_flash" in str(k) and v > 0
                for k, v in steps.items())
     assert any("gather" in str(k) and v > 0 for k, v in steps.items())
-    avoided1 = sum(
-        reg["llm_kv_gather_bytes_avoided_total"]._values.values())
-    assert avoided1 > avoided0        # kernel runs count avoided bytes
+    ctx1 = sum(reg["llm_decode_ctx_tokens"]._sums.values())
+    assert ctx1 - ctx0 == sum(
+        len(p) + i for p in prompts for i in range(1, 8))
 
 
 # --- tensor-parallel paged engines ------------------------------------
