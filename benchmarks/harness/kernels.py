@@ -109,19 +109,3 @@ def paged_decode_bytes(ctx_tokens: int, slot_steps: int, kv_heads: int,
 def paged_decode_flops(ctx_tokens: int, kv_heads: int, group: int,
                        head_dim: int) -> int:
     return 4 * head_dim * kv_heads * group * ctx_tokens
-
-
-def train_required_flops_per_token(model: dict, n_layers: int,
-                                   seq: int) -> float:
-    """Forward + backward operations one trained token requires: 6 per
-    matmul parameter (the embedding lookup is a gather, the head is a
-    matmul) plus causal attention, with no recomputation."""
-    d, f = model["hidden_size"], model["intermediate_size"]
-    h, kvh = model["num_attention_heads"], model["num_key_value_heads"]
-    hd = d // h
-    per_layer = d * h * hd + 2 * d * kvh * hd + h * hd * d + 3 * d * f
-    matmul = n_layers * per_layer + d * model["vocab_size"]
-    pairs_per_token = (seq + 1) / 2
-    attn = (flash_fwd_flops(1, h, hd) + flash_bwd_flops(1, h, hd)) \
-        * pairs_per_token * n_layers
-    return 6.0 * matmul + attn

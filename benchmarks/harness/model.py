@@ -1,5 +1,7 @@
-"""From a configuration file (the model's published ``config.json`` keys
-plus this benchmark's ``deployment``) to the program's config object."""
+"""A configuration file (the model's published ``config.json`` keys
+plus this benchmark's ``deployment``) and a traffic file as they are
+run, and the compile cache. What turns the keys into the program's
+config object is the configuration's family (``families/*.py``)."""
 
 from __future__ import annotations
 
@@ -19,26 +21,6 @@ def resolved(model: dict) -> dict:
     out["deployment"] = {**model["deployment"],
                          **over.get("deployment", {})}
     return out
-
-
-def llama_config(model: dict, **overrides):
-    from ray_tpu.models.llama import LlamaConfig
-    heads = model["num_attention_heads"]
-    if model.get("head_dim", model["hidden_size"] // heads) * heads \
-            != model["hidden_size"]:
-        raise ValueError("the program derives head_dim as hidden_size / "
-                         "heads; this configuration needs another")
-    return LlamaConfig(
-        vocab_size=model["vocab_size"], dim=model["hidden_size"],
-        n_layers=model["num_hidden_layers"], n_heads=heads,
-        n_kv_heads=model["num_key_value_heads"],
-        ffn_dim=model["intermediate_size"],
-        max_seq_len=model["max_position_embeddings"],
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        dtype={"bfloat16": "bfloat16",
-               "float32": "float32"}[model["torch_dtype"]],
-        **overrides)
 
 
 def traffic(cell: dict) -> dict:

@@ -1,97 +1,8 @@
-"""Plain references, independent of the code under test: the decoder's
-forward pass and loss in straightforward float32 ``jax.numpy`` at
-``highest`` matmul precision - no kernels, no cache, no batching tricks
-- and the comparisons that decide ``correct``.
-
-The architecture (Mistral-7B-v0.3 and Yi-1.5 share it): token embedding;
-per layer  x += Wo.attn(rope(Wq.n1(x)), rope(Wk.n1(x)), Wv.n1(x)),
-x += Wdown.(silu(Wgate.n2(x)) * Wup.n2(x)) with RMSNorm n1, n2, grouped
-query heads, causal softmax attention scaled by 1/sqrt(head_dim),
-rotary embedding over (first half, second half) pairs with base
-``rope_theta``; final RMSNorm; untied output head. Departure from the
-published description: none (Mistral-7B-v0.3 has no sliding window).
-Weights are upcast one layer at a time so the float32 copy never
-exceeds one layer.
+"""The comparisons that decide ``correct``, the same for every family.
+The plain references themselves are the families' (``families/*.py``).
 """
 
 from __future__ import annotations
-
-import functools
-
-
-def _f32_forward(params, tokens, *, n_heads, n_kv_heads, head_dim,
-                 rope_theta, eps):
-    """tokens (b, s) int32 -> logits (b, s, vocab) float32."""
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-    b, s = tokens.shape
-    g = n_heads // n_kv_heads
-
-    def rms(x, w):
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                 + eps) * w.astype(f32)
-
-    half = head_dim // 2
-    freqs = rope_theta ** (-jnp.arange(half, dtype=f32) / half)
-    ang = jnp.arange(s, dtype=f32)[:, None] * freqs[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-
-    def rope(x):
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               -1)
-
-    causal = jnp.tril(jnp.ones((s, s), bool))
-
-    def layer(x, lp):
-        lp = jax.tree.map(lambda w: w.astype(f32), lp)
-        y = rms(x, lp["attn_norm"])
-        q = rope((y @ lp["wq"]).reshape(b, s, n_heads, head_dim))
-        k = rope((y @ lp["wk"]).reshape(b, s, n_kv_heads, head_dim))
-        v = (y @ lp["wv"]).reshape(b, s, n_kv_heads, head_dim)
-        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(f32(head_dim))
-        sc = jnp.where(causal[None, None], sc, -jnp.inf)
-        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-        x = x + o.reshape(b, s, n_heads * head_dim) @ lp["wo"]
-        y = rms(x, lp["mlp_norm"])
-        x = x + (jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"])) \
-            @ lp["w_down"]
-        return x, None
-
-    x = jnp.take(params["embed"], tokens, axis=0).astype(f32)
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = rms(x, params["final_norm"])
-    return x @ params["lm_head"].astype(f32)
-
-
-def _model_kw(cfg) -> dict:
-    return dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-                eps=cfg.norm_eps)
-
-
-def forward(params, tokens, cfg):
-    import jax
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(functools.partial(_f32_forward, **_model_kw(cfg)))(
-            params, tokens)
-
-
-def logits_and_loss(params, batch, cfg):
-    """The reference's logits (b, s, vocab) and its mean cross-entropy
-    against ``batch["targets"]``, from one forward."""
-    import jax
-    import jax.numpy as jnp
-
-    def f(params, tokens, targets):
-        logits = _f32_forward(params, tokens, **_model_kw(cfg))
-        logz = jax.nn.logsumexp(logits, -1)
-        gold = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
-        return logits, jnp.mean(logz - gold)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(f)(params, batch["tokens"], batch["targets"])
 
 
 def rel_err_device(a, b):
@@ -111,39 +22,3 @@ def rel_err(a, b) -> float:
     import numpy as np
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
-
-
-def serve_parity(params, cfg, seed: int, prompt_len: int, *, buckets,
-                 block: int, kv_impl: str, interpret: bool) -> dict:
-    """Prefill one seeded prompt through the served prefill, write its
-    KV into a paged pool, decode one step through the block table with
-    the served decode path, and compare both logits with the reference's
-    full forward over the same prompt+1 tokens."""
-    import random
-
-    import jax.numpy as jnp
-    import numpy as np
-    from ray_tpu.llm import kvcache as kc
-    from ray_tpu.llm import model as lm
-    rng = random.Random(seed)
-    toks = [rng.randrange(1, cfg.vocab_size) for _ in range(prompt_len)]
-    bucket = min(b for b in buckets if b >= prompt_len)
-    logits, kv = lm.prefill(params, jnp.asarray(lm.pad_prompt(toks, bucket)),
-                            jnp.int32(prompt_len), cfg, bucket)
-    nb = bucket // block
-    pool = kc.init_pool(cfg, 1 + nb, block, jnp.bfloat16)
-    table = 1 + np.arange(nb, dtype=np.int32)
-    pool = kc.scatter_bucket(pool, kv, jnp.asarray(table), nb)
-    nxt = int(np.argmax(np.asarray(logits)))
-    step = kc.paged_decode_logits(
-        params, pool, jnp.asarray(table[None]),
-        jnp.asarray([prompt_len], jnp.int32),
-        jnp.asarray([nxt], jnp.int32), cfg, impl=kv_impl,
-        interpret=interpret)
-    want = np.asarray(forward(
-        params, jnp.asarray([toks + [nxt]], jnp.int32), cfg))[0]
-    return {"prefill_rel_err": rel_err(logits, want[prompt_len - 1]),
-            "decode_rel_err": rel_err(np.asarray(step)[0],
-                                      want[prompt_len]),
-            "finite": bool(np.isfinite(np.asarray(step)).all()),
-            "prompt_len": prompt_len}

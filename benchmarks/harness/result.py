@@ -1,6 +1,7 @@
 """The lines a run prints. The LAST line of stdout is the result the
 driver reads; everything before it is information, one JSON object a
-line."""
+line. The result is kept, not printed, by the runner: ``run.py`` prints
+it as its very last act, after every process of the run has ended."""
 
 from __future__ import annotations
 
@@ -12,14 +13,22 @@ def note(**row) -> None:
     print(json.dumps(row), flush=True)
 
 
+_kept = []
+
+
 def final(*, correct: bool, attempted: int, failed: int, metrics: dict,
           device: dict, breakdown: dict | None = None) -> None:
+    """Keep the result's row for ``take()``."""
     row = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         row["breakdown"] = breakdown
-    sys.stdout.flush()
-    print(json.dumps(row), flush=True)
+    _kept[:] = [row]
+
+
+def take() -> dict | None:
+    """The row the runner kept, once."""
+    return _kept.pop() if _kept else None
 
 
 def finish(cell: dict, trace: bool, ctx: dict, *, correct: bool,

@@ -14,6 +14,7 @@ import socket
 import sys
 import tempfile
 import time
+import traceback
 
 from harness import loadgen, model as hmodel, result, spec
 
@@ -28,6 +29,11 @@ def _free_port() -> int:
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
         t_proc0: float) -> int:
+    fam = spec.family(cell["family"])
+    if not hasattr(fam, "serve_parity"):
+        print(f"benchmark: family {cell['family']!r} has no serve_parity: "
+              f"it cannot be served yet. No result.", file=sys.stderr)
+        return 6
     # the runtime's workers inherit this environment
     os.environ.setdefault("RAY_TPU_METRICS_EXPORT_INTERVAL_S", "30")
     cache = hmodel.compile_cache()
@@ -43,7 +49,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     dep = m["deployment"]
     params = hmodel.traffic(cell)
     cfg = LLMConfig(
-        model=hmodel.llama_config(m), max_slots=dep["max_slots"],
+        model=fam.config(m), max_slots=dep["max_slots"],
         max_len=dep["max_len"], cache_dtype=dep["cache_dtype"],
         kv_block_size=dep["kv_block_size"], seed=seed % (2 ** 31),
         **({"prefill_buckets": tuple(dep["prefill_buckets"])}
@@ -78,7 +84,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
                         "kv_impl", "kv_interpret", "prefill_impl",
                         "pool_blocks", "block_size", "n_layers", "init_s")})
         parity = get(handle.bench_parity.remote(
-            seed, dep["parity_prompt_len"]), timeout=900)
+            seed, dep["parity_prompt_len"], cell["family"]), timeout=900)
         tol = dep["parity_tolerance"]
         parity_ok = (parity["finite"]
                      and parity["prefill_rel_err"] <= tol
@@ -110,11 +116,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     finally:
         if not ok:
             _tail_logs(os.path.join(work, "logs"))
-        try:
-            serve.shutdown()
-        finally:
-            ray_tpu.shutdown()
-            shutil.rmtree(work, ignore_errors=True)
+        # whatever went wrong above is what the caller must see: a
+        # failure of the shutdown is printed, not raised over it
+        for stop in (serve.shutdown, ray_tpu.shutdown):
+            try:
+                stop()
+            except Exception:   # noqa: BLE001 - the run is ending
+                traceback.print_exc()
+        shutil.rmtree(work, ignore_errors=True)
 
 
 def _delta(c0: dict, c1: dict) -> dict:

@@ -118,12 +118,13 @@ class BenchLLMServer(_LLMServer):
         loop = asyncio.get_running_loop()
         return await loop.run_in_executor(None, xp.reduce_file, xplane)
 
-    async def bench_parity(self, seed: int, prompt_len: int) -> dict:
-        from harness import reference
+    async def bench_parity(self, seed: int, prompt_len: int,
+                           family: str) -> dict:
+        from harness import spec
         loop = asyncio.get_running_loop()
         st = self.engine.stats
         return await loop.run_in_executor(
-            None, lambda: reference.serve_parity(
+            None, lambda: spec.family(family).serve_parity(
                 self.engine.params, self.engine.cfg, seed, prompt_len,
                 buckets=self.engine.buckets, block=st["block_size"],
                 kv_impl=st["kv_impl"], interpret=st["kv_interpret"]))

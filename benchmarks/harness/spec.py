@@ -11,6 +11,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -36,6 +37,8 @@ def cell(name: str, bench: dict | None = None, root: str = ROOT) -> dict:
     cfg = next(c for c in bench["configs"] if c["name"] == wl["config"])
     out = dict(wl)
     out["model"] = _load(os.path.join(root, cfg["file"]))
+    out["family"] = out["model"]["deployment"].get("family") \
+        or _module("families", "__init__").DEFAULT
     out["traffic_params"] = _load(
         os.path.join(BENCH_DIR, "traffic", wl["traffic"] + ".json"))
 
@@ -50,15 +53,34 @@ def metric_file(name: str) -> dict:
     return _load(os.path.join(BENCH_DIR, "metrics", name + ".json"))
 
 
+def _module(kind: str, name: str):
+    path = os.path.join(BENCH_DIR, kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str):
     """readers/<name>.py, loaded by path; its ``read(ctx, **args)``
     returns a number, or None when there is nothing to read."""
-    path = os.path.join(BENCH_DIR, "readers", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_reader_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("readers", name).read
+
+
+def family(name: str):
+    """families/<name>.py, loaded by path, once a process: everything
+    the harness asks of a model by its name (``config``, ``module``,
+    ``forward``, ``logits_and_loss``, ``train_required_flops_per_token``
+    and, for a family that can be served, ``serve_parity``)."""
+    key = f"bench_families_{name}"
+    if key not in sys.modules:
+        path = os.path.join(BENCH_DIR, "families", name + ".py")
+        if not os.path.isfile(path):
+            raise SystemExit(f"the configuration names family {name!r}; "
+                             f"there is no {path}")
+        sys.modules[key] = _module("families", name)
+    return sys.modules[key]
 
 
 def read_metrics(defs: list, ctx: dict) -> dict:

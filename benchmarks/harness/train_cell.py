@@ -12,7 +12,7 @@ import shutil
 import tempfile
 import time
 
-from harness import model as hmodel, result
+from harness import model as hmodel, result, spec
 
 
 class _FreezeWatch:
@@ -41,19 +41,19 @@ class _FreezeWatch:
         self._thread.join()
 
 
-def parity(cfg, mesh, params, ref_params, sub) -> tuple:
+def parity(fam, cfg, mesh, params, ref_params, sub) -> tuple:
     """(program's loss, reference's loss, relative norm of the logits'
     difference, relative difference of the losses) on the slice
     ``sub``: the program's forward and loss on ``params`` on this mesh
-    against the plain reference on ``ref_params``."""
+    against the family's plain reference on ``ref_params``."""
     import jax
     from harness import reference
-    from ray_tpu.models import llama
     from ray_tpu.parallel import mesh as pmesh
-    got_logits = jax.jit(lambda p, t: llama.forward(p, t, cfg, mesh))(
+    module = fam.module()
+    got_logits = jax.jit(lambda p, t: module.forward(p, t, cfg, mesh))(
         params, sub["tokens"])
-    got = float(pmesh.make_eval_step(cfg, mesh)(params, sub))
-    want_logits, want = reference.logits_and_loss(ref_params, sub, cfg)
+    got = float(pmesh.make_eval_step(cfg, mesh, model=module)(params, sub))
+    want_logits, want = fam.logits_and_loss(ref_params, sub, cfg)
     want = float(want)
     logits_err = float(reference.rel_err_device(got_logits, want_logits))
     return got, want, logits_err, abs(got - want) / abs(want)
@@ -75,11 +75,12 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     dep = m["deployment"]
     params = hmodel.traffic(cell)
     seq, batch = int(params["seq_len"]), int(dep["batch"])
-    cfg = hmodel.llama_config(m, **dep["model_overrides"])
+    fam = spec.family(cell["family"])
+    cfg = fam.config(m, **dep["model_overrides"])
     devices = jax.devices()[:cell["chips"]]
     mesh = pmesh.make_mesh(pmesh.MeshSpec(
         data=1, context=1, **dep["mesh"]), devices=devices)
-    init_fn, step_fn = pmesh.make_train_step(cfg, mesh)
+    init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=fam.module())
     key = jax.random.PRNGKey(seed % (2 ** 31))
     n_batches = int(params.get("distinct_batches", 2))
 
@@ -113,7 +114,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         rows = mesh.shape["data"] * mesh.shape["fsdp"]   # one a shard
         sub = {k: v[:rows, :n_ref] for k, v in data[0].items()}
         got, want, logits_err, loss_err = parity(
-            cfg, mesh, state.params, state.params, sub)
+            fam, cfg, mesh, state.params, state.params, sub)
         parity_ok = (logits_err <= dep["parity_logits_tolerance"]
                      and loss_err <= dep["parity_loss_tolerance"])
         result.note(note="parity", device=device, loss=got, reference=want,

@@ -4,14 +4,18 @@
     python3 benchmarks/run.py --workload <name> --seed <n> \
         --seconds <s> --trace <0|1>
 
-The last line of stdout is the result (see README.md). Without a TPU
-holding the chips the cell asks for, the exit code is not 0 and no
-result is printed - unless BENCH_REHEARSAL=1, which runs tiny widths on
-the CPU to rehearse the control flow (its numbers mean nothing).
+The last line of stdout is the result (see README.md), printed after
+every process the run started has ended; the line before it says what
+that took. Without a TPU holding the chips the cell asks for, the exit
+code is not 0 and no result is printed - unless BENCH_REHEARSAL=1, which
+runs tiny widths on the CPU to rehearse the control flow (its numbers
+mean nothing).
 """
 
 import argparse
+import importlib
 import os
+import signal
 import sys
 import time
 
@@ -25,6 +29,15 @@ for p in (BENCH_DIR, ROOT):
         sys.path.insert(0, p)
 
 
+def _cut(signum, frame):
+    """A run cut by ``timeout`` tears down like any other: Python's
+    default SIGTERM would skip every ``finally``."""
+    raise SystemExit(128 + signum)
+
+
+CUTS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -36,16 +49,38 @@ def main(argv=None) -> int:
         print("benchmark: the program (ray_tpu/) is not in this checkout",
               file=sys.stderr)
         return 4
-    from harness import spec
+    from harness import procs, result, spec
     cell = spec.cell(a.workload)
     kind = cell["model"]["deployment"]["kind"]
-    if kind == "serve":
-        from harness import serve_cell as runner
-    elif kind == "train":
-        from harness import train_cell as runner
-    else:
-        raise SystemExit(f"configuration kind {kind!r}")
-    return runner.run(cell, a.seed, a.seconds, bool(a.trace), T_PROC0)
+    mark = procs.begin()    # before anything of the run starts a process
+    for sig in CUTS:
+        signal.signal(sig, _cut)
+    try:
+        # harness/<kind>_cell.py: serve_cell, train_cell
+        try:
+            runner = importlib.import_module(f"harness.{kind}_cell")
+        except ModuleNotFoundError as e:
+            if e.name != f"harness.{kind}_cell":
+                raise
+            raise SystemExit(f"configuration kind {kind!r}") from None
+        code = runner.run(cell, a.seed, a.seconds, bool(a.trace), T_PROC0)
+    finally:
+        # the runner's own shutdown has run; now nothing may cut the
+        # teardown short, and nothing of the run may outlive it
+        for sig in CUTS:
+            signal.signal(sig, signal.SIG_IGN)
+        t = time.monotonic()
+        outlived = procs.end_all()
+        result.note(note="teardown", mark=mark, outlived=outlived,
+                    seconds=time.monotonic() - t)
+    row = result.take()
+    if any(p["how"] == "alive" for p in outlived):
+        print("benchmark: a process of this run could not be ended. "
+              "No result.", file=sys.stderr)
+        return 5
+    if code == 0 and row is not None:
+        result.note(**row)
+    return code
 
 
 if __name__ == "__main__":

@@ -76,3 +76,23 @@ def test_every_cell_finds_its_files_and_reports_enough(bench):
             assert callable(spec.reader(mf["reader"]))
             for key in ("unit", "better", "source", "layer", "moves"):
                 assert mf.get(key) == m.get(key), (m["name"], key)
+
+
+def test_the_kept_result_is_the_contracts_last_line():
+    """The runner keeps the row, ``run.py`` prints it after the teardown
+    note (tests/test_teardown.py has the order): the keys are the
+    contract's, and it is handed over once."""
+    from harness import result
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+              "memory_peak_bytes": 1}
+    result.final(correct=1, attempted=2.0, failed=0, device=device,
+                 metrics={"setup_s": {"value": 1.5, "unit": "s"}},
+                 breakdown={"device_ops": [], "idle_gaps": []})
+    row = result.take()
+    assert list(row) == ["correct", "attempted", "failed", "metrics",
+                         "device", "breakdown"]
+    assert row["correct"] is True and row["attempted"] == 2
+    result.final(correct=True, attempted=1, failed=0, device=device,
+                 metrics={})
+    assert "breakdown" not in result.take()
+    assert result.take() is None

@@ -15,7 +15,7 @@ import sys
 
 import pytest
 
-from harness import spec
+from harness import procs, spec
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
@@ -39,8 +39,24 @@ def run_cell(root, workload, *, rehearsal=True, trace=0, chips=1,
         cwd=root, env=env, capture_output=True, text=True, timeout=900)
 
 
+def teardown_note(proc, at):
+    """The run's teardown note, which is line ``at`` of its stdout;
+    nothing that carries the run's mark is alive any more."""
+    note = json.loads(proc.stdout.strip().splitlines()[at])
+    assert note["note"] == "teardown", note
+    assert all(p["how"] in ("exited", "sigterm", "sigkill")
+               for p in note["outlived"])
+    marked = [pid for pid in procs.table() if procs.has_env(
+        pid, f"{procs.MARK_ENV}={note['mark']}")]
+    assert not marked, [procs.cmdline(pid) for pid in marked]
+    return note
+
+
 def last_line(proc):
+    """The result, which is the last line; the teardown note is the one
+    before it."""
     assert proc.returncode == 0, proc.stderr[-3000:]
+    teardown_note(proc, -2)
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
@@ -75,6 +91,7 @@ def test_no_tpu_no_result(workload):
     proc = run_cell(spec.ROOT, workload, rehearsal=False)
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout
+    teardown_note(proc, -1)     # a refused run tears down all the same
 
 
 def test_nothing_but_the_benchmark_no_result(tmp_path):
@@ -85,16 +102,53 @@ def test_nothing_but_the_benchmark_no_result(tmp_path):
     assert proc.returncode != 0 and '"correct"' not in proc.stdout
 
 
-def test_a_later_pr_adds_files_only(tmp_path):
-    """A configuration, a traffic mix, a cell and a per-layer metric
-    (with a reader of its own) are added by new files and new entries;
-    no file that was there is touched."""
-    root = tmp_path
+# a family file of a later PR: here the accepted one under another name
+REEXPORT = (
+    "from harness import spec\n"
+    "globals().update({k: v for k, v in vars(spec.family('llama')).items()\n"
+    "                  if not k.startswith('__') and k not in %r})\n")
+
+
+def scratch_checkout(root):
     shutil.copytree(spec.BENCH_DIR, root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(spec.ROOT, "ray_tpu"), root / "ray_tpu")
-    b = root / "benchmarks"
-    bench = spec.benchmark()
+    return root / "benchmarks", spec.benchmark()
+
+
+def add_cell(b, bench, name, like, family):
+    """A configuration like ``like`` but of ``family``, and a cell on it
+    with the traffic and end-to-end metrics of ``like``'s cell."""
+    cfg = json.load(open(b / "configs" / f"{like}.json"))
+    cfg["deployment"]["family"] = family
+    (b / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    was = next(w for w in bench["workloads"] if w["config"] == like)
+    bench["configs"].append({
+        "name": name, "source": cfg["source"], "why": "test",
+        "file": f"benchmarks/configs/{name}.json",
+        "reduced": ["num_hidden_layers"]})
+    bench["workloads"].append(dict(was, name=name, config=name))
+    for m in bench["end_to_end"]:
+        if was["name"] in m.get("workloads", ()):
+            m["workloads"].append(name)
+
+
+def harness_files(b):
+    return {p: p.read_bytes() for p in sorted((b / "harness").iterdir())
+            if p.is_file()}
+
+
+def test_a_later_pr_adds_files_only(tmp_path):
+    """A configuration, a traffic mix, a cell and a per-layer metric
+    (with a reader of its own) are added by new files and new entries,
+    and so is a model family with a train cell on it; no file that was
+    there is touched."""
+    root = tmp_path
+    b, bench = scratch_checkout(root)
+    before = harness_files(b)
+    (b / "families" / "throwaway.py").write_text(REEXPORT % ((),))
+    add_cell(b, bench, "throwaway-train", "mistral-7b-v0.3-train",
+             "throwaway")
 
     cfg = json.load(open(b / "configs" / "mistral-7b-v0.3-serve.json"))
     cfg["rehearsal"]["num_hidden_layers"] = 1
@@ -132,6 +186,30 @@ def test_a_later_pr_adds_files_only(tmp_path):
     out = last_line(run_cell(str(root), "throwaway-cell", trace=0,
                              extra_env=env))
     assert {"tpot_p50_ms", "setup_s"} <= set(out["metrics"])
+    # the new family's train cell, through make_train_step(model=...)
+    assert spec.cell("throwaway-train", bench,
+                     root=str(root))["family"] == "throwaway"
+    out = last_line(run_cell(str(root), "throwaway-train", trace=0,
+                             extra_env=env))
+    assert out["correct"] and out["attempted"] > 0
+    assert harness_files(b) == before
+
+
+def test_a_family_that_cannot_be_served_is_refused_early(tmp_path):
+    """A serve configuration on a family without ``serve_parity`` exits
+    non-zero before the runtime is started: nothing but the teardown
+    note is printed, and that says no process had to be ended."""
+    root = tmp_path
+    b, bench = scratch_checkout(root)
+    (b / "families" / "noserve.py").write_text(
+        REEXPORT % (("serve_parity",),))
+    add_cell(b, bench, "noserve-serve", "mistral-7b-v0.3-serve", "noserve")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    proc = run_cell(str(root), "noserve-serve",
+                    extra_env={"PYTHONPATH": spec.ROOT})
+    assert proc.returncode != 0 and "serve_parity" in proc.stderr
+    assert len(proc.stdout.strip().splitlines()) == 1
+    assert teardown_note(proc, -1)["outlived"] == []
 
 
 def test_the_prepared_docs_cell_needs_entries_only(tmp_path):

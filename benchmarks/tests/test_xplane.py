@@ -101,17 +101,6 @@ def test_interval_arithmetic():
     assert xplane._subtract([[0, 4], [6, 9]], []) == [[0, 4], [6, 9]]
 
 
-def test_train_required_flops_per_token():
-    m = dict(hidden_size=4096, intermediate_size=14336,
-             num_attention_heads=32, num_key_value_heads=8,
-             vocab_size=32768)
-    per_layer = 4096 * 4096 * 2 + 2 * 4096 * 1024 + 3 * 4096 * 14336
-    matmul = 4 * per_layer + 4096 * 32768
-    attn = 14 * 128 * 32 * (4096 + 1) / 2 * 4
-    assert kernels.train_required_flops_per_token(m, 4, 4096) == \
-        pytest.approx(6 * matmul + attn)
-
-
 def test_readers_that_count_from_the_trace_and_the_client(reduced):
     """Decode steps come from the trace itself (kernel calls / layers);
     what the engine's public counters lack comes from the client's

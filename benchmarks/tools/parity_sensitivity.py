@@ -38,10 +38,11 @@ def main() -> int:
     result.require_tpu(jaxenv.describe_device(), cell["chips"])
     m = hmodel.resolved(cell["model"])
     dep = m["deployment"]
-    cfg = hmodel.llama_config(m, **dep["model_overrides"])
+    fam = spec.family(cell["family"])
+    cfg = fam.config(m, **dep["model_overrides"])
     mesh = pmesh.make_mesh(pmesh.MeshSpec(data=1, context=1, **dep["mesh"]),
                            devices=jax.devices()[:cell["chips"]])
-    init_fn, _ = pmesh.make_train_step(cfg, mesh)
+    init_fn, _ = pmesh.make_train_step(cfg, mesh, model=fam.module())
     key = jax.random.PRNGKey(a.seed % (2 ** 31))
     rows = mesh.shape["data"] * mesh.shape["fsdp"]
     n = int(dep["parity_tokens"])
@@ -82,7 +83,7 @@ def main() -> int:
             try:
                 damaged = jax.jit(damage)(params)
                 _, _, logits_err, loss_err = train_cell.parity(
-                    cfg, mesh, damaged, params, sub)
+                    fam, cfg, mesh, damaged, params, sub)
             except Exception as e:  # noqa: BLE001 - report and go on
                 result.note(variant=name, error=f"{type(e).__name__}: {e}")
                 continue
