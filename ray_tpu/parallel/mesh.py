@@ -106,10 +106,14 @@ def make_train_step(cfg, mesh: Mesh,
 
     ``model`` is any module exposing the model-family protocol
     (init_params / param_shardings / loss_fn) — ray_tpu.models.llama
-    (default) or ray_tpu.models.moe."""
+    (default) or ray_tpu.models.moe. A family that also has
+    ``loss_and_metrics`` (-> loss, {name: device scalar}) gets those
+    scalars added to a step's metrics (moe: ``moe_aux_loss``,
+    ``moe_load_max_over_mean``)."""
     opt = optimizer if optimizer is not None else default_optimizer()
     _loss = loss_fn if loss_fn is not None else (
         lambda p, b: model.loss_fn(p, b, cfg, mesh, axes))
+    family_metrics = loss_fn is None and hasattr(model, "loss_and_metrics")
     pspecs = model.param_shardings(cfg, axes)
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                           is_leaf=lambda x: isinstance(x, P))
@@ -131,14 +135,21 @@ def make_train_step(cfg, mesh: Mesh,
     def step_fn(state: TrainState, batch: dict):
         batch = {k: jax.lax.with_sharding_constraint(v, batch_spec)
                  for k, v in batch.items()}
-        loss, grads = jax.value_and_grad(_loss)(state.params, batch)
+        if family_metrics:
+            (loss, extra), grads = jax.value_and_grad(
+                lambda p, b: model.loss_and_metrics(p, b, cfg, mesh, axes),
+                has_aux=True)(state.params, batch)
+        else:
+            loss, grads = jax.value_and_grad(_loss)(state.params, batch)
+            extra = {}
         with jax.named_scope("optimizer"):   # a name in the device trace
             updates, opt_state = opt.update(grads, state.opt_state,
                                             state.params)
             params = optax.apply_updates(state.params, updates)
             gnorm = optax.global_norm(grads)
         return (TrainState(params, opt_state, state.step + 1),
-                {"loss": loss, "grad_norm": gnorm, "step": state.step + 1})
+                {"loss": loss, "grad_norm": gnorm, "step": state.step + 1,
+                 **extra})
 
     return init_fn, step_fn
 

@@ -12,6 +12,7 @@ the clock never reaches guards nothing.)
 """
 
 import os
+import re
 import sys
 
 import pytest
@@ -129,3 +130,49 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert {bench.classify(op) for op in ops} == kernels
     for op in ops:
         assert bench.classify(op) in op["name"], op["name"]
+
+
+# --- the grouped matmul of the MoE train path -------------------------------
+
+def _gmm(lhs, rhs, group_sizes):
+    from ray_tpu.ops.pallas import grouped_matmul
+    return grouped_matmul.gmm(lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(lhs, rhs, group_sizes):
+    return jax.grad(lambda l, r: _gmm(l, r, group_sizes).astype(
+        jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
+
+
+# the cell train-olmoe: 4 x 4096 tokens x 8 experts a token = 131,072
+# rows, 64 groups, hidden 2048, expert width 1024
+_ROWS, _EXPERTS, _HIDDEN, _WIDTH = 131072, 64, 2048, 1024
+GMM_CASES = {
+    "gmm_up": (_gmm, (_HIDDEN, _WIDTH), {"moe_gmm"}),
+    "gmm_down": (_gmm, (_WIDTH, _HIDDEN), {"moe_gmm"}),
+    "gmm_up_bwd": (_gmm_bwd, (_HIDDEN, _WIDTH),
+                   {"moe_gmm_t", "moe_gmm_drhs"}),
+    "gmm_down_bwd": (_gmm_bwd, (_WIDTH, _HIDDEN),
+                     {"moe_gmm_t", "moe_gmm_drhs"}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GMM_CASES))
+def test_grouped_matmul_compiles_for_v5e(chip, name):
+    fn, (k, n), names = GMM_CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+        ((_ROWS, k), jnp.bfloat16), ((_EXPERTS, k, n), jnp.bfloat16),
+        ((_EXPERTS,), jnp.int32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    bench = _bench_kernels()
+    ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
+    # the kernels themselves, under their names; the benchmark's
+    # reduction takes none of them for a flash or a paged kernel: they
+    # land in unknown_kernel, where readers/moe_gmm.py looks
+    for op in ops:
+        assert bench.classify(op) == "unknown_kernel", op
+    # moe_gmm__.1, transpose_jvp_moe_gmm_t__.1, ...
+    found = [re.search(r"moe_gmm(_drhs|_t)?(?=_|\.|$)", op["name"])
+             for op in ops]
+    assert all(found), [op["name"] for op in ops]
+    assert sorted(m.group(0) for m in found) == sorted(names)

@@ -1,7 +1,9 @@
-"""MoE family: routing correctness, expert-parallel sharding, training.
+"""MoE family: shapes, gradients, expert-parallel sharding, training.
 
-Expert parallelism is native here (a mesh axis + GSPMD all-to-alls) where
-the reference only forwards EP flags to vLLM (SURVEY.md section 2.3).
+Expert parallelism is native here (a mesh axis) where the reference only
+forwards EP flags to vLLM (SURVEY.md section 2.3). Routing against the
+plain reference, dropless behaviour and the grouped matmul are in
+tests/test_zz_moe_olmoe.py.
 """
 
 import jax
@@ -23,51 +25,13 @@ def test_forward_shapes_and_aux():
     params = moe.init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                 cfg.vocab_size)
-    logits, aux = moe.forward(params, tokens, cfg)
+    logits = moe.forward(params, tokens, cfg)      # logits, as llama's
     assert logits.shape == (2, 32, cfg.vocab_size)
     assert logits.dtype == jnp.float32
-    aux = float(aux)
+    _, stats = moe.loss_and_metrics(
+        params, {"tokens": tokens, "targets": tokens}, cfg)
+    aux = float(stats["moe_aux_loss"])
     assert np.isfinite(aux) and aux > 0.0
-
-
-def test_route_respects_topk_and_capacity():
-    cfg = _cfg()
-    s, E, k = 32, cfg.n_experts, cfg.experts_per_token
-    C = cfg.capacity(s)
-    y = jax.random.normal(jax.random.PRNGKey(2), (2, s, cfg.dim),
-                          jnp.float32)
-    router = jax.random.normal(jax.random.PRNGKey(3), (cfg.dim, E),
-                               jnp.float32)
-    dispatch, combine, aux = moe._route(y, router, cfg)
-    d = np.asarray(dispatch)
-    # each token occupies at most k slots, each slot at most once
-    per_token = d.sum(axis=(2, 3))
-    assert per_token.max() <= k + 1e-6
-    # no expert column holds more than one token per capacity slot
-    per_slot = d.sum(axis=1)            # (b, E, C)
-    assert per_slot.max() <= 1 + 1e-6
-    assert d.shape == (2, s, E, C)
-    # combine weights live only where dispatch does
-    c = np.asarray(combine)
-    assert (c[d == 0] == 0).all()
-    # gates on kept slots sum to <= 1 per token (== 1 when nothing dropped)
-    assert c.sum(axis=(2, 3)).max() <= 1 + 1e-5
-
-
-def test_balanced_router_keeps_all_tokens():
-    # round-robin token->expert assignment fits within capacity exactly:
-    # nothing is dropped when the load is balanced
-    cfg = _cfg(experts_per_token=1)
-    s, E = 64, cfg.n_experts
-    # y rows one-hot on (token % E); router projects those dims to logits
-    y = jax.nn.one_hot(jnp.arange(s) % E, cfg.dim)[None]      # (1, s, dim)
-    router = jnp.zeros((cfg.dim, E)).at[:E, :E].set(10 * jnp.eye(E))
-    dispatch, combine, _ = moe._route(y, router, cfg)
-    kept = float(np.asarray(dispatch).sum())
-    assert kept == s  # every token kept
-    # and the row-sum of combine is exactly 1 (single expert, no drops)
-    np.testing.assert_allclose(
-        np.asarray(combine).sum(axis=(2, 3)), 1.0, rtol=1e-5)
 
 
 def test_grads_flow_to_experts_and_router():
