@@ -99,6 +99,10 @@ def engine_metrics() -> dict:
       llm_decode_slot_steps    slots x steps per block
       llm_decode_ctx_tokens    positions attended per block, summed
                                over its slots and steps
+      llm_decode_kv_fetch_tokens  positions of K (and of V) the paged
+                               kernel's walk fetched per block, summed
+                               over ALL slots (idle ones are walked
+                               too) and its steps
       llm_prefill_tokens       prompt tokens run through a prefill
                                forward per admit (prefix hits excluded)
 
@@ -138,6 +142,13 @@ def engine_metrics() -> dict:
             "llm_decode_ctx_tokens",
             "Context positions attended per decode block, summed over "
             "its active slots and steps",
+            boundaries=(64, 256, 1024, 4096, 16384, 65536, 262144,
+                        1048576)),
+        "kv_fetch_tokens": m.Histogram(
+            "llm_decode_kv_fetch_tokens",
+            "Positions of K (and of V) the paged-decode kernel's walk "
+            "fetched per decode block, summed over all slots, idle "
+            "ones included, and its steps",
             boundaries=(64, 256, 1024, 4096, 16384, 65536, 262144,
                         1048576)),
         "prefill_tokens": m.Histogram(
@@ -858,6 +869,19 @@ class LLMEngine:
                                 + len(self._slots[i].out)
                                 for i in active)
                     + n * block * (block - 1) // 2)
+                if self._paged and self._kv_impl == "paged_flash":
+                    # what the kernel's walk fetched for them: every
+                    # slot's live blocks at every step, an idle slot
+                    # (length 1 + step) included
+                    from ray_tpu.ops.pallas.paged_attention import \
+                        fetched_positions
+                    at = np.ones((self.max_slots, 1), np.int64)
+                    for i in active:
+                        at[i] = len(self._slots[i].tokens) \
+                            + len(self._slots[i].out)
+                    self._m["kv_fetch_tokens"].observe(int(
+                        fetched_positions(at + np.arange(block),
+                                          self._block).sum()))
                 self._record_block(n, block, member_traces, first_ctx,
                                    block=block)
                 with phase("emit"):

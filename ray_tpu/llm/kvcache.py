@@ -9,15 +9,18 @@ TPU-native on the engine's static-shape rules:
 - the POOL is one preallocated tensor pair per engine,
   ``(layers, num_blocks, kv_heads, block_size, head_dim)`` — shapes
   never change, so XLA compiles the paged decode step exactly once.
-  It is head-major because the decode kernel streams one head of one
-  block per grid step and the TPU compiler needs that slab to be
+  It is head-major so that one physical block is one contiguous
+  ``(kv_heads, block_size, head_dim)`` slab: the decode kernel moves
+  all KV heads of a block with one DMA, and each head's rows are
   whole tiles (ops/pallas/paged_attention.py);
 - each request owns a BLOCK TABLE (fixed width ``max_len //
-  block_size``) of physical block ids; decode gathers the table's
-  blocks into the attention view and scatters the new token's KV back
-  through it (bitwise-identical to the monolithic cache: gathered
-  values are the same bytes in the same order, and masked tail
-  positions contribute exact zeros);
+  block_size``) of physical block ids. Decode attends through it:
+  the kernel walks the table's LIVE entries and fetches those blocks
+  itself, or (impl ``gather``) the table's blocks are gathered into
+  the attention view (bitwise-identical to the monolithic cache:
+  gathered values are the same bytes in the same order, and masked
+  tail positions contribute exact zeros); the new token's KV is
+  written back through the table either way;
 - a PREFIX CHAIN INDEX (hash-chained per full token block, the radix
   structure flattened into parent links) maps prompt prefixes to
   cached block chains: a request sharing a cached prefix adopts those
@@ -660,11 +663,13 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     attention math (and therefore the sampled tokens) is bitwise
     identical (pinned by tests/test_zz_kvcache.py parity tests).
 
-    impl='paged_flash': the pallas kernel walks the block table
-    directly (ops/pallas/paged_attention.py) — no gathered view, no
-    O(slots x max_len x layers) copy per emitted token. Same f32
-    attention math; online softmax agrees with the gather path to f32
-    rounding (bitwise on the integer constructions
+    impl='paged_flash': the pallas kernel walks each slot's LIVE
+    table entries (``ceil(length / block_size)`` of them, a run-time
+    count: the table's width costs nothing) and fetches those pool
+    blocks with its own DMAs (ops/pallas/paged_attention.py) — no
+    gathered view, no O(slots x max_len x layers) copy per emitted
+    token. Same f32 attention math; online softmax agrees with the
+    gather path to f32 rounding (bitwise on the integer constructions
     tests/test_zz_paged_attn.py pins).
 
     With ``mesh``, the kernel path runs under shard_map: kv heads

@@ -3,41 +3,64 @@
 Fuses the two reference designs the serving stack sits between:
 vLLM's PagedAttention (block tables over a fixed KV pool) and the
 flash-attention tiling already in ``flash_attention.py`` (online
-softmax, VMEM-resident running max/sum). One decode step used to cost
-a full ``gather_table`` — an O(slots x max_len x layers) HBM copy
+softmax over chunks of the context). One decode step used to cost a
+full ``gather_table`` — an O(slots x max_len x layers) HBM copy
 materializing the contiguous ``(slots, max_len)`` attention view —
-before any attention math ran. Here the Pallas grid walks each slot's
-block table DIRECTLY: the kv index_map reads the scalar-prefetched
-table and streams the slot's physical pool blocks into VMEM one at a
-time, accumulating online-softmax attention. The gathered view never
-exists; ``gather_table`` stays only on the prefix-hit prefill path and
-in debug/parity tooling.
+before any attention math ran. Here the kernel walks each slot's block
+table DIRECTLY and fetches the slot's physical pool blocks itself; the
+gathered view never exists. ``gather_table`` stays only on the
+prefix-hit prefill path and in debug/parity tooling.
+
+The walk. The grid is ``(slots,)``; both pools stay in HBM
+(``memory_space=pl.ANY``), and tables and lengths are the two
+scalar-prefetch operands. For a slot of ``length`` valid positions the
+kernel runs ``ceil(live / C)`` turns of a loop, ``live =
+live_blocks(length, block_size)``: a run-time trip count, so a slot
+with 37 tokens costs one turn and one with 4,096 costs 32, and no
+table width, bucket or compiled variant enters into it. A turn works
+on one CHUNK of C pool blocks: for each of the chunk's table entries
+that is live it starts one DMA ``k_pool[table[b, i*C + c]] -> kbuf``
+and one for V. The pool is HEAD-MAJOR — one layer is ``(num_blocks,
+kv_heads, block_size, head_dim)`` — so one physical block is
+contiguous and one descriptor moves ALL KV heads of it (32 KB at 8
+heads x 16 x 128 bf16). Two buffers each for K and V: chunk ``i + 1``
+is started before chunk ``i`` is waited for and computed. Entries
+past a slot's last live block are neither started nor waited for
+(``fetched_positions`` is the rule, exported for the engine's
+counter), so the kernel never reads a block the slot does not own;
+what an unfetched part of a buffer still holds is masked out of both
+products. An idle slot (length 1, table row = trash) costs one block
+of each pool and one turn.
+
+``chunk_blocks`` is the one place that chooses C, from what the pool
+shows: about 128 positions a chunk (one lane-width of scores), capped
+so that the four buffers stay within 4 MB of VMEM.
+
+(Until PR 29 the grid was ``(slots, kv_heads, table_width)`` with one
+``(block_size, head_dim)`` tile a step, blocks past the last live one
+clamped in the index_map on the belief that the pipeliner's elided
+fetch made short slots free. The fetch was elided; the grid step was
+not: 65,536 steps a layer at 32 slots x 8 heads x 256 blocks whatever
+the contexts held, and the benchmark's ledger read 0.22% of the HBM
+roofline.)
 
 Numerics mirror ``ray_tpu.llm.model._gqa_attend_cached`` (the gather
-path's attention): f32 score dot, post-dot ``/ sqrt(head_dim)`` scale,
-f32 exp, f32 accumulation — online softmax is an exact refactoring of
-the masked softmax for the same summation order within a block, so the
-two impls agree to f32 rounding (and bitwise on integer-valued
+path's attention): f32 scores, post-dot ``/ sqrt(head_dim)`` scale,
+f32 exp, f32 running max / sum / accumulator, f32 output. q and k
+enter the score product as stored when both are bf16 (a product of
+two bf16 values is exact in f32, so the sum is the one the upcast
+gave); p stays f32 into the PV product and v is widened. Online
+softmax is an exact refactoring of the masked softmax, so the two
+impls agree to f32 rounding (and bitwise on integer-valued
 constructions; see tests/test_zz_paged_attn.py).
 
-The pool is HEAD-MAJOR — one layer is ``(num_blocks, kv_heads,
-block_size, head_dim)`` — so the block one grid step streams,
-``(block_size, head_dim)`` for one head of one physical block, is a
-whole number of TPU tiles (the TPU compiler refuses a block whose
-second-minor dim is a size-1 slice of a longer one, which a
-token-major pool forced). ``table_view`` is the one place that turns
-this layout back into the contiguous ``(slots, len, kv_heads,
-head_dim)`` view the gather path and the references attend over.
-
-Grid: ``(slots, kv_heads, table_width)`` with the table-walk dimension
-sequential ("arbitrary"). Blocks past a slot's last live block are
-clamped to the last live one in the index_map — reads stay inside
-blocks the slot owns, and Mosaic's pipeliner elides the duplicate
-consecutive fetches, so short slots don't pay for the table width.
+``table_view`` is the one place that turns the head-major layout back
+into the contiguous ``(slots, len, kv_heads, head_dim)`` view the
+gather path and the references attend over.
 
 Interpret mode (``interpret=True``) runs the same kernel logic through
 the Pallas interpreter — tier-1 (JAX_PLATFORMS=cpu) exercises the real
-table walk, masking, and online-softmax phases, not a shadow
+table walk, DMAs, masking, and online-softmax phases, not a shadow
 implementation.
 """
 
@@ -51,61 +74,103 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-LANES = 128  # m/l scratch are broadcast along the lane dim
+CHUNK_POSITIONS = 128           # one lane-width of scores a chunk
+BUFFER_BYTES = 4 * 1024 * 1024  # K and V, two buffers each
 
 
-def _last_block(length, bs):
-    """Index of the last live pool block for a slot with ``length``
-    valid positions (length >= 1 on the decode path: empty slots carry
-    position 0 => length 1, table row = trash)."""
-    return jnp.maximum(length, 1) - 1
+def chunk_blocks(kv_heads, block_size, head_dim, itemsize):
+    """C: the pool blocks one fetch of the walk brings in, from the
+    shape and dtype of the pool alone."""
+    block_bytes = kv_heads * block_size * head_dim * itemsize
+    return max(1, min(CHUNK_POSITIONS // block_size,
+                      BUFFER_BYTES // (4 * block_bytes)))
 
 
-def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, bs, hd):
+def live_blocks(length, block_size):
+    """Pool blocks the walk visits for a slot with ``length`` valid
+    positions: ``ceil(max(length, 1) / block_size)``; an idle slot
+    (length 0 or 1) still has one. The kernel's trip count and the
+    engine's counter both come from here, so it is written to work on
+    ints, numpy arrays and traced scalars alike."""
+    return (length + (length < 1) + block_size - 1) // block_size
+
+
+def fetched_positions(length, block_size):
+    """Positions of K (and of V) the walk fetches for a slot with
+    ``length`` valid positions: its live blocks, whole, and nothing
+    after them (the tail of a chunk is guarded, not rounded up)."""
+    return live_blocks(length, block_size) * block_size
+
+
+def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 kbuf, vbuf, sems, *, bs, cb, width):
     b_ = pl.program_id(0)
-    j = pl.program_id(2)
+    kvh, g, hd = q_ref.shape[1:]
+    t = cb * bs                                 # positions a chunk
     length = lengths_ref[b_]
-    last = _last_block(length, bs) // bs
+    live = jnp.minimum(live_blocks(length, bs), width)
+    n_chunks = (live + cb - 1) // cb
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def fetch(i, buf, wait):
+        """Start (or wait for) the DMAs of chunk ``i`` into buffer
+        ``buf``: one a pool block for K, one for V, the live ones
+        only."""
+        def entry(c, carry):
+            # a wait only needs the copy's shape, not its source
+            blk = 0 if wait else tables_ref[b_, i * cb + c]
+            rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
+            for s, (pool, dst) in enumerate(((k_hbm, kbuf),
+                                             (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    pool.at[blk], dst.at[buf, :, rows, :],
+                    sems.at[s, buf])
+                cp.wait() if wait else cp.start()
+            return carry
 
-    @pl.when(j <= last)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)         # (g, hd)
-        k = k_ref[0, 0].astype(jnp.float32)         # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        jax.lax.fori_loop(0, jnp.minimum(live - i * cb, cb), entry, 0)
+
+    q = q_ref[0]                                # (kvh, g, hd)
+    if not (q.dtype == kbuf.dtype == jnp.bfloat16):
+        q = q.astype(jnp.float32)
+    fetch(0, 0, wait=False)
+
+    def chunk(i, carry):
+        m_prev, l_prev, acc = carry
+        buf = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_chunks)
+        def _():
+            fetch(i + 1, 1 - buf, wait=False)
+
+        fetch(i, buf, wait=True)
+        k = kbuf[buf].astype(q.dtype)           # (kvh, t, hd)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) / jnp.sqrt(
-                jnp.float32(hd))                    # (g, bs)
-        cols = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        keep = cols < length
+                jnp.float32(hd))                # (kvh, g, t)
+        keep = i * t + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2) < length
         s = jnp.where(keep, s, NEG_INF)
-
-        m_prev = m_scr[...][:, :1]                  # (g, 1)
-        l_prev = l_scr[...][:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        # rows past ``length`` hold whatever the pool or an earlier
+        # chunk left there: p is 0 for them, and 0 x NaN is not
+        rows = i * t + jax.lax.broadcasted_iota(
+            jnp.int32, (kvh, t, hd), 1) < length
+        v = jnp.where(rows, vbuf[buf].astype(jnp.float32), 0.0)
+        acc = acc * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)  # (kvh, g, hd)
+        return m_new, l_new, acc
 
-    @pl.when(j == last)
-    def _finalize():
-        l = l_scr[...][:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk,
+        (jnp.full((kvh, g, 1), NEG_INF, jnp.float32),
+         jnp.zeros((kvh, g, 1), jnp.float32),
+         jnp.zeros((kvh, g, hd), jnp.float32)))
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
@@ -127,39 +192,35 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         raise ValueError(
             f"pool heads/dim {(kvh_p, hd_p)} != query {(kvh, hd)}")
     w = tables.shape[1]
+    cb = min(chunk_blocks(kvh, bs, hd, k_pool.dtype.itemsize), w)
 
-    def _qmap(b_, h_, j, t, ln):
-        return (b_, h_, 0, 0)
-
-    def _kvmap(b_, h_, j, t, ln):
-        # clamp past-the-end walks onto the slot's last live block:
-        # reads never leave blocks the slot owns, and the pipeliner
-        # skips re-fetching the same block on consecutive steps
-        last = _last_block(ln[b_], bs) // bs
-        return (t[b_, jnp.minimum(j, last)], h_, 0, 0)
+    def _qmap(b_, t, ln):
+        return (b_, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, w),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), _qmap),
-            pl.BlockSpec((1, 1, bs, hd), _kvmap),
-            pl.BlockSpec((1, 1, bs, hd), _kvmap),
+            pl.BlockSpec((1, kvh, g, hd), _qmap),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), _qmap),
+        out_specs=pl.BlockSpec((1, kvh, g, hd), _qmap),
         scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((2, kvh, cb * bs, hd), k_pool.dtype),
+            pltpu.VMEM((2, kvh, cb * bs, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    kernel = functools.partial(_decode_kernel, bs=bs, hd=hd)
+    kernel = functools.partial(_walk_kernel, bs=bs, cb=cb, width=w)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
+        # a slot's walk starts and waits for its own DMAs: slots are
+        # independent, and a chip with two cores may split them
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool,

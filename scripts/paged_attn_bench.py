@@ -1,200 +1,140 @@
-"""Paged-attention decode bench: gather-view vs fused kernel A/B,
-plus the tensor-parallel paged prefix-reuse row.
+"""The paged-decode kernel alone, on the chip: time one call of
+``ops/pallas/paged_attention.paged_attention`` at the shapes of the
+cell ``serve-chat-open`` (32 slots, 5,882 pool blocks of 16, a table of
+256 blocks, 8 KV heads x group 4, head 128, bf16) for contexts of
+64 / 256 / 1,024 / 4,096 tokens at 8 and 32 live slots, and give the
+share of the HBM roofline each reading is: the K and V bytes of the
+LIVE contexts, plus queries and outputs, at the chip's published
+bandwidth over the measured time (``benchmarks/harness/kernels.py
+paged_decode_bytes`` and ``peaks.py``: the cell's metric's own count).
 
-Three rows, all direct-engine (no HTTP — the decode loop is the thing
-under test):
+Slots that are not live are idle as the engine presents them: an
+all-trash table row and length 1. Live slots' tables name random pool
+blocks, so no two fetches are neighbours in HBM.
 
-1. ``decode``: long-context decode TPOT with ``kv_impl=gather`` (the
-   materialized-view baseline) vs ``kv_impl=auto`` (resolves to the
-   fused block-table kernel on a real TPU backend, to gather on CPU —
-   re-run this script unchanged on a TPU box for the real A/B). The
-   per-step HBM copy the kernel removes is also committed as bytes.
-2. ``kernel_parity``: the equal-logits evidence — the same prompts
-   decoded with ``kv_impl=paged_flash`` (pallas interpreter off-TPU)
-   must emit exactly the gather baseline's tokens.
-3. ``tp_prefix``: tensor-parallel (tp=2) paged engine with prefix
-   reuse — warm (shared-prefix hit) vs cold TTFT, hit tokens > 0,
-   tokens equal.
+``--old PATH`` times a second module beside it (the parent commit's
+kernel, unpacked under ``.scratch/``), same inputs, and compares the
+outputs. It is a one-off for PERF.md, no cell's code; without a TPU it
+exits 3 (``--rehearse`` runs tiny shapes through the interpreter to
+check the control flow; its times mean nothing).
 
-Results land under SERVE_BENCH.json ``paged_attn`` and
-LONGCTX_BENCH.json ``paged_attn``.
-
-Run from the repo root: python scripts/paged_attn_bench.py
-(CPU-friendly; every row stamps the device it ran on).
+    chiprun -- python scripts/paged_attn_bench.py --old \\
+        .scratch/parent/ray_tpu/ops/pallas/paged_attention.py
 """
 
 import argparse
-import asyncio
+import importlib.util
 import json
 import os
 import statistics
 import sys
 import time
 
-import numpy as np
+# the required bytes and the peak are the benchmark's own
+sys.path[:0] = [".", "benchmarks"]
+from harness import kernels, peaks  # noqa: E402
 
-sys.path.insert(0, ".")
-
-
-def _prompt(seed, n):
-    return [int(x) for x in
-            np.random.default_rng(seed).integers(1, 127, n)]
+CELL = dict(slots=32, blocks=5882, bs=16, width=256, kvh=8, g=4, hd=128)
+TINY = dict(slots=4, blocks=41, bs=16, width=16, kvh=2, g=2, hd=128)
 
 
-def _engine(cfg, params, **kw):
-    from ray_tpu.llm.engine import LLMEngine
-    base = dict(max_slots=4, cache_dtype="float32",
-                prefix_cache=False)
-    base.update(kw)
-    return LLMEngine(cfg, params, **base)
-
-
-def _gen_all(eng, prompts, max_new):
-    async def go():
-        outs = await asyncio.gather(*[
-            eng.generate(p, max_new_tokens=max_new) for p in prompts])
-        await eng.stop()
-        return outs
-    return asyncio.run(go())
-
-
-def _decode_row(cfg, params, impl, prompts, max_new, runs, **kw):
-    """Median decode TPOT (ms/token) over ``runs`` fresh engines —
-    TTFT (prefill) excluded: TPOT = (total - ttft) / (tokens - 1)."""
-    tpots, toks = [], None
-    for _ in range(runs):
-        eng = _engine(cfg, params, kv_impl=impl, **kw)
-        t0 = time.monotonic()
-        outs = _gen_all(eng, prompts, max_new)
-        total = time.monotonic() - t0
-        ttft = max(o["ttft_s"] for o in outs)
-        steps = max_new - 1
-        tpots.append((total - ttft) / steps * 1000.0)
-        toks = [o["tokens"] for o in outs]
-    return {"impl": impl, "resolved": eng._kv_impl,
-            "tpot_ms": round(statistics.median(tpots), 3)}, toks
+def _load(path):
+    spec = importlib.util.spec_from_file_location("old_paged", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--runs", type=int, default=3)
-    ap.add_argument("--long-prompt", type=int, default=512)
-    ap.add_argument("--max-new", type=int, default=48)
-    args = ap.parse_args()
+    ap.add_argument("--old", help="path of a second kernel module")
+    ap.add_argument("--reps", type=int, default=32,
+                    help="kernel calls chained in one program")
+    ap.add_argument("--rounds", type=int, default=5)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/paged_attn_bench.json")
+    a = ap.parse_args()
 
     import jax
-    from ray_tpu.llm import kvcache
-    from ray_tpu.models import llama
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops.pallas import paged_attention as new
 
-    device = os.environ.get("JAX_PLATFORMS",
-                            jax.devices()[0].platform)
-    cfg = llama.tiny(vocab_size=128, dim=64, n_layers=2, n_heads=4,
-                     n_kv_heads=2, ffn_dim=128, dtype="float32",
-                     logits_dtype="float32", attn_impl="reference")
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not a.rehearse:
+        print(json.dumps({"error": "needs a TPU", "device": device}))
+        return 3
+    # a rehearsal's shares mean as little as its times
+    hbm = peaks.PEAKS["TPU v5 lite"]["hbm_bytes_per_s"] if a.rehearse \
+        else peaks.peaks(dev.device_kind)["hbm_bytes_per_s"]
+    shp = TINY if a.rehearse else CELL
+    slots, nb, bs, w = (shp[k] for k in ("slots", "blocks", "bs", "width"))
+    kvh, g, hd = shp["kvh"], shp["g"], shp["hd"]
+    mods = {"new": new}
+    if a.old:
+        mods["old"] = _load(a.old)
 
-    # --- row 1: long-context decode TPOT, gather vs auto ------------
-    long_kw = dict(max_len=args.long_prompt + args.max_new + 16,
-                   prefill_buckets=(256,), kv_block_size=16)
-    prompts = [_prompt(i, args.long_prompt) for i in range(4)]
-    base, base_toks = _decode_row(cfg, params, "gather", prompts,
-                                  args.max_new, args.runs, **long_kw)
-    auto, auto_toks = _decode_row(cfg, params, "auto", prompts,
-                                  args.max_new, args.runs, **long_kw)
-    assert auto_toks == base_toks, "auto impl moved tokens"
-    decode = {"gather": base, "auto": auto,
-              "prompt_tokens": args.long_prompt,
-              "max_new": args.max_new, "slots": len(prompts)}
-    print(f"# decode: {json.dumps(decode)}", file=sys.stderr)
+    rng = np.random.default_rng(0)
+    kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, (slots, kvh, g, hd), jnp.bfloat16)
+    k_pool = jax.random.normal(kk, (nb, kvh, bs, hd), jnp.bfloat16)
+    v_pool = jax.random.normal(kv_, (nb, kvh, bs, hd), jnp.bfloat16)
 
-    # --- row 2: kernel parity at equal logits (small: interpreter) --
-    par_kw = dict(max_len=64, prefill_buckets=(16,), kv_block_size=8)
-    par_prompts = [_prompt(50 + i, 12) for i in range(2)]
-    g_out = _gen_all(_engine(cfg, params, kv_impl="gather", **par_kw),
-                     par_prompts, 16)
-    k_eng = _engine(cfg, params, kv_impl="paged_flash", **par_kw)
-    k_resolved = k_eng._kv_impl
-    k_interp = k_eng._kv_interpret
-    k_out = _gen_all(k_eng, par_prompts, 16)
-    parity = {"tokens_equal":
-              [o["tokens"] for o in k_out] ==
-              [o["tokens"] for o in g_out],
-              "impl": k_resolved, "interpret": bool(k_interp)}
-    print(f"# kernel_parity: {json.dumps(parity)}", file=sys.stderr)
-    assert parity["tokens_equal"], "kernel diverged from gather"
+    def chained(fn):
+        """``reps`` calls in one program, each query depending on the
+        call before it, so that none is hoisted or overlapped."""
+        @jax.jit
+        def run(q, k_pool, v_pool, tables, lengths):
+            def body(_, q):
+                o = fn(q, k_pool, v_pool, tables, lengths,
+                       interpret=a.rehearse)
+                return (q.astype(jnp.float32) + 1e-6 * o).astype(q.dtype)
+            return jax.lax.fori_loop(0, a.reps, body, q)
+        return run
 
-    # --- row 3: tp=2 paged prefix reuse ------------------------------
-    from jax.sharding import Mesh
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tensor",))
-    tp_kw = dict(max_len=512, prefill_buckets=(64, 256),
-                 kv_block_size=16, mesh=mesh)
-    shared = _prompt(90, 192)
-    reqs = [shared + _prompt(91 + i, 8) for i in range(3)]
-
-    def tp_run(prefix_cache):
-        eng = _engine(cfg, params, kv_impl="gather",
-                      prefix_cache=prefix_cache, **tp_kw)
-        assert eng._paged, "TP engine must run paged"
-
-        async def go():
-            if prefix_cache:
-                await eng.generate(shared, max_new_tokens=4)
-            outs = []
-            for r in reqs:        # serial: TTFT unpolluted by queueing
-                outs.append(await eng.generate(r, max_new_tokens=16))
-            stats = eng.stats
-            await eng.stop()
-            return outs, stats
-        return asyncio.run(go())
-
-    cold_outs, _ = tp_run(False)
-    warm_outs, warm_stats = tp_run(True)
-    tp_prefix = {
-        "ttft_ms_cold": round(statistics.median(
-            o["ttft_s"] for o in cold_outs) * 1000.0, 2),
-        "ttft_ms_hit": round(statistics.median(
-            o["ttft_s"] for o in warm_outs) * 1000.0, 2),
-        "hit_tokens": int(warm_stats["prefix_hit_tokens"]),
-        "tokens_equal": [o["tokens"] for o in warm_outs] ==
-                        [o["tokens"] for o in cold_outs],
-        "tp": 2}
-    print(f"# tp_prefix: {json.dumps(tp_prefix)}", file=sys.stderr)
-    assert tp_prefix["hit_tokens"] > 0
-    assert tp_prefix["tokens_equal"]
-
-    caveat = None
-    if kvcache.resolve_attn_impl("auto") == "gather":
-        caveat = ("CPU host: auto resolves to the gather view, so the "
-                  "decode A/B is gather-vs-gather and the fused-kernel "
-                  "row is PARITY evidence only (pallas interpreter is "
-                  "not a timing proxy). Re-run unchanged on a TPU box "
-                  "for the real kernel TPOT.")
-    doc = {"decode": decode, "kernel_parity": parity,
-           "tp_prefix": tp_prefix, "device": device,
-           "model": "tiny 64d/2L fp32", "caveat": caveat}
-    print(json.dumps(doc, indent=1))
-
-    for path, key, row in (
-            ("SERVE_BENCH.json", "paged_attn", doc),
-            ("LONGCTX_BENCH.json", "paged_attn",
-             {"prompt_tokens": args.long_prompt,
-              "decode_tpot_ms_gather": base["tpot_ms"],
-              "decode_tpot_ms_auto": auto["tpot_ms"],
-              "auto_resolved": auto["resolved"],
-              "kernel_tokens_equal": parity["tokens_equal"],
-              "tp_prefix_hit_ttft_ms": tp_prefix["ttft_ms_hit"],
-              "tp_prefix_cold_ttft_ms": tp_prefix["ttft_ms_cold"],
-              "device": device, "caveat": caveat})):
-        try:
-            with open(path) as f:
-                bench = json.load(f)
-        except FileNotFoundError:
-            bench = {}
-        bench[key] = row
-        with open(path, "w") as f:
-            json.dump(bench, f, indent=1)
-            f.write("\n")
-        print(f"# wrote {path} {key} key", file=sys.stderr)
+    runs = {name: chained(m.paged_attention) for name, m in mods.items()}
+    rows = []
+    for ctx in (64, 256, 1024, 4096) if not a.rehearse else (16, 256):
+        for live in (8, 32) if not a.rehearse else (1, 4):
+            lengths = np.ones((slots,), np.int32)
+            lengths[:live] = ctx
+            tables = np.zeros((slots, w), np.int32)     # trash rows
+            tables[:live] = rng.integers(1, nb, (live, w))
+            tb, ln = jnp.asarray(tables), jnp.asarray(lengths)
+            need = kernels.paged_decode_bytes(
+                int(lengths.sum()), slots, kvh, g, hd)
+            row = {"ctx": ctx, "live_slots": live, "required_bytes": need,
+                   "fetched_positions": int(
+                       new.fetched_positions(lengths, bs).sum())}
+            outs = {}
+            for name, run in runs.items():
+                jax.block_until_ready(run(q, k_pool, v_pool, tb, ln))
+                ts = []
+                for _ in range(a.rounds):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(q, k_pool, v_pool, tb, ln))
+                    ts.append((time.perf_counter() - t0) / a.reps)
+                t = statistics.median(ts)
+                row[f"{name}_us"] = 1e6 * t
+                row[f"{name}_roofline_pct"] = 100.0 * need / hbm / t
+                outs[name] = np.asarray(mods[name].paged_attention(
+                    q, k_pool, v_pool, tb, ln, interpret=a.rehearse))
+            ref = np.asarray(new.paged_attention_reference(
+                q, k_pool, v_pool, tb, ln))
+            for name, o in outs.items():
+                row[f"{name}_vs_reference"] = float(
+                    np.linalg.norm(o - ref) / np.linalg.norm(ref))
+            if "old" in outs:
+                row["speedup"] = row["old_us"] / row["new_us"]
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    doc = {"device": device, "shape": shp, "reps": a.reps,
+           "chunk_blocks": new.chunk_blocks(kvh, bs, hd, 2), "rows": rows}
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    with open(a.out, "w") as f:
+        json.dump(doc, f, indent=1)
+    print(json.dumps({"ok": True, "device": device, "rows": len(rows)}))
     return 0
 
 

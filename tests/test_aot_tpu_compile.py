@@ -61,8 +61,8 @@ def _flash_bwd(q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-def _paged_case(kv_heads, group):
-    slots, blocks, block, width = 8, 2305, 16, 256      # 8 x 4096 tokens
+def _paged_case(kv_heads, group, slots=8, blocks=2305):
+    block, width = 16, 256          # 8 x 4096 tokens unless told otherwise
     return pa.paged_attention, (
         ((slots, kv_heads, group, HD), jnp.bfloat16),
         ((blocks, kv_heads, block, HD), jnp.bfloat16),
@@ -89,6 +89,11 @@ CASES = {
                         {"paged_decode"}),
     "paged_decode_g4": (*_paged_case(kv_heads=8, group=4),   # Llama-3-8B
                         {"paged_decode"}),
+    # the cell serve-chat-open as the chip runs it: Mistral-7B-v0.3,
+    # 32 slots over the auto-sized pool of 5,882 blocks
+    "paged_decode_chat_cell": (
+        *_paged_case(kv_heads=8, group=4, slots=32, blocks=5882),
+        {"paged_decode"}),
 }
 
 

@@ -1,8 +1,10 @@
 """Paged-attention decode kernel (ops/pallas/paged_attention.py) and
 its serving integration: kernel-vs-gather parity (allclose on random
-values, BITWISE on integer constructions), COW-forked tables diverging
-mid-decode, tensor-parallel paged engines, chunk-grid-aligned prefix
-hits, and the paged_attn_impl / paged_attn_interpret Config knobs.
+values, BITWISE on integer constructions), every edge of the walk over
+a slot's live blocks (chunks of C pool blocks, idle slots, a poisoned
+pool, the exported fetch rule), COW-forked tables diverging mid-decode,
+tensor-parallel paged engines, chunk-grid-aligned prefix hits, and the
+paged_attn_impl / paged_attn_interpret Config knobs.
 
 All kernel tests run interpret=True — tier-1 (JAX_PLATFORMS=cpu)
 exercises the real table walk / masking / online-softmax logic through
@@ -54,7 +56,7 @@ def _tp_mesh(size):
     return Mesh(np.asarray(jax.devices()[:size]), ("tensor",))
 
 
-def _rand_case(seed, *, b, w, bs, kvh, g, hd, nb):
+def _rand_case(seed, *, b, w, bs, kvh, g, hd, nb, dtype=jnp.float32):
     """Random q/pool + disjoint per-slot block tables."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, kvh, g, hd)).astype(np.float32))
@@ -64,7 +66,12 @@ def _rand_case(seed, *, b, w, bs, kvh, g, hd, nb):
                     .astype(np.float32))
     tables = jnp.asarray(
         (1 + np.arange(b * w)).reshape(b, w).astype(np.int32))
-    return q, k, v, tables
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), tables
+
+
+# one compile a shape: the walk's trip count is a run-time value, so
+# every length below reuses the program of its shape
+_walk = jax.jit(functools.partial(pa.paged_attention, interpret=True))
 
 
 # --- kernel unit (interpret mode) -------------------------------------
@@ -164,6 +171,186 @@ def test_kernel_cow_forked_tables_diverge_mid_decode():
         q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(forked),
         lengths))
     np.testing.assert_allclose(after, want, rtol=2e-6, atol=2e-6)
+
+
+# --- the walk: a slot's live blocks, C pool blocks a fetch -------------
+
+# block 16 x 64-wide heads: chunk_blocks gives C = 8, 128 positions a
+# chunk; a table of 20 blocks is two whole chunks and a half one
+_BS, _W, _HD = 16, 20, 64
+_C = pa.chunk_blocks(2, _BS, _HD, 4)
+_EDGES = {"one": 1, "block": _BS, "block_plus_1": _BS + 1,
+          "chunk": _C * _BS, "chunk_plus_1": _C * _BS + 1,
+          "two_chunks": 2 * _C * _BS, "full_table": _W * _BS}
+
+
+def test_chunk_rule_reads_the_pool_shape_only():
+    """C from the pool's shape and dtype: about 128 positions a chunk,
+    the four buffers (K and V, two each) within 4 MB, never under one
+    block."""
+    assert _C == 8
+    assert pa.chunk_blocks(8, 16, 128, 2) == 8       # the chat cell: 1 MB
+    assert pa.chunk_blocks(32, 16, 128, 2) == 8      # MHA 7B: 4 MB
+    assert pa.chunk_blocks(32, 16, 128, 4) == 4      # f32 cache: capped
+    assert pa.chunk_blocks(8, 8, 128, 2) == 16
+    assert pa.chunk_blocks(8, 32, 128, 2) == 4
+    assert pa.chunk_blocks(8, 256, 128, 2) == 1
+    assert pa.chunk_blocks(64, 128, 256, 4) == 1     # never zero
+    for kvh, bs, hd, size in ((8, 16, 128, 2), (32, 16, 128, 4),
+                              (8, 8, 64, 4), (32, 32, 128, 2)):
+        c = pa.chunk_blocks(kvh, bs, hd, size)
+        assert 4 * c * kvh * bs * hd * size <= pa.BUFFER_BYTES
+
+
+@pytest.mark.parametrize("edge", sorted(_EDGES))
+def test_walk_length_edges(edge):
+    """A length on each edge of the walk (one position, a whole block,
+    one past it, a whole chunk, one past it, two chunks, the full
+    table) beside a mid-chunk slot: the kernel agrees with the
+    gather-then-softmax reference to f32 rounding."""
+    assert _W > 2 * _C and _W % _C      # the table ends inside a chunk
+    q, k, v, tables = _rand_case(10, b=2, w=_W, bs=_BS, kvh=2, g=2,
+                                 hd=_HD, nb=1 + 2 * _W)
+    lengths = jnp.asarray([_EDGES[edge], 5 * _BS + 3], jnp.int32)
+    got = _walk(q, k, v, tables, lengths)
+    want = pa.paged_attention_reference(q, k, v, tables, lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_walk_idle_slots_beside_full_ones():
+    """Idle slots as the engine presents them (an all-trash table row,
+    length 1 + step) between slots that fill the table: each row is
+    its own walk, and an idle row's value is the trash block's first
+    positions, nothing of its neighbours."""
+    b = 5
+    q, k, v, tables = _rand_case(11, b=b, w=_W, bs=_BS, kvh=2, g=2,
+                                 hd=_HD, nb=1 + b * _W)
+    tables = np.asarray(tables).copy()
+    tables[[1, 3, 4]] = kc.TRASH
+    tables = jnp.asarray(tables)
+    lengths = jnp.asarray([_W * _BS, 1, _W * _BS, 4, 1], jnp.int32)
+    got = np.asarray(_walk(q, k, v, tables, lengths))
+    want = np.asarray(
+        pa.paged_attention_reference(q, k, v, tables, lengths))
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    # one position: the softmax weight is 1 and the value is v's row
+    assert np.array_equal(
+        got[1], np.broadcast_to(np.asarray(v)[kc.TRASH, :, :1],
+                                got[1].shape))
+
+
+@pytest.mark.parametrize("kvh,g", [(8, 4), (32, 1), (2, 1)])
+def test_walk_head_layouts(kvh, g):
+    """GQA as the chat cell has it (8 x 4), MHA (32 x 1) and a shard of
+    a tensor-parallel engine (2 x 1), bf16 pool and queries: q and k
+    enter the score product as stored, and the result is the f32
+    reference's on the same bf16 values."""
+    bs, w, hd = 16, 10, 128
+    assert pa.chunk_blocks(kvh, bs, hd, 2) == 8
+    q, k, v, tables = _rand_case(12, b=3, w=w, bs=bs, kvh=kvh, g=g,
+                                 hd=hd, nb=1 + 3 * w, dtype=jnp.bfloat16)
+    lengths = jnp.asarray([1, 8 * bs + 1, w * bs], jnp.int32)
+    got = _walk(q, k, v, tables, lengths)
+    assert got.dtype == jnp.float32 and got.shape == q.shape
+    want = pa.paged_attention_reference(q, k, v, tables, lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("bs,dtype", [(8, "float32"), (32, "float32"),
+                                      (16, "bfloat16"), (128, "float32")])
+def test_walk_block_sizes_and_pool_dtypes(bs, dtype):
+    """Block sizes other than 16 change C (16, 4, 8, 1 blocks a chunk)
+    and an f32 pool changes the score product's dtype; the walk is the
+    same."""
+    hd, w = 64, 160 // bs * 2 + 1               # two chunks and a block
+    c = pa.chunk_blocks(2, bs, hd, jnp.dtype(dtype).itemsize)
+    assert c == max(1, 128 // bs)
+    q, k, v, tables = _rand_case(13, b=3, w=w, bs=bs, kvh=2, g=2,
+                                 hd=hd, nb=1 + 3 * w,
+                                 dtype=jnp.dtype(dtype))
+    lengths = jnp.asarray([c * bs + 1, 2 * c * bs, w * bs], jnp.int32)
+    got = _walk(q, k, v, tables, lengths)
+    want = pa.paged_attention_reference(q, k, v, tables, lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_walk_mixed_dtypes_upcast_to_f32():
+    """bf16 queries over an f32 pool (and the reverse): both operands
+    of the score product are widened, never narrowed."""
+    q, k, v, tables = _rand_case(14, b=2, w=_W, bs=_BS, kvh=2, g=2,
+                                 hd=_HD, nb=1 + 2 * _W)
+    lengths = jnp.asarray([3 * _BS + 5, _W * _BS], jnp.int32)
+    for qd, pd in ((jnp.bfloat16, jnp.float32),
+                   (jnp.float32, jnp.bfloat16)):
+        qq, kk, vv = q.astype(qd), k.astype(pd), v.astype(pd)
+        got = _walk(qq, kk, vv, tables, lengths)
+        want = pa.paged_attention_reference(qq, kk, vv, tables, lengths)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("edge", ["block_plus_1", "chunk", "chunk_plus_1",
+                                  "full_table"])
+def test_walk_poisoned_pool_is_bit_equal(edge):
+    """Every block a slot does not own, and every position past its
+    length inside its last block, filled with NaN: the output is
+    BIT-EQUAL to the clean pool's. Nothing past the live context
+    reaches the result, not even as 0 x NaN."""
+    b = 3
+    q, k, v, tables = _rand_case(15, b=b, w=_W, bs=_BS, kvh=2, g=2,
+                                 hd=_HD, nb=1 + b * _W)
+    lens = [_EDGES[edge], 1, 3 * _BS - 1]
+    tables = np.asarray(tables).copy()
+    tables[1] = kc.TRASH                        # an idle slot
+    own = np.zeros((k.shape[0], _BS), bool)     # [block, position]
+    for row, n in zip(tables, lens):
+        for j in range(-(-n // _BS)):
+            own[row[j], :min(_BS, n - j * _BS)] = True
+    assert not own.all() and own[kc.TRASH, 0] and not own[kc.TRASH, 1]
+    poison = np.where(own[:, None, :, None], 0.0, np.nan)
+    kp, vp = (jnp.asarray(np.asarray(x) + poison.astype(np.float32))
+              for x in (k, v))
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    clean = np.asarray(_walk(q, k, v, tables, lengths))
+    dirty = np.asarray(_walk(q, kp, vp, tables, lengths))
+    assert np.isfinite(clean).all()
+    assert np.array_equal(clean, dirty)
+
+
+@pytest.mark.parametrize("length", [0, 1, 16, 17, 128, 129, 300, 320])
+def test_fetch_rule_counts_the_blocks_the_walk_names(length):
+    """``fetched_positions`` (what the engine's llm_decode_kv_fetch_
+    tokens sums) against the walk itself: with equal scores and block
+    j's values the indicator of column j, the output's non-zero
+    columns ARE the blocks the walk attended, and their weights the
+    positions it took from each."""
+    bs, w, kvh, g, hd = _BS, _W, 2, 2, 128
+    nb = 1 + w
+    q = jnp.ones((1, kvh, g, hd), jnp.float32)
+    k = jnp.zeros((nb, kvh, bs, hd), jnp.float32)
+    v = jnp.broadcast_to(jnp.eye(nb, hd)[:, None, None, :],
+                         (nb, kvh, bs, hd))
+    tables = jnp.asarray(1 + np.arange(w)[None], jnp.int32)
+    got = np.asarray(_walk(q, k, v, tables,
+                           jnp.asarray([length], jnp.int32)))[0, 0, 0]
+    if length == 0:
+        # the empty-row guard: one block walked, nothing attended
+        assert not got.any() and pa.fetched_positions(0, bs) == bs
+        return
+    n = length
+    named = np.flatnonzero(got)
+    assert list(named) == list(range(1, 1 + -(-n // bs)))
+    assert pa.fetched_positions(length, bs) == len(named) * bs
+    np.testing.assert_allclose(
+        got[named] * n,
+        [min(bs, n - j * bs) for j in range(len(named))], rtol=1e-6)
+    # the rule on arrays, as the engine calls it
+    assert np.array_equal(
+        pa.fetched_positions(np.asarray([[length, length + bs]]), bs),
+        [[len(named) * bs, (len(named) + 1) * bs]])
 
 
 # --- impl resolution + Config knobs -----------------------------------
@@ -268,6 +455,40 @@ def test_engine_kernel_impl_matches_gather_impl(tiny_model):
     ctx1 = sum(reg["llm_decode_ctx_tokens"]._sums.values())
     assert ctx1 - ctx0 == sum(
         len(p) + i for p in prompts for i in range(1, 8))
+
+
+def test_engine_counts_what_the_walk_fetched(tiny_model):
+    """llm_decode_kv_fetch_tokens: the kernel's fetch rule summed over
+    ALL slots (idle ones are walked too, at length 1 + step) and a
+    block's steps, observed beside llm_decode_ctx_tokens. One request
+    alone in three slots: output token i >= 1 of a P-token prompt
+    walks ceil((P + i) / block) blocks and each idle slot one. The
+    gather impl walks nothing and counts nothing."""
+    from ray_tpu.util import metrics as M
+    cfg, params = tiny_model
+    prompt, new, bs, slots = _prompt(130, 13), 9, 8, 3
+
+    async def gen(impl):
+        eng = LLMEngine(cfg, params, max_slots=slots, max_len=32,
+                        prefill_buckets=(16,), cache_dtype="float32",
+                        kv_block_size=bs, prefix_cache=False,
+                        kv_impl=impl)
+        out = await eng.generate(prompt, max_new_tokens=new)
+        await eng.stop()
+        return out
+
+    from ray_tpu.llm.engine import engine_metrics
+    hist = engine_metrics()["kv_fetch_tokens"]
+    assert hist is M._REGISTRY["llm_decode_kv_fetch_tokens"]
+    before = sum(hist._sums.values())
+    asyncio.run(gen("gather"))
+    assert sum(hist._sums.values()) == before
+    asyncio.run(gen("paged_flash"))
+    want = sum(pa.fetched_positions(len(prompt) + i, bs)
+               + (slots - 1) * bs for i in range(1, new))
+    assert sum(hist._sums.values()) - before == want
+    # 13 + i crosses a block edge at 17 and again at 25: not a constant
+    assert want > (new - 1) * (2 * bs + (slots - 1) * bs)
 
 
 # --- tensor-parallel paged engines ------------------------------------
