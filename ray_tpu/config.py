@@ -155,37 +155,25 @@ class Config:
     testing_channel_failure: str = ""
 
     # --- paged KV cache (llm/kvcache.py) ---
-    # Token-block size of the engine's paged KV cache. The serving
-    # default: fixed-size blocks from a preallocated pool, per-request
-    # block tables, ref-counted prefix reuse for shared system
-    # prompts — tensor-parallel engines included (the pool shards its
-    # kv-head dim over the mesh). 0 selects the legacy monolithic slot
-    # cache (bucketed doubling growth). The effective size is
-    # gcd-adjusted to divide every prefill bucket and max_len.
+    # Token-block size of the engine's KV cache, the paged pool:
+    # fixed-size blocks from a preallocated pool, per-request block
+    # tables, ref-counted prefix reuse for shared system prompts —
+    # tensor-parallel engines included (the pool shards its kv-head
+    # dim over the mesh). At least 1 (the engine refuses less); the
+    # effective size is gcd-adjusted to divide every prefill bucket
+    # and max_len. The decode attention over the tables is not a
+    # knob: the pallas kernel on a TPU, the gather view elsewhere
+    # (kvcache.resolve_attn_impl).
     kvcache_block_size: int = 16
     # Pool size in blocks (0 = auto: worst case — every slot at
     # max_len — plus one chain of prefix-cache headroom, capped at
-    # half the free HBM when the devmon gauges know it).
+    # a quarter of the free HBM when the devmon gauges know it).
     kvcache_pool_blocks: int = 0
     # Prefix reuse: hash-chained full prompt blocks enter a cached
     # index at request finish; a later request sharing the prefix
     # adopts those blocks ref-counted and prefills only its suffix.
     # Off: blocks free immediately at request finish.
     kvcache_prefix_cache: bool = True
-    # Paged decode attention impl: "paged_flash" walks each slot's
-    # block table directly in the pallas kernel
-    # (ops/pallas/paged_attention.py — no gathered (slots, max_len)
-    # view, no O(slots x max_len x layers) HBM copy per token);
-    # "gather" materializes the view per layer (the debug/parity
-    # path); "auto" = paged_flash on a real TPU backend, gather
-    # elsewhere. Engines also take this per-instance via
-    # LLMEngine(kv_impl=...).
-    paged_attn_impl: str = "auto"
-    # Force the pallas interpreter for the paged-flash kernel (it is
-    # forced automatically off-TPU so kv_impl="paged_flash" still runs
-    # the real kernel logic under JAX_PLATFORMS=cpu; the knob exists
-    # to debug kernel/compiler divergence ON a TPU).
-    paged_attn_interpret: bool = False
 
     # --- speculative decoding (llm/spec.py) ---
     # Draft-and-verify generation in the paged engine (speculative

@@ -9,9 +9,9 @@ rebuilt TPU-native:
   granularity (continuous batching — no waiting for the batch to drain),
 - every decode step is ONE jitted call over all slots (static shapes:
   the MXU sees the same batched matmuls every step, zero recompiles),
-- prompts prefill into a shared static KV cache through shape buckets
-  (one compile per bucket), admitted before each decode step for low
-  time-to-first-token.
+- prompts prefill through shape buckets (one compile per bucket) into
+  the ONE KV cache there is, the paged block pool of llm/kvcache.py,
+  admitted before each decode step for low time-to-first-token.
 
 The engine is asyncio-native so it drops straight into a Serve replica;
 device steps run on an executor thread to keep the event loop live.
@@ -108,8 +108,8 @@ def engine_metrics() -> dict:
 
     HBM attribution (the engine half of util/devmon.py's device plane):
 
-      llm_kv_cache_bytes           live KV cache bytes on device
-      llm_kv_cache_headroom_bytes  growth left before max_len capacity
+      llm_kv_cache_bytes           KV bytes of the pool's live blocks
+      llm_kv_cache_headroom_bytes  KV bytes of the pool's free blocks
     """
     from ray_tpu.util import metrics as m
     seconds = (.0001, .00025, .0005, .001, .0025, .005, .01, .025, .05,
@@ -180,12 +180,12 @@ def engine_metrics() -> dict:
             boundaries=(1, 2, 4, 8, 16, 32, 64, 128, 256)),
         "kv_bytes": m.Gauge(
             "llm_kv_cache_bytes",
-            "Bytes of the engine's static KV cache currently on device"),
+            "Bytes of the KV pool's live blocks (referenced by requests "
+            "or held by the prefix index)"),
         "kv_headroom": m.Gauge(
             "llm_kv_cache_headroom_bytes",
-            "Bytes of bucketed KV growth left before the cache reaches "
-            "its max_len capacity (0 = fully grown; watch next to "
-            "device_hbm_used_bytes for OOM creep)"),
+            "Bytes of the KV pool's free blocks (0 = admits park until "
+            "a request finishes or a cached chain is evicted)"),
     }
 
 
@@ -220,7 +220,7 @@ class _Request:
     # KV computed by a remote prefill engine (disaggregated serving):
     # {"k","v": (layers, bucket, kvh, hd) numpy, "logits": (vocab,)}
     prefilled: Optional[dict] = None
-    # paged-KV state (engine paged mode): engine-unique sequence id,
+    # KV-pool state: engine-unique sequence id,
     # the block allocation handed out at admission, and the prompt
     # tokens served from cached prefix blocks (stamped on the
     # terminal trace span and surfaced in the result)
@@ -249,11 +249,11 @@ class LLMEngine:
                  kv_block_size: Optional[int] = None,
                  kv_pool_blocks: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
-                 kv_impl: Optional[str] = None,
+                 kv_impl: str = "auto",
                  spec: Optional[bool] = None,
                  detokenize: Optional[Callable[[List[int]], str]] = None):
         """With ``mesh``, the engine runs TENSOR-PARALLEL: params shard
-        per lm.serve_param_specs (Megatron layout), the KV cache shards
+        per lm.serve_param_specs (Megatron layout), the KV pool shards
         its kv-head dim, and every prefill/decode jit runs SPMD over the
         mesh with GSPMD inserting the two psums per layer. This is how a
         model larger than one chip's HBM serves (reference:
@@ -293,15 +293,14 @@ class LLMEngine:
         self.buckets = tuple(sorted(b for b in prefill_buckets
                                     if b <= max_len)) or (max_len,)
         self.detokenize = detokenize
-        # Paged KV (llm/kvcache.py) is the default serving cache:
-        # fixed-size token blocks from a preallocated pool, per-request
-        # block tables, and prefix reuse for shared system prompts —
+        # The KV cache is the paged pool of llm/kvcache.py: fixed-size
+        # token blocks from a preallocated pool, per-request block
+        # tables, and prefix reuse for shared system prompts —
         # tensor-parallel engines included (the pool shards its kv-head
         # dim over the mesh; the block-index ops and the decode
         # attention are head-local, so tables stay replicated and no
-        # collective is added). kv_block_size=0 selects the legacy
-        # MONOLITHIC cache (bucketed doubling growth). None reads the
-        # Config knobs (kvcache_block_size etc.).
+        # collective is added). None reads the Config knobs
+        # (kvcache_block_size etc.).
         from ray_tpu.config import get_config
         _cfg = get_config()
         if kv_block_size is None:
@@ -311,15 +310,16 @@ class LLMEngine:
         if prefix_cache is None:
             prefix_cache = bool(getattr(_cfg, "kvcache_prefix_cache",
                                         True))
-        if kv_impl is None:
-            kv_impl = str(getattr(_cfg, "paged_attn_impl", "auto"))
+        if kv_block_size < 1:
+            raise ValueError(
+                f"kv_block_size must be >= 1, got {kv_block_size}: the "
+                "paged pool is the engine's only KV cache and its blocks "
+                "need a size")
         if spec is None:
             spec = bool(getattr(_cfg, "spec_decode", False))
-        self._paged = kv_block_size > 0
         # Speculative decoding (llm/spec.py): draft-and-verify rides
-        # the block-table verify forward, so it requires paged mode;
-        # on the monolithic cache the knob is ignored.
-        self._spec = bool(spec) and self._paged
+        # the block-table verify forward
+        self._spec = bool(spec)
         self._spec_k = max(1, int(getattr(_cfg, "spec_draft_tokens", 4)))
         self._spec_ngram = max(1, int(getattr(_cfg, "spec_ngram_max", 3)))
         self._spec_window = max(1, int(getattr(_cfg,
@@ -327,64 +327,48 @@ class LLMEngine:
         self._spec_buckets = specdec.width_buckets(self._spec_k)
         self._specm = specdec.spec_metrics() if self._spec else None
         self._kvm = kvcache.kvcache_metrics()
-        if self._paged:
-            # decode attention impl: the fused block-table kernel
-            # (paged_flash) vs the materialized gather view; "auto"
-            # resolves by backend. Off-TPU the kernel runs through the
-            # pallas interpreter — tier-1 exercises the real table
-            # walk, not a shadow path.
-            self._kv_impl = kvcache.resolve_attn_impl(kv_impl)
-            self._kv_interpret = bool(
-                getattr(_cfg, "paged_attn_interpret", False)) or (
-                    self._kv_impl == "paged_flash"
-                    and self._device["platform"] != "tpu")
-            # effective block size must divide every prefill bucket
-            # and max_len (prefill writes land block-aligned): shrink
-            # to the gcd instead of erroring on small test buckets
-            b = kv_block_size
-            for v in (*self.buckets, max_len):
-                b = math.gcd(b, v)
-            self._block = max(1, b)
-            self._table_w = max_len // self._block
-            per_tok = (cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
-                       * 2 * jnp.dtype(cache_dtype).itemsize)
-            # the pool shards its kv heads over the tensor axis
-            tp = mesh.shape[tensor_axis] if mesh is not None else 1
-            nb = kvcache.auto_pool_blocks(
-                max_slots, self._table_w, per_tok * self._block // tp,
-                kv_pool_blocks)
-            self._cache_len = max_len     # no growth: tables span it
-            self._pool = kvcache.init_pool(cfg, nb, self._block,
-                                           jnp.dtype(cache_dtype))
-            if mesh is not None:
-                # pool shards its kv-head dim (Megatron layout, same
-                # axis as the monolithic cache); block ids index dim 1,
-                # orthogonal to the shard, so scatter/gather/copy jits
-                # run under GSPMD unchanged
-                from jax.sharding import NamedSharding, PartitionSpec \
-                    as P
-                s = NamedSharding(
-                    mesh, P(None, None, tensor_axis, None, None))
-                self._pool = {k: jax.device_put(v, s)
-                              for k, v in self._pool.items()}
-            self._kv = kvcache.KVBlockManager(
-                nb, self._block, table_width=self._table_w,
-                prefix_cache=prefix_cache, metrics=self._kvm)
-            self._tables = np.full((max_slots, self._table_w),
-                                   kvcache.TRASH, np.int32)
-            self._blocked: deque = deque()   # admits parked on pool
-            self._seq_counter = 0
-            self._cache = None
-        else:
-            # Bucketed KV growth (the dense-cache fallback): the cache
-            # starts at a small length and DOUBLES, up to max_len, only
-            # when an admitted request actually needs the room —
-            # max_len=8k costs 8k-sized HBM only once an 8k request
-            # arrives, and each growth step is one bounded recompile.
-            self._cache_len = min(max_len, max(1024, self.buckets[-1]))
-            self._cache = lm.init_cache(cfg, max_slots, self._cache_len,
-                                        dtype=jnp.dtype(cache_dtype),
-                                        mesh=mesh, axis=tensor_axis)
+        # decode attention: the fused block-table kernel (paged_flash)
+        # on a TPU, the materialized gather view elsewhere; the kwarg
+        # overrides the platform's choice (the kernel's reference in
+        # parity checks). Off a TPU the kernel runs through the pallas
+        # interpreter — tier-1 exercises the real table walk, not a
+        # shadow path.
+        self._kv_impl = kvcache.resolve_attn_impl(kv_impl)
+        self._kv_interpret = (self._kv_impl == "paged_flash"
+                              and self._device["platform"] != "tpu")
+        # effective block size must divide every prefill bucket
+        # and max_len (prefill writes land block-aligned): shrink
+        # to the gcd instead of erroring on small test buckets
+        b = kv_block_size
+        for v in (*self.buckets, max_len):
+            b = math.gcd(b, v)
+        self._block = b
+        self._table_w = max_len // self._block
+        per_tok = (cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+                   * 2 * jnp.dtype(cache_dtype).itemsize)
+        # the pool shards its kv heads over the tensor axis
+        tp = mesh.shape[tensor_axis] if mesh is not None else 1
+        nb = kvcache.auto_pool_blocks(
+            max_slots, self._table_w, per_tok * self._block // tp,
+            kv_pool_blocks)
+        self._pool = kvcache.init_pool(cfg, nb, self._block,
+                                       jnp.dtype(cache_dtype))
+        if mesh is not None:
+            # pool shards its kv-head dim (Megatron layout); block ids
+            # index dim 1, orthogonal to the shard, so
+            # scatter/gather/copy jits run under GSPMD unchanged
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            s = NamedSharding(
+                mesh, P(None, None, tensor_axis, None, None))
+            self._pool = {k: jax.device_put(v, s)
+                          for k, v in self._pool.items()}
+        self._kv = kvcache.KVBlockManager(
+            nb, self._block, table_width=self._table_w,
+            prefix_cache=prefix_cache, metrics=self._kvm)
+        self._tables = np.full((max_slots, self._table_w),
+                               kvcache.TRASH, np.int32)
+        self._blocked: deque = deque()   # admits parked on pool
+        self._seq_counter = 0
         self._slots: List[Optional[_Request]] = [None] * max_slots
         self._waiting: "asyncio.Queue[_Request]" = asyncio.Queue()
         self._rng = np.random.default_rng(seed)
@@ -427,35 +411,28 @@ class LLMEngine:
     def stats(self) -> dict:
         """Scalar engine counters (the per-phase distributions live in
         the metrics registry — see engine_metrics())."""
-        out = {"requests": self._requests,
-               "tokens_generated": self._tokens_generated,
-               "ttft_sum": self._ttft_sum,
-               "ttft_count": self._ttft_count,
-               "cache_len": self._cache_len,
-               "paged": self._paged,
-               "pid": os.getpid(),
-               "device": dict(self._device),
-               "prefill_impl": self._prefill_impl}
-        if self._paged:
-            out.update(block_size=self._block,
-                       pool_blocks=self._kv.num_blocks,
-                       blocks_used=self._kv.used_blocks(),
-                       blocks_cached=self._kv.cached_blocks(),
-                       blocks_free=self._kv.free_blocks(),
-                       prefix_hit_tokens=self._kv.hit_tokens_total,
-                       kv_impl=self._kv_impl,
-                       kv_interpret=self._kv_interpret,
-                       spec=self._spec)
-        return out
+        return {"requests": self._requests,
+                "tokens_generated": self._tokens_generated,
+                "ttft_sum": self._ttft_sum,
+                "ttft_count": self._ttft_count,
+                "pid": os.getpid(),
+                "device": dict(self._device),
+                "prefill_impl": self._prefill_impl,
+                "block_size": self._block,
+                "pool_blocks": self._kv.num_blocks,
+                "blocks_used": self._kv.used_blocks(),
+                "blocks_cached": self._kv.cached_blocks(),
+                "blocks_free": self._kv.free_blocks(),
+                "prefix_hit_tokens": self._kv.hit_tokens_total,
+                "kv_impl": self._kv_impl,
+                "kv_interpret": self._kv_interpret,
+                "spec": self._spec}
 
     def _kv_per_token_bytes(self) -> float:
         """Device bytes one KV position of one slot costs (both k and
         v, all layers) — the unit request-level HBM attribution is
         priced in."""
-        if self._paged:
-            return kvcache.pool_block_bytes(self._pool) / self._block
-        n = self._cache["k"].nbytes + self._cache["v"].nbytes
-        return n / float(self.max_slots * self._cache_len)
+        return kvcache.pool_block_bytes(self._pool) / self._block
 
     @contextlib.contextmanager
     def _phase(self, name: str):
@@ -470,8 +447,7 @@ class LLMEngine:
             self._gap_admit += ph.dur
         if ph.dur > SLOW_PHASE_S and name != "idle":
             active = sum(r is not None for r in self._slots)
-            waiting = self._waiting.qsize() + (
-                len(self._blocked) if self._paged else 0)
+            waiting = self._waiting.qsize() + len(self._blocked)
             events.record("engine", "slow_phase", phase=name,
                           dur=ph.dur, active=active, waiting=waiting,
                           pid=os.getpid())
@@ -480,49 +456,16 @@ class LLMEngine:
                 name, ph.dur, active, waiting)
 
     def _kv_account(self) -> None:
-        """Publish the engine's explicit KV HBM attribution. Paged:
-        live bytes = blocks referenced by live requests plus resident
+        """Publish the engine's explicit KV HBM attribution: live
+        bytes = blocks referenced by live requests plus resident
         prefix-cache blocks (the pool bounds HBM by LIVE tokens, the
-        vLLM property); headroom = free blocks. Monolithic: cache
-        bytes + the bucketed growth left before max_len capacity. The
-        gauges ride the worker's metrics push to the head next to
-        util/devmon.py's device_hbm_* series."""
-        if self._paged:
-            bb = kvcache.pool_block_bytes(self._pool)
-            live = self._kv.used_blocks() + self._kv.cached_blocks()
-            self._m["kv_bytes"].set(bb * live)
-            self._m["kv_headroom"].set(bb * self._kv.free_blocks())
-            return
-        cur = self._cache["k"].nbytes + self._cache["v"].nbytes
-        per_tok = self._kv_per_token_bytes()
-        headroom = per_tok * self.max_slots \
-            * (self.max_len - self._cache_len)
-        self._m["kv_bytes"].set(cur)
-        self._m["kv_headroom"].set(headroom)
-
-    def _grow_cache(self, need: int) -> None:
-        """Double the per-slot KV length (bucketed) until >= need,
-        capped at max_len; active slots' KV is preserved (zero-pad on
-        the length axis, resharded onto the mesh when tensor-parallel)."""
-        new_len = self._cache_len
-        while new_len < need:
-            new_len *= 2
-        new_len = min(new_len, self.max_len)
-        pad = new_len - self._cache_len
-        if pad <= 0:
-            return
-        jax, jnp = _jx()
-        c = self._cache
-        widths = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
-        k, v = jnp.pad(c["k"], widths), jnp.pad(c["v"], widths)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            s = NamedSharding(self.mesh,
-                              P(None, None, None, self.tensor_axis, None))
-            k, v = jax.device_put(k, s), jax.device_put(v, s)
-        self._cache = {"k": k, "v": v, "length": c["length"]}
-        self._cache_len = new_len
-        self._kv_account()
+        vLLM property); headroom = free blocks. The gauges ride the
+        worker's metrics push to the head next to util/devmon.py's
+        device_hbm_* series."""
+        bb = kvcache.pool_block_bytes(self._pool)
+        live = self._kv.used_blocks() + self._kv.cached_blocks()
+        self._m["kv_bytes"].set(bb * live)
+        self._m["kv_headroom"].set(bb * self._kv.free_blocks())
 
     # --- public API -----------------------------------------------------
 
@@ -633,9 +576,8 @@ class LLMEngine:
                      top_p=float(top_p), top_k=int(top_k), stop=stop,
                      prefilled=prefilled, deadline_ts=deadline_ts,
                      trace=tracing.current_context())
-        if self._paged:
-            self._seq_counter += 1
-            r.seq = self._seq_counter
+        self._seq_counter += 1
+        r.seq = self._seq_counter
         if self._spec:
             r.drafter = specdec.PromptLookupDrafter(
                 k=self._spec_k, ngram_max=self._spec_ngram,
@@ -647,9 +589,8 @@ class LLMEngine:
 
     def _result(self, r: _Request) -> dict:
         out = {"tokens": r.out,
-               "ttft_s": (r.first_token_at or 0) - r.submitted}
-        if self._paged:
-            out["prefix_hit_tokens"] = r.prefix_hit
+               "ttft_s": (r.first_token_at or 0) - r.submitted,
+               "prefix_hit_tokens": r.prefix_hit}
         if self.detokenize is not None:
             out["text"] = self.detokenize(r.out)
         return out
@@ -680,9 +621,9 @@ class LLMEngine:
 
     def _pop_candidate(self) -> Optional[_Request]:
         """Next admissible request: pool-parked admits first (FIFO —
-        paged mode re-tries them once blocks free up), then the
-        waiting queue. Deadline-expired candidates fail fast here."""
-        while self._paged and self._blocked:
+        re-tried once blocks free up), then the waiting queue.
+        Deadline-expired candidates fail fast here."""
+        while self._blocked:
             cand = self._blocked.popleft()
             if cand.deadline_ts is not None and \
                     time.time() > cand.deadline_ts:
@@ -714,7 +655,7 @@ class LLMEngine:
                     r = self._pop_candidate()
                     if r is None:
                         continue
-                    if self._paged and r.kv_alloc is None:
+                    if r.kv_alloc is None:
                         # full-horizon block reservation at admission:
                         # decode can then never fail mid-flight on pool
                         # pressure — overload parks the ADMIT instead
@@ -744,9 +685,9 @@ class LLMEngine:
                     except KVHandoffError as e:
                         # a dead/freed remote KV handle fails ITS request
                         # only — the shared loop and other slots live on
-                        # (resolution happens before any cache write, so
+                        # (resolution happens before any pool write, so
                         # no partial state was left behind; the slot is
-                        # passed so a paged table row set before the
+                        # passed so the table row set before the
                         # failure reverts to trash with the blocks)
                         self._fail(r, slot, e)
                         continue
@@ -774,7 +715,7 @@ class LLMEngine:
                           if r is not None]
                 if not active:
                     self._gap_from = None   # nobody is stalled by it
-                    if self._paged and self._blocked:
+                    if self._blocked:
                         # pool-parked admits with nothing running can
                         # only be waiting on eviction — re-try shortly
                         # instead of parking on the (possibly empty)
@@ -812,7 +753,7 @@ class LLMEngine:
                             budget = min(
                                 self._spec_k,
                                 r.max_new_tokens - len(r.out) - 1,
-                                self._cache_len - len(r.tokens)
+                                self.max_len - len(r.tokens)
                                 - len(r.out) - 1)
                             if budget < 1:
                                 continue
@@ -840,7 +781,7 @@ class LLMEngine:
                         r = self._slots[i]
                         block = min(block,
                                     r.max_new_tokens - len(r.out),
-                                    self._cache_len - len(r.tokens)
+                                    self.max_len - len(r.tokens)
                                     - len(r.out))
                     # pow2, rounded down
                     block = 1 << (max(1, block).bit_length() - 1)
@@ -869,7 +810,7 @@ class LLMEngine:
                                 + len(self._slots[i].out)
                                 for i in active)
                     + n * block * (block - 1) // 2)
-                if self._paged and self._kv_impl == "paged_flash":
+                if self._kv_impl == "paged_flash":
                     # what the kernel's walk fetched for them: every
                     # slot's live blocks at every step, an idle slot
                     # (length 1 + step) included
@@ -902,7 +843,7 @@ class LLMEngine:
             for i, r in enumerate(self._slots):
                 if r is not None:
                     self._fail(r, i, e)
-            while self._paged and self._blocked:
+            while self._blocked:
                 self._fail(self._blocked.popleft(), None, e)
             while not self._waiting.empty():
                 self._fail(self._waiting.get_nowait(), None, e)
@@ -942,17 +883,16 @@ class LLMEngine:
         w0, w1 = tracing.wall(t0), tracing.wall(t1)
         tracing.record_batch_span(
             "engine", "decode", member_traces, w0, w1, slots=slots,
-            kv_impl=self._kv_impl if self._paged else "monolithic",
-            **attrs)
+            kv_impl=self._kv_impl, **attrs)
         devmon.record_device_window("decode", w0, w1, trace=ex or "")
 
     def _admit_sync(self, slot: int, r: _Request) -> int:
         """Prefill entry (executor thread): binds the request's trace
         context for the duration of the admit so any XLA compile it
-        triggers (a cold shape bucket, a cache growth) is stamped with
-        the request's trace id — util/devmon.py's compile listener
-        reads the ambient context, and the span then rides this
-        request's `ray-tpu trace` waterfall as a dev:compile lane."""
+        triggers (a cold shape bucket) is stamped with the request's
+        trace id — util/devmon.py's compile listener reads the ambient
+        context, and the span then rides this request's `ray-tpu
+        trace` waterfall as a dev:compile lane."""
         if r.trace is None:
             return self._admit_impl(slot, r)
         tok = tracing.set_request_context(r.trace)
@@ -982,11 +922,14 @@ class LLMEngine:
         return arr
 
     def _admit_impl(self, slot: int, r: _Request) -> int:
-        """Prefill (executor thread): pad to bucket, fill cache slot
-        (monolithic) or scatter into the request's block table
-        (paged). Returns the first sampled token. Remotely-prefilled
-        requests skip the forward pass: their shipped KV is written
-        straight into the slot."""
+        """Prefill (executor thread): the scheduler already reserved
+        the block table (r.kv_alloc); write the prompt's KV through it
+        and return the first sampled token. Three paths: shipped-KV
+        handoff (disaggregated: the forward ran on the remote tier),
+        cold bucketed prefill (one lm.prefill forward padded to its
+        bucket, scatter), and prefix-hit / long-prompt chunked prefill
+        (gather cached prefix blocks, run lm.prefill_chunk on the
+        suffix only — the prefix's device time is ~eliminated)."""
         _, jnp = _jx()
         n = len(r.tokens)
         r.admitted_at = time.monotonic()
@@ -997,60 +940,54 @@ class LLMEngine:
                 "engine", "queue", r.trace, r.trace.span_id,
                 r.t_submit_wall,
                 r.t_submit_wall + (r.admitted_at - r.submitted))
-        if self._paged:
-            return self._admit_paged(slot, r)
-        # Bucketed growth runs HERE (executor thread): padding and
-        # re-uploading a multi-GB cache on the event loop would stall
-        # every in-flight stream. Admits and decode blocks are awaited
-        # one at a time by the loop, so cache mutation stays serialized.
-        need = n + r.max_new_tokens
-        pad_to = 0
-        if r.prefilled is not None:
-            # pd.py ships BLOCK-granular KV (transfer scales with the
-            # prompt); re-pad to a bucket multiple here so the donated
-            # write_prefill_to_cache keeps bucket-bounded compile
-            # variants instead of one per distinct block count
-            L = int(r.prefilled["k"].shape[1])
-            big = self.buckets[-1]
-            pad_to = (lm.bucket_for(self.buckets, L) if L <= big
-                      else -(-L // big) * big)
-            pad_to = min(pad_to, self.max_len)
-            need = max(need, pad_to)
-        if need > self._cache_len:
-            self._grow_cache(need)
+        table = r.kv_alloc["table"]
+        hit = r.prefix_hit
+        B = self._block
+        self._tables[slot] = table
         with self._phase("prefill.dispatch") as disp:
             if r.prefilled is not None:
                 # device TTFT for a disaggregated request is the
-                # handoff resolution + cache write on THIS engine (the
-                # prefill forward ran on the remote tier)
+                # handoff resolution + pool write on THIS engine
                 p = r.prefilled
                 r.prefilled = None      # free the host copy after write
                 take = self._take_handoff
-                kv_k = jnp.asarray(take(p["k"]))
-                kv_v = jnp.asarray(take(p["v"]))
-                r.handoff_bytes = int(kv_k.nbytes + kv_v.nbytes)
-                self._kvm["handoff_bytes"].inc(r.handoff_bytes)
-                padw = pad_to - kv_k.shape[1]
-                if padw > 0:
-                    widths = ((0, 0), (0, padw), (0, 0), (0, 0))
-                    kv_k = jnp.pad(kv_k, widths)
-                    kv_v = jnp.pad(kv_v, widths)
-                kv = {"k": kv_k, "v": kv_v}
+                k_np = np.asarray(take(p["k"]))
+                v_np = np.asarray(take(p["v"]))
                 logits, ran = take(p["logits"]), 0
-            elif n <= self.buckets[-1]:
+                r.handoff_bytes = int(k_np.nbytes + v_np.nbytes)
+                self._kvm["handoff_bytes"].inc(r.handoff_bytes)
+                acc_len = self._acc_len()
+                pad = acc_len - k_np.shape[1]
+                widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+                acc = {"k": jnp.asarray(np.pad(k_np, widths)),
+                       "v": jnp.asarray(np.pad(v_np, widths))}
+                # shared prefix blocks (a hit makes the shipped bytes
+                # for them redundant) and beyond-horizon slots write
+                # to trash
+                targets = table.copy()
+                targets[:hit // B] = kvcache.TRASH
+                self._pool = kvcache.scatter_table(
+                    self._pool, acc, jnp.asarray(targets))
+            elif hit == 0 and n <= self.buckets[-1]:
+                # cache-cold short prompt: one lm.prefill forward,
+                # padded only to its bucket; pad-garbage blocks
+                # redirect to trash via the table's unallocated tail
                 b = self._bucket_for(n)
                 padded = lm.pad_prompt(r.tokens, b)
                 logits, kv = lm.prefill(self.params, jnp.asarray(padded),
-                                        jnp.int32(n), self.cfg,
-                                        self._cache_len)
+                                        jnp.int32(n), self.cfg, b)
+                nb = b // B
+                phys = np.full((nb,), kvcache.TRASH, np.int32)
+                phys[:min(nb, self._table_w)] = table[:min(
+                    nb, self._table_w)]
+                self._pool = kvcache.scatter_bucket(
+                    self._pool, kv, jnp.asarray(phys), nb)
                 ran = n
             else:
-                logits, kv = self._chunked_prefill(r.tokens)
-                ran = n
-            self._cache = lm.write_prefill_to_cache(
-                self._cache, kv, slot, jnp.int32(n))
+                logits = self._prefill_into_blocks(r, table, hit)
+                ran = n - self._prefill_start(hit)
         return self._first_token(slot, r, disp, logits,
-                                 self._cache["k"], ran)
+                                 self._pool["k"], ran)
 
     def _first_token(self, slot: int, r: _Request, disp, logits,
                      written, ran: int) -> int:
@@ -1104,64 +1041,6 @@ class LLMEngine:
         chunk = self.buckets[-1]
         return (hit // chunk) * chunk
 
-    def _admit_paged(self, slot: int, r: _Request) -> int:
-        """Paged prefill: the scheduler already reserved the block
-        table (r.kv_alloc); write the prompt's KV through it. Three
-        paths: shipped-KV handoff (disaggregated), cold bucketed
-        prefill (one forward, scatter — bitwise-identical to the
-        monolithic path), and prefix-hit / long-prompt chunked prefill
-        (gather cached prefix blocks, run lm.prefill_chunk on the
-        suffix only — the prefix's device time is ~eliminated)."""
-        _, jnp = _jx()
-        n = len(r.tokens)
-        table = r.kv_alloc["table"]
-        hit = r.prefix_hit
-        B = self._block
-        self._tables[slot] = table
-        with self._phase("prefill.dispatch") as disp:
-            if r.prefilled is not None:
-                p = r.prefilled
-                r.prefilled = None
-                take = self._take_handoff
-                k_np = np.asarray(take(p["k"]))
-                v_np = np.asarray(take(p["v"]))
-                logits, ran = take(p["logits"]), 0
-                r.handoff_bytes = int(k_np.nbytes + v_np.nbytes)
-                self._kvm["handoff_bytes"].inc(r.handoff_bytes)
-                acc_len = self._acc_len()
-                pad = acc_len - k_np.shape[1]
-                widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-                acc = {"k": jnp.asarray(np.pad(k_np, widths)),
-                       "v": jnp.asarray(np.pad(v_np, widths))}
-                # shared prefix blocks (a hit makes the shipped bytes
-                # for them redundant) and beyond-horizon slots write
-                # to trash
-                targets = table.copy()
-                targets[:hit // B] = kvcache.TRASH
-                self._pool = kvcache.scatter_table(
-                    self._pool, acc, jnp.asarray(targets))
-            elif hit == 0 and n <= self.buckets[-1]:
-                # cache-cold short prompt: the SAME lm.prefill forward
-                # the monolithic engine runs (bitwise parity), padded
-                # only to its bucket; pad-garbage blocks redirect to
-                # trash via the table's unallocated tail
-                b = self._bucket_for(n)
-                padded = lm.pad_prompt(r.tokens, b)
-                logits, kv = lm.prefill(self.params, jnp.asarray(padded),
-                                        jnp.int32(n), self.cfg, b)
-                nb = b // B
-                phys = np.full((nb,), kvcache.TRASH, np.int32)
-                phys[:min(nb, self._table_w)] = table[:min(
-                    nb, self._table_w)]
-                self._pool = kvcache.scatter_bucket(
-                    self._pool, kv, jnp.asarray(phys), nb)
-                ran = n
-            else:
-                logits = self._prefill_into_blocks(r, table, hit)
-                ran = n - self._prefill_start(hit)
-        return self._first_token(slot, r, disp, logits,
-                                 self._pool["k"], ran)
-
     def _prefill_into_blocks(self, r: _Request, table: np.ndarray,
                              hit: int):
         """Prefix-hit (and long-prompt) prefill: gather the table's
@@ -1213,44 +1092,6 @@ class LLMEngine:
             "engine", "prefill", r.trace, r.trace.span_id, w0, w1,
             tokens=len(r.tokens))
 
-    def _chunked_prefill(self, tokens: List[int]):
-        """Prompts past the largest bucket stream through
-        lm.prefill_chunk in bucket-sized pieces, each attending to the
-        accumulated KV of the pieces before it. Returns (last-token
-        logits, {"k","v"} (layers, max_len, kvh, hd)) — the same shape
-        contract as lm.prefill, so the cache write is identical."""
-        jax, jnp = _jx()
-        cdt = self._cache["k"].dtype
-        chunk = self.buckets[-1]
-        # accumulator length is a BUCKET MULTIPLE >= the current cache
-        # length: a padded final chunk written at a chunk-multiple
-        # offset then never overruns it (dynamic_update_slice CLAMPS
-        # the start index on overrun, which would silently shift the
-        # chunk and corrupt earlier positions); sliced back to
-        # _cache_len before the cache write
-        acc_len = ((self._cache_len + chunk - 1) // chunk) * chunk
-        shape = (self.cfg.n_layers, acc_len, self.cfg.n_kv_heads,
-                 self.cfg.head_dim)
-        acc = {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            s = NamedSharding(self.mesh,
-                              P(None, None, self.tensor_axis, None))
-            acc = {k: jax.device_put(v, s) for k, v in acc.items()}
-        off = 0
-        logits = None
-        while off < len(tokens):
-            part = tokens[off:off + chunk]
-            b = self._bucket_for(len(part))
-            padded = lm.pad_prompt(part, b)
-            logits, acc = lm.prefill_chunk(
-                self.params, jnp.asarray(padded), jnp.int32(len(part)),
-                jnp.int32(off), acc, self.cfg)
-            off += len(part)
-        if acc_len > self._cache_len:
-            acc = {k: v[:, :self._cache_len] for k, v in acc.items()}
-        return logits, acc
-
     def _decode_sync(self, tokens: np.ndarray, temps: np.ndarray,
                      top_ps: np.ndarray, top_ks: np.ndarray,
                      block: int,
@@ -1290,28 +1131,21 @@ class LLMEngine:
             filters_on = bool((top_ps < 1.0).any() or (top_ks > 0).any())
             tp = jnp.asarray(top_ps) if filters_on else None
             tk = jnp.asarray(top_ks) if filters_on else None
-            if self._paged:
-                # per-slot write positions are host-derived (prompt +
-                # emitted - 1: the last emitted token's KV lands this
-                # step), matching the monolithic cache's device-side
-                # length counter by construction; empty slots write
-                # into the trash block
-                lengths = np.zeros((self.max_slots,), np.int32)
-                for i, r in enumerate(self._slots):
-                    if r is not None:
-                        lengths[i] = len(r.tokens) + len(r.out) - 1
-                out, self._pool = kvcache.paged_decode_steps(
-                    self.params, self._pool, jnp.asarray(self._tables),
-                    jnp.asarray(lengths), jnp.asarray(tokens),
-                    jnp.asarray(temps), key, self.cfg, block, tp, tk,
-                    impl=self._kv_impl, interpret=self._kv_interpret,
-                    mesh=self.mesh, axis=self.tensor_axis)
-                self._kvm["attn_steps"].inc(
-                    block, tags={"impl": self._kv_impl})
-            else:
-                out, self._cache = lm.decode_steps(
-                    self.params, self._cache, jnp.asarray(tokens),
-                    jnp.asarray(temps), key, self.cfg, block, tp, tk)
+            # per-slot write positions are host-derived (prompt +
+            # emitted - 1: the last emitted token's KV lands this
+            # step); empty slots write into the trash block
+            lengths = np.zeros((self.max_slots,), np.int32)
+            for i, r in enumerate(self._slots):
+                if r is not None:
+                    lengths[i] = len(r.tokens) + len(r.out) - 1
+            out, self._pool = kvcache.paged_decode_steps(
+                self.params, self._pool, jnp.asarray(self._tables),
+                jnp.asarray(lengths), jnp.asarray(tokens),
+                jnp.asarray(temps), key, self.cfg, block, tp, tk,
+                impl=self._kv_impl, interpret=self._kv_interpret,
+                mesh=self.mesh, axis=self.tensor_axis)
+            self._kvm["attn_steps"].inc(
+                block, tags={"impl": self._kv_impl})
         with self._phase("decode.readback") as back:
             out = np.asarray(out)
         self._dev_span = (disp.t0, back.t1)
@@ -1474,9 +1308,7 @@ class LLMEngine:
             self._specm["accept_rate"].set(r.spec_accepted / r.spec_drafted)
         if r.trace is None:
             return
-        extra = {}
-        if self._paged:
-            extra["prefix_hit_tokens"] = r.prefix_hit
+        extra = {"prefix_hit_tokens": r.prefix_hit}
         if r.handoff_bytes:
             extra["kv_handoff_bytes"] = r.handoff_bytes
         if r.spec_drafted:
@@ -1496,7 +1328,7 @@ class LLMEngine:
         follow-up conversation turn extends the same chain). The
         slot's table row reverts to trash so post-finish garbage
         writes can't land in reallocated blocks."""
-        if not self._paged or r.kv_alloc is None:
+        if r.kv_alloc is None:
             return
         # kv_written gates the prefix-cache insert: a request that
         # failed BEFORE its prefill scatter holds zero/stale blocks —

@@ -16,11 +16,10 @@ TPU-native on the engine's static-shape rules:
 - each request owns a BLOCK TABLE (fixed width ``max_len //
   block_size``) of physical block ids. Decode attends through it:
   the kernel walks the table's LIVE entries and fetches those blocks
-  itself, or (impl ``gather``) the table's blocks are gathered into
-  the attention view (bitwise-identical to the monolithic cache:
-  gathered values are the same bytes in the same order, and masked
-  tail positions contribute exact zeros); the new token's KV is
-  written back through the table either way;
+  itself, or (impl ``gather``, the kernel's reference) the table's
+  blocks are gathered into the attention view (the same bytes in
+  token order; masked tail positions contribute exact zeros); the new
+  token's KV is written back through the table either way;
 - a PREFIX CHAIN INDEX (hash-chained per full token block, the radix
   structure flattened into parent links) maps prompt prefixes to
   cached block chains: a request sharing a cached prefix adopts those
@@ -145,7 +144,7 @@ class BlockPoolExhausted(RuntimeError):
 class KVBlockManager:
     """Host-side accounting for one engine's block pool. Not
     thread-safe by itself — the engine serializes admits/frees on its
-    scheduler loop, matching the monolithic cache's discipline."""
+    scheduler loop."""
 
     def __init__(self, num_blocks: int, block_size: int, *,
                  table_width: int, prefix_cache: bool = True,
@@ -502,8 +501,7 @@ def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
     ``block_bytes`` is what one block costs PER DEVICE.
     The cap never shrinks below ONE full-horizon request
     (table_width blocks): a max_len-sized request must be servable —
-    serially — on any pool the engine auto-sizes, matching what the
-    monolithic cache guarantees."""
+    serially — on any pool the engine auto-sizes."""
     if configured:
         return max(2, int(configured))
     base = slots * table_width + table_width
@@ -653,15 +651,14 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
     """One decode step's (slots, vocab) f32 logits for every slot
-    against the paged pool. Runs lm.decode_logits_core — the SAME
-    transformer body as the monolithic cache — with block-table
-    write/attend plugged in.
+    against the paged pool: lm.decode_logits_core with the new token's
+    place in the pool worked out from the tables, and the attention
+    over the table plugged in.
 
-    impl='gather': the attention view is materialized per layer
-    (paged_attention.table_view) — the gathered view holds the
-    same bytes in the same order as the monolithic cache, so the
-    attention math (and therefore the sampled tokens) is bitwise
-    identical (pinned by tests/test_zz_kvcache.py parity tests).
+    impl='gather': the reference. The attention view is materialized
+    per layer (paged_attention.table_view: the table's blocks in
+    table order, so masked tail positions contribute exact zeros) and
+    attended by lm._gqa_attend_cached.
 
     impl='paged_flash': the pallas kernel walks each slot's LIVE
     table entries (``ceil(length / block_size)`` of them, a run-time
@@ -678,7 +675,7 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     collectives (the gather path needs nothing: GSPMD partitions the
     plain-jnp view fine)."""
     jax, jnp = _jx()
-    from ray_tpu.llm.model import decode_logits_core
+    from ray_tpu.llm.model import _gqa_attend_cached, decode_logits_core
     from ray_tpu.ops.pallas.paged_attention import table_view
     b = tokens.shape[0]
     bs = pool["k"].shape[3]
@@ -689,14 +686,6 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     off = positions % bs
     phys = tables[jnp.arange(b), blk]
 
-    def write(ck, cv, k, v):    # ck/cv: (num_blocks, kvh, bs, hd)
-        return (ck.at[phys, :, off].set(k.astype(ck.dtype)),
-                cv.at[phys, :, off].set(v.astype(cv.dtype)))
-
-    def view(ck, cv):
-        return table_view(ck, tables), table_view(cv, tables)
-
-    attend = None
     if impl == "paged_flash":
         from ray_tpu.ops.pallas.paged_attention import paged_attention
 
@@ -704,9 +693,9 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
             return paged_attention(qg, ck, cv, tb, ln,
                                    interpret=interpret)
 
-        def attend(q, ck, cv, pos):     # q: (b, h, hd)
+        def attend(q, ck, cv, pos):     # q: (b, 1, h, hd)
             g = cfg.n_heads // kvh
-            qg = q.reshape(b, kvh, g, hd)
+            qg = q[:, 0].reshape(b, kvh, g, hd)
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
                 t = axis
@@ -721,10 +710,14 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                 fn = _kernel
             o = fn(qg, ck, cv, tables, pos + 1)
             return o.reshape(b, cfg.n_heads * hd)
+    else:
+        def attend(q, ck, cv, pos):     # ck/cv: (num_blocks, kvh, bs, hd)
+            vk, vv = table_view(ck, tables), table_view(cv, tables)
+            return _gqa_attend_cached(q[:, 0], vk, vv, pos + 1, cfg)
 
     logits, nk, nv = decode_logits_core(
-        params, pool["k"], pool["v"], tokens, positions, cfg, write,
-        view, attend)
+        params, pool["k"], pool["v"], tokens, positions, cfg,
+        (phys, off), attend)
     return logits, {"k": nk, "v": nv}
 
 
@@ -758,10 +751,12 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
                        key, cfg, n: int, top_ps=None, top_ks=None, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
-    """n chained decode steps against the block pool in ONE dispatch —
-    the paged twin of lm.decode_steps (same fold_in schedule, same
-    block semantics; slots past their request produce discardable
-    garbage in the trash block). ``impl``/``interpret``/``mesh`` are
+    """n chained decode steps against the block pool in ONE dispatch
+    (lax.scan on device; step i samples under fold_in(key, i)), which
+    amortizes the host<->device roundtrip. Returns (tokens (n, slots)
+    int32, pool). Slots past their request produce discardable
+    garbage in the trash block; the caller masks on eos and bounds n
+    by each slot's horizon. ``impl``/``interpret``/``mesh`` are
     trace-time constants — each combination (x pool geometry) compiles
     its own variant, cached in _JITS."""
     impl = resolve_attn_impl(impl)
@@ -797,9 +792,10 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
                        axis="tensor"):
     """Speculative verify against the block pool: score w in-flight
     tokens per slot (last emitted + up to w-1 drafts) in ONE forward.
-    Runs lm.verify_tokens_core — decode_token_core widened to w — with
-    the block-table write/attend plugged in, so verify numerics can
-    never drift from sequential paged decode.
+    Runs lm.verify_tokens_core — decode_logits_core widened to w — with
+    the same table arithmetic and attention choice as
+    _paged_logits_core, so verify numerics can never drift from
+    sequential paged decode.
 
     tokens: (b, w) int32, column 0 at cache position ``lengths``;
     writes all w KVs through the table (positions past a slot's table
@@ -817,7 +813,7 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     still gathers ONCE per round where sequential decode gathered per
     token, which is the spec-decode win the bench measures."""
     jax, jnp = _jx()
-    from ray_tpu.llm.model import verify_tokens_core
+    from ray_tpu.llm.model import _gqa_attend_multi, verify_tokens_core
     from ray_tpu.ops.pallas.paged_attention import table_view
     b, wq = tokens.shape
     bs = pool["k"].shape[3]
@@ -829,14 +825,6 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     off = pos % bs
     phys = jnp.take_along_axis(tables, blk, axis=1)     # (b, wq)
 
-    def write(ck, cv, k, v):    # k/v: (b, wq, kvh, hd)
-        return (ck.at[phys, :, off].set(k.astype(ck.dtype)),
-                cv.at[phys, :, off].set(v.astype(cv.dtype)))
-
-    def view(ck, cv):
-        return table_view(ck, tables), table_view(cv, tables)
-
-    attend = None
     if impl == "paged_flash":
         from ray_tpu.ops.pallas.paged_attention import (
             paged_attention_verify)
@@ -858,10 +846,15 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
                 fn = paged_attention_verify
             o = fn(qg, ck, cv, tables, pos_grid + 1)
             return o.reshape(b, wq, cfg.n_heads * hd)
+    else:
+        def attend(q, ck, cv, pos_grid):
+            vk, vv = table_view(ck, tables), table_view(cv, tables)
+            return _gqa_attend_multi(q.reshape(b, wq, -1), vk, vv,
+                                     pos_grid + 1, cfg)
 
     logits, nk, nv = verify_tokens_core(
         params, pool["k"], pool["v"], tokens, positions, cfg,
-        write, view, attend)
+        (phys, off), attend)
     return logits, {"k": nk, "v": nv}
 
 

@@ -1,17 +1,20 @@
-"""Cache-aware llama forwards for inference: prefill + single-token decode.
+"""Cache-aware llama forwards for inference: prefill, decode, verify.
 
 The model side of the LLM serving stack (reference:
 python/ray/llm/_internal/serve/... wraps vLLM; here the engine is native:
 the training model in models/llama.py is reused — same params, same
-config — with two inference-shaped entry points that XLA compiles once
-per shape bucket):
+config — with inference-shaped forwards that XLA compiles once per
+shape bucket; TPU rule: no dynamic shapes):
 
-- `prefill`: full-sequence forward that also emits per-layer K/V, written
-  into a static-shape slot cache (TPU rule: no dynamic shapes — prompts
-  are padded to a bucket, the cache is (layers, slots, max_len, kvh, hd)).
-- `decode_step`: one token for every active slot, attending against the
-  cache with a position mask. Batch dimension = slots, so the MXU sees
-  one batched matmul per layer regardless of how many requests are live.
+- `prefill` / `prefill_chunk`: a prompt (padded to a bucket) or one
+  chunk of a long one; they emit per-layer K/V in token order, which
+  the engine scatters into the KV pool's blocks (llm/kvcache.py).
+- `decode_logits_core` / `verify_tokens_core`: one token (or w tokens)
+  for every slot against the pool, the one KV cache there is. Batch
+  dimension = slots, so the MXU sees one batched matmul per layer
+  regardless of how many requests are live. The jitted programs around
+  them are kvcache's `paged_decode_steps`, `paged_decode_logits` and
+  `paged_verify_steps`.
 """
 
 from __future__ import annotations
@@ -44,24 +47,6 @@ def pad_prompt(tokens, bucket: int):
     out = np.zeros((bucket,), np.int32)
     out[:len(tokens)] = tokens
     return out
-
-
-def init_cache(cfg: LlamaConfig, slots: int, max_len: int,
-               dtype=jnp.bfloat16, mesh: Optional[Mesh] = None,
-               axis: str = "tensor") -> dict:
-    """Static KV slot cache. With a mesh, k/v shard their KV-head dim
-    over the tensor axis — the engine's decode attention then runs
-    fully local per tensor shard (Megatron layout)."""
-    shape = (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim)
-    cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-             "length": jnp.zeros((slots,), jnp.int32)}
-    if mesh is not None:
-        kv_s = NamedSharding(mesh, P(None, None, None, axis, None))
-        rep = NamedSharding(mesh, P())
-        cache = {"k": jax.device_put(cache["k"], kv_s),
-                 "v": jax.device_put(cache["v"], kv_s),
-                 "length": jax.device_put(cache["length"], rep)}
-    return cache
 
 
 def serve_param_specs(cfg: LlamaConfig, axis: str = "tensor") -> dict:
@@ -404,43 +389,24 @@ def filter_logits(scaled, top_ks=None, top_ps=None):
     return masked
 
 
-def decode_token_core(params: dict, kcache: jax.Array,
-                      vcache: jax.Array, tokens: jax.Array,
-                      positions: jax.Array, temps: jax.Array,
-                      key: jax.Array, cfg: LlamaConfig,
-                      write, view,
-                      top_ps: Optional[jax.Array] = None,
-                      top_ks: Optional[jax.Array] = None,
-                      attend=None):
-    """One decode step for every slot, sampled on device:
-    decode_logits_core + sample. Returns (sampled tokens, new kcache,
-    new vcache)."""
-    logits, nk, nv = decode_logits_core(
-        params, kcache, vcache, tokens, positions, cfg, write, view,
-        attend)
-    return sample(logits, temps, key, top_ps, top_ks), nk, nv
-
-
-def decode_logits_core(params: dict, kcache: jax.Array,
-                       vcache: jax.Array, tokens: jax.Array,
-                       positions: jax.Array, cfg: LlamaConfig,
-                       write, view, attend=None):
-    """THE decode-step transformer, shared by the monolithic slot
-    cache and the paged block pool (llm/kvcache.py) so the two can
-    never drift numerically — the paged engine's bitwise-parity
-    contract hangs on both running exactly this op sequence. The
-    cache layout is abstracted by two callables applied per layer:
-    ``write(ck, cv, k, v) -> (ck, cv)`` appends the new token's KV
-    (k/v: (slots, kvh, hd)); ``view(ck, cv) -> (vk, vv)`` yields the
-    (slots, L, kvh, hd) attention view. ``attend(q, ck, cv,
-    positions) -> (slots, h*hd) f32`` REPLACES the view +
-    _gqa_attend_cached pair when set — the paged-flash path computes
-    attention straight through the block table without ever
-    materializing the view (ops/pallas/paged_attention.py). Returns
-    ((slots, vocab) f32 logits, new kcache, new vcache)."""
+def decode_logits_core(params: dict, kpool: jax.Array,
+                       vpool: jax.Array, tokens: jax.Array,
+                       positions: jax.Array, cfg: LlamaConfig, at,
+                       attend):
+    """THE decode-step transformer: one token for every slot against
+    the KV pool, k/v (layers, blocks, kvh, block_size, hd). Per layer
+    the new token's KV lands in the pool at ``at`` = (physical block,
+    row in it), each (slots,), which the caller derives from the block
+    tables; then ``attend(q, ck, cv, positions) -> (slots, h*hd) f32``
+    (q: (slots, 1, h, hd)) is the attention over the slot's table,
+    the one thing callers differ in: the kernel that walks the table
+    itself (ops/pallas/paged_attention.py), or its reference,
+    table_view + _gqa_attend_cached. Returns ((slots, vocab) f32
+    logits, new kpool, new vpool)."""
     x = jnp.take(params["embed"], tokens[:, None], axis=0)  # (b, 1, emb)
     rc, rs = _rope_tables(positions[:, None], cfg.head_dim,
                           cfg.rope_theta)
+    phys, off = at
 
     def layer(carry, xs):
         x = carry
@@ -448,12 +414,10 @@ def decode_logits_core(params: dict, kcache: jax.Array,
         y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(y, lp, cfg)  # (b, 1, ...)
         q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        ck, cv = write(ck, cv, k[:, 0], v[:, 0])
-        if attend is not None:
-            o = attend(q[:, 0], ck, cv, positions)
-        else:
-            vk, vv = view(ck, cv)
-            o = _gqa_attend_cached(q[:, 0], vk, vv, positions + 1, cfg)
+        k, v = k[:, 0], v[:, 0]
+        ck = ck.at[phys, :, off].set(k.astype(ck.dtype))
+        cv = cv.at[phys, :, off].set(v.astype(cv.dtype))
+        o = attend(q, ck, cv, positions)
         x = x + (o.astype(x.dtype) @ lp["wo"])[:, None]
         y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
@@ -461,7 +425,7 @@ def decode_logits_core(params: dict, kcache: jax.Array,
         return x, (ck, cv)
 
     x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], kcache, vcache))
+        layer, x, (params["layers"], kpool, vpool))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, nk, nv
@@ -493,27 +457,28 @@ def _gqa_attend_multi(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
     return out.reshape(b, w, h * hd)
 
 
-def verify_tokens_core(params: dict, kcache: jax.Array,
-                       vcache: jax.Array, tokens: jax.Array,
-                       positions: jax.Array, cfg: LlamaConfig,
-                       write, view, attend=None):
-    """The speculative-verify transformer: decode_token_core widened
-    from one token per slot to w — same layer scan, same cache
-    write/view plumbing, so the verify forward can never drift from
-    sequential decode. tokens: (b, w) int32 where column 0 is the last
-    emitted token and columns 1..w-1 the draft; positions: (b,) cache
-    position of column 0 (= tokens_so_far - 1). All w KVs are written
-    (position p+j for column j); the returned logits (b, w, vocab)
-    f32 row j is the model's distribution for position p+j+1 — the
-    verdict on draft token j+1. No device sampling: acceptance is a
-    host decision (llm/spec.py) so rejection sampling can inspect the
-    full distribution. ``write(ck, cv, k, v)`` takes (b, w, kvh, hd)
-    slabs; ``attend(q, ck, cv, pos)`` takes q (b, w, h, hd) and the
-    (b, w) positions grid."""
+def verify_tokens_core(params: dict, kpool: jax.Array,
+                       vpool: jax.Array, tokens: jax.Array,
+                       positions: jax.Array, cfg: LlamaConfig, at,
+                       attend):
+    """The speculative-verify transformer: decode_logits_core widened
+    from one token per slot to w — same layer scan, same pool write,
+    so the verify forward can never drift from sequential decode.
+    tokens: (b, w) int32 where column 0 is the last emitted token and
+    columns 1..w-1 the draft; positions: (b,) cache position of column
+    0 (= tokens_so_far - 1). All w KVs are written (position p+j for
+    column j, at ``at`` = (physical block, row), each (b, w)); the
+    returned logits (b, w, vocab) f32 row j is the model's
+    distribution for position p+j+1 — the verdict on draft token j+1.
+    No device sampling: acceptance is a host decision (llm/spec.py) so
+    rejection sampling can inspect the full distribution.
+    ``attend(q, ck, cv, pos) -> (b, w, h*hd) f32`` takes q
+    (b, w, h, hd) and the (b, w) positions grid."""
     b, w = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)           # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
     rc, rs = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
+    phys, off = at
 
     def layer(carry, xs):
         x = carry
@@ -521,13 +486,9 @@ def verify_tokens_core(params: dict, kcache: jax.Array,
         y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(y, lp, cfg)                          # (b, w, ...)
         q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        ck, cv = write(ck, cv, k, v)
-        if attend is not None:
-            o = attend(q, ck, cv, pos)
-        else:
-            vk, vv = view(ck, cv)
-            o = _gqa_attend_multi(q.reshape(b, w, -1), vk, vv,
-                                  pos + 1, cfg)
+        ck = ck.at[phys, :, off].set(k.astype(ck.dtype))
+        cv = cv.at[phys, :, off].set(v.astype(cv.dtype))
+        o = attend(q, ck, cv, pos)
         x = x + o.astype(x.dtype) @ lp["wo"]
         y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
@@ -535,81 +496,7 @@ def verify_tokens_core(params: dict, kcache: jax.Array,
         return x, (ck, cv)
 
     x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], kcache, vcache))
+        layer, x, (params["layers"], kpool, vpool))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
     return logits, nk, nv
-
-
-def _decode_core(params: dict, cache: dict, tokens: jax.Array,
-                 temps: jax.Array, key: jax.Array,
-                 cfg: LlamaConfig,
-                 top_ps: Optional[jax.Array] = None,
-                 top_ks: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, dict]:
-    """One token for every slot. tokens: (slots,) int32 (last sampled
-    token per slot); temps: (slots,) f32 sampling temperatures; key: rng
-    for this step; cache["length"]: (slots,) current lengths (cache
-    position of `tokens` = length, appended here). Returns
-    (sampled next tokens (slots,) int32, updated cache)."""
-    b = tokens.shape[0]
-    positions = cache["length"]  # (b,) where the new token goes
-
-    def write(ck, cv, k, v):
-        return (ck.at[jnp.arange(b), positions].set(k.astype(ck.dtype)),
-                cv.at[jnp.arange(b), positions].set(v.astype(cv.dtype)))
-
-    def view(ck, cv):
-        return ck, cv           # the slot cache IS the attention view
-
-    out, nk, nv = decode_token_core(
-        params, cache["k"], cache["v"], tokens, positions, temps, key,
-        cfg, write, view, top_ps, top_ks)
-    return out, {"k": nk, "v": nv, "length": cache["length"] + 1}
-
-
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def decode_step(params: dict, cache: dict, tokens: jax.Array,
-                temps: jax.Array, key: jax.Array,
-                cfg: LlamaConfig) -> Tuple[jax.Array, dict]:
-    return _decode_core(params, cache, tokens, temps, key, cfg)
-
-
-@partial(jax.jit, static_argnames=("cfg", "n"), donate_argnums=(1,))
-def decode_steps(params: dict, cache: dict, tokens: jax.Array,
-                 temps: jax.Array, key: jax.Array, cfg: LlamaConfig,
-                 n: int, top_ps: Optional[jax.Array] = None,
-                 top_ks: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, dict]:
-    """n chained decode steps in ONE dispatch (lax.scan on device).
-    Amortizes the host<->device roundtrip. Returns (tokens
-    (n, slots) int32, updated cache). Slots
-    whose request finishes mid-block produce discardable garbage; the
-    caller masks on eos and bounds n by cache headroom."""
-    def body(carry, i):
-        cache, toks = carry
-        out, cache = _decode_core(params, cache, toks, temps,
-                                  jax.random.fold_in(key, i), cfg,
-                                  top_ps, top_ks)
-        return (cache, out), out
-
-    (cache, _), outs = lax.scan(body, (cache, tokens),
-                                jnp.arange(n, dtype=jnp.int32))
-    return outs, cache
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def write_prefill_to_cache(cache: dict, kv: dict, slot: jax.Array,
-                           length: jax.Array) -> dict:
-    """Install a prefilled request's KV into `slot`. The cache is
-    donated so XLA updates it in place instead of copying the full
-    (layers, slots, max_len, ...) buffers per admission."""
-    zero = jnp.int32(0)
-    k = lax.dynamic_update_slice(
-        cache["k"], kv["k"][:, None].astype(cache["k"].dtype),
-        (zero, slot, zero, zero, zero))
-    v = lax.dynamic_update_slice(
-        cache["v"], kv["v"][:, None].astype(cache["v"].dtype),
-        (zero, slot, zero, zero, zero))
-    return {"k": k, "v": v,
-            "length": cache["length"].at[slot].set(length)}

@@ -30,13 +30,12 @@ class PrefillEngine:
     """Stateless prompt prefill: tokens -> {kv, logits, length}.
 
     Shape-bucketed like LLMEngine's in-engine prefill (one compile per
-    bucket); the returned KV is sliced to BLOCK granularity (the paged
-    cache's token-block size) before shipping, so the handoff moves
+    bucket); the returned KV is sliced to BLOCK granularity (the KV
+    pool's token-block size) before shipping, so the handoff moves
     ceil(n / block) blocks instead of a whole padded bucket — a
     65-token prompt ships 80 positions at block 16, not 128. The
-    decode engine re-pads on arrival (paged: into its accumulator;
-    monolithic: to its bucket) and frees the prefill side's copy at
-    handoff (TensorRef handles are single-use; the host-staged numpy
+    decode engine re-pads on arrival (into its accumulator) and frees
+    the prefill side's copy at handoff (TensorRef handles are single-use; the host-staged numpy
     copy dies with the request object).
     """
 
@@ -56,17 +55,14 @@ class PrefillEngine:
             block_size = int(getattr(get_config(),
                                      "kvcache_block_size", 16))
         # same gcd adjustment the engine applies, so both tiers agree
-        # on what a block is; 0 = bucket-granular legacy shipping
-        if block_size > 0:
-            for v in (*self.buckets, max_len):
-                block_size = math.gcd(block_size, v)
-        self.block_size = max(0, block_size)
+        # on what a block is
+        for v in (*self.buckets, max_len):
+            block_size = math.gcd(block_size, v)
+        self.block_size = block_size
 
     def _ship_len(self, n: int, upper: int) -> int:
         """Positions to ship for an n-token prompt: the smallest block
-        multiple covering it (bucket-granular when blocks are off)."""
-        if self.block_size <= 0:
-            return upper
+        multiple covering it."""
         b = self.block_size
         return min(upper, -(-n // b) * b)
 

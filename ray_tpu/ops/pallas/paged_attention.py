@@ -231,8 +231,8 @@ def table_view(pool, tables):
     """One layer of the head-major pool seen through block tables as
     the contiguous attention view: (num_blocks, kv_heads, block_size,
     head_dim) x (slots, width) -> (slots, width * block_size, kv_heads,
-    head_dim). The same bytes in token order, so attention over the
-    view is bitwise what the monolithic cache computes."""
+    head_dim). The same bytes in token order: attention over the view
+    is the kernel's reference."""
     b, w = tables.shape
     _, kvh, bs, hd = pool.shape
     g = pool[tables]                        # (b, w, kvh, bs, hd)

@@ -32,10 +32,11 @@ class LLMConfig:
     model_overrides: dict = field(default_factory=dict)
     checkpoint: Optional[str] = None
     max_slots: int = 8
-    # long-context by default: the engine's KV cache starts small and
-    # grows in buckets, so 8k max_len costs 8k-sized HBM only when an
-    # 8k request actually arrives; prompts past the largest bucket
-    # stream through chunked prefill
+    # long-context by default: max_len is the width of a request's
+    # block table, not memory; the KV pool is sized once at start
+    # (kv_pool_blocks) and HBM follows the LIVE tokens, so an 8k
+    # request costs 8k positions only while it runs; prompts past the
+    # largest bucket stream through chunked prefill
     max_len: int = 8192
     prefill_buckets: tuple = (64, 128, 256, 512, 1024, 2048)
     cache_dtype: str = "bfloat16"
@@ -48,11 +49,12 @@ class LLMConfig:
     # models larger than one chip's HBM serve (reference:
     # llm_config.py:181-186 tensor_parallel_size)
     tensor_parallel: int = 1
-    # Paged KV cache (llm/kvcache.py): None = the Config knobs
-    # (kvcache_block_size / kvcache_pool_blocks /
-    # kvcache_prefix_cache); 0 blocks = monolithic cache. Prefix reuse
-    # is what makes a shared system prompt cheap: requests sharing
-    # cached prefix blocks skip prefill for them.
+    # The KV cache, a paged block pool (llm/kvcache.py): None = the
+    # Config knobs (kvcache_block_size / kvcache_pool_blocks /
+    # kvcache_prefix_cache). kv_block_size is the tokens a block
+    # holds, at least 1 (the replica refuses less at start). Prefix
+    # reuse is what makes a shared system prompt cheap: requests
+    # sharing cached prefix blocks skip prefill for them.
     kv_block_size: Optional[int] = None
     kv_pool_blocks: Optional[int] = None
     prefix_cache: Optional[bool] = None

@@ -330,9 +330,6 @@ KNOB_FAMILIES = {
     # SLO-driven replica autoscaling: interval, cooldown, step,
     # utilization deadband (serve/autoscale.py)
     "autoscale": ("serve_autoscale_", ""),
-    # paged-attention decode path: kernel-vs-gather impl selection and
-    # the pallas interpret override (ops/pallas/paged_attention.py)
-    "paged_attn": ("paged_attn_", ""),
     # goodput ledger: level switch + straggler z-threshold/window
     # (util/goodput.py, train/controller.py detector)
     "goodput": ("goodput_", ""),

@@ -23,6 +23,21 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _serve_port_per_xdist_worker():
+    """Every xdist worker runs clusters of its own on the one host, and
+    serve's HTTP proxy binds a fixed default port: two workers inside
+    serve tests at the same moment fought over it ("address already in
+    use": test_llm.py's serve tests, red in two of PR 29's tier-1
+    runs). Each worker gets a default of its own; a run without xdist
+    keeps 8000."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")    # "gw3"
+    if worker[2:].isdigit():
+        from ray_tpu.serve import api as serve_api
+        serve_api.DEFAULT_HTTP_PORT = 8001 + int(worker[2:])
+    yield
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     import jax

@@ -36,24 +36,35 @@ def _ref_greedy(cfg, params, prompt, n):
 
 
 def test_cached_decode_matches_full_forward(tiny_model):
+    """The serving forwards without an engine: lm.prefill into a
+    bucket, scatter_bucket into the KV pool's blocks, then one
+    paged_decode_logits step and a greedy paged_decode_steps block
+    through slot 2's block table reproduce the training model's full
+    forward."""
+    from ray_tpu.llm import kvcache
     cfg, params = tiny_model
     prompt = [3, 7, 11, 19, 2]
     ref = _ref_greedy(cfg, params, prompt, 6)
 
+    bucket, block, slots = 8, 4, 4
     logits, kv = lm.prefill(params, jnp.pad(jnp.array(prompt, jnp.int32),
                                             (0, 3)),
-                            jnp.int32(len(prompt)), cfg, 32)
-    cache = lm.init_cache(cfg, 4, 32, dtype=jnp.float32)
-    cache = lm.write_prefill_to_cache(cache, kv, 2, jnp.int32(len(prompt)))
-    out = [int(jnp.argmax(logits))]
-    key = jax.random.PRNGKey(0)
-    temps = jnp.zeros((4,), jnp.float32)  # greedy
-    for _ in range(5):
-        toks = jnp.zeros((4,), jnp.int32).at[2].set(out[-1])
-        sampled, cache = lm.decode_step(params, cache, toks, temps,
-                                        key, cfg)
-        out.append(int(sampled[2]))
-    assert out == ref
+                            jnp.int32(len(prompt)), cfg, bucket)
+    pool = kvcache.init_pool(cfg, 9, block, jnp.float32)
+    tables = np.full((slots, 8), kvcache.TRASH, np.int32)
+    tables[2, :3] = [5, 1, 7]               # 12 positions for slot 2
+    pool = kvcache.scatter_bucket(pool, kv, jnp.asarray(tables[2, :2]), 2)
+    first = int(jnp.argmax(logits))
+    toks = jnp.zeros((slots,), jnp.int32).at[2].set(first)
+    at = jnp.zeros((slots,), jnp.int32).at[2].set(len(prompt))
+    step = kvcache.paged_decode_logits(
+        params, pool, jnp.asarray(tables), at, toks, cfg, impl="gather")
+    assert [first, int(jnp.argmax(step[2]))] == ref[:2]
+    sampled, pool = kvcache.paged_decode_steps(
+        params, pool, jnp.asarray(tables), at, toks,
+        jnp.zeros((slots,), jnp.float32),               # greedy
+        jax.random.PRNGKey(0), cfg, 5, impl="gather")
+    assert [first] + [int(t) for t in sampled[:, 2]] == ref
 
 
 def test_continuous_batching_matches_sequential(tiny_model):
@@ -309,10 +320,14 @@ def test_serve_llm_deployment():
 
 def test_serve_llm_streaming():
     """Tokens stream out of the replica as they are produced: the first
-    token arrives well before the generation finishes, and the streamed
-    sequence equals the non-streamed greedy result."""
-    import time as _t
-
+    token is observed before the last one is produced, and the streamed
+    sequence equals the non-streamed greedy result. The order is read
+    off the engine's own token counter, asked for once the first token
+    is in hand: whatever it answers was produced by then at the latest,
+    so an answer short of the whole generation puts the first token's
+    arrival before the last token's production. (It was a share of
+    wall time, first token before 0.8 of the total, which the delivery
+    latency of a loaded host can eat: red in PR 29's tier-1 run.)"""
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.serve.llm import (LLMConfig, build_llm_deployment,
@@ -330,23 +345,23 @@ def test_serve_llm_streaming():
             cache_dtype="float32", steps_per_sync=1)
         h = serve.run(build_llm_deployment(cfg, name="LLMStream"),
                       name="llmstream")
-        ref = ray_tpu.get(h.generate.remote([7, 3], max_new_tokens=40),
+        n = 100
+        ref = ray_tpu.get(h.generate.remote([7, 3], max_new_tokens=n),
                           timeout=180)["tokens"]
+        # the stream path's first use pays a start-up of its own
+        assert list(stream_generate(h, [7, 3], max_new_tokens=2)) == ref[:2]
+        before = ray_tpu.get(h.stats.remote(),
+                             timeout=60)["tokens_generated"]
 
-        t0 = _t.monotonic()
-        first_at = None
+        asked = None
         got = []
-        for tok in stream_generate(h, [7, 3], max_new_tokens=40):
-            if first_at is None:
-                first_at = _t.monotonic() - t0
+        for tok in stream_generate(h, [7, 3], max_new_tokens=n):
+            if asked is None:
+                asked = h.stats.remote()    # sent after the first token
             got.append(tok)
-        total = _t.monotonic() - t0
         assert got == ref
-        # Timing is only meaningful when generation took long enough for
-        # multiple polls; a warm tiny model can finish inside one poll.
-        if total > 0.5:
-            assert first_at is not None and first_at < total * 0.8, \
-                (first_at, total)
+        produced = ray_tpu.get(asked, timeout=60)["tokens_generated"]
+        assert 1 <= produced - before < n, (produced - before, n)
         serve.shutdown()
     finally:
         ray_tpu.shutdown()

@@ -1,9 +1,10 @@
-"""Long-context serving: bucketed KV-cache growth, chunked prefill at
-multi-k prompt lengths, flash-kernel parity in the serving forward pass.
+"""Long-context serving: chunked prefill at multi-k prompt lengths,
+flash-kernel parity in the serving forward pass, and the block size
+the one KV cache needs.
 
 Reference capability: vLLM long-context serving (paged KV + chunked
-prefill) behind ray.serve.llm; here the engine's dense cache grows in
-buckets and prompts stream through lm.prefill_chunk.
+prefill) behind ray.serve.llm; here prompts stream through
+lm.prefill_chunk into the engine's paged pool.
 """
 
 import asyncio
@@ -36,30 +37,23 @@ def _engine(cfg, params, **kw):
     return LLMEngine(cfg, params, **kw)
 
 
-def test_cache_starts_small_and_grows_in_buckets():
-    """The MONOLITHIC cache's bucketed growth (kv_block_size=0 — the
-    fallback mode; the paged default bounds HBM by live blocks
-    instead, covered by tests/test_zz_kvcache.py)."""
+@pytest.mark.parametrize("how", ["kwarg_zero", "kwarg_negative",
+                                 "config_zero"])
+def test_block_size_below_one_is_refused(how, monkeypatch):
+    """The paged pool is the engine's only KV cache: a block size of 0
+    (once the switch to a second, slot-shaped cache) or less is
+    refused at construction, by name, whether it comes from the kwarg
+    or from Config.kvcache_block_size."""
+    from ray_tpu import config as rconfig
     cfg = _tiny()
-    eng = _engine(cfg, _params(cfg), max_len=8192,
-                  prefill_buckets=(64, 128, 256), kv_block_size=0)
-    assert eng._cache_len == 1024          # not 8192 up front
-    assert eng.stats["cache_len"] == 1024
-
-    async def run(n_prompt, n_new):
-        prompt = [int(x) for x in
-                  np.random.default_rng(0).integers(1, 127, n_prompt)]
-        return await eng.generate(prompt, max_new_tokens=n_new,
-                                  temperature=0.0)
-
-    out = asyncio.run(run(16, 8))
-    assert len(out["tokens"]) == 8
-    assert eng._cache_len == 1024          # short request: no growth
-    # a request needing 1500 positions doubles the cache once
-    out = asyncio.run(run(1400, 100))
-    assert len(out["tokens"]) == 100
-    assert eng._cache_len == 2048
-    assert eng.stats["cache_len"] == 2048
+    kw = {}
+    if how == "config_zero":
+        monkeypatch.setattr(rconfig.get_config(), "kvcache_block_size", 0)
+    else:
+        kw["kv_block_size"] = 0 if how == "kwarg_zero" else -16
+    with pytest.raises(ValueError, match="paged pool"):
+        _engine(cfg, _params(cfg), max_len=256, prefill_buckets=(64,),
+                **kw)
 
 
 def test_long_prompt_chunked_equals_single_bucket():

@@ -78,9 +78,17 @@ def test_sharded_params_and_cache_are_actually_sharded(tiny_model):
     shards = wq.addressable_shards
     assert len({s.device for s in shards}) == 2
     assert all(s.data.shape[-1] == wq.shape[-1] // 2 for s in shards)
-    cache = lm.init_cache(cfg, 2, 64, dtype=jnp.float32, mesh=mesh)
-    kshards = cache["k"].addressable_shards
-    assert all(s.data.shape[3] == cfg.n_kv_heads // 2 for s in kshards)
+    # the TP engine's KV pool (layers, blocks, kvh, block, hd) splits
+    # its kv heads over the same axis, on both devices
+    eng = LLMEngine(cfg, params, max_slots=2, max_len=64,
+                    prefill_buckets=(16,), cache_dtype="float32",
+                    mesh=mesh)
+    for key in ("k", "v"):
+        kshards = eng._pool[key].addressable_shards
+        assert len({s.device for s in kshards}) == 2
+        assert all(s.data.shape[2] == cfg.n_kv_heads // 2
+                   and s.data.shape[:2] == eng._pool[key].shape[:2]
+                   for s in kshards)
 
 
 def test_sharding_divisibility_validated(tiny_model):

@@ -150,17 +150,29 @@ def test_tuner_over_trainer(runtime):
     assert best.metrics["loss"] == pytest.approx(0.1)
 
 
-def test_asha_interrupts_trainer_trials_live(runtime):
+def test_asha_interrupts_trainer_trials_live(runtime, tmp_path):
     """Live report streaming: ASHA must stop a losing TRAINER trial
-    mid-run (before its 20 steps finish), not post-hoc."""
+    mid-run (before its 20 steps finish), not post-hoc. Asynchronous
+    halving culls a trial at a rung only if a better one recorded
+    there first, and under load the two trials' workers come up in
+    either order (the loser ran ahead, and to its end, in 1 of 10 runs
+    beside five busy xdist workers): the loser holds its first report
+    until the winner has passed two rungs (a flag file), so who is
+    ahead is the test's choice and not a race of process start-ups."""
     from ray_tpu import train
     from ray_tpu.train import ScalingConfig
+    flag = str(tmp_path / "winner_passed_two_rungs")
 
     def train_fn(config=None):
+        import os
         import time as _t
         lr = (config or {}).get("lr", 1.0)
+        while lr > 1.0 and not os.path.exists(flag):
+            _t.sleep(0.05)
         for step in range(20):
             train.report({"loss": lr * 100.0 / (step + 1)})
+            if lr <= 1.0 and step == 3:
+                open(flag, "w").close()
             _t.sleep(0.25)
 
     trainer = train.JaxTrainer(

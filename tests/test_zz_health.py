@@ -908,9 +908,11 @@ def test_parse_since_and_spark():
 
 
 def test_proxy_shed_advisory_logs_when_burning(caplog):
-    """Log-only advisory: a shed while the health plane reports the
-    deployment's budget burning names the autoscaler hook; a healthy
-    or absent snapshot stays silent. (Cache pre-seeded: no RPC.)"""
+    """A shed while the health plane reports the deployment's budget
+    burning logs one line that names the autoscale_hint it sends (the
+    log comes before the hint RPC, which has no controller to reach
+    here); a healthy or absent snapshot stays silent. (Cache
+    pre-seeded: no health RPC.)"""
     import logging
 
     from ray_tpu.serve.proxy import HTTPProxy
@@ -922,7 +924,7 @@ def test_proxy_shed_advisory_logs_when_burning(caplog):
             "tier": "page"}}}}
     with caplog.at_level(logging.WARNING, logger="ray_tpu.serve.proxy"):
         asyncio.run(p._consult_health("app1"))
-    assert any("autoscaler hook" in r.message for r in caplog.records)
+    assert any("autoscale_hint" in r.getMessage() for r in caplog.records)
     caplog.clear()
     # rate-limited: a shed storm gets ONE line per cache window
     with caplog.at_level(logging.WARNING, logger="ray_tpu.serve.proxy"):

@@ -4,7 +4,7 @@ values, BITWISE on integer constructions), every edge of the walk over
 a slot's live blocks (chunks of C pool blocks, idle slots, a poisoned
 pool, the exported fetch rule), COW-forked tables diverging mid-decode,
 tensor-parallel paged engines, chunk-grid-aligned prefix hits, and the
-paged_attn_impl / paged_attn_interpret Config knobs.
+platform's choice of the engine's decode attention.
 
 All kernel tests run interpret=True — tier-1 (JAX_PLATFORMS=cpu)
 exercises the real table walk / masking / online-softmax logic through
@@ -365,32 +365,36 @@ def test_resolve_attn_impl():
         kc.resolve_attn_impl("flash")
 
 
-def test_config_knobs_drive_engine_impl(tiny_model, monkeypatch):
-    """paged_attn_impl / paged_attn_interpret (Config, overridable via
-    RAY_TPU_PAGED_ATTN_IMPL / RAY_TPU_PAGED_ATTN_INTERPRET) select the
-    decode attention path when the kv_impl kwarg is left at None; off
-    TPU the engine force-enables the interpreter for the kernel impl."""
-    from ray_tpu.config import get_config
-    cfg_obj = get_config()
+def test_platform_picks_engine_impl(tiny_model, monkeypatch):
+    """No knob picks the decode attention: the platform does (the
+    kernel on a TPU, the gather view elsewhere), the kv_impl kwarg
+    overrides it (the kernel's reference in parity checks), and off a
+    TPU the kernel runs through the interpreter."""
+    import sys
     cfg, params = tiny_model
     kw = dict(max_slots=2, max_len=32, prefill_buckets=(8,),
               cache_dtype="float32", kv_block_size=8)
 
-    monkeypatch.setattr(cfg_obj, "paged_attn_impl", "gather")
     eng = LLMEngine(cfg, params, **kw)
-    assert eng._paged and eng._kv_impl == "gather"
+    assert eng._kv_impl == "gather"       # this backend is no TPU
     assert not eng._kv_interpret
     assert eng.stats["kv_impl"] == "gather"
+    assert eng.stats["kv_interpret"] is False
 
-    monkeypatch.setattr(cfg_obj, "paged_attn_impl", "paged_flash")
-    monkeypatch.setattr(cfg_obj, "paged_attn_interpret", False)
+    # what resolve_attn_impl asks; the device itself stays the CPU
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_on_tpu",
+                        lambda: True)
     eng = LLMEngine(cfg, params, **kw)
     assert eng._kv_impl == "paged_flash"
-    assert eng._kv_interpret          # forced: no TPU backend here
+    assert eng._kv_interpret          # no TPU under the kernel here
+    assert eng.stats["kv_interpret"] is True
 
-    # the explicit kwarg beats the Config knob
+    # the explicit kwarg beats the platform's choice, both ways
     eng = LLMEngine(cfg, params, kv_impl="gather", **kw)
-    assert eng._kv_impl == "gather"
+    assert eng._kv_impl == "gather" and not eng._kv_interpret
+    monkeypatch.undo()
+    eng = LLMEngine(cfg, params, kv_impl="paged_flash", **kw)
+    assert eng._kv_impl == "paged_flash" and eng._kv_interpret
 
 
 # --- decode-path parity through the engine ----------------------------
@@ -507,7 +511,7 @@ def test_tp_engine_runs_paged_gather(tiny_model):
                         prefill_buckets=(8,), cache_dtype="float32",
                         kv_block_size=8, prefix_cache=False,
                         kv_impl="gather", mesh=_tp_mesh(2))
-        assert eng._paged
+        assert eng._kv_impl == "gather"
         outs = await asyncio.gather(*[
             eng.generate(p, max_new_tokens=8) for p in prompts])
         await eng.stop()
@@ -534,7 +538,7 @@ def test_tp_engine_kernel_with_prefix_reuse(tiny_model):
                         cache_dtype="float32", kv_block_size=8,
                         prefix_cache=True, kv_impl="paged_flash",
                         mesh=_tp_mesh(2))
-        assert eng._paged and eng._kv_impl == "paged_flash"
+        assert eng._kv_impl == "paged_flash"
         await eng.generate(shared, max_new_tokens=4)
         out = await eng.generate(req, max_new_tokens=8)
         stats = eng.stats
