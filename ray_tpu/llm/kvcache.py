@@ -493,11 +493,15 @@ def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
     slot at max_len) plus one full chain of prefix-cache headroom,
     capped at a quarter of the free HBM of the fullest local device
     when the backend reports a capacity (devmon.hbm_snapshot; the CPU
-    backend reports none). A quarter, because the decode program
-    holds three pool-sized buffers while it runs — the donated pool
-    and two the layer scan stacks its outputs into (the compiler's
-    memory analysis at Llama-2-7B widths: 2.25 GB of pool, 4.5 GB of
-    temporaries) — and prefill needs room beside it.
+    backend reports none). The decode program holds ONE pool-sized
+    buffer since PR 31 (the donated pool, updated in place: the
+    compiler's memory analysis at the chat cell's geometry gives 3.1
+    GB of pool and 1.3 MB of temporaries, where the layer scan's
+    stacked outputs took 3.9 GB); the quarter dates from the three it
+    held before, and is kept until a PR of its own raises it (a larger
+    pool moves the memory reading and the admission, ROADMAP Speed 1).
+    The prefill-side programs (scatter_bucket, scatter_table,
+    copy_block) and the prompt's accumulator need room beside it.
     ``block_bytes`` is what one block costs PER DEVICE.
     The cap never shrinks below ONE full-horizon request
     (table_width blocks): a max_len-sized request must be servable —
@@ -647,77 +651,114 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
     return sample(logits, temps, key, top_ps, top_ks), pool
 
 
+def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
+    """The attention hook of lm.decode_logits_core / verify_tokens_core
+    against the block pool, ``attend(l, q, k, v, kpool, vpool) -> (o,
+    kpool, vpool)``: the new rows k, v go into layer ``l`` of the
+    stacked pools at ``at`` = (physical block, row in it), and q
+    attends through ``tables`` over ``lens`` valid positions. ``at``
+    and ``lens`` are (slots,) for a decode step, (slots, w) for a
+    verify round, which attends with the multi-query functions.
+
+    Both impls see layer l as a WINDOW of the flat pool (every layer's
+    blocks in one (layers * blocks, kvh, block_size, hd) array: a
+    reshape of the row-major stacked pool, no copy), by adding
+    ``l * blocks`` to the block ids: nothing slices a layer out or
+    stacks one back, so a program that donates the pool holds one
+    buffer of it.
+
+    impl='paged_flash': the aliased block writer, then the kernel
+    that walks each slot's LIVE table entries (``ceil(length /
+    block_size)`` of them, a run-time count) and fetches those blocks
+    with its own DMAs (ops/pallas/paged_attention.py kv_write,
+    paged_attention) — no gathered view, no pool-sized copy. XLA
+    performs no write on the pool itself: it would lay the pool out
+    token-major for it and convert the whole pool back for the kernel
+    on every layer. A verify round attends through the gather twin
+    (paged_attention_verify) after the same write. With ``mesh`` both
+    run under one shard_map: kv heads sharded over ``axis``, tables,
+    ids and lengths replicated — each shard works on its own head
+    slice, no collectives.
+
+    impl='gather': the reference, for the CPU and for parity. A
+    scatter on the carry, then table_view (the table's blocks in
+    table order, so masked tail positions contribute exact zeros)
+    under lm._gqa_attend_cached / _gqa_attend_multi. Same f32 math;
+    the kernel's online softmax agrees with it to f32 rounding
+    (bitwise on the integer constructions tests/test_zz_paged_attn.py
+    pins). GSPMD partitions it as it is."""
+    jax, _ = _jx()
+    from ray_tpu.llm import model as lm
+    from ray_tpu.ops.pallas import paged_attention as pa
+    phys, off = at
+    lead = phys.shape                   # (slots,) or (slots, w)
+    multi = len(lead) == 2
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def flat(pool):
+        return pool.reshape(-1, *pool.shape[2:])
+
+    if impl == "paged_flash":
+        def write_attend(qg, k, v, kf, vf, tb, blocks, rows, ln):
+            kf, vf = pa.kv_write(kf, vf, blocks, rows, k, v,
+                                 interpret=interpret)
+            o = (pa.paged_attention_verify(qg, kf, vf, tb, ln) if multi
+                 else pa.paged_attention(qg, kf, vf, tb, ln,
+                                         interpret=interpret))
+            return o, kf, vf
+
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+            heads, new = P(None, axis, None, None), P(None, axis, None)
+            qs = P(*(None,) * len(lead), axis, None, None)
+            write_attend = jax.shard_map(
+                write_attend, mesh=mesh,
+                in_specs=(qs, new, new, heads, heads, P(), P(), P(),
+                          P()),
+                out_specs=(qs, heads, heads), check_vma=False)
+
+        def attend(l, q, k, v, kp, vp):
+            base = l * kp.shape[1]
+            o, kf, vf = write_attend(
+                q.reshape(*lead, kvh, h // kvh, hd),
+                k.reshape(-1, kvh, hd).astype(kp.dtype),
+                v.reshape(-1, kvh, hd).astype(vp.dtype),
+                flat(kp), flat(vp), tables + base,
+                (phys + base).reshape(-1), off.reshape(-1), lens)
+            return (o.reshape(*lead, h * hd), kf.reshape(kp.shape),
+                    vf.reshape(vp.shape))
+    else:
+        attn = lm._gqa_attend_multi if multi else lm._gqa_attend_cached
+
+        def attend(l, q, k, v, kp, vp):
+            kp = kp.at[l, phys, :, off].set(k.astype(kp.dtype))
+            vp = vp.at[l, phys, :, off].set(v.astype(vp.dtype))
+            tb = tables + l * kp.shape[1]
+            o = attn(q.reshape(*lead, h * hd),
+                     pa.table_view(flat(kp), tb),
+                     pa.table_view(flat(vp), tb), lens, cfg)
+            return o, kp, vp
+    return attend
+
+
 def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
     """One decode step's (slots, vocab) f32 logits for every slot
     against the paged pool: lm.decode_logits_core with the new token's
-    place in the pool worked out from the tables, and the attention
-    over the table plugged in.
-
-    impl='gather': the reference. The attention view is materialized
-    per layer (paged_attention.table_view: the table's blocks in
-    table order, so masked tail positions contribute exact zeros) and
-    attended by lm._gqa_attend_cached.
-
-    impl='paged_flash': the pallas kernel walks each slot's LIVE
-    table entries (``ceil(length / block_size)`` of them, a run-time
-    count: the table's width costs nothing) and fetches those pool
-    blocks with its own DMAs (ops/pallas/paged_attention.py) — no
-    gathered view, no O(slots x max_len x layers) copy per emitted
-    token. Same f32 attention math; online softmax agrees with the
-    gather path to f32 rounding (bitwise on the integer constructions
-    tests/test_zz_paged_attn.py pins).
-
-    With ``mesh``, the kernel path runs under shard_map: kv heads
-    sharded over ``axis``, block tables/lengths replicated — each
-    shard walks the same tables over its own head slice, no
-    collectives (the gather path needs nothing: GSPMD partitions the
-    plain-jnp view fine)."""
-    jax, jnp = _jx()
-    from ray_tpu.llm.model import _gqa_attend_cached, decode_logits_core
-    from ray_tpu.ops.pallas.paged_attention import table_view
+    place in the pool worked out from the tables, and the write and
+    the attention over the table plugged in (_pool_attend)."""
+    _, jnp = _jx()
+    from ray_tpu.llm.model import decode_logits_core
     b = tokens.shape[0]
     bs = pool["k"].shape[3]
-    w = tables.shape[1]
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
     positions = lengths
-    blk = jnp.clip(positions // bs, 0, w - 1)
-    off = positions % bs
-    phys = tables[jnp.arange(b), blk]
-
-    if impl == "paged_flash":
-        from ray_tpu.ops.pallas.paged_attention import paged_attention
-
-        def _kernel(qg, ck, cv, tb, ln):
-            return paged_attention(qg, ck, cv, tb, ln,
-                                   interpret=interpret)
-
-        def attend(q, ck, cv, pos):     # q: (b, 1, h, hd)
-            g = cfg.n_heads // kvh
-            qg = q[:, 0].reshape(b, kvh, g, hd)
-            if mesh is not None:
-                from jax.sharding import PartitionSpec as P
-                t = axis
-                fn = jax.shard_map(
-                    _kernel, mesh=mesh,
-                    in_specs=(P(None, t, None, None),
-                              P(None, t, None, None),
-                              P(None, t, None, None), P(), P()),
-                    out_specs=P(None, t, None, None),
-                    check_vma=False)
-            else:
-                fn = _kernel
-            o = fn(qg, ck, cv, tables, pos + 1)
-            return o.reshape(b, cfg.n_heads * hd)
-    else:
-        def attend(q, ck, cv, pos):     # ck/cv: (num_blocks, kvh, bs, hd)
-            vk, vv = table_view(ck, tables), table_view(cv, tables)
-            return _gqa_attend_cached(q[:, 0], vk, vv, pos + 1, cfg)
-
+    blk = jnp.clip(positions // bs, 0, tables.shape[1] - 1)
+    at = (tables[jnp.arange(b), blk], positions % bs)
     logits, nk, nv = decode_logits_core(
         params, pool["k"], pool["v"], tokens, positions, cfg,
-        (phys, off), attend)
+        _pool_attend(cfg, tables, at, positions + 1, impl=impl,
+                     interpret=interpret, mesh=mesh, axis=axis))
     return logits, {"k": nk, "v": nv}
 
 
@@ -754,11 +795,24 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
     """n chained decode steps against the block pool in ONE dispatch
     (lax.scan on device; step i samples under fold_in(key, i)), which
     amortizes the host<->device roundtrip. Returns (tokens (n, slots)
-    int32, pool). Slots past their request produce discardable
-    garbage in the trash block; the caller masks on eos and bounds n
-    by each slot's horizon. ``impl``/``interpret``/``mesh`` are
-    trace-time constants — each combination (x pool geometry) compiles
-    its own variant, cached in _JITS."""
+    int32, pool). The pool is DONATED and is the carry of the step
+    scan and of each step's layer scan: the program updates it in
+    place (tests/test_aot_tpu_compile.py holds the compiled program to
+    that). Slots past their request produce discardable garbage in
+    the trash block; the caller masks on eos and bounds n by each
+    slot's horizon. ``impl``/``interpret``/``mesh`` are trace-time
+    constants — each combination (x pool geometry) compiles its own
+    variant, cached in _JITS."""
+    return decode_steps_program(
+        pool, impl=impl, interpret=interpret, mesh=mesh, axis=axis)(
+        params, pool, tables, lengths, tokens, temps, key, cfg, n,
+        top_ps, top_ks)
+
+
+def decode_steps_program(pool, *, impl="gather", interpret=False,
+                         mesh=None, axis="tensor"):
+    """The jitted program behind paged_decode_steps for a pool of this
+    geometry (arrays or their shapes), built once a variant."""
     impl = resolve_attn_impl(impl)
     key_ = ("paged_decode_steps", *_pool_key(pool), impl,
             bool(interpret), mesh, axis)
@@ -769,8 +823,8 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
 
         @partial(jax.jit, static_argnames=("cfg", "n"),
                  donate_argnums=(1,))
-        def fn(params, pool, tables, lengths, tokens, temps, key, cfg,
-               n, top_ps, top_ks):
+        def paged_decode_steps(params, pool, tables, lengths, tokens,
+                               temps, key, cfg, n, top_ps, top_ks):
             def body(carry, i):
                 pool, toks = carry
                 out, pool = _paged_decode_core(
@@ -782,9 +836,8 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
             (pool, _), outs = _lax.scan(body, (pool, tokens),
                                         jnp.arange(n, dtype=jnp.int32))
             return outs, pool
-        _JITS[key_] = fn
-    return fn(params, pool, tables, lengths, tokens, temps, key,
-              cfg, n, top_ps, top_ks)
+        fn = _JITS[key_] = paged_decode_steps
+    return fn
 
 
 def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
@@ -793,9 +846,9 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     """Speculative verify against the block pool: score w in-flight
     tokens per slot (last emitted + up to w-1 drafts) in ONE forward.
     Runs lm.verify_tokens_core — decode_logits_core widened to w — with
-    the same table arithmetic and attention choice as
-    _paged_logits_core, so verify numerics can never drift from
-    sequential paged decode.
+    the same table arithmetic, pool write and attention choice as
+    _paged_logits_core (_pool_attend), so verify numerics can never
+    drift from sequential paged decode.
 
     tokens: (b, w) int32, column 0 at cache position ``lengths``;
     writes all w KVs through the table (positions past a slot's table
@@ -807,54 +860,23 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     draft j+1. Acceptance is a host decision (llm/spec.py) — the
     device ships w*vocab floats per slot per ROUND, not per token.
 
-    impl='paged_flash' uses the gather-twin multi-query attention
-    (ops/pallas/paged_attention.paged_attention_verify) — the fused
-    single-query kernel doesn't take multi-query rows yet; the twin
-    still gathers ONCE per round where sequential decode gathered per
-    token, which is the spec-decode win the bench measures."""
-    jax, jnp = _jx()
-    from ray_tpu.llm.model import _gqa_attend_multi, verify_tokens_core
-    from ray_tpu.ops.pallas.paged_attention import table_view
-    b, wq = tokens.shape
+    impl='paged_flash' attends with the gather-twin multi-query
+    attention (ops/pallas/paged_attention.paged_attention_verify) —
+    the fused single-query kernel doesn't take multi-query rows yet;
+    the twin still gathers ONCE per round where sequential decode
+    gathered per token, which is the spec-decode win the bench
+    measures."""
+    _, jnp = _jx()
+    from ray_tpu.llm.model import verify_tokens_core
+    wq = tokens.shape[1]
     bs = pool["k"].shape[3]
-    w = tables.shape[1]
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    positions = lengths
-    pos = positions[:, None] + jnp.arange(wq, dtype=jnp.int32)[None]
-    blk = jnp.clip(pos // bs, 0, w - 1)
-    off = pos % bs
-    phys = jnp.take_along_axis(tables, blk, axis=1)     # (b, wq)
-
-    if impl == "paged_flash":
-        from ray_tpu.ops.pallas.paged_attention import (
-            paged_attention_verify)
-
-        def attend(q, ck, cv, pos_grid):    # q: (b, wq, h, hd)
-            g = cfg.n_heads // kvh
-            qg = q.reshape(b, wq, kvh, g, hd)
-            if mesh is not None:
-                from jax.sharding import PartitionSpec as P
-                t = axis
-                fn = jax.shard_map(
-                    paged_attention_verify, mesh=mesh,
-                    in_specs=(P(None, None, t, None, None),
-                              P(None, t, None, None),
-                              P(None, t, None, None), P(), P()),
-                    out_specs=P(None, None, t, None, None),
-                    check_vma=False)
-            else:
-                fn = paged_attention_verify
-            o = fn(qg, ck, cv, tables, pos_grid + 1)
-            return o.reshape(b, wq, cfg.n_heads * hd)
-    else:
-        def attend(q, ck, cv, pos_grid):
-            vk, vv = table_view(ck, tables), table_view(cv, tables)
-            return _gqa_attend_multi(q.reshape(b, wq, -1), vk, vv,
-                                     pos_grid + 1, cfg)
-
+    pos = lengths[:, None] + jnp.arange(wq, dtype=jnp.int32)[None]
+    blk = jnp.clip(pos // bs, 0, tables.shape[1] - 1)
+    at = (jnp.take_along_axis(tables, blk, axis=1), pos % bs)  # (b, wq)
     logits, nk, nv = verify_tokens_core(
-        params, pool["k"], pool["v"], tokens, positions, cfg,
-        (phys, off), attend)
+        params, pool["k"], pool["v"], tokens, lengths, cfg,
+        _pool_attend(cfg, tables, at, pos + 1, impl=impl,
+                     interpret=interpret, mesh=mesh, axis=axis))
     return logits, {"k": nk, "v": nv}
 
 
@@ -862,13 +884,22 @@ def paged_verify_steps(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
     """One speculative verify round in one dispatch — the verify twin
-    of paged_decode_steps. tokens: (b, w) with w drawn from the
-    engine's verify-width buckets; each (pool geometry, w, impl)
-    combination compiles exactly once, cached in _JITS (the
-    compile-discipline tests count both the _JITS keys and devmon's
-    jit(paged_verify_steps) compile spans)."""
+    of paged_decode_steps (pool donated, updated in place). tokens:
+    (b, w) with w drawn from the engine's verify-width buckets; each
+    (pool geometry, w, impl) combination compiles exactly once, cached
+    in _JITS (the compile-discipline tests count both the _JITS keys
+    and devmon's jit(paged_verify_steps) compile spans)."""
+    return verify_steps_program(
+        pool, int(tokens.shape[1]), impl=impl, interpret=interpret,
+        mesh=mesh, axis=axis)(params, pool, tables, lengths, tokens,
+                              cfg)
+
+
+def verify_steps_program(pool, wq: int, *, impl="gather",
+                         interpret=False, mesh=None, axis="tensor"):
+    """The jitted program behind paged_verify_steps for a pool of this
+    geometry and a verify width, built once a variant."""
     impl = resolve_attn_impl(impl)
-    wq = int(tokens.shape[1])
     key_ = ("paged_verify_steps", wq, *_pool_key(pool), impl,
             bool(interpret), mesh, axis)
     fn = _JITS.get(key_)
@@ -881,6 +912,5 @@ def paged_verify_steps(params, pool, tables, lengths, tokens, cfg, *,
             return _paged_verify_core(
                 params, pool, tables, lengths, tokens, cfg, impl=impl,
                 interpret=interpret, mesh=mesh, axis=axis)
-        fn = paged_verify_steps
-        _JITS[key_] = fn
-    return fn(params, pool, tables, lengths, tokens, cfg)
+        fn = _JITS[key_] = paged_verify_steps
+    return fn

@@ -391,44 +391,43 @@ def filter_logits(scaled, top_ks=None, top_ps=None):
 
 def decode_logits_core(params: dict, kpool: jax.Array,
                        vpool: jax.Array, tokens: jax.Array,
-                       positions: jax.Array, cfg: LlamaConfig, at,
-                       attend):
+                       positions: jax.Array, cfg: LlamaConfig, attend):
     """THE decode-step transformer: one token for every slot against
-    the KV pool, k/v (layers, blocks, kvh, block_size, hd). Per layer
-    the new token's KV lands in the pool at ``at`` = (physical block,
-    row in it), each (slots,), which the caller derives from the block
-    tables; then ``attend(q, ck, cv, positions) -> (slots, h*hd) f32``
-    (q: (slots, 1, h, hd)) is the attention over the slot's table,
-    the one thing callers differ in: the kernel that walks the table
-    itself (ops/pallas/paged_attention.py), or its reference,
+    the KV pool, k/v (layers, blocks, kvh, block_size, hd). The pools
+    are the layer scan's CARRY, never sliced by layer and never
+    stacked back: a decode program that donates (or itself carries)
+    them updates them in place. Per layer, ``attend(l, q, k, v, kpool,
+    vpool) -> ((slots, h*hd) f32, kpool, vpool)`` (l: the layer's
+    index, traced; q: (slots, 1, h, hd); k, v: (slots, kvh, hd), the
+    new token's rows) puts the rows into layer l of the pools and
+    attends over the slot's table, the one thing callers differ in:
+    the aliased writer and the kernel that walks the table itself
+    (ops/pallas/paged_attention.py), or their reference, a scatter +
     table_view + _gqa_attend_cached. Returns ((slots, vocab) f32
-    logits, new kpool, new vpool)."""
+    logits, kpool, vpool)."""
     x = jnp.take(params["embed"], tokens[:, None], axis=0)  # (b, 1, emb)
     rc, rs = _rope_tables(positions[:, None], cfg.head_dim,
                           cfg.rope_theta)
-    phys, off = at
 
     def layer(carry, xs):
-        x = carry
-        lp, ck, cv = xs
+        x, ck, cv = carry
+        lp, l = xs
         y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(y, lp, cfg)  # (b, 1, ...)
         q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        k, v = k[:, 0], v[:, 0]
-        ck = ck.at[phys, :, off].set(k.astype(ck.dtype))
-        cv = cv.at[phys, :, off].set(v.astype(cv.dtype))
-        o = attend(q, ck, cv, positions)
+        o, ck, cv = attend(l, q, k[:, 0], v[:, 0], ck, cv)
         x = x + (o.astype(x.dtype) @ lp["wo"])[:, None]
         y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
                  @ lp["w_down"])
-        return x, (ck, cv)
+        return (x, ck, cv), None
 
-    x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], kpool, vpool))
+    index = jnp.arange(kpool.shape[0], dtype=jnp.int32)
+    (x, kpool, vpool), _ = _scan_layers(
+        layer, (x, kpool, vpool), (params["layers"], index))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, nk, nv
+    return logits, kpool, vpool
 
 
 def _gqa_attend_multi(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
@@ -459,44 +458,43 @@ def _gqa_attend_multi(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
 
 def verify_tokens_core(params: dict, kpool: jax.Array,
                        vpool: jax.Array, tokens: jax.Array,
-                       positions: jax.Array, cfg: LlamaConfig, at,
-                       attend):
+                       positions: jax.Array, cfg: LlamaConfig, attend):
     """The speculative-verify transformer: decode_logits_core widened
-    from one token per slot to w — same layer scan, same pool write,
-    so the verify forward can never drift from sequential decode.
+    from one token per slot to w — same layer scan with the pools as
+    its carry, same pool write, so the verify forward can never drift
+    from sequential decode.
     tokens: (b, w) int32 where column 0 is the last emitted token and
     columns 1..w-1 the draft; positions: (b,) cache position of column
     0 (= tokens_so_far - 1). All w KVs are written (position p+j for
-    column j, at ``at`` = (physical block, row), each (b, w)); the
-    returned logits (b, w, vocab) f32 row j is the model's
-    distribution for position p+j+1 — the verdict on draft token j+1.
-    No device sampling: acceptance is a host decision (llm/spec.py) so
-    rejection sampling can inspect the full distribution.
-    ``attend(q, ck, cv, pos) -> (b, w, h*hd) f32`` takes q
-    (b, w, h, hd) and the (b, w) positions grid."""
+    column j); the returned logits (b, w, vocab) f32 row j is the
+    model's distribution for position p+j+1 — the verdict on draft
+    token j+1. No device sampling: acceptance is a host decision
+    (llm/spec.py) so rejection sampling can inspect the full
+    distribution.
+    ``attend(l, q, k, v, kpool, vpool) -> ((b, w, h*hd) f32, kpool,
+    vpool)`` takes q (b, w, h, hd) and the new rows k, v
+    (b, w, kvh, hd)."""
     b, w = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)           # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
     rc, rs = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
-    phys, off = at
 
     def layer(carry, xs):
-        x = carry
-        lp, ck, cv = xs
+        x, ck, cv = carry
+        lp, l = xs
         y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(y, lp, cfg)                          # (b, w, ...)
         q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        ck = ck.at[phys, :, off].set(k.astype(ck.dtype))
-        cv = cv.at[phys, :, off].set(v.astype(cv.dtype))
-        o = attend(q, ck, cv, pos)
+        o, ck, cv = attend(l, q, k, v, ck, cv)
         x = x + o.astype(x.dtype) @ lp["wo"]
         y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
                  @ lp["w_down"])
-        return x, (ck, cv)
+        return (x, ck, cv), None
 
-    x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], kpool, vpool))
+    index = jnp.arange(kpool.shape[0], dtype=jnp.int32)
+    (x, kpool, vpool), _ = _scan_layers(
+        layer, (x, kpool, vpool), (params["layers"], index))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
-    return logits, nk, nv
+    return logits, kpool, vpool

@@ -1,4 +1,4 @@
-"""Pallas TPU paged-attention decode kernel.
+"""Pallas TPU paged-attention decode kernel, and the pool's block writer.
 
 Fuses the two reference designs the serving stack sits between:
 vLLM's PagedAttention (block tables over a fixed KV pool) and the
@@ -53,6 +53,15 @@ gave); p stays f32 into the PV product and v is widened. Online
 softmax is an exact refactoring of the masked softmax, so the two
 impls agree to f32 rounding (and bitwise on integer-valued
 constructions; see tests/test_zz_paged_attn.py).
+
+``kv_write`` is the pool's one writer on the decode path (PR 31): the
+new token's K and V rows of every slot go into their blocks through a
+custom call that aliases both pools in to out, a read-modify-write of
+each entry's block. The walk needs the pool row-major and head-major;
+a write that XLA performs itself makes XLA lay the pool out token-major
+and convert the whole pool back on every layer. With the writer beside
+the walk no XLA instruction touches the pool, and a decode program
+carries one buffer of it from its first step to its last.
 
 ``table_view`` is the one place that turns the head-major layout back
 into the contiguous ``(slots, len, kv_heads, head_dim)`` view the
@@ -225,6 +234,99 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool,
       v_pool)
+
+
+def _kv_write_kernel(blocks_ref, rows_ref, k_new, v_new, k_in, v_in,
+                     k_out, v_out, buf, sems):
+    del k_in, v_in                  # the same buffers as k_out, v_out
+    i = pl.program_id(0)
+    blk, row = blocks_ref[i], rows_ref[i]
+    pools = (k_out, v_out)
+    reads = [pltpu.make_async_copy(pool.at[blk], buf.at[s], sems.at[s])
+             for s, pool in enumerate(pools)]
+    writes = [pltpu.make_async_copy(buf.at[s], pool.at[blk], sems.at[s])
+              for s, pool in enumerate(pools)]
+    for cp in reads:
+        cp.start()
+    for s, new in enumerate((k_new, v_new)):
+        reads[s].wait()
+        block = buf[s]                          # (kvh, bs, hd)
+        here = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == row
+        buf[s] = jnp.where(here, new[0][:, None, :], block)
+        writes[s].start()
+    for cp in writes:
+        cp.wait()
+
+
+def kv_write(k_pool, v_pool, blocks, rows, k_new, v_new, *,
+             interpret=False):
+    """Write ``n`` new positions' K and V rows into the pools IN PLACE:
+    ``pool[blocks[i], :, rows[i]] = new[i]`` for i = 0..n-1, in that
+    order (a later entry for the same place wins, and two entries in
+    one block both land).
+
+    k_pool/v_pool: (num_blocks, kv_heads, block_size, head_dim) —
+    every layer of the engine pool flattened along its first two axes,
+    so ``blocks`` are ``layer * blocks_a_layer + physical block``;
+    blocks, rows: (n,) int32; k_new/v_new: (n, kv_heads, head_dim) in
+    the pools' dtypes. Returns the two pools: the same buffers, aliased
+    in to out, so a program that donates or carries the pool copies
+    nothing pool-sized.
+
+    Why a kernel for a 2-KB write: XLA would lay the pool out
+    token-major for a one-row scatter, the walk kernel above needs it
+    row-major and head-major, and XLA then converts the WHOLE pool
+    between the two around every layer (eight pool-sized copies a
+    decode step, three quarters of the chat cell's device time before
+    PR 31). A custom call pins the layout. A row of a bf16 pool is a
+    sixteenth of a (16, 128) tile and cannot be the target of a DMA,
+    so each entry is a read-modify-write of its whole block: the block
+    (all heads: 32 KB at 8 x 16 x 128 bf16) to VMEM, row ``rows[i]``
+    replaced under an iota mask, the block back. K and V move
+    together; entries run one after another, since two may name one
+    block."""
+    n, kvh, hd = k_new.shape
+    _, kvh_p, bs, hd_p = k_pool.shape
+    if (kvh_p, hd_p) != (kvh, hd) or v_pool.shape != k_pool.shape \
+            or v_new.shape != k_new.shape:
+        raise ValueError(
+            f"pools {k_pool.shape}, {v_pool.shape} do not take rows "
+            f"{k_new.shape}, {v_new.shape}")
+    if {k_new.dtype, v_new.dtype, v_pool.dtype} != {k_pool.dtype}:
+        raise ValueError("pools and new rows must share one dtype")
+
+    def _row(i, blocks, rows):
+        return (i, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[
+            pl.BlockSpec((1, kvh, hd), _row),
+            pl.BlockSpec((1, kvh, hd), _row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            pltpu.VMEM((2, kvh, bs, hd), k_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # operands count the two scalar-prefetch arrays: 4, 5 are the pools
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="kv_write",
+    )(blocks.astype(jnp.int32), rows.astype(jnp.int32), k_new, v_new,
+      k_pool, v_pool)
 
 
 def table_view(pool, tables):

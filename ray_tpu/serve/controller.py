@@ -26,7 +26,15 @@ from ray_tpu.runtime.ids import ActorID
 
 RECONCILE_INTERVAL_S = 0.25
 HEALTH_CHECK_INTERVAL_S = 1.0
-HEALTH_CHECK_TIMEOUT_S = 10.0
+# A replica answers its ping on the event loop its handlers run on, and
+# that loop can stand still for longer than a request may: stopping a
+# profiler trace of 8 s of decode steps at 6 ms each took 12.6 s (PR 31,
+# on the chip), and at 10 s the controller replaced a healthy replica
+# holding 7 GB of weights and cache in the middle of a traced run. A dead
+# process fails its ping at once, whatever this says; the timeout is for
+# a loop that is alive and busy. (Ray Serve: health_check_timeout_s 30,
+# and three failures in a row.)
+HEALTH_CHECK_TIMEOUT_S = 60.0
 
 
 class _ReplicaInfo:

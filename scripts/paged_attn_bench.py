@@ -12,6 +12,12 @@ Slots that are not live are idle as the engine presents them: an
 all-trash table row and length 1. Live slots' tables name random pool
 blocks, so no two fetches are neighbours in HBM.
 
+Then the pool's block writer, ``kv_write``, alone (PR 31): the 32
+entries of one layer of a decode step, K and V, each a read-modify-write
+of a whole pool block; all 32 slots live on distinct blocks, the cell's
+mix of 12 live and 20 idle slots (the idle ones all write the trash
+block), and 160 entries as a verify round of width 5 writes them.
+
 ``--old PATH`` times a second module beside it (the parent commit's
 kernel, unpacked under ``.scratch/``), same inputs, and compares the
 outputs. It is a one-off for PERF.md, no cell's code; without a TPU it
@@ -23,6 +29,7 @@ check the control flow; its times mean nothing).
 """
 
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -129,8 +136,48 @@ def main():
                 row["speedup"] = row["old_us"] / row["new_us"]
             print(json.dumps(row), flush=True)
             rows.append(row)
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def write(k_pool, v_pool, blocks, at, k_new, v_new):
+        """``reps`` writes in one program, the pools carried in place."""
+        def body(_, pools):
+            return tuple(new.kv_write(*pools, blocks, at, k_new, v_new,
+                                      interpret=a.rehearse))
+        return jax.lax.fori_loop(0, a.reps, body, (k_pool, v_pool))
+
+    writes = []
+    block_bytes = kvh * bs * hd * k_pool.dtype.itemsize
+    for what, n, live in (("all_live", slots, slots),
+                          ("cell_mix", slots, 3 * slots // 8),
+                          ("verify_w5", 5 * slots, 5 * slots)):
+        blocks = np.zeros((n,), np.int32)               # trash
+        blocks[:live] = rng.permutation(np.arange(1, nb))[:live]
+        at = jnp.asarray(rng.integers(0, bs, (n,)), jnp.int32)
+        blocks = jnp.asarray(blocks)
+        k_new, v_new = (jax.random.normal(
+            jax.random.PRNGKey(i), (n, kvh, hd), jnp.bfloat16)
+            for i in (1, 2))
+        # block 0 takes the idle entries in an order of its own
+        want = np.asarray(k_pool.at[blocks, :, at].set(k_new)[1:]
+                          .astype(jnp.float32))
+        ts = []
+        for _ in range(a.rounds + 1):                   # first: compile
+            t0 = time.perf_counter()
+            k_pool, v_pool = jax.block_until_ready(
+                write(k_pool, v_pool, blocks, at, k_new, v_new))
+            ts.append((time.perf_counter() - t0) / a.reps)
+        t = statistics.median(ts[1:])
+        # what the writer moves, not what the algorithm needs (2 KB a row)
+        moved = 4 * n * block_bytes
+        row = {"kv_write": what, "entries": n, "live": live,
+               "us": 1e6 * t, "us_per_entry": 1e6 * t / n,
+               "moved_bytes": moved, "moved_GB_per_s": moved / t / 1e9,
+               "equals_scatter": bool(np.array_equal(
+                   np.asarray(k_pool[1:].astype(jnp.float32)), want))}
+        print(json.dumps(row), flush=True)
+        writes.append(row)
     doc = {"device": device, "shape": shp, "reps": a.reps,
-           "chunk_blocks": new.chunk_blocks(kvh, bs, hd, 2), "rows": rows}
+           "chunk_blocks": new.chunk_blocks(kvh, bs, hd, 2), "rows": rows,
+           "kv_write": writes}
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
     with open(a.out, "w") as f:
         json.dump(doc, f, indent=1)

@@ -31,8 +31,8 @@ HD = 128        # head_dim of every Llama-2/3 width
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e device; the persistent compile cache is off
+def topo():
+    """A described v5e 2x2; the persistent compile cache is off
     around these compiles (an entry written for a described device
     cannot be read back without one, and warns on the next run)."""
     from jax.experimental import topologies
@@ -45,9 +45,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e device."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _flash(q, k, v, **kw):
@@ -181,3 +187,131 @@ def test_grouped_matmul_compiles_for_v5e(chip, name):
              for op in ops]
     assert all(found), [op["name"] for op in ops]
     assert sorted(m.group(0) for m in found) == sorted(names)
+
+
+# --- the decode programs hold the KV pool in place --------------------------
+
+# the cell serve-chat-open's pool: 8 layers x 5,882 blocks x 8 KV heads
+# x 16 rows x 128, bf16, 32 slots over tables of 256 blocks. The model
+# around it is narrow (the compile is about the pool, and fast)
+_POOL = (8, 5882, 8, 16, HD)
+_SLOTS, _TABLE = 32, 256
+_LAYER_BYTES = 2 * 5882 * 8 * 16 * HD     # one layer's K (or V), bf16: 193 MB
+# what may have a result of the pool's shape: the kernels (which alias
+# it), and what passes a buffer on without touching it
+_PASS_ON = {"parameter", "tuple", "get-tuple-element", "bitcast", "while"}
+
+
+def _decode_args(chip, wq=None, tp=None):
+    """Shapes of a paged_decode_steps / paged_verify_steps call at the
+    cell's pool geometry, on one described chip or (``tp``: a mesh of
+    the topology's four) tensor-parallel as the engine lays them out."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.llm import model as lm
+    from ray_tpu.models import llama
+    cfg = llama.LlamaConfig(vocab_size=2048, dim=8 * HD, n_layers=_POOL[0],
+                            n_heads=8, n_kv_heads=_POOL[2], ffn_dim=1024,
+                            dtype="bfloat16")
+
+    def shape(s, d, spec=P()):
+        return jax.ShapeDtypeStruct(
+            s, d, sharding=NamedSharding(tp, spec) if tp else chip)
+
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a, spec: shape(a.shape, a.dtype, spec), params,
+        lm.serve_param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+    pool = {k: shape(_POOL, jnp.bfloat16,
+                     P(None, None, "tensor", None, None)) for k in "kv"}
+    ids = shape((_SLOTS,), jnp.int32)
+    args = [params, pool, shape((_SLOTS, _TABLE), jnp.int32), ids]
+    if wq:
+        return pool, args + [shape((_SLOTS, wq), jnp.int32), cfg]
+    return pool, args + [ids, shape((_SLOTS,), jnp.float32),
+                         shape((2,), jnp.uint32), cfg, 8, None, None]
+
+
+def _pool_shaped(compiled, tp=1) -> list:
+    """(opcode, name) of every instruction of the compiled program
+    with a result of the pool's shape — one layer of it, all layers
+    stacked or flat; under ``tp`` a shard's heads — the Pallas
+    kernels apart."""
+    bench = _bench_kernels()
+    layers, blocks, *block = _POOL
+    block[0] //= tp
+    shapes = {(n, *block) for n in (blocks, layers * blocks)} \
+        | {(layers, blocks, *block)}
+    found = []
+    for ln in compiled.as_text().splitlines():
+        op = bench.parse_op(ln.strip().removeprefix("ROOT "))
+        if shapes & {dims for _, dims in op["result"]} \
+                and not op.get("custom_kernel"):
+            found.append((op["opcode"], op["name"]))
+    return found
+
+
+def _kernel_ops(compiled) -> list:
+    bench = _bench_kernels()
+    ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
+    for op in ops:
+        op["class"] = bench.classify(op)
+    return ops
+
+
+@pytest.mark.parametrize("tp", [1, 4], ids=["one_chip", "tp4"])
+def test_decode_steps_update_the_pool_in_place(topo, chip, tp):
+    """paged_decode_steps (n = 8) as the chat cell runs it, compiled
+    for the described v5e: the donated pool is the ONE pool-sized
+    buffer of the program. Before PR 31 XLA converted the whole pool
+    between its own layout for the one-row write and the kernel's, on
+    every layer: eight pool-sized copies a step, 3.9 GB of
+    temporaries, three quarters of the cell's device time — and
+    nothing on a CPU showed it. This is the guard."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from ray_tpu.llm import kvcache
+    mesh = Mesh(np.asarray(topo.devices), ("tensor",)) if tp > 1 else None
+    pool, args = _decode_args(chip, tp=mesh)
+    compiled = kvcache.decode_steps_program(
+        pool, impl="paged_flash", mesh=mesh).lower(*args).compile()
+    # (a) nothing but the kernels makes a pool-sized array
+    held = _pool_shaped(compiled, tp)
+    assert held and {code for code, _ in held} <= _PASS_ON, held
+    # (b) no pool-sized temporary: under ONE layer's K, and the pool
+    # that comes in is the pool that goes out
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < _LAYER_BYTES // tp
+    assert mem.alias_size_in_bytes >= 2 * _POOL[0] * _LAYER_BYTES // tp
+    # (c) the benchmark's reduction tells the walk from the writer,
+    # and each carries its name
+    ops = _kernel_ops(compiled)
+    assert {op["class"] for op in ops} == {"paged_decode",
+                                           "unknown_kernel"}
+    names = {"paged_decode": "paged_decode", "unknown_kernel": "kv_write"}
+    for op in ops:
+        assert names[op["class"]] in op["name"], op["name"]
+    # the writer's signature, as the issue fixed it
+    writer = next(op for op in ops if op["class"] == "unknown_kernel")
+    assert [(d, len(s)) for d, s in writer["operands"]] == [
+        ("s32", 1), ("s32", 1), ("bf16", 3), ("bf16", 3), ("bf16", 4),
+        ("bf16", 4)]
+
+
+def test_verify_steps_write_the_pool_in_place(chip):
+    """paged_verify_steps (w = 5): the same writer (160 entries), then
+    an XLA gather over the flat pool, the view of every slot's table
+    (0.57 GB at this geometry: what a fused verify kernel would
+    save). No cell runs it; what holds is held: no pool-sized copy,
+    temporaries under one stacked pool."""
+    from ray_tpu.llm import kvcache
+    pool, args = _decode_args(chip, wq=5)
+    compiled = kvcache.verify_steps_program(
+        pool, 5, impl="paged_flash").lower(*args).compile()
+    held = _pool_shaped(compiled)
+    assert held and {code for code, _ in held} <= _PASS_ON, held
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < _POOL[0] * _LAYER_BYTES
+    ops = _kernel_ops(compiled)
+    assert {op["class"] for op in ops} == {"unknown_kernel"}
+    assert all("kv_write" in op["name"] for op in ops)

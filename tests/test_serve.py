@@ -156,6 +156,29 @@ def test_replica_recovery(cluster):
     assert out == [f"r:{i}" for i in range(6)]
 
 
+def test_a_busy_event_loop_is_not_a_dead_replica(cluster):
+    """A replica whose loop stands still for longer than a request may
+    (a profiler trace being stopped on it took 12.6 s on the chip) is
+    alive: the controller's health check waits for it and does not
+    replace it (at a 10-s timeout it did, mid-run)."""
+    @serve.deployment
+    class Stalls:
+        async def __call__(self, seconds=0.0):
+            time.sleep(seconds)         # on the loop, on purpose
+            return os.getpid()
+
+    import os
+    h = serve.run(Stalls.bind(), name="app_stall", route_prefix=None)
+    pid = ray_tpu.get(h.remote(), timeout=30)
+    before = set(serve.status()["Stalls"]["replicas"])
+    assert ray_tpu.get(h.remote(12.0), timeout=60) == pid
+    time.sleep(1.5)                     # a reconcile or two after it
+    st = serve.status()["Stalls"]["replicas"]
+    assert set(st) == before, st
+    assert [r["state"] for r in st.values()] == ["RUNNING"]
+    assert ray_tpu.get(h.remote(), timeout=30) == pid
+
+
 def test_autoscaling_up_and_down(cluster):
     @serve.deployment(autoscaling_config={
         "min_replicas": 1, "max_replicas": 3,

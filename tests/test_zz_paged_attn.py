@@ -397,6 +397,127 @@ def test_platform_picks_engine_impl(tiny_model, monkeypatch):
     assert eng._kv_impl == "paged_flash" and eng._kv_interpret
 
 
+# --- the aliased block writer (interpret mode) ------------------------
+
+
+def _write_case(seed, *, layers=2, nb=6, kvh=2, bs=8, hd=128,
+                dtype=jnp.float32):
+    """Stacked random pools (layers, nb, kvh, bs, hd) in ``dtype``."""
+    rng = np.random.default_rng(seed)
+    shape = (layers, nb, kvh, bs, hd)
+    return [jnp.asarray(rng.normal(size=shape).astype(np.float32))
+            .astype(dtype) for _ in "kv"]
+
+
+def _check_kv_write(kp, vp, layer, phys, rows):
+    """kv_write on layer ``layer`` of the flat pools against the
+    scatter it replaces: BIT-EQUAL on the whole of both pools."""
+    nb, kvh, hd = kp.shape[1], kp.shape[2], kp.shape[4]
+    rng = np.random.default_rng(len(phys))
+    new = [jnp.asarray(rng.normal(size=(len(phys), kvh, hd))
+                       .astype(np.float32)).astype(kp.dtype)
+           for _ in "kv"]
+    phys, rows = (jnp.asarray(x, jnp.int32) for x in (phys, rows))
+    # the scatter's order over equal places is not defined: entries
+    # for one place carry the same row, so that any order is right
+    _, place = np.unique(np.stack([phys, rows], 1), axis=0,
+                         return_inverse=True)
+    new = [n[np.asarray(place).reshape(-1)] for n in new]
+    flat = [p.reshape(-1, *p.shape[2:]) for p in (kp, vp)]
+    got = jax.jit(functools.partial(pa.kv_write, interpret=True))(
+        *flat, layer * nb + phys, rows, *new)
+    for g, pool, n in zip(got, (kp, vp), new):
+        want = pool.at[layer, phys, :, rows].set(n)
+        assert g.dtype == pool.dtype
+        np.testing.assert_array_equal(
+            np.asarray(g.reshape(pool.shape).astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+
+
+_WRITES = {     # (physical blocks, rows) at block size bs
+    "row_0": lambda bs: ([3, 1], [0, 0]),
+    "row_last": lambda bs: ([2, 5], [bs - 1, bs - 1]),
+    # idle slots all write the trash block, beside a live one
+    "trash_block": lambda bs: ([0, 0, 4, 0], [1, 1, 2, 1]),
+    # a verify round: a slot's drafts follow each other in one block
+    "two_in_a_block": lambda bs: ([3, 3, 3, 4], [bs - 2, bs - 1, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITES))
+@pytest.mark.parametrize("layer", [0, 1])
+def test_kv_write_is_the_scatter_bit_for_bit(case, layer):
+    kp, vp = _write_case(1)
+    _check_kv_write(kp, vp, layer, *_WRITES[case](kp.shape[3]))
+
+
+@pytest.mark.parametrize("bs", [8, 16, 32])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kvh", [1, 8, 32])
+def test_kv_write_block_sizes_dtypes_heads(bs, dtype, kvh):
+    """One algorithm whose parameters are the pool's shape and dtype."""
+    kp, vp = _write_case(2, kvh=kvh, bs=bs, dtype=jnp.dtype(dtype))
+    _check_kv_write(kp, vp, 1, [5, 2, 2, 0], [bs - 1, 0, 3, 1])
+
+
+def test_kv_write_refuses_rows_the_pool_cannot_take():
+    kp, vp = (p.reshape(-1, *p.shape[2:]) for p in _write_case(3))
+    ids = jnp.zeros((2,), jnp.int32)
+    rows = jnp.zeros((2, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match="do not take rows"):
+        pa.kv_write(kp, vp, ids, ids, rows[:, :1], rows[:, :1])
+    with pytest.raises(ValueError, match="one dtype"):
+        pa.kv_write(kp, vp, ids, ids, rows.astype(jnp.bfloat16),
+                    rows.astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("n", [1, 8])
+def test_decode_steps_kernel_matches_gather_in_place(tiny_model, n):
+    """paged_decode_steps — the engine's one decode call — with the
+    writer and the walk interpreted against the gather reference, n
+    steps (an idle slot writing the trash block beside live ones, a
+    slot crossing a block edge): the same tokens; the same pools — bit
+    for bit wherever no step wrote and in layer 0 (its rows come from
+    the tokens alone), to f32 rounding in the rows of layer 1 (the
+    online softmax is a refactoring of the reference's, not its
+    bits); and the donated pool consumed, not copied."""
+    cfg, params = tiny_model
+    bs, w, slots = 8, 4, 4
+    tables = np.zeros((slots, w), np.int32)     # slot 3 idle: trash
+    tables[:3] = (1 + np.arange(3 * w)).reshape(3, w)
+    lengths = np.asarray([1, 12, 6, 0], np.int32)
+    tokens = jnp.asarray([3, 9, 27, 0], jnp.int32)
+    temps = jnp.zeros((slots,), jnp.float32)
+    fresh = kc.init_pool(cfg, 1 + 3 * w, bs, jnp.float32)
+    fresh = {k: np.asarray(jax.random.normal(jax.random.PRNGKey(i),
+                                             v.shape))
+             for i, (k, v) in enumerate(fresh.items())}
+
+    def run(**kw):
+        pool = {k: jnp.asarray(v) for k, v in fresh.items()}
+        out, new = kc.paged_decode_steps(
+            params, pool, jnp.asarray(tables), jnp.asarray(lengths),
+            tokens, temps, jax.random.PRNGKey(7), cfg, n, **kw)
+        assert all(v.is_deleted() for v in pool.values())
+        return np.asarray(out), {k: np.asarray(v) for k, v in new.items()}
+
+    want_toks, want = run(impl="gather")
+    got_toks, got = run(impl="paged_flash", interpret=True)
+    assert got_toks.shape == (n, slots)
+    np.testing.assert_array_equal(got_toks, want_toks)
+    pos = lengths[:, None] + np.arange(n)[None]             # (slots, n)
+    phys = np.take_along_axis(tables, pos // bs, axis=1)
+    wrote = np.zeros(fresh["k"].shape[1:4:2], bool)         # (nb, bs)
+    wrote[phys, pos % bs] = True
+    for k in "kv":
+        np.testing.assert_array_equal(got[k][0], want[k][0])
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-5, atol=2e-5)
+        still = ~np.broadcast_to(wrote[None, :, None, :, None],
+                                 got[k].shape)
+        np.testing.assert_array_equal(got[k][still], fresh[k][still])
+        assert (got[k][~still] != fresh[k][~still]).any()
+
+
 # --- decode-path parity through the engine ----------------------------
 
 
