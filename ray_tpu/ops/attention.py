@@ -85,18 +85,17 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     # Under jax.checkpoint with a save_only_these_names policy, naming the
     # kernel outputs lets the backward pass reuse them instead of re-running
     # the forward kernel (q/k/v are cheap weight-matmul recomputes; o/lse
-    # are not). The lse residual is stored logically (BH, S, 1) — saving the
-    # kernel's lane-broadcast (BH, S, LANES) layout would cost 128x the HBM.
+    # are not). lse is (BH, 1, S), one value a lane, as the kernels write
+    # and read it.
     from jax.ad_checkpoint import checkpoint_name
     o = checkpoint_name(o, "attn_out")
-    lse_small = checkpoint_name(lse[:, :, :1], "attn_lse")
-    return o, (q, k, v, o, lse_small)
+    lse = checkpoint_name(lse, "attn_lse")
+    return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, q_offset,
                res, do):
-    q, k, v, o, lse_small = res
-    lse = jnp.broadcast_to(lse_small, lse_small.shape[:2] + (_fa.LANES,))
+    q, k, v, o, lse = res
     dq, dk, dv = _fa.flash_attention_bwd(
         q, k, v, o, do, lse, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret)

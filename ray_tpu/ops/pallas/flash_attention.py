@@ -5,16 +5,53 @@ S x S score matrix out of HBM; blocks are sized for the MXU (128 lanes) and
 VMEM residency. Used by :mod:`ray_tpu.ops.attention` which wires it into a
 ``jax.custom_vjp``.
 
-Design notes (measured on v5e):
+Design notes (measured on a v5e at the train cells' shapes: b*h 128, 4096
+causal tokens, head 128, 1024 x 1024 tiles, bf16; PR 34, PERF.md section 6):
 - Matmul operands stay in the input dtype (bf16) with f32 MXU accumulation;
   upcasting operands to f32 would halve MXU throughput.
 - ``sm_scale`` is folded into ``q`` before the kernels run, saving a full
-  elementwise pass over the S x S score matrix in every kernel (the VPU, not
-  the MXU, is the bottleneck of flash attention at long seq). The dq output
+  elementwise pass over the S x S score matrix in every kernel. The dq output
   is rescaled once outside (O(S*D), negligible).
-- One masked code path: TPU predication (pl.when) compiles both branches
-  into the kernel, so splitting interior/edge tiles doubles VMEM stack for
-  no win (measured).
+- A tile's body is STRAIGHT-LINE code over its sub-tiles. What a loop turn
+  costs is the pipeline's fill and drain, ~600 cycles, against the 256 MXU
+  cycles of a 256 x 256 forward sub-tile: the same walk as run-time loops
+  over the sub-tiles (``fori_loop``, or ``pl.when`` around each) took 21.9 ms
+  a forward call against the whole-tile body's 7.45; as one body per
+  distinct tile it takes 5.65. So the sub-tile kinds are decided when the
+  kernel is traced (the tiles that differ are few and known from the
+  shapes) and the program ids only pick the body.
+- The masks were never the cost: the whole-tile body without them on the six
+  interior tiles of ten read 7.25 ms against 7.45. What the sub-tiles buy is
+  the diagonal tiles' skipped work (a diagonal tile does 62.5% of a full
+  one's) with every sub-tile still in one basic block.
+- Per-row statistics stay lane-replicated (rows, LANES) from scratch to
+  scratch; slicing a column out ([:, :1]) and broadcasting it back costs a
+  cross-lane move per 8 rows per use. The forward's whole-tile body went
+  7.25 -> 6.25 ms with this (and no loop). Deferring the row SUM's cross-lane
+  reduction to the last tile (lane-partial sums) was 2% slower, not faster.
+- dk/dv computes its scores keys-major (s^T = k q'^T; lse and delta as
+  rows): p^T and ds^T then feed the dv and dk products as they are. The
+  queries-major form transposes both (1024 x 1024) operands: 10.18 ms a call
+  against 8.49 whole-tile, 7.43 sub-tiled.
+- A tile above the diagonal is not fetched: its index map names the block
+  the previous step held. Forward 5.65 -> 5.33 ms, dk/dv 7.43 -> 6.72 (a
+  skipped step still moved q and do, 0.5 MB), dq 6.58 -> 6.30.
+- Sub-tiles of 256 x 256: 128-row strips are slower forward (6.29 ms), 512
+  slower everywhere (5.76 / 7.73 / 6.83) and under 90% required work.
+- lse and delta travel as rows (BH, 1, S), one value a lane: the forward
+  turns its column into a row once a q tile (a transpose), dq turns the
+  rows into lane-replicated columns once a q tile, dk/dv reads them as they
+  are. Forward 5.17 -> 4.89 ms (no 512-KB lse block to write), dq 6.30 ->
+  5.98; and the train step loses two 268-MB broadcasts a layer. A version
+  that sliced a row out of the lane-replicated lse made XLA copy 268 MB a
+  layer, and in the dense cell, which fills its chip, recompute one more
+  FFN product a layer: +43 ms a step, more than the kernels had won.
+  As shipped: forward 4.89 ms (57.1% of the compute roofline by the
+  benchmark's count), dk/dv 6.72 + dq 5.98 (54.9%); before 7.45 (37.5%) and
+  10.18 + 7.49 (39.5%).
+- exp runs in the input dtype, as before. On this chip f32 exp is FASTER in
+  the forward (5.26 against 5.65 ms; no change backward) and more exact, but
+  it changes the numbers every parity check reads: left for its own change.
 
 Sequence lengths need not divide the block size: wrappers zero-pad to block
 multiples and kernels mask out-of-bounds columns (padded rows are sliced off
@@ -34,7 +71,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-LANES = 128  # m/l scratch are broadcast along the lane dim
+LANES = 128  # per-row statistics are replicated along the lane dim
 
 
 def _pad_seq(x, block):
@@ -46,21 +83,191 @@ def _pad_seq(x, block):
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
 
 
-def _mask_s(s, qi, ki, block_q, block_k, kv_len, causal, offset):
-    """Bounds + causal mask for a (block_q, block_k) score tile.
+# The (block_q, block_k) tile is what the grid and the DMAs move. The
+# body cuts it into (sub_q, sub_k) sub-tiles, each one of three kinds:
+# above the causal diagonal or wholly past kv_len (never computed),
+# interior (no iota, no compare, no select) or cut by the diagonal or by
+# the kv_len edge (masked). Where the diagonal and the edge cross a tile
+# depends on two numbers only (``_tile_key``), so the few tiles that
+# differ are known from the shapes: each gets its own straight-line body
+# (a ``plan``), chosen by the program ids.
+_SUB_WIDTHS = (256, 128)
 
-    ``offset = sk - sq`` aligns the causal diagonal with the END of the kv
-    sequence (query i attends keys j <= i + offset), matching mha_reference —
-    e.g. a decode step (sq=1) against a longer KV cache attends everything.
-    """
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    keep = cols < kv_len
+
+def _sub_tiles(block_q, block_k):
+    """(sub_q, sub_k): the widest listed width that divides the block,
+    or the block itself (a tile of one sub-tile)."""
+    def width(block):
+        return next((w for w in _SUB_WIDTHS if block % w == 0), block)
+    return width(block_q), width(block_k)
+
+
+def _geometry(block_q, block_k, kv_len, offset, causal):
+    """What the plans and the three kernels are built from."""
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
+    return dict(block_q=block_q, block_k=block_k, sub_q=sub_q, sub_k=sub_k,
+                kv_len=kv_len, offset=offset, causal=causal)
+
+
+def _k_bounds(d, r, sub_q, sub_k, n_sub, causal):
+    """The keys of one q sub-tile, in sub-tiles of ``sub_k``: ``(n_int,
+    n_run)`` with [0, n_int) interior, [n_int, n_run) masked and the
+    rest skipped. ``d`` is the last key, counted from the tile's first,
+    that the sub-tile's FIRST row attends (row i attends keys
+    <= i + offset), ``r`` the keys left before kv_len."""
+    lo, hi = r - 1, r - 1           # last key every row / any row attends
     if causal:
-        keep = jnp.logical_and(keep, rows + offset >= cols)
-    return jnp.where(keep, s, NEG_INF), keep
+        lo, hi = min(lo, d), min(hi, d + sub_q - 1)
+    return (min(max(lo + 1, 0) // sub_k, n_sub),
+            min(max(hi + sub_k, 0) // sub_k, n_sub))
+
+
+def _q_bounds(d, r, sub_q, sub_k, n_sub, causal):
+    """The same cut seen from one k sub-tile, over the tile's queries in
+    sub-tiles of ``sub_q``: ``(first_run, first_int)`` with
+    [0, first_run) skipped, [first_run, first_int) masked and the rest
+    interior. ``d`` is the last key, counted from the SUB-TILE's first,
+    that the tile's first row attends; ``r`` the keys left from there."""
+    first_run = first_int = 0
+    if causal:          # rows that reach the first key; that reach the last
+        first_run = max(-d, 0) // sub_q
+        first_int = max(sub_k - 1 - d + sub_q - 1, 0) // sub_q
+    if r < sub_k:       # the kv_len edge cuts every row
+        first_int = n_sub
+    if r <= 0:
+        first_run = n_sub
+    return min(first_run, n_sub), min(first_int, n_sub)
+
+
+def _tile_key(qi, ki, block_q, block_k, kv_len, offset, causal):
+    """What a tile's plan depends on: how far the diagonal is from the
+    tile's corner (clamped where it no longer cuts the tile) and the
+    keys left before kv_len. Python ints or traced scalars."""
+    lo, hi = (min, max) if isinstance(qi, int) else (jnp.minimum,
+                                                     jnp.maximum)
+    r = lo(kv_len - ki * block_k, block_k)
+    if not causal:
+        return block_k - 1, r
+    return lo(hi(qi * block_q + offset - ki * block_k, -block_q),
+              block_k - 1), r
+
+
+def _plans(nq, nk, by, *, block_q, block_k, sub_q, sub_k, kv_len, offset,
+           causal):
+    """{key: plan} over the grid's tiles. A plan lists the bounds of
+    each q sub-tile over the tile's keys (``by`` "q": forward and dq) or
+    of each k sub-tile over its queries ("k": dk/dv)."""
+    plans = {}
+    for qi in range(nq):
+        for ki in range(nk):
+            d, r = _tile_key(qi, ki, block_q, block_k, kv_len, offset,
+                             causal)
+            if by == "q":
+                plan = tuple(
+                    _k_bounds(d + i * sub_q, r, sub_q, sub_k,
+                              block_k // sub_k, causal)
+                    for i in range(block_q // sub_q))
+            else:
+                plan = tuple(
+                    _q_bounds(d - j * sub_k, r - j * sub_k, sub_q, sub_k,
+                              block_q // sub_q, causal)
+                    for j in range(block_k // sub_k))
+            plans[d, r] = plan
+    return plans
+
+
+def tile_plan(sq, sk, block_q, block_k, *, causal=True, q_offset=None):
+    """What the kernels do at these shapes, counted from the plans they
+    are built from: sub-tiles ``skipped`` / ``interior`` / ``masked``
+    over the tiles of one head, the distinct tile ``bodies``, and
+    ``required_share``: the (query, key) pairs attention requires over
+    the pairs computed."""
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    offset = (sk - sq) if q_offset is None else int(q_offset)
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    geom = _geometry(block_q, block_k, sk, offset, causal)
+    sub_q, sub_k = geom["sub_q"], geom["sub_k"]
+    plans = _plans(nq, nk, "q", **geom)
+    n_sub = block_k // sub_k
+    out = {"sub_q": sub_q, "sub_k": sub_k, "skipped": 0, "interior": 0,
+           "masked": 0,
+           "bodies": sum(any(run for _, run in p) for p in plans.values())}
+    for qi in range(nq):
+        for ki in range(nk):
+            for n_int, n_run in plans[_tile_key(qi, ki, block_q, block_k, sk,
+                                                offset, causal)]:
+                out["interior"] += n_int
+                out["masked"] += n_run - n_int
+                out["skipped"] += n_sub - n_run
+    required = sum(min(sk, i + offset + 1) if causal else sk
+                   for i in range(sq) if not causal or i + offset >= 0)
+    computed = (out["interior"] + out["masked"]) * sub_q * sub_k
+    out["required_share"] = required / computed if computed else 1.0
+    return out
+
+
+def _on_plan(plans, key, body):
+    """Run ``body(plan)`` of the tile whose key the program ids give."""
+    for (d, r), plan in plans.items():
+        pl.when(jnp.logical_and(key[0] == d, key[1] == r))(
+            functools.partial(body, plan))
+
+
+def _keep(row0, col0, shape, kv_len, causal, offset, keys_dim=1):
+    """Bounds + causal mask of a masked piece whose first query is
+    ``row0`` and first key ``col0``; keys run along ``keys_dim``."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, keys_dim)
+    keep = cols < kv_len - col0
+    if causal:
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - keys_dim)
+        keep = jnp.logical_and(keep, cols - rows <= row0 + offset - col0)
+    return keep
+
+
+def _mask_piece(x, keep, lo, hi, fill):
+    """``x`` with its columns [lo, hi) put under ``keep``."""
+    if lo == hi:
+        return x
+    parts = [jnp.where(keep, x[:, lo:hi], fill)]
+    if lo:
+        parts.insert(0, x[:, :lo])
+    if hi < x.shape[1]:
+        parts.append(x[:, hi:])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _lanes(x, n):
+    """A lane-replicated (rows, LANES) statistic, ``n`` columns wide."""
+    if n <= LANES:
+        return x[:, :n]
+    if n % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return jnp.concatenate([x] * (n // LANES), axis=1)
+
+
+def _to_row(col):
+    """A lane-replicated (n, LANES) statistic as a row (1, n)."""
+    return col.T[:1, :]
+
+
+def _to_col(row):
+    """A row (1, n) as a lane-replicated (n, LANES) column."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T
+
+
+def _pdt(dtype):
+    """exp and p in the input dtype (p feeds an MXU matmul in it
+    anyway); f32 inputs keep f32."""
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _last_k_block(qi, block_q, block_k, num_kv_blocks, offset):
@@ -70,9 +277,20 @@ def _last_k_block(qi, block_q, block_k, num_kv_blocks, offset):
     return jnp.clip(last, 0, num_kv_blocks - 1)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                causal, block_q, block_k, num_kv_blocks, kv_len,
-                offset, with_lse):
+def _kv_map(nk, *, causal, block_q, block_k, offset, **_):
+    """K and V blocks over a (b, qi, ki) grid. A tile above the diagonal
+    is not fetched either: it names the block the step before it held."""
+    def index(b, qi, ki):
+        if causal:
+            ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, nk,
+                                               offset))
+        return (b, ki, 0)
+    return index
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
+                block_k, sub_q, sub_k, num_kv_blocks, kv_len, offset,
+                with_lse):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -80,6 +298,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    d = q_ref.shape[-1]
 
     @pl.when(ki == 0)
     def _init():
@@ -87,59 +306,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def tile(plan):
+        pdt = _pdt(q_ref.dtype)
+        for iq, (n_int, n_run) in enumerate(plan):
+            if not n_run:
+                continue
+            rows = slice(iq * sub_q, (iq + 1) * sub_q)
+            lo, w = n_int * sub_k, n_run * sub_k    # keys: unmasked, all
+            q = q_ref[0, rows, :]                   # (sub_q, d), pre-scaled
+            v = v_ref[0, :w, :]                     # (w, d)
+            s = _dot(q, k_ref[0, :w, :], _NT)       # (sub_q, w) f32
+            keep = None if lo == w else _keep(
+                qi * block_q + iq * sub_q, ki * block_k + lo,
+                (sub_q, w - lo), kv_len, causal, offset)
+            s = _mask_piece(s, keep, lo, w, NEG_INF)
+            # m, l and alpha are lane-replicated (sub_q, LANES)
+            m_prev = m_scr[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp((s - _lanes(m_new, w)).astype(pdt))
+            # a fully masked row's exp(0) too
+            p = _mask_piece(p, keep, lo, w, pdt(0.0))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[rows, :] = alpha * l_scr[rows, :] + jnp.sum(
+                p.astype(jnp.float32), axis=-1, keepdims=True)
+            acc_scr[rows, :] = acc_scr[rows, :] * _lanes(alpha, d) + _dot(
+                p.astype(v.dtype), v, _NN)
+            m_scr[rows, :] = m_new
+
+    _on_plan(plans, _tile_key(qi, ki, block_q, block_k, kv_len, offset,
+                              causal), tile)
+
     # Last kv block this q block attends to (inclusive).
     if causal:
         last_k = _last_k_block(qi, block_q, block_k, num_kv_blocks, offset)
     else:
         last_k = num_kv_blocks - 1
 
-    @pl.when(ki <= last_k)
-    def _compute():
-        q = q_ref[0]                                # (block_q, d), pre-scaled
-        k = k_ref[0]                                # (block_k, d)
-        v = v_ref[0]                                # (block_k, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s, keep = _mask_s(s, qi, ki, block_q, block_k,
-                          kv_len, causal, offset)
-
-        m_prev = m_scr[...][:, :1]                  # (block_q, 1)
-        l_prev = l_scr[...][:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # (block_q, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # exp in the input dtype: bf16 exp is measurably faster on the VPU
-        # and p feeds a bf16 MXU matmul anyway; f32 inputs keep f32 exp.
-        pdt = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
-        p = jnp.where(keep, jnp.exp((s - m_new).astype(pdt)), pdt(0.0))
-        alpha = jnp.exp(m_prev - m_new)             # (block_q, 1)
-        l_new = alpha * l_prev + jnp.sum(p.astype(jnp.float32), axis=-1,
-                                         keepdims=True)
-
-        acc = acc_scr[...]
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[...] = acc
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
     @pl.when(ki == last_k)
     def _finalize():
-        m = m_scr[...][:, :1]
-        l = l_scr[...][:, :1]
+        l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)             # fully-masked rows
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / _lanes(l, d)).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref[0].shape)
+            lse_ref[0] = _to_row(m_scr[...] + jnp.log(l))
 
 
 def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
                         interpret=False, with_lse=True, q_offset=None):
-    """q,k,v: (BH, S, D) -> (o: (BH, S, D), lse: (BH, S, LANES) f32 | None).
+    """q,k,v: (BH, S, D) -> (o: (BH, S, D), lse: (BH, 1, S) f32 | None).
 
-    lse is the row logsumexp saved as a backward residual (lane-broadcast
-    layout; logically (BH, S)). Inference callers pass with_lse=False to
-    skip the extra HBM write (pallas outputs are never DCE'd).
+    lse is the row logsumexp saved as a backward residual, one value a
+    lane (a lane-replicated (BH, S, LANES) copy is 128 times the bytes,
+    and in a train step that fills its chip the temporaries it costs
+    make XLA recompute a matmul). Inference callers pass with_lse=False
+    to skip the HBM write (pallas outputs are never DCE'd).
 
     ``q_offset`` places the causal diagonal: query row i attends keys
     <= i + q_offset. Default (None) = sk - sq, i.e. queries are the
@@ -156,26 +376,27 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
     nq = qp.shape[1] // block_q
     nk = kp.shape[1] // block_k
 
+    tiles = _geometry(block_q, block_k, sk, offset, causal)
     kernel = functools.partial(
-        _fwd_kernel, causal=causal,
-        block_q=block_q, block_k=block_k, num_kv_blocks=nk, kv_len=sk,
-        offset=offset, with_lse=with_lse)
+        _fwd_kernel, plans=_plans(nq, nk, "q", **tiles), num_kv_blocks=nk,
+        with_lse=with_lse, **tiles)
+    kv_map = _kv_map(nk, **tiles)
 
     out_specs = [pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))]
     out_shape = [jax.ShapeDtypeStruct(qp.shape, q.dtype)]
     if with_lse:
         out_specs.append(
-            pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)))
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)))
         out_shape.append(
-            jax.ShapeDtypeStruct((bh, qp.shape[1], LANES), jnp.float32))
+            jax.ShapeDtypeStruct((bh, 1, qp.shape[1]), jnp.float32))
 
     res = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -191,7 +412,7 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
     )(qp, kp, vp)
     if with_lse:
         out, lse = res
-        return out[:, :sq], lse[:, :sq]
+        return out[:, :sq], lse[:, :, :sq]
     return res[0][:, :sq], None
 
 
@@ -205,9 +426,11 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
 # ---------------------------------------------------------------------------
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, block_q, block_k, num_q_blocks, kv_len,
-                offset):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, plans, causal, block_q,
+                block_k, sub_q, sub_k, num_q_blocks, kv_len, offset):
+    """Scores are computed keys-major, s^T = k q'^T, so that p^T and
+    ds^T feed the dv and dk products as they are; lse and delta arrive
+    as rows (1, block_q), one value a lane."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -216,38 +439,34 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if causal:
-        # First q block whose rows attend this kv block: i + offset >= ki*bk.
-        first_q = jnp.maximum(0, ki * block_k - offset) // block_q
-        should_run = qi >= first_q
-    else:
-        should_run = qi >= 0
+    def tile(plan):
+        pdt = _pdt(q_ref.dtype)
+        for jk, (first_run, first_int) in enumerate(plan):
+            if first_run * sub_q == block_q:
+                continue
+            cols = slice(jk * sub_k, (jk + 1) * sub_k)
+            r0 = first_run * sub_q                  # queries from here on,
+            hi = first_int * sub_q - r0             # masked up to here
+            k = k_ref[0, cols, :]                   # (sub_k, d)
+            v = v_ref[0, cols, :]
+            q = q_ref[0, r0:, :]                    # (n, d), pre-scaled
+            do = do_ref[0, r0:, :]
+            s = _dot(k, q, _NT)                     # (sub_k, n) f32
+            p = jnp.exp((s - lse_ref[0, :, r0:]).astype(pdt))
+            if hi:
+                p = _mask_piece(p, _keep(
+                    qi * block_q + r0, ki * block_k + jk * sub_k,
+                    (sub_k, hi), kv_len, causal, offset, keys_dim=0),
+                    0, hi, pdt(0.0))
+            # dv += p^T do
+            dv_scr[cols, :] += _dot(p.astype(do.dtype), do, _NN)
+            # dp^T = v do^T ; ds^T = p^T * (dp^T - delta)
+            ds = p * (_dot(v, do, _NT) - delta_ref[0, :, r0:])
+            # dk = ds^T q'  (q' = sm_scale*q: the scale is included)
+            dk_scr[cols, :] += _dot(ds.astype(q.dtype), q, _NN)
 
-    @pl.when(should_run)
-    def _compute():
-        q = q_ref[0]                                # (bq, d), pre-scaled
-        k = k_ref[0]                                # (bk, d)
-        v = v_ref[0]
-        do = do_ref[0]                              # (bq, d)
-        lse = lse_ref[0][:, :1]                     # (bq, 1)
-        delta = delta_ref[0][:, :1]                 # (bq, 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s, keep = _mask_s(s, qi, ki, block_q, block_k, kv_len, causal, offset)
-        pdt = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
-        p = jnp.where(keep, jnp.exp((s - lse).astype(pdt)), pdt(0.0))  # (bq, bk)
-        # dv += p^T do
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dp = do v^T ; ds = p * (dp - delta)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        # dk = ds^T q'  (q' = sm_scale*q, so the scale is already included)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _on_plan(plans, _tile_key(qi, ki, block_q, block_k, kv_len, offset,
+                              causal), tile)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -256,8 +475,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr,
-               *, causal, block_q, block_k, num_kv_blocks, kv_len,
+               dq_ref, dq_scr, lse_scr, delta_scr, *, plans, causal,
+               block_q, block_k, sub_q, sub_k, num_kv_blocks, kv_len,
                offset):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -265,32 +484,40 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # the rows lse and delta arrive as, turned once a q tile into
+        # lane-replicated (block_q, LANES) columns
+        lse_scr[...] = _to_col(lse_ref[0])
+        delta_scr[...] = _to_col(delta_ref[0])
+
+    def tile(plan):
+        pdt = _pdt(q_ref.dtype)
+        for iq, (n_int, n_run) in enumerate(plan):
+            if not n_run:
+                continue
+            rows = slice(iq * sub_q, (iq + 1) * sub_q)
+            lo, w = n_int * sub_k, n_run * sub_k    # keys: unmasked, all
+            q = q_ref[0, rows, :]                   # pre-scaled
+            do = do_ref[0, rows, :]
+            k = k_ref[0, :w, :]
+            s = _dot(q, k, _NT)                     # (sub_q, w) f32
+            p = jnp.exp((s - _lanes(lse_scr[rows, :], w)).astype(pdt))
+            if lo < w:
+                p = _mask_piece(p, _keep(
+                    qi * block_q + iq * sub_q, ki * block_k + lo,
+                    (sub_q, w - lo), kv_len, causal, offset),
+                    lo, w, pdt(0.0))
+            ds = p * (_dot(do, v_ref[0, :w, :], _NT)
+                      - _lanes(delta_scr[rows, :], w))
+            # dq' = ds k ; wrapper multiplies by sm_scale once outside.
+            dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _on_plan(plans, _tile_key(qi, ki, block_q, block_k, kv_len, offset,
+                              causal), tile)
 
     if causal:
         last_k = _last_k_block(qi, block_q, block_k, num_kv_blocks, offset)
     else:
         last_k = num_kv_blocks - 1
-
-    @pl.when(ki <= last_k)
-    def _compute():
-        q = q_ref[0]                                # pre-scaled
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s, keep = _mask_s(s, qi, ki, block_q, block_k, kv_len, causal, offset)
-        pdt = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
-        p = jnp.where(keep, jnp.exp((s - lse).astype(pdt)), pdt(0.0))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        # dq' = ds k ; wrapper multiplies by sm_scale once outside.
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
 
     @pl.when(ki == last_k)
     def _finalize():
@@ -299,7 +526,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd(q, k, v, o, do, lse, *, sm_scale, causal,
                         block_q=128, block_k=128, interpret=False):
-    """lse: (BH, S, LANES) f32 from flash_attention_fwd."""
+    """lse: (BH, 1, S) f32 from flash_attention_fwd."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
     block_q = min(block_q, sq)
@@ -309,27 +536,39 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, sm_scale, causal,
     qp = _pad_seq(q, block_q)
     kp, vp = _pad_seq(k, block_k), _pad_seq(v, block_k)
     op, dop = _pad_seq(o, block_q), _pad_seq(do, block_q)
-    lse = _pad_seq(lse, block_q)
     sqp, skp = qp.shape[1], kp.shape[1]
+    lse = jnp.pad(lse, ((0, 0), (0, 0), (0, sqp - sq)))
     nq = sqp // block_q
     nk = skp // block_k
+    tiles = _geometry(block_q, block_k, sk, offset, causal)
+    kv_map = _kv_map(nk, **tiles)
 
+    def first_q(ki, qi):    # dk/dv's twin of kv_map: queries not fetched
+        if causal:
+            first = jnp.maximum(0, ki * block_k - offset) // block_q
+            qi = jnp.maximum(qi, jnp.minimum(first, nq - 1))
+        return qi
+
+    # like lse one value a lane, (bh, 1, sqp): dk/dv has its queries
+    # along the lanes, dq turns both into columns once a q tile
     delta = jnp.sum(dop.astype(jnp.float32) * op.astype(jnp.float32),
-                    axis=-1)                                  # (bh, sqp)
-    delta = jnp.broadcast_to(delta[:, :, None], (bh, sqp, LANES))
+                    axis=-1)[:, None, :]
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q_blocks=nq,
-                          kv_len=sk, offset=offset),
+        functools.partial(_dkv_kernel, num_q_blocks=nq,
+                          plans=_plans(nq, nk, "k", **tiles), **tiles),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, d),
+                         lambda b, ki, qi: (b, first_q(ki, qi), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, d),
+                         lambda b, ki, qi: (b, first_q(ki, qi), 0)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, ki, qi: (b, 0, first_q(ki, qi))),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, ki, qi: (b, 0, first_q(ki, qi))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
@@ -350,21 +589,22 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, sm_scale, causal,
     )(qp, kp, vp, dop, lse, delta)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, num_kv_blocks=nk,
-                          kv_len=sk, offset=offset),
+        functools.partial(_dq_kernel, num_kv_blocks=nk,
+                          plans=_plans(nq, nk, "q", **tiles), **tiles),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sqp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,

@@ -143,6 +143,41 @@ def test_kernel_compiles_for_v5e(chip, name):
         assert bench.classify(op) in op["name"], op["name"]
 
 
+# --- the flash kernels at the three train cells' shapes ---------------------
+
+# (batch on this chip, heads on this chip): train-dense-1chip 4 x 32,
+# train-yi34b-4chip 4 x 28 (batch 8 over fsdp 2, 56 heads over tensor 2),
+# train-olmoe 4 x 16; 4096 tokens, head 128, 1024 x 1024 tiles
+_CELL_HEADS = {"dense_bh128": 32, "yi_bh112": 28, "olmoe_bh64": 16}
+# operand ranks and result count by which the benchmark tells them apart
+_FLASH_SIGNATURE = {"flash_fwd": ([3] * 3, 2), "flash_bwd_dkv": ([3] * 6, 2),
+                    "flash_bwd_dq": ([3] * 6, 1)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_HEADS))
+def test_flash_kernels_compile_at_the_train_cells_shapes(chip, cell):
+    """Forward and both backward kernels, with the sub-tiled body, fit
+    the compiler's VMEM limit at each cell's b*h and keep the names,
+    operand signatures and result counts the benchmark finds them by."""
+    shape = ((4, 4096, _CELL_HEADS[cell], HD), jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(*shape, sharding=chip)] * 3
+    compiled = jax.jit(_flash_bwd).lower(*args).compile()
+    bench = _bench_kernels()
+    ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
+    assert sorted(bench.classify(op) for op in ops) == sorted(
+        _FLASH_SIGNATURE)
+    for op in ops:
+        name = bench.classify(op)
+        assert name in op["name"], op["name"]
+        ranks, results = _FLASH_SIGNATURE[name]
+        assert [len(dims) for _, dims in op["operands"]] == ranks
+        assert len(op["result"]) == results
+        # q first: the readers take b*h, seq and head_dim from it
+        assert op["operands"][0] == (
+            "bf16", (4 * _CELL_HEADS[cell], 4096, HD)), op["operands"][0]
+        assert all(dt in ("bf16", "f32") for dt, _ in op["operands"])
+
+
 # --- the grouped matmul of the MoE train path -------------------------------
 
 def _gmm(lhs, rhs, group_sizes):
