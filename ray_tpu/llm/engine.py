@@ -32,7 +32,6 @@ from typing import Callable, List, Optional, Sequence
 import numpy as np
 
 from ray_tpu.llm import kvcache, model as lm, spec as specdec
-from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.util import devmon, events, tracing
 
 logger = logging.getLogger("ray_tpu.llm.engine")
@@ -106,6 +105,28 @@ def engine_metrics() -> dict:
       llm_prefill_tokens       prompt tokens run through a prefill
                                forward per admit (prefix hits excluded)
 
+    Per decode block, as its tokens are read back (the device scalars
+    of a model with expert layers come back with them, no sync of their
+    own) and as its blocks were set aside:
+
+      llm_moe_routed_size        assignments (live slots x experts a
+                                 token) the block's steps routed, summed
+                                 over the expert layers
+      llm_moe_local_size         those of them on experts this device
+                                 holds
+      llm_moe_experts_hit_size   held experts some row reached, summed
+                                 over steps and layers (their weights
+                                 were read)
+      llm_moe_experts_held_size  held experts x expert layers x steps:
+                                 what the hits are a share of
+      llm_kv_blocks_global_size  pool blocks of the global layers in use
+      llm_kv_blocks_window_size  pool blocks of the window layers in use
+      llm_kv_window_freed_size   window-layer blocks freed for this
+                                 block (their sequences' windows passed
+                                 them)
+      llm_kv_used_bytes          pool bytes those blocks hold, both kinds
+      llm_kv_live_tokens         positions the live requests hold
+
     HBM attribution (the engine half of util/devmon.py's device plane):
 
       llm_kv_cache_bytes           KV bytes of the pool's live blocks
@@ -156,6 +177,32 @@ def engine_metrics() -> dict:
             "Prompt tokens run through a prefill forward per admitted "
             "request (prefix-cache hits excluded)",
             boundaries=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096)),
+        # dimensionless counts a block: the names end in _size (or
+        # _tokens), as scripts/check_metrics_lint.py asks
+        **{key: m.Histogram(
+            f"llm_{key}" + ("" if key.endswith("_tokens") else "_size"),
+            text, boundaries=(1, 4, 16, 64, 256, 1024, 4096, 16384, 65536))
+           for key, text in (
+            ("moe_routed", "Assignments of live slots routed per decode "
+             "block, summed over its steps and expert layers"),
+            ("moe_local", "Assignments of live slots on held experts per "
+             "decode block"),
+            ("moe_experts_hit", "Held experts reached by some row per "
+             "decode block, summed over its steps and expert layers"),
+            ("moe_experts_held", "Held experts times expert layers times "
+             "steps per decode block"),
+            ("kv_blocks_global", "Global-layer pool blocks in use at a "
+             "decode block"),
+            ("kv_blocks_window", "Window-layer pool blocks in use at a "
+             "decode block"),
+            ("kv_window_freed", "Window-layer blocks freed for a decode "
+             "block"),
+            ("kv_live_tokens", "Positions the live requests hold at a "
+             "decode block"))},
+        "kv_used_bytes": m.Histogram(
+            "llm_kv_used_bytes",
+            "Pool bytes in use at a decode block, both layer kinds",
+            boundaries=tuple(2.0 ** e for e in range(20, 36, 2))),
         "stream_lag": m.Histogram(
             "llm_stream_lag_s",
             "Wait of a streamed token between the scheduler loop's "
@@ -240,7 +287,7 @@ class _Request:
 
 
 class LLMEngine:
-    def __init__(self, cfg: LlamaConfig, params, *, max_slots: int = 8,
+    def __init__(self, cfg, params, *, max_slots: int = 8,
                  max_len: int = 1024,
                  prefill_buckets: Sequence[int] = (64, 128, 256, 512),
                  cache_dtype="bfloat16", seed: int = 0,
@@ -252,7 +299,11 @@ class LLMEngine:
                  kv_impl: str = "auto",
                  spec: Optional[bool] = None,
                  detokenize: Optional[Callable[[List[int]], str]] = None):
-        """With ``mesh``, the engine runs TENSOR-PARALLEL: params shard
+        """``cfg`` is a model family's config object (a LlamaConfig, or
+        one with the optional fields llm/model.py lists: layer kinds,
+        a window, experts) and ``params`` that family's tree.
+
+        With ``mesh``, the engine runs TENSOR-PARALLEL: params shard
         per lm.serve_param_specs (Megatron layout), the KV pool shards
         its kv-head dim, and every prefill/decode jit runs SPMD over the
         mesh with GSPMD inserting the two psums per layer. This is how a
@@ -275,6 +326,13 @@ class LLMEngine:
             # partitions fine.
             import dataclasses
             cfg = dataclasses.replace(cfg, attn_impl="reference")
+        # a model with window layers keeps a second pool for them
+        # (kvcache.pool_kinds); None for the Llama family
+        self._kinds = kvcache.pool_kinds(cfg)
+        if mesh is not None and (self._kinds or lm.has_experts(cfg)):
+            raise NotImplementedError(
+                "tensor-parallel serving shards the Llama family's "
+                "parameter tree only (lm.serve_param_specs)")
         self.cfg = cfg
         self.mesh = mesh
         self.tensor_axis = tensor_axis
@@ -307,6 +365,16 @@ class LLMEngine:
             kv_block_size = int(getattr(_cfg, "kvcache_block_size", 16))
         if kv_pool_blocks is None:
             kv_pool_blocks = int(getattr(_cfg, "kvcache_pool_blocks", 0))
+        if self._kinds:
+            # a window layer frees the blocks its window has passed: a
+            # cached prefix would have to keep them. Asked for: refused;
+            # left to the Config's default: off
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with window layers: prefix reuse "
+                    "is not supported for a model whose window layers "
+                    "free the blocks they have passed")
+            prefix_cache = False
         if prefix_cache is None:
             prefix_cache = bool(getattr(_cfg, "kvcache_prefix_cache",
                                         True))
@@ -317,6 +385,10 @@ class LLMEngine:
                 "need a size")
         if spec is None:
             spec = bool(getattr(_cfg, "spec_decode", False))
+        if spec and self._kinds:
+            raise ValueError(
+                "speculative decoding is not supported with window "
+                "layers: the verify forward attends global layers only")
         # Speculative decoding (llm/spec.py): draft-and-verify rides
         # the block-table verify forward
         self._spec = bool(spec)
@@ -344,15 +416,34 @@ class LLMEngine:
             b = math.gcd(b, v)
         self._block = b
         self._table_w = max_len // self._block
-        per_tok = (cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
-                   * 2 * jnp.dtype(cache_dtype).itemsize)
+        # Decode block size per host sync: throughput lever when the
+        # device link is latency-bound. Kept power-of-2-bucketed so XLA
+        # compiles at most log2(steps_per_sync)+1 block variants.
+        self.steps_per_sync = max(1, steps_per_sync)
+        # bytes a position costs in one layer (k and v), and the pool
+        # of each layer kind: the window layers' is sized exactly (a
+        # ring of blocks a slot), the global layers' by auto_pool_blocks
+        # from what is free beside it
+        layer_tok = (cfg.n_kv_heads * cfg.head_dim * 2
+                     * jnp.dtype(cache_dtype).itemsize)
+        n_window = len(dict(self._kinds or ()).get(kvcache.WINDOW, ()))
+        window = None
+        if n_window:
+            ring = kvcache.window_ring_blocks(
+                cfg.sliding_window, self._block, self.steps_per_sync)
+            window = (max_slots * ring + 1, cfg.sliding_window,
+                      self.steps_per_sync)
         # the pool shards its kv heads over the tensor axis
         tp = mesh.shape[tensor_axis] if mesh is not None else 1
         nb = kvcache.auto_pool_blocks(
-            max_slots, self._table_w, per_tok * self._block // tp,
-            kv_pool_blocks)
-        self._pool = kvcache.init_pool(cfg, nb, self._block,
-                                       jnp.dtype(cache_dtype))
+            max_slots, self._table_w,
+            (cfg.n_layers - n_window) * layer_tok * self._block // tp,
+            kv_pool_blocks,
+            reserved_bytes=(window[0] * n_window * layer_tok * self._block
+                            if window else 0))
+        self._pool = kvcache.init_pool(
+            cfg, nb, self._block, jnp.dtype(cache_dtype),
+            window_blocks=window[0] if window else 0)
         if mesh is not None:
             # pool shards its kv-head dim (Megatron layout); block ids
             # index dim 1, orthogonal to the shard, so
@@ -364,9 +455,14 @@ class LLMEngine:
                           for k, v in self._pool.items()}
         self._kv = kvcache.KVBlockManager(
             nb, self._block, table_width=self._table_w,
-            prefix_cache=prefix_cache, metrics=self._kvm)
+            prefix_cache=prefix_cache, metrics=self._kvm, window=window)
+        self._block_bytes = kvcache.kind_block_bytes(self._pool)
         self._tables = np.full((max_slots, self._table_w),
                                kvcache.TRASH, np.int32)
+        # the window layers' tables (their own block ids), if any
+        self._wtables = np.full((max_slots, self._table_w),
+                                kvcache.TRASH, np.int32) if window else None
+        self._window_freed = 0
         self._blocked: deque = deque()   # admits parked on pool
         self._seq_counter = 0
         self._slots: List[Optional[_Request]] = [None] * max_slots
@@ -374,10 +470,6 @@ class LLMEngine:
         self._rng = np.random.default_rng(seed)
         self._key = jax.random.PRNGKey(seed)
         self._step = 0
-        # Decode block size per host sync: throughput lever when the
-        # device link is latency-bound. Kept power-of-2-bucketed so XLA
-        # compiles at most log2(steps_per_sync)+1 block variants.
-        self.steps_per_sync = max(1, steps_per_sync)
         self._loop_task: Optional[asyncio.Task] = None
         self._stopped = False
         # Request-phase telemetry rides the metrics registry (tagged
@@ -426,7 +518,11 @@ class LLMEngine:
                 "prefix_hit_tokens": self._kv.hit_tokens_total,
                 "kv_impl": self._kv_impl,
                 "kv_interpret": self._kv_interpret,
-                "spec": self._spec}
+                "spec": self._spec,
+                **({"pool_blocks_window": self._kv.window_blocks,
+                    "blocks_used_window": self._kv.window_used_blocks(),
+                    "window_blocks_freed": self._kv.window_freed_total}
+                   if self._kinds else {})}
 
     def _kv_per_token_bytes(self) -> float:
         """Device bytes one KV position of one slot costs (both k and
@@ -462,10 +558,13 @@ class LLMEngine:
         vLLM property); headroom = free blocks. The gauges ride the
         worker's metrics push to the head next to util/devmon.py's
         device_hbm_* series."""
-        bb = kvcache.pool_block_bytes(self._pool)
+        bb = self._block_bytes[kvcache.GLOBAL]
         live = self._kv.used_blocks() + self._kv.cached_blocks()
-        self._m["kv_bytes"].set(bb * live)
-        self._m["kv_headroom"].set(bb * self._kv.free_blocks())
+        wb = self._block_bytes.get(kvcache.WINDOW, 0)
+        self._m["kv_bytes"].set(
+            bb * live + wb * self._kv.window_used_blocks())
+        self._m["kv_headroom"].set(
+            bb * self._kv.free_blocks() + wb * len(self._kv.wfree))
 
     # --- public API -----------------------------------------------------
 
@@ -795,6 +894,7 @@ class LLMEngine:
                         top_ps[i] = self._slots[i].top_p
                         top_ks[i] = self._slots[i].top_k
                     member_traces, first_ctx = self._members(active)
+                    self._set_aside(active, block)
                 out = await loop.run_in_executor(
                     None, self._decode_sync, tokens, temps, top_ps,
                     top_ks, block, first_ctx)
@@ -813,16 +913,23 @@ class LLMEngine:
                 if self._kv_impl == "paged_flash":
                     # what the kernel's walk fetched for them: every
                     # slot's live blocks at every step, an idle slot
-                    # (length 1 + step) included
+                    # (length 1 + step) included; a window layer's from
+                    # its window's first block on, so the mean a layer
                     from ray_tpu.ops.pallas.paged_attention import \
                         fetched_positions
                     at = np.ones((self.max_slots, 1), np.int64)
                     for i in active:
                         at[i] = len(self._slots[i].tokens) \
                             + len(self._slots[i].out)
-                    self._m["kv_fetch_tokens"].observe(int(
-                        fetched_positions(at + np.arange(block),
-                                          self._block).sum()))
+                    at = at + np.arange(block)
+                    fetched = sum(
+                        len(layers) * int(fetched_positions(
+                            at, self._block,
+                            lm.window_of(self.cfg, kind)).sum())
+                        for kind, layers in self._kinds or (
+                            (kvcache.GLOBAL, range(self.cfg.n_layers)),))
+                    self._m["kv_fetch_tokens"].observe(
+                        fetched / self.cfg.n_layers)
                 self._record_block(n, block, member_traces, first_ctx,
                                    block=block)
                 with phase("emit"):
@@ -852,6 +959,32 @@ class LLMEngine:
             for i, r in enumerate(self._slots):
                 if r is not None:
                     self._finish(r, i)
+
+    def _set_aside(self, active: List[int], block: int) -> None:
+        """Before a decode block of ``block`` steps: a model with
+        window layers gets the window-layer blocks the block writes and
+        gives back the ones each sequence's window has passed
+        (kvcache.advance_window); then what the pool holds by layer
+        kind is observed, for any model."""
+        live = 0
+        for i in active:
+            r = self._slots[i]
+            n = len(r.tokens) + len(r.out)
+            live += n
+            if self._kinds:
+                self._wtables[i] = self._kv.advance_window(
+                    r.seq, n - 1, block)
+        used = {kvcache.GLOBAL: self._kv.used_blocks()}
+        if self._kinds:
+            used[kvcache.WINDOW] = self._kv.window_used_blocks()
+            freed = self._kv.window_freed_total
+            self._m["kv_window_freed"].observe(freed - self._window_freed)
+            self._window_freed = freed
+        for kind, n in used.items():
+            self._m["kv_blocks_" + kind].observe(n)
+        self._m["kv_used_bytes"].observe(
+            sum(n * self._block_bytes[kind] for kind, n in used.items()))
+        self._m["kv_live_tokens"].observe(live)
 
     def _members(self, active: List[int]):
         """(sorted trace ids of the batch's traced requests, the first
@@ -944,6 +1077,8 @@ class LLMEngine:
         hit = r.prefix_hit
         B = self._block
         self._tables[slot] = table
+        if self._kinds:
+            self._wtables[slot] = r.kv_alloc["window_table"]
         with self._phase("prefill.dispatch") as disp:
             if r.prefilled is not None:
                 # device TTFT for a disaggregated request is the
@@ -967,7 +1102,8 @@ class LLMEngine:
                 targets = table.copy()
                 targets[:hit // B] = kvcache.TRASH
                 self._pool = kvcache.scatter_table(
-                    self._pool, acc, jnp.asarray(targets))
+                    self._pool, acc, self._targets(targets, slot),
+                    self._kinds)
             elif hit == 0 and n <= self.buckets[-1]:
                 # cache-cold short prompt: one lm.prefill forward,
                 # padded only to its bucket; pad-garbage blocks
@@ -981,13 +1117,29 @@ class LLMEngine:
                 phys[:min(nb, self._table_w)] = table[:min(
                     nb, self._table_w)]
                 self._pool = kvcache.scatter_bucket(
-                    self._pool, kv, jnp.asarray(phys), nb)
+                    self._pool, kv, self._targets(phys, slot), nb,
+                    self._kinds)
                 ran = n
             else:
-                logits = self._prefill_into_blocks(r, table, hit)
+                logits = self._prefill_into_blocks(r, table, hit, slot)
                 ran = n - self._prefill_start(hit)
         return self._first_token(slot, r, disp, logits,
                                  self._pool["k"], ran)
+
+    def _targets(self, phys: np.ndarray, slot: int):
+        """The physical ids a prefill's KV is written through: ``phys``
+        for the global layers and, for a model with window layers, the
+        slot's window table cut to the same width beside it (by kind):
+        only the blocks the first decode step's window reaches are
+        allocated there, the rest of the prompt's go to trash."""
+        _, jnp = _jx()
+        if not self._kinds:
+            return jnp.asarray(phys)
+        wphys = np.full(phys.shape, kvcache.TRASH, np.int32)
+        w = min(len(phys), self._table_w)
+        wphys[:w] = self._wtables[slot][:w]
+        return {kvcache.GLOBAL: jnp.asarray(phys),
+                kvcache.WINDOW: jnp.asarray(wphys)}
 
     def _first_token(self, slot: int, r: _Request, disp, logits,
                      written, ran: int) -> int:
@@ -1042,7 +1194,7 @@ class LLMEngine:
         return (hit // chunk) * chunk
 
     def _prefill_into_blocks(self, r: _Request, table: np.ndarray,
-                             hit: int):
+                             hit: int, slot: int):
         """Prefix-hit (and long-prompt) prefill: gather the table's
         cached blocks into a contiguous accumulator, run the suffix
         through lm.prefill_chunk at the prefix offset (pieces aligned
@@ -1056,8 +1208,8 @@ class LLMEngine:
         B = self._block
         chunk = self.buckets[-1]
         acc_len = self._acc_len()
-        acc = kvcache.gather_table(self._pool, jnp.asarray(table),
-                                   acc_len)
+        acc = kvcache.gather_table(self._pool, self._targets(table, slot),
+                                   acc_len, self._kinds)
         off = self._prefill_start(hit)
         logits = None
         while off < n:
@@ -1071,8 +1223,8 @@ class LLMEngine:
             off = end
         targets = table.copy()
         targets[:hit // B] = kvcache.TRASH
-        self._pool = kvcache.scatter_table(self._pool, acc,
-                                           jnp.asarray(targets))
+        self._pool = kvcache.scatter_table(
+            self._pool, acc, self._targets(targets, slot), self._kinds)
         return logits
 
     @staticmethod
@@ -1138,16 +1290,29 @@ class LLMEngine:
             for i, r in enumerate(self._slots):
                 if r is not None:
                     lengths[i] = len(r.tokens) + len(r.out) - 1
-            out, self._pool = kvcache.paged_decode_steps(
-                self.params, self._pool, jnp.asarray(self._tables),
-                jnp.asarray(lengths), jnp.asarray(tokens),
-                jnp.asarray(temps), key, self.cfg, block, tp, tk,
-                impl=self._kv_impl, interpret=self._kv_interpret,
-                mesh=self.mesh, axis=self.tensor_axis)
+            tables = jnp.asarray(self._tables)
+            if self._kinds:
+                tables = {kvcache.GLOBAL: tables,
+                          kvcache.WINDOW: jnp.asarray(self._wtables)}
+            out, self._pool, counts = kvcache.decode_steps_program(
+                self._pool, impl=self._kv_impl,
+                interpret=self._kv_interpret, mesh=self.mesh,
+                axis=self.tensor_axis)(
+                self.params, self._pool, tables, jnp.asarray(lengths),
+                jnp.asarray(tokens), jnp.asarray(temps), key, self.cfg,
+                block, tp, tk)
             self._kvm["attn_steps"].inc(
                 block, tags={"impl": self._kv_impl})
         with self._phase("decode.readback") as back:
-            out = np.asarray(out)
+            out, counts = jax.device_get((out, counts))
+        if counts is not None:
+            # the expert layers' counts came back with the tokens
+            for key_, per_step in counts.items():
+                self._m["moe_" + key_].observe(int(per_step.sum()))
+            self._m["moe_experts_held"].observe(
+                block * self.cfg.n_held
+                * (self.cfg.n_layers
+                   - getattr(self.cfg, "n_dense_layers", 0)))
         self._dev_span = (disp.t0, back.t1)
         return out
 
@@ -1345,6 +1510,8 @@ class LLMEngine:
         r.kv_alloc = None
         if slot is not None:
             self._tables[slot] = kvcache.TRASH
+            if self._kinds:
+                self._wtables[slot] = kvcache.TRASH
         self._kv_account()
 
     def _finish(self, r: _Request, slot: Optional[int]):

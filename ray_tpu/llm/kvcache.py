@@ -32,6 +32,19 @@ TPU-native on the engine's static-shape rules:
   pool pressure (a parent evicted before its child would orphan the
   child: chain lookups walk from the root).
 
+A model with WINDOW layers (sliding-window attention: a query attends
+the last ``sliding_window`` positions) has a second, small pool for
+them, with block ids and a table of its own a sequence, accounted for
+by the same manager: a window layer holds a sequence's blocks from the
+one with the window's first position on, at most
+``window_ring_blocks`` of them; ``advance_window`` allocates the
+blocks the next decode dispatch writes and FREES the ones the window
+has passed (their table entries revert to trash; the kernel's walk
+starts past them, so they are never read again). The global layers'
+blocks are reserved for the full horizon as before. Prefix reuse is
+refused for such a model: a cached chain would have to keep every
+window block it freed.
+
 Physical block 0 is the TRASH block: writes for finished/empty slots
 and bucket-padding garbage are redirected there so freed blocks can be
 reallocated immediately without a device sync.
@@ -68,6 +81,10 @@ def kvcache_metrics() -> dict:
                                   block granularity (llm/pd.py)
       llm_paged_attn_steps_total  paged decode steps by attention impl
                                   ({impl}: paged_flash | gather)
+      llm_kv_window_blocks_used   window-layer pool blocks held by live
+                                  requests
+      llm_kv_window_blocks_freed_total  window-layer blocks freed because
+                                  their sequence's window passed them
     """
     from ray_tpu.util import metrics as m
     return {
@@ -90,6 +107,13 @@ def kvcache_metrics() -> dict:
             "llm_kv_handoff_bytes_total",
             "KV bytes shipped prefill->decode at block granularity "
             "in the disaggregated path"),
+        "window_used": m.Gauge(
+            "llm_kv_window_blocks_used",
+            "Window-layer KV pool blocks held by live requests"),
+        "window_freed": m.Counter(
+            "llm_kv_window_blocks_freed_total",
+            "Window-layer KV pool blocks freed because their sequence's "
+            "window passed them"),
         "attn_steps": m.Counter(
             "llm_paged_attn_steps_total",
             "Paged decode steps taken, tagged by attention impl "
@@ -148,9 +172,18 @@ class KVBlockManager:
 
     def __init__(self, num_blocks: int, block_size: int, *,
                  table_width: int, prefix_cache: bool = True,
-                 metrics: Optional[dict] = None):
+                 metrics: Optional[dict] = None,
+                 window: Optional[Tuple[int, int, int]] = None):
+        """``window`` = (blocks of the window layers' pool, the sliding
+        window, the steps one decode dispatch runs at most), for a
+        model with window layers."""
         if num_blocks < 2:
             raise ValueError("pool needs >= 2 blocks (one is trash)")
+        if window is not None and prefix_cache:
+            raise ValueError(
+                "prefix caching is not supported with window layers: a "
+                "window layer frees the blocks its window has passed, "
+                "and a cached prefix would have to keep them")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.table_width = int(table_width)
@@ -164,6 +197,60 @@ class KVBlockManager:
         self.hit_tokens_total = 0
         self._tick = 0
         self._m = metrics
+        # window layers: ids of their own pool (0 = its trash block),
+        # and per live sequence {logical block: physical id}
+        self.window = None
+        self.wfree: deque = deque()
+        self.wseqs: Dict[object, Dict[int, int]] = {}
+        self.window_freed_total = 0
+        if window is not None:
+            wblocks, self.window, steps = (int(x) for x in window)
+            self.window_blocks = wblocks
+            self.ring = window_ring_blocks(self.window, self.block_size,
+                                           steps)
+            if wblocks - 1 < self.ring:
+                raise ValueError(
+                    f"the window layers' pool needs {self.ring} blocks a "
+                    f"sequence and a trash block, got {wblocks}")
+            self.wfree = deque(range(1, wblocks))
+
+    # -- window layers ---------------------------------------------------
+
+    def window_used_blocks(self) -> int:
+        """Window-layer blocks live sequences hold: the ids that are not
+        on the free list (id 0 is the trash block)."""
+        return self.window_blocks - 1 - len(self.wfree) if self.window \
+            else 0
+
+    def _window_row(self, held: Dict[int, int]) -> np.ndarray:
+        row = np.full((self.table_width,), TRASH, np.int32)
+        for logical, phys in held.items():
+            row[logical] = phys
+        return row
+
+    def advance_window(self, seq_id, length: int, steps: int = 1):
+        """Before a decode dispatch that writes positions ``length ...
+        length + steps - 1`` of ``seq_id`` (its first query attends
+        positions >= length + 1 - window): free the window-layer blocks
+        that lie wholly below that, allocate those the dispatch writes,
+        and return the sequence's window table (table_width,) int32.
+        Never fails for an admitted sequence: it holds at most ``ring``
+        blocks, which admission set aside."""
+        held = self.wseqs[seq_id]
+        first = max(length + 1 - self.window, 0) // self.block_size
+        last = min((length + steps - 1) // self.block_size,
+                   self.table_width - 1)
+        for logical in [b for b in held if b < first]:
+            self.wfree.append(held.pop(logical))
+            self.window_freed_total += 1
+            if self._m is not None:
+                self._m["window_freed"].inc()
+        for logical in range(first, last + 1):
+            if logical not in held:
+                held[logical] = self.wfree.popleft()
+        if self._m is not None:
+            self._m["window_used"].set(self.window_used_blocks())
+        return self._window_row(held)
 
     # -- introspection ---------------------------------------------------
 
@@ -242,6 +329,9 @@ class KVBlockManager:
             raise BlockPoolExhausted(
                 f"request horizon needs {total} blocks; pool holds "
                 f"{self.num_blocks - 1}")
+        if self.window is not None and \
+                (len(self.wseqs) + 1) * self.ring > self.window_blocks - 1:
+            return None     # every ring of the window layers' pool is out
         hit_tokens, hit_phys, hashes = self._lookup(tokens)
         # pin the hit blocks BEFORE any eviction: at refcount 0 they
         # are themselves eviction candidates once their chain suffix
@@ -271,8 +361,13 @@ class KVBlockManager:
         if self._m is not None and hit_tokens:
             self._m["hit_tokens"].inc(hit_tokens)
         self._publish()
-        return {"table": table, "hit_tokens": hit_tokens,
-                "new_blocks": new_blocks}
+        out = {"table": table, "hit_tokens": hit_tokens,
+               "new_blocks": new_blocks}
+        if self.window is not None:
+            # the prompt's blocks the first decode step's window reaches
+            self.wseqs[seq_id] = {}
+            out["window_table"] = self.advance_window(seq_id, n, 0)
+        return out
 
     def _release(self, phys: int) -> None:
         """Drop one live reference; a block neither referenced nor
@@ -298,6 +393,9 @@ class KVBlockManager:
         seq = self.seqs.pop(seq_id, None)
         if seq is None:
             return
+        self.wfree.extend(self.wseqs.pop(seq_id, {}).values())
+        if self._m is not None and self.window is not None:
+            self._m["window_used"].set(self.window_used_blocks())
         if self.prefix_cache and cache:
             # ``out_tokens`` is the FULL token stream (prompt +
             # generated) when the caller wants generated full blocks
@@ -472,29 +570,81 @@ def _jx():
     return jax, jnp
 
 
-def init_pool(cfg, num_blocks: int, block_size: int, dtype) -> dict:
-    """The pool tensors: k/v of shape
-    (layers, num_blocks, kv_heads, block_size, head_dim)."""
+# A layer KIND ("global": attends everything; "window": the last
+# sliding_window positions) has its own pair of pool arrays, its own
+# block ids and its own table a sequence. A model of global layers only
+# (the Llama family) has the one pair it always had. (llm/model.py
+# takes the two names from here: it imports jax, and this module must
+# not at import time.)
+GLOBAL, WINDOW = "global", "window"
+POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv")}
+
+
+def pool_kinds(cfg):
+    """``((kind, its layers), ...)`` for a model with window layers
+    (hashable: the device ops below are built per value), None for one
+    of global layers only."""
+    from ray_tpu.llm.model import kind_layers
+    kinds = kind_layers(cfg)
+    if GLOBAL not in kinds:
+        raise NotImplementedError(
+            "a model whose layers are all window layers is not served "
+            "yet: the pool's geometry is read off its global layers")
+    return tuple(kinds.items()) if WINDOW in kinds else None
+
+
+def init_pool(cfg, num_blocks: int, block_size: int, dtype,
+              window_blocks: int = 0) -> dict:
+    """The pool tensors: k/v of shape (global layers, num_blocks,
+    kv_heads, block_size, head_dim) and, for a model with window
+    layers, wk/wv (window layers, window_blocks, ...)."""
     _, jnp = _jx()
-    shape = (cfg.n_layers, num_blocks, cfg.n_kv_heads, block_size,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    kinds = pool_kinds(cfg) or ((GLOBAL, tuple(range(cfg.n_layers))),)
+    pool = {}
+    for kind, layers in kinds:
+        shape = (len(layers),
+                 num_blocks if kind == GLOBAL else max(2, window_blocks),
+                 cfg.n_kv_heads, block_size, cfg.head_dim)
+        for key in POOL_KEYS[kind]:
+            pool[key] = jnp.zeros(shape, dtype)
+    return pool
+
+
+def kind_block_bytes(pool: dict) -> dict:
+    """{kind: device bytes one block id of that kind costs (k + v, all
+    the kind's layers)}."""
+    return {kind: sum(pool[key].nbytes // pool[key].shape[1]
+                      for key in keys)
+            for kind, keys in POOL_KEYS.items() if keys[0] in pool}
 
 
 def pool_block_bytes(pool: dict) -> int:
-    """Device bytes one block costs (k + v, all layers)."""
-    nb = pool["k"].shape[1]
-    return (pool["k"].nbytes + pool["v"].nbytes) // nb
+    """Device bytes one block of the global layers costs (k + v, all of
+    them)."""
+    return kind_block_bytes(pool)[GLOBAL]
+
+
+def window_ring_blocks(window: int, block_size: int, steps: int) -> int:
+    """Blocks of a window layer one sequence holds at most: the window
+    plus the ``steps`` positions one decode dispatch writes ahead, cut
+    into blocks, plus one for where the cut falls."""
+    return -(-(window + steps - 1) // block_size) + 1
 
 
 def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
-                     configured: int = 0) -> int:
-    """Pool size: the explicit knob wins; otherwise worst case (every
-    slot at max_len) plus one full chain of prefix-cache headroom,
-    capped at a quarter of the free HBM of the fullest local device
-    when the backend reports a capacity (devmon.hbm_snapshot; the CPU
-    backend reports none). The decode program holds ONE pool-sized
-    buffer since PR 31 (the donated pool, updated in place: the
+                     configured: int = 0, reserved_bytes: int = 0) -> int:
+    """Blocks of the GLOBAL layers' pool: the explicit knob wins;
+    otherwise worst case (every slot at max_len) plus one full chain of
+    prefix-cache headroom, capped at a quarter of the free HBM of the
+    fullest local device when the backend reports a capacity
+    (devmon.hbm_snapshot; the CPU backend reports none). A block's cost
+    differs by layer kind, so the rule is stated in bytes: the quarter
+    is of what is free AFTER ``reserved_bytes``, the window layers'
+    pool, which the engine sizes first and exactly (slots x
+    window_ring_blocks: a window layer never holds more, so there is
+    nothing to cap), and ``block_bytes`` is what a block id of the
+    global layers costs (k + v over the global layers only). The decode
+    program holds ONE pool-sized buffer since PR 31 (the donated pool, updated in place: the
     compiler's memory analysis at the chat cell's geometry gives 3.1
     GB of pool and 1.3 MB of temporaries, where the layer scan's
     stacked outputs took 3.9 GB); the quarter dates from the three it
@@ -514,7 +664,8 @@ def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
                  for r in devmon.hbm_snapshot(record=False)
                  if r["limit"]]
     if headrooms:
-        cap = int(min(headrooms) // 4 // max(1, block_bytes))
+        cap = int(max(0, min(headrooms) - reserved_bytes) // 4
+                  // max(1, block_bytes))
         base = max(table_width, min(base, cap))
     return base + 1     # + trash block
 
@@ -524,7 +675,9 @@ _JITS: dict = {}    # (op, pool geometry, dtype) -> jitted callable
 
 def _pool_key(pool: dict) -> tuple:
     """Cache-key component identifying one pool's compiled geometry."""
-    return (tuple(pool["k"].shape), str(pool["k"].dtype))
+    wk = pool.get("wk")
+    return (tuple(pool["k"].shape), str(pool["k"].dtype),
+            *(() if wk is None else (tuple(wk.shape),)))
 
 
 def _to_blocks(kv, nb: int, pool):
@@ -537,7 +690,7 @@ def _to_blocks(kv, nb: int, pool):
         0, 1, 3, 2, 4).astype(pool.dtype)
 
 
-def _jit(name: str, pool: dict):
+def _jit(name: str, pool: dict, kinds=None):
     """Build-once cache for the jitted device ops: jax must not be
     imported at module import time (the engine's lazy-import rule),
     and a fresh jax.jit wrapper per call would retrace every call.
@@ -545,46 +698,73 @@ def _jit(name: str, pool: dict):
     process serving two model configs (two replicas, a debug engine
     next to a prod one) must not replay a callable whose donated
     buffers and reshape constants were traced for the other pool's
-    shape."""
-    key = (name, *_pool_key(pool))
+    shape. ``kinds`` (pool_kinds): None, or which layers of the
+    token-order KV go to which kind's arrays, each through that kind's
+    physical ids (``phys`` is then a dict by kind)."""
+    key = (name, *_pool_key(pool), kinds)
     fn = _JITS.get(key)
     if fn is not None:
         return fn
     jax, jnp = _jx()
 
+    def parts(phys):
+        """(layers or None for all, the pool's keys, the ids) a kind."""
+        if kinds is None:
+            return ((None, POOL_KEYS[GLOBAL], phys),)
+        return tuple((np.asarray(layers), POOL_KEYS[kind], phys[kind])
+                     for kind, layers in kinds)
+
+    def of(kv, layers):
+        return kv if layers is None else kv[layers]
+
     if name == "scatter_bucket":
         @partial(jax.jit, donate_argnums=(0,), static_argnames=("nb",))
         def fn(pool, kv, phys, nb):
-            return {key: pool[key].at[:, phys].set(
-                        _to_blocks(kv[key], nb, pool[key]))
-                    for key in ("k", "v")}
+            return {**pool, **{
+                dst: pool[dst].at[:, ids].set(
+                    _to_blocks(of(kv[src], layers), nb, pool[dst]))
+                for layers, keys, ids in parts(phys)
+                for src, dst in zip(("k", "v"), keys)}}
     elif name == "gather_table":
         @partial(jax.jit, static_argnames=("acc_len",))
         def fn(pool, phys, acc_len):
-            L, _, kvh, bs, hd = pool["k"].shape
-            w = phys.shape[0]
             out = {}
-            for key in ("k", "v"):
-                g = pool[key][:, phys]           # (L, w, kvh, bs, hd)
-                g = g.transpose(0, 1, 3, 2, 4).reshape(
-                    L, w * bs, kvh, hd)
-                pad = acc_len - w * bs
-                if pad > 0:
-                    g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                out[key] = g
+            for src in ("k", "v"):
+                views = []
+                for layers, keys, ids in parts(phys):
+                    dst = keys[src == "v"]
+                    L, _, kvh, bs, hd = pool[dst].shape
+                    w = ids.shape[0]
+                    g = pool[dst][:, ids]        # (L, w, kvh, bs, hd)
+                    g = g.transpose(0, 1, 3, 2, 4).reshape(
+                        L, w * bs, kvh, hd)
+                    pad = acc_len - w * bs
+                    if pad > 0:
+                        g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                    views.append((layers, g))
+                if kinds is None:
+                    out[src] = views[0][1]
+                else:       # back into the model's layer order
+                    order = np.argsort(np.concatenate(
+                        [layers for layers, _ in views]))
+                    out[src] = jnp.concatenate(
+                        [g for _, g in views])[order]
             return out
     elif name == "scatter_table":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, acc, phys):
             bs = pool["k"].shape[3]
-            w = phys.shape[0]
-            return {key: pool[key].at[:, phys].set(
-                        _to_blocks(acc[key][:, :w * bs], w, pool[key]))
-                    for key in ("k", "v")}
+            return {**pool, **{
+                dst: pool[dst].at[:, ids].set(_to_blocks(
+                    of(acc[src], layers)[:, :ids.shape[0] * bs],
+                    ids.shape[0], pool[dst]))
+                for layers, keys, ids in parts(phys)
+                for src, dst in zip(("k", "v"), keys)}}
     elif name == "copy_block":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, src, dst):
-            return {"k": pool["k"].at[:, dst].set(pool["k"][:, src]),
+            return {**pool,
+                    "k": pool["k"].at[:, dst].set(pool["k"][:, src]),
                     "v": pool["v"].at[:, dst].set(pool["v"][:, src])}
     else:
         raise KeyError(name)
@@ -592,32 +772,34 @@ def _jit(name: str, pool: dict):
     return fn
 
 
-def scatter_bucket(pool: dict, kv: dict, phys, nb: int) -> dict:
+def scatter_bucket(pool: dict, kv: dict, phys, nb: int,
+                   kinds=None) -> dict:
     """Write a bucket-padded prefill's KV into ``nb`` physical blocks
     (pad-garbage blocks redirected to trash by the caller's phys).
     One compile per bucket size."""
-    return _jit("scatter_bucket", pool)(pool, kv, phys, nb)
+    return _jit("scatter_bucket", pool, kinds)(pool, kv, phys, nb)
 
 
-def gather_table(pool: dict, phys, acc_len: int) -> dict:
+def gather_table(pool: dict, phys, acc_len: int, kinds=None) -> dict:
     """Gather one block table's KV into a contiguous accumulator
     (layers, acc_len, kvh, hd) for chunked prefill over a cached
     prefix. acc_len >= table_width * block_size (zero tail). No
     longer on the decode hot path — decode attends straight through
     the table (ops/pallas/paged_attention.py); this stays for the
     prefix-hit prefill accumulator and debug/parity tooling."""
-    return _jit("gather_table", pool)(pool, phys, acc_len)
+    return _jit("gather_table", pool, kinds)(pool, phys, acc_len)
 
 
-def scatter_table(pool: dict, acc: dict, phys) -> dict:
+def scatter_table(pool: dict, acc: dict, phys, kinds=None) -> dict:
     """Write an accumulator back through a full-width physical target
     vector (shared-prefix and beyond-horizon slots point at trash so
     shared blocks are never written). One compile total."""
-    return _jit("scatter_table", pool)(pool, acc, phys)
+    return _jit("scatter_table", pool, kinds)(pool, acc, phys)
 
 
 def copy_block(pool: dict, src: int, dst: int) -> dict:
-    """Device-side block copy (the COW divergence path)."""
+    """Device-side block copy (the COW divergence path; blocks of the
+    global layers, the only ones that are ever shared)."""
     _, jnp = _jx()
     return _jit("copy_block", pool)(pool, jnp.int32(src),
                                     jnp.int32(dst))
@@ -643,34 +825,56 @@ def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
     """One sampled token for every slot against the paged pool:
-    _paged_logits_core + the on-device sampler."""
+    _paged_logits_core + the on-device sampler. Returns (tokens, pool,
+    the expert layers' counts or None)."""
     from ray_tpu.llm.model import sample
-    logits, pool = _paged_logits_core(
+    logits, pool, counts = _paged_logits_core(
         params, pool, tables, lengths, tokens, cfg, impl=impl,
         interpret=interpret, mesh=mesh, axis=axis)
-    return sample(logits, temps, key, top_ps, top_ks), pool
+    return sample(logits, temps, key, top_ps, top_ks), pool, counts
+
+
+def _by_kind(tables) -> dict:
+    """Block tables by layer kind: a model of global layers only hands
+    the one array it always did."""
+    return tables if isinstance(tables, dict) else {GLOBAL: tables}
+
+
+def _places(tables, pos, bs):
+    """{kind: (physical block, row in it)} of positions ``pos`` (slots,)
+    or (slots, w) in each kind's tables."""
+    _, jnp = _jx()
+    out = {}
+    for kind, tb in _by_kind(tables).items():
+        blk = jnp.clip(pos // bs, 0, tb.shape[1] - 1)
+        phys = (tb[jnp.arange(tb.shape[0]), blk] if pos.ndim == 1
+                else jnp.take_along_axis(tb, blk, axis=1))
+        out[kind] = (phys, pos % bs)
+    return out
 
 
 def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     """The attention hook of lm.decode_logits_core / verify_tokens_core
-    against the block pool, ``attend(l, q, k, v, kpool, vpool) -> (o,
-    kpool, vpool)``: the new rows k, v go into layer ``l`` of the
-    stacked pools at ``at`` = (physical block, row in it), and q
-    attends through ``tables`` over ``lens`` valid positions. ``at``
-    and ``lens`` are (slots,) for a decode step, (slots, w) for a
-    verify round, which attends with the multi-query functions.
+    against the block pool, ``attend(ref, q, k, v, pool) -> (o, pool)``:
+    the new rows k, v go into the layer's place (``ref.kind_index``) in
+    the stacked pools of its kind at ``at[kind]`` = (physical block, row
+    in it), and q attends through ``tables[kind]`` over ``lens`` valid
+    positions, a window layer over the last ``sliding_window`` of them.
+    ``at`` and ``lens`` are (slots,) for a decode step, (slots, w) for
+    a verify round, which attends with the multi-query functions.
 
-    Both impls see layer l as a WINDOW of the flat pool (every layer's
-    blocks in one (layers * blocks, kvh, block_size, hd) array: a
-    reshape of the row-major stacked pool, no copy), by adding
-    ``l * blocks`` to the block ids: nothing slices a layer out or
-    stacks one back, so a program that donates the pool holds one
-    buffer of it.
+    Both impls see a layer as a WINDOW of its kind's flat pool (every
+    layer's blocks in one (layers * blocks, kvh, block_size, hd) array:
+    a reshape of the row-major stacked pool, no copy), by adding
+    ``kind_index * blocks`` to the block ids: nothing slices a layer
+    out or stacks one back, so a program that donates the pool holds
+    one buffer of it.
 
     impl='paged_flash': the aliased block writer, then the kernel
     that walks each slot's LIVE table entries (``ceil(length /
-    block_size)`` of them, a run-time count) and fetches those blocks
-    with its own DMAs (ops/pallas/paged_attention.py kv_write,
+    block_size)`` of them, a run-time count; a window layer's from the
+    block that holds its window's first position) and fetches those
+    blocks with its own DMAs (ops/pallas/paged_attention.py kv_write,
     paged_attention) — no gathered view, no pool-sized copy. XLA
     performs no write on the pool itself: it would lay the pool out
     token-major for it and convert the whole pool back for the kernel
@@ -690,54 +894,72 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     jax, _ = _jx()
     from ray_tpu.llm import model as lm
     from ray_tpu.ops.pallas import paged_attention as pa
-    phys, off = at
-    lead = phys.shape                   # (slots,) or (slots, w)
+    tables = _by_kind(tables)
+    lead = lens.shape                   # (slots,) or (slots, w)
     multi = len(lead) == 2
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if multi and WINDOW in tables:
+        raise NotImplementedError(
+            "the verify forward attends global layers only")
 
     def flat(pool):
         return pool.reshape(-1, *pool.shape[2:])
 
-    if impl == "paged_flash":
-        def write_attend(qg, k, v, kf, vf, tb, blocks, rows, ln):
-            kf, vf = pa.kv_write(kf, vf, blocks, rows, k, v,
-                                 interpret=interpret)
-            o = (pa.paged_attention_verify(qg, kf, vf, tb, ln) if multi
-                 else pa.paged_attention(qg, kf, vf, tb, ln,
-                                         interpret=interpret))
-            return o, kf, vf
+    def window_kw(kind):
+        return {} if kind == GLOBAL else {"window": cfg.sliding_window}
 
-        if mesh is not None:
+    if impl == "paged_flash":
+        def writer(kind):
+            def write_attend(qg, k, v, kf, vf, tb, blocks, rows, ln):
+                kf, vf = pa.kv_write(kf, vf, blocks, rows, k, v,
+                                     interpret=interpret)
+                o = (pa.paged_attention_verify(qg, kf, vf, tb, ln) if multi
+                     else pa.paged_attention(qg, kf, vf, tb, ln,
+                                             interpret=interpret,
+                                             **window_kw(kind)))
+                return o, kf, vf
+
+            if mesh is None:
+                return write_attend
             from jax.sharding import PartitionSpec as P
             heads, new = P(None, axis, None, None), P(None, axis, None)
             qs = P(*(None,) * len(lead), axis, None, None)
-            write_attend = jax.shard_map(
+            return jax.shard_map(
                 write_attend, mesh=mesh,
                 in_specs=(qs, new, new, heads, heads, P(), P(), P(),
                           P()),
                 out_specs=(qs, heads, heads), check_vma=False)
+        write_attend = {kind: writer(kind) for kind in tables}
 
-        def attend(l, q, k, v, kp, vp):
-            base = l * kp.shape[1]
-            o, kf, vf = write_attend(
+        def attend(ref, q, k, v, pool):
+            kk, vk = POOL_KEYS[ref.kind]
+            kp, vp = pool[kk], pool[vk]
+            phys, off = at[ref.kind]
+            base = ref.kind_index * kp.shape[1]
+            o, kf, vf = write_attend[ref.kind](
                 q.reshape(*lead, kvh, h // kvh, hd),
                 k.reshape(-1, kvh, hd).astype(kp.dtype),
                 v.reshape(-1, kvh, hd).astype(vp.dtype),
-                flat(kp), flat(vp), tables + base,
+                flat(kp), flat(vp), tables[ref.kind] + base,
                 (phys + base).reshape(-1), off.reshape(-1), lens)
-            return (o.reshape(*lead, h * hd), kf.reshape(kp.shape),
-                    vf.reshape(vp.shape))
+            return o.reshape(*lead, h * hd), {
+                **pool, kk: kf.reshape(kp.shape), vk: vf.reshape(vp.shape)}
     else:
         attn = lm._gqa_attend_multi if multi else lm._gqa_attend_cached
 
-        def attend(l, q, k, v, kp, vp):
+        def attend(ref, q, k, v, pool):
+            kk, vk = POOL_KEYS[ref.kind]
+            kp, vp = pool[kk], pool[vk]
+            phys, off = at[ref.kind]
+            l = ref.kind_index
             kp = kp.at[l, phys, :, off].set(k.astype(kp.dtype))
             vp = vp.at[l, phys, :, off].set(v.astype(vp.dtype))
-            tb = tables + l * kp.shape[1]
+            tb = tables[ref.kind] + l * kp.shape[1]
             o = attn(q.reshape(*lead, h * hd),
                      pa.table_view(flat(kp), tb),
-                     pa.table_view(flat(vp), tb), lens, cfg)
-            return o, kp, vp
+                     pa.table_view(flat(vp), tb), lens, cfg,
+                     **window_kw(ref.kind))
+            return o, {**pool, kk: kp, vk: vp}
     return attend
 
 
@@ -747,19 +969,16 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     """One decode step's (slots, vocab) f32 logits for every slot
     against the paged pool: lm.decode_logits_core with the new token's
     place in the pool worked out from the tables, and the write and
-    the attention over the table plugged in (_pool_attend)."""
-    _, jnp = _jx()
+    the attention over the table plugged in (_pool_attend). Returns
+    (logits, pool, the expert layers' counts or None)."""
     from ray_tpu.llm.model import decode_logits_core
-    b = tokens.shape[0]
     bs = pool["k"].shape[3]
     positions = lengths
-    blk = jnp.clip(positions // bs, 0, tables.shape[1] - 1)
-    at = (tables[jnp.arange(b), blk], positions % bs)
-    logits, nk, nv = decode_logits_core(
-        params, pool["k"], pool["v"], tokens, positions, cfg,
-        _pool_attend(cfg, tables, at, positions + 1, impl=impl,
-                     interpret=interpret, mesh=mesh, axis=axis))
-    return logits, {"k": nk, "v": nv}
+    return decode_logits_core(
+        params, pool, tokens, positions, cfg,
+        _pool_attend(cfg, tables, _places(tables, positions, bs),
+                     positions + 1, impl=impl, interpret=interpret,
+                     mesh=mesh, axis=axis))
 
 
 def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
@@ -769,7 +988,9 @@ def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
     block pool, which is left untouched (not donated) — the parity
     entry point: the same step under impl='paged_flash' and
     impl='gather' must agree (chip_smoke.py checks that on the chip at
-    real widths; tests/test_zz_paged_attn.py under the interpreter)."""
+    real widths; tests/test_zz_paged_attn.py under the interpreter).
+    ``tables``: (slots, width), or by layer kind for a model with
+    window layers."""
     impl = resolve_attn_impl(impl)
     key_ = ("paged_decode_logits", *_pool_key(pool), impl,
             bool(interpret), mesh, axis)
@@ -802,17 +1023,24 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
     the trash block; the caller masks on eos and bounds n by each
     slot's horizon. ``impl``/``interpret``/``mesh`` are trace-time
     constants — each combination (x pool geometry) compiles its own
-    variant, cached in _JITS."""
-    return decode_steps_program(
+    variant, cached in _JITS. (The program itself, decode_steps_program,
+    also returns the expert layers' counts a step; the engine reads
+    them with the tokens.)"""
+    outs, pool, _ = decode_steps_program(
         pool, impl=impl, interpret=interpret, mesh=mesh, axis=axis)(
         params, pool, tables, lengths, tokens, temps, key, cfg, n,
         top_ps, top_ks)
+    return outs, pool
 
 
 def decode_steps_program(pool, *, impl="gather", interpret=False,
                          mesh=None, axis="tensor"):
     """The jitted program behind paged_decode_steps for a pool of this
-    geometry (arrays or their shapes), built once a variant."""
+    geometry (arrays or their shapes), built once a variant. It
+    returns (tokens (n, slots), pool, counts): for a model with expert
+    layers ``{"routed", "local", "experts_hit"}`` (n,) int32 each, a
+    step's sums over its layers (models/moe.py serve_block), else
+    None."""
     impl = resolve_attn_impl(impl)
     key_ = ("paged_decode_steps", *_pool_key(pool), impl,
             bool(interpret), mesh, axis)
@@ -827,15 +1055,15 @@ def decode_steps_program(pool, *, impl="gather", interpret=False,
                                temps, key, cfg, n, top_ps, top_ks):
             def body(carry, i):
                 pool, toks = carry
-                out, pool = _paged_decode_core(
+                out, pool, counts = _paged_decode_core(
                     params, pool, tables, lengths + i, toks, temps,
                     jax.random.fold_in(key, i), cfg, top_ps, top_ks,
                     impl=impl, interpret=interpret, mesh=mesh,
                     axis=axis)
-                return (pool, out), out
-            (pool, _), outs = _lax.scan(body, (pool, tokens),
-                                        jnp.arange(n, dtype=jnp.int32))
-            return outs, pool
+                return (pool, out), (out, counts)
+            (pool, _), (outs, counts) = _lax.scan(
+                body, (pool, tokens), jnp.arange(n, dtype=jnp.int32))
+            return outs, pool, counts
         fn = _JITS[key_] = paged_decode_steps
     return fn
 
@@ -871,13 +1099,10 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     wq = tokens.shape[1]
     bs = pool["k"].shape[3]
     pos = lengths[:, None] + jnp.arange(wq, dtype=jnp.int32)[None]
-    blk = jnp.clip(pos // bs, 0, tables.shape[1] - 1)
-    at = (jnp.take_along_axis(tables, blk, axis=1), pos % bs)  # (b, wq)
-    logits, nk, nv = verify_tokens_core(
-        params, pool["k"], pool["v"], tokens, lengths, cfg,
-        _pool_attend(cfg, tables, at, pos + 1, impl=impl,
-                     interpret=interpret, mesh=mesh, axis=axis))
-    return logits, {"k": nk, "v": nv}
+    return verify_tokens_core(
+        params, pool, tokens, lengths, cfg,
+        _pool_attend(cfg, tables, _places(tables, pos, bs), pos + 1,
+                     impl=impl, interpret=interpret, mesh=mesh, axis=axis))
 
 
 def paged_verify_steps(params, pool, tables, lengths, tokens, cfg, *,

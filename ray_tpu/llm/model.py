@@ -1,10 +1,14 @@
-"""Cache-aware llama forwards for inference: prefill, decode, verify.
+"""Cache-aware forwards for inference: prefill, decode, verify.
 
 The model side of the LLM serving stack (reference:
 python/ray/llm/_internal/serve/... wraps vLLM; here the engine is native:
-the training model in models/llama.py is reused — same params, same
-config — with inference-shaped forwards that XLA compiles once per
-shape bucket; TPU rule: no dynamic shapes):
+the training models in models/llama.py and models/moe.py are reused —
+same params, same config — with inference-shaped forwards that XLA
+compiles once per shape bucket; TPU rule: no dynamic shapes). Every
+forward runs ONE layer body (`_layer`) over the layers as `_run_layers`
+cuts them (a scan over a repeated period of layer kinds); what a model
+family adds to the Llama case is listed under "what the engine is
+handed" below:
 
 - `prefill` / `prefill_chunk`: a prompt (padded to a bucket) or one
   chunk of a long one; they emit per-layer K/V in token order, which
@@ -19,6 +23,7 @@ shape bucket; TPU rule: no dynamic shapes):
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional, Tuple
 
@@ -27,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.llm.kvcache import GLOBAL, WINDOW
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm, _rope,
                                   _rope_tables)
 
@@ -102,18 +108,245 @@ def _scan_layers(layer, x, xs):
     return lax.scan(jax.named_scope("layer")(layer), x, xs)
 
 
+# --- what the engine is handed ---------------------------------------------
+#
+# A model family is the module that defines the config's class: it makes
+# the parameters (``init_params``). What the serving forwards below need
+# of a config beyond the Llama fields are optional fields, read with
+# ``getattr`` so that a LlamaConfig is the case "every layer global, one
+# dense stack, pre-norm, RoPE everywhere, no experts":
+#
+#   layer_types      "global" | "window" per layer (sliding_window wide)
+#   n_dense_layers   leading layers live in ``params["dense_layers"]``
+#                    (a dense SwiGLU); the rest in ``params["layers"]``,
+#                    whose leaves decide the feed-forward: a ``router``
+#                    means experts (models/moe.py serve_block)
+#   qk_norm, qk_head_norm, post_norm, rope_layers   (models/moe.py)
+
+
+def model_family(cfg):
+    """The module that defines ``cfg``'s class (models/llama.py,
+    models/moe.py): ``init_params(rng, cfg)`` makes its parameters."""
+    import sys
+    return sys.modules[type(cfg).__module__]
+
+
+def layer_kinds(cfg) -> tuple:
+    """The attention kind of every layer, "global" or "window"."""
+    kinds = tuple(getattr(cfg, "layer_types", ()) or ())
+    if not kinds:
+        return (GLOBAL,) * cfg.n_layers
+    if len(kinds) != cfg.n_layers or set(kinds) - {GLOBAL, WINDOW}:
+        raise ValueError(
+            f"layer_types must name {cfg.n_layers} layers 'global' or "
+            f"'window', got {kinds}")
+    if WINDOW in kinds and getattr(cfg, "sliding_window", 0) < 1:
+        raise ValueError("window layers need sliding_window >= 1")
+    return kinds
+
+
+def kind_layers(cfg) -> dict:
+    """{kind: the layers of that kind, in order}; "global" first, a
+    kind with no layer left out. The KV pool keeps one pair of arrays a
+    kind (llm/kvcache.py init_pool)."""
+    kinds = layer_kinds(cfg)
+    return {k: tuple(i for i, x in enumerate(kinds) if x == k)
+            for k in (GLOBAL, WINDOW) if k in kinds}
+
+
+def window_of(cfg, kind: str):
+    """The window of a layer kind: None for a global layer."""
+    return cfg.sliding_window if kind == WINDOW else None
+
+
+def has_experts(cfg) -> bool:
+    return getattr(cfg, "n_experts", 0) > 0
+
+
+@dataclasses.dataclass(frozen=True)
+class _Segment:
+    """``repeats`` periods of layers whose kinds are ``kinds``, rows
+    ``row ...`` of ``params[stack]``, the first being layer ``layer0``."""
+    stack: str
+    row: int
+    layer0: int
+    kinds: tuple
+    repeats: int
+
+
+def _segments(cfg) -> tuple:
+    """The layers as runs that one scan can walk: per stack, the
+    smallest period whose repetition covers the most layers, then the
+    same for what is left. Twelve periods of (window, window, window,
+    global) behind a dense first layer are three segments; a Llama
+    model is one, of period one."""
+    kinds = layer_kinds(cfg)
+    n_dense = getattr(cfg, "n_dense_layers", 0)
+    out = []
+    for stack, lo, hi in (("dense_layers", 0, n_dense),
+                          ("layers", n_dense, cfg.n_layers)):
+        row = 0
+        while lo + row < hi:
+            rest = kinds[lo + row:hi]
+            p = next(p for p in range(1, len(rest) + 1)
+                     if rest[:p] * (len(rest) // p)
+                     == rest[:p * (len(rest) // p)])
+            out.append(_Segment(stack, row, lo + row, rest[:p],
+                                len(rest) // p))
+            row += p * (len(rest) // p)
+    return tuple(out)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerRef:
+    """Which layer a body is running: its attention ``kind``, its place
+    among the layers of that kind (``kind_index``: the layer axis of the
+    kind's pool) and in its parameter stack (``stack``, ``row``); the
+    indices are Python ints or traced."""
+    kind: str
+    layer: object
+    kind_index: object
+    stack: dict
+    row: object
+
+
+def _affine(i, a: int, b: int):
+    """``i * a + b`` with nothing traced for a = 1, b = 0."""
+    if a != 1:
+        i = i * a
+    return i + b if b else i
+
+
+def _run_layers(params, cfg, carry, body, per_layer=()):
+    """Run ``body(carry, lp, ref, *extras) -> (carry, y)`` over every
+    layer in order: each segment a ``lax.scan`` over its periods (the
+    kinds inside a period are static, so each has its own code), a
+    segment of one period unrolled. ``per_layer`` arrays have all the
+    layers on their first axis and are handed to the body a layer at a
+    time. Returns (carry, the ys with the layers on their first axis,
+    or None where the body returns none)."""
+    kinds = layer_kinds(cfg)
+    pieces = []
+    for seg in _segments(cfg):
+        stack, p, r = params[seg.stack], len(seg.kinds), seg.repeats
+        before = {k: kinds[:seg.layer0].count(k) for k in set(seg.kinds)}
+        rows = next(iter(jax.tree.leaves(stack))).shape[0]
+
+        def period(carry, lps, extras, i, seg=seg, stack=stack, p=p,
+                   before=before):
+            outs = []
+            for j, kind in enumerate(seg.kinds):
+                ref = LayerRef(
+                    kind, _affine(i, p, seg.layer0 + j),
+                    _affine(i, seg.kinds.count(kind),
+                            before[kind] + seg.kinds[:j].count(kind)),
+                    stack, _affine(i, p, seg.row + j))
+                carry, y = body(carry, lps[j], ref,
+                                *(e[j] for e in extras))
+                outs.append(y)
+            return carry, outs
+
+        if r == 1:
+            lps = [jax.tree.map(lambda w, j=j: w[seg.row + j], stack)
+                   for j in range(p)]
+            extras = [[e[seg.layer0 + j] for j in range(p)]
+                      for e in per_layer]
+            carry, outs = period(carry, lps, extras, 0)
+            pieces.extend(jax.tree.map(lambda y: y[None], o) for o in outs)
+            continue
+        index = jnp.arange(r, dtype=jnp.int32)
+        if p == 1 and rows == r:
+            # one kind, the whole stack: the stack itself is scanned
+            def step(carry, xs, period=period):
+                lp, i, *extras = xs
+                carry, (y,) = period(carry, [lp], [[e] for e in extras], i)
+                return carry, y
+            carry, ys = _scan_layers(
+                step, carry,
+                (stack, index, *(e[seg.layer0:seg.layer0 + r]
+                                 for e in per_layer)))
+            pieces.append(ys)
+            continue
+
+        def cut(w, first):
+            return w[first:first + r * p].reshape(r, p, *w.shape[1:])
+
+        def step(carry, xs, period=period, p=p):
+            lps, i, *extras = xs
+            carry, outs = period(
+                carry, [jax.tree.map(lambda w, j=j: w[j], lps)
+                        for j in range(p)],
+                [[e[j] for j in range(p)] for e in extras], i)
+            return carry, jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
+        carry, ys = _scan_layers(
+            step, carry,
+            (jax.tree.map(lambda w: cut(w, seg.row), stack), index,
+             *(cut(e, seg.layer0) for e in per_layer)))
+        pieces.append(jax.tree.map(
+            lambda y: y.reshape(r * p, *y.shape[2:]), ys))
+    if len(pieces) == 1:
+        return carry, pieces[0]
+    return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys), *pieces)
+
+
 def _qkv(y, lp, cfg: LlamaConfig):
     b, s = y.shape[:2]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (y @ lp["wq"]).reshape(b, s, h, hd)
-    k = (y @ lp["wk"]).reshape(b, s, kvh, hd)
-    v = (y @ lp["wv"]).reshape(b, s, kvh, hd)
-    return q, k, v
+
+    def heads(w, n, norm=None):
+        z = y @ lp[w]
+        if norm and getattr(cfg, "qk_norm", False):     # the whole width
+            z = _rmsnorm(z, lp[norm], cfg.norm_eps)
+        z = z.reshape(b, s, n, hd)
+        if norm and getattr(cfg, "qk_head_norm", False):    # a head
+            z = _rmsnorm(z, lp[norm], cfg.norm_eps)
+        return z
+    return heads("wq", h, "q_norm"), heads("wk", kvh, "k_norm"), \
+        heads("wv", kvh)
 
 
-def _gqa_attend_cached(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
+def _feed_forward(y, lp, cfg, ref: LayerRef, active):
+    """The layer's feed-forward on normed rows y (b, s, d): the dense
+    SwiGLU, or for a layer with a router this device's part of the
+    expert layer (models/moe.py serve_block). Returns (out, the expert
+    layer's counts or None)."""
+    if "router" not in lp:
+        with jax.named_scope("mlp"):
+            return ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
+                    @ lp["w_down"]), None
+    from ray_tpu.models import moe
+    out, stats = moe.serve_block(
+        y.reshape(-1, y.shape[-1]), lp, cfg, stack=ref.stack, row=ref.row,
+        active=active)
+    return out.reshape(y.shape), stats
+
+
+def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
+    """One decoder layer of every serving forward. x (b, s, d);
+    ``rope`` the (cos, sin) tables of the positions; ``attend(q, k, v,
+    wo)`` attends (the forwards differ in nothing else) and returns the
+    projected output, shaped like x. Returns (x, k, v, expert counts or
+    None); k is as the cache keeps it (after RoPE)."""
+    post = getattr(cfg, "post_norm", False)
+    eps = cfg.norm_eps
+    with jax.named_scope("attention." + ref.kind):
+        y = x if post else _rmsnorm(x, lp["attn_norm"], eps)
+        q, k, v = _qkv(y, lp, cfg)
+        if ref.kind == WINDOW or getattr(cfg, "rope_layers", "all") == "all":
+            q, k = _rope(q, *rope), _rope(k, *rope)
+        a = attend(q, k, v, lp["wo"])
+        x = x + (_rmsnorm(a, lp["attn_norm"], eps) if post else a)
+    y = x if post else _rmsnorm(x, lp["mlp_norm"], eps)
+    m, stats = _feed_forward(y, lp, cfg, ref, active)
+    x = x + (_rmsnorm(m, lp["mlp_norm"], eps) if post else m)
+    return x, k, v, stats
+
+
+def _gqa_attend_cached(q, cache_k, cache_v, lengths, cfg: LlamaConfig,
+                       window=None):
     """q: (b, h, hd) current-token queries; cache_k/v: (b, L, kvh, hd);
-    lengths: (b,) valid cache entries per slot (incl. current token)."""
+    lengths: (b,) valid cache entries per slot (incl. current token);
+    ``window``: attend the last ``window`` of them only."""
     b = q.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = h // kvh
@@ -121,6 +354,9 @@ def _gqa_attend_cached(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
     kf = cache_k.astype(jnp.float32)
     scores = jnp.einsum("bkgd,blkd->bkgl", qg, kf) / jnp.sqrt(hd)
     mask = jnp.arange(cache_k.shape[1])[None] < lengths[:, None]  # (b, L)
+    if window is not None:
+        mask = mask & (jnp.arange(cache_k.shape[1])[None]
+                       >= lengths[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgl,blkd->bkgd", probs,
@@ -149,6 +385,23 @@ def resolve_prefill_impl(cfg: LlamaConfig) -> str:
     return impl
 
 
+def _prefill_attn_kw(cfg, kind: str) -> dict:
+    """The prefill attention call's arguments beside the common ones: the
+    config's flash tiles (the kernel's own 128 x 128 where it says
+    nothing else) and, for a window layer, the window."""
+    kw = {"block_q": cfg.attn_block_q, "block_k": cfg.attn_block_k}
+    if kind == WINDOW:
+        kw["window"] = cfg.sliding_window
+    return kw
+
+
+def _head(x, params, cfg, length):
+    """Last valid row of x (1, s, d) -> (vocab,) float32 logits."""
+    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    last = jnp.take(x[0], length - 1, axis=0)
+    return (last @ params["lm_head"]).astype(jnp.float32)
+
+
 @partial(jax.jit, static_argnames=("cfg", "max_len"))
 def prefill(params: dict, tokens: jax.Array, length: jax.Array,
             cfg: LlamaConfig, max_len: int) -> Tuple[jax.Array, dict]:
@@ -158,34 +411,29 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
 
     Attention dispatches through ops.attention (cfg.attn_impl): the
     pallas flash kernel tiles long prompts on TPU instead of
-    materializing the O(s^2) score tensor. Causal alone is exact here:
-    pad keys sit at positions >= length, and every USED query row is
-    < length, so causality already excludes them (pad rows' outputs are
-    garbage but only row length-1 is read)."""
+    materializing the O(s^2) score tensor (a window layer's band
+    included). Causal alone is exact here: pad keys sit at positions >=
+    length, and every USED query row is < length, so causality already
+    excludes them (pad rows' outputs are garbage but only row length-1
+    is read)."""
     from ray_tpu.ops.attention import attention as _attention
     s = tokens.shape[0]
     x = jnp.take(params["embed"], tokens[None], axis=0)  # (1, s, emb)
     positions = jnp.arange(s, dtype=jnp.int32)[None]
-    rc, rs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    h, hd = cfg.n_heads, cfg.head_dim
 
-    def layer(x, lp):
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(y, lp, cfg)
-        q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        h, hd = cfg.n_heads, cfg.head_dim
-        o = _attention(q, k, v, causal=True, sm_scale=hd ** -0.5,
-                       impl=_serve_attn_impl(cfg))
-        o = o.reshape(1, s, h * hd).astype(x.dtype)
-        x = x + o @ lp["wo"]
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
-                 @ lp["w_down"])
+    def layer(x, lp, ref):
+        def attend(q, k, v, wo):
+            o = _attention(q, k, v, causal=True, sm_scale=hd ** -0.5,
+                           impl=_serve_attn_impl(cfg),
+                           **_prefill_attn_kw(cfg, ref.kind))
+            return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
+        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
         return x, (k[0], v[0])
 
-    x, (ks, vs) = _scan_layers(layer, x, params["layers"])
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take(x[0], length - 1, axis=0)
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
+    x, (ks, vs) = _run_layers(params, cfg, x, layer)
+    logits = _head(x, params, cfg, length)
     # pad kv (layers, s, kvh, hd) -> (layers, max_len, kvh, hd)
     pad = [(0, 0), (0, max_len - s), (0, 0), (0, 0)]
     return logits, {"k": jnp.pad(ks, pad), "v": jnp.pad(vs, pad)}
@@ -227,6 +475,14 @@ def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
                               jnp.asarray(offset, jnp.int32), acc, cfg)
 
 
+def _into_acc(acc, new, offset):
+    """One layer's accumulator (L, kvh, hd) with the chunk's rows
+    (1, s, kvh, hd) written at ``offset``."""
+    return lax.dynamic_update_slice(
+        acc, new[0].astype(acc.dtype),
+        (jnp.int32(offset), jnp.int32(0), jnp.int32(0)))
+
+
 @partial(jax.jit, static_argnames=("cfg", "offset", "impl"),
          donate_argnums=(4,))
 def _prefill_chunk_flash(params: dict, tokens: jax.Array,
@@ -241,36 +497,22 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
     h, hd = cfg.n_heads, cfg.head_dim
     x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
-    rc, rs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
-    def layer(carry, xs):
-        x = carry
-        lp, ak, av = xs     # ak/av: (L, kvh, hd) this layer's acc
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(y, lp, cfg)
-        q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        ak = lax.dynamic_update_slice(
-            ak, k[0].astype(ak.dtype),
-            (jnp.int32(offset), jnp.int32(0), jnp.int32(0)))
-        av = lax.dynamic_update_slice(
-            av, v[0].astype(av.dtype),
-            (jnp.int32(offset), jnp.int32(0), jnp.int32(0)))
-        o = _attention(q, ak[None].astype(q.dtype),
-                       av[None].astype(q.dtype), causal=True,
-                       sm_scale=hd ** -0.5, impl=impl, q_offset=offset)
-        o = o.reshape(1, s, h * hd).astype(x.dtype)
-        x = x + o @ lp["wo"]
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
-                 @ lp["w_down"])
-        return x, (ak, av)
+    def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
+        def attend(q, k, v, wo):
+            nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
+            o = _attention(q, nk[None].astype(q.dtype),
+                           nv[None].astype(q.dtype), causal=True,
+                           sm_scale=hd ** -0.5, impl=impl, q_offset=offset,
+                           **_prefill_attn_kw(cfg, ref.kind))
+            return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
+        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
+        return x, (_into_acc(ak, k, offset), _into_acc(av, v, offset))
 
-    x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], acc["k"], acc["v"]))
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take(x[0], length - 1, axis=0)
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": nk, "v": nv}
+    x, (nk, nv) = _run_layers(params, cfg, x, layer,
+                              per_layer=(acc["k"], acc["v"]))
+    return _head(x, params, cfg, length), {"k": nk, "v": nv}
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(4,))
@@ -284,44 +526,35 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
     g = h // kvh
     x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
-    rc, rs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     q_pos = positions[0]                                    # (s,)
     k_pos = jnp.arange(L, dtype=jnp.int32)                  # (L,)
     # causal over ABSOLUTE positions (covers both earlier chunks and
     # intra-chunk order), limited to valid keys
     m = (k_pos[None, :] <= q_pos[:, None]) & \
         (k_pos[None, :] < offset + length)
+    masks = {GLOBAL: m}
+    if WINDOW in layer_kinds(cfg):
+        masks[WINDOW] = m & (k_pos[None, :]
+                             > q_pos[:, None] - cfg.sliding_window)
 
-    def layer(carry, xs):
-        x = carry
-        lp, ak, av = xs     # ak/av: (L, kvh, hd) this layer's acc
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(y, lp, cfg)
-        q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        ak = lax.dynamic_update_slice(
-            ak, k[0].astype(ak.dtype), (offset, jnp.int32(0), jnp.int32(0)))
-        av = lax.dynamic_update_slice(
-            av, v[0].astype(av.dtype), (offset, jnp.int32(0), jnp.int32(0)))
-        qg = q[0].reshape(s, kvh, g, hd).astype(jnp.float32)
-        kf = ak.astype(jnp.float32)                         # (L, kvh, hd)
-        scores = jnp.einsum("skgd,lkd->kgsl", qg, kf) / jnp.sqrt(hd)
-        scores = jnp.where(m[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("kgsl,lkd->skgd", probs,
-                       av.astype(jnp.float32))
-        o = o.reshape(1, s, h * hd).astype(x.dtype)
-        x = x + o @ lp["wo"]
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
-                 @ lp["w_down"])
-        return x, (ak, av)
+    def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
+        def attend(q, k, v, wo):
+            nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
+            qg = q[0].reshape(s, kvh, g, hd).astype(jnp.float32)
+            kf = nk.astype(jnp.float32)                     # (L, kvh, hd)
+            scores = jnp.einsum("skgd,lkd->kgsl", qg, kf) / jnp.sqrt(hd)
+            scores = jnp.where(masks[ref.kind][None, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            o = jnp.einsum("kgsl,lkd->skgd", probs,
+                           nv.astype(jnp.float32))
+            return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
+        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
+        return x, (_into_acc(ak, k, offset), _into_acc(av, v, offset))
 
-    x, (nk, nv) = _scan_layers(
-        layer, x, (params["layers"], acc["k"], acc["v"]))
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take(x[0], length - 1, axis=0)
-    logits = (last @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": nk, "v": nv}
+    x, (nk, nv) = _run_layers(params, cfg, x, layer,
+                              per_layer=(acc["k"], acc["v"]))
+    return _head(x, params, cfg, length), {"k": nk, "v": nv}
 
 
 def sample(logits: jax.Array, temps: jax.Array, key: jax.Array,
@@ -389,45 +622,54 @@ def filter_logits(scaled, top_ks=None, top_ps=None):
     return masked
 
 
-def decode_logits_core(params: dict, kpool: jax.Array,
-                       vpool: jax.Array, tokens: jax.Array,
+def _add_counts(total, stats):
+    """The expert layers' counts of one step, summed over its layers."""
+    if stats is None:
+        return total
+    return stats if total is None else jax.tree.map(jnp.add, total, stats)
+
+
+def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
                        positions: jax.Array, cfg: LlamaConfig, attend):
     """THE decode-step transformer: one token for every slot against
-    the KV pool, k/v (layers, blocks, kvh, block_size, hd). The pools
-    are the layer scan's CARRY, never sliced by layer and never
-    stacked back: a decode program that donates (or itself carries)
-    them updates them in place. Per layer, ``attend(l, q, k, v, kpool,
-    vpool) -> ((slots, h*hd) f32, kpool, vpool)`` (l: the layer's
-    index, traced; q: (slots, 1, h, hd); k, v: (slots, kvh, hd), the
-    new token's rows) puts the rows into layer l of the pools and
-    attends over the slot's table, the one thing callers differ in:
-    the aliased writer and the kernel that walks the table itself
+    the KV pool (llm/kvcache.py init_pool: k/v (layers of a kind,
+    blocks, kvh, block_size, hd) a layer kind). The pool is the layer
+    scans' CARRY, never sliced by layer and never stacked back: a
+    decode program that donates (or itself carries) it updates it in
+    place. Per layer, ``attend(ref, q, k, v, pool) -> ((slots, h*hd)
+    f32, pool)`` (ref: the LayerRef, its indices traced in a scan; q:
+    (slots, 1, h, hd); k, v: (slots, kvh, hd), the new token's rows)
+    puts the rows into the layer's place in its kind's pool and attends
+    over the slot's table of that kind, the one thing callers differ
+    in: the aliased writer and the kernel that walks the table itself
     (ops/pallas/paged_attention.py), or their reference, a scatter +
     table_view + _gqa_attend_cached. Returns ((slots, vocab) f32
-    logits, kpool, vpool)."""
+    logits, pool, the expert layers' counts summed over the layers or
+    None: models/moe.py serve_block, live rows being the slots at a
+    position > 0)."""
     x = jnp.take(params["embed"], tokens[:, None], axis=0)  # (b, 1, emb)
-    rc, rs = _rope_tables(positions[:, None], cfg.head_dim,
-                          cfg.rope_theta)
+    rope = _rope_tables(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    active = positions > 0 if has_experts(cfg) else None
 
-    def layer(carry, xs):
-        x, ck, cv = carry
-        lp, l = xs
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(y, lp, cfg)  # (b, 1, ...)
-        q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        o, ck, cv = attend(l, q, k[:, 0], v[:, 0], ck, cv)
-        x = x + (o.astype(x.dtype) @ lp["wo"])[:, None]
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
-                 @ lp["w_down"])
-        return (x, ck, cv), None
+    def layer(carry, lp, ref):
+        x, pool, counts = carry
 
-    index = jnp.arange(kpool.shape[0], dtype=jnp.int32)
-    (x, kpool, vpool), _ = _scan_layers(
-        layer, (x, kpool, vpool), (params["layers"], index))
+        def attend_(q, k, v, wo):
+            nonlocal pool
+            o, pool = attend(ref, q, k[:, 0], v[:, 0], pool)
+            return (o.astype(x.dtype) @ wo)[:, None]
+        x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active)
+        return (x, pool, _add_counts(counts, stats)), None
+
+    counts = None
+    if has_experts(cfg):
+        counts = {k: jnp.int32(0)
+                  for k in ("routed", "local", "experts_hit")}
+    (x, pool, counts), _ = _run_layers(params, cfg, (x, pool, counts),
+                                       layer)
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, kpool, vpool
+    return logits, pool, counts
 
 
 def _gqa_attend_multi(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
@@ -456,11 +698,10 @@ def _gqa_attend_multi(q, cache_k, cache_v, lengths, cfg: LlamaConfig):
     return out.reshape(b, w, h * hd)
 
 
-def verify_tokens_core(params: dict, kpool: jax.Array,
-                       vpool: jax.Array, tokens: jax.Array,
+def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
                        positions: jax.Array, cfg: LlamaConfig, attend):
     """The speculative-verify transformer: decode_logits_core widened
-    from one token per slot to w — same layer scan with the pools as
+    from one token per slot to w — same layer scan with the pool as
     its carry, same pool write, so the verify forward can never drift
     from sequential decode.
     tokens: (b, w) int32 where column 0 is the last emitted token and
@@ -471,30 +712,24 @@ def verify_tokens_core(params: dict, kpool: jax.Array,
     token j+1. No device sampling: acceptance is a host decision
     (llm/spec.py) so rejection sampling can inspect the full
     distribution.
-    ``attend(l, q, k, v, kpool, vpool) -> ((b, w, h*hd) f32, kpool,
-    vpool)`` takes q (b, w, h, hd) and the new rows k, v
-    (b, w, kvh, hd)."""
+    ``attend(ref, q, k, v, pool) -> ((b, w, h*hd) f32, pool)`` takes q
+    (b, w, h, hd) and the new rows k, v (b, w, kvh, hd)."""
     b, w = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)           # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
-    rc, rs = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
+    rope = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
 
-    def layer(carry, xs):
-        x, ck, cv = carry
-        lp, l = xs
-        y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(y, lp, cfg)                          # (b, w, ...)
-        q, k = _rope(q, rc, rs), _rope(k, rc, rs)
-        o, ck, cv = attend(l, q, k, v, ck, cv)
-        x = x + o.astype(x.dtype) @ lp["wo"]
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
-                 @ lp["w_down"])
-        return (x, ck, cv), None
+    def layer(carry, lp, ref):
+        x, pool = carry
 
-    index = jnp.arange(kpool.shape[0], dtype=jnp.int32)
-    (x, kpool, vpool), _ = _scan_layers(
-        layer, (x, kpool, vpool), (params["layers"], index))
+        def attend_(q, k, v, wo):
+            nonlocal pool
+            o, pool = attend(ref, q, k, v, pool)
+            return o.astype(x.dtype) @ wo
+        x, _, _, _ = _layer(x, lp, cfg, ref, rope, attend_)
+        return (x, pool), None
+
+    (x, pool), _ = _run_layers(params, cfg, (x, pool), layer)
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
-    return logits, kpool, vpool
+    return logits, pool

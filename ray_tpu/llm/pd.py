@@ -23,7 +23,6 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ray_tpu.llm import model as lm
-from ray_tpu.models.llama import LlamaConfig
 
 
 class PrefillEngine:
@@ -39,7 +38,7 @@ class PrefillEngine:
     copy dies with the request object).
     """
 
-    def __init__(self, cfg: LlamaConfig, params, *,
+    def __init__(self, cfg, params, *,
                  prefill_buckets: Sequence[int] = (64, 128, 256, 512),
                  max_len: int = 1024,
                  cache_dtype: str = "bfloat16",

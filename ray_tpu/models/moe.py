@@ -1,10 +1,15 @@
 """Mixture-of-Experts decoder family, TPU-first: dropless top-k routing
-over a grouped matmul. Mixtral and OLMoE are settings of one config.
+over a grouped matmul. Mixtral, OLMoE and the sigmoid-routed hybrid
+(``k_exaone_236b_a23b``) are settings of one config.
 
 The expert layer, for the (tokens, d) rows ``y`` of a normed hidden state:
-router logits in float32, softmax over all experts, the ``k`` largest
-probabilities per token (renormalised to sum to 1 when ``norm_topk_prob``,
-as Mixtral does; kept as they are when not, as OLMoE does); a stable sort
+router logits in float32, then one of two scorings (``scoring``).
+"softmax": softmax over all experts, the ``k`` largest probabilities per
+token (renormalised to sum to 1 when ``norm_topk_prob``, as Mixtral does;
+kept as they are when not, as OLMoE does). "sigmoid": s = sigmoid(logits);
+the ``k`` largest of s + b choose the experts (b the router's selection
+bias, a parameter that never enters a gate), the gates are the chosen s,
+renormalised when ``norm_topk_prob``, times ``routed_scaling``. Then a stable sort
 of the tokens x k assignments by expert and a count give the row order and
 the group sizes; the rows are gathered in that order, go through three
 grouped matmuls (gate, up, down: ``ops/pallas/grouped_matmul.py`` on a
@@ -14,6 +19,14 @@ whatever the load: there is no capacity and nothing is dropped, and the
 cost follows tokens x k, not experts x capacity. Dispatch and combine are
 gathers in both directions (the backward of a gather by a permutation is
 the gather by its inverse), so no scatter runs on the device.
+``n_shared_experts`` adds one SwiGLU of ``n_shared_experts * ffn_dim`` that
+every token goes through, beside the routed sum.
+
+A device can hold a SLICE of the experts without a mesh: ``experts_held``
+of ``n_experts`` from ``first_expert`` on. The router still scores all
+``n_experts``; the layer computes the held experts' part (plus the shared
+expert) and returns that partial sum: what an expert-parallel deployment
+adds up across its devices. Nothing here stands in for the other devices.
 
 Expert parallelism is a mesh axis (``MeshAxes.expert``): the expert layer
 runs under ``shard_map`` with the tokens sharded over the batch and
@@ -35,11 +48,23 @@ Attention is shared with the Llama family (``ray_tpu.models.llama``): RoPE
 + GQA + flash/ring kernels, identical remat policies. ``qk_norm`` adds
 OLMoE's RMSNorm with a learned weight over the whole projected q and k,
 before the split into heads and before RoPE.
+
+The serving-only shapes (``llm/model.py`` runs them; the train forward
+here refuses them): ``layer_types`` ("window" layers attend the last
+``sliding_window`` positions, "global" ones everything), ``n_dense_layers``
+leading layers with a dense SwiGLU of ``dense_ffn_dim``, ``qk_head_norm``
+(RMSNorm of q and k over ``head_dim``, per head, before RoPE),
+``post_norm`` (the sub-layer norms on each sub-layer's OUTPUT: x +
+Norm(Attn(x))), ``rope_layers`` "window" (no RoPE on global layers); and,
+until a training configuration needs them, "sigmoid" scoring, the shared
+expert and a held slice (``serve_block``; the train forward's ``_experts``
+shares ``_route``, ``_sort_by_expert`` and ``_gated_sum`` with it).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -82,27 +107,62 @@ class MoEConfig:
     # "auto": the Pallas kernel on a TPU, lax.ragged_dot elsewhere;
     # "pallas", "pallas_interpret" (CPU tests), "ragged_dot"
     gmm_impl: str = "auto"
+    # 0: dim // n_heads
+    head_size: int = 0
+    # "softmax", or "sigmoid" with the selection bias and this scale
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    n_shared_experts: int = 0
+    # this device's slice of the experts: 0 = all of them
+    experts_held: int = 0
+    first_expert: int = 0
+    # serving-only shapes (module docstring)
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    n_dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    qk_head_norm: bool = False
+    post_norm: bool = False
+    rope_layers: str = "all"
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def _attn_params(self) -> int:
+        d, h, kvh, hd = self.dim, self.n_heads, self.n_kv_heads, self.head_dim
+        attn = d * h * hd + 2 * d * kvh * hd + h * hd * d + 2 * d
+        if self.qk_norm:
+            attn += h * hd + kvh * hd
+        if self.qk_head_norm:
+            attn += 2 * hd
+        return attn
 
     def _layer_params(self, experts: int) -> int:
         d, f = self.dim, self.ffn_dim
-        h, kvh, hd = self.n_heads, self.n_kv_heads, self.head_dim
-        attn = d * h * hd + 2 * d * kvh * hd + h * hd * d
-        if self.qk_norm:
-            attn += h * hd + kvh * hd
-        return attn + d * self.n_experts + 3 * experts * d * f + 2 * d
+        router = d * self.n_experts \
+            + (self.n_experts if self.scoring == "sigmoid" else 0)
+        return self._attn_params() + router \
+            + 3 * (experts + self.n_shared_experts) * d * f
+
+    def _params(self, experts: int) -> int:
+        dense = self._attn_params() + 3 * self.dim * self.dense_ffn_dim
+        return 2 * self.vocab_size * self.dim + self.dim \
+            + self.n_dense_layers * dense \
+            + (self.n_layers - self.n_dense_layers) \
+            * self._layer_params(experts)
 
     def num_params(self) -> int:
-        return 2 * self.vocab_size * self.dim + self.dim \
-            + self.n_layers * self._layer_params(self.n_experts)
+        """Parameters this device holds (``experts_held`` of the experts)."""
+        return self._params(self.n_held)
 
     def num_active_params(self) -> int:
         """Params touched per token (top-k experts, not all)."""
-        return 2 * self.vocab_size * self.dim + self.dim \
-            + self.n_layers * self._layer_params(self.experts_per_token)
+        return self._params(self.experts_per_token)
 
     def flops_per_token(self, seq_len: int) -> float:
         n_matmul = self.num_active_params() - self.vocab_size * self.dim
@@ -124,6 +184,27 @@ def olmoe_1b_7b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def k_exaone_236b_a23b(**kw) -> MoEConfig:
+    """LGAI-EXAONE/K-EXAONE-236B-A23B ``config.json``: 48 layers in the
+    period window, window, window, global (window 128), layer 0 dense,
+    128 sigmoid-routed experts of width 2048, 8 a token, one shared
+    expert, head_dim 128 beside hidden 6144 / 64 heads."""
+    defaults = dict(
+        vocab_size=153600, dim=6144, n_layers=48, n_heads=64, n_kv_heads=8,
+        head_size=128, ffn_dim=2048, n_experts=128, experts_per_token=8,
+        norm_topk_prob=True, scoring="sigmoid", routed_scaling=2.5,
+        n_shared_experts=1, n_dense_layers=1, dense_ffn_dim=18432,
+        sliding_window=128, qk_head_norm=True, post_norm=True,
+        rope_layers="window", max_seq_len=262144, rope_theta=1e6,
+        norm_eps=1e-5)
+    defaults.update(kw)
+    if "layer_types" not in defaults:
+        defaults["layer_types"] = tuple(
+            "global" if i % 4 == 3 else "window"
+            for i in range(defaults["n_layers"]))
+    return MoEConfig(**defaults)
+
+
 def tiny(**kw) -> MoEConfig:
     defaults = dict(vocab_size=512, dim=64, n_layers=2, n_heads=4,
                     n_kv_heads=2, ffn_dim=128, n_experts=4,
@@ -134,7 +215,120 @@ def tiny(**kw) -> MoEConfig:
 
 # --- params ----------------------------------------------------------------
 
+def _serving_only(cfg: MoEConfig) -> bool:
+    """Whether ``cfg`` has a shape only the serving forwards run."""
+    return bool(cfg.layer_types or cfg.n_dense_layers or cfg.experts_held
+                or cfg.qk_head_norm or cfg.post_norm
+                or cfg.rope_layers != "all" or cfg.head_size
+                or cfg.scoring != "softmax" or cfg.n_shared_experts)
+
+
+# elements of one float32 draw while a leaf is made: 1 GB
+_DRAW = 1 << 28
+# The random selection bias of a sigmoid router. Of the size of the gap
+# between neighbouring scores near the 8th of 128 (0.009), so that
+# choosing by s + b and weighting by s differ at most tokens; not larger:
+# at 0.05 an expert's bias moved its share of the tokens fourfold, and how
+# many experts a decode step reaches (its bytes) then hung on the seed.
+ROUTER_BIAS_SCALE = 0.01
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "scale"),
+                   donate_argnums=(0,))
+def _fill_rows(buf, key, at, *, rows, scale):
+    draw = jax.random.normal(key, (rows, *buf.shape[1:]), jnp.float32)
+    return lax.dynamic_update_slice_in_dim(
+        buf, (draw * scale).astype(buf.dtype), at, axis=0)
+
+
+def _normal_stack(key, shape, fan_in, dtype):
+    """A leaf of fan-in scaled normals, made ``_DRAW`` elements at a time
+    along its first axis into one buffer: the float32 draw stays small
+    beside the leaf (a stacked expert leaf is gigabytes, its float32
+    twice that)."""
+    per_row = 1
+    for n in shape[1:]:
+        per_row *= n
+    rows = max(1, min(shape[0], _DRAW // per_row))
+    while shape[0] % rows:
+        rows -= 1
+    buf = jnp.zeros(shape, dtype)
+    for i, k in enumerate(jax.random.split(key, shape[0] // rows)):
+        buf = _fill_rows(buf, k, i * rows, rows=rows, scale=fan_in ** -0.5)
+    return buf
+
+
+def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
+    """Parameters of a config with serving-only shapes: the leading dense
+    layers and the expert layers are two stacks (``dense_layers``,
+    ``layers``), the experts only those this device holds."""
+    dtype = jnp.dtype(cfg.dtype)
+    d, f, E, held = cfg.dim, cfg.ffn_dim, cfg.n_experts, cfg.n_held
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(rng, 32))
+    # A post-norm stream is a sum of normed outputs. At norm weights of 1
+    # beside an embedding of d ** -0.5 the token would be a hundredth of
+    # it and the attention's output most: at random weights that is nearly
+    # the mean of the context's values, the same for every position of a
+    # window, so a request's tokens would all choose the same experts (the
+    # experts a decode step reaches, its bytes, then hang on which requests
+    # are live and on the seed). No trained model routes so: the embedding
+    # gets unit RMS, the attention's norm a weight of 0.25.
+    embed_fan_in, attn_norm = (1, 0.25) if cfg.post_norm else (d, 1.0)
+
+    def stack(*shape, fan_in):
+        return _normal_stack(next(keys), shape, fan_in, dtype)
+
+    def attn(L):
+        out = {"attn_norm": jnp.full((L, d), attn_norm, dtype),
+               "wq": stack(L, d, h * hd, fan_in=d),
+               "wk": stack(L, d, kvh * hd, fan_in=d),
+               "wv": stack(L, d, kvh * hd, fan_in=d),
+               "wo": stack(L, h * hd, d, fan_in=h * hd),
+               "mlp_norm": jnp.ones((L, d), dtype)}
+        if cfg.qk_head_norm:
+            # normed q and k of weight 1 would score q.k / sqrt(hd) with
+            # a spread of sqrt(hd): a softmax that is one-hot, which no
+            # trained model has; hd ** -0.25 each gives a unit spread
+            out["q_norm"] = jnp.full((L, hd), hd ** -0.25, dtype)
+            out["k_norm"] = jnp.full((L, hd), hd ** -0.25, dtype)
+        elif cfg.qk_norm:
+            out["q_norm"] = jnp.ones((L, h * hd), dtype)
+            out["k_norm"] = jnp.ones((L, kvh * hd), dtype)
+        return out
+
+    Ld, Ls = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
+    params = {"embed": stack(cfg.vocab_size, d, fan_in=embed_fan_in),
+              "final_norm": jnp.ones((d,), dtype),
+              "lm_head": stack(d, cfg.vocab_size, fan_in=d)}
+    if Ld:
+        fd = cfg.dense_ffn_dim
+        params["dense_layers"] = {
+            **attn(Ld), "w_gate": stack(Ld, d, fd, fan_in=d),
+            "w_up": stack(Ld, d, fd, fan_in=d),
+            "w_down": stack(Ld, fd, d, fan_in=fd)}
+    layers = {
+        **attn(Ls),
+        "router": jax.random.normal(next(keys), (Ls, d, E), jnp.float32)
+        * (d ** -0.5),
+        "w_gate": stack(Ls, held, d, f, fan_in=d),
+        "w_up": stack(Ls, held, d, f, fan_in=d),
+        "w_down": stack(Ls, held, f, d, fan_in=f)}
+    if cfg.scoring == "sigmoid":
+        layers["router_bias"] = ROUTER_BIAS_SCALE * jax.random.normal(
+            next(keys), (Ls, E), jnp.float32)
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        layers.update(shared_gate=stack(Ls, d, fs, fan_in=d),
+                      shared_up=stack(Ls, d, fs, fan_in=d),
+                      shared_down=stack(Ls, fs, d, fan_in=fs))
+    params["layers"] = layers
+    return params
+
+
 def init_params(rng: jax.Array, cfg: MoEConfig) -> dict:
+    if _serving_only(cfg):
+        return _init_serving(rng, cfg)
     dtype = jnp.dtype(cfg.dtype)
     d, f, E = cfg.dim, cfg.ffn_dim, cfg.n_experts
     h, kvh, hd, L = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
@@ -250,49 +444,130 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _grouped(x, w, group_sizes, cfg: MoEConfig):
+def _grouped(x, w, group_sizes, cfg: MoEConfig, layer=None):
+    """``w`` (E, k, n), or with ``layer`` the whole stack (L, E, k, n) of
+    which the kernel reads layer ``layer`` in place (a slice of the stack
+    handed to a custom call would be copied out first)."""
     impl = cfg.gmm_impl
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "ragged_dot"
     if impl == "ragged_dot":
+        if layer is not None:
+            w = lax.dynamic_index_in_dim(w, layer, keepdims=False)
         return lax.ragged_dot(x, w, group_sizes)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"unknown gmm_impl: {cfg.gmm_impl!r}")
+    if layer is not None:
+        return grouped_matmul.gmm_stacked(x, w, group_sizes, layer,
+                                          impl == "pallas_interpret")
     return grouped_matmul.gmm(x, w, group_sizes, impl == "pallas_interpret")
+
+
+def _route(y, router, bias, cfg: MoEConfig):
+    """(gates (T, k) float32, experts (T, k) int32, scores (T, E)): the
+    module docstring's two scorings."""
+    k = cfg.experts_per_token
+    logits = jnp.dot(y.astype(jnp.float32), router,
+                     precision=lax.Precision.HIGHEST)
+    if cfg.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)                           # (T, E)
+        _, experts = lax.top_k(probs + bias, k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    elif cfg.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)                  # (T, E)
+        gates, experts = lax.top_k(probs, k)                     # (T, k)
+    else:
+        raise ValueError(f"unknown scoring: {cfg.scoring!r}")
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        gates = gates * cfg.routed_scaling
+    return gates, experts, probs
+
+
+def _sort_by_expert(experts, first_expert, local: int):
+    """(mine, order, inverse, group_sizes) of the (T, k) assignments for a
+    device that holds experts ``first_expert ... + local``: the others'
+    sort last, into rows no group covers."""
+    mine = experts.reshape(-1) - first_expert
+    mine = jnp.where((mine >= 0) & (mine < local), mine, local)
+    order = jnp.argsort(mine, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    # counts by compare-and-sum: a scatter-add into E bins serialises
+    group_sizes = jnp.sum(mine[:, None] == jnp.arange(local), axis=0,
+                          dtype=jnp.int32)
+    return mine, order, inverse, group_sizes
+
+
+def _gated_sum(y, gates, order, inverse, group_sizes, w, cfg: MoEConfig,
+               layer=None):
+    """The routed rows of y through the experts ``w`` holds
+    (``w["w_gate" | "w_up" | "w_down"]``: a layer's, or with ``layer`` the
+    stacks), summed per token by ``gates``."""
+    with jax.named_scope("moe.experts"):
+        x = _dispatch(y, order, inverse)                         # (T*k, d)
+        h = jax.nn.silu(_grouped(x, w["w_gate"], group_sizes, cfg, layer)) \
+            * _grouped(x, w["w_up"], group_sizes, cfg, layer)
+        rows = _grouped(h, w["w_down"], group_sizes, cfg, layer)
+    with jax.named_scope("moe.combine"):
+        return _combine(rows, gates, order, inverse)
 
 
 def _experts(y, router, w_gate, w_up, w_down, cfg: MoEConfig,
              first_expert=0):
     """y (T, d) -> (out (T, d) from the experts ``first_expert ...`` that
-    ``w_*`` hold, assignments per expert (E,), summed router probabilities
+    ``w_*`` hold, assignments per expert (E,), summed router scores
     (E,)); the last two over all experts."""
-    T, k, E = y.shape[0], cfg.experts_per_token, cfg.n_experts
-    local = w_gate.shape[0]
+    E = cfg.n_experts
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(y.astype(jnp.float32), router,
-                         precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)                  # (T, E)
-        gates, experts = lax.top_k(probs, k)                     # (T, k)
-        if cfg.norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-        # other devices' experts sort last, into rows no group covers
-        mine = experts.reshape(-1) - first_expert
-        mine = jnp.where((mine >= 0) & (mine < local), mine, local)
-        order = jnp.argsort(mine, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        # counts by compare-and-sum: a scatter-add into E bins serialises
-        group_sizes = jnp.sum(mine[:, None] == jnp.arange(local), axis=0,
-                              dtype=jnp.int32)
+        gates, experts, probs = _route(y, router, None, cfg)
+        _, order, inverse, group_sizes = _sort_by_expert(
+            experts, first_expert, w_gate.shape[0])
         counts = jnp.sum(experts.reshape(-1, 1) == jnp.arange(E), axis=0,
                          dtype=jnp.float32)
-    with jax.named_scope("moe.experts"):
-        x = _dispatch(y, order, inverse)                         # (T*k, d)
-        h = jax.nn.silu(_grouped(x, w_gate, group_sizes, cfg)) \
-            * _grouped(x, w_up, group_sizes, cfg)
-        rows = _grouped(h, w_down, group_sizes, cfg)
-    with jax.named_scope("moe.combine"):
-        out = _combine(rows, gates, order, inverse)
+    out = _gated_sum(y, gates, order, inverse, group_sizes,
+                     {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}, cfg)
     return out, counts, jnp.sum(probs, axis=0)
+
+
+def _shared(y, lp):
+    """The shared expert: one SwiGLU every token goes through."""
+    with jax.named_scope("moe.shared"):
+        return (jax.nn.silu(y @ lp["shared_gate"]) * (y @ lp["shared_up"])) \
+            @ lp["shared_down"]
+
+
+def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
+                active=None):
+    """The expert layer of the serving forwards. y (T, d) normed rows ->
+    (this device's part of the layer (T, d): its held experts' gated sum
+    plus the shared expert, and routing counts or None).
+
+    ``lp`` is the layer's parameters; with ``stack`` and ``row`` the
+    grouped matmuls read the layer's experts in place in the stacked
+    leaves. ``active`` (T,) marks the rows that are live requests (a
+    decode step computes every slot): with it the counts come back,
+    device scalars ``{"routed", "local", "experts_hit"}``: assignments of
+    live rows, those of them on held experts, and the held experts that
+    any row reached (whose weights the step read)."""
+    src, layer = (stack, row) if stack is not None else (lp, None)
+    with jax.named_scope("moe.route"):
+        gates, experts, _ = _route(y, lp["router"], lp.get("router_bias"),
+                                   cfg)
+        mine, order, inverse, group_sizes = _sort_by_expert(
+            experts, cfg.first_expert, cfg.n_held)
+        stats = None
+        if active is not None:
+            live = jnp.repeat(active, cfg.experts_per_token)
+            stats = {
+                "routed": jnp.sum(live, dtype=jnp.int32),
+                "local": jnp.sum(live & (mine < cfg.n_held),
+                                 dtype=jnp.int32),
+                "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32)}
+    out = _gated_sum(y, gates, order, inverse, group_sizes, src, cfg, layer)
+    if cfg.n_shared_experts:
+        out = out + _shared(y, lp)
+    return out, stats
 
 
 def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
@@ -332,6 +607,13 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
              mesh: Optional[Mesh], axes: MeshAxes):
     """tokens (b, s) int32 -> (logits (b, s, vocab), routing statistics
     ``{"moe_aux_loss", "moe_load_max_over_mean"}``, float32 scalars)."""
+    if _serving_only(cfg):
+        raise NotImplementedError(
+            "the train forward runs full-attention layers of all the "
+            "softmax-routed experts; layer_types, n_dense_layers, "
+            "experts_held, qk_head_norm, post_norm, rope_layers, head_size, "
+            "sigmoid scoring and shared experts are the serving forwards' "
+            "(ray_tpu.llm.model)")
     b, s = tokens.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 

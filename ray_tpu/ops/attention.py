@@ -31,11 +31,13 @@ def _repeat_kv(k: jax.Array, num_heads: int) -> jax.Array:
 def mha_reference(q, k, v, *, causal: bool = True,
                   sm_scale: Optional[float] = None,
                   segment_ids: Optional[jax.Array] = None,
-                  q_offset: Optional[int] = None) -> jax.Array:
+                  q_offset: Optional[int] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """Plain XLA attention. (b, s, h, d) layout. O(S^2) memory — the
     correctness oracle and the CPU-test path. ``q_offset`` places the
     causal diagonal (query i attends keys <= i + q_offset; default
-    sk - sq: queries are the last rows)."""
+    sk - sq: queries are the last rows); ``window`` keeps the last
+    ``window`` of those keys (a sliding-window layer)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     k = _repeat_kv(k, h)
@@ -47,6 +49,9 @@ def mha_reference(q, k, v, *, causal: bool = True,
     if causal:
         diag = (sk - sq) if q_offset is None else q_offset
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=diag)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                                    k=diag - window)
         keep = keep & mask[None, None]
     if segment_ids is not None:
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
@@ -61,24 +66,24 @@ def mha_reference(q, k, v, *, causal: bool = True,
 
 # --- flash attention with custom vjp (pallas fwd + pallas bwd) -------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-           q_offset=None):
+           q_offset=None, window=None):
     # Primal (inference) path: skip the lse output entirely.
     o, _ = _fa.flash_attention_fwd(q, k, v, sm_scale=sm_scale, causal=causal,
                                    block_q=block_q, block_k=block_k,
                                    interpret=interpret, with_lse=False,
-                                   q_offset=q_offset)
+                                   q_offset=q_offset, window=window)
     return o
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-               q_offset=None):
-    if q_offset is not None:
+               q_offset=None, window=None):
+    if q_offset is not None or window is not None:
         raise NotImplementedError(
-            "q_offset (chunked-prefill causal placement) is an "
-            "inference-only path; the backward kernels assume the "
-            "queries are the last rows")
+            "q_offset (chunked-prefill causal placement) and window are "
+            "inference-only paths; the backward kernels assume causal "
+            "queries that are the last rows")
     o, lse = _fa.flash_attention_fwd(q, k, v, sm_scale=sm_scale, causal=causal,
                                      block_q=block_q, block_k=block_k,
                                      interpret=interpret)
@@ -94,7 +99,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, q_offset,
-               res, do):
+               window, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _fa.flash_attention_bwd(
         q, k, v, o, do, lse, sm_scale=sm_scale, causal=causal,
@@ -109,10 +114,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False,
-                    q_offset: Optional[int] = None) -> jax.Array:
+                    q_offset: Optional[int] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Pallas flash attention, (b, s, h, d) layout, differentiable
-    (except with q_offset, which is the inference-only chunked-prefill
-    causal placement)."""
+    (except with q_offset, the inference-only chunked-prefill causal
+    placement, or window, a sliding-window layer's band)."""
     b, sq, h, d = q.shape
     k = _repeat_kv(k, h)
     v = _repeat_kv(v, h)
@@ -123,7 +129,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     of = _flash(qf, kf, vf, scale, causal, block_q, block_k, interpret,
-                q_offset)
+                q_offset, window)
     return of.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
 
@@ -138,7 +144,8 @@ def attention(q, k, v, *, causal: bool = True,
               sm_scale: Optional[float] = None,
               impl: str = "auto",
               block_q: int = 128, block_k: int = 128,
-              q_offset: Optional[int] = None) -> jax.Array:
+              q_offset: Optional[int] = None,
+              window: Optional[int] = None) -> jax.Array:
     """Dispatch: 'auto' uses the Pallas kernel on TPU for seq >= 128 and the
     XLA reference otherwise. 'flash' / 'reference' force a path;
     'flash_interpret' runs the kernel in interpret mode (CPU tests)."""
@@ -146,13 +153,14 @@ def attention(q, k, v, *, causal: bool = True,
         impl = "flash" if (_on_tpu() and q.shape[1] >= 128) else "reference"
     if impl == "reference":
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             q_offset=q_offset)
+                             q_offset=q_offset, window=window)
     if impl == "flash":
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k,
-                               q_offset=q_offset)
+                               q_offset=q_offset, window=window)
     if impl == "flash_interpret":
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k,
-                               interpret=True, q_offset=q_offset)
+                               interpret=True, q_offset=q_offset,
+                               window=window)
     raise ValueError(f"unknown attention impl: {impl}")

@@ -53,6 +53,12 @@ causal tokens, head 128, 1024 x 1024 tiles, bf16; PR 34, PERF.md section 6):
   the forward (5.26 against 5.65 ms; no change backward) and more exact, but
   it changes the numbers every parity check reads: left for its own change.
 
+- A BAND (``window=w``, serving prefill of a sliding-window layer: row i
+  attends keys in (i + offset - w, i + offset]) is the same plan with a lower
+  edge: a q sub-tile's keys are skipped below the band, masked where its
+  lower edge cuts, interior between the edges, masked on the diagonal; tiles
+  wholly below the band are neither computed nor fetched. Forward only.
+
 Sequence lengths need not divide the block size: wrappers zero-pad to block
 multiples and kernels mask out-of-bounds columns (padded rows are sliced off
 and padded inputs are zeros, so gradients through padding vanish).
@@ -102,11 +108,16 @@ def _sub_tiles(block_q, block_k):
     return width(block_q), width(block_k)
 
 
-def _geometry(block_q, block_k, kv_len, offset, causal):
+def _geometry(block_q, block_k, kv_len, offset, causal, window=None):
     """What the plans and the three kernels are built from."""
     sub_q, sub_k = _sub_tiles(block_q, block_k)
-    return dict(block_q=block_q, block_k=block_k, sub_q=sub_q, sub_k=sub_k,
+    geom = dict(block_q=block_q, block_k=block_k, sub_q=sub_q, sub_k=sub_k,
                 kv_len=kv_len, offset=offset, causal=causal)
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a band under the causal diagonal")
+        geom["window"] = int(window)
+    return geom
 
 
 def _k_bounds(d, r, sub_q, sub_k, n_sub, causal):
@@ -120,6 +131,18 @@ def _k_bounds(d, r, sub_q, sub_k, n_sub, causal):
         lo, hi = min(lo, d), min(hi, d + sub_q - 1)
     return (min(max(lo + 1, 0) // sub_k, n_sub),
             min(max(hi + sub_k, 0) // sub_k, n_sub))
+
+
+def _band_bounds(d, r, sub_q, sub_k, n_sub, window):
+    """``_k_bounds`` for a band: ``(n_skip, n_low, n_int, n_run)`` with
+    [0, n_skip) below the band (skipped), [n_skip, n_low) cut by its
+    lower edge (masked), [n_low, n_int) interior, [n_int, n_run) masked
+    (diagonal, kv_len) and the rest skipped. Row i of the sub-tile
+    attends the keys in (d + i - window, d + i]."""
+    n_int, n_run = _k_bounds(d, r, sub_q, sub_k, n_sub, True)
+    n_skip = min(max(d - window + 1, 0) // sub_k, n_run)     # first row's
+    n_low = min(max(d + sub_q - window + sub_k - 1, 0) // sub_k, n_run)
+    return n_skip, max(n_low, n_skip), max(n_int, n_low, n_skip), n_run
 
 
 def _q_bounds(d, r, sub_q, sub_k, n_sub, causal):
@@ -139,30 +162,38 @@ def _q_bounds(d, r, sub_q, sub_k, n_sub, causal):
     return min(first_run, n_sub), min(first_int, n_sub)
 
 
-def _tile_key(qi, ki, block_q, block_k, kv_len, offset, causal):
+def _tile_key(qi, ki, block_q, block_k, kv_len, offset, causal,
+              window=None):
     """What a tile's plan depends on: how far the diagonal is from the
-    tile's corner (clamped where it no longer cuts the tile) and the
-    keys left before kv_len. Python ints or traced scalars."""
+    tile's corner (clamped where it no longer cuts the tile; with a
+    window, where the tile lies wholly below the band) and the keys
+    left before kv_len. Python ints or traced scalars."""
     lo, hi = (min, max) if isinstance(qi, int) else (jnp.minimum,
                                                      jnp.maximum)
     r = lo(kv_len - ki * block_k, block_k)
     if not causal:
         return block_k - 1, r
     return lo(hi(qi * block_q + offset - ki * block_k, -block_q),
-              block_k - 1), r
+              block_k - 1 + (window or 0)), r
 
 
 def _plans(nq, nk, by, *, block_q, block_k, sub_q, sub_k, kv_len, offset,
-           causal):
+           causal, window=None):
     """{key: plan} over the grid's tiles. A plan lists the bounds of
     each q sub-tile over the tile's keys (``by`` "q": forward and dq) or
-    of each k sub-tile over its queries ("k": dk/dv)."""
+    of each k sub-tile over its queries ("k": dk/dv); with a window
+    (forward only) the four bounds of ``_band_bounds``."""
     plans = {}
     for qi in range(nq):
         for ki in range(nk):
             d, r = _tile_key(qi, ki, block_q, block_k, kv_len, offset,
-                             causal)
-            if by == "q":
+                             causal, window)
+            if window is not None:
+                plan = tuple(
+                    _band_bounds(d + i * sub_q, r, sub_q, sub_k,
+                                 block_k // sub_k, window)
+                    for i in range(block_q // sub_q))
+            elif by == "q":
                 plan = tuple(
                     _k_bounds(d + i * sub_q, r, sub_q, sub_k,
                               block_k // sub_k, causal)
@@ -176,7 +207,14 @@ def _plans(nq, nk, by, *, block_q, block_k, sub_q, sub_k, kv_len, offset,
     return plans
 
 
-def tile_plan(sq, sk, block_q, block_k, *, causal=True, q_offset=None):
+def _band4(bounds):
+    """A plan entry as ``_band_bounds`` gives it: a causal entry
+    ``(n_int, n_run)`` has nothing below it."""
+    return bounds if len(bounds) == 4 else (0, 0, *bounds)
+
+
+def tile_plan(sq, sk, block_q, block_k, *, causal=True, q_offset=None,
+              window=None):
     """What the kernels do at these shapes, counted from the plans they
     are built from: sub-tiles ``skipped`` / ``interior`` / ``masked``
     over the tiles of one head, the distinct tile ``bodies``, and
@@ -185,21 +223,25 @@ def tile_plan(sq, sk, block_q, block_k, *, causal=True, q_offset=None):
     block_q, block_k = min(block_q, sq), min(block_k, sk)
     offset = (sk - sq) if q_offset is None else int(q_offset)
     nq, nk = -(-sq // block_q), -(-sk // block_k)
-    geom = _geometry(block_q, block_k, sk, offset, causal)
+    geom = _geometry(block_q, block_k, sk, offset, causal, window)
     sub_q, sub_k = geom["sub_q"], geom["sub_k"]
     plans = _plans(nq, nk, "q", **geom)
     n_sub = block_k // sub_k
     out = {"sub_q": sub_q, "sub_k": sub_k, "skipped": 0, "interior": 0,
            "masked": 0,
-           "bodies": sum(any(run for _, run in p) for p in plans.values())}
+           "bodies": sum(any(_band4(b)[3] > _band4(b)[0] for b in p)
+                         for p in plans.values())}
     for qi in range(nq):
         for ki in range(nk):
-            for n_int, n_run in plans[_tile_key(qi, ki, block_q, block_k, sk,
-                                                offset, causal)]:
-                out["interior"] += n_int
-                out["masked"] += n_run - n_int
-                out["skipped"] += n_sub - n_run
-    required = sum(min(sk, i + offset + 1) if causal else sk
+            for bounds in plans[_tile_key(qi, ki, block_q, block_k, sk,
+                                          offset, causal, window)]:
+                n_skip, n_low, n_int, n_run = _band4(bounds)
+                out["interior"] += n_int - n_low
+                out["masked"] += (n_low - n_skip) + (n_run - n_int)
+                out["skipped"] += n_sub - n_run + n_skip
+    required = sum((min(sk, i + offset + 1)
+                    - max(i + offset + 1 - (window or sk), 0))
+                   if causal else sk
                    for i in range(sq) if not causal or i + offset >= 0)
     computed = (out["interior"] + out["masked"]) * sub_q * sub_k
     out["required_share"] = required / computed if computed else 1.0
@@ -213,14 +255,19 @@ def _on_plan(plans, key, body):
             functools.partial(body, plan))
 
 
-def _keep(row0, col0, shape, kv_len, causal, offset, keys_dim=1):
-    """Bounds + causal mask of a masked piece whose first query is
-    ``row0`` and first key ``col0``; keys run along ``keys_dim``."""
+def _keep(row0, col0, shape, kv_len, causal, offset, keys_dim=1,
+          window=None):
+    """Bounds + causal mask (and the band's lower edge) of a masked piece
+    whose first query is ``row0`` and first key ``col0``; keys run along
+    ``keys_dim``."""
     cols = jax.lax.broadcasted_iota(jnp.int32, shape, keys_dim)
     keep = cols < kv_len - col0
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - keys_dim)
         keep = jnp.logical_and(keep, cols - rows <= row0 + offset - col0)
+        if window is not None:
+            keep = jnp.logical_and(
+                keep, cols - rows > row0 + offset - col0 - window)
     return keep
 
 
@@ -277,20 +324,25 @@ def _last_k_block(qi, block_q, block_k, num_kv_blocks, offset):
     return jnp.clip(last, 0, num_kv_blocks - 1)
 
 
-def _kv_map(nk, *, causal, block_q, block_k, offset, **_):
+def _kv_map(nk, *, causal, block_q, block_k, offset, window=None, **_):
     """K and V blocks over a (b, qi, ki) grid. A tile above the diagonal
-    is not fetched either: it names the block the step before it held."""
+    is not fetched either: it names the block the step before it held;
+    tiles below a band name the band's first."""
     def index(b, qi, ki):
         if causal:
             ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k, nk,
                                                offset))
+        if window is not None:
+            first = jnp.maximum(qi * block_q + offset - window + 1, 0) \
+                // block_k
+            ki = jnp.maximum(ki, jnp.minimum(first, nk - 1))
         return (b, ki, 0)
     return index
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
                 block_k, sub_q, sub_k, num_kv_blocks, kv_len, offset,
-                with_lse):
+                with_lse, window=None):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -308,24 +360,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
 
     def tile(plan):
         pdt = _pdt(q_ref.dtype)
-        for iq, (n_int, n_run) in enumerate(plan):
-            if not n_run:
+        for iq, bounds in enumerate(plan):
+            n_skip, n_low, n_int, n_run = _band4(bounds)
+            if n_run == n_skip:
                 continue
             rows = slice(iq * sub_q, (iq + 1) * sub_q)
-            lo, w = n_int * sub_k, n_run * sub_k    # keys: unmasked, all
+            k0 = n_skip * sub_k                     # keys from here on,
+            lo, w = (n_int - n_skip) * sub_k, (n_run - n_skip) * sub_k
+            low = (n_low - n_skip) * sub_k          # the band's edge in [0, low)
             q = q_ref[0, rows, :]                   # (sub_q, d), pre-scaled
-            v = v_ref[0, :w, :]                     # (w, d)
-            s = _dot(q, k_ref[0, :w, :], _NT)       # (sub_q, w) f32
+            v = v_ref[0, k0:k0 + w, :]              # (w, d)
+            s = _dot(q, k_ref[0, k0:k0 + w, :], _NT)    # (sub_q, w) f32
             keep = None if lo == w else _keep(
-                qi * block_q + iq * sub_q, ki * block_k + lo,
-                (sub_q, w - lo), kv_len, causal, offset)
-            s = _mask_piece(s, keep, lo, w, NEG_INF)
+                qi * block_q + iq * sub_q, ki * block_k + (k0 + lo),
+                (sub_q, w - lo), kv_len, causal, offset, window=window)
+            below = None if not low else _keep(
+                qi * block_q + iq * sub_q, ki * block_k + k0,
+                (sub_q, low), kv_len, causal, offset, window=window)
+            s = _mask_piece(_mask_piece(s, keep, lo, w, NEG_INF),
+                            below, 0, low, NEG_INF)
             # m, l and alpha are lane-replicated (sub_q, LANES)
             m_prev = m_scr[rows, :]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp((s - _lanes(m_new, w)).astype(pdt))
             # a fully masked row's exp(0) too
-            p = _mask_piece(p, keep, lo, w, pdt(0.0))
+            p = _mask_piece(_mask_piece(p, keep, lo, w, pdt(0.0)),
+                            below, 0, low, pdt(0.0))
             alpha = jnp.exp(m_prev - m_new)
             l_scr[rows, :] = alpha * l_scr[rows, :] + jnp.sum(
                 p.astype(jnp.float32), axis=-1, keepdims=True)
@@ -334,7 +394,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
             m_scr[rows, :] = m_new
 
     _on_plan(plans, _tile_key(qi, ki, block_q, block_k, kv_len, offset,
-                              causal), tile)
+                              causal, window), tile)
 
     # Last kv block this q block attends to (inclusive).
     if causal:
@@ -352,7 +412,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
 
 
 def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
-                        interpret=False, with_lse=True, q_offset=None):
+                        interpret=False, with_lse=True, q_offset=None,
+                        window=None):
     """q,k,v: (BH, S, D) -> (o: (BH, S, D), lse: (BH, 1, S) f32 | None).
 
     lse is the row logsumexp saved as a backward residual, one value a
@@ -365,7 +426,9 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
     <= i + q_offset. Default (None) = sk - sq, i.e. queries are the
     LAST sq rows of the kv sequence. Chunked prefill passes the chunk's
     absolute start position instead (queries sit mid-sequence, not at
-    the end); must be static — one compile per distinct offset."""
+    the end); must be static — one compile per distinct offset.
+    ``window`` (static, with ``causal``): row i attends the ``window``
+    keys up to i + q_offset only."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
     block_q = min(block_q, sq)
@@ -376,7 +439,7 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
     nq = qp.shape[1] // block_q
     nk = kp.shape[1] // block_k
 
-    tiles = _geometry(block_q, block_k, sk, offset, causal)
+    tiles = _geometry(block_q, block_k, sk, offset, causal, window)
     kernel = functools.partial(
         _fwd_kernel, plans=_plans(nq, nk, "q", **tiles), num_kv_blocks=nk,
         with_lse=with_lse, **tiles)
@@ -408,7 +471,7 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
     )(qp, kp, vp)
     if with_lse:
         out, lse = res

@@ -29,6 +29,22 @@ last axis, which the MXU does for free). ``d_rhs[e] = lhs_e^T @ dout_e``
 is a second kernel, ``tgmm``: it walks the same visits and accumulates over
 each group's row tiles; a group with no rows is visited once with every
 row masked, so its gradient is written as zeros.
+
+Serving (``gmm_stacked``, forward only) has two more needs. The weights of
+all layers are one stacked array and a decode step must not copy a layer
+out of it, so the kernel is handed the whole stack and the layer's index
+(a fifth prefetched scalar) and reads ``stack[layer, group]`` in place. And
+a device that holds a slice of the experts sees most rows belong to no
+group of its own: a decode step of 32 tokens x 8 has 256 rows, ~32 of them
+on 16 held experts, 0-6 a group. Rows come in tiles of ``DECODE_ROW_TILE``
+there (a visit multiplies its whole tile, and at 256 rows a tile every
+visit would do eight times the rows the step has for it), an empty group is
+never visited (its weights are not read), and a visit of the tail writes
+its zeros without a product and without a weight block: its steps, like
+those past the last visit, name the block the last multiplying visit held
+(the train kernels keep their tail's product: their tail is empty, and
+their contraction is one block, so a repeated step names one block too).
+The small-tile variant is named ``moe_gmm_decode``.
 """
 
 from __future__ import annotations
@@ -50,6 +66,9 @@ VMEM_LIMIT = 96 * 1024 * 1024
 # MXU badly: on a v5e 256 measured best (155-158 TFLOP/s forward against
 # 149 at 512 and 153 at 128; PERF.md, PR 28)
 ROW_TILE = 256
+# serving, up to DECODE_ROWS rows a call (a decode step's slots x k)
+DECODE_ROW_TILE = 32
+DECODE_ROWS = 512
 
 
 def _tile(dim: int, want: int, unit: int) -> int:
@@ -102,8 +121,11 @@ def _own_rows(offsets, group, tile, tm: int, width: int):
     return jnp.logical_and(rows >= offsets[group], rows < offsets[group + 1])
 
 
-def _gmm_kernel(offsets, group_ids, tile_ids, n_visits, lhs_ref, rhs_ref,
-                out_ref, *scratch, tm, tiles_k, n_groups, transpose_rhs):
+def _gmm_kernel(offsets, group_ids, tile_ids, n_visits, *refs, tm, tiles_k,
+                n_groups, transpose_rhs, stacked=False):
+    # ``stacked``: one more prefetched scalar (the layer), and a visit of
+    # the tail multiplies nothing
+    lhs_ref, rhs_ref, out_ref, *scratch = refs[1:] if stacked else refs
     v, k_i = pl.program_id(1), pl.program_id(2)
     group = group_ids[v]
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
@@ -119,7 +141,15 @@ def _gmm_kernel(offsets, group_ids, tile_ids, n_visits, lhs_ref, rhs_ref,
         out_ref[...] = jnp.where(own, acc, out_ref[...].astype(
             jnp.float32)).astype(out_ref.dtype)
 
-    @pl.when(v < n_visits[0])
+    if stacked:
+        @pl.when(jnp.logical_and(v < n_visits[0], group >= n_groups))
+        def _tail():
+            @pl.when(k_i == tiles_k - 1)
+            def _zeros():
+                store(jnp.zeros(out_ref.shape, jnp.float32))
+
+    @pl.when(jnp.logical_and(v < n_visits[0], group < n_groups)
+             if stacked else v < n_visits[0])
     def _visit():
         if tiles_k == 1:
             store(product())
@@ -248,6 +278,63 @@ def tgmm(lhs, dout, group_sizes, *, tiles=None, interpret=False):
         interpret=interpret,
         name="moe_gmm_drhs",
     )(*meta, lhs, dout)
+
+
+def gmm_stacked(lhs, stack, group_sizes, layer, interpret=False):
+    """``gmm`` against layer ``layer`` (a traced or Python int) of the
+    stacked weights ``stack`` (L, E, k, n), read in place; forward only.
+    lhs (m, k), group_sizes (E,) -> (m, n); rows past the last group are
+    zeros."""
+    m, k = lhs.shape
+    n_layers, n_groups, k_w, n = stack.shape
+    if k_w != k:
+        raise ValueError(f"gmm_stacked: lhs {lhs.shape} does not contract "
+                         f"with the stack {stack.shape}")
+    decode = m <= DECODE_ROWS
+    tm = _tile(m, DECODE_ROW_TILE if decode else ROW_TILE, 8)
+    tk, tn = _tile(k, 2048, 128), _tile(n, 2048, 128)
+    tiles_k = k // tk
+    visits = _visits(group_sizes, m, tm, tail=True)
+    # the visits that multiply: a step after them (the tail's, or past
+    # the last visit) keeps the weight block the last of them held, so
+    # nothing is fetched for it, whatever its k step
+    steps = visits[1].shape[0]
+    n_real = jnp.sum(jnp.logical_and(
+        visits[1] < n_groups, jnp.arange(steps) < visits[3][0]),
+        dtype=jnp.int32)
+    meta = (*visits, jnp.stack([jnp.asarray(layer, jnp.int32), n_real]))
+
+    def rhs_index(n_i, v, k_i, offsets, group_ids, tile_ids, n_visits, more):
+        real = v < more[1]
+        group = jnp.minimum(
+            group_ids[jnp.where(real, v, jnp.maximum(more[1] - 1, 0))],
+            n_groups - 1)
+        return (more[0] * n_groups + group,
+                jnp.where(real, k_i, tiles_k - 1), n_i)
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k,
+                          n_groups=n_groups, transpose_rhs=False,
+                          stacked=True),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda n_i, v, k_i, o, g, t, nv, l:
+                             (t[v], k_i)),
+                pl.BlockSpec((None, tk, tn), rhs_index),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), lambda n_i, v, k_i, o, g, t,
+                                   nv, l: (t[v], n_i)),
+            grid=(n // tn, meta[1].shape[0], tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]
+            if tiles_k > 1 else []),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_gmm_decode" if decode else "moe_gmm",
+    )(*meta, lhs, stack.reshape(n_layers * n_groups, k, n))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

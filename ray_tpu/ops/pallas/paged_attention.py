@@ -32,6 +32,16 @@ what an unfetched part of a buffer still holds is masked out of both
 products. An idle slot (length 1, table row = trash) costs one block
 of each pool and one turn.
 
+A WINDOW layer (``window=w``: the query at position ``length - 1``
+attends positions ``>= length - w`` only) starts its walk at the block
+that holds position ``length - w`` (``first_block``): the chunks before
+it are never entered, the entries before it in its first chunk are not
+fetched, and the positions below ``length - w`` in that block are
+masked. So the blocks a sequence has passed are never read, and the
+cache manager frees them (llm/kvcache.py). The window is a static
+parameter; the kernel's operands are the same five, and the variant is
+named ``paged_decode_window``.
+
 ``chunk_blocks`` is the one place that chooses C, from what the pool
 shows: about 128 positions a chunk (one lane-width of scores), capped
 so that the four buffers stay within 4 MB of VMEM.
@@ -104,21 +114,36 @@ def live_blocks(length, block_size):
     return (length + (length < 1) + block_size - 1) // block_size
 
 
-def fetched_positions(length, block_size):
+def first_block(length, block_size, window=None):
+    """The first pool block the walk visits: 0, or for a window layer
+    the block that holds position ``length - window``. Ints, numpy
+    arrays and traced scalars alike."""
+    if window is None:
+        return length * 0
+    return (length - window) * (length > window) // block_size
+
+
+def fetched_positions(length, block_size, window=None):
     """Positions of K (and of V) the walk fetches for a slot with
-    ``length`` valid positions: its live blocks, whole, and nothing
-    after them (the tail of a chunk is guarded, not rounded up)."""
-    return live_blocks(length, block_size) * block_size
+    ``length`` valid positions: its live blocks from the first one the
+    walk visits, whole, and nothing after them (the tail of a chunk is
+    guarded, not rounded up)."""
+    return (live_blocks(length, block_size)
+            - first_block(length, block_size, window)) * block_size
 
 
 def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
-                 kbuf, vbuf, sems, *, bs, cb, width):
+                 kbuf, vbuf, sems, *, bs, cb, width, window=None):
     b_ = pl.program_id(0)
     kvh, g, hd = q_ref.shape[1:]
     t = cb * bs                                 # positions a chunk
     length = lengths_ref[b_]
     live = jnp.minimum(live_blocks(length, bs), width)
     n_chunks = (live + cb - 1) // cb
+    first = 0 if window is None else jnp.minimum(
+        first_block(length, bs, window), live - 1)
+    chunk0 = 0 if window is None else first // cb
+    floor = None if window is None else length - window
 
     def fetch(i, buf, wait):
         """Start (or wait for) the DMAs of chunk ``i`` into buffer
@@ -136,12 +161,17 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                 cp.wait() if wait else cp.start()
             return carry
 
-        jax.lax.fori_loop(0, jnp.minimum(live - i * cb, cb), entry, 0)
+        jax.lax.fori_loop(
+            0 if window is None else jnp.maximum(first - i * cb, 0),
+            jnp.minimum(live - i * cb, cb), entry, 0)
 
     q = q_ref[0]                                # (kvh, g, hd)
     if not (q.dtype == kbuf.dtype == jnp.bfloat16):
         q = q.astype(jnp.float32)
-    fetch(0, 0, wait=False)
+    if window is None:
+        fetch(0, 0, wait=False)
+    else:
+        fetch(chunk0, jax.lax.rem(chunk0, 2), wait=False)
 
     def chunk(i, carry):
         m_prev, l_prev, acc = carry
@@ -157,8 +187,10 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) / jnp.sqrt(
                 jnp.float32(hd))                # (kvh, g, t)
-        keep = i * t + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2) < length
+        at = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        keep = at < length
+        if window is not None:
+            keep = jnp.logical_and(keep, at >= floor)
         s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
@@ -166,8 +198,10 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         # rows past ``length`` hold whatever the pool or an earlier
         # chunk left there: p is 0 for them, and 0 x NaN is not
-        rows = i * t + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, t, hd), 1) < length
+        at = i * t + jax.lax.broadcasted_iota(jnp.int32, (kvh, t, hd), 1)
+        rows = at < length
+        if window is not None:
+            rows = jnp.logical_and(rows, at >= floor)
         v = jnp.where(rows, vbuf[buf].astype(jnp.float32), 0.0)
         acc = acc * alpha + jax.lax.dot_general(
             p, v, (((2,), (1,)), ((0,), (0,))),
@@ -175,7 +209,7 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         return m_new, l_new, acc
 
     _, l, acc = jax.lax.fori_loop(
-        0, n_chunks, chunk,
+        chunk0, n_chunks, chunk,
         (jnp.full((kvh, g, 1), NEG_INF, jnp.float32),
          jnp.zeros((kvh, g, 1), jnp.float32),
          jnp.zeros((kvh, g, hd), jnp.float32)))
@@ -183,7 +217,7 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
-                    interpret=False):
+                    window=None, interpret=False):
     """Single-token decode attention straight through block tables.
 
     q: (slots, kv_heads, group, head_dim) — grouped queries, one token
@@ -193,7 +227,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     valid positions per slot INCLUDING the current token (>= 1).
     Returns (slots, kv_heads, group, head_dim) float32 — the same
     value ``_gqa_attend_cached`` computes from the gathered view, with
-    no gathered view.
+    no gathered view. ``window`` (static): attend the last ``window``
+    positions only, walking from the block that holds the first of them.
     """
     b, kvh, g, hd = q.shape
     nb, kvh_p, bs, hd_p = k_pool.shape
@@ -221,7 +256,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    kernel = functools.partial(_walk_kernel, bs=bs, cb=cb, width=w)
+    kernel = functools.partial(_walk_kernel, bs=bs, cb=cb, width=w,
+                               window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -231,7 +267,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "paged_decode_window",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool,
       v_pool)
 
@@ -341,7 +377,8 @@ def table_view(pool, tables):
     return g.transpose(0, 1, 3, 2, 4).reshape(b, w * bs, kvh, hd)
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, lengths):
+def paged_attention_reference(q, k_pool, v_pool, tables, lengths,
+                              window=None):
     """Gather-then-softmax reference (the exact math
     ``_gqa_attend_cached`` runs on the gathered view) — the parity
     target the kernel is tested against, and the debug tool for
@@ -352,7 +389,10 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths):
     qf = q.astype(jnp.float32)
     scores = jnp.einsum("bkgd,blkd->bkgl", qf,
                         vk.astype(jnp.float32)) / jnp.sqrt(hd)
-    mask = jnp.arange(vk.shape[1])[None] < lengths[:, None]
+    pos = jnp.arange(vk.shape[1])[None]
+    mask = pos < lengths[:, None]
+    if window is not None:
+        mask = mask & (pos >= lengths[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bkgl,blkd->bkgd", probs,

@@ -24,9 +24,11 @@ class LLMConfig:
     """What to serve and how to batch it.
 
     `model` names a config constructor in ray_tpu.models.llama (e.g.
-    "tiny", "llama2_7b") or is a LlamaConfig; `checkpoint` optionally
-    points at an orbax dir of params — absent, params are randomly
-    initialized (useful for shape/perf work and tests).
+    "tiny", "llama2_7b") or is a model family's config object (a
+    LlamaConfig, a MoEConfig: the module that defines its class makes
+    its parameters); `checkpoint` optionally points at an orbax dir of
+    params — absent, params are randomly initialized (useful for
+    shape/perf work and tests).
     """
     model: object = "tiny"
     model_overrides: dict = field(default_factory=dict)
@@ -90,7 +92,8 @@ def _load_model(cfg: LLMConfig):
         import orbax.checkpoint as ocp
         params = ocp.StandardCheckpointer().restore(cfg.checkpoint)
     else:
-        params = llama.init_params(
+        from ray_tpu.llm.model import model_family
+        params = model_family(model_cfg).init_params(
             jax.random.PRNGKey(cfg.seed), model_cfg)
     return model_cfg, params
 

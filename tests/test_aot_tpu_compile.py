@@ -350,3 +350,127 @@ def test_verify_steps_write_the_pool_in_place(chip):
     ops = _kernel_ops(compiled)
     assert {op["class"] for op in ops} == {"unknown_kernel"}
     assert all("kv_write" in op["name"] for op in ops)
+
+
+# --- window layers and a served expert layer (PR 35) -------------------------
+# the cell serve-exaone-reason-open: 8 KV heads, group 8, head 128, 32
+# slots over tables of 256 blocks; the window layers' pool is 32 rings of
+# 10 blocks; a step's expert layer sees 256 rows, 16 held experts of
+# width 2048 beside hidden 6144
+
+def _paged_window(q, k, v, tables, lengths):
+    return pa.paged_attention(q, k, v, tables, lengths, window=128)
+
+
+def _flash_band(q, k, v):
+    return A.flash_attention(q, k, v, causal=True, window=128)
+
+
+def _gmm_stacked(lhs, stack, sizes, layer):
+    from ray_tpu.ops.pallas import grouped_matmul
+    return grouped_matmul.gmm_stacked(lhs, stack, sizes, layer[0])
+
+
+_BF, _I32 = jnp.bfloat16, jnp.int32
+HYBRID_CASES = {
+    # name: (function, shapes, {kernel name: the benchmark's class})
+    "paged_decode_window": (
+        _paged_window,
+        (((32, 8, 8, HD), _BF), ((6 * 321, 8, 16, HD), _BF),
+         ((6 * 321, 8, 16, HD), _BF), ((32, 256), _I32), ((32,), _I32)),
+        {"paged_decode_window": "paged_decode"}),
+    "flash_prefill_band_2048": (
+        _flash_band, (((1, 2048, 64, HD), _BF),) * 3,
+        {"flash_fwd_window": "flash_fwd"}),
+    "gmm_decode_up": (
+        _gmm_stacked,
+        (((256, 6144), _BF), ((7, 16, 6144, 2048), _BF), ((16,), _I32),
+         ((1,), _I32)), {"moe_gmm_decode": "unknown_kernel"}),
+    "gmm_decode_down": (
+        _gmm_stacked,
+        (((256, 2048), _BF), ((7, 16, 2048, 6144), _BF), ((16,), _I32),
+         ((1,), _I32)), {"moe_gmm_decode": "unknown_kernel"}),
+    "gmm_prefill_up": (
+        _gmm_stacked,
+        (((2048 * 8, 6144), _BF), ((7, 16, 6144, 2048), _BF), ((16,), _I32),
+         ((1,), _I32)), {"moe_gmm": "unknown_kernel"}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HYBRID_CASES))
+def test_window_and_expert_kernels_compile_for_v5e(chip, name):
+    """The window walk and the band keep the operand signatures the
+    benchmark's reduction knows the paged and flash kernels by (so a
+    program that runs them still counts as decode / prefill), and
+    carry names of their own; the serving grouped matmuls read the
+    stacked weights in place (no copy of a layer beside the call)."""
+    fn, shapes, names = HYBRID_CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    bench = _bench_kernels()
+    ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
+    assert ops
+    (kernel, cls), = names.items()
+    for op in ops:
+        assert bench.classify(op) == cls, op
+        assert re.search(kernel + r"(?=_|\.|$)", op["name"]), op["name"]
+    if "gmm" in name:
+        stack = shapes[1][0]
+        # nothing of the stack's size, or a layer's, but the argument
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 2 * stack[1] * stack[2] * stack[3] // 4
+
+
+def test_the_hybrid_cells_decode_program_compiles_for_v5e(chip):
+    """paged_decode_steps (n = 8) at the cell's published widths and
+    pool geometry, on a described v5e: every layer calls the paged
+    kernel once a step (6 window walks, 2 global) and the writer once,
+    the seven sparse layers three decode-shape grouped matmuls each,
+    both pools are updated in place, and the weights and the pools fit
+    one chip beside the program's temporaries."""
+    import json
+    import sys
+    from ray_tpu.llm import kvcache
+    from ray_tpu.models import moe
+    bench_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             os.pardir, "benchmarks")
+    with open(os.path.join(
+            bench_dir, "configs",
+            "k-exaone-236b-a23b-serve-ep8.json")) as f:
+        model = json.load(f)
+    sys.path.insert(0, bench_dir)
+    try:
+        from harness import spec
+        cfg = spec.family("exaone_moe").config(model, gmm_impl="pallas")
+    finally:
+        sys.path.remove(bench_dir)
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: moe.init_params(jax.random.PRNGKey(0), cfg)))
+    ring = kvcache.window_ring_blocks(128, 16, 8)
+    pool = {"k": shape((2, 8449, 8, 16, HD), _BF),
+            "v": shape((2, 8449, 8, 16, HD), _BF),
+            "wk": shape((6, 32 * ring + 1, 8, 16, HD), _BF),
+            "wv": shape((6, 32 * ring + 1, 8, 16, HD), _BF)}
+    ids = shape((32,), _I32)
+    tables = {k: shape((32, 256), _I32) for k in ("global", "window")}
+    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
+        params, pool, tables, ids, ids, shape((32,), jnp.float32),
+        shape((2,), jnp.uint32), cfg, 8, None, None).compile()
+    ops = _kernel_ops(compiled)
+    names = {}
+    for op in ops:
+        name = re.sub(r"[._]*\d*$", "", op["name"])
+        names[name] = names.get(name, 0) + 1
+    assert names == {"paged_decode_window": 6, "paged_decode": 2,
+                     "kv_write": 8, "moe_gmm_decode": 21}, names
+    # the reduction's steps = paged calls / layers holds: 8 a step
+    assert sum(op["class"] == "paged_decode" for op in ops) == 8
+    mem = compiled.memory_analysis()
+    import numpy as np
+    pool_bytes = sum(2 * int(np.prod(a.shape)) for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9

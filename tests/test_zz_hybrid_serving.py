@@ -1,0 +1,282 @@
+"""A model of window and global layers, a dense first layer and
+sigmoid-routed experts of which this device holds a slice, SERVED: the
+one engine, the one block manager and the forwards of ``llm/model.py``
+against the plain reference of ``benchmarks/families/exaone_moe.py``, on
+the CPU at tiny widths with seeded weights."""
+import asyncio
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import kvcache as kc
+from ray_tpu.llm import model as lm
+from ray_tpu.models import moe
+
+BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir,
+                     "benchmarks")
+
+
+@pytest.fixture(scope="module")
+def fam():
+    """benchmarks/families/exaone_moe.py: the plain reference."""
+    sys.path.insert(0, BENCH)
+    try:
+        from harness import spec
+        yield spec.family("exaone_moe")
+    finally:
+        sys.path.remove(BENCH)
+
+
+def _cfg(**kw):
+    """Dense layer 0, then two periods (window, window, global, window):
+    the runner scans them. head_dim 32 beside hidden 64 / 4 heads = 16."""
+    base = dict(vocab_size=256, dim=64, n_layers=9, n_heads=4, n_kv_heads=2,
+                head_size=32, ffn_dim=64, n_experts=16, experts_per_token=4,
+                experts_held=4, first_expert=4, n_dense_layers=1,
+                dense_ffn_dim=128, sliding_window=32, max_seq_len=512,
+                dtype="float32", attn_impl="reference",
+                gmm_impl="ragged_dot")
+    base.update(kw)
+    return moe.k_exaone_236b_a23b(**base)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return moe.init_params(jax.random.PRNGKey(0), _cfg())
+
+
+def test_the_layers_run_as_segments():
+    segs = lm._segments(_cfg())
+    assert [(s.stack, s.kinds, s.repeats) for s in segs] == [
+        ("dense_layers", ("window",), 1),
+        ("layers", ("window", "window", "global", "window"), 2)]
+    # the published depth: the dense layer, eleven periods, the rest
+    full = lm._segments(moe.k_exaone_236b_a23b())
+    assert [(s.layer0, len(s.kinds), s.repeats) for s in full] == [
+        (0, 1, 1), (1, 4, 11), (45, 2, 1), (47, 1, 1)]
+    # a Llama model is one scan of the whole stack
+    from ray_tpu.models import llama
+    one, = lm._segments(llama.tiny())
+    assert (one.kinds, one.repeats) == (("global",), llama.tiny().n_layers)
+
+
+KERNELS = dict(attn_impl="flash_interpret", gmm_impl="pallas_interpret")
+PATHS = {
+    "xla": ({}, "gather", False, 100, (64, 128)),
+    "paged_kernel": ({}, "paged_flash", True, 100, (64, 128)),
+    "all_kernels": (KERNELS, "paged_flash", True, 130, (64, 256)),
+}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_served_prefill_and_decode_are_the_reference(fam, params, path):
+    """Prompt and decode both past the window (32), across a block
+    edge: the band, the freed block and the window walk are all in the
+    comparison. A float32 pool: agreement to rounding."""
+    over, impl, interpret, prompt, buckets = PATHS[path]
+    got = fam.serve_parity(params, _cfg(**over), 3, prompt, buckets=buckets,
+                           block=8, kv_impl=impl, interpret=interpret,
+                           cache_dtype="float32")
+    assert got["finite"] and got["window_blocks_freed"] >= 1
+    # every compared position, whatever its margin
+    assert len(got["prefill_rel_errs"]) == len(got["prefill_margins"]) == 15
+    assert len(got["decode_rel_errs"]) == 16
+    assert max(got["prefill_rel_errs"]) < 5e-6, got["prefill_rel_errs"]
+    assert max(got["decode_rel_errs"]) < 5e-6, got["decode_rel_errs"]
+
+
+def test_the_margin_is_the_distance_to_another_set_of_held_experts(fam):
+    """Experts 4-7 held, 4 of 16 chosen. The margin of a token is how far
+    its scores must move before it gets another set of HELD experts: a
+    swap among experts held elsewhere does not count."""
+    cfg = _cfg(dim=16)
+    router = jnp.eye(16, dtype=jnp.float32)
+    bias = jnp.zeros((16,), jnp.float32)
+    logit = lambda p: float(np.log(p / (1 - p)))                 # noqa: E731
+
+    def row(scores):    # expert e scores scores[e] (0.1 where not said)
+        return jnp.asarray([[logit(scores.get(e, 0.1)) for e in range(16)]],
+                           jnp.float32)
+    # chosen 0, 1, 2, 5 (0.9, 0.8, 0.7, 0.6); first left out: 9 at 0.59,
+    # then held 6 at 0.4. Held 5 falls out after 0.01; 6 is 0.2 away.
+    near = row({0: .9, 1: .8, 2: .7, 5: .6, 9: .59, 6: .4})
+    assert float(fam.held_margin(near, router, bias, cfg)[0]) \
+        == pytest.approx(0.01, abs=1e-5)
+    # the same tie between two experts held elsewhere: the held 5 is
+    # safely in (0.3 over the first left out), the held 6 0.19 under the
+    # last chosen
+    far = row({0: .9, 1: .8, 5: .7, 2: .6, 9: .59, 6: .4})
+    assert float(fam.held_margin(far, router, bias, cfg)[0]) \
+        == pytest.approx(0.11, abs=1e-5)
+    # the bias moves the choice: it counts
+    assert float(fam.held_margin(
+        near, router, bias.at[6].set(0.195), cfg)[0]) \
+        == pytest.approx(0.005, abs=1e-5)
+
+
+def test_the_comparison_judges_the_clear_positions(fam):
+    errs, margins = [0.011, 0.3, 0.012, 0.02], [1.0, 1e-4, 0.5, 0.2]
+    assert fam._judged(errs, margins) == 0.02        # the tie is left out
+    assert fam._judged(errs, [0.0] * 4) == pytest.approx(0.016)  # median
+    # a fault in most positions, none of them clear: the median's
+    assert fam._judged([0.3, 0.3, 0.3, 0.011], [0, 0, 0, 1.0]) == 0.3
+    assert 0 < fam.CLEAR_MARGIN < 0.05
+
+
+def _rows(n=24, d=64):
+    return jax.random.normal(jax.random.PRNGKey(5), (n, d), jnp.float32)
+
+
+def _layer(params, row=2):
+    return jax.tree.map(lambda w: w[row], params["layers"])
+
+
+@pytest.mark.parametrize("first", [0, 4, 8, 12])
+def test_the_expert_layer_computes_its_share(fam, first):
+    """Experts ``first ... first + 4`` of 16 held: the program's sorted,
+    grouped layer is the reference's sum over the held experts."""
+    cfg = _cfg(first_expert=first)
+    lp = _layer(moe.init_params(jax.random.PRNGKey(1), cfg))
+    x = _rows()
+    with jax.default_matmul_precision("highest"):
+        got, _ = moe.serve_block(x, lp, cfg)
+        want = fam.layer_share(x, lp, cfg)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(fam):
+    """The guide's share test: the four shares' routed parts (experts
+    0-4, 4-8, 8-12, 12-16) plus the shared expert counted once are the
+    uncut reference's layer, in the program and in the reference."""
+    whole = _cfg(experts_held=0, first_expert=0)
+    lp = _layer(moe.init_params(jax.random.PRNGKey(2), whole))
+    x = _rows()
+    with jax.default_matmul_precision("highest"):
+        want = fam.layer_share(x, lp, whole)        # all 16 experts
+        shared = fam.layer_share(x, lp, whole) - fam.routed_share(
+            x, lp, whole)
+        program, reference = shared, shared
+        for first in range(0, 16, 4):
+            cfg = _cfg(first_expert=first)
+            mine = {**lp, **{k: lp[k][first:first + 4]
+                             for k in ("w_gate", "w_up", "w_down")}}
+            program = program + moe.serve_block(x, mine, cfg)[0] - shared
+            reference = reference + fam.routed_share(x, mine, cfg)
+    np.testing.assert_allclose(reference, want, atol=2e-5)
+    np.testing.assert_allclose(program, want, atol=5e-5)
+
+
+def test_the_layer_counts_what_it_routed():
+    cfg = _cfg()
+    lp = _layer(moe.init_params(jax.random.PRNGKey(1), cfg))
+    active = jnp.arange(24) < 10
+    _, stats = moe.serve_block(_rows(), lp, cfg, active=active)
+    assert int(stats["routed"]) == 10 * cfg.experts_per_token
+    assert 0 <= int(stats["local"]) <= int(stats["routed"])
+    assert 0 <= int(stats["experts_hit"]) <= cfg.n_held
+
+
+@pytest.mark.parametrize("post_norm, embed_rms, attn_norm",
+                         [(True, 1.0, 0.25), (False, 64 ** -0.5, 1.0)])
+def test_a_post_norm_stream_is_made_token_specific(params, post_norm,
+                                                   embed_rms, attn_norm):
+    """Random weights of a post-norm model: the embedding at unit RMS and
+    the attention's norm at 0.25, so that a token's own row, not the mean
+    of its context, is most of what the router reads; a pre-norm model
+    keeps the fan-in scaled embedding and norms of 1."""
+    if not post_norm:
+        params = moe.init_params(jax.random.PRNGKey(0),
+                                 _cfg(post_norm=False))
+    rms = float(jnp.sqrt(jnp.mean(params["embed"] ** 2)))
+    assert abs(rms / embed_rms - 1) < 0.02
+    for stack in ("dense_layers", "layers"):
+        np.testing.assert_array_equal(params[stack]["attn_norm"], attn_norm)
+        np.testing.assert_array_equal(params[stack]["mlp_norm"], 1.0)
+
+
+def test_the_train_forward_refuses_serving_only_shapes(params):
+    with pytest.raises(NotImplementedError):
+        moe.forward(params, jnp.zeros((1, 8), jnp.int32), _cfg())
+
+
+def _engine(params, **kw):
+    from ray_tpu.llm.engine import LLMEngine
+    return LLMEngine(_cfg(), params, max_slots=4, max_len=256,
+                     prefill_buckets=(64, 128), cache_dtype="float32",
+                     kv_block_size=8, steps_per_sync=4, **kw)
+
+
+def test_the_engine_serves_it_and_frees_what_the_window_passed(fam, params):
+    """Through LLMEngine: greedy tokens equal the reference's (float32
+    pool; 20 tokens, prompts below and past the window), a window layer
+    never holds more than its ring of blocks a sequence, frees blocks
+    while requests decode, and gives everything back."""
+    from ray_tpu.llm.engine import engine_metrics
+
+    async def run():
+        eng = _engine(params)
+        rng = np.random.default_rng(0)
+        prompts = [[int(t) for t in rng.integers(1, 256, n)]
+                   for n in (20, 100, 70)]
+        outs = await asyncio.gather(*[
+            eng.generate(p, max_new_tokens=20) for p in prompts])
+        stats = eng.stats
+        await eng.stop()
+        return prompts, outs, stats
+
+    before = _sums("kv_blocks_window", "kv_window_freed", "moe_routed",
+                   "moe_local", "moe_experts_hit", "moe_experts_held")
+    prompts, outs, stats = asyncio.run(run())
+    for p, o in zip(prompts, outs):
+        # one forward over prompt + reply: each reply token is the
+        # reference's greedy choice given everything before it
+        logits = np.asarray(fam.forward(
+            params, jnp.asarray([p + o["tokens"]], jnp.int32), _cfg()))[0]
+        want = np.argmax(logits[len(p) - 1:-1], axis=-1)
+        assert o["tokens"] == [int(t) for t in want]
+    ring = kc.window_ring_blocks(32, 8, 4)
+    assert stats["pool_blocks_window"] == 4 * ring + 1
+    assert stats["blocks_used_window"] == 0 and stats["blocks_used"] == 0
+    assert stats["window_blocks_freed"] >= 4
+    after = _sums("kv_blocks_window", "kv_window_freed", "moe_routed",
+                  "moe_local", "moe_experts_hit", "moe_experts_held")
+    d = {k: after[k] - before[k] for k in after}
+    assert d["kv_window_freed"] == stats["window_blocks_freed"]
+    assert 0 < d["moe_local"] < d["moe_routed"]
+    assert 0 < d["moe_experts_hit"] <= d["moe_experts_held"]
+    assert engine_metrics()["kv_blocks_window"] is not None
+
+
+def _sums(*keys):
+    from ray_tpu.llm.engine import engine_metrics
+    out = {}
+    for key in keys:
+        h = engine_metrics()[key]
+        out[key] = sum(float(ln.rsplit(" ", 1)[1])
+                       for ln in h.render().splitlines()
+                       if ln.startswith(h.name + "_sum"))
+    return out
+
+
+def test_prefix_reuse_and_speculation_are_refused_at_start(params):
+    with pytest.raises(ValueError, match="prefix"):
+        _engine(params, prefix_cache=True)
+    with pytest.raises(ValueError, match="speculative"):
+        _engine(params, spec=True)
+
+
+def test_load_model_makes_the_familys_parameters():
+    from ray_tpu.serve.llm import LLMConfig, _load_model
+    cfg = _cfg(dtype="bfloat16")
+    got_cfg, params = _load_model(LLMConfig(model=cfg, seed=3))
+    assert got_cfg is cfg
+    assert params["layers"]["w_gate"].shape == (8, 4, 64, 64)
+    assert params["layers"]["w_gate"].dtype == jnp.bfloat16
+    assert params["layers"]["router"].shape == (8, 64, 16)   # all 16 scored
+    assert params["dense_layers"]["w_gate"].shape == (1, 64, 128)
+    n = sum(x.size for x in jax.tree.leaves(params))
+    assert n == cfg.num_params()
