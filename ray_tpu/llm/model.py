@@ -294,7 +294,13 @@ def _qkv(y, lp, cfg: LlamaConfig):
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def heads(w, n, norm=None):
-        z = y @ lp[w]
+        # fenced: XLA would fold the reshape into heads into the product,
+        # and then wants the WEIGHT turned (out, in): the stack copied in
+        # a decode program's entry, a layer's slice of it written out
+        # every step where the layers are unrolled, a transposing copy a
+        # layer in prefill (tests/test_aot_tpu_compile.py holds the
+        # compiled programs to reading wq, wk and wv where they lie)
+        z = lax.optimization_barrier(y @ lp[w])
         if norm and getattr(cfg, "qk_norm", False):     # the whole width
             z = _rmsnorm(z, lp[norm], cfg.norm_eps)
         z = z.reshape(b, s, n, hd)

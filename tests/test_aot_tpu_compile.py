@@ -237,16 +237,17 @@ _LAYER_BYTES = 2 * 5882 * 8 * 16 * HD     # one layer's K (or V), bf16: 193 MB
 _PASS_ON = {"parameter", "tuple", "get-tuple-element", "bitcast", "while"}
 
 
-def _decode_args(chip, wq=None, tp=None):
+def _decode_args(chip, wq=None, tp=None, cfg=None):
     """Shapes of a paged_decode_steps / paged_verify_steps call at the
     cell's pool geometry, on one described chip or (``tp``: a mesh of
-    the topology's four) tensor-parallel as the engine lays them out."""
+    the topology's four) tensor-parallel as the engine lays them out.
+    The model is narrow unless a ``cfg`` is given."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ray_tpu.llm import model as lm
     from ray_tpu.models import llama
-    cfg = llama.LlamaConfig(vocab_size=2048, dim=8 * HD, n_layers=_POOL[0],
-                            n_heads=8, n_kv_heads=_POOL[2], ffn_dim=1024,
-                            dtype="bfloat16")
+    cfg = cfg or llama.LlamaConfig(
+        vocab_size=2048, dim=8 * HD, n_layers=_POOL[0], n_heads=8,
+        n_kv_heads=_POOL[2], ffn_dim=1024, dtype="bfloat16")
 
     def shape(s, d, spec=P()):
         return jax.ShapeDtypeStruct(
@@ -267,23 +268,38 @@ def _decode_args(chip, wq=None, tp=None):
                          shape((2,), jnp.uint32), cfg, 8, None, None]
 
 
+def _with_result_of(compiled, shapes):
+    """(parsed instruction, its line) for every instruction of the
+    compiled program with a result of one of ``shapes``, outside the
+    fused computations (what a fusion makes is its own result's shape)
+    and the Pallas kernels."""
+    bench = _bench_kernels()
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    computation = None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            computation = head.group(1)
+        if head or computation in fused:
+            continue
+        op = bench.parse_op(ln.strip().removeprefix("ROOT "))
+        if shapes & {dims for _, dims in op["result"]} \
+                and not op.get("custom_kernel"):
+            yield op, ln
+
+
 def _pool_shaped(compiled, tp=1) -> list:
     """(opcode, name) of every instruction of the compiled program
     with a result of the pool's shape — one layer of it, all layers
     stacked or flat; under ``tp`` a shard's heads — the Pallas
     kernels apart."""
-    bench = _bench_kernels()
     layers, blocks, *block = _POOL
     block[0] //= tp
     shapes = {(n, *block) for n in (blocks, layers * blocks)} \
         | {(layers, blocks, *block)}
-    found = []
-    for ln in compiled.as_text().splitlines():
-        op = bench.parse_op(ln.strip().removeprefix("ROOT "))
-        if shapes & {dims for _, dims in op["result"]} \
-                and not op.get("custom_kernel"):
-            found.append((op["opcode"], op["name"]))
-    return found
+    return [(op["opcode"], op["name"])
+            for op, _ in _with_result_of(compiled, shapes)]
 
 
 def _kernel_ops(compiled) -> list:
@@ -292,6 +308,18 @@ def _kernel_ops(compiled) -> list:
     for op in ops:
         op["class"] = bench.classify(op)
     return ops
+
+
+def _compile_chat_decode(topo, chip, tp, cfg=None):
+    """(parameter shapes, the compiled paged_decode_steps, n = 8) at the
+    chat cell's pool geometry, on one chip or over the topology's four."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from ray_tpu.llm import kvcache
+    mesh = Mesh(np.asarray(topo.devices), ("tensor",)) if tp > 1 else None
+    pool, args = _decode_args(chip, tp=mesh, cfg=cfg)
+    return args[0], kvcache.decode_steps_program(
+        pool, impl="paged_flash", mesh=mesh).lower(*args).compile()
 
 
 @pytest.mark.parametrize("tp", [1, 4], ids=["one_chip", "tp4"])
@@ -303,13 +331,7 @@ def test_decode_steps_update_the_pool_in_place(topo, chip, tp):
     every layer: eight pool-sized copies a step, 3.9 GB of
     temporaries, three quarters of the cell's device time — and
     nothing on a CPU showed it. This is the guard."""
-    import numpy as np
-    from jax.sharding import Mesh
-    from ray_tpu.llm import kvcache
-    mesh = Mesh(np.asarray(topo.devices), ("tensor",)) if tp > 1 else None
-    pool, args = _decode_args(chip, tp=mesh)
-    compiled = kvcache.decode_steps_program(
-        pool, impl="paged_flash", mesh=mesh).lower(*args).compile()
+    _, compiled = _compile_chat_decode(topo, chip, tp)
     # (a) nothing but the kernels makes a pool-sized array
     held = _pool_shaped(compiled, tp)
     assert held and {code for code, _ in held} <= _PASS_ON, held
@@ -421,45 +443,78 @@ def test_window_and_expert_kernels_compile_for_v5e(chip, name):
             < 2 * stack[1] * stack[2] * stack[3] // 4
 
 
-def test_the_hybrid_cells_decode_program_compiles_for_v5e(chip):
+def _cell_config(file, family, model=None, **overrides):
+    """The program's config of ``benchmarks/configs/<file>``, as the
+    cell builds it (``model``: keys of the file changed first)."""
+    import json
+    import sys
+    bench_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             os.pardir, "benchmarks")
+    with open(os.path.join(bench_dir, "configs", file)) as f:
+        published = {**json.load(f), **(model or {})}
+    sys.path.insert(0, bench_dir)
+    try:
+        from harness import spec
+        return spec.family(family).config(published, **overrides)
+    finally:
+        sys.path.remove(bench_dir)
+
+
+def _hybrid_config(**kw):
+    return _cell_config("k-exaone-236b-a23b-serve-ep8.json", "exaone_moe",
+                        gmm_impl="pallas", **kw)
+
+
+def _shapes_of(chip, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree)
+
+
+def _hybrid_params(chip, cfg):
+    from ray_tpu.models import moe
+    return _shapes_of(chip, jax.eval_shape(
+        lambda: moe.init_params(jax.random.PRNGKey(0), cfg)))
+
+
+def _hybrid_decode(chip, cfg):
+    """(params, pool, the compiled paged_decode_steps, n = 8) at the
+    cell's pool geometry: 32 slots, the global layers' 8,449 blocks,
+    the window layers' 32 rings."""
+    from ray_tpu.llm import kvcache
+    from ray_tpu.llm import model as lm
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    params = _hybrid_params(chip, cfg)
+    kinds = lm.kind_layers(cfg)
+    ring = kvcache.window_ring_blocks(128, 16, 8)
+    glob = (len(kinds["global"]), 8449, 8, 16, HD)
+    win = (len(kinds["window"]), 32 * ring + 1, 8, 16, HD)
+    pool = {"k": shape(glob, _BF), "v": shape(glob, _BF),
+            "wk": shape(win, _BF), "wv": shape(win, _BF)}
+    ids = shape((32,), _I32)
+    tables = {k: shape((32, 256), _I32) for k in ("global", "window")}
+    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
+        params, pool, tables, ids, ids, shape((32,), jnp.float32),
+        shape((2,), jnp.uint32), cfg, 8, None, None).compile()
+    return params, pool, compiled
+
+
+@pytest.fixture(scope="module")
+def hybrid_decode(chip):
+    """The hybrid cell's decode program, compiled once for its tests."""
+    return _hybrid_decode(chip, _hybrid_config())
+
+
+def test_the_hybrid_cells_decode_program_compiles_for_v5e(hybrid_decode):
     """paged_decode_steps (n = 8) at the cell's published widths and
     pool geometry, on a described v5e: every layer calls the paged
     kernel once a step (6 window walks, 2 global) and the writer once,
     the seven sparse layers three decode-shape grouped matmuls each,
     both pools are updated in place, and the weights and the pools fit
     one chip beside the program's temporaries."""
-    import json
-    import sys
-    from ray_tpu.llm import kvcache
-    from ray_tpu.models import moe
-    bench_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             os.pardir, "benchmarks")
-    with open(os.path.join(
-            bench_dir, "configs",
-            "k-exaone-236b-a23b-serve-ep8.json")) as f:
-        model = json.load(f)
-    sys.path.insert(0, bench_dir)
-    try:
-        from harness import spec
-        cfg = spec.family("exaone_moe").config(model, gmm_impl="pallas")
-    finally:
-        sys.path.remove(bench_dir)
-
-    def shape(s, d):
-        return jax.ShapeDtypeStruct(s, d, sharding=chip)
-    params = jax.tree.map(
-        lambda a: shape(a.shape, a.dtype),
-        jax.eval_shape(lambda: moe.init_params(jax.random.PRNGKey(0), cfg)))
-    ring = kvcache.window_ring_blocks(128, 16, 8)
-    pool = {"k": shape((2, 8449, 8, 16, HD), _BF),
-            "v": shape((2, 8449, 8, 16, HD), _BF),
-            "wk": shape((6, 32 * ring + 1, 8, 16, HD), _BF),
-            "wv": shape((6, 32 * ring + 1, 8, 16, HD), _BF)}
-    ids = shape((32,), _I32)
-    tables = {k: shape((32, 256), _I32) for k in ("global", "window")}
-    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
-        params, pool, tables, ids, ids, shape((32,), jnp.float32),
-        shape((2,), jnp.uint32), cfg, 8, None, None).compile()
+    _, pool, compiled = hybrid_decode
     ops = _kernel_ops(compiled)
     names = {}
     for op in ops:
@@ -474,3 +529,98 @@ def test_the_hybrid_cells_decode_program_compiles_for_v5e(chip):
     pool_bytes = sum(2 * int(np.prod(a.shape)) for a in pool.values())
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+# --- the serving forwards read wq, wk and wv in place (PR 36) ----------------
+# Without the fence in ``llm/model.py _qkv`` XLA turns the three leaves
+# (layer, out, in): the stack copied in a decode program's entry, a layer's
+# slice of it written out again on every step where ``_run_layers`` unrolls
+# a segment (13% of the hybrid cell's device time), a transposing copy a
+# layer in prefill. Nothing on a CPU shows it.
+
+def _assert_projections_in_place(compiled, params, tp=1):
+    """No instruction of the compiled program has a result of the shape
+    of wq, wk or wv (under ``tp`` a shard's columns) - one layer or the
+    stack, as held or transposed - but what passes a buffer on, and the
+    compiler's prefetch: an asynchronous copy that keeps the held layout
+    and lands in the fast memory space S(1), the weight's ONE read."""
+    shapes = set()
+    for stack in ("layers", "dense_layers"):
+        for w in ("wq", "wk", "wv"):
+            if w in params.get(stack, {}):
+                n, rows, cols = params[stack][w].shape
+                for dims in ((rows, cols // tp), (cols // tp, rows)):
+                    shapes |= {dims, (1, *dims), (n, *dims)}
+    moved = []
+    for op, ln in _with_result_of(compiled, shapes):
+        code = op["opcode"]
+        if code in ("copy-start", "copy-done"):
+            result = ln.split(" " + code + "(")[0]
+            orders = set(re.findall(r"\]\{([\d,]*)", result)) - {""}
+            if "S(1)" in result and len(orders) == 1:
+                continue
+        if code not in _PASS_ON:
+            moved.append((code, op["name"]))
+    assert not moved, moved
+
+
+def _in_place_case(topo, chip, hybrid_decode, case):
+    """(parameter shapes, compiled program, tensor-parallel size)."""
+    from ray_tpu.models import llama
+    chat = _cell_config("mistral-7b-v0.3-serve.json", "llama")
+    if case.startswith("chat_decode"):
+        tp = 4 if case.endswith("tp4") else 1
+        return *_compile_chat_decode(topo, chip, tp, cfg=chat), tp
+    if case == "hybrid_decode":
+        params, _, compiled = hybrid_decode
+        return params, compiled, 1
+    if case == "chat_prefill":
+        cfg, params = chat, _shapes_of(chip, jax.eval_shape(
+            lambda: llama.init_params(jax.random.PRNGKey(0), chat)))
+    else:
+        cfg = _hybrid_config()
+        params = _hybrid_params(chip, cfg)
+    # the flash kernel named: under pytest 'auto' sees the CPU and takes
+    # the reference
+    import dataclasses
+    from ray_tpu.llm import model as lm
+    bucket = 2048
+    return params, lm.prefill.lower(
+        params, jax.ShapeDtypeStruct((bucket,), _I32, sharding=chip),
+        jax.ShapeDtypeStruct((), _I32, sharding=chip),
+        dataclasses.replace(cfg, attn_impl="flash"), bucket).compile(), 1
+
+
+@pytest.mark.parametrize("case", ["chat_decode", "chat_decode_tp4",
+                                  "chat_prefill", "hybrid_decode",
+                                  "hybrid_prefill"])
+def test_serving_forwards_read_the_projections_in_place(
+        topo, chip, hybrid_decode, case):
+    """Both serve cells' decode programs (n = 8; the chat cell's at
+    Mistral's PUBLISHED widths, on one chip and tensor-parallel over
+    four) and their prefill programs at the largest bucket (2,048):
+    nothing but the products reads wq, wk or wv - no copy of the stack
+    in the entry, no slice of it written out, no transpose - and the
+    decode programs' temporaries stay under 0.2 GB (1.76 GB and 0.34 GB
+    before PR 36)."""
+    params, compiled, tp = _in_place_case(topo, chip, hybrid_decode, case)
+    _assert_projections_in_place(compiled, params, tp)
+    assert "tpu_custom_call" in compiled.as_text()
+    if "decode" in case:
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_the_period_scan_reads_the_projections_stack_in_place(chip):
+    """``_run_layers``' scan over a repeated PERIOD of layer kinds (no
+    cell runs it; the published depth would: eleven periods of four):
+    the hybrid configuration at 9 layers and 4 held experts is the dense
+    layer and one segment of two periods. Since PR 36 its decode program
+    no longer re-lays-out the stacked wq / wk / wv in its entry (2.56 GB
+    of temporaries before). What is left is the scan's own: each turn
+    copies its period's rows of every leaf the body indexes (four
+    layers' wq and wo 403 MB each, wk / wv 50, the shared expert's three
+    101: 1.21 GB): PERF.md section 7."""
+    cfg = _hybrid_config(model={"num_hidden_layers": 9, "num_experts": 4})
+    params, _, compiled = _hybrid_decode(chip, cfg)
+    _assert_projections_in_place(compiled, params)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9

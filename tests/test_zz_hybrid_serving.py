@@ -280,3 +280,87 @@ def test_load_model_makes_the_familys_parameters():
     assert params["dense_layers"]["w_gate"].shape == (1, 64, 128)
     n = sum(x.size for x in jax.tree.leaves(params))
     assert n == cfg.num_params()
+
+
+# --- the projections' products are fenced from the reshape into heads --------
+# (PR 36: tests/test_aot_tpu_compile.py holds what that does to the compiled
+# programs; here: it moves no number)
+
+def _fence_model(name):
+    from ray_tpu.models import llama
+    if name == "llama":
+        cfg = llama.tiny(dtype="float32", attn_impl="reference")
+        return cfg, llama.init_params(jax.random.PRNGKey(2), cfg)
+    cfg = (_cfg() if name == "qk_head_norm" else moe.tiny(
+        n_experts=8, experts_per_token=2, norm_topk_prob=False, qk_norm=True,
+        dtype="float32", attn_impl="reference"))
+    return cfg, moe.init_params(jax.random.PRNGKey(2), cfg)
+
+
+def _fence_logits(forward, cfg, params, mesh):
+    """One forward's logits through a jit of its own (so that what is
+    traced is ``lm._qkv`` as it stands now): a 64-token prompt, or 4
+    slots mid-request over pools of random rows."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rng = np.random.default_rng(7)
+    if forward == "prefill":
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, 64), jnp.int32)
+        return jax.jit(lambda p: lm.prefill.__wrapped__(
+            p, tokens, jnp.int32(61), cfg, 64)[0])(params)
+    slots, width, bs = 4, 8, 8
+    pool = kc.init_pool(cfg, slots * width + 1, bs, jnp.float32,
+                        window_blocks=slots * width + 1)
+    pool = {k: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for k, a in pool.items()}
+    if mesh is not None:
+        pool = jax.device_put(pool, NamedSharding(
+            mesh, P(None, None, "tensor", None, None)))
+    table = jnp.asarray(
+        1 + np.arange(slots * width).reshape(slots, width), jnp.int32)
+    tables = ({k: table for k in ("global", "window")}
+              if "wk" in pool else table)
+    lengths = jnp.asarray([3, 17, 40, 55], jnp.int32)
+    kw = dict(mesh=mesh, axis="tensor")
+    if forward == "verify":
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 3)),
+                             jnp.int32)
+        return jax.jit(lambda p, pool: kc._paged_verify_core(
+            p, pool, tables, lengths, tokens, cfg, impl="gather",
+            **kw)[0])(params, pool)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, slots), jnp.int32)
+    impl = {"decode_gather": "gather", "decode_kernel": "paged_flash"}[forward]
+    return jax.jit(lambda p, pool: kc._paged_logits_core(
+        p, pool, tables, lengths, tokens, cfg, impl=impl, interpret=True,
+        **kw)[0])(params, pool)
+
+
+_FENCE_CASES = [
+    (model, forward, placed)
+    for model, placements in (("llama", ("one_device", "tensor_mesh")),
+                              ("qk_head_norm", ("one_device",)),
+                              ("qk_norm", ("one_device",)))
+    for forward in ("decode_gather", "decode_kernel", "prefill", "verify")
+    for placed in placements
+    # the verify forward attends global layers only
+    if (model, forward) != ("qk_head_norm", "verify")]
+
+
+@pytest.mark.parametrize("model, forward, placed", _FENCE_CASES)
+def test_the_fenced_projections_move_no_number(monkeypatch, model, forward,
+                                               placed):
+    """Prefill, a decode step (the XLA reference and the interpreted
+    kernels) and a verify round, for a Llama model, one with q/k-norm
+    over the whole width (OLMoE) and one with a norm a head (the hybrid
+    model above), on one device and tensor-parallel over two: the
+    logits with the products fenced are the logits without."""
+    cfg, params = _fence_model(model)
+    mesh = None
+    if placed == "tensor_mesh":
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("tensor",))
+        params = lm.shard_params_for_serving(params, mesh, cfg)
+    got = np.asarray(_fence_logits(forward, cfg, params, mesh))
+    # ``_qkv`` as it was: the product, then the reshape
+    monkeypatch.setattr(lm.lax, "optimization_barrier", lambda x: x)
+    want = np.asarray(_fence_logits(forward, cfg, params, mesh))
+    assert np.isfinite(want).all() and np.abs(want).max() > 1e-3
+    assert np.linalg.norm(got - want) <= 1e-6 * np.linalg.norm(want)
