@@ -42,8 +42,9 @@ logger = logging.getLogger("ray_tpu.llm.engine")
 # between two of them is thread hops and other coroutines.
 PHASES = ("admit.alloc", "prefill.dispatch", "prefill.wait",
           "prefill.sample", "decode.prepare", "decode.dispatch",
-          "decode.readback", "verify.prepare", "verify.dispatch",
-          "verify.readback", "verify.accept", "emit", "yield", "idle")
+          "decode.readback", "decode.account", "verify.prepare",
+          "verify.dispatch", "verify.readback", "verify.accept", "emit",
+          "yield", "idle")
 # a phase (other than idle) over this long leaves a slow_phase event
 SLOW_PHASE_S = 1.0
 
@@ -80,20 +81,50 @@ def engine_metrics() -> dict:
       llm_ttft_device_s  prefill device compute (block_until_ready)
       llm_ttft_wall_s    submit -> first token, wall clock
       llm_tpot_s         decode wall time per output token
+      llm_request_tpot_s (last emit - first emit) / (tokens - 1) of a
+                         finished request of two tokens or more, on the
+                         engine's clock: the inside twin of a client's
+                         time per output token (also ``tpot_s`` on the
+                         request's engine/generate span)
       llm_batch_size     active decode slots per step block
       llm_stream_lag_s   a streamed token's wait between the loop's
                          emit and its generate_stream consumer
+      llm_stream_consume_s  what that consumer then does with the token
+                         before it comes back for the next (the
+                         replica's stream hop, on the engine's event
+                         loop)
 
     The scheduler loop, timed and counted where the work is done (the
     sums and counts are what the benchmark's per-layer metrics read):
 
-      llm_loop_<phase>_s       one per PHASES entry: seconds per span
+      llm_loop_<phase>_s       one per PHASES entry (dots as
+                               underscores): seconds per span.
+                               admit.alloc (block reservation),
+                               prefill.dispatch / prefill.wait /
+                               prefill.sample (executor thread),
+                               decode.prepare (block size, inputs, the
+                               window layers' blocks), decode.dispatch
+                               / decode.readback (executor thread: the
+                               block's window), decode.account (the
+                               counters below, the block's linked span
+                               and device window), verify.prepare /
+                               verify.dispatch / verify.readback /
+                               verify.accept (a speculative round),
+                               emit (tokens to their requests), yield
+                               (one turn of the event loop: the stream
+                               consumers wake in it), idle (parked)
       llm_decode_gap_s         previous block's read-back end -> this
                                block's dispatch, while a request went
                                on decoding: what a decoding slot stalls
                                between blocks
       llm_decode_gap_admit_s   of that gap, the part inside
                                engine.admit.* and engine.prefill.*
+      llm_decode_hop_s         a decode block's two thread hops, summed:
+                               decode.prepare's end to decode.dispatch's
+                               start (loop thread to executor) and
+                               decode.readback's end to decode.account's
+                               start (the loop's wake-up, behind
+                               whatever else its thread was running)
       llm_decode_block_steps   decode steps per block
       llm_decode_slot_steps    slots x steps per block
       llm_decode_ctx_tokens    positions attended per block, summed
@@ -152,6 +183,12 @@ def engine_metrics() -> dict:
             "llm_decode_gap_admit_s",
             "The part of llm_decode_gap_s spent inside engine.admit.* "
             "and engine.prefill.* spans", boundaries=seconds),
+        "decode_hop": m.Histogram(
+            "llm_decode_hop_s",
+            "A decode block's two thread hops: engine.decode.prepare's "
+            "end to engine.decode.dispatch's start plus "
+            "engine.decode.readback's end to engine.decode.account's "
+            "start", boundaries=seconds),
         "block_steps": m.Histogram(
             "llm_decode_block_steps", "Decode steps per block",
             boundaries=(1, 2, 4, 8, 16, 32)),
@@ -208,6 +245,18 @@ def engine_metrics() -> dict:
             "Wait of a streamed token between the scheduler loop's "
             "emit and its generate_stream consumer",
             boundaries=seconds),
+        "stream_consume": m.Histogram(
+            "llm_stream_consume_s",
+            "From generate_stream's yield of a token to its consumer's "
+            "return for the next: the stream hop's work on the "
+            "engine's event loop",
+            boundaries=(.00001, .000025, .00005) + seconds),
+        "request_tpot": m.Histogram(
+            "llm_request_tpot_s",
+            "(last emit - first emit) / (tokens - 1) of a finished "
+            "request of two tokens or more, on the engine's clock",
+            boundaries=(.0005, .001, .0025, .005, .01, .025, .05, .1,
+                        .25, .5, 1, 2.5)),
         "queue": m.Histogram(
             "llm_queue_s",
             "Wait from request submission to slot admission"),
@@ -257,6 +306,11 @@ class _Request:
     deadline_ts: Optional[float] = None
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
+    # the newest emit's stamp and the tokens emitted (a matched stop
+    # sequence is trimmed from ``out``, not from this count): with
+    # first_token_at, the engine's own time per output token
+    last_token_at: Optional[float] = None
+    emitted: int = 0
     prefill_device_s: float = 0.0           # block_until_ready-bounded
     # request trace context ambient at submission (the serve replica
     # binds it before user code): engine queue/prefill/generate spans
@@ -329,6 +383,14 @@ class LLMEngine:
         # a model with window layers keeps a second pool for them
         # (kvcache.pool_kinds); None for the Llama family
         self._kinds = kvcache.pool_kinds(cfg)
+        # (layers, window) of each layer kind the paged kernel walks:
+        # what llm_decode_kv_fetch_tokens counts by
+        self._walks = [
+            (len(layers), lm.window_of(cfg, kind))
+            for kind, layers in self._kinds or (
+                (kvcache.GLOBAL, range(cfg.n_layers)),)]
+        self._idle_fetch: dict = {}     # block's steps -> what the walk
+                                        # fetches for one idle slot
         if mesh is not None and (self._kinds or lm.has_experts(cfg)):
             raise NotImplementedError(
                 "tensor-parallel serving shards the Llama family's "
@@ -618,8 +680,11 @@ class LLMEngine:
             if isinstance(t, BaseException):
                 raise t
             tok, t_put = t
-            self._m["stream_lag"].observe(time.monotonic() - t_put)
+            t_out = time.monotonic()
+            self._m["stream_lag"].observe(t_out - t_put)
             yield tok
+            # back for the next: what the consumer did with this one
+            self._m["stream_consume"].observe(time.monotonic() - t_out)
 
     async def generate_prefilled(self, tokens, prefilled: dict,
                                  **kw) -> dict:
@@ -874,7 +939,7 @@ class LLMEngine:
                 # steps (discarded at emit, slot freed at the sync) —
                 # the batch's throughput is worth more than the waste,
                 # and headroom bounds below keep its cache writes legal.
-                with phase("decode.prepare"):
+                with phase("decode.prepare") as prep:
                     block = self.steps_per_sync
                     for i in active:
                         r = self._slots[i]
@@ -894,44 +959,18 @@ class LLMEngine:
                         top_ps[i] = self._slots[i].top_p
                         top_ks[i] = self._slots[i].top_k
                     member_traces, first_ctx = self._members(active)
-                    self._set_aside(active, block)
-                out = await loop.run_in_executor(
+                    lens = self._set_aside(active, block)
+                out, counts = await loop.run_in_executor(
                     None, self._decode_sync, tokens, temps, top_ps,
                     top_ks, block, first_ctx)
-                # the work of the block just read back, counted here:
-                # steps, slot-steps, and the positions each step
-                # attended (prompt + emitted so far, one more a step).
-                # A slot that hits eos mid-block still ran its steps.
-                n = len(active)
-                self._m["block_steps"].observe(block)
-                self._m["slot_steps"].observe(n * block)
-                self._m["ctx_tokens"].observe(
-                    block * sum(len(self._slots[i].tokens)
-                                + len(self._slots[i].out)
-                                for i in active)
-                    + n * block * (block - 1) // 2)
-                if self._kv_impl == "paged_flash":
-                    # what the kernel's walk fetched for them: every
-                    # slot's live blocks at every step, an idle slot
-                    # (length 1 + step) included; a window layer's from
-                    # its window's first block on, so the mean a layer
-                    from ray_tpu.ops.pallas.paged_attention import \
-                        fetched_positions
-                    at = np.ones((self.max_slots, 1), np.int64)
-                    for i in active:
-                        at[i] = len(self._slots[i].tokens) \
-                            + len(self._slots[i].out)
-                    at = at + np.arange(block)
-                    fetched = sum(
-                        len(layers) * int(fetched_positions(
-                            at, self._block,
-                            lm.window_of(self.cfg, kind)).sum())
-                        for kind, layers in self._kinds or (
-                            (kvcache.GLOBAL, range(self.cfg.n_layers)),))
-                    self._m["kv_fetch_tokens"].observe(
-                        fetched / self.cfg.n_layers)
-                self._record_block(n, block, member_traces, first_ctx,
-                                   block=block)
+                with phase("decode.account") as acc:
+                    # what the block spent between the two threads
+                    t_disp, t_back = self._dev_span
+                    self._m["decode_hop"].observe(
+                        (t_disp - prep.t1) + (acc.t0 - t_back))
+                    self._account_block(lens, block, counts)
+                    self._record_block(len(active), block, member_traces,
+                                       first_ctx, block=block)
                 with phase("emit"):
                     for step in range(block):
                         for i in active:
@@ -939,11 +978,12 @@ class LLMEngine:
                             if r is None:  # finished earlier this block
                                 continue
                             self._emit_token(r, int(out[step, i]), i)
-                # whoever is still in its slot waits for the next
-                # block from the moment this one was read back
-                self._gap_from = self._dev_span[1] if any(
-                    self._slots[i] is not None for i in active) else None
-                self._gap_admit = 0.0
+                    # whoever is still in its slot waits for the next
+                    # block from the moment this one was read back
+                    self._gap_from = self._dev_span[1] if any(
+                        self._slots[i] is not None for i in active) \
+                        else None
+                    self._gap_admit = 0.0
                 with phase("yield"):
                     await asyncio.sleep(0)
         except BaseException as e:  # noqa: BLE001 — fail all requests
@@ -960,20 +1000,22 @@ class LLMEngine:
                 if r is not None:
                     self._finish(r, i)
 
-    def _set_aside(self, active: List[int], block: int) -> None:
+    def _set_aside(self, active: List[int], block: int) -> List[int]:
         """Before a decode block of ``block`` steps: a model with
         window layers gets the window-layer blocks the block writes and
         gives back the ones each sequence's window has passed
         (kvcache.advance_window); then what the pool holds by layer
-        kind is observed, for any model."""
-        live = 0
+        kind is observed, for any model. Returns the positions each
+        active slot holds as the block starts (prompt + emitted)."""
+        lens = []
         for i in active:
             r = self._slots[i]
             n = len(r.tokens) + len(r.out)
-            live += n
+            lens.append(n)
             if self._kinds:
                 self._wtables[i] = self._kv.advance_window(
                     r.seq, n - 1, block)
+        live = sum(lens)
         used = {kvcache.GLOBAL: self._kv.used_blocks()}
         if self._kinds:
             used[kvcache.WINDOW] = self._kv.window_used_blocks()
@@ -985,6 +1027,45 @@ class LLMEngine:
         self._m["kv_used_bytes"].observe(
             sum(n * self._block_bytes[kind] for kind, n in used.items()))
         self._m["kv_live_tokens"].observe(live)
+        return lens
+
+    def _account_block(self, lens: List[int], block: int,
+                       counts: Optional[dict]) -> None:
+        """The work of the block just read back, counted (the loop's
+        ``decode.account`` phase): steps, slot-steps, the positions
+        each step attended (``lens`` at its start, one more a step; a
+        slot that hits eos mid-block still ran its steps), what the
+        kernel's walk fetched for them, and the expert layers' device
+        scalars that came back with the tokens."""
+        n = len(lens)
+        self._m["block_steps"].observe(block)
+        self._m["slot_steps"].observe(n * block)
+        self._m["ctx_tokens"].observe(
+            block * sum(lens) + n * block * (block - 1) // 2)
+        if self._kv_impl == "paged_flash":
+            # every slot's live blocks at every step, an idle slot
+            # (length 1 + step) included; a window layer's from its
+            # window's first block on, so the mean a layer
+            from ray_tpu.ops.pallas.paged_attention import \
+                fetched_positions_run as run
+            bs = self._block
+            if block not in self._idle_fetch:   # the same every block
+                self._idle_fetch[block] = sum(
+                    layers * run(1, block, bs, w)
+                    for layers, w in self._walks)
+            fetched = (self.max_slots - n) * self._idle_fetch[block]
+            for layers, w in self._walks:
+                fetched += layers * sum(run(a, block, bs, w)
+                                        for a in lens)
+            self._m["kv_fetch_tokens"].observe(
+                fetched / self.cfg.n_layers)
+        if counts is not None:
+            for key, per_step in counts.items():
+                self._m["moe_" + key].observe(int(per_step.sum()))
+            self._m["moe_experts_held"].observe(
+                block * self.cfg.n_held
+                * (self.cfg.n_layers
+                   - getattr(self.cfg, "n_dense_layers", 0)))
 
     def _members(self, active: List[int]):
         """(sorted trace ids of the batch's traced requests, the first
@@ -1248,8 +1329,10 @@ class LLMEngine:
                      top_ps: np.ndarray, top_ks: np.ndarray,
                      block: int,
                      trace_ctx: Optional[tracing.TraceContext] = None
-                     ) -> np.ndarray:
-        """Returns (block, slots) int32 sampled tokens. ``trace_ctx``
+                     ) -> tuple:
+        """Returns the (block, slots) int32 sampled tokens and the
+        expert layers' per-step counts (None for a model without
+        expert layers). ``trace_ctx``
         (the first member trace of the batch) is bound while the block
         runs so a decode-path XLA compile — a new block-size variant,
         a filter toggle — stamps a member's trace id onto its
@@ -1266,7 +1349,7 @@ class LLMEngine:
 
     def _decode_impl(self, tokens: np.ndarray, temps: np.ndarray,
                      top_ps: np.ndarray, top_ks: np.ndarray,
-                     block: int) -> np.ndarray:
+                     block: int) -> tuple:
         jax, jnp = _jx()
         with self._phase("decode.dispatch") as disp:
             if self._gap_from is not None:
@@ -1305,16 +1388,8 @@ class LLMEngine:
                 block, tags={"impl": self._kv_impl})
         with self._phase("decode.readback") as back:
             out, counts = jax.device_get((out, counts))
-        if counts is not None:
-            # the expert layers' counts came back with the tokens
-            for key_, per_step in counts.items():
-                self._m["moe_" + key_].observe(int(per_step.sum()))
-            self._m["moe_experts_held"].observe(
-                block * self.cfg.n_held
-                * (self.cfg.n_layers
-                   - getattr(self.cfg, "n_dense_layers", 0)))
         self._dev_span = (disp.t0, back.t1)
-        return out
+        return out, counts
 
     async def _spec_round(self, loop, active: List[int],
                           drafts: dict) -> None:
@@ -1385,9 +1460,12 @@ class LLMEngine:
                                 # max_new): the tail of an accepted
                                 # draft is dropped
                     self._emit_token(r, int(t), i)
-        self._record_block(
-            len(active), max(1.0, emitted_total / max(1, len(active))),
-            member_traces, first_ctx, block=emitted_total, spec_k=w - 1)
+        with self._phase("decode.account"):
+            self._record_block(
+                len(active),
+                max(1.0, emitted_total / max(1, len(active))),
+                member_traces, first_ctx, block=emitted_total,
+                spec_k=w - 1)
 
     def _verify_sync(self, tokens_bw: np.ndarray, lengths: np.ndarray,
                      trace_ctx: Optional[tracing.TraceContext] = None
@@ -1434,9 +1512,11 @@ class LLMEngine:
 
     def _emit_token(self, r: _Request, tok: int, slot: int):
         """Append one sampled token; finish the request if done."""
+        r.last_token_at = now = time.monotonic()
+        r.emitted += 1
         if r.first_token_at is None:
-            r.first_token_at = time.monotonic()
-            wall = r.first_token_at - r.submitted
+            r.first_token_at = now
+            wall = now - r.submitted
             self._ttft_sum += wall
             self._ttft_count += 1
             self._m["ttft_wall"].observe(wall)
@@ -1449,7 +1529,7 @@ class LLMEngine:
         r.out.append(tok)
         self._tokens_generated += 1
         if r.stream is not None:
-            r.stream.put_nowait((tok, time.monotonic()))
+            r.stream.put_nowait((tok, now))
         if r.stop:
             for seq in r.stop:
                 if len(r.out) >= len(seq) and r.out[-len(seq):] == seq:
@@ -1460,13 +1540,16 @@ class LLMEngine:
                 or (r.eos_id is not None and tok == r.eos_id)):
             self._finish(r, slot)
 
-    def _record_done(self, r: _Request, error: bool) -> None:
+    def _record_done(self, r: _Request, error: bool,
+                     tpot_s: Optional[float] = None) -> None:
         """Terminal engine span for one request: submit -> done, with
-        the produced token count and the request's KV high-watermark
-        (prompt + generated positions priced at the cache's per-token
-        bytes) — the trace drill-down shows what the request cost in
-        HBM, not just time. Recorded at most once (finish, fail, and
-        the loop's shutdown sweep can all reach a request)."""
+        the produced token count, the engine's own time per output
+        token (``tpot_s``, from _finish) and the request's KV
+        high-watermark (prompt + generated positions priced at the
+        cache's per-token bytes) — the trace drill-down shows what the
+        request cost in HBM, not just time. Recorded at most once
+        (finish, fail, and the loop's shutdown sweep can all reach a
+        request)."""
         # the accept-rate gauge tracks every finished speculative
         # request, traced or not (the span extra below needs a trace)
         if r.spec_drafted and self._specm is not None:
@@ -1474,6 +1557,8 @@ class LLMEngine:
         if r.trace is None:
             return
         extra = {"prefix_hit_tokens": r.prefix_hit}
+        if tpot_s is not None:
+            extra["tpot_s"] = tpot_s
         if r.handoff_bytes:
             extra["kv_handoff_bytes"] = r.handoff_bytes
         if r.spec_drafted:
@@ -1515,7 +1600,16 @@ class LLMEngine:
         self._kv_account()
 
     def _finish(self, r: _Request, slot: Optional[int]):
-        self._record_done(r, error=False)
+        # the engine's own measure of a token, from ONE pair of stamps
+        # to two sinks: the histogram and the request's generate span
+        # (a median over requests survives a stalled window, a
+        # histogram's sum does not)
+        tpot_s = None
+        if r.emitted >= 2:
+            tpot_s = (r.last_token_at - r.first_token_at) / (r.emitted - 1)
+            self._m["request_tpot"].observe(
+                tpot_s, exemplar=r.trace.trace_id if r.trace else None)
+        self._record_done(r, error=False, tpot_s=tpot_s)
         self._free_kv(r, slot)
         if slot is not None and self._slots[slot] is r:
             self._slots[slot] = None

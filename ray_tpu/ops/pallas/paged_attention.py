@@ -132,6 +132,34 @@ def fetched_positions(length, block_size, window=None):
             - first_block(length, block_size, window)) * block_size
 
 
+def _ceil_sum(n, bs):
+    """Sum of ``ceil(x / bs)`` over x = 1 .. n."""
+    q, r = divmod(n, bs)
+    return bs * q * (q + 1) // 2 + (q + 1) * r
+
+
+def _floor_sum(m, bs):
+    """Sum of ``y // bs`` over y = 1 .. m."""
+    q, r = divmod(m, bs)
+    return bs * q * (q - 1) // 2 + q * (r + 1)
+
+
+def fetched_positions_run(length, steps, block_size, window=None):
+    """``fetched_positions`` summed over ``steps`` consecutive decode
+    steps of a slot that starts at ``length`` (lengths ``length ...
+    length + steps - 1``), in closed form on plain ints: the engine
+    counts a decode block's fetches with it, once a live slot."""
+    idle = min(steps, max(0, 1 - length))   # lengths under 1: one block
+    a, b = length + idle, length + steps - 1
+    blocks = idle
+    if b >= a:
+        blocks += _ceil_sum(b, block_size) - _ceil_sum(a - 1, block_size)
+    if window is not None and b > window:
+        blocks -= _floor_sum(b - window, block_size) \
+            - _floor_sum(max(a - 1 - window, 0), block_size)
+    return blocks * block_size
+
+
 def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                  kbuf, vbuf, sems, *, bs, cb, width, window=None):
     b_ = pl.program_id(0)

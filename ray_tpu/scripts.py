@@ -559,7 +559,7 @@ def cmd_trace(args) -> int:
     import time as _time
 
     from ray_tpu.util.state import summarize_traces, traces_from_events
-    from ray_tpu.util.tracing import filter_trace, to_chrome
+    from ray_tpu.util.tracing import filter_trace, stream_attrs, to_chrome
     addr = _resolve_address(args)
     r = _call_head(addr, "collect_timeline")
     evs = r.get("events", [])
@@ -613,6 +613,8 @@ def cmd_trace(args) -> int:
                      f"kept={e.get('keep')}]")
         elif e.get("links"):
             extra = f"  [batch x{len(e['links'])}]"
+        elif attrs := stream_attrs(e):
+            extra = f"  [{attrs}]"
         print(f"{e.get('component', '?'):8s} {e.get('seg', '?'):10s} "
               f"{(e.get('dur') or 0.0) * 1e3:9.2f} ms  "
               f"node={str(e.get('node', ''))[:8] or '-':8s} "

@@ -161,6 +161,17 @@ def proxy_metrics() -> dict:
             "serve_proxy_handler_s",
             "Time awaiting the deployment handler's result",
             tag_keys=("deployment",)),
+        # one sample a STREAM and stage, never one a token: the
+        # stream's mean seconds a token inside the object's fetch
+        # ("get"), its release ("free") and the socket write + drain
+        # ("write") of _dispatch_stream
+        "stream_token": m.Histogram(
+            "serve_proxy_stream_token_s",
+            "A stream's mean seconds per token in one stage of the "
+            "proxy's per-token path (get, free, write)",
+            tag_keys=("deployment", "stage"),
+            boundaries=(.00001, .000025, .00005, .0001, .00025, .0005,
+                        .001, .0025, .005, .01, .025, .05, .1, .25, 1)),
         # the availability SLI: the health plane's per-deployment
         # availability objective reads code="5xx" increments off this
         # (util/health.py derived objectives)
@@ -547,6 +558,7 @@ class HTTPProxy:
                                         tracing.new_span_id())
         else:
             tctx = tracing.mint_context()
+        fetched, t_route = self._routes_fetched, time.time()
         try:
             await self._refresh_routes(deadline_ts)
         except Exception as e:
@@ -571,6 +583,11 @@ class HTTPProxy:
             # failing refresh re-runs at most once per second, not on
             # every request during a controller outage
             self._routes_fetched = time.monotonic()
+        if tctx is not None and self._routes_fetched != fetched:
+            # this request paid for the table's refresh (at most one a
+            # second does): its own segment, like the queue's
+            tracing.record_request_span(
+                "proxy", "route", tctx, tctx.span_id, t_route, time.time())
         if path == "/-/routes":
             return self._respond(writer, 200, {"routes": self._routes})
         dep = self._match(path)
@@ -710,7 +727,16 @@ class HTTPProxy:
         The request deadline bounds the WHOLE stream: each token wait
         spends the remaining budget, and the replica/engine cancels its
         side when the budget runs out. Returns "close" — an SSE
-        response ends with the connection."""
+        response ends with the connection.
+
+        The per-token path is stamped once a token around its three
+        awaits and summed: the stream's proxy/handler span carries
+        ``tokens``, ``first_token_s`` (arrival at the proxy to the
+        first ``data:`` written and drained), ``get_s`` / ``free_s`` /
+        ``write_s`` and ``t_first`` / ``t_last`` (the first and last
+        token's write, monotonic stamps on tracing.wall's clock:
+        (t_last - t_first) / (tokens - 1) is the token gap AT the
+        socket). Nothing is recorded per token."""
         from ray_tpu.serve.handle import DeploymentHandle
         loop = asyncio.get_running_loop()
 
@@ -759,17 +785,29 @@ class HTTPProxy:
                      b"Content-Type: text/event-stream\r\n"
                      b"Cache-Control: no-cache\r\n" + tid_hdr +
                      b"Connection: close\r\n\r\n")
+        n_tok, t_first, t_last = 0, None, None
+        get_s = free_s = write_s = 0.0
         try:
             async for ref in gen:
                 rem = fault.remaining_s(deadline_ts)
                 if rem is not None and rem <= 0:
                     raise fault.DeadlineExceeded("mid-stream")
+                t_a = time.monotonic()
                 t = await api.get_async(
                     ref, timeout=rem if rem is not None else 120.0)
+                t_b = time.monotonic()
                 await api._g.ctx.free([ref])  # long-lived proxy process
+                t_c = time.monotonic()
                 writer.write(
                     f"data: {json.dumps({'token': t})}\n\n".encode())
                 await writer.drain()
+                t_last = time.monotonic()
+                get_s += t_b - t_a
+                free_s += t_c - t_b
+                write_s += t_last - t_c
+                n_tok += 1
+                if t_first is None:
+                    t_first = t_last
             writer.write(b"event: done\ndata: {}\n\n")
             await writer.drain()
         except (ConnectionResetError, BrokenPipeError):
@@ -809,11 +847,22 @@ class HTTPProxy:
                 "deployment": dep,
                 "code": {"ok": "200",
                          "deadline": "504"}.get(status, "500")})
+            stream = {}
+            if n_tok:
+                for stage, total in (("get", get_s), ("free", free_s),
+                                     ("write", write_s)):
+                    self._m["stream_token"].observe(
+                        total / n_tok, {**tags, "stage": stage})
+                stream = {"first_token_s": t_first - (t_arrive or t_sent),
+                          "get_s": get_s, "free_s": free_s,
+                          "write_s": write_s,
+                          "t_first": tracing.wall(t_first),
+                          "t_last": tracing.wall(t_last)}
             if tctx is not None:
                 tracing.record_request_span(
                     "proxy", "handler", tctx, tctx.span_id,
                     t_sent_wall, time.time(), deployment=dep,
-                    error=status != "ok")
+                    error=status != "ok", tokens=n_tok, **stream)
                 tracing.finish_request(
                     tctx, t_arrive_wall or t_sent_wall, time.time(),
                     status=status, deployment=dep)

@@ -227,13 +227,19 @@ class Replica:
         serve/_private/replica.py streaming call path). The propagated
         deadline is bound to the request context (the engine cancels at
         it, reclaiming its slot); the stream itself is cut the moment
-        the budget is spent."""
+        the budget is spent. The handler span carries ``items`` and
+        ``push_s``: the summed time from handing an item to the runtime
+        (the yield) to the runtime's return for the next, which is its
+        put into the object plane as this generator sees it (admission
+        under the producer's backpressure and the push's start; the
+        push's RPC completes on its own task)."""
         from ray_tpu.serve.multiplex import _current_model_id
         dl_token, dl, pctx, tr_token, hid = await self._admit(meta)
         self._ongoing += 1
         t_run = time.monotonic()
         t_run_wall = time.time()
         ok = False
+        items, push_s = 0, 0.0
         tags = {"deployment": self.deployment_name}
         token = None
         mid = (meta or {}).get("multiplexed_model_id")
@@ -243,28 +249,24 @@ class Replica:
         try:
             fn = getattr(self.instance, method)
             if inspect.isasyncgenfunction(fn):
-                async for item in fn(*args, **kwargs):
-                    if dl is not None and time.time() > dl:
-                        self._fm["deadline"].inc(
-                            tags={"where": "replica"})
-                        raise fault.DeadlineExceeded(
-                            f"stream {method} cut at the deadline on "
-                            f"replica {self.replica_id}")
-                    yield item
+                gen = fn(*args, **kwargs)
             elif inspect.isgeneratorfunction(fn):
                 from ray_tpu.util.aio import drive_sync_gen
-                async for item in drive_sync_gen(fn(*args, **kwargs)):
-                    if dl is not None and time.time() > dl:
-                        self._fm["deadline"].inc(
-                            tags={"where": "replica"})
-                        raise fault.DeadlineExceeded(
-                            f"stream {method} cut at the deadline on "
-                            f"replica {self.replica_id}")
-                    yield item
+                gen = drive_sync_gen(fn(*args, **kwargs))
             else:
                 raise TypeError(
                     f"streaming call to {method!r}, which is not a "
                     "generator method")
+            async for item in gen:
+                if dl is not None and time.time() > dl:
+                    self._fm["deadline"].inc(tags={"where": "replica"})
+                    raise fault.DeadlineExceeded(
+                        f"stream {method} cut at the deadline on "
+                        f"replica {self.replica_id}")
+                t_put = time.monotonic()
+                yield item
+                items += 1
+                push_s += time.monotonic() - t_put
             self._processed += 1
             ok = True
         except GeneratorExit:
@@ -286,7 +288,8 @@ class Replica:
                     "replica", "handler", pctx, pctx.span_id,
                     t_run_wall, time.time(), span_id=hid,
                     error=not ok, deployment=self.deployment_name,
-                    method=method, replica=self.replica_id)
+                    method=method, replica=self.replica_id,
+                    items=items, push_s=push_s)
             fault.reset_request_deadline(dl_token)
             if token is not None:
                 _current_model_id.reset(token)

@@ -36,6 +36,31 @@ recorded — `ray-tpu trace` / the dashboard /traces page list only
 traces with a root; unkept traces' segment spans age out of the bounded
 buffers without ever surfacing. RAY_TPU_TRACE_REQUESTS=0 disables the
 layer entirely (nothing minted, every record path no-ops).
+
+The proxy's own segments: ``queue`` (only when the request waited for
+admission), ``route`` (only when it paid for the routing table's
+refresh, at most one request a second), ``handler`` (the deployment
+call) and the root ``request``.
+
+A STREAMED request's per-token path is summed onto the ONE span each
+hop records anyway, never an event per token (the "request" bucket
+holds 8,192 events):
+  proxy/handler    ``tokens``; ``first_token_s`` (arrival at the proxy
+                   to the first ``data:`` written and drained);
+                   ``get_s`` / ``free_s`` / ``write_s`` (summed seconds
+                   in the object's fetch, its release, the socket write
+                   + drain); ``t_first`` / ``t_last`` (the first and
+                   last token's write on ``wall()`` of the monotonic
+                   clock: (t_last - t_first) / (tokens - 1) is the
+                   token gap AT the socket)
+  replica/handler  ``items``; ``push_s`` (summed seconds from handing
+                   an item to the runtime's streaming return to its
+                   coming back for the next)
+  engine/generate  ``tpot_s`` ((last emit - first emit) / (tokens - 1)
+                   on the engine's clock; any request of two tokens or
+                   more)
+``stream_attrs`` renders them as the one line `ray-tpu trace <id>`
+prints beside such a span.
 """
 
 from __future__ import annotations
@@ -399,7 +424,31 @@ _COLLECTIVE_ROUND_ARGS = ("op", "codec", "cid", "step", "bytes",
 _REQUEST_SPAN_ARGS = ("trace", "span", "parent", "seg", "status",
                       "keep", "deployment", "method", "http_status",
                       "error", "links", "step", "block", "slots",
-                      "tokens", "attempt", "replica", "kv_bytes")
+                      "tokens", "attempt", "replica", "kv_bytes",
+                      "first_token_s", "get_s", "free_s", "write_s",
+                      "t_first", "t_last", "items", "push_s", "tpot_s")
+
+
+def stream_attrs(e: dict) -> str:
+    """The token-path attributes of one request span (module
+    docstring) as a short text, "" for a span without them."""
+    out = []
+    if e.get("tpot_s") is not None:
+        out.append(f"{e['tpot_s'] * 1e3:.3f} ms/token")
+    if e.get("items"):
+        out.append(f"{e['items']} items, push "
+                   f"{e.get('push_s', 0.0) / e['items'] * 1e6:.0f} us each")
+    n = e.get("tokens")
+    if n and e.get("t_first") is not None:
+        out.append(f"{n} tokens, first after "
+                   f"{e['first_token_s'] * 1e3:.2f} ms")
+        if n > 1:
+            out.append(f"{(e['t_last'] - e['t_first']) / (n - 1) * 1e3:.3f}"
+                       " ms/token at the socket")
+        out.append("get/free/write " + "/".join(
+            f"{e.get(k, 0.0) / n * 1e6:.0f}"
+            for k in ("get_s", "free_s", "write_s")) + " us a token")
+    return ", ".join(out)
 
 
 _DEVICE_SPAN_ARGS = ("fn", "cache_hit", "trace", "seg", "device",
