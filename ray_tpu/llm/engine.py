@@ -78,7 +78,10 @@ def engine_metrics() -> dict:
     util/metrics.push_loop). Catalog:
 
       llm_queue_s        submit -> slot admission (waiting for a slot)
-      llm_ttft_device_s  prefill device compute (block_until_ready)
+      llm_ttft_device_s  prefill dispatch -> its results ready: the
+                         prefill's device compute and, ahead of it on
+                         the device, what is left of the decode block
+                         in flight it was enqueued behind
       llm_ttft_wall_s    submit -> first token, wall clock
       llm_tpot_s         decode wall time per output token
       llm_request_tpot_s (last emit - first emit) / (tokens - 1) of a
@@ -116,9 +119,15 @@ def engine_metrics() -> dict:
       llm_decode_gap_s         previous block's read-back end -> this
                                block's dispatch, while a request went
                                on decoding: what a decoding slot stalls
-                               between blocks
+                               between blocks. 0 for a block enqueued
+                               before its predecessor was read back
+                               (the device went from one to the other)
       llm_decode_gap_admit_s   of that gap, the part inside
                                engine.admit.* and engine.prefill.*
+      llm_decode_ahead_size    decode blocks enqueued and not yet read
+                               back as a block is enqueued: 1 when it
+                               went out ahead of its predecessor's
+                               read-back, 0 when nothing was in flight
       llm_decode_hop_s         a decode block's two thread hops, summed:
                                decode.prepare's end to decode.dispatch's
                                start (loop thread to executor) and
@@ -178,11 +187,18 @@ def engine_metrics() -> dict:
             "llm_decode_gap_s",
             "Previous decode block's read-back end to this block's "
             "dispatch, observed when a request of the previous block "
-            "is still decoding", boundaries=seconds),
+            "is still decoding; 0 when this block was enqueued before "
+            "that read-back", boundaries=seconds),
         "gap_admit": m.Histogram(
             "llm_decode_gap_admit_s",
             "The part of llm_decode_gap_s spent inside engine.admit.* "
             "and engine.prefill.* spans", boundaries=seconds),
+        "decode_ahead": m.Histogram(
+            "llm_decode_ahead_size",
+            "Decode blocks enqueued and not yet read back as a block is "
+            "enqueued: 1 when it went out ahead of its predecessor's "
+            "read-back, 0 when nothing was in flight",
+            boundaries=(0, 1)),
         "decode_hop": m.Histogram(
             "llm_decode_hop_s",
             "A decode block's two thread hops: engine.decode.prepare's "
@@ -262,8 +278,10 @@ def engine_metrics() -> dict:
             "Wait from request submission to slot admission"),
         "ttft_device": m.Histogram(
             "llm_ttft_device_s",
-            "Device compute time producing the first token (prefill "
-            "forward + cache write, block_until_ready-bounded)"),
+            "Prefill dispatch to its results ready (forward + cache "
+            "write, block_until_ready-bounded): the device time producing "
+            "the first token, behind what is left of the decode block in "
+            "flight"),
         "ttft_wall": m.Histogram(
             "llm_ttft_wall_s",
             "Wall time from submission to first token"),
@@ -338,6 +356,38 @@ class _Request:
     drafter: Optional[specdec.PromptLookupDrafter] = None
     spec_drafted: int = 0
     spec_accepted: int = 0
+
+
+@dataclass
+class _Block:
+    """One decode block from decode.prepare to its read-back. The loop
+    keeps at most one enqueued and unread (``LLMEngine._inflight``)
+    while it prepares and enqueues the next."""
+    steps: int
+    reqs: dict                  # slot -> the request it decodes there
+    lens: List[int]             # positions each holds as the block starts
+    tokens: np.ndarray          # (slots,) first tokens the host knows
+    keep: np.ndarray            # (slots,) bool: the first token is the
+                                # predecessor's last row, on the device
+    lengths: np.ndarray         # (slots,) first write positions
+    tables: object              # the block tables as it was prepared
+    temps: np.ndarray
+    top_ps: np.ndarray
+    top_ks: np.ndarray
+    member_traces: List[str]
+    first_ctx: Optional[tracing.TraceContext]
+    t_prep: float = 0.0         # decode.prepare's end
+    # stamped by the executor thread: dispatch start, read-back end
+    t_disp: float = 0.0
+    t_back: float = 0.0
+    # device results: (steps, slots) tokens, the expert layers' counts,
+    # the last row (the successor's first tokens)
+    out: object = None
+    counts: object = None
+    last: object = None
+    # KV releases of requests that ended after this block was enqueued
+    # with their tables in it: run once it has been read back
+    held: list = field(default_factory=list)
 
 
 class LLMEngine:
@@ -515,6 +565,17 @@ class LLMEngine:
                 mesh, P(None, None, tensor_axis, None, None))
             self._pool = {k: jax.device_put(v, s)
                           for k, v in self._pool.items()}
+            self._tok_sharding = NamedSharding(mesh, P())
+        else:
+            # one device: the pool is committed to it from the start,
+            # as a block's first tokens are (below), so no program of
+            # the engine ever meets an argument of the other kind and
+            # compiles a second time for it
+            from jax.sharding import SingleDeviceSharding
+            self._tok_sharding = SingleDeviceSharding(
+                next(iter(self._pool["k"].devices())))
+            self._pool = {k: jax.device_put(v, self._tok_sharding)
+                          for k, v in self._pool.items()}
         self._kv = kvcache.KVBlockManager(
             nb, self._block, table_width=self._table_w,
             prefix_cache=prefix_cache, metrics=self._kvm, window=window)
@@ -540,9 +601,13 @@ class LLMEngine:
         self._m = engine_metrics()
         self._phases = {p: ("engine." + p, self._m[_loop_key(p)])
                         for p in PHASES}
+        # the decode block enqueued and not yet read back, if any: the
+        # loop enqueues its successor before it reads this one
+        self._inflight: Optional[_Block] = None
         # the stall between two decode blocks: where the last read-back
-        # ended (None when no request carried over), the admit/prefill
-        # seconds since, and the last block's device interval
+        # ended (None when no request carried over, or when the next
+        # block was enqueued before it), the admit/prefill seconds
+        # since, and the device interval of the last block read back
         self._gap_from: Optional[float] = None
         self._gap_admit = 0.0
         self._dev_span = (0.0, 0.0)
@@ -877,7 +942,8 @@ class LLMEngine:
                         self._expire(r, i)
                 active = [i for i, r in enumerate(self._slots)
                           if r is not None]
-                if not active:
+                fl = self._inflight
+                if not active and fl is None:
                     self._gap_from = None   # nobody is stalled by it
                     if self._blocked:
                         # pool-parked admits with nothing running can
@@ -904,9 +970,12 @@ class LLMEngine:
                 # nothing to match yet) the engine falls through to the
                 # vanilla block path below — that fallback plus the
                 # drafter's accept-rate backoff is what bounds the
-                # adversarial-prompt overhead.
+                # adversarial-prompt overhead. A drafter reads the
+                # host's copy of its request's tokens, so while a slot
+                # holds one no block is left in flight (2, below) and
+                # this always runs with every token read back.
                 drafts: dict = {}
-                if self._spec:
+                if self._spec and fl is None:
                     with phase("verify.prepare"):
                         for i in active:
                             r = self._slots[i]
@@ -930,59 +999,94 @@ class LLMEngine:
                     with phase("yield"):
                         await asyncio.sleep(0)
                     continue
-                # 2) a BLOCK of decode steps for every active slot, one
-                # host sync per block. Sampling is on-device
-                # (lm.sample); only token ids come back. Block size is
-                # bounded by each slot's remaining budget so no request
-                # over-runs max_new_tokens or the cache.
-                # A slot hitting eos mid-block wastes its remaining
-                # steps (discarded at emit, slot freed at the sync) —
-                # the batch's throughput is worth more than the waste,
-                # and headroom bounds below keep its cache writes legal.
-                with phase("decode.prepare") as prep:
-                    block = self.steps_per_sync
-                    for i in active:
-                        r = self._slots[i]
-                        block = min(block,
-                                    r.max_new_tokens - len(r.out),
-                                    self.max_len - len(r.tokens)
-                                    - len(r.out))
-                    # pow2, rounded down
-                    block = 1 << (max(1, block).bit_length() - 1)
-                    tokens = np.zeros((self.max_slots,), np.int32)
-                    temps = np.zeros((self.max_slots,), np.float32)
-                    top_ps = np.ones((self.max_slots,), np.float32)
-                    top_ks = np.zeros((self.max_slots,), np.int32)
-                    for i in active:
-                        tokens[i] = self._slots[i].out[-1]
-                        temps[i] = self._slots[i].temperature
-                        top_ps[i] = self._slots[i].top_p
-                        top_ks[i] = self._slots[i].top_k
-                    member_traces, first_ctx = self._members(active)
-                    lens = self._set_aside(active, block)
-                out, counts = await loop.run_in_executor(
-                    None, self._decode_sync, tokens, temps, top_ps,
-                    top_ks, block, first_ctx)
+                # 2) a BLOCK of decode steps for every slot that has
+                # tokens left, ENQUEUED BEFORE THE BLOCK IN FLIGHT IS
+                # READ BACK: the device runs programs in the order they
+                # were enqueued, so block n + 1 starts the moment block
+                # n ends, and preparing it, the thread hops, its launch,
+                # the copy back of block n's tokens, their accounting,
+                # their emission and the turn of the event loop in
+                # which the streams' consumers wake all run behind the
+                # device, not in front of it. Never more than one block
+                # ahead: a request admitted at this turn's head has its
+                # prefill enqueued behind the block in flight and joins
+                # the block enqueued now.
+                # What block n + 1 needs of block n it takes without the
+                # host: sampling is on-device (lm.sample) and its first
+                # tokens are block n's last row, left on the device
+                # (kvcache.carry_tokens); write positions are host
+                # arithmetic (block n's steps added); the tables are
+                # known because admission reserved the whole horizon.
+                # One host sync per block, only token ids come back.
+                # Block size is bounded by each slot's remaining budget
+                # AFTER the block in flight, so no request over-runs
+                # max_new_tokens or the cache, and a slot whose budget
+                # ends inside block n is not in block n + 1.
+                # A slot that ends where the host could not foresee it
+                # (eos or a stop sequence mid-block, a deadline, a
+                # failure) wastes its remaining steps of block n and
+                # rides out block n + 1 (discarded at emit, slot freed
+                # at the sync): the batch's throughput is worth more
+                # than the waste, the headroom bounds keep its cache
+                # writes inside its own blocks, and _free_kv holds
+                # those blocks until block n + 1 has been read back.
+                left = {i: self._left(i, fl) for i in active}
+                new = None
+                if any(n > 0 for n in left.values()):
+                    with phase("decode.prepare") as prep:
+                        new = self._prepare(left, fl)
+                    new.t_prep = prep.t1
+                # read back the block that was in flight; with none,
+                # the new one stays in flight for the next turn, unless
+                # a slot's drafter needs its tokens on the host now
+                back = fl
+                if back is None and any(
+                        self._slots[i].drafter is not None for i in active):
+                    back = new
+                if new is None and back is None:
+                    raise RuntimeError(
+                        "a live slot with no step left and nothing in "
+                        "flight: it should have finished at its emit")
+                await loop.run_in_executor(
+                    None, self._decode_sync, new, fl, back)
+                self._inflight = new if back is not new else None
+                if back is None:
+                    continue
                 with phase("decode.account") as acc:
                     # what the block spent between the two threads
-                    t_disp, t_back = self._dev_span
                     self._m["decode_hop"].observe(
-                        (t_disp - prep.t1) + (acc.t0 - t_back))
-                    self._account_block(lens, block, counts)
-                    self._record_block(len(active), block, member_traces,
-                                       first_ctx, block=block)
+                        (back.t_disp - back.t_prep)
+                        + (acc.t0 - back.t_back))
+                    self._account_block(back.lens, back.steps,
+                                        back.counts)
+                    # the block's own window opens where its
+                    # predecessor's closed, if it was enqueued earlier:
+                    # two blocks' windows never overlap
+                    self._dev_span = (max(back.t_disp, self._dev_span[1]),
+                                      back.t_back)
+                    self._record_block(
+                        len(back.reqs), back.steps, back.member_traces,
+                        back.first_ctx, block=back.steps)
+                    # it has run: nothing enqueued names the blocks of
+                    # the requests that ended under it any more
+                    for release in back.held:
+                        release()
                 with phase("emit"):
-                    for step in range(block):
-                        for i in active:
-                            r = self._slots[i]
-                            if r is None:  # finished earlier this block
+                    for step in range(back.steps):
+                        for i, r in back.reqs.items():
+                            # finished earlier this block, or before it
+                            # (its rows are waste), or the slot is
+                            # another request's by now
+                            if self._slots[i] is not r:
                                 continue
-                            self._emit_token(r, int(out[step, i]), i)
+                            self._emit_token(r, int(back.out[step, i]), i)
                     # whoever is still in its slot waits for the next
-                    # block from the moment this one was read back
-                    self._gap_from = self._dev_span[1] if any(
-                        self._slots[i] is not None for i in active) \
-                        else None
+                    # block from the moment this one was read back,
+                    # unless that block is already enqueued
+                    self._gap_from = back.t_back if (
+                        self._inflight is None and any(
+                            self._slots[i] is r
+                            for i, r in back.reqs.items())) else None
                     self._gap_admit = 0.0
                 with phase("yield"):
                     await asyncio.sleep(0)
@@ -996,23 +1100,95 @@ class LLMEngine:
                 self._fail(self._waiting.get_nowait(), None, e)
             raise
         finally:
+            self._discard_inflight()
             for i, r in enumerate(self._slots):
                 if r is not None:
                     self._finish(r, i)
 
-    def _set_aside(self, active: List[int], block: int) -> List[int]:
-        """Before a decode block of ``block`` steps: a model with
-        window layers gets the window-layer blocks the block writes and
-        gives back the ones each sequence's window has passed
-        (kvcache.advance_window); then what the pool holds by layer
-        kind is observed, for any model. Returns the positions each
-        active slot holds as the block starts (prompt + emitted)."""
-        lens = []
-        for i in active:
-            r = self._slots[i]
-            n = len(r.tokens) + len(r.out)
-            lens.append(n)
-            if self._kinds:
+    def _discard_inflight(self) -> None:
+        """The loop is ending (a failure, stop()): the block in flight
+        is never read back. Its results are dropped and the releases it
+        held are made now: whatever touches those blocks next is
+        enqueued behind it."""
+        blk, self._inflight = self._inflight, None
+        if blk is not None:
+            for release in blk.held:
+                release()
+
+    def _left(self, slot: int, fl: Optional[_Block]) -> int:
+        """Decode steps ``slot``'s request can still take once the
+        block in flight (``fl``) has run: its budget and the cache's,
+        less what it has emitted and what ``fl`` will emit for it."""
+        r = self._slots[slot]
+        have = len(r.out)
+        if fl is not None and fl.reqs.get(slot) is r:
+            have += fl.steps
+        return min(r.max_new_tokens - have,
+                   self.max_len - len(r.tokens) - have)
+
+    def _prepare(self, left: dict, fl: Optional[_Block]) -> _Block:
+        """The loop's ``decode.prepare`` phase: the next block, over
+        the slots of ``left`` (slot -> steps left after the block in
+        flight) that have steps left. Its size is the smallest of them
+        rounded down to a power of two (bounded variants); a slot of
+        ``fl`` starts from ``fl``'s last row and ``fl.steps`` positions
+        further on, any other from the host's last token."""
+        slots = [i for i, n in left.items() if n > 0]
+        block = min(self.steps_per_sync, *(left[i] for i in slots))
+        block = 1 << (block.bit_length() - 1)       # pow2, rounded down
+        n = self.max_slots
+        tokens = np.zeros((n,), np.int32)
+        keep = np.zeros((n,), bool)
+        lengths = np.zeros((n,), np.int32)
+        temps = np.zeros((n,), np.float32)
+        top_ps = np.ones((n,), np.float32)
+        top_ks = np.zeros((n,), np.int32)
+        reqs, lens = {}, []
+        for i in slots:
+            r = reqs[i] = self._slots[i]
+            at = len(r.tokens) + len(r.out)
+            if fl is not None and fl.reqs.get(i) is r:
+                keep[i] = True
+                at += fl.steps
+            else:
+                tokens[i] = r.out[-1]
+            lens.append(at)
+            # the last emitted token's KV lands in the block's first
+            # step; empty slots write into the trash block
+            lengths[i] = at - 1
+            temps[i] = r.temperature
+            top_ps[i] = r.top_p
+            top_ks[i] = r.top_k
+        member_traces, first_ctx = self._members(slots)
+        self._set_aside(reqs, lens, block)
+        # the tables as they are NOW, copied: admission and _free_kv
+        # write the live ones while this block is in flight. A slot that
+        # is not in the block (idle, or ending inside the block in
+        # flight) writes to the trash block.
+        out = [i for i in range(n) if i not in reqs]
+        tables = self._tables.copy()
+        tables[out] = kvcache.TRASH
+        if self._kinds:
+            wtables = self._wtables.copy()
+            wtables[out] = kvcache.TRASH
+            tables = {kvcache.GLOBAL: tables, kvcache.WINDOW: wtables}
+        return _Block(block, reqs, lens, tokens, keep, lengths, tables,
+                      temps, top_ps, top_ks, member_traces, first_ctx)
+
+    def _set_aside(self, reqs: dict, lens: List[int], block: int) -> None:
+        """Before a decode block of ``block`` steps over ``reqs`` (slot
+        -> request; ``lens`` the positions each holds as the block
+        starts: prompt + emitted + what the block in flight emits): a
+        model with window layers gets the window-layer blocks the block
+        writes and gives back the ones each sequence's window has
+        passed (kvcache.advance_window); then what the pool holds by
+        layer kind is observed, for any model. The block in flight may
+        still read a ring block given back here: whatever writes it
+        next (this block, through the sequence that takes it, or a
+        later prefill) is enqueued behind the block in flight, and the
+        device runs them in that order."""
+        if self._kinds:
+            for (i, r), n in zip(reqs.items(), lens):
                 self._wtables[i] = self._kv.advance_window(
                     r.seq, n - 1, block)
         live = sum(lens)
@@ -1027,7 +1203,6 @@ class LLMEngine:
         self._m["kv_used_bytes"].observe(
             sum(n * self._block_bytes[kind] for kind, n in used.items()))
         self._m["kv_live_tokens"].observe(live)
-        return lens
 
     def _account_block(self, lens: List[int], block: int,
                        counts: Optional[dict]) -> None:
@@ -1325,71 +1500,89 @@ class LLMEngine:
             "engine", "prefill", r.trace, r.trace.span_id, w0, w1,
             tokens=len(r.tokens))
 
-    def _decode_sync(self, tokens: np.ndarray, temps: np.ndarray,
-                     top_ps: np.ndarray, top_ks: np.ndarray,
-                     block: int,
-                     trace_ctx: Optional[tracing.TraceContext] = None
-                     ) -> tuple:
-        """Returns the (block, slots) int32 sampled tokens and the
-        expert layers' per-step counts (None for a model without
-        expert layers). ``trace_ctx``
-        (the first member trace of the batch) is bound while the block
-        runs so a decode-path XLA compile — a new block-size variant,
-        a filter toggle — stamps a member's trace id onto its
-        dev:compile span instead of vanishing into unattributed time."""
-        if trace_ctx is None:
-            return self._decode_impl(tokens, temps, top_ps, top_ks,
-                                     block)
-        tok = tracing.set_request_context(trace_ctx)
+    def _decode_sync(self, new: Optional[_Block], fl: Optional[_Block],
+                     back: Optional[_Block]) -> None:
+        """One turn's device work (executor thread): enqueue ``new``
+        behind ``fl``, the block in flight, then read ``back`` back
+        (``fl``, or ``new`` itself when it may not stay in flight).
+        The first member trace of the batch is bound meanwhile so a
+        decode-path XLA compile — a new block-size variant, a filter
+        toggle — stamps a member's trace id onto its dev:compile span
+        instead of vanishing into unattributed time."""
+        ctx = (new or back).first_ctx
+        tok = tracing.set_request_context(ctx) if ctx is not None else None
         try:
-            return self._decode_impl(tokens, temps, top_ps, top_ks,
-                                     block)
+            if new is not None:
+                self._dispatch(new, fl)
+            if back is not None:
+                self._readback(back)
         finally:
-            tracing.reset_request_context(tok)
+            if tok is not None:
+                tracing.reset_request_context(tok)
 
-    def _decode_impl(self, tokens: np.ndarray, temps: np.ndarray,
-                     top_ps: np.ndarray, top_ks: np.ndarray,
-                     block: int) -> tuple:
+    def _dispatch(self, blk: _Block, fl: Optional[_Block]) -> None:
+        """Enqueue ``blk`` (asynchronous: the call returns once the
+        program is launched). ``fl`` is the block in flight, not read
+        back yet: the device starts ``blk`` the moment it ends."""
         jax, jnp = _jx()
         with self._phase("decode.dispatch") as disp:
-            if self._gap_from is not None:
+            # keep is set only for slots of the block in flight
+            carried = bool(blk.keep.any())
+            if carried:
+                # a request of the block in flight goes on in this
+                # one, enqueued before that one is read back: the
+                # slot does not stall between them
+                self._m["gap"].observe(0.0)
+                self._m["gap_admit"].observe(0.0)
+            elif fl is None and self._gap_from is not None:
                 # a request of the last block has been waiting for
                 # this one since that block was read back
                 self._m["gap"].observe(disp.t0 - self._gap_from)
                 self._m["gap_admit"].observe(self._gap_admit)
-            self._step += block
+            self._m["decode_ahead"].observe(0 if fl is None else 1)
+            blk.t_disp = disp.t0
+            self._step += blk.steps
             key = jax.random.fold_in(self._key, self._step)
             # The top-p/top-k filters cost two O(V log V) vocab sorts
             # per decode step: only pay them when some ACTIVE request
             # enabled a filter (None compiles the plain sampler — one
             # extra jit variant, bounded).
-            filters_on = bool((top_ps < 1.0).any() or (top_ks > 0).any())
-            tp = jnp.asarray(top_ps) if filters_on else None
-            tk = jnp.asarray(top_ks) if filters_on else None
-            # per-slot write positions are host-derived (prompt +
-            # emitted - 1: the last emitted token's KV lands this
-            # step); empty slots write into the trash block
-            lengths = np.zeros((self.max_slots,), np.int32)
-            for i, r in enumerate(self._slots):
-                if r is not None:
-                    lengths[i] = len(r.tokens) + len(r.out) - 1
-            tables = jnp.asarray(self._tables)
-            if self._kinds:
-                tables = {kvcache.GLOBAL: tables,
-                          kvcache.WINDOW: jnp.asarray(self._wtables)}
-            out, self._pool, counts = kvcache.decode_steps_program(
-                self._pool, impl=self._kv_impl,
-                interpret=self._kv_interpret, mesh=self.mesh,
-                axis=self.tensor_axis)(
-                self.params, self._pool, tables, jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(temps), key, self.cfg,
-                block, tp, tk)
+            filters_on = bool((blk.top_ps < 1.0).any()
+                              or (blk.top_ks > 0).any())
+            tp = jnp.asarray(blk.top_ps) if filters_on else None
+            tk = jnp.asarray(blk.top_ks) if filters_on else None
+            # first tokens: the block in flight's last row for the
+            # slots that go on from it, never seen by the host; what
+            # the host holds for the others. Either way they arrive
+            # committed to one sharding: one compiled program a size.
+            if carried:
+                tokens = kvcache.carry_tokens(
+                    fl.last, blk.tokens, blk.keep, self._tok_sharding)
+            else:
+                tokens = jax.device_put(blk.tokens, self._tok_sharding)
+            tables = blk.tables
+            tables = {k: jnp.asarray(t) for k, t in tables.items()} \
+                if self._kinds else jnp.asarray(tables)
+            blk.out, self._pool, blk.counts, blk.last = \
+                kvcache.decode_steps_program(
+                    self._pool, impl=self._kv_impl,
+                    interpret=self._kv_interpret, mesh=self.mesh,
+                    axis=self.tensor_axis)(
+                    self.params, self._pool, tables,
+                    jnp.asarray(blk.lengths), tokens,
+                    jnp.asarray(blk.temps), key, self.cfg, blk.steps,
+                    tp, tk)
             self._kvm["attn_steps"].inc(
-                block, tags={"impl": self._kv_impl})
+                blk.steps, tags={"impl": self._kv_impl})
+
+    def _readback(self, blk: _Block) -> None:
+        """Wait for ``blk`` and copy its tokens (and the expert layers'
+        counts, which come with them) to the host. A device error in
+        the block surfaces here and fails the live requests."""
+        jax, _ = _jx()
         with self._phase("decode.readback") as back:
-            out, counts = jax.device_get((out, counts))
-        self._dev_span = (disp.t0, back.t1)
-        return out, counts
+            blk.out, blk.counts = jax.device_get((blk.out, blk.counts))
+        blk.t_back = back.t1
 
     async def _spec_round(self, loop, active: List[int],
                           drafts: dict) -> None:
@@ -1576,8 +1769,21 @@ class LLMEngine:
         """Return a finished/failed request's blocks to the pool; its
         full prompt+output block chain enters the prefix index (a
         follow-up conversation turn extends the same chain). The
-        slot's table row reverts to trash so post-finish garbage
-        writes can't land in reallocated blocks."""
+        slot's table row reverts to trash at once, so no block prepared
+        from here on names them.
+
+        The block IN FLIGHT may: it was enqueued, with a copy of the
+        tables, before the host could know the request would end (eos
+        or a stop sequence inside the block before it, a deadline, a
+        failure), and it writes one position a step past the request's
+        end through that copy. So the release, to the pool and to the
+        prefix index alike, WAITS until that block has been read back:
+        until then the blocks are the request's own, nothing can be
+        handed them or be served them as a cached prefix, and the
+        wasted writes land where nobody reads. Nothing is in flight, or
+        the block in flight does not hold the request (its budget ended
+        inside the block before: foreseen, it was left out): released
+        now."""
         if r.kv_alloc is None:
             return
         # kv_written gates the prefix-cache insert: a request that
@@ -1591,13 +1797,21 @@ class LLMEngine:
         stream = list(r.tokens) + list(r.out)
         if r.out:
             stream = stream[:-1]
-        self._kv.free_seq(r.seq, stream, cache=r.kv_written)
         r.kv_alloc = None
         if slot is not None:
             self._tables[slot] = kvcache.TRASH
             if self._kinds:
                 self._wtables[slot] = kvcache.TRASH
-        self._kv_account()
+        seq, cache = r.seq, r.kv_written
+
+        def release():
+            self._kv.free_seq(seq, stream, cache=cache)
+            self._kv_account()
+        fl = self._inflight
+        if fl is not None and any(x is r for x in fl.reqs.values()):
+            fl.held.append(release)
+        else:
+            release()
 
     def _finish(self, r: _Request, slot: Optional[int]):
         # the engine's own measure of a token, from ONE pair of stamps

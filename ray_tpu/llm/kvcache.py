@@ -1026,7 +1026,7 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
     variant, cached in _JITS. (The program itself, decode_steps_program,
     also returns the expert layers' counts a step; the engine reads
     them with the tokens.)"""
-    outs, pool, _ = decode_steps_program(
+    outs, pool, _, _ = decode_steps_program(
         pool, impl=impl, interpret=interpret, mesh=mesh, axis=axis)(
         params, pool, tables, lengths, tokens, temps, key, cfg, n,
         top_ps, top_ks)
@@ -1037,10 +1037,13 @@ def decode_steps_program(pool, *, impl="gather", interpret=False,
                          mesh=None, axis="tensor"):
     """The jitted program behind paged_decode_steps for a pool of this
     geometry (arrays or their shapes), built once a variant. It
-    returns (tokens (n, slots), pool, counts): for a model with expert
-    layers ``{"routed", "local", "experts_hit"}`` (n,) int32 each, a
-    step's sums over its layers (models/moe.py serve_block), else
-    None."""
+    returns (tokens (n, slots), pool, counts, last): counts, for a
+    model with expert layers, ``{"routed", "local", "experts_hit"}``
+    (n,) int32 each, a step's sums over its layers (models/moe.py
+    serve_block), else None; ``last`` (slots,) is the final step's
+    row of tokens, the scan's carry: what the NEXT block starts from,
+    left on the device for an engine that enqueues that block before
+    it reads this one back (carry_tokens)."""
     impl = resolve_attn_impl(impl)
     key_ = ("paged_decode_steps", *_pool_key(pool), impl,
             bool(interpret), mesh, axis)
@@ -1061,11 +1064,31 @@ def decode_steps_program(pool, *, impl="gather", interpret=False,
                     impl=impl, interpret=interpret, mesh=mesh,
                     axis=axis)
                 return (pool, out), (out, counts)
-            (pool, _), (outs, counts) = _lax.scan(
+            (pool, last), (outs, counts) = _lax.scan(
                 body, (pool, tokens), jnp.arange(n, dtype=jnp.int32))
-            return outs, pool, counts
+            return outs, pool, counts, last
         fn = _JITS[key_] = paged_decode_steps
     return fn
+
+
+def carry_tokens(last, tokens, keep, sharding):
+    """The first tokens of a decode block that is enqueued before its
+    predecessor is read back: ``last`` (the predecessor's final row,
+    still on the device) where ``keep``, else the host's ``tokens`` (a
+    slot admitted since, or an idle one): (slots,) int32 under
+    ``sharding``, the one a block's tokens always arrive in, so both
+    ways of making them meet the same compiled decode program. One
+    program a sharding, whatever the predecessor's size."""
+    key_ = ("carry_tokens", sharding)
+    fn = _JITS.get(key_)
+    if fn is None:
+        jax, jnp = _jx()
+
+        @partial(jax.jit, out_shardings=sharding)
+        def carry_tokens(last, tokens, keep):
+            return jnp.where(keep, last, tokens)
+        fn = _JITS[key_] = carry_tokens
+    return fn(last, tokens, keep)
 
 
 def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,

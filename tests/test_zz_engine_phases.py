@@ -185,6 +185,74 @@ def test_every_loop_phase_is_observed_and_stream_lag_counts_tokens(
     assert 0 < spent <= wall
 
 
+@pytest.mark.parametrize("spec", [False, True])
+def test_a_block_in_flight_is_counted_once_a_block(tiny_model, spec,
+                                                   monkeypatch):
+    """PR 39. ``decode_ahead`` is observed once a block: 0 for the
+    first block after idle (nothing is in flight), 1 for a block
+    enqueued before its predecessor was read back. ``gap`` is still
+    observed once a block that carries a request on and is never
+    negative: 0 when the block went out ahead. No two blocks' windows
+    (``batch`` spans, device windows) overlap. An engine whose slots
+    hold drafters (``spec``) leaves nothing in flight: every block 0,
+    every gap the stall it was before."""
+    seen = {"llm_decode_ahead_size": [], "llm_decode_gap_s": [],
+            "llm_decode_gap_admit_s": [], "llm_tpot_s": []}
+    real = M.Histogram.observe
+
+    def observe(self, value, *a, **kw):
+        if self.name in seen:
+            seen[self.name].append(value)
+        return real(self, value, *a, **kw)
+    monkeypatch.setattr(M.Histogram, "observe", observe)
+    tid = "3a" * 16
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False, spec=spec)
+        before = _totals()
+        tok = tracing.set_request_context(
+            tracing.TraceContext(tid, tracing.new_span_id()))
+        try:
+            # 12 steps in blocks of 4, 4, 4; the loop parks; 5 in 4, 1
+            await eng.generate([3, 5, 7, 11], max_new_tokens=13)
+            await asyncio.sleep(0.05)
+            await eng.generate([2, 9, 4], max_new_tokens=6)
+        finally:
+            tracing.reset_request_context(tok)
+        await eng.stop()
+        return _delta(before, _totals())
+
+    events.clear()
+    d = asyncio.run(go())
+    ahead = seen["llm_decode_ahead_size"]
+    gaps, admits = seen["llm_decode_gap_s"], seen["llm_decode_gap_admit_s"]
+    assert d["decode_ahead_count"] == d["block_steps_count"] == len(ahead)
+    assert len(gaps) == len(admits)
+    assert all(0 <= a <= g for a, g in zip(admits, gaps))
+    spans = sorted((e["ts"], e["dur"], e["block"]) for e in events.dump()
+                   if e.get("name") == "batch" and tid in e["links"])
+    wins = sorted((e["ts"], e["dur"]) for e in events.dump()
+                  if e.get("cat") == "device_window"
+                  and e.get("seg") == "decode")
+    # the blocks' (and verify rounds') windows: none overlaps the next
+    assert spans and [(t, dur) for t, dur, _ in spans] == wins
+    assert all(t0 + dur <= t1 + 1e-9 for (t0, dur, _), (t1, _, _)
+               in zip(spans, spans[1:]))
+    if spec:
+        # however the drafters split the work into rounds and blocks
+        assert ahead and not any(ahead)
+        assert gaps and all(g > 0 for g in gaps)
+    else:
+        assert ahead == [0, 1, 1, 0, 1] and d["block_steps_sum"] == 17
+        # one gap a block that carries a request on, none a stall
+        assert gaps == [0, 0, 0]
+        # llm_tpot_s is each block's own window over its steps
+        assert len(spans) == 5
+        assert sorted(seen["llm_tpot_s"]) == pytest.approx(
+            sorted(dur / n for _, dur, n in spans), abs=1e-6)
+    events.clear()
+
+
 def test_spec_round_uses_the_verify_phases(tiny_model):
     tid = "5e" * 16
 

@@ -251,6 +251,70 @@ def test_the_engine_serves_it_and_frees_what_the_window_passed(fam, params):
     assert engine_metrics()["kv_blocks_window"] is not None
 
 
+@pytest.mark.parametrize("stagger_s", [0.0, 0.01])
+def test_window_rings_with_a_block_in_flight(fam, params, stagger_s):
+    """PR 39: the engine enqueues block n + 1 before it reads back
+    block n, so a window layer's ring is advanced (blocks given back,
+    the next ones taken) for a block whose predecessor still runs, and
+    a request that ends on an eos mid-block rides out one more block
+    with its ring held. Five requests through four slots, prompts below
+    and past the window, budgets that are no power of two, one ended by
+    an eos inside a block: every reply is the reference's greedy
+    choice, and every block of both pools comes back."""
+    from ray_tpu.llm.engine import engine_metrics
+    rng = np.random.default_rng(1)
+    prompts = [[int(t) for t in rng.integers(1, 256, n)]
+               for n in (20, 100, 70, 33, 50)]
+    news = (21, 13, 27, 19, 11)
+
+    def reference(p, o):
+        logits = np.asarray(fam.forward(
+            params, jnp.asarray([p + o], jnp.int32), _cfg()))[0]
+        return [int(t) for t in np.argmax(logits[len(p) - 1:-1], axis=-1)]
+
+    def ahead():
+        h = engine_metrics()["decode_ahead"]
+        return (sum(h._sums.values()),
+                sum(sum(c) for c in h._counts.values()))
+
+    async def one(eng, i, p, kw):
+        await asyncio.sleep(i * stagger_s)
+        return await eng.generate(p, **kw)
+
+    async def run(kws):
+        eng = _engine(params)
+        s0, n0 = ahead()
+        outs = await asyncio.gather(*[
+            one(eng, i, p, kw) for i, (p, kw) in enumerate(zip(prompts, kws))])
+        for _ in range(200):        # the block that rode out an eos
+            if eng._inflight is None:
+                break
+            await asyncio.sleep(0.005)
+        stats = eng.stats
+        await eng.stop()
+        s1, n1 = ahead()
+        return [o["tokens"] for o in outs], stats, s1 - s0, n1 - n0
+
+    plain, stats, went_ahead, blocks = asyncio.run(
+        run([{"max_new_tokens": n} for n in news]))
+    for p, o in zip(prompts, plain):
+        assert o == reference(p, o)
+    assert went_ahead / blocks > 0.5, (went_ahead, blocks)
+    assert stats["blocks_used_window"] == 0 and stats["blocks_used"] == 0
+    assert stats["window_blocks_freed"] >= 4
+    # the third request again, ended by an eos inside a block of four
+    # (reply index 1-4 is the first block, 5-8 the second, ...)
+    o = plain[2]
+    at = next(i for i in range(2, len(o)) if i % 4 and o.index(o[i]) == i)
+    kws = [{"max_new_tokens": n} for n in news]
+    kws[2]["eos_id"] = o[at]
+    cut, stats, _, _ = asyncio.run(run(kws))
+    assert cut[2] == o[:at + 1]
+    assert [c for i, c in enumerate(cut) if i != 2] == \
+        [c for i, c in enumerate(plain) if i != 2]
+    assert stats["blocks_used_window"] == 0 and stats["blocks_used"] == 0
+
+
 def _sums(*keys):
     from ray_tpu.llm.engine import engine_metrics
     out = {}

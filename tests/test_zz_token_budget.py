@@ -142,40 +142,55 @@ def test_a_decode_block_leaves_its_phases_in_order_and_flat(
         elif kind == "exit":
             assert open_ == name
             open_ = None
+    # 9 decode steps in blocks of 4, 4 and 1, each enqueued before its
+    # predecessor is read back (PR 39): the first turn only prepares
+    # and dispatches; every later one prepares and dispatches the next
+    # block, THEN reads back, accounts and emits the one in flight and
+    # yields; the last has nothing left to enqueue
+    path = [name for kind, name, _ in recorded if kind == "enter"
+            and name.split(".", 1)[1] in (
+                "decode.prepare", "decode.dispatch", "decode.readback",
+                "decode.account", "emit", "yield")]
+    path = path[path.index("engine.decode.prepare"):]
+    ahead = ["engine." + p for p in ("decode.prepare", "decode.dispatch")]
+    behind = ["engine." + p for p in (
+        "decode.readback", "decode.account", "emit", "yield")]
+    assert path == ahead + (ahead + behind) * 2 + behind, path
     blocks = _blocks(recorded)
-    assert len(blocks) >= 3         # 9 decode steps in blocks of <= 4
-    want = ["engine." + p for p in (
-        "decode.prepare", "decode.dispatch", "decode.readback",
-        "decode.account", "emit", "yield")]
-    for b in blocks:
-        entered = [name for kind, name, _ in b if kind == "enter"]
-        assert entered[:6] == want, entered
+    assert len(blocks) == 3
+    for b in blocks[1:]:
         # every record of the block's counters and of _record_block
-        # lies between decode.account's two stamps
-        a0 = b.index(next(e for e in b if e[:2] == (
-            "enter", "engine.decode.account")))
-        a1 = b.index(next(e for e in b if e[:2] == (
-            "exit", "engine.decode.account")))
+        # lies between a decode.account's two stamps
+        spans = [(b.index(e0), b.index(e1)) for e0, e1 in zip(
+            [e for e in b if e[:2] == ("enter", "engine.decode.account")],
+            [e for e in b if e[:2] == ("exit", "engine.decode.account")])]
         records = [i for i, e in enumerate(b) if e[0] == "record"]
-        assert records and all(a0 < i < a1 for i in records), b
+        assert records and all(
+            any(a0 < i < a1 for a0, a1 in spans) for i in records), b
         names = {b[i][1] for i in records}
         assert {"block_steps", "slot_steps", "ctx_tokens", "batch",
                 "tpot", "decode_hop", "record_batch_span",
                 "record_device_window"} <= names
+    assert not [e for e in blocks[0] if e[0] == "record"]
     events.clear()
 
 
 def test_no_statement_of_the_block_path_lies_outside_a_phase():
     """Between ``_decode_sync``'s return and the ``yield`` phase the
-    loop's source has ``with phase(...)`` blocks only."""
+    loop's source has ``with phase(...)`` blocks only, and the two
+    lines that keep a block in flight (PR 39): which block that is now,
+    and the turn's end when there is none to read back."""
     src = inspect.getsource(engine_mod.LLMEngine._run)
     tail = src[src.index("self._decode_sync"):]
     tail = tail[tail.index("\n") + 1:tail.index('with phase("yield")')]
-    lines = [ln for ln in tail.splitlines()[1:] if ln.strip()]
+    lines = [ln for ln in tail.splitlines() if ln.strip()]
     depth = len(lines[0]) - len(lines[0].lstrip())
     top = [ln.strip() for ln in lines
            if len(ln) - len(ln.lstrip()) == depth]
-    assert top and all(ln.startswith("with phase(") for ln in top), top
+    assert top[:2] == ["self._inflight = new if back is not new else None",
+                       "if back is None:"], top
+    assert top[2:] and all(
+        ln.startswith("with phase(") for ln in top[2:]), top
 
 
 # --- (ii) the engine's own measure of a token --------------------------------
@@ -210,19 +225,24 @@ def test_request_tpot_is_the_block_windows_and_gaps_over_the_steps(
     assert len(gen) == 1 and gen[0]["tokens"] == new
     assert gen[0]["tpot_s"] == pytest.approx(tpot, rel=1e-9)
     # first dispatch's start to last read-back's end is every block's
-    # window and every gap between two blocks
+    # window: each block is enqueued before its predecessor is read
+    # back (PR 39), so the windows touch and no gap lies between them
     disp = [t for kind, name, t in recorded
             if (kind, name) == ("enter", "engine.decode.dispatch")]
     back = [t for kind, name, t in recorded
             if (kind, name) == ("exit", "engine.decode.readback")]
+    assert len(disp) == len(back) == 3      # 12 steps in blocks of 4
     span = back[-1] - disp[0]
-    longest = max(b1 - d0 for d0, b1 in zip(disp, back)) + max(
-        [d1 - b0 for b0, d1 in zip(back, disp[1:])] or [0.0])
+    longest = max(b1 - d0 for d0, b1 in zip(disp, back))
     assert abs(tpot * (new - 1) - span) <= longest
-    # the gaps the engine observed are the ones between those blocks
-    assert d["gap_count"] == len(disp) - 1
-    assert d["gap_sum"] == pytest.approx(
-        sum(d1 - b0 for b0, d1 in zip(back, disp[1:])), abs=1e-6)
+    assert all(d1 < b0 for b0, d1 in zip(back, disp[1:]))
+    # one gap observation a block that carries a request on, none of
+    # them a stall; the first block after idle went out with nothing in
+    # flight, the others ahead of their predecessors' read-backs
+    assert d["gap_count"] == d["gap_admit_count"] == len(disp) - 1
+    assert d["gap_sum"] == d["gap_admit_sum"] == 0.0
+    assert d["decode_ahead_count"] == len(disp)
+    assert d["decode_ahead_sum"] == len(disp) - 1
     events.clear()
 
 
