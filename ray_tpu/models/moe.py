@@ -11,14 +11,22 @@ the ``k`` largest of s + b choose the experts (b the router's selection
 bias, a parameter that never enters a gate), the gates are the chosen s,
 renormalised when ``norm_topk_prob``, times ``routed_scaling``. Then a stable sort
 of the tokens x k assignments by expert and a count give the row order and
-the group sizes; the rows are gathered in that order, go through three
-grouped matmuls (gate, up, down: ``ops/pallas/grouped_matmul.py`` on a
-TPU, ``jax.lax.ragged_dot`` elsewhere), and come back weighted by their
-gate and summed per token. Every token reaches all ``k`` of its experts
-whatever the load: there is no capacity and nothing is dropped, and the
-cost follows tokens x k, not experts x capacity. Dispatch and combine are
-gathers in both directions (the backward of a gather by a permutation is
-the gather by its inverse), so no scatter runs on the device.
+the group sizes; the rows, in that order, go through three grouped
+matmuls (gate, up, down: ``ops/pallas/grouped_matmul.py`` on a TPU,
+``jax.lax.ragged_dot`` elsewhere), and come back weighted by their gate and
+summed per token. Every token reaches all ``k`` of its experts whatever the
+load: there is no capacity and nothing is dropped, and the cost follows
+tokens x k, not experts x capacity. Dispatch and combine are gathers in
+both directions (the backward of a gather by a permutation is the gather
+by its inverse), so no scatter runs on the device, through two
+``custom_vjp``s. Of the six (T*k, d) gathers a train step's layer could
+hold, three are written (PR 43): with a layer's own weights and the kernel
+(``_gate_up``), gate and up read y's rows BY ID in one call, forward,
+recomputed and for the weights' gradients, so ``_dispatch``'s copy exists
+only under ``ragged_dot`` and in serving; ``_combine``'s backward gathers
+the cotangent's rows once and takes the gates' gradient from them in
+sorted order. Left: ``_combine``'s forward (rows[inverse]), its backward
+(g[order // k]) and the rows' gradient going home (``_token_sums``).
 ``n_shared_experts`` adds one SwiGLU of ``n_shared_experts * ffn_dim`` that
 every token goes through, beside the routed sum.
 
@@ -396,11 +404,24 @@ def _rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
+def _token_of(order, tokens: int):
+    """The token (row of y) of each sorted assignment: ``order // k``."""
+    return order // (order.shape[0] // tokens)
+
+
+def _token_sums(g, inverse, tokens: int):
+    """The backward of reading y's rows in sorted order: the cotangent
+    rows ``g`` (T*k, d) brought back by the inverse permutation and summed
+    over each token's k, in float32 -> (T, d)."""
+    dy = _rows(g, inverse).reshape(tokens, -1, g.shape[1])
+    return dy.astype(jnp.float32).sum(1).astype(g.dtype)
+
+
 @jax.custom_vjp
 def _dispatch(y, order, inverse):
     """Rows of ``y`` (T, d) in sorted-assignment order (T*k, d): row ``p``
     is the token of assignment ``order[p]``."""
-    return _rows(y, order // (order.shape[0] // y.shape[0]))
+    return _rows(y, _token_of(order, y.shape[0]))
 
 
 def _dispatch_fwd(y, order, inverse):
@@ -409,11 +430,46 @@ def _dispatch_fwd(y, order, inverse):
 
 def _dispatch_bwd(res, g):
     inverse, tokens = res
-    dy = _rows(g, inverse).reshape(tokens, -1, g.shape[1])
-    return dy.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+    return _token_sums(g, inverse, tokens), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _gate_up(y, w_gate, w_up, order, inverse, group_sizes, interpret):
+    """``(x @ w_gate[e], x @ w_up[e])`` for the rows ``x = _dispatch(y)``
+    of every expert ``e``, with x never written: one kernel call fetches
+    each row tile of x from y by id and multiplies it by both weights
+    (``grouped_matmul.gmm_rows``). The backward reads y the same way for
+    the weights' gradients; the rows' gradient comes back in sorted order
+    and goes home as ``_dispatch``'s does."""
+    return grouped_matmul.gmm_rows(
+        y, _token_of(order, y.shape[0]), (w_gate, w_up), group_sizes,
+        interpret=interpret)
+
+
+def _gate_up_fwd(y, w_gate, w_up, order, inverse, group_sizes, interpret):
+    return (_gate_up(y, w_gate, w_up, order, inverse, group_sizes, interpret),
+            (y, w_gate, w_up, order, inverse, group_sizes))
+
+
+def _gate_up_bwd(interpret, res, douts):
+    y, w_gate, w_up, order, inverse, group_sizes = res
+    d_x = grouped_matmul.gmm_t(tuple(douts), (w_gate, w_up), group_sizes,
+                               interpret)
+    d_gate, d_up = grouped_matmul.tgmm(
+        y, tuple(douts), group_sizes, rows=_token_of(order, y.shape[0]),
+        interpret=interpret)
+    # the weights' gradients before anything reads the rows': left to
+    # itself the scheduler puts them after the attention's backward and
+    # recomputes both cotangents for them
+    d_x, d_gate, d_up = lax.optimization_barrier((d_x, d_gate, d_up))
+    return (_token_sums(d_x, inverse, y.shape[0]), d_gate, d_up, None, None,
+            None)
+
+
+_gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
 
 
 @jax.custom_vjp
@@ -433,30 +489,38 @@ def _combine_fwd(rows, gates, order, inverse):
 def _combine_bwd(res, g):
     rows, gates, order, inverse = res
     k = gates.shape[1]
-    d_rows = (_rows(g, order // k).astype(jnp.float32)
-              * _rows(gates.reshape(-1), order)[:, None])
-    mine = _rows(rows, inverse).reshape(*gates.shape, -1)
-    d_gates = jnp.einsum("tkd,td->tk", mine.astype(jnp.float32),
-                         g.astype(jnp.float32))
+    # ONE gather of g's rows, in sorted order, serves both gradients: a
+    # row's gate gradient is its dot with the row of ``rows`` beside it
+    # (read in the pass that scales it), and only those T*k numbers go
+    # back to token order
+    g_rows = _rows(g, order // k).astype(jnp.float32)
+    d_rows = g_rows * _rows(gates.reshape(-1), order)[:, None]
+    dots = jnp.sum(rows.astype(jnp.float32) * g_rows, axis=1)
+    d_gates = _rows(dots, inverse).reshape(gates.shape)
     return d_rows.astype(rows.dtype), d_gates, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _gmm_impl(cfg: MoEConfig) -> str:
+    impl = cfg.gmm_impl
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "ragged_dot"
+    if impl not in ("ragged_dot", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown gmm_impl: {cfg.gmm_impl!r}")
+    return impl
+
+
 def _grouped(x, w, group_sizes, cfg: MoEConfig, layer=None):
     """``w`` (E, k, n), or with ``layer`` the whole stack (L, E, k, n) of
     which the kernel reads layer ``layer`` in place (a slice of the stack
     handed to a custom call would be copied out first)."""
-    impl = cfg.gmm_impl
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ragged_dot"
+    impl = _gmm_impl(cfg)
     if impl == "ragged_dot":
         if layer is not None:
             w = lax.dynamic_index_in_dim(w, layer, keepdims=False)
         return lax.ragged_dot(x, w, group_sizes)
-    if impl not in ("pallas", "pallas_interpret"):
-        raise ValueError(f"unknown gmm_impl: {cfg.gmm_impl!r}")
     if layer is not None:
         return grouped_matmul.gmm_stacked(x, w, group_sizes, layer,
                                           impl == "pallas_interpret")
@@ -505,10 +569,19 @@ def _gated_sum(y, gates, order, inverse, group_sizes, w, cfg: MoEConfig,
     (``w["w_gate" | "w_up" | "w_down"]``: a layer's, or with ``layer`` the
     stacks), summed per token by ``gates``."""
     with jax.named_scope("moe.experts"):
-        x = _dispatch(y, order, inverse)                         # (T*k, d)
-        h = jax.nn.silu(_grouped(x, w["w_gate"], group_sizes, cfg, layer)) \
-            * _grouped(x, w["w_up"], group_sizes, cfg, layer)
-        rows = _grouped(h, w["w_down"], group_sizes, cfg, layer)
+        impl = _gmm_impl(cfg)
+        if layer is None and impl != "ragged_dot":
+            # a layer's own weights (the train step): the differentiable
+            # kernels, which read y's rows by id. The stack (serving, a
+            # few rows an expert) keeps the gathered copy: 3 MB a step.
+            gate, up = _gate_up(y, w["w_gate"], w["w_up"], order, inverse,
+                                group_sizes, impl == "pallas_interpret")
+        else:
+            x = _dispatch(y, order, inverse)                     # (T*k, d)
+            gate, up = (_grouped(x, w[name], group_sizes, cfg, layer)
+                        for name in ("w_gate", "w_up"))
+        rows = _grouped(jax.nn.silu(gate) * up, w["w_down"], group_sizes,
+                        cfg, layer)
     with jax.named_scope("moe.combine"):
         return _combine(rows, gates, order, inverse)
 

@@ -190,9 +190,24 @@ def _gmm_bwd(lhs, rhs, group_sizes):
         jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
 
 
+def _gate_up_by_id(y, w, group_sizes, order):
+    """Gate and up of the rows of y in sorted order, read by id, and the
+    three gradients (PR 43): one fused call forward, one for both
+    weights' gradients, one for the rows'."""
+    from ray_tpu.models import moe
+    inverse = jnp.argsort(order).astype(jnp.int32)
+
+    def loss(y, w_gate, w_up):
+        gate, up = moe._gate_up(y, w_gate, w_up, order, inverse,
+                                group_sizes, False)
+        return (gate.astype(jnp.float32) * up.astype(jnp.float32)).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(y, w, w)
+
+
 # the cell train-olmoe: 4 x 4096 tokens x 8 experts a token = 131,072
 # rows, 64 groups, hidden 2048, expert width 1024
 _ROWS, _EXPERTS, _HIDDEN, _WIDTH = 131072, 64, 2048, 1024
+_TOKENS = _ROWS // 8
 GMM_CASES = {
     "gmm_up": (_gmm, (_HIDDEN, _WIDTH), {"moe_gmm"}),
     "gmm_down": (_gmm, (_WIDTH, _HIDDEN), {"moe_gmm"}),
@@ -200,15 +215,20 @@ GMM_CASES = {
                    {"moe_gmm_t", "moe_gmm_drhs"}),
     "gmm_down_bwd": (_gmm_bwd, (_WIDTH, _HIDDEN),
                      {"moe_gmm_t", "moe_gmm_drhs"}),
+    # y (16,384 rows) and the 131,072 ids in place of the gathered lhs
+    "gate_up_by_id": (_gate_up_by_id, (_HIDDEN, _WIDTH),
+                      {"moe_gmm_rows", "moe_gmm_t", "moe_gmm_drhs_rows"}),
 }
 
 
 @pytest.mark.parametrize("name", sorted(GMM_CASES))
 def test_grouped_matmul_compiles_for_v5e(chip, name):
     fn, (k, n), names = GMM_CASES[name]
+    by_id = name.endswith("by_id")
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
-        ((_ROWS, k), jnp.bfloat16), ((_EXPERTS, k, n), jnp.bfloat16),
-        ((_EXPERTS,), jnp.int32))]
+        ((_TOKENS if by_id else _ROWS, k), jnp.bfloat16),
+        ((_EXPERTS, k, n), jnp.bfloat16), ((_EXPERTS,), jnp.int32),
+        *([((_ROWS,), jnp.int32)] if by_id else []))]
     compiled = jax.jit(fn).lower(*args).compile()
     bench = _bench_kernels()
     ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
@@ -218,10 +238,72 @@ def test_grouped_matmul_compiles_for_v5e(chip, name):
     for op in ops:
         assert bench.classify(op) == "unknown_kernel", op
     # moe_gmm__.1, transpose_jvp_moe_gmm_t__.1, ...
-    found = [re.search(r"moe_gmm(_drhs|_t)?(?=_|\.|$)", op["name"])
-             for op in ops]
+    found = [re.search(r"moe_gmm(_drhs_rows|_drhs|_rows|_t)?(?=_|\.|$)",
+                       op["name"]) for op in ops]
     assert all(found), [op["name"] for op in ops]
     assert sorted(m.group(0) for m in found) == sorted(names)
+    if by_id:
+        # 8 or 9 operands, the walk's and the ids of rank 1, rows of rank
+        # 2 (weights of rank 3): never the three or six rank-3 operands of
+        # a flash kernel nor the five of the paged one
+        for op in ops:
+            ranks = [len(dims) for _, dims in op["operands"]]
+            assert len(ranks) >= 8 and {1, 2} <= set(ranks), op
+        # nothing of the gathered copy's shape but the rows' gradient
+        big = [op["name"] for op, _ in _with_result_of(
+            compiled, {(_ROWS, _HIDDEN)})]
+        assert len(big) <= 2, big       # d_lhs's gather home, its reshape
+
+
+def _olmoe_layer_grad(chip):
+    """The train-olmoe cell's loss gradient, compiled: the program whose
+    scanned layer body the step runs."""
+    from ray_tpu.models import llama, moe
+    cfg = _cell_config("olmoe-1b-7b-train.json", "moe", gmm_impl="pallas",
+                       attn_impl="flash", attn_block_q=1024,
+                       attn_block_k=1024, logits_dtype="bfloat16",
+                       remat_policy="full")
+    params = _shapes_of(chip, jax.eval_shape(
+        lambda: moe.init_params(jax.random.PRNGKey(0), cfg)))
+    batch = {k: jax.ShapeDtypeStruct((4, 4096), jnp.int32, sharding=chip)
+             for k in ("tokens", "targets")}
+    was = llama._on_tpu
+    llama._on_tpu = lambda: True      # flash: the kernel, not the fallback
+    try:
+        return jax.jit(jax.grad(lambda p, b: moe.loss_fn(p, b, cfg))).lower(
+            params, batch).compile()
+    finally:
+        llama._on_tpu = was
+
+
+def test_the_olmoe_layer_writes_three_gathered_copies_not_six(chip):
+    """The engagement of PR 43's mechanism is static, so a count holds
+    it: of the six ``bf16[T*k, d]`` gathers a layer pass could write
+    (``models/moe.py``'s docstring), the compiled gradient holds the
+    combine's forward one, ONE combine gather in the backward (the
+    cotangent's rows; the gates' gradient is taken from them) and the rows'
+    gradient going home; y's rows are read by id, forward and
+    recomputed."""
+    compiled = _olmoe_layer_grad(chip)
+    text = compiled.as_text()
+    big = f"bf16[{_ROWS},{_HIDDEN}]"
+    gathers = [ln for ln in text.splitlines()
+               if re.match(r"\s*(ROOT )?%gather[\w.]* = " + re.escape(big),
+                           ln)]
+    where = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in gathers]
+    experts = [w for w in where if "moe.experts/gather" in w]
+    combine = [w for w in where if "moe.combine/gather" in w]
+    assert len(experts) + len(combine) == len(where), where
+    # only ``_token_sums``: in the backward, and not the recomputation
+    assert all("transpose(jvp" in w and "rematted" not in w
+               for w in experts), experts
+    assert len({w for w in experts}) == 1, experts
+    backward = {w for w in combine if "transpose(jvp" in w}
+    assert len(backward) <= 1 and len(set(combine) - backward) == 1, combine
+    names = {re.search(r"moe_gmm[a-z_]*?(?=_*\.|__)", op["name"]).group(0)
+             for op in _kernel_ops(compiled)
+             if op["class"] == "unknown_kernel"}
+    assert {"moe_gmm_rows", "moe_gmm_drhs_rows"} <= names, names
 
 
 # --- the decode programs hold the KV pool in place --------------------------

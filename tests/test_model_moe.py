@@ -47,12 +47,22 @@ def test_grads_flow_to_experts_and_router():
         assert np.abs(g).max() > 0, f"no gradient reached {name}"
 
 
-def test_expert_parallel_matches_single_device(mesh8):
+# the kernels read y's rows by id where a row is whole tiles (1024 float32
+# values); on the expert mesh half of a device's assignments are foreign
+# and sort into the tail, which is neither fetched nor multiplied
+BY_ID = dict(gmm_impl="pallas_interpret", dim=1024, n_heads=4, n_kv_heads=2,
+             dtype="float32")
+
+
+@pytest.mark.parametrize("kw", [{}, BY_ID], ids=["ragged_dot", "rows_by_id"])
+def test_expert_parallel_matches_single_device(mesh8, kw):
     del mesh8  # ensure the session platform is initialized
-    cfg = _cfg()
+    cfg = _cfg(**kw)
     params = moe.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
-                                cfg.vocab_size)
+    # by id a tile's starts are written out and traced one by one: 64
+    # assignments, not 256
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 8 if kw else 32),
+                                0, cfg.vocab_size)
     batch = {"tokens": tokens, "targets": tokens}
 
     ref = float(moe.loss_fn(params, batch, cfg))
@@ -61,6 +71,15 @@ def test_expert_parallel_matches_single_device(mesh8):
     with mesh:
         sharded = float(moe.loss_fn(params, batch, cfg, mesh))
     np.testing.assert_allclose(sharded, ref, rtol=2e-2)
+    if kw:
+        want = jax.grad(lambda p: moe.loss_fn(p, batch, cfg))(params)
+        with mesh:
+            got = jax.jit(jax.grad(lambda p: moe.loss_fn(
+                p, batch, cfg, mesh)))(params)
+        for name in ("w_gate", "w_up", "w_down", "router", "mlp_norm"):
+            g, w = (np.asarray(t["layers"][name], np.float32)
+                    for t in (got, want))
+            assert np.linalg.norm(g - w) < 1e-3 * np.linalg.norm(w), name
 
 
 def test_moe_train_step_on_expert_mesh():

@@ -5,6 +5,7 @@ the routing statistics in a step's metrics, and the benchmark's new
 entries with the cell's CPU rehearsal.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -277,6 +278,178 @@ def test_gmm_refuses_tiles_that_do_not_divide():
         gm._gmm_call(jnp.zeros((60, 128)), jnp.zeros((2, 128, 128)),
                      jnp.asarray([30, 30], jnp.int32), tiles=(16, 128, 128),
                      interpret=True)
+
+
+# --- rows read by id (PR 43) -----------------------------------------------
+
+# (group sizes, tokens T, assignments a token k, rows a tile): every id
+# repeats k times, as ``order // k`` does
+ROWS = {
+    "a_tile_straddles_groups": ([10, 23, 7, 24], 16, 4, 16),
+    "empty_groups": ([0, 40, 0, 24], 16, 4, 16),
+    "tail_rows_past_the_last_group": ([10, 0, 23, 7, 0, 8], 16, 4, 16),
+    "whole_tiles_in_the_tail": ([5, 9], 16, 4, 16),
+    "every_row_in_the_tail": ([0, 0, 0], 8, 4, 16),
+    "one_tile": ([5, 27], 8, 4, 32),
+}
+# a fetched row is whole (8, 128) tiles of 32-bit words
+WIDE = {"float32": (jnp.float32, 1024), "bfloat16": (jnp.bfloat16, 2048)}
+
+
+def _rows_case(case, dtype, k, n=128):
+    sizes, tokens, per, tm = ROWS[case]
+    m = tokens * per
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 6)
+    src = jax.random.normal(keys[0], (tokens, k), jnp.float32).astype(dtype)
+    rhs = tuple((jax.random.normal(key, (len(sizes), k, n), jnp.float32)
+                 * k ** -0.5).astype(dtype) for key in keys[1:3])
+    douts = tuple(jax.random.normal(key, (m, n), jnp.float32).astype(dtype)
+                  for key in keys[3:5])
+    rows = jax.random.permutation(
+        keys[5], jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), per))
+    return src, rows, rhs, douts, jnp.asarray(sizes, jnp.int32), tm
+
+
+@pytest.mark.parametrize("wide", sorted(WIDE))
+@pytest.mark.parametrize("case", sorted(ROWS))
+def test_rows_fetched_by_id_equal_the_gathered_call(case, wide):
+    """``gmm_rows`` and ``tgmm(rows=)`` against a gather and the id-less
+    kernels: the same products of the same operands, so bit for bit; one
+    rhs and two, one dout and two (the fused calls against two calls)."""
+    dtype, k = WIDE[wide]
+    src, rows, rhs, douts, gs, tm = _rows_case(case, dtype, k)
+    assert gm.row_words(k, dtype) == 8
+    x = src[rows]
+    want = [gm._gmm_call(x, r, gs, tiles=(tm, k, 128), interpret=True)
+            for r in rhs]
+    got = gm.gmm_rows(src, rows, rhs, gs, tiles=(tm, 128), interpret=True)
+    one, = gm.gmm_rows(src, rows, rhs[:1], gs, tiles=(tm, 128),
+                       interpret=True)
+    for g, w in zip((*got, one), (*want, want[0])):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+    # rows past the last group are zeros, whatever was fetched
+    assert not np.asarray(got[0][int(gs.sum()):], np.float32).any()
+    want = [gm.tgmm(x, d, gs, tiles=(tm, k, 128), interpret=True)
+            for d in douts]
+    got = gm.tgmm(src, douts, gs, rows=rows, tiles=(tm, k, 128),
+                  interpret=True)
+    one = gm.tgmm(src, douts[0], gs, rows=rows, tiles=(tm, k, 128),
+                  interpret=True)
+    for g, w in zip((*got, one), (*want, want[0])):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def test_a_row_that_is_no_whole_tile_is_gathered_first():
+    """Where a DMA cannot take a row (``row_words`` 0) the rows are
+    gathered and the id-less kernels run: the same values."""
+    assert gm.row_words(128, jnp.float32) == 0
+    assert gm.row_words(2048, jnp.float16) == 0     # only bf16 unpacks
+    src, rows, rhs, douts, gs, tm = _rows_case(
+        "tail_rows_past_the_last_group", jnp.float32, 128)
+    x = src[rows]
+    got = gm.gmm_rows(src, rows, rhs, gs, interpret=True)
+    for g, r in zip(got, rhs):
+        np.testing.assert_array_equal(g, gm._gmm_call(x, r, gs,
+                                                      interpret=True))
+    np.testing.assert_array_equal(
+        gm.tgmm(src, douts[0], gs, rows=rows, interpret=True),
+        gm.tgmm(x, douts[0], gs, interpret=True))
+
+
+def test_gmm_t_sums_its_pairs_before_the_one_rounding():
+    src, rows, rhs, douts, gs, tm = _rows_case(
+        "tail_rows_past_the_last_group", jnp.float32, 256)
+    both = gm.gmm_t(douts, rhs, gs, True)
+    each = sum(gm.gmm_t(d, r, gs, True) for d, r in zip(douts, rhs))
+    np.testing.assert_allclose(both, each, atol=1e-4)
+    assert not np.asarray(both[int(gs.sum()):]).any()
+    with pytest.raises(ValueError, match="does not contract"):
+        gm.gmm_t(douts, rhs[:1], gs, True)
+
+
+@pytest.mark.parametrize("first_expert", [0, 4], ids=["all_held", "a_slice"])
+def test_gate_up_by_id_values_and_every_gradient(first_expert):
+    """``_gate_up`` (the kernels, y's rows by id) against ``_dispatch`` and
+    two differentiable ``gmm`` calls (the gathered copy): values, and the
+    gradients of y and of both weights; float32, the same products in
+    another order of summation. With a slice of the experts held, half
+    the assignments sort into the tail."""
+    tokens, k, d, f, held = 16, 4, 1024, 128, 4
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    y = jax.random.normal(keys[0], (tokens, d), jnp.float32)
+    w_gate, w_up = ((jax.random.normal(key, (held, d, f), jnp.float32)
+                     * d ** -0.5) for key in keys[1:3])
+    experts = jax.random.randint(keys[3], (tokens, k), 0, 8)
+    _, order, inverse, sizes = moe._sort_by_expert(experts, first_expert,
+                                                   held)
+    weight = jax.random.normal(keys[4], (2, tokens * k, f), jnp.float32)
+
+    def by_id(y, w_gate, w_up):
+        gate, up = moe._gate_up(y, w_gate, w_up, order, inverse, sizes, True)
+        return jnp.sum(gate * weight[0]) + jnp.sum(jnp.sin(up) * weight[1])
+
+    def gathered(y, w_gate, w_up):
+        x = moe._dispatch(y, order, inverse)
+        gate, up = (gm.gmm(x, w, sizes, True) for w in (w_gate, w_up))
+        return jnp.sum(gate * weight[0]) + jnp.sum(jnp.sin(up) * weight[1])
+
+    got = jax.value_and_grad(by_id, (0, 1, 2))(y, w_gate, w_up)
+    want = jax.value_and_grad(gathered, (0, 1, 2))(y, w_gate, w_up)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert _rel(g, w) < 1e-5
+
+
+def test_combine_backward_gathers_once_and_matches_the_einsum():
+    """``_combine``'s ``d_rows`` and ``d_gates`` against ``jax.grad`` of
+    the plain form (rows back in token order, an einsum with the gates),
+    in float32: the gates' gradient is taken in sorted order from the one
+    gather of the cotangent's rows."""
+    tokens, k, d = 24, 4, 64
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    rows = jax.random.normal(keys[0], (tokens * k, d), jnp.float32)
+    gates = jax.nn.softmax(jax.random.normal(keys[1], (tokens, k)), -1)
+    experts = jax.random.randint(keys[2], (tokens, k), 0, 8)
+    _, order, inverse, _ = moe._sort_by_expert(experts, 0, 8)
+    weight = jax.random.normal(keys[3], (tokens, d), jnp.float32)
+
+    def plain(rows, gates):
+        mine = rows[inverse].reshape(tokens, k, d)
+        with jax.default_matmul_precision("highest"):
+            return jnp.sum(jnp.einsum("tkd,tk->td", mine, gates) * weight)
+
+    got = jax.grad(lambda r, g: jnp.sum(
+        moe._combine(r, g, order, inverse) * weight), (0, 1))(rows, gates)
+    want = jax.grad(plain, (0, 1))(rows, gates)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", ["olmoe", "mixtral"])
+def test_loss_gradient_by_id_matches_ragged_dot(shape):
+    """The whole loss's gradient at toy depth and a width whose rows the
+    kernels fetch (1024 float32 values), ``pallas_interpret`` against
+    ``ragged_dot``: every leaf."""
+    kw = dict(dim=1024, n_heads=4, n_kv_heads=2, ffn_dim=128,
+              dtype="float32", attn_impl="reference", remat_policy="full")
+    cfg = _olmoe_tiny(**kw) if shape == "olmoe" else moe.tiny(**kw)
+    params, batch = _setup(cfg)
+    # 64 assignments: a tile's starts are written out, 256 of them at the
+    # default tile, which the interpreter traces one by one
+    batch = {k: v[:1, :32] for k, v in batch.items()}
+
+    def grads(impl):
+        c = dataclasses.replace(cfg, gmm_impl=impl)
+        return jax.value_and_grad(lambda p: moe.loss_fn(p, batch, c))(params)
+
+    (loss, got), (want_loss, want) = grads("pallas_interpret"), \
+        grads("ragged_dot")
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    errs = jax.tree.map(_rel, got, want)
+    assert max(jax.tree.leaves(errs)) < 2e-4, errs
 
 
 # --- the step's metrics ----------------------------------------------------
