@@ -28,7 +28,7 @@ the cotangent's rows once and takes the gates' gradient from them in
 sorted order. Left: ``_combine``'s forward (rows[inverse]), its backward
 (g[order // k]) and the rows' gradient going home (``_token_sums``).
 ``n_shared_experts`` adds one SwiGLU of ``n_shared_experts * ffn_dim`` that
-every token goes through, beside the routed sum.
+every token goes through, beside the routed sum (``_shared``).
 
 A device can hold a SLICE of the experts without a mesh: ``experts_held``
 of ``n_experts`` from ``first_expert`` on. The router still scores all
@@ -57,16 +57,32 @@ Attention is shared with the Llama family (``ray_tpu.models.llama``): RoPE
 OLMoE's RMSNorm with a learned weight over the whole projected q and k,
 before the split into heads and before RoPE.
 
+Layer kinds of a TRAINING configuration (``layer_types`` of "linear" and
+"full"; none means every layer full): a "full" layer is the attention
+above, with, by their switches, a ``head_size`` that is not dim / heads,
+``qk_head_norm`` (RMSNorm of q and k over ``head_dim``, per head, before
+RoPE), RoPE on the first ``rotary_dim`` dimensions of a head only, and
+``attn_output_gate`` (the q projection is twice as wide, a head's second
+half a gate: o * sigmoid(gate) before the output projection). A "linear"
+layer is a Gated DeltaNet (``_gated_delta_net``; the rule itself is
+``ops/gated_delta.py``). ``zero_centered_norm`` makes every RMSNorm
+x * rsqrt(mean(x^2) + eps) * (1 + w) but the linear layer's gated one. The
+kinds' parameters are stacks of their own (``linear_layers``,
+``full_layers``) beside ``layers``, which holds what every layer has; the
+layers run as a ``lax.scan`` over whole periods of the pattern.
+``shared_expert_gate`` weighs the shared expert by sigmoid(w . y) a token;
+with ``experts_held`` the train step computes the held experts' partial sum
+too, and its statistics carry ``moe_local_share``.
+
 The serving-only shapes (``llm/model.py`` runs them; the train forward
-here refuses them): ``layer_types`` ("window" layers attend the last
-``sliding_window`` positions, "global" ones everything), ``n_dense_layers``
-leading layers with a dense SwiGLU of ``dense_ffn_dim``, ``qk_head_norm``
-(RMSNorm of q and k over ``head_dim``, per head, before RoPE),
-``post_norm`` (the sub-layer norms on each sub-layer's OUTPUT: x +
-Norm(Attn(x))), ``rope_layers`` "window" (no RoPE on global layers); and,
-until a training configuration needs them, "sigmoid" scoring, the shared
-expert and a held slice (``serve_block``; the train forward's ``_experts``
-shares ``_route``, ``_sort_by_expert`` and ``_gated_sum`` with it).
+here refuses them): ``layer_types`` of "window" (such layers attend the
+last ``sliding_window`` positions) and "global", ``n_dense_layers``
+leading layers with a dense SwiGLU of ``dense_ffn_dim``, ``post_norm``
+(the sub-layer norms on each sub-layer's OUTPUT: x + Norm(Attn(x))),
+``rope_layers`` "window" (no RoPE on global layers) and "sigmoid" scoring
+(its selection bias has no training rule here). One expert layer serves
+both: ``serve_block`` and the train forward's ``_experts`` share ``_route``,
+``_sort_by_expert``, ``_gated_sum`` and ``_shared``.
 """
 
 from __future__ import annotations
@@ -83,6 +99,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ray_tpu.models import llama
 from ray_tpu.models.llama import MeshAxes, _attend, _on_tpu, _rmsnorm, \
     _rope, _rope_tables
+from ray_tpu.ops import gated_delta
 from ray_tpu.ops.pallas import grouped_matmul
 
 
@@ -124,12 +141,24 @@ class MoEConfig:
     # this device's slice of the experts: 0 = all of them
     experts_held: int = 0
     first_expert: int = 0
-    # serving-only shapes (module docstring)
+    # per layer: "linear" | "full" (training), "window" | "global" (serving)
     layer_types: tuple = ()
+    qk_head_norm: bool = False
+    # the full layers of a training configuration (module docstring)
+    rotary_dim: int = 0         # 0: the whole head
+    attn_output_gate: bool = False
+    zero_centered_norm: bool = False
+    shared_expert_gate: bool = False
+    # the linear (gated delta rule) layers
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0     # of one head
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 4
+    # serving-only shapes (module docstring)
     sliding_window: int = 0
     n_dense_layers: int = 0
     dense_ffn_dim: int = 0
-    qk_head_norm: bool = False
     post_norm: bool = False
     rope_layers: str = "all"
 
@@ -141,26 +170,45 @@ class MoEConfig:
     def n_held(self) -> int:
         return self.experts_held or self.n_experts
 
+    @property
+    def linear_widths(self) -> tuple:
+        """(key width, value width) of a linear layer, all heads."""
+        return (self.linear_key_heads * self.linear_key_dim,
+                self.linear_value_heads * self.linear_value_dim)
+
     def _attn_params(self) -> int:
         d, h, kvh, hd = self.dim, self.n_heads, self.n_kv_heads, self.head_dim
-        attn = d * h * hd + 2 * d * kvh * hd + h * hd * d + 2 * d
+        q = 2 if self.attn_output_gate else 1
+        attn = q * d * h * hd + 2 * d * kvh * hd + h * hd * d + 2 * d
         if self.qk_norm:
             attn += h * hd + kvh * hd
         if self.qk_head_norm:
             attn += 2 * hd
         return attn
 
+    def _linear_params(self) -> int:
+        d, (kw, vw) = self.dim, self.linear_widths
+        hv = self.linear_value_heads
+        return d * (2 * kw + 2 * vw) + d * 2 * hv + vw * d + 2 * d \
+            + (2 * kw + vw) * self.linear_conv_kernel + 2 * hv \
+            + self.linear_value_dim
+
     def _layer_params(self, experts: int) -> int:
+        """One expert layer without its mixer."""
         d, f = self.dim, self.ffn_dim
         router = d * self.n_experts \
-            + (self.n_experts if self.scoring == "sigmoid" else 0)
-        return self._attn_params() + router \
-            + 3 * (experts + self.n_shared_experts) * d * f
+            + (self.n_experts if self.scoring == "sigmoid" else 0) \
+            + (d if self.shared_expert_gate else 0)
+        return router + 3 * (experts + self.n_shared_experts) * d * f
 
     def _params(self, experts: int) -> int:
         dense = self._attn_params() + 3 * self.dim * self.dense_ffn_dim
+        linear = self.layer_types.count("linear")
+        mixers = linear * self._linear_params() \
+            + (self.n_layers - self.n_dense_layers - linear) \
+            * self._attn_params()
         return 2 * self.vocab_size * self.dim + self.dim \
-            + self.n_dense_layers * dense \
+            + self.n_dense_layers * dense + mixers \
             + (self.n_layers - self.n_dense_layers) \
             * self._layer_params(experts)
 
@@ -213,6 +261,30 @@ def k_exaone_236b_a23b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def qwen3_next_80b_a3b(**kw) -> MoEConfig:
+    """Qwen/Qwen3-Next-80B-A3B-Instruct ``config.json``: 48 layers in the
+    period linear, linear, linear, full; the linear layers 16 key and 32
+    value heads of 128 behind a causal conv of 4; the full ones 16 query /
+    2 KV heads of 256 with an output gate and RoPE on 64 dimensions; 512
+    softmax-routed experts of width 512, 10 a token, and a gated shared
+    expert; zero-centred norms."""
+    defaults = dict(
+        vocab_size=151936, dim=2048, n_layers=48, n_heads=16, n_kv_heads=2,
+        head_size=256, ffn_dim=512, n_experts=512, experts_per_token=10,
+        norm_topk_prob=True, n_shared_experts=1, shared_expert_gate=True,
+        qk_head_norm=True, rotary_dim=64, attn_output_gate=True,
+        zero_centered_norm=True, linear_key_heads=16, linear_value_heads=32,
+        linear_key_dim=128, linear_value_dim=128, linear_conv_kernel=4,
+        aux_loss_weight=0.001, max_seq_len=262144, rope_theta=1e7,
+        norm_eps=1e-6)
+    defaults.update(kw)
+    if "layer_types" not in defaults:
+        defaults["layer_types"] = tuple(
+            "full" if i % 4 == 3 else "linear"
+            for i in range(defaults["n_layers"]))
+    return MoEConfig(**defaults)
+
+
 def tiny(**kw) -> MoEConfig:
     defaults = dict(vocab_size=512, dim=64, n_layers=2, n_heads=4,
                     n_kv_heads=2, ffn_dim=128, n_experts=4,
@@ -223,12 +295,23 @@ def tiny(**kw) -> MoEConfig:
 
 # --- params ----------------------------------------------------------------
 
+TRAIN_KINDS = ("linear", "full")
+
+
 def _serving_only(cfg: MoEConfig) -> bool:
     """Whether ``cfg`` has a shape only the serving forwards run."""
-    return bool(cfg.layer_types or cfg.n_dense_layers or cfg.experts_held
-                or cfg.qk_head_norm or cfg.post_norm
-                or cfg.rope_layers != "all" or cfg.head_size
-                or cfg.scoring != "softmax" or cfg.n_shared_experts)
+    return bool(set(cfg.layer_types) - set(TRAIN_KINDS)
+                or cfg.n_dense_layers or cfg.post_norm
+                or cfg.rope_layers != "all" or cfg.scoring != "softmax")
+
+
+def _kind_layers(cfg: MoEConfig) -> dict:
+    """{kind: how many layers} of a configuration with layer kinds."""
+    if len(cfg.layer_types) != cfg.n_layers:
+        raise ValueError(f"layer_types must name {cfg.n_layers} layers, "
+                         f"got {cfg.layer_types}")
+    return {kind: cfg.layer_types.count(kind)
+            for kind in TRAIN_KINDS if kind in cfg.layer_types}
 
 
 # elements of one float32 draw while a leaf is made: 1 GB
@@ -334,66 +417,149 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
     return params
 
 
+def _full_leaves(cfg: MoEConfig) -> tuple:
+    """Of one full-attention mixer: ({projection: (shape, fan-in)}, {norm
+    weight: shape})."""
+    d, h, kvh, hd = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    weights = {
+        "wq": ((d, (2 if cfg.attn_output_gate else 1) * h * hd), d),
+        "wk": ((d, kvh * hd), d), "wv": ((d, kvh * hd), d),
+        "wo": ((h * hd, d), h * hd)}
+    norms = {}
+    if cfg.qk_head_norm:
+        norms = {"q_norm": (hd,), "k_norm": (hd,)}
+    elif cfg.qk_norm:
+        norms = {"q_norm": (h * hd,), "k_norm": (kvh * hd,)}
+    return weights, norms
+
+
+def _init_linear(key, cfg: MoEConfig, L: int, norm_init) -> dict:
+    """The stack of ``L`` Gated DeltaNet mixers. ``config.json`` has no key
+    for their init; it is the layer's in flash-linear-attention: ``A_log``
+    = log U(0, 16), ``dt_bias`` the inverse softplus of a log-uniform step
+    in [1e-3, 1e-1] (so a head's state outlives a chunk at some heads and
+    fades within one at others), the depthwise conv uniform at its fan-in
+    of ``linear_conv_kernel``. ``w_qkvz``'s columns are [q | k | v | z],
+    ``w_ba``'s [b | a] and ``conv``'s channels [q | k | v], each kind's
+    heads in order: the layout the mixer multiplies with (a checkpoint
+    that groups them by key head is permuted once, on load)."""
+    d, (kw, vw), hv = cfg.dim, cfg.linear_widths, cfg.linear_value_heads
+    ks = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(ks[3], (L, hv), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    bound = cfg.linear_conv_kernel ** -0.5
+    return {
+        "w_qkvz": norm_init(ks[0], (L, d, 2 * kw + 2 * vw), d),
+        "w_ba": norm_init(ks[1], (L, d, 2 * hv), d),
+        "conv": jax.random.uniform(
+            ks[2], (L, 2 * kw + vw, cfg.linear_conv_kernel), jnp.float32,
+            -bound, bound).astype(jnp.dtype(cfg.dtype)),
+        # float32, as the router: they set decays that compound over a row
+        "A_log": jnp.log(jax.random.uniform(ks[4], (L, hv), jnp.float32,
+                                            1e-4, 16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "gdn_norm": jnp.ones((L, cfg.linear_value_dim),
+                             jnp.dtype(cfg.dtype)),
+        "w_out": norm_init(ks[5], (L, vw, d), vw)}
+
+
 def init_params(rng: jax.Array, cfg: MoEConfig) -> dict:
     if _serving_only(cfg):
         return _init_serving(rng, cfg)
     dtype = jnp.dtype(cfg.dtype)
-    d, f, E = cfg.dim, cfg.ffn_dim, cfg.n_experts
-    h, kvh, hd, L = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
+    d, f, E, held = cfg.dim, cfg.ffn_dim, cfg.n_experts, cfg.n_held
+    L = cfg.n_layers
     ks = jax.random.split(rng, 10)
+    one = 0.0 if cfg.zero_centered_norm else 1.0
 
     def norm_init(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
+    def full(n):
+        weights, norms = _full_leaves(cfg)
+        out = {name: norm_init(ks[1 + i], (n, *shape), fan)
+               for i, (name, (shape, fan)) in enumerate(weights.items())}
+        out.update({name: jnp.full((n, *shape), one, dtype)
+                    for name, shape in norms.items()})
+        return out
+
     layers = {
-        "attn_norm": jnp.ones((L, d), dtype),
-        "wq": norm_init(ks[1], (L, d, h * hd), d),
-        "wk": norm_init(ks[2], (L, d, kvh * hd), d),
-        "wv": norm_init(ks[3], (L, d, kvh * hd), d),
-        "wo": norm_init(ks[4], (L, h * hd, d), h * hd),
-        "mlp_norm": jnp.ones((L, d), dtype),
+        "attn_norm": jnp.full((L, d), one, dtype),
+        "mlp_norm": jnp.full((L, d), one, dtype),
         # router in f32: tiny, and top-k tie-breaks are dtype-sensitive
         "router": (jax.random.normal(ks[5], (L, d, E), jnp.float32)
                    * (d ** -0.5)),
-        "w_gate": norm_init(ks[6], (L, E, d, f), d),
-        "w_up": norm_init(ks[7], (L, E, d, f), d),
-        "w_down": norm_init(ks[8], (L, E, f, d), f),
+        "w_gate": norm_init(ks[6], (L, held, d, f), d),
+        "w_up": norm_init(ks[7], (L, held, d, f), d),
+        "w_down": norm_init(ks[8], (L, held, f, d), f),
     }
-    if cfg.qk_norm:
-        layers["q_norm"] = jnp.ones((L, h * hd), dtype)
-        layers["k_norm"] = jnp.ones((L, kvh * hd), dtype)
-    return {
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        k1, k2, k3, k4 = jax.random.split(jax.random.fold_in(rng, 1), 4)
+        layers.update(shared_gate=norm_init(k1, (L, d, fs), d),
+                      shared_up=norm_init(k2, (L, d, fs), d),
+                      shared_down=norm_init(k3, (L, fs, d), fs))
+        if cfg.shared_expert_gate:
+            layers["shared_expert_gate"] = norm_init(k4, (L, d, 1), d)
+    params = {
         "embed": norm_init(ks[0], (cfg.vocab_size, d), d),
         "layers": layers,
-        "final_norm": jnp.ones((d,), dtype),
+        "final_norm": jnp.full((d,), one, dtype),
         "lm_head": norm_init(ks[9], (d, cfg.vocab_size), d),
     }
+    if not cfg.layer_types:
+        layers.update(full(L))
+        return params
+    kinds = _kind_layers(cfg)
+    if "full" in kinds:
+        params["full_layers"] = full(kinds["full"])
+    if "linear" in kinds:
+        params["linear_layers"] = _init_linear(
+            jax.random.fold_in(rng, 2), cfg, kinds["linear"], norm_init)
+    return params
 
 
 def param_shardings(cfg: MoEConfig, axes: MeshAxes = MeshAxes()) -> dict:
     t, fs, ep = axes.tensor, axes.fsdp, axes.expert
+    full = {"wq": P(None, fs, t), "wk": P(None, fs, t), "wv": P(None, fs, t),
+            "wo": P(None, t, fs)}
+    if cfg.qk_norm or cfg.qk_head_norm:
+        full.update(q_norm=P(None, None), k_norm=P(None, None))
     layers = {
         "attn_norm": P(None, None),
-        "wq": P(None, fs, t),
-        "wk": P(None, fs, t),
-        "wv": P(None, fs, t),
-        "wo": P(None, t, fs),
         "mlp_norm": P(None, None),
         "router": P(None, fs, None),
         "w_gate": P(None, ep, fs, t),
         "w_up": P(None, ep, fs, t),
         "w_down": P(None, ep, t, fs),
     }
-    if cfg.qk_norm:
-        layers["q_norm"] = P(None, None)
-        layers["k_norm"] = P(None, None)
-    return {
+    if cfg.n_shared_experts:
+        layers.update(shared_gate=P(None, fs, t), shared_up=P(None, fs, t),
+                      shared_down=P(None, t, fs))
+        if cfg.shared_expert_gate:
+            layers["shared_expert_gate"] = P(None, fs, None)
+    out = {
         "embed": P(t, fs),
         "layers": layers,
         "final_norm": P(None),
         "lm_head": P(fs, t),
     }
+    if not cfg.layer_types:
+        layers.update(full)
+        return out
+    kinds = _kind_layers(cfg)
+    if "full" in kinds:
+        out["full_layers"] = full
+    if "linear" in kinds:
+        # a linear layer's heads are not cut by the tensor axis (q, k, v
+        # and z lie side by side in one projection): fsdp only
+        out["linear_layers"] = {
+            "w_qkvz": P(None, fs, None), "w_ba": P(None, fs, None),
+            "conv": P(None, None, None), "A_log": P(None, None),
+            "dt_bias": P(None, None), "gdn_norm": P(None, None),
+            "w_out": P(None, None, fs)}
+    return out
 
 
 # --- the expert layer ------------------------------------------------------
@@ -604,10 +770,14 @@ def _experts(y, router, w_gate, w_up, w_down, cfg: MoEConfig,
 
 
 def _shared(y, lp):
-    """The shared expert: one SwiGLU every token goes through."""
+    """The shared expert: one SwiGLU every token goes through, weighed by
+    sigmoid(w . y) a token where the layer has that gate."""
     with jax.named_scope("moe.shared"):
-        return (jax.nn.silu(y @ lp["shared_gate"]) * (y @ lp["shared_up"])) \
+        out = (jax.nn.silu(y @ lp["shared_gate"]) * (y @ lp["shared_up"])) \
             @ lp["shared_down"]
+        if "shared_expert_gate" in lp:
+            out = jax.nn.sigmoid(y @ lp["shared_expert_gate"]) * out
+        return out
 
 
 def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
@@ -645,15 +815,21 @@ def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
 
 def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
                axes: MeshAxes):
-    """y (b, s, d) normed hidden -> (expert-mixed output (b, s, d),
-    assignments per expert and token (E,), mean router probability (E,))."""
+    """y (b, s, d) normed hidden -> (the routed experts' output (b, s, d):
+    with ``experts_held`` the held experts' partial sum, what an
+    expert-parallel deployment adds up across its devices; assignments per
+    expert and token (E,), mean router probability (E,))."""
     b, s, d = y.shape
+    if cfg.experts_held and mesh is not None \
+            and mesh.shape.get(axes.expert, 1) > 1:
+        raise ValueError("a held slice of the experts and an expert mesh "
+                         "axis are two ways to say which experts are here")
     weights = (lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"])
     ep, t = axes.expert, axes.tensor
 
     def local(y, router, w_gate, w_up, w_down):
         bl, sl, _ = y.shape
-        first = lax.axis_index(ep) * w_gate.shape[0]
+        first = cfg.first_expert + lax.axis_index(ep) * w_gate.shape[0]
         out, counts, probs = _experts(y.reshape(bl * sl, d), router, w_gate,
                                       w_up, w_down, cfg, first)
         tok_axes = (*axes.batch, axes.context)
@@ -661,7 +837,8 @@ def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
                 lax.psum(counts, tok_axes), lax.psum(probs, tok_axes))
 
     if mesh is None:
-        out, counts, probs = _experts(y.reshape(b * s, d), *weights, cfg)
+        out, counts, probs = _experts(y.reshape(b * s, d), *weights, cfg,
+                                      cfg.first_expert)
         out = out.reshape(b, s, d)
     else:
         # check_vma=False: pallas_call outputs carry no vma under shard_map
@@ -676,19 +853,114 @@ def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
 
 # --- forward ---------------------------------------------------------------
 
+def _norm(x, w, cfg: MoEConfig):
+    """RMSNorm; zero-centred (``1 + w``, in float32) where the config says."""
+    if not cfg.zero_centered_norm:
+        return _rmsnorm(x, w, cfg.norm_eps)
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + cfg.norm_eps)
+            * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def _rope_part(x, cos, sin):
+    """RoPE on the first ``2 * cos.shape[-1]`` dimensions of a head."""
+    rd = 2 * cos.shape[-1]
+    if rd == x.shape[-1]:
+        return _rope(x, cos, sin)
+    return jnp.concatenate([_rope(x[..., :rd], cos, sin), x[..., rd:]], -1)
+
+
+def _attention(y, mp, cfg: MoEConfig, rope, mesh, axes):
+    """A full-attention mixer: y (b, s, d) normed -> (b, s, d)."""
+    b, s, _ = y.shape
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q, k, v = y @ mp["wq"], y @ mp["wk"], y @ mp["wv"]
+    gate = None
+    if cfg.attn_output_gate:        # a head's columns: its query, its gate
+        q, gate = jnp.split(q.reshape(b, s, h, 2 * hd), 2, axis=-1)
+    if cfg.qk_norm:
+        q = _norm(q, mp["q_norm"], cfg)
+        k = _norm(k, mp["k_norm"], cfg)
+    q, k = q.reshape(b, s, h, hd), k.reshape(b, s, kvh, hd)
+    if cfg.qk_head_norm:
+        q = _norm(q, mp["q_norm"], cfg)
+        k = _norm(k, mp["k_norm"], cfg)
+    q, k = _rope_part(q, *rope), _rope_part(k, *rope)
+    o = _attend(q, k, v.reshape(b, s, kvh, hd), cfg, mesh,
+                axes).astype(y.dtype)
+    if gate is not None:
+        with jax.named_scope("attention.gate"):
+            o = o * jax.nn.sigmoid(gate)
+    return o.reshape(b, s, h * hd) @ mp["wo"]
+
+
+def _l2norm(x, eps=1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
+
+
+def _causal_conv(x, w):
+    """Depthwise causal convolution: x (b, s, C), w (C, K) -> (b, s, C)
+    float32, ``out[t] = sum_j w[:, j] x[t - (K - 1) + j]``."""
+    s, taps = x.shape[1], w.shape[1]
+    x = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    return sum(x[:, j:j + s].astype(jnp.float32) * w[:, j]
+               for j in range(taps))
+
+
+def _gated_delta_net(y, mp, cfg: MoEConfig):
+    """A linear mixer (Gated DeltaNet): y (b, s, d) normed -> (b, s, d).
+    ``w_qkvz``'s columns are [q | k | v | z] and ``w_ba``'s [b | a], each
+    kind's heads in order (value head j reads key head j // r); a row is
+    a multiple of ``gated_delta.CHUNK`` tokens."""
+    b, s, _ = y.shape
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv, r = cfg.linear_key_dim, cfg.linear_value_dim, hv // hk
+    kw, vw = cfg.linear_widths
+    f32 = jnp.float32
+    with jax.named_scope("gdn.proj"):
+        qkvz = y @ mp["w_qkvz"]
+        mixed, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
+        ba = (y @ mp["w_ba"]).astype(f32)
+        beta = jax.nn.sigmoid(ba[..., :hv])                 # (b, s, hv)
+        g = -jnp.exp(mp["A_log"]) * jax.nn.softplus(
+            ba[..., hv:] + mp["dt_bias"])
+    with jax.named_scope("gdn.conv"):
+        mixed = jax.nn.silu(_causal_conv(mixed, mp["conv"]))
+        q, k, v = jnp.split(mixed, [kw, 2 * kw], axis=-1)
+        q = (_l2norm(q.reshape(b, s, hk, dk)) * dk ** -0.5).astype(y.dtype)
+        k = _l2norm(k.reshape(b, s, hk, dk)).astype(y.dtype)
+        q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
+        v = v.reshape(b, s, hv, dv).astype(y.dtype)
+    o, _ = gated_delta.chunk_gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope("gdn.gate_norm"):
+        o = _rmsnorm(o, mp["gdn_norm"], cfg.norm_eps) \
+            * jax.nn.silu(z.reshape(b, s, hv, dv).astype(f32)).astype(y.dtype)
+    with jax.named_scope("gdn.out"):
+        return o.reshape(b, s, hv * dv) @ mp["w_out"]
+
+
+def _period(kinds: tuple) -> int:
+    """The shortest period the layer pattern repeats with."""
+    n = len(kinds)
+    return next(p for p in range(1, n + 1)
+                if n % p == 0 and kinds == kinds[:p] * (n // p))
+
+
 def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
              mesh: Optional[Mesh], axes: MeshAxes):
     """tokens (b, s) int32 -> (logits (b, s, vocab), routing statistics
-    ``{"moe_aux_loss", "moe_load_max_over_mean"}``, float32 scalars)."""
+    ``{"moe_aux_loss", "moe_load_max_over_mean"}`` and, of a held slice,
+    ``"moe_local_share"``: float32 scalars)."""
     if _serving_only(cfg):
         raise NotImplementedError(
-            "the train forward runs full-attention layers of all the "
-            "softmax-routed experts; layer_types, n_dense_layers, "
-            "experts_held, qk_head_norm, post_norm, rope_layers, head_size, "
-            "sigmoid scoring and shared experts are the serving forwards' "
-            "(ray_tpu.llm.model)")
+            "the train forward runs linear and full-attention layers of "
+            "softmax-routed experts; window / global layer_types, "
+            "n_dense_layers, post_norm, rope_layers and sigmoid scoring "
+            "are the serving forwards' (ray_tpu.llm.model)")
     b, s = tokens.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     def act_constraint(x, spec):
         if mesh is not None:
@@ -699,31 +971,61 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
     x = jnp.take(params["embed"], tokens, axis=0)
     x = act_constraint(x, P(axes.batch, axes.context, None))
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    rope_cos, rope_sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = _rope_tables(positions, cfg.rotary_dim or cfg.head_dim,
+                        cfg.rope_theta)
 
-    # the scopes only name the ops in a device trace (metadata)
-    def layer(x, lp):
-        with jax.named_scope("attention"):
-            y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = y @ lp["wq"], y @ lp["wk"], y @ lp["wv"]
-            if cfg.qk_norm:
-                q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-                k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-            q = _rope(q.reshape(b, s, h, hd), rope_cos, rope_sin)
-            k = _rope(k.reshape(b, s, kvh, hd), rope_cos, rope_sin)
-            o = _attend(q, k, v.reshape(b, s, kvh, hd), cfg, mesh,
-                        axes).astype(x.dtype)
-            x = x + (o.reshape(b, s, h * hd) @ lp["wo"])
+    # the scopes only name the ops in a device trace (metadata). ``lp``:
+    # what every layer has; ``mp``: this layer's mixer
+    def layer(kind, x, lp, mp):
+        if kind == "full":
+            with jax.named_scope("attention"):
+                y = _norm(x, lp["attn_norm"], cfg)
+                x = x + _attention(y, mp, cfg, rope, mesh, axes)
+                x = act_constraint(x, P(axes.batch, axes.context, None))
+        else:
+            y = _norm(x, lp["attn_norm"], cfg)
+            x = x + _gated_delta_net(y, mp, cfg)
             x = act_constraint(x, P(axes.batch, axes.context, None))
-        y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        y = _norm(x, lp["mlp_norm"], cfg)
         moe_out, load, prob = _moe_block(y, lp, cfg, mesh, axes)
+        if cfg.n_shared_experts:
+            moe_out = moe_out + _shared(y, lp)
         x = x + moe_out
         x = act_constraint(x, P(axes.batch, axes.context, None))
         return x, (load, prob)
 
-    step = llama._remat(layer, cfg)
-    x, (load, prob) = lax.scan(step, x, params["layers"])    # (L, E) each
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    step = {kind: llama._remat(functools.partial(layer, kind), cfg)
+            for kind in TRAIN_KINDS}
+    if not cfg.layer_types:
+        x, (load, prob) = lax.scan(lambda x, lp: step["full"](x, lp, lp),
+                                   x, params["layers"])       # (L, E) each
+    else:
+        # one scan turn is one period of the pattern, its layers in order
+        kinds = cfg.layer_types[:_period(cfg.layer_types)]
+        turns = cfg.n_layers // len(kinds)
+
+        def folded(tree, per):
+            return jax.tree.map(
+                lambda a: a.reshape(turns, per, *a.shape[1:]), tree)
+
+        def period(x, xs):
+            common, mixers = xs
+            seen, stats = dict.fromkeys(mixers, 0), []
+            for i, kind in enumerate(kinds):
+                lp = jax.tree.map(lambda a: a[i], common)
+                mp = jax.tree.map(lambda a: a[seen[kind]], mixers[kind])
+                seen[kind] += 1
+                x, stat = step[kind](x, lp, mp)
+                stats.append(stat)
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *stats)
+
+        x, (load, prob) = lax.scan(period, x, (
+            folded(params["layers"], len(kinds)),
+            {kind: folded(params[kind + "_layers"], kinds.count(kind))
+             for kind in set(kinds)}))
+        load = load.reshape(cfg.n_layers, -1)
+        prob = prob.reshape(cfg.n_layers, -1)
+    x = _norm(x, params["final_norm"], cfg)
     logits = (x @ params["lm_head"]).astype(jnp.dtype(cfg.logits_dtype))
     # the published term pools the routers of all layers: a token of layer
     # l is one more token, so f and p are means over layers
@@ -731,6 +1033,10 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
     load = lax.stop_gradient(load)
     stats = {"moe_aux_loss": aux, "moe_load_max_over_mean": jnp.mean(
         jnp.max(load, axis=1) / jnp.mean(load, axis=1))}
+    if cfg.experts_held:
+        # assignments on the experts held here over all assignments
+        held = load[:, cfg.first_expert:cfg.first_expert + cfg.n_held]
+        stats["moe_local_share"] = jnp.sum(held) / jnp.sum(load)
     return logits, stats
 
 

@@ -706,3 +706,65 @@ def test_the_period_scan_reads_the_projections_stack_in_place(chip):
     params, _, compiled = _hybrid_decode(chip, cfg)
     _assert_projections_in_place(compiled, params)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
+# --- the linear-attention train cell's step ---------------------------------
+
+def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
+    """``train-qwen3next-ep16``'s whole train step (``make_train_step`` on a
+    mesh of one described chip, batch and rows as the cell runs them):
+    the flash kernels compile at head 256 with 16 / 2 heads, forward and
+    backward; the step fits the chip by the compiler's memory analysis and
+    is sized like a deployment's (11 GB or more); and it holds no custom
+    call the benchmark would not know: every one is a flash kernel by its
+    operand signature or a grouped matmul by its name, so that
+    ``moe_gmm_dev_ms.train`` (every unknown kernel's time) counts the
+    grouped matmuls alone. The chunked delta rule is plain XLA."""
+    import json
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.models import llama, moe
+    from ray_tpu.parallel import mesh as pmesh
+    file = "qwen3-next-80b-a3b-train-ep16.json"
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           os.pardir, "benchmarks", "configs", file)) as f:
+        dep = json.load(f)["deployment"]
+    cfg = _cell_config(file, dep["family"], gmm_impl="pallas",
+                       **dep["model_overrides"])
+    mesh = pmesh.make_mesh(pmesh.MeshSpec(data=1, context=1, **dep["mesh"]),
+                           devices=topo.devices[:1])
+    init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=moe)
+    here = NamedSharding(mesh, P())
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=here), tree)
+    state = shapes(jax.eval_shape(init_fn, jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((dep["batch"], 4096), jnp.int32,
+                                     sharding=here)
+             for k in ("tokens", "targets")}
+    was = llama._on_tpu
+    llama._on_tpu = lambda: True      # flash: the kernel, not the fallback
+    try:
+        with mesh:
+            compiled = step_fn.lower(state, batch).compile()
+    finally:
+        llama._on_tpu = was
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    assert 11e9 <= total < 15.75e9, total
+    ops = _kernel_ops(compiled)
+    flash = {"flash_fwd": 0, "flash_bwd_dq": 0, "flash_bwd_dkv": 0}
+    for op in ops:
+        if op["class"] in flash:
+            flash[op["class"]] += 1
+            assert op["class"] in op["name"], op["name"]
+            # q first: b * heads (the KV heads repeated), rows, head 256
+            assert op["operands"][0] == (
+                "bf16", (dep["batch"] * 16, 4096, 256)), op["operands"][0]
+        else:
+            assert op["class"] == "unknown_kernel" \
+                and "moe_gmm" in op["name"], op["name"]
+    # one full layer in four: forward, the remat's forward, one backward
+    assert flash == {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert len(ops) - 4 == 4 * 8, [op["name"] for op in ops]

@@ -1,0 +1,119 @@
+"""The chunked gated delta rule (``ray_tpu/ops/gated_delta.py``) against
+the token-by-token recurrence it must agree with: float32 on the CPU,
+outputs, final state and the gradients of all five inputs, over at least
+four chunks."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from ray_tpu.ops import gated_delta as gd
+
+B, S, H, DK, DV = 2, 256, 3, 16, 24
+INPUTS = ("q", "k", "v", "g", "beta")
+
+
+@pytest.fixture(scope="module")
+def inputs():
+    """Unit keys, scaled unit queries, decays from 'none a chunk' to 'gone
+    in a chunk' by head, write strengths in (0, 1)."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(ks[0], (B, S, H, DK))
+    k = jax.random.normal(ks[1], (B, S, H, DK))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, S, H, DV))
+    g = -jax.random.uniform(ks[3], (B, S, H)) * jnp.array([0.002, 0.05, 0.5])
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    return q, k, v, g, beta
+
+
+def recurrence(q, k, v, g, beta, state=None):
+    """The rule one token at a time: (o (b, s, h, dv), final state)."""
+    if state is None:
+        state = jnp.zeros((q.shape[0], q.shape[2], q.shape[3], v.shape[3]))
+
+    def token(state, x):
+        o, state = gd.recurrent_gated_delta_step(*x, state)
+        return state, o
+    state, o = lax.scan(token, state, tuple(
+        jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_outputs_and_final_state(inputs, chunk):
+    assert S // chunk >= 4
+    o, state = gd.chunk_gated_delta_rule(*inputs, chunk=chunk)
+    want_o, want_state = recurrence(*inputs)
+    np.testing.assert_allclose(o, want_o, atol=2e-6)
+    np.testing.assert_allclose(state, want_state, atol=5e-6)
+    # the state matters: the slow head's outlives the row
+    assert float(jnp.abs(want_state[:, 0]).mean()) > 0.05
+
+
+def _objective(rule):
+    def f(*args):
+        o, state = rule(*args)
+        return jnp.sum(o * o) + jnp.sum(jnp.sin(state))
+    return f
+
+
+@pytest.fixture(scope="module")
+def want_grads(inputs):
+    return jax.grad(_objective(recurrence), argnums=range(5))(*inputs)
+
+
+@pytest.fixture(scope="module")
+def got_grads(inputs):
+    """{chunk: the five gradients}, one compile a chunk size."""
+    return {chunk: jax.grad(_objective(
+        lambda *a, c=chunk: gd.chunk_gated_delta_rule(*a, chunk=c)),
+        argnums=range(5))(*inputs) for chunk in (16, 64)}
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("which", INPUTS)
+def test_gradients(want_grads, got_grads, chunk, which):
+    i = INPUTS.index(which)
+    scale = float(jnp.abs(want_grads[i]).max())
+    np.testing.assert_allclose(got_grads[chunk][i], want_grads[i],
+                               atol=2e-5 * scale)
+
+
+@pytest.mark.parametrize("cut", [64, 192])
+def test_the_recurrent_step_continues_a_chunked_prefix(inputs, cut):
+    """Serving's two halves: a prefix by chunks (of the module's CHUNK),
+    then token by token from the state it left, is the whole row's rule."""
+    assert gd.CHUNK == 64
+    head = [a[:, :cut] for a in inputs]
+    tail = [a[:, cut:] for a in inputs]
+    _, state = gd.chunk_gated_delta_rule(*head)
+    o_tail, state = recurrence(*tail, state=state)
+    want_o, want_state = gd.chunk_gated_delta_rule(*inputs)
+    np.testing.assert_allclose(o_tail, want_o[:, cut:], atol=2e-6)
+    np.testing.assert_allclose(state, want_state, atol=5e-6)
+
+
+def test_a_row_that_is_no_multiple_of_the_chunk_is_refused(inputs):
+    with pytest.raises(AssertionError):
+        gd.chunk_gated_delta_rule(*[a[:, :100] for a in inputs], chunk=64)
+
+
+def test_the_chunk_inverse_and_its_backward():
+    """(I + a)^-1 by block recursion, also where keys repeat (a of ones:
+    the case a Neumann product's powers lose), and the gradient its
+    custom rule gives against autodiff through a dense inverse."""
+    c = 64
+    strict = jnp.tril(jnp.ones((c, c)), -1)
+    a = jax.random.normal(jax.random.PRNGKey(1), (3, c, c)) * 0.3 * strict
+    a = a.at[0].set(strict)
+    eye = jnp.eye(c)
+    got = gd.unit_lower_inverse(a)
+    np.testing.assert_allclose(got, jnp.linalg.inv(eye + a), atol=1e-4)
+    np.testing.assert_allclose(got[0], eye - jnp.eye(c, k=-1), atol=1e-6)
+    w = jax.random.normal(jax.random.PRNGKey(2), (3, c, c))
+    got_g = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * w))(a)
+    want_g = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(eye + a) * w))(a)
+    np.testing.assert_allclose(got_g[1:], want_g[1:], rtol=1e-3, atol=1e-3)
