@@ -18,15 +18,16 @@ summed per token. Every token reaches all ``k`` of its experts whatever the
 load: there is no capacity and nothing is dropped, and the cost follows
 tokens x k, not experts x capacity. Dispatch and combine are gathers in
 both directions (the backward of a gather by a permutation is the gather
-by its inverse), so no scatter runs on the device, through two
-``custom_vjp``s. Of the six (T*k, d) gathers a train step's layer could
-hold, three are written (PR 43): with a layer's own weights and the kernel
-(``_gate_up``), gate and up read y's rows BY ID in one call, forward,
-recomputed and for the weights' gradients, so ``_dispatch``'s copy exists
-only under ``ragged_dot`` and in serving; ``_combine``'s backward gathers
-the cotangent's rows once and takes the gates' gradient from them in
-sorted order. Left: ``_combine``'s forward (rows[inverse]), its backward
-(g[order // k]) and the rows' gradient going home (``_token_sums``).
+by its inverse), so no scatter runs on the device where all experts are
+held, through two ``custom_vjp``s. Of the six (T*k, d) gathers a train
+step's layer could hold, three are written (PR 43): with a layer's own
+weights and the kernel (``_gate_up``), gate and up read y's rows BY ID in
+one call, forward, recomputed and for the weights' gradients, so
+``_dispatch``'s copy exists only under ``ragged_dot`` and in serving;
+``_combine``'s backward gathers the cotangent's rows once and takes the
+gates' gradient from them in sorted order. Left: ``_combine``'s forward
+(rows[inverse]), its backward (g[order // k]) and the rows' gradient going
+home (``_token_sums``).
 ``n_shared_experts`` adds one SwiGLU of ``n_shared_experts * ffn_dim`` that
 every token goes through, beside the routed sum (``_shared``).
 
@@ -35,6 +36,21 @@ of ``n_experts`` from ``first_expert`` on. The router still scores all
 ``n_experts``; the layer computes the held experts' part (plus the shared
 expert) and returns that partial sum: what an expert-parallel deployment
 adds up across its devices. Nothing here stands in for the other devices.
+
+Such a device owns about ``held / n_experts`` of the assignments; the
+others' sort into a tail no group covers. The TRAIN step (``_held_sum``;
+under an expert mesh axis too) passes over its own rows only: the first
+``_local_bound`` sorted assignments (twice the expected share, in whole
+row tiles: from the shapes, no setting) are the work list of the kernels,
+of the elementwise passes and of the combine, which there is a sum of the
+list's gate-weighted rows INTO their tokens (a float32 scatter-add; its
+backward one gather of as many rows), and so is the rows' gradient going
+home. A routing that sends the device more than the bound takes the path
+above over every assignment instead (``lax.cond`` on the count, in the
+layer): the same sum, nothing dropped, no capacity. The step's statistics
+say how many layers took the list (``moe_compact_share``). Serving
+(``serve_block``) keeps the full path: a decode step pays for the held
+experts' bytes, not for its 256 rows.
 
 Expert parallelism is a mesh axis (``MeshAxes.expert``): the expert layer
 runs under ``shard_map`` with the tokens sharded over the batch and
@@ -715,6 +731,10 @@ def _route(y, router, bias, cfg: MoEConfig):
     return gates, experts, probs
 
 
+def _inverse(order):
+    return jnp.argsort(order).astype(jnp.int32)
+
+
 def _sort_by_expert(experts, first_expert, local: int):
     """(mine, order, inverse, group_sizes) of the (T, k) assignments for a
     device that holds experts ``first_expert ... + local``: the others'
@@ -722,7 +742,7 @@ def _sort_by_expert(experts, first_expert, local: int):
     mine = experts.reshape(-1) - first_expert
     mine = jnp.where((mine >= 0) & (mine < local), mine, local)
     order = jnp.argsort(mine, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
+    inverse = _inverse(order)
     # counts by compare-and-sum: a scatter-add into E bins serialises
     group_sizes = jnp.sum(mine[:, None] == jnp.arange(local), axis=0,
                           dtype=jnp.int32)
@@ -752,11 +772,205 @@ def _gated_sum(y, gates, order, inverse, group_sizes, w, cfg: MoEConfig,
         return _combine(rows, gates, order, inverse)
 
 
+# A device that holds FEWER experts than the router scores (a held slice,
+# an expert mesh axis) owns the first ``sum(group_sizes)`` entries of
+# ``order``; the rest sort into the tail no group covers. The train step
+# then works on a list of ``_local_bound`` assignments ("work": the head of
+# ``order``) where that covers the device's own: y's rows by id, the three
+# grouped matmuls and the elementwise passes on (R, .), and the two ways
+# between tokens and rows as sums of R rows INTO their tokens (a
+# scatter-add, as the embedding's backward is), not gathers of T*k.
+
+def _local_bound(assignments: int, held: int, experts: int) -> int:
+    """Rows of the work list of a device that holds ``held`` of ``experts``:
+    twice its expected share of the assignments, in whole row tiles. From
+    the shapes alone; a routing that sends it more takes the full path."""
+    tile = grouped_matmul.ROW_TILE
+    return -(-2 * assignments * held // (experts * tile)) * tile
+
+
+def _work_rows(order, w, cfg: MoEConfig) -> int:
+    return _local_bound(order.shape[0], w["w_gate"].shape[0], cfg.n_experts)
+
+
+def _sum_into_tokens(rows, token, tokens: int):
+    """``rows`` (R, d) summed into the tokens ``token`` (R,) names, in
+    float32 -> (T, d) float32."""
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[token].add(
+        rows.astype(jnp.float32), mode="promise_in_bounds")
+
+
+def _grouped_grads(x, w, group_sizes, dout, cfg: MoEConfig):
+    """(d_x, d_w) of ``_grouped(x, w, group_sizes, cfg)`` for its
+    cotangent ``dout``."""
+    impl = _gmm_impl(cfg)
+    if impl == "ragged_dot":
+        return jax.vjp(lambda x, w: lax.ragged_dot(x, w, group_sizes), x,
+                       w)[1](dout)
+    interpret = impl == "pallas_interpret"
+    return (grouped_matmul.gmm_t(dout, w, group_sizes, interpret),
+            grouped_matmul.tgmm(x, dout, group_sizes, interpret=interpret))
+
+
+def _local_fwd(y, gates, order, group_sizes, w, cfg: MoEConfig):
+    """``_gated_sum`` over the work list, the first ``_local_bound``
+    entries of ``order``, which hold every assignment of the experts ``w``
+    holds (a layer's own weights) -> (out (T, d), what ``_local_bwd`` reads
+    again: gate, up (R, f) and the experts' rows (R, d), zeros past the
+    last group). The combine is the rows, weighted by their gates, summed
+    into their tokens in float32."""
+    tokens, k = gates.shape
+    work = order[:_work_rows(order, w, cfg)]
+    token = work // k
+    with jax.named_scope("moe.experts"):
+        impl = _gmm_impl(cfg)
+        if impl == "ragged_dot":
+            x = _rows(y, token)                                  # (R, d)
+            gate, up = (_grouped(x, w[name], group_sizes, cfg)
+                        for name in ("w_gate", "w_up"))
+        else:
+            gate, up = grouped_matmul.gmm_rows(
+                y, token, (w["w_gate"], w["w_up"]), group_sizes,
+                interpret=impl == "pallas_interpret")
+        rows = _grouped(jax.nn.silu(gate) * up, w["w_down"], group_sizes,
+                        cfg)
+    with jax.named_scope("moe.combine"):
+        scaled = rows.astype(jnp.float32) \
+            * _rows(gates.reshape(-1), work)[:, None]
+        out = _sum_into_tokens(scaled, token, tokens).astype(rows.dtype)
+    return out, (gate, up, rows)
+
+
+def _local_bwd(y, gates, order, group_sizes, w, kept, g, cfg: MoEConfig):
+    """(d_y, d_gates, d_w) of ``_local_fwd``'s out for its cotangent ``g``
+    (T, d): one gather of g's R rows serves the rows' and the gates'
+    gradients (``_combine_bwd``), R gate gradients return to their (T, k)
+    places (the other assignments' rows were zero, so are their gates'
+    gradients), and the rows' gradient goes home as a sum into tokens (the
+    list's rows past the last group come back zero from ``gmm_t``)."""
+    gate, up, rows = kept
+    tokens, k = gates.shape
+    work = order[:rows.shape[0]]
+    token = work // k
+    with jax.named_scope("moe.combine"):
+        g_rows = _rows(g, token).astype(jnp.float32)
+        d_rows = (g_rows * _rows(gates.reshape(-1), work)[:, None]).astype(
+            rows.dtype)
+        dots = jnp.sum(rows.astype(jnp.float32) * g_rows, axis=1)
+        d_gates = jnp.zeros(gates.size, jnp.float32).at[work].set(
+            dots, mode="promise_in_bounds", unique_indices=True)
+    with jax.named_scope("moe.experts"):
+        hidden, hidden_vjp = jax.vjp(lambda a, b: jax.nn.silu(a) * b, gate,
+                                     up)
+        d_hidden, d_down = _grouped_grads(hidden, w["w_down"], group_sizes,
+                                          d_rows, cfg)
+        douts = hidden_vjp(d_hidden)
+        impl = _gmm_impl(cfg)
+        if impl == "ragged_dot":
+            x = _rows(y, token)
+            (d_x, d_gate), (d_xu, d_up) = (
+                _grouped_grads(x, w[name], group_sizes, dout, cfg)
+                for name, dout in zip(("w_gate", "w_up"), douts))
+            d_x = d_x + d_xu
+        else:
+            interpret = impl == "pallas_interpret"
+            d_x = grouped_matmul.gmm_t(douts, (w["w_gate"], w["w_up"]),
+                                       group_sizes, interpret)
+            d_gate, d_up = grouped_matmul.tgmm(
+                y, douts, group_sizes, rows=token, interpret=interpret)
+            # as ``_gate_up_bwd``: the weights' gradients before anything
+            # reads the rows'
+            d_x, d_gate, d_up = lax.optimization_barrier((d_x, d_gate, d_up))
+        d_y = _sum_into_tokens(d_x, token, tokens).astype(y.dtype)
+    return (d_y, d_gates.reshape(gates.shape),
+            {"w_gate": d_gate, "w_up": d_up, "w_down": d_down})
+
+
+def _full_fwd(y, gates, order, group_sizes, w, cfg: MoEConfig):
+    """``_gated_sum`` over every assignment, as the other side of the
+    branch: only it sorts the inverse permutation."""
+    return _gated_sum(y, gates, order, _inverse(order), group_sizes, w, cfg)
+
+
+def _full_bwd(y, gates, order, group_sizes, w, g, cfg: MoEConfig):
+    """(d_y, d_gates, d_w) of ``_full_fwd``, which kept nothing but its
+    inputs: computed anew, then differentiated."""
+    return jax.vjp(lambda y, gates, w: _full_fwd(
+        y, gates, order, group_sizes, w, cfg), y, gates, w)[1](g)
+
+
+@functools.lru_cache(maxsize=None)
+def _once(side, cfg: MoEConfig):
+    """``side(*arrays, cfg=cfg)`` as ONE jitted callable a (side, cfg): a
+    program that calls it again on the same shapes (the forward, the
+    remat's forward and the backward of every layer of a scan turn trace
+    both sides of the branch) traces and lowers its kernels once, not
+    once a call: seconds of a warm start."""
+    return jax.jit(functools.partial(side, cfg=cfg))
+
+
+def _fits(order, group_sizes, w, cfg: MoEConfig):
+    """Whether the device's own assignments fit the work list: a device
+    bool."""
+    return jnp.sum(group_sizes) <= _work_rows(order, w, cfg)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _bounded_sum(y, gates, order, group_sizes, w, cfg: MoEConfig):
+    """``_gated_sum`` by the work list where the device's own assignments
+    fit it (``_local_fwd``) and over every assignment where a routing sent
+    it more (``_full_fwd``), chosen on the device (``lax.cond``): the same
+    sum up to the order of float32 additions inside a token's, nothing
+    dropped, no capacity. One ``custom_vjp`` with the branch inside its
+    forward and inside its backward, so each is one conditional that
+    passes nothing through (differentiated by JAX, a ``lax.cond`` hands
+    every residual of either side, the weights among them, out of the
+    branch and in again). The full side keeps nothing but its inputs."""
+    return _bounded_sum_fwd(y, gates, order, group_sizes, w, cfg)[0]
+
+
+def _bounded_sum_fwd(y, gates, order, group_sizes, w, cfg):
+    local = _once(_local_fwd, cfg)
+
+    def full(*operands):
+        kept = jax.eval_shape(local, *operands)[1]
+        return (_once(_full_fwd, cfg)(*operands),
+                jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), kept))
+
+    out, kept = lax.cond(_fits(order, group_sizes, w, cfg), local, full, y,
+                         gates, order, group_sizes, w)
+    return out, (y, gates, order, group_sizes, w, kept)
+
+
+def _bounded_sum_bwd(cfg, res, g):
+    y, gates, order, group_sizes, w, kept = res
+    d_y, d_gates, d_w = lax.cond(
+        _fits(order, group_sizes, w, cfg), _once(_local_bwd, cfg),
+        lambda y, gates, order, sizes, w, kept, g: _once(_full_bwd, cfg)(
+            y, gates, order, sizes, w, g),
+        y, gates, order, group_sizes, w, kept, g)
+    return d_y, d_gates, None, None, d_w
+
+
+_bounded_sum.defvjp(_bounded_sum_fwd, _bounded_sum_bwd)
+
+
+def _held_sum(y, gates, order, inverse, group_sizes, w, cfg: MoEConfig):
+    """The train step's ``_gated_sum`` -> (out (T, d), whether it took the
+    work list: a float32 1 or 0; None where the step has no such path: all
+    experts held, or so few rows that the bound covers them all)."""
+    if w["w_gate"].shape[0] == cfg.n_experts \
+            or _work_rows(order, w, cfg) >= order.shape[0]:
+        return _gated_sum(y, gates, order, inverse, group_sizes, w, cfg), None
+    return (_bounded_sum(y, gates, order, group_sizes, w, cfg),
+            _fits(order, group_sizes, w, cfg).astype(jnp.float32))
+
+
 def _experts(y, router, w_gate, w_up, w_down, cfg: MoEConfig,
              first_expert=0):
     """y (T, d) -> (out (T, d) from the experts ``first_expert ...`` that
     ``w_*`` hold, assignments per expert (E,), summed router scores
-    (E,)); the last two over all experts."""
+    (E,): the last two over all experts; ``_held_sum``'s flag)."""
     E = cfg.n_experts
     with jax.named_scope("moe.route"):
         gates, experts, probs = _route(y, router, None, cfg)
@@ -764,9 +978,10 @@ def _experts(y, router, w_gate, w_up, w_down, cfg: MoEConfig,
             experts, first_expert, w_gate.shape[0])
         counts = jnp.sum(experts.reshape(-1, 1) == jnp.arange(E), axis=0,
                          dtype=jnp.float32)
-    out = _gated_sum(y, gates, order, inverse, group_sizes,
-                     {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}, cfg)
-    return out, counts, jnp.sum(probs, axis=0)
+    out, fits = _held_sum(y, gates, order, inverse, group_sizes,
+                          {"w_gate": w_gate, "w_up": w_up, "w_down": w_down},
+                          cfg)
+    return out, counts, jnp.sum(probs, axis=0), fits
 
 
 def _shared(y, lp):
@@ -817,8 +1032,10 @@ def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
                axes: MeshAxes):
     """y (b, s, d) normed hidden -> (the routed experts' output (b, s, d):
     with ``experts_held`` the held experts' partial sum, what an
-    expert-parallel deployment adds up across its devices; assignments per
-    expert and token (E,), mean router probability (E,))."""
+    expert-parallel deployment adds up across its devices; the layer's
+    statistics: assignments per expert and token (E,), mean router
+    probability (E,) and, where the step has a work-list path
+    (``_experts``), the share of the devices that took it)."""
     b, s, d = y.shape
     if cfg.experts_held and mesh is not None \
             and mesh.shape.get(axes.expert, 1) > 1:
@@ -830,25 +1047,27 @@ def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
     def local(y, router, w_gate, w_up, w_down):
         bl, sl, _ = y.shape
         first = cfg.first_expert + lax.axis_index(ep) * w_gate.shape[0]
-        out, counts, probs = _experts(y.reshape(bl * sl, d), router, w_gate,
-                                      w_up, w_down, cfg, first)
+        out, counts, probs, fits = _experts(
+            y.reshape(bl * sl, d), router, w_gate, w_up, w_down, cfg, first)
         tok_axes = (*axes.batch, axes.context)
         return (lax.psum(out, (ep, t)).reshape(bl, sl, d),
-                lax.psum(counts, tok_axes), lax.psum(probs, tok_axes))
+                lax.psum(counts, tok_axes), lax.psum(probs, tok_axes),
+                () if fits is None else (lax.pmean(fits, (ep, *tok_axes)),))
 
     if mesh is None:
-        out, counts, probs = _experts(y.reshape(b * s, d), *weights, cfg,
-                                      cfg.first_expert)
-        out = out.reshape(b, s, d)
+        out, counts, probs, fits = _experts(y.reshape(b * s, d), *weights,
+                                            cfg, cfg.first_expert)
+        out, fits = out.reshape(b, s, d), () if fits is None else (fits,)
     else:
         # check_vma=False: pallas_call outputs carry no vma under shard_map
-        out, counts, probs = jax.shard_map(
+        out, counts, probs, fits = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axes.batch, axes.context, None), P(None, None),
                       P(ep, None, t), P(ep, None, t), P(ep, t, None)),
-            out_specs=(P(axes.batch, axes.context, None), P(None), P(None)),
+            out_specs=(P(axes.batch, axes.context, None), P(None), P(None),
+                       P()),
             check_vma=False)(y, *weights)
-    return out, counts / (b * s), probs / (b * s)
+    return out, (counts / (b * s), probs / (b * s), *fits)
 
 
 # --- forward ---------------------------------------------------------------
@@ -952,8 +1171,11 @@ def _period(kinds: tuple) -> int:
 def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
              mesh: Optional[Mesh], axes: MeshAxes):
     """tokens (b, s) int32 -> (logits (b, s, vocab), routing statistics
-    ``{"moe_aux_loss", "moe_load_max_over_mean"}`` and, of a held slice,
-    ``"moe_local_share"``: float32 scalars)."""
+    ``{"moe_aux_loss", "moe_load_max_over_mean"}``, of a held slice
+    ``"moe_local_share"`` and, where a device holds fewer experts than the
+    router scores, ``"moe_compact_share"`` (the expert layers that took the
+    work list of ``_experts``; 1 unless a routing overflowed its bound):
+    float32 scalars)."""
     if _serving_only(cfg):
         raise NotImplementedError(
             "the train forward runs linear and full-attention layers of "
@@ -987,18 +1209,19 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
             x = x + _gated_delta_net(y, mp, cfg)
             x = act_constraint(x, P(axes.batch, axes.context, None))
         y = _norm(x, lp["mlp_norm"], cfg)
-        moe_out, load, prob = _moe_block(y, lp, cfg, mesh, axes)
+        moe_out, stat = _moe_block(y, lp, cfg, mesh, axes)
         if cfg.n_shared_experts:
             moe_out = moe_out + _shared(y, lp)
         x = x + moe_out
         x = act_constraint(x, P(axes.batch, axes.context, None))
-        return x, (load, prob)
+        return x, stat
 
     step = {kind: llama._remat(functools.partial(layer, kind), cfg)
             for kind in TRAIN_KINDS}
     if not cfg.layer_types:
-        x, (load, prob) = lax.scan(lambda x, lp: step["full"](x, lp, lp),
-                                   x, params["layers"])       # (L, E) each
+        x, (load, prob, *fits) = lax.scan(
+            lambda x, lp: step["full"](x, lp, lp), x,
+            params["layers"])                                 # (L, E) each
     else:
         # one scan turn is one period of the pattern, its layers in order
         kinds = cfg.layer_types[:_period(cfg.layer_types)]
@@ -1019,7 +1242,7 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
                 stats.append(stat)
             return x, jax.tree.map(lambda *a: jnp.stack(a), *stats)
 
-        x, (load, prob) = lax.scan(period, x, (
+        x, (load, prob, *fits) = lax.scan(period, x, (
             folded(params["layers"], len(kinds)),
             {kind: folded(params[kind + "_layers"], kinds.count(kind))
              for kind in set(kinds)}))
@@ -1037,6 +1260,9 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
         # assignments on the experts held here over all assignments
         held = load[:, cfg.first_expert:cfg.first_expert + cfg.n_held]
         stats["moe_local_share"] = jnp.sum(held) / jnp.sum(load)
+    if fits:
+        # the layers (and devices) that worked on their own rows only
+        stats["moe_compact_share"] = jnp.mean(fits[0])
     return logits, stats
 
 

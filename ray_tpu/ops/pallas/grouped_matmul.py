@@ -48,8 +48,17 @@ sixteen rows deep, so the kernels read a PACKED copy of ``src``
 VMEM by strided loads, a shift and a mask. The ids are a sixth prefetched
 scalar array (131,072 int32, 512 KB, fit). A tile is fetched whole if any
 step visits it: ``gmm_rows`` neither fetches nor multiplies the tiles that
-lie wholly in the tail (the expert mesh's foreign assignments) and writes
-their zeros; ``tgmm`` visits one only for an empty group's masked visit.
+lie wholly in the tail and writes their zeros; ``tgmm`` visits one only
+for an empty group's masked visit. The tail is the assignments of experts
+another device holds (a held slice, an expert mesh axis). The train step
+hands the kernels its work list (``models/moe.py _held_sum``: the first
+``R`` sorted assignments, ``R`` twice the device's expected share in
+whole row tiles), so a call's tail is at most ``R`` less the device's own
+rows, about half of ``R``, and the row ids ``R`` int32 (80 KB in the cell
+whose 163,840 assignments took 640 KB). The tail of EVERY other device's
+assignments (fifteen rows of sixteen at 32 experts of 512) still comes
+where a routing overflows ``R`` (that step's full path) and in serving
+(``gmm_stacked`` below, and ``serve_block`` on a layer's own weights).
 Fused over gate and up (two rhs, two outputs; two dout, two gradients) a
 13-us visit hides the fetch to within 1.8 us (``gmm_rows``: 7.7 ms for
 6.7) and 1.5-3.3 us (``tgmm``: 8.0-9.0 for 7.1): measured, PERF.md, PR 43.
@@ -69,8 +78,9 @@ visit would do eight times the rows the step has for it), an empty group is
 never visited (its weights are not read), and a visit of the tail writes
 its zeros without a product and without a weight block: its steps, like
 those past the last visit, name the block the last multiplying visit held
-(the train kernels keep their tail's product: their tail is empty, and
-their contraction is one block, so a repeated step names one block too).
+(the train kernels keep their tail's product: their tail is empty or
+under ``R`` rows, and their contraction is one block, so a repeated step
+names one block too).
 The small-tile variant is named ``moe_gmm_decode``.
 """
 

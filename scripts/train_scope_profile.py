@@ -3,7 +3,9 @@ milliseconds a step of every ``jax.named_scope`` the models set
 (``gdn.chunk``, ``gdn.scan``, ``gdn.conv``, ``gdn.proj``, ``gdn.gate_norm``,
 ``gdn.out``, ``attention``, ``attention.gate``, ``moe.route``,
 ``moe.experts``, ``moe.combine``, ``moe.shared``, ``loss``, ``optimizer``)
-and of every Pallas kernel by its name. The benchmark's own reduction
+and of every Pallas kernel by its name, with the step's own metrics beside
+them (``moe_local_share``, ``moe_compact_share``: the expert layers that
+worked on their own rows only). The benchmark's own reduction
 (``benchmarks/harness/xplane.py``) reads event names only, and a train
 cell keeps no profile for its readers: this is what PERF.md's tables by
 scope come from. The compiled step's text gives each instruction its

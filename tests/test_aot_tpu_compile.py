@@ -767,4 +767,11 @@ def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
                 and "moe_gmm" in op["name"], op["name"]
     # one full layer in four: forward, the remat's forward, one backward
     assert flash == {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
-    assert len(ops) - 4 == 4 * 8, [op["name"] for op in ops]
+    # the grouped matmuls: eight a layer on the work list (``moe._held_sum``:
+    # 20,480 rows of the 163,840), and the same eight on the full path,
+    # the other side of the layer's branch
+    assert len(ops) - 4 == 2 * 4 * 8, [op["name"] for op in ops]
+    # the row ids, ``moe_gmm_rows``' sixth prefetched scalar array
+    assert {op["operands"][5] for op in ops
+            if "moe_gmm_rows" in op["name"]} \
+        == {("s32", (20480,)), ("s32", (163840,))}
