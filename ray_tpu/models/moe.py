@@ -81,7 +81,13 @@ RoPE), RoPE on the first ``rotary_dim`` dimensions of a head only, and
 ``attn_output_gate`` (the q projection is twice as wide, a head's second
 half a gate: o * sigmoid(gate) before the output projection). A "linear"
 layer is a Gated DeltaNet (``_gated_delta_net``; the rule itself is
-``ops/gated_delta.py``). ``zero_centered_norm`` makes every RMSNorm
+``ops/gated_delta.py``). Between its input and output projections the
+mixer is head-major, (b, heads, s, 128): q, k, v and z are each the
+product with their own columns of ``w_qkvz``, the causal conv + silu + L2
+norm of a kind (``_conv_silu_norm``) and the gated norm (``_gated_norm``)
+are each one function with a hand-written backward that keeps its bf16
+inputs only, and no activation is reshaped across the minor dimension or
+stored in float32 on the way. ``zero_centered_norm`` makes every RMSNorm
 x * rsqrt(mean(x^2) + eps) * (1 + w) but the linear layer's gated one. The
 kinds' parameters are stacks of their own (``linear_layers``,
 ``full_layers``) beside ``layers``, which holds what every layer has; the
@@ -1114,51 +1120,150 @@ def _attention(y, mp, cfg: MoEConfig, rope, mesh, axes):
     return o.reshape(b, s, h * hd) @ mp["wo"]
 
 
-def _l2norm(x, eps=1e-6):
-    x32 = x.astype(jnp.float32)
-    return x32 * lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
-
-
-def _causal_conv(x, w):
-    """Depthwise causal convolution: x (b, s, C), w (C, K) -> (b, s, C)
-    float32, ``out[t] = sum_j w[:, j] x[t - (K - 1) + j]``."""
-    s, taps = x.shape[1], w.shape[1]
-    x = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+def _conv_silu(x, w):
+    """x (b, h, s, d), w (h, d, K) -> float32 (the depthwise causal conv
+    along the tokens ``sum_j w[..., j] x[t - (K - 1) + j]``, zeros before
+    the row; its sigmoid; its silu)."""
+    s, taps = x.shape[2], w.shape[-1]
+    x = jnp.pad(x, ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    return sum(x[:, j:j + s].astype(jnp.float32) * w[:, j]
-               for j in range(taps))
+    pre = sum(x[:, :, j:j + s].astype(jnp.float32) * w[:, None, :, j]
+              for j in range(taps))
+    sg = jax.nn.sigmoid(pre)
+    return pre, sg, pre * sg
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv_silu_norm(x, w, scale):
+    """One pass over a kind's heads, head-major: x (b, h, s, d) the
+    projection's product, w (h, d, K) its conv channels -> silu(conv(x)),
+    L2-normalised over ``d`` and times ``scale`` unless ``scale`` is None,
+    in x's dtype; float32 in between. Its backward keeps x and w alone."""
+    with jax.named_scope("gdn.conv"):
+        a = _conv_silu(x, w)[2]
+        if scale is not None:
+            a = a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6) \
+                * scale
+        return a.astype(x.dtype)
+
+
+def _conv_silu_norm_fwd(x, w, scale):
+    return _conv_silu_norm(x, w, scale), (x, w)
+
+
+def _conv_silu_norm_bwd(scale, res, g):
+    """Three reads of x, none of a float32 array: (1) of a normed kind,
+    the per-head sums ``a.a`` and ``g.a``; (2) conv, silu and norm computed
+    anew, ONE gradient of the conv's pre-activation written, in x's dtype;
+    (3) the transposed conv (``K`` shifted reads of it, summed in float32)
+    and the conv weights' gradient from the same array. Each pass takes
+    its operands through ``lax.optimization_barrier``: where two passes (or
+    this backward and the remat's forward beside it) share an expression,
+    the compiler stores the float32 silu(conv) for both, and fused into its
+    readers the gradient would be computed again at every shift."""
+    f32 = jnp.float32
+    with jax.named_scope("gdn.conv"):
+        x, w = lax.optimization_barrier(res)
+        s, taps = x.shape[2], w.shape[-1]
+        if scale is not None:
+            a = _conv_silu(x, w)[2]
+            sums = (jnp.sum(a * a, -1, keepdims=True),
+                    jnp.sum(g.astype(f32) * a, -1, keepdims=True))
+            x, w, g, (ss, ga) = lax.optimization_barrier((x, w, g, sums))
+        pre, sg, a = _conv_silu(x, w)
+        g = g.astype(f32)
+        if scale is not None:
+            r = lax.rsqrt(ss + 1e-6)
+            g = (g - a * (r * r * ga)) * (r * scale)
+        d_pre = lax.optimization_barrier(
+            (g * sg * (1.0 + pre * (1.0 - sg))).astype(x.dtype))
+        # d_pre[u + (K - 1) - j], zeros past the row: what tap j of token
+        # u's input met, for the transposed conv and, against x[u], for
+        # the weights' gradient (one set of shifted reads serves both)
+        ahead = jnp.pad(d_pre, ((0, 0), (0, 0), (0, taps - 1), (0, 0)))
+        ahead = [ahead[:, :, taps - 1 - j:taps - 1 - j + s].astype(f32)
+                 for j in range(taps)]
+        w32, x32 = w.astype(f32), x.astype(f32)
+        d_x = sum(d * w32[:, None, :, j] for j, d in enumerate(ahead))
+        d_w = jnp.stack([jnp.sum(d * x32, axis=(0, 2)) for d in ahead], -1)
+        return d_x.astype(x.dtype), d_w.astype(w.dtype)
+
+
+_conv_silu_norm.defvjp(_conv_silu_norm_fwd, _conv_silu_norm_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gated_norm(o, z, w, eps):
+    """``rmsnorm(o) * w * silu(z)`` over the minor dimension, head-major: o,
+    z (b, h, s, d), w (d,) -> o's dtype, float32 in between and rounded
+    once. Its backward keeps o, z and w alone."""
+    with jax.named_scope("gdn.gate_norm"):
+        o32 = o.astype(jnp.float32)
+        var = jnp.mean(o32 * o32, -1, keepdims=True)
+        return (o32 * lax.rsqrt(var + eps) * w.astype(jnp.float32)
+                * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+
+
+def _gated_norm_fwd(o, z, w, eps):
+    return _gated_norm(o, z, w, eps), (o, z, w)
+
+
+def _gated_norm_bwd(eps, res, g):
+    o, z, w = res
+    f32 = jnp.float32
+    with jax.named_scope("gdn.gate_norm"):
+        o32, z32, w32, g = o.astype(f32), z.astype(f32), w.astype(f32), \
+            g.astype(f32)
+        r = lax.rsqrt(jnp.mean(o32 * o32, -1, keepdims=True) + eps)
+        n, sg = o32 * r, jax.nn.sigmoid(z32)
+        gn, gate = g * n, z32 * sg
+        d_n = g * w32 * gate
+        d_o = r * (d_n - n * jnp.mean(d_n * n, -1, keepdims=True))
+        d_z = gn * w32 * (sg * (1.0 + z32 * (1.0 - sg)))
+        d_w = jnp.sum(gn * gate, axis=(0, 1, 2))
+        return d_o.astype(o.dtype), d_z.astype(z.dtype), d_w.astype(w.dtype)
+
+
+_gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
 
 
 def _gated_delta_net(y, mp, cfg: MoEConfig):
     """A linear mixer (Gated DeltaNet): y (b, s, d) normed -> (b, s, d).
     ``w_qkvz``'s columns are [q | k | v | z] and ``w_ba``'s [b | a], each
     kind's heads in order (value head j reads key head j // r); a row is
-    a multiple of ``gated_delta.CHUNK`` tokens."""
-    b, s, _ = y.shape
+    a multiple of ``gated_delta.CHUNK`` tokens.
+
+    Between the two projections every activation is HEAD-MAJOR, (b, heads,
+    s, head width): q, k, v and z are each y's product with their own
+    columns of ``w_qkvz`` (a view of the weight, (d, heads, width)), which
+    writes that layout; the conv shifts along ``s``, both norms reduce over
+    the minor dimension, the rule splits ``s`` into chunks, and ``w_out``'s
+    product contracts heads and width. Nothing in between is reshaped
+    across the minor dimension or stored in float32."""
+    d = y.shape[-1]
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
-    dk, dv, r = cfg.linear_key_dim, cfg.linear_value_dim, hv // hk
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
     kw, vw = cfg.linear_widths
     f32 = jnp.float32
     with jax.named_scope("gdn.proj"):
-        qkvz = y @ mp["w_qkvz"]
-        mixed, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
-        ba = (y @ mp["w_ba"]).astype(f32)
-        beta = jax.nn.sigmoid(ba[..., :hv])                 # (b, s, hv)
-        g = -jnp.exp(mp["A_log"]) * jax.nn.softplus(
-            ba[..., hv:] + mp["dt_bias"])
-    with jax.named_scope("gdn.conv"):
-        mixed = jax.nn.silu(_causal_conv(mixed, mp["conv"]))
-        q, k, v = jnp.split(mixed, [kw, 2 * kw], axis=-1)
-        q = (_l2norm(q.reshape(b, s, hk, dk)) * dk ** -0.5).astype(y.dtype)
-        k = _l2norm(k.reshape(b, s, hk, dk)).astype(y.dtype)
-        q, k = jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2)
-        v = v.reshape(b, s, hv, dv).astype(y.dtype)
+        def heads(first, h, width):
+            w = mp["w_qkvz"][:, first:first + h * width]
+            return jnp.einsum("bsd,dhk->bhsk", y, w.reshape(d, h, width))
+        q, k = heads(0, hk, dk), heads(kw, hk, dk)
+        v, z = heads(2 * kw, hv, dv), heads(2 * kw + vw, hv, dv)
+        ba = jnp.einsum("bsd,dh->bhs", y, mp["w_ba"]).astype(f32)
+        beta = jax.nn.sigmoid(ba[:, :hv])                   # (b, hv, s)
+        g = -jnp.exp(mp["A_log"])[:, None] * jax.nn.softplus(
+            ba[:, hv:] + mp["dt_bias"][:, None])
+    conv = mp["conv"]
+    q = _conv_silu_norm(q, conv[:kw].reshape(hk, dk, -1), dk ** -0.5)
+    k = _conv_silu_norm(k, conv[kw:2 * kw].reshape(hk, dk, -1), 1.0)
+    v = _conv_silu_norm(v, conv[2 * kw:].reshape(hv, dv, -1), None)
     o, _ = gated_delta.chunk_gated_delta_rule(q, k, v, g, beta)
-    with jax.named_scope("gdn.gate_norm"):
-        o = _rmsnorm(o, mp["gdn_norm"], cfg.norm_eps) \
-            * jax.nn.silu(z.reshape(b, s, hv, dv).astype(f32)).astype(y.dtype)
+    o = _gated_norm(o, z, mp["gdn_norm"], cfg.norm_eps)
     with jax.named_scope("gdn.out"):
-        return o.reshape(b, s, hv * dv) @ mp["w_out"]
+        return jnp.einsum("bhsv,hvd->bsd", o,
+                          mp["w_out"].reshape(hv, dv, d))
 
 
 def _period(kinds: tuple) -> int:

@@ -21,6 +21,15 @@ c = 64) and applied by matmuls. Between chunks (``gdn.scan``) a ``lax.scan``
 carries the state: ``V' = U - W S``, ``o = (exp(G) Q) S + lower(Q K^T D) V'``,
 ``S <- exp(G_c) S + (exp(G_c - G) K)^T V'``. Every exponent is <= 0.
 
+Layouts. ``chunk_gated_delta_rule`` takes and returns HEAD-MAJOR arrays,
+(b, heads, s, width), the layout ``models/moe.py``'s mixer works in: its
+entry splits ``s`` into chunks and moves the chunk axis to the front,
+(n, b, h, c, width), a move of a major axis in the operands' dtype, and its
+exit moves it back after the one rounding of ``o``. q and k may come with
+fewer heads than v (a key head serves ``h // hk`` value heads): they are
+repeated as the chunked layout is written. ``row_major`` holds an array in
+memory in the order of its dimensions, on either side of both moves.
+
 Gates, decays and the carried state are float32; the matmuls take their
 operands in the dtype of ``q`` (bf16 in a bf16 model: the state is rounded
 for a product, never where it is carried) and accumulate in float32. The
@@ -34,11 +43,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # tokens a chunk: the matrices within one are CHUNK x CHUNK
 CHUNK = 64
 # of the products that build a chunk's inverse (float32 operands)
 SOLVE_PRECISION = lax.Precision.HIGH
+
+
+def row_major(x):
+    """x, held in memory in the order of its dimensions. Left to itself
+    the compiler lays a head-major product out as its matmul likes it
+    (tokens minor) and carries float32 converts across the move into the
+    chunked layout: a constraint on either side of a move keeps the move
+    in x's dtype (with either pair of the four left out a float32 copy
+    comes back). Its cotangent is held the same way."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
 def _mm(a, b, spec):
@@ -88,20 +109,24 @@ unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
-    """q, k (b, s, h, dk), v (b, s, h, dv); g, beta (b, s, h) float32 ->
-    (o (b, s, h, dv) in v's dtype, the final state (b, h, dk, dv) float32).
-    ``s`` is a multiple of ``chunk``, ``chunk`` a power of two. q and k come
-    normalised and scaled as the layer wants them."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    """HEAD-MAJOR in and out: q, k (b, hk, s, dk), v (b, h, s, dv); g, beta
+    (b, h, s) float32 -> (o (b, h, s, dv) in v's dtype, the final state
+    (b, h, dk, dv) float32). ``hk`` divides ``h``: value head j reads key
+    head j // (h // hk), repeated as the chunked layout is written and
+    nowhere before. ``s`` is a multiple of ``chunk``, ``chunk`` a power of
+    two. q and k come normalised and scaled as the layer wants them."""
+    b, h, s, dv = v.shape
+    dk = q.shape[-1]
     c = chunk
     assert s % c == 0 and c & (c - 1) == 0, (s, c)
+    assert h % q.shape[1] == 0 and q.shape == k.shape, (q.shape, k.shape, h)
     n = s // c
     cd, f32 = q.dtype, jnp.float32
 
-    def chunks(x):      # (b, s, h, ...) -> (n, b, h, c, ...)
-        x = x.reshape(b, n, c, h, *x.shape[3:])
-        return jnp.moveaxis(x, (1, 3), (0, 2))
+    def chunks(x):      # (b, h or hk, s, ...) -> (n, b, h, c, ...)
+        x = row_major(x).reshape(*x.shape[:2], n, c, *x.shape[3:])
+        x = row_major(jnp.moveaxis(x, 2, 0))
+        return jnp.repeat(x, h // x.shape[2], axis=2)
 
     with jax.named_scope("gdn.chunk"):
         q, k, v = chunks(q), chunks(k), chunks(v)
@@ -138,8 +163,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
                                          (w, u, qg, k_end, g_end))
     with jax.named_scope("gdn.chunk"):
         o = inter + _mm(qk, v_new, "...ij,...jd->...id")     # (n, b, h, c, dv)
-        o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, s, h, dv)
-    return o.astype(v.dtype), state
+        o = jnp.moveaxis(row_major(o.astype(v.dtype)), 0, 2)
+    return row_major(o.reshape(b, h, s, dv)), state
 
 
 def recurrent_gated_delta_step(q, k, v, g, beta, state):

@@ -114,7 +114,7 @@ def main() -> int:
                        for ev in lines["XLA Modules"].events],
            "by_scope_ms": dict(sorted(by_scope.items(),
                                       key=lambda kv: -kv[1])),
-           "ops": sorted(by_op.values(), key=lambda r: -r[2])[:400],
+           "ops": sorted(by_op.values(), key=lambda r: -r[2])[:1500],
            "metrics": {k: float(v) for k, v in met.items()},
            "memory": jax.devices()[0].memory_stats()}
     print(json.dumps({k: out[k] for k in ("step_ms", "by_scope_ms",

@@ -11,6 +11,7 @@ every interpret-mode test while the compiler refused it at every shape.
 the clock never reaches guards nothing.)
 """
 
+import math
 import os
 import re
 import sys
@@ -350,11 +351,10 @@ def _decode_args(chip, wq=None, tp=None, cfg=None):
                          shape((2,), jnp.uint32), cfg, 8, None, None]
 
 
-def _with_result_of(compiled, shapes):
+def _top_level_ops(compiled):
     """(parsed instruction, its line) for every instruction of the
-    compiled program with a result of one of ``shapes``, outside the
-    fused computations (what a fusion makes is its own result's shape)
-    and the Pallas kernels."""
+    compiled program outside the fused computations (what a fusion makes
+    is its own result's shape)."""
     bench = _bench_kernels()
     text = compiled.as_text()
     fused = set(re.findall(r"calls=%([\w.\-]+)", text))
@@ -365,7 +365,13 @@ def _with_result_of(compiled, shapes):
             computation = head.group(1)
         if head or computation in fused:
             continue
-        op = bench.parse_op(ln.strip().removeprefix("ROOT "))
+        yield bench.parse_op(ln.strip().removeprefix("ROOT ")), ln
+
+
+def _with_result_of(compiled, shapes):
+    """``_top_level_ops`` with a result of one of ``shapes``, the Pallas
+    kernels apart."""
+    for op, ln in _top_level_ops(compiled):
         if shapes & {dims for _, dims in op["result"]} \
                 and not op.get("custom_kernel"):
             yield op, ln
@@ -775,3 +781,39 @@ def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
     assert {op["operands"][5] for op in ops
             if "moe_gmm_rows" in op["name"]} \
         == {("s32", (20480,)), ("s32", (163840,))}
+
+
+def test_the_linear_mixer_moves_no_float32_activation(chip):
+    """``models/moe.py _gated_delta_net``, forward and backward at the
+    cell's shapes (batch 4 x 4096, hidden 2048; no remat around it): between
+    its two projections the mixer is head-major and its elementwise stages
+    keep bf16 residuals only, so the compiled program holds NO relayout and
+    no stored broadcast of an activation in float32 (before PR 47: nine
+    ``copy f32[2048,8,32,128]`` and its like, the L2 norm's ``rsqrt``
+    broadcast to full size, the conv's float32 result kept for the
+    backward), and its bytes stay under a cap set from the finished change
+    (34.9 GB by the compiler's count, 47.4 at the parent; the chunked rule
+    itself is 30 of them) with 15% of room."""
+    from ray_tpu.models import moe
+    file = "qwen3-next-80b-a3b-train-ep16.json"
+    cfg = _cell_config(file, "qwen3_next")
+    mp = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=chip),
+        jax.eval_shape(lambda: moe.init_params(jax.random.PRNGKey(0), cfg))[
+            "linear_layers"])
+    y = jax.ShapeDtypeStruct((4, 4096, cfg.dim), jnp.bfloat16, sharding=chip)
+
+    def both(y, mp, ct):
+        out, vjp = jax.vjp(lambda y, mp: moe._gated_delta_net(y, mp, cfg),
+                           y, mp)
+        return out, vjp(ct)
+    compiled = jax.jit(both).lower(y, mp, y).compile()
+    big = 4 * 4096 * cfg.dim
+    moved = [(op["opcode"], op["name"], dims)
+             for op, _ in _top_level_ops(compiled)
+             if op["opcode"] in ("copy", "reshape", "broadcast")
+             for dtype, dims in op["result"]
+             if dtype == "f32" and math.prod(dims) >= big]
+    assert not moved, moved
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.cost_analysis()["bytes accessed"] < 1.15 * 34.9e9

@@ -1,7 +1,7 @@
 """The chunked gated delta rule (``ray_tpu/ops/gated_delta.py``) against
 the token-by-token recurrence it must agree with: float32 on the CPU,
 outputs, final state and the gradients of all five inputs, over at least
-four chunks."""
+four chunks. Head-major, as the rule takes them: (b, h, s, ...)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,27 +19,28 @@ def inputs():
     """Unit keys, scaled unit queries, decays from 'none a chunk' to 'gone
     in a chunk' by head, write strengths in (0, 1)."""
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    q = jax.random.normal(ks[0], (B, S, H, DK))
-    k = jax.random.normal(ks[1], (B, S, H, DK))
+    q = jax.random.normal(ks[0], (B, H, S, DK))
+    k = jax.random.normal(ks[1], (B, H, S, DK))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (B, S, H, DV))
-    g = -jax.random.uniform(ks[3], (B, S, H)) * jnp.array([0.002, 0.05, 0.5])
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    v = jax.random.normal(ks[2], (B, H, S, DV))
+    g = -jax.random.uniform(ks[3], (B, H, S)) \
+        * jnp.array([0.002, 0.05, 0.5])[:, None]
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, H, S)))
     return q, k, v, g, beta
 
 
 def recurrence(q, k, v, g, beta, state=None):
-    """The rule one token at a time: (o (b, s, h, dv), final state)."""
+    """The rule one token at a time: (o (b, h, s, dv), final state)."""
     if state is None:
-        state = jnp.zeros((q.shape[0], q.shape[2], q.shape[3], v.shape[3]))
+        state = jnp.zeros((q.shape[0], q.shape[1], q.shape[3], v.shape[3]))
 
     def token(state, x):
         o, state = gd.recurrent_gated_delta_step(*x, state)
         return state, o
     state, o = lax.scan(token, state, tuple(
-        jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)))
-    return jnp.moveaxis(o, 0, 1), state
+        jnp.moveaxis(a, 2, 0) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 2), state
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
@@ -87,18 +88,44 @@ def test_the_recurrent_step_continues_a_chunked_prefix(inputs, cut):
     """Serving's two halves: a prefix by chunks (of the module's CHUNK),
     then token by token from the state it left, is the whole row's rule."""
     assert gd.CHUNK == 64
-    head = [a[:, :cut] for a in inputs]
-    tail = [a[:, cut:] for a in inputs]
+    head = [a[:, :, :cut] for a in inputs]
+    tail = [a[:, :, cut:] for a in inputs]
     _, state = gd.chunk_gated_delta_rule(*head)
     o_tail, state = recurrence(*tail, state=state)
     want_o, want_state = gd.chunk_gated_delta_rule(*inputs)
-    np.testing.assert_allclose(o_tail, want_o[:, cut:], atol=2e-6)
+    np.testing.assert_allclose(o_tail, want_o[:, :, cut:], atol=2e-6)
     np.testing.assert_allclose(state, want_state, atol=5e-6)
 
 
 def test_a_row_that_is_no_multiple_of_the_chunk_is_refused(inputs):
     with pytest.raises(AssertionError):
-        gd.chunk_gated_delta_rule(*[a[:, :100] for a in inputs], chunk=64)
+        gd.chunk_gated_delta_rule(*[a[:, :, :100] for a in inputs], chunk=64)
+
+
+@pytest.mark.parametrize("which", ["o", "state", "q", "k"])
+def test_value_heads_that_share_a_key_head(inputs, which):
+    """q and k with fewer heads than v: value head j reads key head
+    j // r, as if they had been repeated, and a key head's gradient is the
+    sum over its value heads."""
+    q, k, v, g, beta = inputs
+    q, k = q[:, :1], k[:, :1]
+
+    def repeated(q, k):
+        return gd.chunk_gated_delta_rule(
+            jnp.repeat(q, H, axis=1), jnp.repeat(k, H, axis=1), v, g, beta)
+
+    def shared(q, k):
+        return gd.chunk_gated_delta_rule(q, k, v, g, beta)
+    if which in ("o", "state"):
+        i = ("o", "state").index(which)
+        np.testing.assert_allclose(shared(q, k)[i], repeated(q, k)[i],
+                                   atol=1e-6)
+        return
+    i = ("q", "k").index(which)
+    got = jax.grad(_objective(shared), argnums=i)(q, k)
+    want = jax.grad(_objective(repeated), argnums=i)(q, k)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(
+        jnp.abs(want).max()))
 
 
 def test_the_chunk_inverse_and_its_backward():

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from ray_tpu.models import moe
 from ray_tpu.parallel import MeshSpec, make_mesh, make_train_step
@@ -265,3 +266,102 @@ def test_a_train_step_learns_and_reports_its_local_share():
     for kind in ("linear_layers", "full_layers", "layers"):
         for name, by in moved[kind].items():
             assert by > 0, (kind, name)
+
+
+# --- the linear mixer's two fused stages against the plain expressions -------
+# token-major, as the mixer had them before it went head-major: the reference
+
+def _plain_conv_stage(mixed, conv, hk, hv, dk, dv):
+    """mixed (b, s, C) with C = [q | k | v], conv (C, K) -> q, k
+    (b, s, hk, dk), v (b, s, hv, dv): causal depthwise conv, silu, L2 norm
+    of q and k a head, q scaled."""
+    b, s, _ = mixed.shape
+    taps = conv.shape[1]
+    x = jnp.pad(mixed, ((0, 0), (taps - 1, 0), (0, 0)))
+    act = jax.nn.silu(sum(x[:, j:j + s] * conv[:, j] for j in range(taps)))
+    q, k, v = jnp.split(act, [hk * dk, 2 * hk * dk], axis=-1)
+
+    def l2norm(x):
+        return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    return (l2norm(q.reshape(b, s, hk, dk)) * dk ** -0.5,
+            l2norm(k.reshape(b, s, hk, dk)), v.reshape(b, s, hv, dv))
+
+
+def _fused_conv_stage(mixed, conv, hk, hv, dk, dv):
+    """The same through ``moe._conv_silu_norm``, a kind at a time on
+    head-major views, returned token-major."""
+    b, s, _ = mixed.shape
+    kw = hk * dk
+    out = []
+    for lo, hi, h, d, scale in ((0, kw, hk, dk, dk ** -0.5),
+                                (kw, 2 * kw, hk, dk, 1.0),
+                                (2 * kw, 2 * kw + hv * dv, hv, dv, None)):
+        x = mixed[..., lo:hi].reshape(b, s, h, d).transpose(0, 2, 1, 3)
+        y = moe._conv_silu_norm(x, conv[lo:hi].reshape(h, d, -1), scale)
+        out.append(y.transpose(0, 2, 1, 3))
+    return tuple(out)
+
+
+def _plain_gated_norm(o, z, w, eps):
+    """o, z (b, s, h, d): rmsnorm(o) * w * silu(z)."""
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    return o * lax.rsqrt(var + eps) * w * jax.nn.silu(z)
+
+
+def _fused_gated_norm(o, z, w, eps):
+    return moe._gated_norm(o.transpose(0, 2, 1, 3), z.transpose(0, 2, 1, 3),
+                           w, eps).transpose(0, 2, 1, 3)
+
+
+_HK, _HV, _DK, _DV = 2, 4, 16, 16      # hv != hk
+
+
+@pytest.fixture(scope="module")
+def stages():
+    """{stage: (outputs, gradients of a weighted sum of them by argument)}
+    for the plain and the fused expressions: two batch rows of 8 tokens, a
+    conv of 4 taps (the first three tokens' taps reach before the row)."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 8)
+    c = 2 * _HK * _DK + _HV * _DV
+    mixed = jax.random.normal(ks[0], (2, 8, c))
+    conv = jax.random.uniform(ks[1], (c, 4), minval=-0.5, maxval=0.5)
+    cot = [jax.random.normal(k, (2, 8, h, d)) for k, h, d in (
+        (ks[2], _HK, _DK), (ks[3], _HK, _DK), (ks[4], _HV, _DV))]
+    o = jax.random.normal(ks[5], (2, 8, _HV, _DV))
+    z = jax.random.normal(ks[6], (2, 8, _HV, _DV))
+    w = 1.0 + 0.1 * jax.random.normal(ks[7], (_DV,))
+    got = {}
+    for name, conv_stage, gated in (
+            ("plain", _plain_conv_stage, _plain_gated_norm),
+            ("fused", _fused_conv_stage, _fused_gated_norm)):
+        def conv_out(mixed, conv):
+            return conv_stage(mixed, conv, _HK, _HV, _DK, _DV)
+
+        def conv_obj(mixed, conv):
+            return sum(jnp.sum(a * g) for a, g in zip(conv_out(mixed, conv),
+                                                      cot))
+
+        def norm_obj(o, z, w):
+            return jnp.sum(gated(o, z, w, 1e-6) * cot[2])
+        got[name] = {
+            "conv": dict(zip(("q", "k", "v"), conv_out(mixed, conv))),
+            "conv_grad": dict(zip(("mixed", "conv"), jax.grad(
+                conv_obj, argnums=(0, 1))(mixed, conv))),
+            "gated_norm": {"out": gated(o, z, w, 1e-6)},
+            "gated_norm_grad": dict(zip(("o", "z", "weight"), jax.grad(
+                norm_obj, argnums=(0, 1, 2))(o, z, w)))}
+    return got
+
+
+@pytest.mark.parametrize("stage,which", [
+    ("conv", "q"), ("conv", "k"), ("conv", "v"), ("conv_grad", "mixed"),
+    ("conv_grad", "conv"), ("gated_norm", "out"), ("gated_norm_grad", "o"),
+    ("gated_norm_grad", "z"), ("gated_norm_grad", "weight")])
+def test_the_fused_stages_against_the_plain_expressions(stages, stage, which):
+    """conv + silu + L2 norm and the gated norm, each one function with a
+    hand-written backward on head-major arrays: outputs and every gradient
+    (inputs, conv weights, norm weight) are the plain token-major
+    expressions' under autodiff."""
+    want, got = stages["plain"][stage][which], stages["fused"][stage][which]
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
