@@ -370,7 +370,7 @@ class _Block:
     keep: np.ndarray            # (slots,) bool: the first token is the
                                 # predecessor's last row, on the device
     lengths: np.ndarray         # (slots,) first write positions
-    tables: object              # the block tables as it was prepared
+    tables: dict                # kind -> the block tables as prepared
     temps: np.ndarray
     top_ps: np.ndarray
     top_ks: np.ndarray
@@ -430,18 +430,17 @@ class LLMEngine:
             # partitions fine.
             import dataclasses
             cfg = dataclasses.replace(cfg, attn_impl="reference")
-        # a model with window layers keeps a second pool for them
-        # (kvcache.pool_kinds); None for the Llama family
-        self._kinds = kvcache.pool_kinds(cfg)
+        # the cache's layout, ((kind, its layers), ...): a pool, block
+        # ids and a table a kind, each a dict by kind for every model
+        self._layout = kvcache.pool_kinds(cfg)
+        windowed = kvcache.WINDOW in dict(self._layout)
         # (layers, window) of each layer kind the paged kernel walks:
         # what llm_decode_kv_fetch_tokens counts by
-        self._walks = [
-            (len(layers), lm.window_of(cfg, kind))
-            for kind, layers in self._kinds or (
-                (kvcache.GLOBAL, range(cfg.n_layers)),)]
+        self._walks = [(len(layers), lm.window_of(cfg, kind))
+                       for kind, layers in self._layout]
         self._idle_fetch: dict = {}     # block's steps -> what the walk
                                         # fetches for one idle slot
-        if mesh is not None and (self._kinds or lm.has_experts(cfg)):
+        if mesh is not None and (windowed or lm.has_experts(cfg)):
             raise NotImplementedError(
                 "tensor-parallel serving shards the Llama family's "
                 "parameter tree only (lm.serve_param_specs)")
@@ -477,7 +476,7 @@ class LLMEngine:
             kv_block_size = int(getattr(_cfg, "kvcache_block_size", 16))
         if kv_pool_blocks is None:
             kv_pool_blocks = int(getattr(_cfg, "kvcache_pool_blocks", 0))
-        if self._kinds:
+        if windowed:
             # a window layer frees the blocks its window has passed: a
             # cached prefix would have to keep them. Asked for: refused;
             # left to the Config's default: off
@@ -497,7 +496,7 @@ class LLMEngine:
                 "need a size")
         if spec is None:
             spec = bool(getattr(_cfg, "spec_decode", False))
-        if spec and self._kinds:
+        if spec and windowed:
             raise ValueError(
                 "speculative decoding is not supported with window "
                 "layers: the verify forward attends global layers only")
@@ -538,7 +537,7 @@ class LLMEngine:
         # from what is free beside it
         layer_tok = (cfg.n_kv_heads * cfg.head_dim * 2
                      * jnp.dtype(cache_dtype).itemsize)
-        n_window = len(dict(self._kinds or ()).get(kvcache.WINDOW, ()))
+        n_window = len(dict(self._layout).get(kvcache.WINDOW, ()))
         window = None
         if n_window:
             ring = kvcache.window_ring_blocks(
@@ -573,19 +572,18 @@ class LLMEngine:
             # compiles a second time for it
             from jax.sharding import SingleDeviceSharding
             self._tok_sharding = SingleDeviceSharding(
-                next(iter(self._pool["k"].devices())))
+                next(iter(kvcache.pool_k(self._pool).devices())))
             self._pool = {k: jax.device_put(v, self._tok_sharding)
                           for k, v in self._pool.items()}
         self._kv = kvcache.KVBlockManager(
             nb, self._block, table_width=self._table_w,
             prefix_cache=prefix_cache, metrics=self._kvm, window=window)
         self._block_bytes = kvcache.kind_block_bytes(self._pool)
-        self._tables = np.full((max_slots, self._table_w),
-                               kvcache.TRASH, np.int32)
-        # the window layers' tables (their own block ids), if any
-        self._wtables = np.full((max_slots, self._table_w),
-                                kvcache.TRASH, np.int32) if window else None
-        self._window_freed = 0
+        # every slot's block table, by kind (each kind its own ids)
+        self._tables = {kind: np.full((max_slots, self._table_w),
+                                      kvcache.TRASH, np.int32)
+                        for kind, _ in self._layout}
+        self._freed: dict = {}      # kind -> freed_by_kind() last seen
         self._blocked: deque = deque()   # admits parked on pool
         self._seq_counter = 0
         self._slots: List[Optional[_Request]] = [None] * max_slots
@@ -630,32 +628,29 @@ class LLMEngine:
     def stats(self) -> dict:
         """Scalar engine counters (the per-phase distributions live in
         the metrics registry — see engine_metrics())."""
-        return {"requests": self._requests,
-                "tokens_generated": self._tokens_generated,
-                "ttft_sum": self._ttft_sum,
-                "ttft_count": self._ttft_count,
-                "pid": os.getpid(),
-                "device": dict(self._device),
-                "prefill_impl": self._prefill_impl,
-                "block_size": self._block,
-                "pool_blocks": self._kv.num_blocks,
-                "blocks_used": self._kv.used_blocks(),
-                "blocks_cached": self._kv.cached_blocks(),
-                "blocks_free": self._kv.free_blocks(),
-                "prefix_hit_tokens": self._kv.hit_tokens_total,
-                "kv_impl": self._kv_impl,
-                "kv_interpret": self._kv_interpret,
-                "spec": self._spec,
-                **({"pool_blocks_window": self._kv.window_blocks,
-                    "blocks_used_window": self._kv.window_used_blocks(),
-                    "window_blocks_freed": self._kv.window_freed_total}
-                   if self._kinds else {})}
-
-    def _kv_per_token_bytes(self) -> float:
-        """Device bytes one KV position of one slot costs (both k and
-        v, all layers) — the unit request-level HBM attribution is
-        priced in."""
-        return kvcache.pool_block_bytes(self._pool) / self._block
+        out = {"requests": self._requests,
+               "tokens_generated": self._tokens_generated,
+               "ttft_sum": self._ttft_sum,
+               "ttft_count": self._ttft_count,
+               "pid": os.getpid(),
+               "device": dict(self._device),
+               "prefill_impl": self._prefill_impl,
+               "block_size": self._block,
+               "blocks_cached": self._kv.cached_blocks(),
+               "blocks_free": self._kv.free_blocks(),
+               "prefix_hit_tokens": self._kv.hit_tokens_total,
+               "kv_impl": self._kv_impl,
+               "kv_interpret": self._kv_interpret,
+               "spec": self._spec}
+        # the global layers' under the bare name, another kind's + _kind
+        for kind, used in self._kv.used_by_kind().items():
+            tail = "" if kind == kvcache.GLOBAL else "_" + kind
+            out["pool_blocks" + tail] = \
+                kvcache.pool_k(self._pool, kind).shape[1]
+            out["blocks_used" + tail] = used
+        for kind, freed in self._kv.freed_by_kind().items():
+            out[kind + "_blocks_freed"] = freed
+        return out
 
     @contextlib.contextmanager
     def _phase(self, name: str):
@@ -685,13 +680,15 @@ class LLMEngine:
         vLLM property); headroom = free blocks. The gauges ride the
         worker's metrics push to the head next to util/devmon.py's
         device_hbm_* series."""
-        bb = self._block_bytes[kvcache.GLOBAL]
-        live = self._kv.used_blocks() + self._kv.cached_blocks()
-        wb = self._block_bytes.get(kvcache.WINDOW, 0)
-        self._m["kv_bytes"].set(
-            bb * live + wb * self._kv.window_used_blocks())
-        self._m["kv_headroom"].set(
-            bb * self._kv.free_blocks() + wb * len(self._kv.wfree))
+        live = self._kv.used_by_kind()
+        # the prefix index holds blocks of the global layers only
+        live[kvcache.GLOBAL] += self._kv.cached_blocks()
+        self._m["kv_bytes"].set(self._bytes_of(live))
+        self._m["kv_headroom"].set(self._bytes_of(self._kv.free_by_kind()))
+
+    def _bytes_of(self, blocks: dict) -> int:
+        """Device bytes of ``blocks`` = {kind: block ids of its pool}."""
+        return sum(n * self._block_bytes[kind] for kind, n in blocks.items())
 
     # --- public API -----------------------------------------------------
 
@@ -1166,43 +1163,35 @@ class LLMEngine:
         # is not in the block (idle, or ending inside the block in
         # flight) writes to the trash block.
         out = [i for i in range(n) if i not in reqs]
-        tables = self._tables.copy()
-        tables[out] = kvcache.TRASH
-        if self._kinds:
-            wtables = self._wtables.copy()
-            wtables[out] = kvcache.TRASH
-            tables = {kvcache.GLOBAL: tables, kvcache.WINDOW: wtables}
+        tables = {kind: t.copy() for kind, t in self._tables.items()}
+        for t in tables.values():
+            t[out] = kvcache.TRASH
         return _Block(block, reqs, lens, tokens, keep, lengths, tables,
                       temps, top_ps, top_ks, member_traces, first_ctx)
 
     def _set_aside(self, reqs: dict, lens: List[int], block: int) -> None:
         """Before a decode block of ``block`` steps over ``reqs`` (slot
         -> request; ``lens`` the positions each holds as the block
-        starts: prompt + emitted + what the block in flight emits): a
-        model with window layers gets the window-layer blocks the block
-        writes and gives back the ones each sequence's window has
-        passed (kvcache.advance_window); then what the pool holds by
-        layer kind is observed, for any model. The block in flight may
+        starts: prompt + emitted + what the block in flight emits): the
+        manager moves the table rows that move (a window layer gets the
+        blocks the block writes and gives back the ones the sequence's
+        window has passed: KVBlockManager.advance); then what the pool
+        holds by layer kind is observed. The block in flight may
         still read a ring block given back here: whatever writes it
         next (this block, through the sequence that takes it, or a
         later prefill) is enqueued behind the block in flight, and the
         device runs them in that order."""
-        if self._kinds:
-            for (i, r), n in zip(reqs.items(), lens):
-                self._wtables[i] = self._kv.advance_window(
-                    r.seq, n - 1, block)
-        live = sum(lens)
-        used = {kvcache.GLOBAL: self._kv.used_blocks()}
-        if self._kinds:
-            used[kvcache.WINDOW] = self._kv.window_used_blocks()
-            freed = self._kv.window_freed_total
-            self._m["kv_window_freed"].observe(freed - self._window_freed)
-            self._window_freed = freed
+        for (i, r), n in zip(reqs.items(), lens):
+            for kind, row in self._kv.advance(r.seq, n - 1, block).items():
+                self._tables[kind][i] = row
+        used = self._kv.used_by_kind()
         for kind, n in used.items():
             self._m["kv_blocks_" + kind].observe(n)
-        self._m["kv_used_bytes"].observe(
-            sum(n * self._block_bytes[kind] for kind, n in used.items()))
-        self._m["kv_live_tokens"].observe(live)
+        for kind, n in self._kv.freed_by_kind().items():
+            self._m[f"kv_{kind}_freed"].observe(n - self._freed.get(kind, 0))
+            self._freed[kind] = n
+        self._m["kv_used_bytes"].observe(self._bytes_of(used))
+        self._m["kv_live_tokens"].observe(sum(lens))
 
     def _account_block(self, lens: List[int], block: int,
                        counts: Optional[dict]) -> None:
@@ -1329,12 +1318,9 @@ class LLMEngine:
                 "engine", "queue", r.trace, r.trace.span_id,
                 r.t_submit_wall,
                 r.t_submit_wall + (r.admitted_at - r.submitted))
-        table = r.kv_alloc["table"]
         hit = r.prefix_hit
-        B = self._block
-        self._tables[slot] = table
-        if self._kinds:
-            self._wtables[slot] = r.kv_alloc["window_table"]
+        for kind, row in r.kv_alloc["tables"].items():
+            self._tables[kind][slot] = row
         with self._phase("prefill.dispatch") as disp:
             if r.prefilled is not None:
                 # device TTFT for a disaggregated request is the
@@ -1355,11 +1341,9 @@ class LLMEngine:
                 # shared prefix blocks (a hit makes the shipped bytes
                 # for them redundant) and beyond-horizon slots write
                 # to trash
-                targets = table.copy()
-                targets[:hit // B] = kvcache.TRASH
                 self._pool = kvcache.scatter_table(
-                    self._pool, acc, self._targets(targets, slot),
-                    self._kinds)
+                    self._pool, acc,
+                    self._targets(slot, self._table_w, hit), self._layout)
             elif hit == 0 and n <= self.buckets[-1]:
                 # cache-cold short prompt: one lm.prefill forward,
                 # padded only to its bucket; pad-garbage blocks
@@ -1368,40 +1352,38 @@ class LLMEngine:
                 padded = lm.pad_prompt(r.tokens, b)
                 logits, kv = lm.prefill(self.params, jnp.asarray(padded),
                                         jnp.int32(n), self.cfg, b)
-                nb = b // B
-                phys = np.full((nb,), kvcache.TRASH, np.int32)
-                phys[:min(nb, self._table_w)] = table[:min(
-                    nb, self._table_w)]
+                nb = b // self._block
                 self._pool = kvcache.scatter_bucket(
-                    self._pool, kv, self._targets(phys, slot), nb,
-                    self._kinds)
+                    self._pool, kv, self._targets(slot, nb), nb,
+                    self._layout)
                 ran = n
             else:
-                logits = self._prefill_into_blocks(r, table, hit, slot)
+                logits = self._prefill_into_blocks(r, hit, slot)
                 ran = n - self._prefill_start(hit)
-        return self._first_token(slot, r, disp, logits,
-                                 self._pool["k"], ran)
+        return self._first_token(slot, r, disp, logits, ran)
 
-    def _targets(self, phys: np.ndarray, slot: int):
-        """The physical ids a prefill's KV is written through: ``phys``
-        for the global layers and, for a model with window layers, the
-        slot's window table cut to the same width beside it (by kind):
-        only the blocks the first decode step's window reaches are
-        allocated there, the rest of the prompt's go to trash."""
+    def _targets(self, slot: int, width: int, hit: int = 0) -> dict:
+        """The physical ids a prefill's KV goes through, by layer kind:
+        ``slot``'s table rows over ``width`` blocks (a bucket wider
+        than the table ends in trash: pad garbage), the first ``hit``
+        tokens' blocks to trash (a shared prefix's: never written). A
+        row names trash wherever it holds no block: past the horizon
+        and, in a window layer, below the first decode step's window."""
         _, jnp = _jx()
-        if not self._kinds:
-            return jnp.asarray(phys)
-        wphys = np.full(phys.shape, kvcache.TRASH, np.int32)
-        w = min(len(phys), self._table_w)
-        wphys[:w] = self._wtables[slot][:w]
-        return {kvcache.GLOBAL: jnp.asarray(phys),
-                kvcache.WINDOW: jnp.asarray(wphys)}
+        w = min(width, self._table_w)
+        out = {}
+        for kind, tables in self._tables.items():
+            ids = np.full((width,), kvcache.TRASH, np.int32)
+            ids[:w] = tables[slot, :w]
+            ids[:hit // self._block] = kvcache.TRASH
+            out[kind] = jnp.asarray(ids)
+        return out
 
     def _first_token(self, slot: int, r: _Request, disp, logits,
-                     written, ran: int) -> int:
+                     ran: int) -> int:
         """The end of every admit path: wait for the prefill the
-        ``disp`` phase dispatched (its logits, and ``written``, the
-        cache it wrote), then sample the first token on the host.
+        ``disp`` phase dispatched (its logits, and the cache it
+        wrote), then sample the first token on the host.
         Dispatch is async, so the wall clock alone cannot attribute a
         slow first token to compute or to queueing: dispatch start to
         wait end bounds the DEVICE portion of TTFT. ``ran`` is how
@@ -1409,7 +1391,7 @@ class LLMEngine:
         jax, _ = _jx()
         with self._phase("prefill.wait") as wait:
             logits_np = np.asarray(logits)
-            jax.block_until_ready(written)
+            jax.block_until_ready(kvcache.pool_k(self._pool))
         if ran:
             self._m["prefill_tokens"].observe(ran)
         r.kv_written = True
@@ -1449,8 +1431,7 @@ class LLMEngine:
         chunk = self.buckets[-1]
         return (hit // chunk) * chunk
 
-    def _prefill_into_blocks(self, r: _Request, table: np.ndarray,
-                             hit: int, slot: int):
+    def _prefill_into_blocks(self, r: _Request, hit: int, slot: int):
         """Prefix-hit (and long-prompt) prefill: gather the table's
         cached blocks into a contiguous accumulator, run the suffix
         through lm.prefill_chunk at the prefix offset (pieces aligned
@@ -1461,11 +1442,10 @@ class LLMEngine:
         (their scatter targets are the trash block)."""
         _, jnp = _jx()
         n = len(r.tokens)
-        B = self._block
         chunk = self.buckets[-1]
-        acc_len = self._acc_len()
-        acc = kvcache.gather_table(self._pool, self._targets(table, slot),
-                                   acc_len, self._kinds)
+        acc = kvcache.gather_table(
+            self._pool, self._targets(slot, self._table_w),
+            self._acc_len(), self._layout)
         off = self._prefill_start(hit)
         logits = None
         while off < n:
@@ -1477,10 +1457,9 @@ class LLMEngine:
                 self.params, jnp.asarray(padded),
                 jnp.int32(len(part)), jnp.int32(off), acc, self.cfg)
             off = end
-        targets = table.copy()
-        targets[:hit // B] = kvcache.TRASH
         self._pool = kvcache.scatter_table(
-            self._pool, acc, self._targets(targets, slot), self._kinds)
+            self._pool, acc, self._targets(slot, self._table_w, hit),
+            self._layout)
         return logits
 
     @staticmethod
@@ -1560,15 +1539,13 @@ class LLMEngine:
                     fl.last, blk.tokens, blk.keep, self._tok_sharding)
             else:
                 tokens = jax.device_put(blk.tokens, self._tok_sharding)
-            tables = blk.tables
-            tables = {k: jnp.asarray(t) for k, t in tables.items()} \
-                if self._kinds else jnp.asarray(tables)
             blk.out, self._pool, blk.counts, blk.last = \
                 kvcache.decode_steps_program(
                     self._pool, impl=self._kv_impl,
                     interpret=self._kv_interpret, mesh=self.mesh,
                     axis=self.tensor_axis)(
-                    self.params, self._pool, tables,
+                    self.params, self._pool,
+                    {k: jnp.asarray(t) for k, t in blk.tables.items()},
                     jnp.asarray(blk.lengths), tokens,
                     jnp.asarray(blk.temps), key, self.cfg, blk.steps,
                     tp, tk)
@@ -1679,7 +1656,8 @@ class LLMEngine:
         _, jnp = _jx()
         with self._phase("verify.dispatch") as disp:
             logits, self._pool = kvcache.paged_verify_steps(
-                self.params, self._pool, jnp.asarray(self._tables),
+                self.params, self._pool,
+                {k: jnp.asarray(t) for k, t in self._tables.items()},
                 jnp.asarray(lengths), jnp.asarray(tokens_bw), self.cfg,
                 impl=self._kv_impl, interpret=self._kv_interpret,
                 mesh=self.mesh, axis=self.tensor_axis)
@@ -1761,8 +1739,8 @@ class LLMEngine:
             "engine", "generate", r.trace, r.trace.span_id,
             r.t_submit_wall, time.time(), error=error,
             tokens=len(r.out),
-            kv_bytes=int(self._kv_per_token_bytes()
-                         * (len(r.tokens) + len(r.out))), **extra)
+            kv_bytes=(self._block_bytes[kvcache.GLOBAL] // self._block
+                      * (len(r.tokens) + len(r.out))), **extra)
         r.trace = None
 
     def _free_kv(self, r: _Request, slot: Optional[int]) -> None:
@@ -1799,9 +1777,8 @@ class LLMEngine:
             stream = stream[:-1]
         r.kv_alloc = None
         if slot is not None:
-            self._tables[slot] = kvcache.TRASH
-            if self._kinds:
-                self._wtables[slot] = kvcache.TRASH
+            for t in self._tables.values():
+                t[slot] = kvcache.TRASH
         seq, cache = r.seq, r.kv_written
 
         def release():

@@ -222,11 +222,13 @@ class KVBlockManager:
         return self.window_blocks - 1 - len(self.wfree) if self.window \
             else 0
 
-    def _window_row(self, held: Dict[int, int]) -> np.ndarray:
-        row = np.full((self.table_width,), TRASH, np.int32)
-        for logical, phys in held.items():
-            row[logical] = phys
-        return row
+    def advance(self, seq_id, length: int, steps: int) -> Dict[str, object]:
+        """{kind: the sequence's table row} for the kinds whose row
+        MOVES before a decode dispatch (advance_window): not the global
+        layers', which admission reserved whole."""
+        if self.window is None:
+            return {}
+        return {WINDOW: self.advance_window(seq_id, length, steps)}
 
     def advance_window(self, seq_id, length: int, steps: int = 1):
         """Before a decode dispatch that writes positions ``length ...
@@ -250,7 +252,10 @@ class KVBlockManager:
                 held[logical] = self.wfree.popleft()
         if self._m is not None:
             self._m["window_used"].set(self.window_used_blocks())
-        return self._window_row(held)
+        row = np.full((self.table_width,), TRASH, np.int32)
+        for logical, phys in held.items():
+            row[logical] = phys
+        return row
 
     # -- introspection ---------------------------------------------------
 
@@ -263,6 +268,26 @@ class KVBlockManager:
 
     def free_blocks(self) -> int:
         return len(self.free)
+
+    def used_by_kind(self) -> Dict[str, int]:
+        """{kind: blocks of its pool live sequences hold}."""
+        used = {GLOBAL: self.used_blocks()}
+        if self.window is not None:
+            used[WINDOW] = self.window_used_blocks()
+        return used
+
+    def free_by_kind(self) -> Dict[str, int]:
+        """{kind: blocks of its pool on the free list}."""
+        free = {GLOBAL: len(self.free)}
+        if self.window is not None:
+            free[WINDOW] = len(self.wfree)
+        return free
+
+    def freed_by_kind(self) -> Dict[str, int]:
+        """{kind: blocks freed so far because their sequence passed
+        them}, for the kinds that free that way."""
+        return {} if self.window is None else {
+            WINDOW: self.window_freed_total}
 
     def _publish(self) -> None:
         if self._m is None:
@@ -312,11 +337,13 @@ class KVBlockManager:
                   max_new: int) -> Optional[dict]:
         """Admit one request: adopt the cached prefix (ref-counted),
         reserve fresh blocks for the rest of its horizon. Returns
-        {"table": np.int32 (table_width,), "hit_tokens": int,
+        {"tables": {kind: np.int32 (table_width,)}, "hit_tokens": int,
         "new_blocks": [phys]} — or None when the pool can't cover it
         right now (caller re-queues the request; eviction of
         refcount-0 chains was already attempted). Raises
-        BlockPoolExhausted when the request can never fit."""
+        BlockPoolExhausted when the request can never fit. (The rows
+        are also there as "table" and "window_table", the names the
+        benchmark's drives read.)"""
         if seq_id in self.seqs:
             raise ValueError(f"seq {seq_id!r} already allocated")
         n = len(tokens)
@@ -361,13 +388,13 @@ class KVBlockManager:
         if self._m is not None and hit_tokens:
             self._m["hit_tokens"].inc(hit_tokens)
         self._publish()
-        out = {"table": table, "hit_tokens": hit_tokens,
-               "new_blocks": new_blocks}
         if self.window is not None:
-            # the prompt's blocks the first decode step's window reaches
             self.wseqs[seq_id] = {}
-            out["window_table"] = self.advance_window(seq_id, n, 0)
-        return out
+        # a window layer's: what the first decode step's window reaches
+        tables = {GLOBAL: table, **self.advance(seq_id, n, 0)}
+        return {"tables": tables, "hit_tokens": hit_tokens,
+                "new_blocks": new_blocks,
+                **{_TABLE_NAMES[kind]: row for kind, row in tables.items()}}
 
     def _release(self, phys: int) -> None:
         """Drop one live reference; a block neither referenced nor
@@ -578,19 +605,20 @@ def _jx():
 # not at import time.)
 GLOBAL, WINDOW = "global", "window"
 POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv")}
+_TABLE_NAMES = {GLOBAL: "table", WINDOW: "window_table"}    # alloc_seq
 
 
-def pool_kinds(cfg):
-    """``((kind, its layers), ...)`` for a model with window layers
-    (hashable: the device ops below are built per value), None for one
-    of global layers only."""
+def pool_kinds(cfg) -> tuple:
+    """How a model's cache is laid out: ``((kind, its layers), ...)``,
+    the global layers first (hashable: the device ops below are built
+    per value); ``((GLOBAL, (0, ..., L - 1)),)`` without window layers."""
     from ray_tpu.llm.model import kind_layers
     kinds = kind_layers(cfg)
     if GLOBAL not in kinds:
         raise NotImplementedError(
             "a model whose layers are all window layers is not served "
             "yet: the pool's geometry is read off its global layers")
-    return tuple(kinds.items()) if WINDOW in kinds else None
+    return tuple(kinds.items())
 
 
 def init_pool(cfg, num_blocks: int, block_size: int, dtype,
@@ -599,9 +627,8 @@ def init_pool(cfg, num_blocks: int, block_size: int, dtype,
     kv_heads, block_size, head_dim) and, for a model with window
     layers, wk/wv (window layers, window_blocks, ...)."""
     _, jnp = _jx()
-    kinds = pool_kinds(cfg) or ((GLOBAL, tuple(range(cfg.n_layers))),)
     pool = {}
-    for kind, layers in kinds:
+    for kind, layers in pool_kinds(cfg):
         shape = (len(layers),
                  num_blocks if kind == GLOBAL else max(2, window_blocks),
                  cfg.n_kv_heads, block_size, cfg.head_dim)
@@ -610,18 +637,18 @@ def init_pool(cfg, num_blocks: int, block_size: int, dtype,
     return pool
 
 
+def pool_k(pool: dict, kind: str = GLOBAL):
+    """A kind's K array, (its layers, its blocks, kv_heads, block_size,
+    head_dim): what a geometry is read off."""
+    return pool[POOL_KEYS[kind][0]]
+
+
 def kind_block_bytes(pool: dict) -> dict:
     """{kind: device bytes one block id of that kind costs (k + v, all
     the kind's layers)}."""
     return {kind: sum(pool[key].nbytes // pool[key].shape[1]
                       for key in keys)
             for kind, keys in POOL_KEYS.items() if keys[0] in pool}
-
-
-def pool_block_bytes(pool: dict) -> int:
-    """Device bytes one block of the global layers costs (k + v, all of
-    them)."""
-    return kind_block_bytes(pool)[GLOBAL]
 
 
 def window_ring_blocks(window: int, block_size: int, steps: int) -> int:
@@ -674,65 +701,82 @@ _JITS: dict = {}    # (op, pool geometry, dtype) -> jitted callable
 
 
 def _pool_key(pool: dict) -> tuple:
-    """Cache-key component identifying one pool's compiled geometry."""
-    wk = pool.get("wk")
-    return (tuple(pool["k"].shape), str(pool["k"].dtype),
-            *(() if wk is None else (tuple(wk.shape),)))
+    """Cache-key component identifying one pool's compiled geometry:
+    the global layers' shape, the dtype, then any other kind's shape."""
+    shapes = [tuple(pool[keys[0]].shape) for keys in POOL_KEYS.values()
+              if keys[0] in pool]
+    return (shapes[0], str(pool_k(pool).dtype), *shapes[1:])
+
+
+# Block tables, write targets and physical ids are dicts by layer kind
+# from the block manager to the kernel, {GLOBAL: ...} for a model of
+# global layers only. The PUBLIC entries also take what callers outside
+# the engine hand them, a bare array for such a model and no layout:
+# these two functions, at an entry's first line, are all that knows it.
+
+def _by_kind(ids) -> dict:
+    return ids if isinstance(ids, dict) else {GLOBAL: ids}
+
+
+def _layout(pool: dict, kinds=None) -> tuple:
+    """``pool_kinds`` of the model ``pool`` was made for: the caller's,
+    which must agree with the pool, or read off a pool of one kind."""
+    held = tuple((kind, pool[keys[0]].shape[0])
+                 for kind, keys in POOL_KEYS.items() if keys[0] in pool)
+    kinds = tuple(kinds or ((kind, tuple(range(n))) for kind, n in held))
+    layers = sorted(l for _, ls in kinds for l in ls)
+    if tuple((kind, len(ls)) for kind, ls in kinds) != held \
+            or layers != list(range(len(layers))):
+        raise ValueError(f"the pool holds (kind, layers) {held}, not the "
+                         f"layout {kinds}: pass its model's pool_kinds(cfg)")
+    return kinds
 
 
 def _to_blocks(kv, nb: int, pool):
-    """Token-order KV (layers, nb * block, kvh, hd) cut into ``nb``
-    head-major pool blocks (layers, nb, kvh, block, hd), in the pool's
-    dtype."""
+    """The first ``nb * block`` positions of token-order KV (layers,
+    positions, kvh, hd) as ``nb`` head-major pool blocks (layers, nb,
+    kvh, block, hd), in the pool's dtype."""
     L, _, kvh, hd = kv.shape
     bs = pool.shape[3]
-    return kv.reshape(L, nb, bs, kvh, hd).transpose(
+    return kv[:, :nb * bs].reshape(L, nb, bs, kvh, hd).transpose(
         0, 1, 3, 2, 4).astype(pool.dtype)
 
 
-def _jit(name: str, pool: dict, kinds=None):
+def _jit(name: str, pool: dict, kinds: tuple = ()):
     """Build-once cache for the jitted device ops: jax must not be
     imported at module import time (the engine's lazy-import rule),
     and a fresh jax.jit wrapper per call would retrace every call.
-    Keyed on (op, pool geometry, dtype) — NOT op name alone: one
-    process serving two model configs (two replicas, a debug engine
+    Keyed on (op, pool geometry, dtype, layout) — NOT op name alone:
+    one process serving two model configs (two replicas, a debug engine
     next to a prod one) must not replay a callable whose donated
     buffers and reshape constants were traced for the other pool's
-    shape. ``kinds`` (pool_kinds): None, or which layers of the
-    token-order KV go to which kind's arrays, each through that kind's
-    physical ids (``phys`` is then a dict by kind)."""
+    shape. ``kinds`` (pool_kinds): which layers of the token-order KV
+    go to which kind's arrays, each through that kind's physical ids
+    (``phys`` is a dict by kind)."""
     key = (name, *_pool_key(pool), kinds)
     fn = _JITS.get(key)
     if fn is not None:
         return fn
     jax, jnp = _jx()
+    model = tuple(range(sum(len(layers) for _, layers in kinds)))
 
-    def parts(phys):
-        """(layers or None for all, the pool's keys, the ids) a kind."""
-        if kinds is None:
-            return ((None, POOL_KEYS[GLOBAL], phys),)
-        return tuple((np.asarray(layers), POOL_KEYS[kind], phys[kind])
-                     for kind, layers in kinds)
+    def rows(x, layers: tuple):
+        """Rows ``layers`` of ``x``: ``x`` itself where they are all of
+        them in order (known here, so a model of one kind is never
+        gathered from or stacked back)."""
+        return x if layers == model else x[np.asarray(layers)]
 
-    def of(kv, layers):
-        return kv if layers is None else kv[layers]
+    if name == "gather_table":
+        # back into the model's layer order
+        order = tuple(np.argsort([l for _, ls in kinds for l in ls]))
 
-    if name == "scatter_bucket":
-        @partial(jax.jit, donate_argnums=(0,), static_argnames=("nb",))
-        def fn(pool, kv, phys, nb):
-            return {**pool, **{
-                dst: pool[dst].at[:, ids].set(
-                    _to_blocks(of(kv[src], layers), nb, pool[dst]))
-                for layers, keys, ids in parts(phys)
-                for src, dst in zip(("k", "v"), keys)}}
-    elif name == "gather_table":
         @partial(jax.jit, static_argnames=("acc_len",))
         def fn(pool, phys, acc_len):
             out = {}
-            for src in ("k", "v"):
+            for i, src in enumerate(("k", "v")):
                 views = []
-                for layers, keys, ids in parts(phys):
-                    dst = keys[src == "v"]
+                for kind, _ in kinds:
+                    dst, ids = POOL_KEYS[kind][i], phys[kind]
                     L, _, kvh, bs, hd = pool[dst].shape
                     w = ids.shape[0]
                     g = pool[dst][:, ids]        # (L, w, kvh, bs, hd)
@@ -741,31 +785,23 @@ def _jit(name: str, pool: dict, kinds=None):
                     pad = acc_len - w * bs
                     if pad > 0:
                         g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                    views.append((layers, g))
-                if kinds is None:
-                    out[src] = views[0][1]
-                else:       # back into the model's layer order
-                    order = np.argsort(np.concatenate(
-                        [layers for layers, _ in views]))
-                    out[src] = jnp.concatenate(
-                        [g for _, g in views])[order]
+                    views.append(g)
+                out[src] = rows(jnp.concatenate(views), order)
             return out
     elif name == "scatter_table":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, acc, phys):
-            bs = pool["k"].shape[3]
             return {**pool, **{
-                dst: pool[dst].at[:, ids].set(_to_blocks(
-                    of(acc[src], layers)[:, :ids.shape[0] * bs],
-                    ids.shape[0], pool[dst]))
-                for layers, keys, ids in parts(phys)
-                for src, dst in zip(("k", "v"), keys)}}
+                dst: pool[dst].at[:, phys[kind]].set(_to_blocks(
+                    rows(acc[src], layers), phys[kind].shape[0], pool[dst]))
+                for kind, layers in kinds
+                for src, dst in zip(("k", "v"), POOL_KEYS[kind])}}
     elif name == "copy_block":
         @partial(jax.jit, donate_argnums=(0,))
         def fn(pool, src, dst):
-            return {**pool,
-                    "k": pool["k"].at[:, dst].set(pool["k"][:, src]),
-                    "v": pool["v"].at[:, dst].set(pool["v"][:, src])}
+            return {**pool, **{
+                key: pool[key].at[:, dst].set(pool[key][:, src])
+                for key in POOL_KEYS[GLOBAL]}}
     else:
         raise KeyError(name)
     _JITS[key] = fn
@@ -775,9 +811,12 @@ def _jit(name: str, pool: dict, kinds=None):
 def scatter_bucket(pool: dict, kv: dict, phys, nb: int,
                    kinds=None) -> dict:
     """Write a bucket-padded prefill's KV into ``nb`` physical blocks
-    (pad-garbage blocks redirected to trash by the caller's phys).
-    One compile per bucket size."""
-    return _jit("scatter_bucket", pool, kinds)(pool, kv, phys, nb)
+    (pad-garbage blocks redirected to trash by the caller's phys):
+    scatter_table over a bucket's width, one compile per bucket size."""
+    phys = _by_kind(phys)
+    if any(ids.shape[0] != nb for ids in phys.values()):
+        raise ValueError(f"a bucket of {nb} blocks needs {nb} ids a kind")
+    return scatter_table(pool, kv, phys, kinds)
 
 
 def gather_table(pool: dict, phys, acc_len: int, kinds=None) -> dict:
@@ -787,14 +826,16 @@ def gather_table(pool: dict, phys, acc_len: int, kinds=None) -> dict:
     longer on the decode hot path — decode attends straight through
     the table (ops/pallas/paged_attention.py); this stays for the
     prefix-hit prefill accumulator and debug/parity tooling."""
-    return _jit("gather_table", pool, kinds)(pool, phys, acc_len)
+    return _jit("gather_table", pool, _layout(pool, kinds))(
+        pool, _by_kind(phys), acc_len)
 
 
 def scatter_table(pool: dict, acc: dict, phys, kinds=None) -> dict:
-    """Write an accumulator back through a full-width physical target
-    vector (shared-prefix and beyond-horizon slots point at trash so
-    shared blocks are never written). One compile total."""
-    return _jit("scatter_table", pool, kinds)(pool, acc, phys)
+    """Write an accumulator's first positions back through a physical
+    target vector (shared-prefix and beyond-horizon slots point at trash
+    so shared blocks are never written). One compile a width."""
+    return _jit("scatter_table", pool, _layout(pool, kinds))(
+        pool, acc, _by_kind(phys))
 
 
 def copy_block(pool: dict, src: int, dst: int) -> dict:
@@ -820,32 +861,12 @@ def resolve_attn_impl(impl: str) -> str:
     return impl
 
 
-def _paged_decode_core(params, pool, tables, lengths, tokens, temps,
-                       key, cfg, top_ps=None, top_ks=None, *,
-                       impl="gather", interpret=False, mesh=None,
-                       axis="tensor"):
-    """One sampled token for every slot against the paged pool:
-    _paged_logits_core + the on-device sampler. Returns (tokens, pool,
-    the expert layers' counts or None)."""
-    from ray_tpu.llm.model import sample
-    logits, pool, counts = _paged_logits_core(
-        params, pool, tables, lengths, tokens, cfg, impl=impl,
-        interpret=interpret, mesh=mesh, axis=axis)
-    return sample(logits, temps, key, top_ps, top_ks), pool, counts
-
-
-def _by_kind(tables) -> dict:
-    """Block tables by layer kind: a model of global layers only hands
-    the one array it always did."""
-    return tables if isinstance(tables, dict) else {GLOBAL: tables}
-
-
 def _places(tables, pos, bs):
     """{kind: (physical block, row in it)} of positions ``pos`` (slots,)
     or (slots, w) in each kind's tables."""
     _, jnp = _jx()
     out = {}
-    for kind, tb in _by_kind(tables).items():
+    for kind, tb in tables.items():
         blk = jnp.clip(pos // bs, 0, tb.shape[1] - 1)
         phys = (tb[jnp.arange(tb.shape[0]), blk] if pos.ndim == 1
                 else jnp.take_along_axis(tb, blk, axis=1))
@@ -894,7 +915,6 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     jax, _ = _jx()
     from ray_tpu.llm import model as lm
     from ray_tpu.ops.pallas import paged_attention as pa
-    tables = _by_kind(tables)
     lead = lens.shape                   # (slots,) or (slots, w)
     multi = len(lead) == 2
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -972,7 +992,7 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     the attention over the table plugged in (_pool_attend). Returns
     (logits, pool, the expert layers' counts or None)."""
     from ray_tpu.llm.model import decode_logits_core
-    bs = pool["k"].shape[3]
+    bs = pool_k(pool).shape[3]
     positions = lengths
     return decode_logits_core(
         params, pool, tokens, positions, cfg,
@@ -1002,10 +1022,9 @@ def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
         def paged_decode_logits(params, pool, tables, lengths, tokens,
                                 cfg):
             return _paged_logits_core(
-                params, pool, tables, lengths, tokens, cfg, impl=impl,
-                interpret=interpret, mesh=mesh, axis=axis)[0]
-        fn = paged_decode_logits
-        _JITS[key_] = fn
+                params, pool, _by_kind(tables), lengths, tokens, cfg,
+                impl=impl, interpret=interpret, mesh=mesh, axis=axis)[0]
+        fn = _JITS[key_] = paged_decode_logits
     return fn(params, pool, tables, lengths, tokens, cfg)
 
 
@@ -1051,18 +1070,21 @@ def decode_steps_program(pool, *, impl="gather", interpret=False,
     if fn is None:
         jax, jnp = _jx()
         from jax import lax as _lax
+        from ray_tpu.llm.model import sample
 
         @partial(jax.jit, static_argnames=("cfg", "n"),
                  donate_argnums=(1,))
         def paged_decode_steps(params, pool, tables, lengths, tokens,
                                temps, key, cfg, n, top_ps, top_ks):
+            tables = _by_kind(tables)
+
             def body(carry, i):
                 pool, toks = carry
-                out, pool, counts = _paged_decode_core(
-                    params, pool, tables, lengths + i, toks, temps,
-                    jax.random.fold_in(key, i), cfg, top_ps, top_ks,
-                    impl=impl, interpret=interpret, mesh=mesh,
-                    axis=axis)
+                at, step_key = lengths + i, jax.random.fold_in(key, i)
+                logits, pool, counts = _paged_logits_core(
+                    params, pool, tables, at, toks, cfg, impl=impl,
+                    interpret=interpret, mesh=mesh, axis=axis)
+                out = sample(logits, temps, step_key, top_ps, top_ks)
                 return (pool, out), (out, counts)
             (pool, last), (outs, counts) = _lax.scan(
                 body, (pool, tokens), jnp.arange(n, dtype=jnp.int32))
@@ -1120,7 +1142,7 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     _, jnp = _jx()
     from ray_tpu.llm.model import verify_tokens_core
     wq = tokens.shape[1]
-    bs = pool["k"].shape[3]
+    bs = pool_k(pool).shape[3]
     pos = lengths[:, None] + jnp.arange(wq, dtype=jnp.int32)[None]
     return verify_tokens_core(
         params, pool, tokens, lengths, cfg,
@@ -1158,7 +1180,7 @@ def verify_steps_program(pool, wq: int, *, impl="gather",
         def paged_verify_steps(params, pool, tables, lengths, tokens,
                                cfg):
             return _paged_verify_core(
-                params, pool, tables, lengths, tokens, cfg, impl=impl,
-                interpret=interpret, mesh=mesh, axis=axis)
+                params, pool, _by_kind(tables), lengths, tokens, cfg,
+                impl=impl, interpret=interpret, mesh=mesh, axis=axis)
         fn = _JITS[key_] = paged_verify_steps
     return fn

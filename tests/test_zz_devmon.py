@@ -390,7 +390,7 @@ def test_engine_kv_accounting_exemplars_and_duty_windows(tiny_model):
         kv0 = eng._m["kv_bytes"]._values[()]
         hr0 = eng._m["kv_headroom"]._values[()]
         assert kv0 == 0
-        assert hr0 == kvcache.pool_block_bytes(eng._pool) \
+        assert hr0 == kvcache.kind_block_bytes(eng._pool)[kvcache.GLOBAL] \
             * eng._kv.free_blocks() > 0
         tok = tracing.set_request_context(
             tracing.TraceContext(tid, tracing.new_span_id()))
@@ -408,7 +408,9 @@ def test_engine_kv_accounting_exemplars_and_duty_windows(tiny_model):
     gen = [e for e in events.dump() if e.get("cat") == "request"
            and e.get("trace") == tid and e.get("seg") == "generate"]
     assert len(gen) == 1
-    expect = int(eng._kv_per_token_bytes() * (3 + 8))
+    from ray_tpu.llm import kvcache
+    expect = kvcache.kind_block_bytes(eng._pool)[kvcache.GLOBAL] \
+        // eng._block * (3 + 8)
     assert gen[0]["kv_bytes"] == expect > 0
     # PR 9 exemplars extended to TPOT and batch-size histograms: a
     # p99 bucket links to this concrete trace

@@ -381,8 +381,7 @@ def _fence_logits(forward, cfg, params, mesh):
             mesh, P(None, None, "tensor", None, None)))
     table = jnp.asarray(
         1 + np.arange(slots * width).reshape(slots, width), jnp.int32)
-    tables = ({k: table for k in ("global", "window")}
-              if "wk" in pool else table)
+    tables = {kind: table for kind in kc.kind_block_bytes(pool)}
     lengths = jnp.asarray([3, 17, 40, 55], jnp.int32)
     kw = dict(mesh=mesh, axis="tensor")
     if forward == "verify":
@@ -428,3 +427,260 @@ def test_the_fenced_projections_move_no_number(monkeypatch, model, forward,
     want = np.asarray(_fence_logits(forward, cfg, params, mesh))
     assert np.isfinite(want).all() and np.abs(want).max() > 1e-3
     assert np.linalg.norm(got - want) <= 1e-6 * np.linalg.norm(want)
+
+
+# --- one representation by layer kind (PR 50) --------------------------------
+# block tables, write targets and physical ids are dicts by kind from the
+# block manager to the kernel for EVERY family; the public entries also take
+# the bare array their callers outside the engine hand them
+
+FAMILIES = ("llama", "hybrid")
+
+
+def _family(name):
+    """(cfg, params) of a family's tiny model: the Llama family (global
+    layers only) or the hybrid above (window and global layers)."""
+    if name == "hybrid":
+        cfg = _cfg()
+        return cfg, moe.init_params(jax.random.PRNGKey(0), cfg)
+    from ray_tpu.models import llama
+    cfg = llama.tiny(vocab_size=128, dim=64, n_layers=3, n_heads=4,
+                     n_kv_heads=2, ffn_dim=128, dtype="float32",
+                     logits_dtype="float32", attn_impl="reference")
+    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _random_pool(cfg, blocks=12, bs=8, seed=0):
+    rng = np.random.default_rng(seed)
+    pool = kc.init_pool(cfg, blocks, bs, jnp.float32, window_blocks=blocks)
+    return {k: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for k, a in pool.items()}
+
+
+def _spellings(cfg, ids: dict):
+    """Every way a caller may hand ``ids`` (by kind) and the layout to
+    scatter_bucket / gather_table / scatter_table."""
+    kinds = kc.pool_kinds(cfg)
+    if len(ids) > 1:
+        return [(ids, (kinds,))]
+    bare = ids[kc.GLOBAL]
+    return [(bare, ()), (bare, (kinds,)), (ids, ()), (ids, (kinds,))]
+
+
+def _blocks(x, nb, bs):
+    """Token-order (layers, nb * bs, kvh, hd) as pool blocks."""
+    L, _, kvh, hd = x.shape
+    return x.reshape(L, nb, bs, kvh, hd).transpose(0, 1, 3, 2, 4)
+
+
+@pytest.mark.parametrize("op", ["scatter_bucket", "gather_table",
+                                "scatter_table"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_cache_ops_take_every_spelling_of_ids_by_kind(family, op):
+    """A bare array, ``{GLOBAL: array}``, with or without the trailing
+    layout: the same pool (or accumulator) bit for bit, equal to a numpy
+    statement of the op by kind, through ONE compiled callable."""
+    cfg, _ = _family(family)
+    bs, nb, kvh, hd = 8, 3, cfg.n_kv_heads, cfg.head_dim
+    rng = np.random.default_rng(1)
+    ids = {kind: jnp.asarray(rng.permutation(np.arange(1, 12))[:nb],
+                             jnp.int32)
+           for kind, _ in kc.pool_kinds(cfg)}
+    kv = {k: jnp.asarray(rng.standard_normal(
+        (cfg.n_layers, nb * bs, kvh, hd)), jnp.float32) for k in "kv"}
+    start = {k: np.asarray(a) for k, a in _random_pool(cfg).items()}
+    want = {k: a.copy() for k, a in start.items()}
+    if op == "gather_table":
+        want = {k: np.zeros((cfg.n_layers, 40, kvh, hd), np.float32)
+                for k in "kv"}
+    for kind, layers in kc.pool_kinds(cfg):
+        for src, dst in zip("kv", kc.POOL_KEYS[kind]):
+            phys = np.asarray(ids[kind])
+            if op == "gather_table":
+                g = start[dst][:, phys].transpose(0, 1, 3, 2, 4)
+                want[src][list(layers), :nb * bs] = g.reshape(
+                    len(layers), nb * bs, kvh, hd)
+            else:
+                want[dst][:, phys] = _blocks(
+                    np.asarray(kv[src])[list(layers)], nb, bs)
+    kc._JITS.clear()
+    for given, layout in _spellings(cfg, ids):
+        pool = {k: jnp.asarray(a) for k, a in start.items()}
+        if op == "scatter_bucket":
+            got = kc.scatter_bucket(pool, kv, given, nb, *layout)
+        elif op == "gather_table":
+            got = kc.gather_table(pool, given, 40, *layout)
+        else:
+            got = kc.scatter_table(pool, kv, given, *layout)
+        assert sorted(got) == sorted(want)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(got[k]), want[k])
+    # a bucket's write is scatter_table's program at the bucket's width
+    assert [k[0] for k in kc._JITS] == [op.replace("bucket", "table")]
+
+
+def test_a_layout_that_is_not_the_pools_is_refused():
+    llama_cfg, _ = _family("llama")
+    hybrid_cfg, _ = _family("hybrid")
+    ids = jnp.asarray([1, 2], jnp.int32)
+    both = {kc.GLOBAL: ids, kc.WINDOW: ids}
+    with pytest.raises(ValueError, match="pool_kinds"):
+        # two kinds: the pool alone does not say which layers are whose
+        kc.gather_table(_random_pool(hybrid_cfg), both, 16)
+    with pytest.raises(ValueError, match="pool_kinds"):
+        kc.gather_table(_random_pool(llama_cfg), ids, 16,
+                        kc.pool_kinds(hybrid_cfg))
+
+
+@pytest.mark.parametrize("entry", ["paged_decode_logits",
+                                   "paged_decode_steps",
+                                   "paged_verify_steps"])
+def test_the_paged_forwards_take_a_bare_table_or_a_dict(entry):
+    """The Llama family's callers outside the engine hand the tables as
+    one array: bitwise what ``{GLOBAL: tables}`` gives, one `_JITS`
+    entry."""
+    cfg, params = _family("llama")
+    slots, width = 3, 4
+    table = jnp.asarray(
+        1 + np.arange(slots * width).reshape(slots, width), jnp.int32)
+    lengths = jnp.asarray([3, 9, 20], jnp.int32)
+    tokens = jnp.asarray([5, 6, 7], jnp.int32)
+
+    def run(tables):
+        pool = _random_pool(cfg, blocks=slots * width + 1)
+        if entry == "paged_decode_logits":
+            return kc.paged_decode_logits(params, pool, tables, lengths,
+                                          tokens, cfg)
+        if entry == "paged_decode_steps":
+            return kc.paged_decode_steps(
+                params, pool, tables, lengths, tokens,
+                jnp.zeros((slots,), jnp.float32), jax.random.PRNGKey(0),
+                cfg, 2)
+        return kc.paged_verify_steps(
+            params, pool, tables, lengths,
+            jnp.stack([tokens, tokens + 1], axis=1), cfg)
+
+    kc._JITS.clear()
+    bare, by_kind = run(table), run({kc.GLOBAL: table})
+    assert jax.tree.structure(bare) == jax.tree.structure(by_kind)
+    for a, b in zip(jax.tree.leaves(bare), jax.tree.leaves(by_kind)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert len(kc._JITS) == 1, list(kc._JITS)
+
+
+@pytest.mark.parametrize("op, gathers", [("scatter_table", 0),
+                                         ("gather_table", 2)])
+def test_a_model_of_one_kind_is_never_gathered_or_stacked(op, gathers):
+    """The lowered text of the Llama family's op: the only gathers are
+    gather_table's own, over a pool's BLOCKS (k and v); no layer is
+    taken out of the KV, nothing is concatenated or sorted back. (The
+    hybrid's text has them: the control.)"""
+    def text(family):
+        cfg, _ = _family(family)
+        pool = _random_pool(cfg)
+        ids = {kind: jnp.asarray([1, 2, 3], jnp.int32)
+               for kind, _ in kc.pool_kinds(cfg)}
+        kinds = kc.pool_kinds(cfg)
+        if op == "gather_table":
+            return kc._jit(op, pool, kinds).lower(pool, ids, 32).as_text()
+        kv = {k: jnp.zeros((cfg.n_layers, 24, cfg.n_kv_heads,
+                            cfg.head_dim), jnp.float32) for k in "kv"}
+        return kc._jit(op, pool, kinds).lower(pool, kv, ids).as_text()
+
+    def count(text):
+        return text.count('"stablehlo.gather"(')
+
+    one = text("llama")
+    assert count(one) == gathers
+    assert "stablehlo.concatenate" not in one and "stablehlo.sort" not in one
+    two = text("hybrid")
+    assert count(two) > 2 * gathers
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_layout_is_said_once(family):
+    """pool_kinds is ``((kind, its layers), ...)`` for every family, and
+    the pool, its block bytes and the ops' default agree with it."""
+    cfg, _ = _family(family)
+    kinds = kc.pool_kinds(cfg)
+    assert kinds == tuple(lm.kind_layers(cfg).items())
+    assert kinds[0][0] == kc.GLOBAL
+    assert sorted(l for _, ls in kinds for l in ls) == list(
+        range(cfg.n_layers))
+    if family == "llama":
+        assert kinds == ((kc.GLOBAL, tuple(range(cfg.n_layers))),)
+    pool = kc.init_pool(cfg, 9, 8, jnp.float32, window_blocks=5)
+    assert sorted(pool) == sorted(
+        key for kind, _ in kinds for key in kc.POOL_KEYS[kind])
+    for kind, layers in kinds:
+        for key in kc.POOL_KEYS[kind]:
+            assert pool[key].shape == (
+                len(layers), 9 if kind == kc.GLOBAL else 5,
+                cfg.n_kv_heads, 8, cfg.head_dim)
+    per_layer = 2 * cfg.n_kv_heads * 8 * cfg.head_dim * 4
+    assert kc.kind_block_bytes(pool) == {
+        kind: len(layers) * per_layer for kind, layers in kinds}
+    assert kc._layout(pool, kinds) == kinds
+    if len(kinds) == 1:
+        assert kc._layout(pool) == kinds
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_an_engine_reports_tables_and_stats_by_kind(family):
+    """Either family through the same code: the engine's tables are a
+    dict by the layout's kinds, and what `stats` says is used, by kind,
+    is what the manager holds and what the tables name: after an
+    admit, with a decode block in flight, and after the release."""
+    from ray_tpu.llm.engine import LLMEngine
+    cfg, params = _family(family)
+    kinds = [kind for kind, _ in kc.pool_kinds(cfg)]
+    name = {kind: "blocks_used" + ("" if kind == kc.GLOBAL else "_" + kind)
+            for kind in kinds}
+
+    def held(eng):
+        stats = eng.stats
+        used = eng._kv.used_by_kind()
+        assert list(used) == list(eng._tables) == kinds
+        assert {kind: stats[name[kind]] for kind in kinds} == used
+        assert used[kc.GLOBAL] == eng._kv.used_blocks()
+        assert sum(used.values()) == eng._kv.used_blocks() \
+            + eng._kv.window_used_blocks()
+        # no block is shared here: the tables name each block once
+        return used, {kind: int(np.count_nonzero(t))
+                      for kind, t in eng._tables.items()}
+
+    async def run():
+        eng = LLMEngine(cfg, params, max_slots=2, max_len=128,
+                        prefill_buckets=(64,), cache_dtype="float32",
+                        kv_block_size=8, steps_per_sync=4,
+                        prefix_cache=False)
+        assert held(eng)[0] == dict.fromkeys(kinds, 0)
+        rng = np.random.default_rng(3)
+        prompt = [int(t) for t in rng.integers(1, cfg.vocab_size, 50)]
+        task = asyncio.ensure_future(
+            eng.generate(prompt, max_new_tokens=24))
+        seen = []
+        while not task.done():
+            if eng._inflight is not None:
+                seen.append(held(eng))
+            await asyncio.sleep(0)
+        await task
+        for _ in range(200):
+            if eng._inflight is None:
+                break
+            await asyncio.sleep(0.005)
+        after = held(eng)
+        stats = eng.stats
+        await eng.stop()
+        return seen, after, stats
+
+    seen, after, stats = asyncio.run(run())
+    assert seen, "no decode block was ever in flight"
+    for used, named in seen:
+        # the full horizon of the global layers, a ring of a window layer
+        assert used[kc.GLOBAL] == -(-(50 + 24) // 8)
+        assert used == named
+    assert after == (dict.fromkeys(kinds, 0), dict.fromkeys(kinds, 0))
+    assert stats["pool_blocks"] == stats["blocks_free"] + 1
+    assert sorted(k for k in stats if k.endswith("_blocks_freed")) == [
+        kind + "_blocks_freed" for kind in kinds if kind != kc.GLOBAL]

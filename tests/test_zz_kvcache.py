@@ -452,7 +452,7 @@ def test_kv_accounting_gauges(tiny_model):
     cached = sum(reg["llm_kv_blocks_cached"]._values.values())
     assert used == 0                      # request finished
     assert cached >= 1                    # its prompt chain is cached
-    bb = kc.pool_block_bytes(eng._pool)
+    bb = kc.kind_block_bytes(eng._pool)[kc.GLOBAL]
     kv_bytes = sum(reg["llm_kv_cache_bytes"]._values.values())
     assert kv_bytes == bb * cached
 

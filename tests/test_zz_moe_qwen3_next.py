@@ -91,7 +91,8 @@ def test_the_cell_its_configuration_and_its_metrics(fam):
                                 "pretrain-4k", 1, "qwen3_next")
     assert {m["name"] for m in cell["end_to_end"]} \
         == {"train_tok_s_chip", "setup_s"}
-    assert {m["name"] for m in cell["per_layer"]} == {
+    # at least these: a `benchmark` PR may add a per-layer metric
+    assert {m["name"] for m in cell["per_layer"]} >= {
         "flash_fwd_roofline.train", "flash_bwd_roofline.train",
         "train_step_dev_ms", "train_mfu_required", "hbm_peak.train",
         "moe_gmm_dev_ms.train", "moe_gmm_roofline.train"}
