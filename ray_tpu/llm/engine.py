@@ -144,6 +144,12 @@ def engine_metrics() -> dict:
                                too) and its steps
       llm_prefill_tokens       prompt tokens run through a prefill
                                forward per admit (prefix hits excluded)
+      llm_prefill_chunks_size  prefill forwards per admit (a prompt past
+                               the largest bucket runs a chunk at a time)
+      llm_latent_rows_expanded_size  cache rows a latent layer expanded
+                               to per-head K and V per admit (a prefix
+                               expanded again for every chunk counts
+                               every time)
 
     Per decode block, as its tokens are read back (the device scalars
     of a model with expert layers come back with them, no sync of their
@@ -161,6 +167,7 @@ def engine_metrics() -> dict:
                                  what the hits are a share of
       llm_kv_blocks_global_size  pool blocks of the global layers in use
       llm_kv_blocks_window_size  pool blocks of the window layers in use
+      llm_kv_blocks_latent_size  pool blocks of the latent layers in use
       llm_kv_window_freed_size   window-layer blocks freed for this
                                  block (their sequences' windows passed
                                  them)
@@ -248,6 +255,14 @@ def engine_metrics() -> dict:
              "decode block"),
             ("kv_blocks_window", "Window-layer pool blocks in use at a "
              "decode block"),
+            ("kv_blocks_latent", "Latent-layer pool blocks in use at a "
+             "decode block"),
+            ("latent_rows_expanded", "Cache rows of a latent layer "
+             "expanded to per-head keys and values per admitted request's "
+             "prefill (one layer's count: a prefix re-expanded for every "
+             "chunk counts every time, bucket padding included)"),
+            ("prefill_chunks", "Prefill forwards per admitted request: 1 "
+             "for a prompt inside the largest bucket, one a chunk beyond"),
             ("kv_window_freed", "Window-layer blocks freed for a decode "
              "block"),
             ("kv_live_tokens", "Positions the live requests hold at a "
@@ -440,7 +455,9 @@ class LLMEngine:
                        for kind, layers in self._layout]
         self._idle_fetch: dict = {}     # block's steps -> what the walk
                                         # fetches for one idle slot
-        if mesh is not None and (windowed or lm.has_experts(cfg)):
+        if mesh is not None and (windowed
+                                 or kvcache.LATENT in dict(self._layout)
+                                 or lm.has_experts(cfg)):
             raise NotImplementedError(
                 "tensor-parallel serving shards the Llama family's "
                 "parameter tree only (lm.serve_param_specs)")
@@ -531,15 +548,18 @@ class LLMEngine:
         # device link is latency-bound. Kept power-of-2-bucketed so XLA
         # compiles at most log2(steps_per_sync)+1 block variants.
         self.steps_per_sync = max(1, steps_per_sync)
-        # bytes a position costs in one layer (k and v), and the pool
-        # of each layer kind: the window layers' is sized exactly (a
-        # ring of blocks a slot), the global layers' by auto_pool_blocks
-        # from what is free beside it
-        layer_tok = (cfg.n_kv_heads * cfg.head_dim * 2
-                     * jnp.dtype(cache_dtype).itemsize)
-        n_window = len(dict(self._layout).get(kvcache.WINDOW, ()))
+        # bytes a block id of each kind costs (a position's row in every
+        # layer of the kind: kvcache.row_bytes), and the pool of each
+        # kind: the window layers' is sized exactly (a ring of blocks a
+        # slot), the pool of the kind a sequence holds whole (global or
+        # latent) by auto_pool_blocks from what is free beside it
+        block_bytes = {
+            kind: len(layers) * self._block
+            * kvcache.row_bytes(cfg, kind, cache_dtype)
+            for kind, layers in self._layout}
+        whole = self._layout[0][0]
         window = None
-        if n_window:
+        if windowed:
             ring = kvcache.window_ring_blocks(
                 cfg.sliding_window, self._block, self.steps_per_sync)
             window = (max_slots * ring + 1, cfg.sliding_window,
@@ -547,10 +567,9 @@ class LLMEngine:
         # the pool shards its kv heads over the tensor axis
         tp = mesh.shape[tensor_axis] if mesh is not None else 1
         nb = kvcache.auto_pool_blocks(
-            max_slots, self._table_w,
-            (cfg.n_layers - n_window) * layer_tok * self._block // tp,
+            max_slots, self._table_w, block_bytes[whole] // tp,
             kv_pool_blocks,
-            reserved_bytes=(window[0] * n_window * layer_tok * self._block
+            reserved_bytes=(window[0] * block_bytes[kvcache.WINDOW]
                             if window else 0))
         self._pool = kvcache.init_pool(
             cfg, nb, self._block, jnp.dtype(cache_dtype),
@@ -577,7 +596,8 @@ class LLMEngine:
                           for k, v in self._pool.items()}
         self._kv = kvcache.KVBlockManager(
             nb, self._block, table_width=self._table_w,
-            prefix_cache=prefix_cache, metrics=self._kvm, window=window)
+            prefix_cache=prefix_cache, metrics=self._kvm, window=window,
+            kind=whole)
         self._block_bytes = kvcache.kind_block_bytes(self._pool)
         # every slot's block table, by kind (each kind its own ids)
         self._tables = {kind: np.full((max_slots, self._table_w),
@@ -642,9 +662,10 @@ class LLMEngine:
                "kv_impl": self._kv_impl,
                "kv_interpret": self._kv_interpret,
                "spec": self._spec}
-        # the global layers' under the bare name, another kind's + _kind
+        # one name a kind: the pool a sequence holds whole (global or
+        # latent layers') under the bare name, another kind's + _kind
         for kind, used in self._kv.used_by_kind().items():
-            tail = "" if kind == kvcache.GLOBAL else "_" + kind
+            tail = "" if kind == self._kv.kind else "_" + kind
             out["pool_blocks" + tail] = \
                 kvcache.pool_k(self._pool, kind).shape[1]
             out["blocks_used" + tail] = used
@@ -681,8 +702,8 @@ class LLMEngine:
         worker's metrics push to the head next to util/devmon.py's
         device_hbm_* series."""
         live = self._kv.used_by_kind()
-        # the prefix index holds blocks of the global layers only
-        live[kvcache.GLOBAL] += self._kv.cached_blocks()
+        # the prefix index holds blocks of the kind held whole only
+        live[self._kv.kind] += self._kv.cached_blocks()
         self._m["kv_bytes"].set(self._bytes_of(live))
         self._m["kv_headroom"].set(self._bytes_of(self._kv.free_by_kind()))
 
@@ -1335,9 +1356,12 @@ class LLMEngine:
                 self._kvm["handoff_bytes"].inc(r.handoff_bytes)
                 acc_len = self._acc_len()
                 pad = acc_len - k_np.shape[1]
-                widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-                acc = {"k": jnp.asarray(np.pad(k_np, widths)),
-                       "v": jnp.asarray(np.pad(v_np, widths))}
+
+                def padded(rows):   # (layers, positions, ...): K/V or latent
+                    return jnp.asarray(np.pad(
+                        rows, ((0, 0), (0, pad))
+                        + ((0, 0),) * (rows.ndim - 2)))
+                acc = {"k": padded(k_np), "v": padded(v_np)}
                 # shared prefix blocks (a hit makes the shipped bytes
                 # for them redundant) and beyond-horizon slots write
                 # to trash
@@ -1357,10 +1381,19 @@ class LLMEngine:
                     self._pool, kv, self._targets(slot, nb), nb,
                     self._layout)
                 ran = n
+                self._count_prefill(
+                    1, lm.chunk_expanded_rows(self.cfg, b, 0, b))
             else:
                 logits = self._prefill_into_blocks(r, hit, slot)
                 ran = n - self._prefill_start(hit)
         return self._first_token(slot, r, disp, logits, ran)
+
+    def _count_prefill(self, chunks: int, rows: int) -> None:
+        """One admitted request's prefill forwards and the cache rows
+        they expanded to per-head K and V (none but for a latent model:
+        ``lm.chunk_expanded_rows``)."""
+        self._m["prefill_chunks"].observe(chunks)
+        self._m["latent_rows_expanded"].observe(rows)
 
     def _targets(self, slot: int, width: int, hit: int = 0) -> dict:
         """The physical ids a prefill's KV goes through, by layer kind:
@@ -1448,6 +1481,7 @@ class LLMEngine:
             self._acc_len(), self._layout)
         off = self._prefill_start(hit)
         logits = None
+        chunks = rows = 0
         while off < n:
             end = min(n, ((off // chunk) + 1) * chunk)
             part = r.tokens[off:end]
@@ -1456,7 +1490,11 @@ class LLMEngine:
             logits, acc = lm.prefill_chunk(
                 self.params, jnp.asarray(padded),
                 jnp.int32(len(part)), jnp.int32(off), acc, self.cfg)
+            chunks += 1
+            rows += lm.chunk_expanded_rows(self.cfg, b, off,
+                                           self._acc_len())
             off = end
+        self._count_prefill(chunks, rows)
         self._pool = kvcache.scatter_table(
             self._pool, acc, self._targets(slot, self._table_w, hit),
             self._layout)
@@ -1739,7 +1777,7 @@ class LLMEngine:
             "engine", "generate", r.trace, r.trace.span_id,
             r.t_submit_wall, time.time(), error=error,
             tokens=len(r.out),
-            kv_bytes=(self._block_bytes[kvcache.GLOBAL] // self._block
+            kv_bytes=(self._block_bytes[self._kv.kind] // self._block
                       * (len(r.tokens) + len(r.out))), **extra)
         r.trace = None
 

@@ -173,10 +173,14 @@ class KVBlockManager:
     def __init__(self, num_blocks: int, block_size: int, *,
                  table_width: int, prefix_cache: bool = True,
                  metrics: Optional[dict] = None,
-                 window: Optional[Tuple[int, int, int]] = None):
+                 window: Optional[Tuple[int, int, int]] = None,
+                 kind: str = "global"):
         """``window`` = (blocks of the window layers' pool, the sliding
         window, the steps one decode dispatch runs at most), for a
-        model with window layers."""
+        model with window layers. ``kind``: the layer kind whose pool
+        the ``num_blocks`` ids are of, the one a sequence holds from its
+        first position on (global layers' K and V, or latent layers'
+        rows): the name its tables and reports go by."""
         if num_blocks < 2:
             raise ValueError("pool needs >= 2 blocks (one is trash)")
         if window is not None and prefix_cache:
@@ -188,6 +192,7 @@ class KVBlockManager:
         self.block_size = int(block_size)
         self.table_width = int(table_width)
         self.prefix_cache = bool(prefix_cache)
+        self.kind = kind
         self.free: deque = deque(range(1, num_blocks))   # 0 = trash
         self.ref: Dict[int, int] = {}                    # phys -> count
         self.entries: Dict[str, _CacheEntry] = {}        # hash -> entry
@@ -271,14 +276,14 @@ class KVBlockManager:
 
     def used_by_kind(self) -> Dict[str, int]:
         """{kind: blocks of its pool live sequences hold}."""
-        used = {GLOBAL: self.used_blocks()}
+        used = {self.kind: self.used_blocks()}
         if self.window is not None:
             used[WINDOW] = self.window_used_blocks()
         return used
 
     def free_by_kind(self) -> Dict[str, int]:
         """{kind: blocks of its pool on the free list}."""
-        free = {GLOBAL: len(self.free)}
+        free = {self.kind: len(self.free)}
         if self.window is not None:
             free[WINDOW] = len(self.wfree)
         return free
@@ -391,7 +396,7 @@ class KVBlockManager:
         if self.window is not None:
             self.wseqs[seq_id] = {}
         # a window layer's: what the first decode step's window reaches
-        tables = {GLOBAL: table, **self.advance(seq_id, n, 0)}
+        tables = {self.kind: table, **self.advance(seq_id, n, 0)}
         return {"tables": tables, "hit_tokens": hit_tokens,
                 "new_blocks": new_blocks,
                 **{_TABLE_NAMES[kind]: row for kind, row in tables.items()}}
@@ -603,44 +608,79 @@ def _jx():
 # (the Llama family) has the one pair it always had. (llm/model.py
 # takes the two names from here: it imports jax, and this module must
 # not at import time.)
-GLOBAL, WINDOW = "global", "window"
-POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv")}
-_TABLE_NAMES = {GLOBAL: "table", WINDOW: "window_table"}    # alloc_seq
+#
+# A LATENT layer (multi-head latent attention) keeps ONE row a position,
+# [c | kr], and no head axis: its pair of arrays is the row's two parts,
+# c (kv_lora_rank wide: what keys and values are expanded from) and kr
+# (qk_rope_head_dim wide: the rotary key every head shares). Like a
+# global layer it attends everything, so a sequence holds its blocks
+# from the first position on and a prefix's blocks can be shared. An
+# array's width is a whole number of 128-lane tiles (LANES): the device
+# lays a narrower minor dimension out so anyway, and a kernel's DMA
+# cannot take part of a tile; kr's 64 values lie in 128, the rest zero.
+GLOBAL, WINDOW, LATENT = "global", "window", "latent"
+LANES = 128
+POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv"), LATENT: ("c", "kr")}
+_TABLE_NAMES = {GLOBAL: "table", WINDOW: "window_table",
+                LATENT: "table"}                            # alloc_seq
 
 
 def pool_kinds(cfg) -> tuple:
     """How a model's cache is laid out: ``((kind, its layers), ...)``,
-    the global layers first (hashable: the device ops below are built
-    per value); ``((GLOBAL, (0, ..., L - 1)),)`` without window layers."""
+    the kind a sequence holds whole (global or latent) first (hashable:
+    the device ops below are built per value); ``((GLOBAL, (0, ..., L -
+    1)),)`` for the Llama family."""
     from ray_tpu.llm.model import kind_layers
     kinds = kind_layers(cfg)
-    if GLOBAL not in kinds:
+    if set(kinds) == {WINDOW}:
         raise NotImplementedError(
             "a model whose layers are all window layers is not served "
-            "yet: the pool's geometry is read off its global layers")
+            "yet: admission reserves a sequence's blocks of the kind it "
+            "holds whole")
     return tuple(kinds.items())
+
+
+def row_shapes(cfg, kind: str) -> tuple:
+    """What one position of one layer of ``kind`` keeps: the shapes of
+    its row in the kind's two arrays (``POOL_KEYS``), heads then width.
+    THE geometry rule: the pool's arrays, a block's bytes and the walk's
+    chunk all follow from it."""
+    if kind == LATENT:
+        return tuple((-(-w // LANES) * LANES,)
+                     for w in (cfg.kv_lora_rank, cfg.qk_rope_head_dim))
+    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
+
+
+def row_bytes(cfg, kind: str, dtype) -> int:
+    """Bytes one position of one layer of ``kind`` costs (both arrays)."""
+    _, jnp = _jx()
+    return sum(int(np.prod(shape)) for shape in row_shapes(cfg, kind)) \
+        * jnp.dtype(dtype).itemsize
 
 
 def init_pool(cfg, num_blocks: int, block_size: int, dtype,
               window_blocks: int = 0) -> dict:
-    """The pool tensors: k/v of shape (global layers, num_blocks,
-    kv_heads, block_size, head_dim) and, for a model with window
-    layers, wk/wv (window layers, window_blocks, ...)."""
+    """The pool tensors, a pair a layer kind (``POOL_KEYS``), each (the
+    kind's layers, its blocks, *a row's heads, block_size, a row's
+    width): k/v (global layers, num_blocks, kv_heads, block_size,
+    head_dim); for window layers wk/wv (window layers, window_blocks,
+    ...); for latent layers c/kr (latent layers, num_blocks, block_size,
+    kv_lora_rank | qk_rope_head_dim)."""
     _, jnp = _jx()
     pool = {}
     for kind, layers in pool_kinds(cfg):
-        shape = (len(layers),
-                 num_blocks if kind == GLOBAL else max(2, window_blocks),
-                 cfg.n_kv_heads, block_size, cfg.head_dim)
-        for key in POOL_KEYS[kind]:
-            pool[key] = jnp.zeros(shape, dtype)
+        blocks = max(2, window_blocks) if kind == WINDOW else num_blocks
+        for key, row in zip(POOL_KEYS[kind], row_shapes(cfg, kind)):
+            pool[key] = jnp.zeros(
+                (len(layers), blocks, *row[:-1], block_size, row[-1]), dtype)
     return pool
 
 
-def pool_k(pool: dict, kind: str = GLOBAL):
-    """A kind's K array, (its layers, its blocks, kv_heads, block_size,
-    head_dim): what a geometry is read off."""
-    return pool[POOL_KEYS[kind][0]]
+def pool_k(pool: dict, kind: Optional[str] = None):
+    """A kind's first array, (its layers, its blocks, ..., block_size,
+    width): what a geometry is read off (the block size is axis -2 for
+    every kind). Without ``kind``: of the first kind the pool holds."""
+    return pool[POOL_KEYS[kind or _held_kinds(pool)[0]][0]]
 
 
 def kind_block_bytes(pool: dict) -> dict:
@@ -660,7 +700,8 @@ def window_ring_blocks(window: int, block_size: int, steps: int) -> int:
 
 def auto_pool_blocks(slots: int, table_width: int, block_bytes: int,
                      configured: int = 0, reserved_bytes: int = 0) -> int:
-    """Blocks of the GLOBAL layers' pool: the explicit knob wins;
+    """Blocks of the pool of the kind a sequence holds whole (the global
+    layers', or the latent layers'): the explicit knob wins;
     otherwise worst case (every slot at max_len) plus one full chain of
     prefix-cache headroom, capped at a quarter of the free HBM of the
     fullest local device when the backend reports a capacity
@@ -702,9 +743,9 @@ _JITS: dict = {}    # (op, pool geometry, dtype) -> jitted callable
 
 def _pool_key(pool: dict) -> tuple:
     """Cache-key component identifying one pool's compiled geometry:
-    the global layers' shape, the dtype, then any other kind's shape."""
-    shapes = [tuple(pool[keys[0]].shape) for keys in POOL_KEYS.values()
-              if keys[0] in pool]
+    the first array's shape, the dtype, then every other array's shape."""
+    shapes = [tuple(pool[key].shape) for kind in _held_kinds(pool)
+              for key in POOL_KEYS[kind]]
     return (shapes[0], str(pool_k(pool).dtype), *shapes[1:])
 
 
@@ -714,15 +755,20 @@ def _pool_key(pool: dict) -> tuple:
 # the engine hand them, a bare array for such a model and no layout:
 # these two functions, at an entry's first line, are all that knows it.
 
-def _by_kind(ids) -> dict:
-    return ids if isinstance(ids, dict) else {GLOBAL: ids}
+def _held_kinds(pool: dict) -> tuple:
+    return tuple(kind for kind, keys in POOL_KEYS.items()
+                 if keys[0] in pool)
+
+
+def _by_kind(ids, pool: dict) -> dict:
+    return ids if isinstance(ids, dict) else {_held_kinds(pool)[0]: ids}
 
 
 def _layout(pool: dict, kinds=None) -> tuple:
     """``pool_kinds`` of the model ``pool`` was made for: the caller's,
     which must agree with the pool, or read off a pool of one kind."""
-    held = tuple((kind, pool[keys[0]].shape[0])
-                 for kind, keys in POOL_KEYS.items() if keys[0] in pool)
+    held = tuple((kind, pool_k(pool, kind).shape[0])
+                 for kind in _held_kinds(pool))
     kinds = tuple(kinds or ((kind, tuple(range(n))) for kind, n in held))
     layers = sorted(l for _, ls in kinds for l in ls)
     if tuple((kind, len(ls)) for kind, ls in kinds) != held \
@@ -732,14 +778,35 @@ def _layout(pool: dict, kinds=None) -> tuple:
     return kinds
 
 
+def _pad_last(x, width: int):
+    """x's last axis zero-padded to ``width`` (a latent row's part to
+    its pool array's whole tiles)."""
+    _, jnp = _jx()
+    pad = width - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
+
+
 def _to_blocks(kv, nb: int, pool):
-    """The first ``nb * block`` positions of token-order KV (layers,
-    positions, kvh, hd) as ``nb`` head-major pool blocks (layers, nb,
-    kvh, block, hd), in the pool's dtype."""
-    L, _, kvh, hd = kv.shape
-    bs = pool.shape[3]
-    return kv[:, :nb * bs].reshape(L, nb, bs, kvh, hd).transpose(
-        0, 1, 3, 2, 4).astype(pool.dtype)
+    """The first ``nb * block`` positions of token-order rows (layers,
+    positions, kvh, hd), or (layers, positions, width) of a kind with no
+    head axis, as ``nb`` pool blocks (layers, nb, kvh, block, hd) /
+    (layers, nb, block, width: the rows zero-padded to the pool's), in
+    the pool's dtype."""
+    bs = pool.shape[-2]
+    blocks = kv[:, :nb * bs].reshape(kv.shape[0], nb, bs, *kv.shape[2:])
+    if kv.ndim == 4:
+        blocks = blocks.transpose(0, 1, 3, 2, 4)        # head-major
+    else:                                               # to whole tiles
+        blocks = _pad_last(blocks, pool.shape[-1])
+    return blocks.astype(pool.dtype)
+
+
+def _from_blocks(g):
+    """``_to_blocks`` back: gathered pool blocks (layers, w, ..., block,
+    width) as token-order rows (layers, w * block, ...)."""
+    if g.ndim == 5:
+        g = g.transpose(0, 1, 3, 2, 4)
+    return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
 
 
 def _jit(name: str, pool: dict, kinds: tuple = ()):
@@ -777,14 +844,11 @@ def _jit(name: str, pool: dict, kinds: tuple = ()):
                 views = []
                 for kind, _ in kinds:
                     dst, ids = POOL_KEYS[kind][i], phys[kind]
-                    L, _, kvh, bs, hd = pool[dst].shape
-                    w = ids.shape[0]
-                    g = pool[dst][:, ids]        # (L, w, kvh, bs, hd)
-                    g = g.transpose(0, 1, 3, 2, 4).reshape(
-                        L, w * bs, kvh, hd)
-                    pad = acc_len - w * bs
+                    g = _from_blocks(pool[dst][:, ids])
+                    pad = acc_len - g.shape[1]
                     if pad > 0:
-                        g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                        g = jnp.pad(g, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (g.ndim - 2))
                     views.append(g)
                 out[src] = rows(jnp.concatenate(views), order)
             return out
@@ -801,7 +865,8 @@ def _jit(name: str, pool: dict, kinds: tuple = ()):
         def fn(pool, src, dst):
             return {**pool, **{
                 key: pool[key].at[:, dst].set(pool[key][:, src])
-                for key in POOL_KEYS[GLOBAL]}}
+                for kind, keys in POOL_KEYS.items() if kind != WINDOW
+                for key in keys if key in pool}}
     else:
         raise KeyError(name)
     _JITS[key] = fn
@@ -813,7 +878,7 @@ def scatter_bucket(pool: dict, kv: dict, phys, nb: int,
     """Write a bucket-padded prefill's KV into ``nb`` physical blocks
     (pad-garbage blocks redirected to trash by the caller's phys):
     scatter_table over a bucket's width, one compile per bucket size."""
-    phys = _by_kind(phys)
+    phys = _by_kind(phys, pool)
     if any(ids.shape[0] != nb for ids in phys.values()):
         raise ValueError(f"a bucket of {nb} blocks needs {nb} ids a kind")
     return scatter_table(pool, kv, phys, kinds)
@@ -827,7 +892,7 @@ def gather_table(pool: dict, phys, acc_len: int, kinds=None) -> dict:
     the table (ops/pallas/paged_attention.py); this stays for the
     prefix-hit prefill accumulator and debug/parity tooling."""
     return _jit("gather_table", pool, _layout(pool, kinds))(
-        pool, _by_kind(phys), acc_len)
+        pool, _by_kind(phys, pool), acc_len)
 
 
 def scatter_table(pool: dict, acc: dict, phys, kinds=None) -> dict:
@@ -835,12 +900,13 @@ def scatter_table(pool: dict, acc: dict, phys, kinds=None) -> dict:
     target vector (shared-prefix and beyond-horizon slots point at trash
     so shared blocks are never written). One compile a width."""
     return _jit("scatter_table", pool, _layout(pool, kinds))(
-        pool, acc, _by_kind(phys))
+        pool, acc, _by_kind(phys, pool))
 
 
 def copy_block(pool: dict, src: int, dst: int) -> dict:
     """Device-side block copy (the COW divergence path; blocks of the
-    global layers, the only ones that are ever shared)."""
+    kind a sequence holds whole, global or latent: the only ones that
+    are ever shared)."""
     _, jnp = _jx()
     return _jit("copy_block", pool)(pool, jnp.int32(src),
                                     jnp.int32(dst))
@@ -925,6 +991,10 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     def flat(pool):
         return pool.reshape(-1, *pool.shape[2:])
 
+    if LATENT in tables:
+        return _latent_attend(cfg, tables[LATENT], at[LATENT], lens,
+                              flat, impl=impl, interpret=interpret)
+
     def window_kw(kind):
         return {} if kind == GLOBAL else {"window": cfg.sliding_window}
 
@@ -983,6 +1053,57 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     return attend
 
 
+def _latent_attend(cfg, tables, at, lens, flat, *, impl, interpret):
+    """``_pool_attend`` for LATENT layers (a model of them alone): q is
+    the ABSORBED query (..., heads, kv_lora_rank + qk_rope_head_dim:
+    lm.latent_absorb), k and v the new positions' c and kr rows, and
+    what comes back is each head's attention-weighted sum of the c rows
+    (..., heads * kv_lora_rank), for lm.latent_unabsorb. One shared row
+    a position: multi-query attention with a key of [c | kr] and the
+    value c, the row fetched once. impl='paged_flash': the aliased row
+    writer, then the walk (ops/pallas/paged_attention.py latent_write,
+    latent_decode; a verify round the gather twin). impl='gather': a
+    scatter on the carry and the plain reference."""
+    _, jnp = _jx()
+    from ray_tpu.llm import model as lm
+    from ray_tpu.ops.pallas import paged_attention as pa
+    lead = lens.shape
+    multi = len(lead) == 2
+    scale = lm.softmax_scale(cfg, LATENT)
+    ck, rk = POOL_KEYS[LATENT]
+    phys, off = at
+
+    def tiles(x, pool):
+        return _pad_last(x, pool.shape[-1])
+
+    def attend(ref, q, c, kr, pool):
+        cp, rp = pool[ck], pool[rk]
+        l = ref.kind_index
+        tb = tables + l * cp.shape[1]
+        lat = cfg.kv_lora_rank
+        q = jnp.concatenate([tiles(q[..., :lat], cp), tiles(q[..., lat:], rp)],
+                            axis=-1).reshape(*lead, cfg.n_heads, -1)
+        c, kr = tiles(c, cp), tiles(kr, rp)
+        if impl == "paged_flash":
+            cf, rf = pa.latent_write(
+                flat(cp), flat(rp), (phys + l * cp.shape[1]).reshape(-1),
+                off.reshape(-1), c.reshape(-1, c.shape[-1]).astype(cp.dtype),
+                kr.reshape(-1, kr.shape[-1]).astype(rp.dtype),
+                interpret=interpret)
+            o = (pa.latent_attention_reference(q, cf, rf, tb, lens,
+                                               sm_scale=scale) if multi
+                 else pa.latent_decode(q, cf, rf, tb, lens, sm_scale=scale,
+                                       interpret=interpret))
+            cp, rp = cf.reshape(cp.shape), rf.reshape(rp.shape)
+        else:
+            cp = cp.at[l, phys, off].set(c.astype(cp.dtype))
+            rp = rp.at[l, phys, off].set(kr.astype(rp.dtype))
+            o = pa.latent_attention_reference(q, flat(cp), flat(rp), tb,
+                                              lens, sm_scale=scale)
+        return o[..., :lat].reshape(*lead, -1), {**pool, ck: cp, rk: rp}
+    return attend
+
+
 def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
@@ -992,7 +1113,7 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     the attention over the table plugged in (_pool_attend). Returns
     (logits, pool, the expert layers' counts or None)."""
     from ray_tpu.llm.model import decode_logits_core
-    bs = pool_k(pool).shape[3]
+    bs = pool_k(pool).shape[-2]
     positions = lengths
     return decode_logits_core(
         params, pool, tokens, positions, cfg,
@@ -1022,7 +1143,7 @@ def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
         def paged_decode_logits(params, pool, tables, lengths, tokens,
                                 cfg):
             return _paged_logits_core(
-                params, pool, _by_kind(tables), lengths, tokens, cfg,
+                params, pool, _by_kind(tables, pool), lengths, tokens, cfg,
                 impl=impl, interpret=interpret, mesh=mesh, axis=axis)[0]
         fn = _JITS[key_] = paged_decode_logits
     return fn(params, pool, tables, lengths, tokens, cfg)
@@ -1076,7 +1197,7 @@ def decode_steps_program(pool, *, impl="gather", interpret=False,
                  donate_argnums=(1,))
         def paged_decode_steps(params, pool, tables, lengths, tokens,
                                temps, key, cfg, n, top_ps, top_ks):
-            tables = _by_kind(tables)
+            tables = _by_kind(tables, pool)
 
             def body(carry, i):
                 pool, toks = carry
@@ -1142,7 +1263,7 @@ def _paged_verify_core(params, pool, tables, lengths, tokens, cfg, *,
     _, jnp = _jx()
     from ray_tpu.llm.model import verify_tokens_core
     wq = tokens.shape[1]
-    bs = pool_k(pool).shape[3]
+    bs = pool_k(pool).shape[-2]
     pos = lengths[:, None] + jnp.arange(wq, dtype=jnp.int32)[None]
     return verify_tokens_core(
         params, pool, tokens, lengths, cfg,
@@ -1180,7 +1301,7 @@ def verify_steps_program(pool, wq: int, *, impl="gather",
         def paged_verify_steps(params, pool, tables, lengths, tokens,
                                cfg):
             return _paged_verify_core(
-                params, pool, _by_kind(tables), lengths, tokens, cfg,
+                params, pool, _by_kind(tables, pool), lengths, tokens, cfg,
                 impl=impl, interpret=interpret, mesh=mesh, axis=axis)
         fn = _JITS[key_] = paged_verify_steps
     return fn

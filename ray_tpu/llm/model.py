@@ -32,9 +32,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.llm.kvcache import GLOBAL, WINDOW
-from ray_tpu.models.llama import (LlamaConfig, _rmsnorm, _rope,
-                                  _rope_tables)
+from ray_tpu.llm.kvcache import GLOBAL, LATENT, WINDOW
+from ray_tpu.models.llama import (LlamaConfig, _rmsnorm, _rope, _rope_pairs,
+                                  _rope_tables, _rope_tables_freqs,
+                                  yarn_inv_freq, yarn_mscale)
 
 
 def bucket_for(buckets, n: int) -> int:
@@ -116,7 +117,10 @@ def _scan_layers(layer, x, xs):
 # ``getattr`` so that a LlamaConfig is the case "every layer global, one
 # dense stack, pre-norm, RoPE everywhere, no experts":
 #
-#   layer_types      "global" | "window" per layer (sliding_window wide)
+#   layer_types      "global" | "window" per layer (sliding_window wide),
+#                    or every layer "latent" (multi-head latent attention:
+#                    the widths and the YaRN rotary of models/moe.py; its
+#                    cache is a row of c and kr a token, not K and V)
 #   n_dense_layers   leading layers live in ``params["dense_layers"]``
 #                    (a dense SwiGLU); the rest in ``params["layers"]``,
 #                    whose leaves decide the feed-forward: a ``router``
@@ -132,16 +136,22 @@ def model_family(cfg):
 
 
 def layer_kinds(cfg) -> tuple:
-    """The attention kind of every layer, "global" or "window"."""
+    """The attention kind of every layer: "global" or "window", or all
+    of them "latent"."""
     kinds = tuple(getattr(cfg, "layer_types", ()) or ())
     if not kinds:
         return (GLOBAL,) * cfg.n_layers
-    if len(kinds) != cfg.n_layers or set(kinds) - {GLOBAL, WINDOW}:
+    if len(kinds) != cfg.n_layers or set(kinds) - {GLOBAL, WINDOW, LATENT}:
         raise ValueError(
-            f"layer_types must name {cfg.n_layers} layers 'global' or "
-            f"'window', got {kinds}")
+            f"layer_types must name {cfg.n_layers} layers 'global', "
+            f"'window' or 'latent', got {kinds}")
     if WINDOW in kinds and getattr(cfg, "sliding_window", 0) < 1:
         raise ValueError("window layers need sliding_window >= 1")
+    if LATENT in kinds and set(kinds) != {LATENT}:
+        raise ValueError(
+            "latent layers beside K/V layers are not served: a prompt's "
+            "token-order rows (prefill's output, the chunked prefill's "
+            "accumulator) are one pair of arrays for all its layers")
     return kinds
 
 
@@ -151,7 +161,7 @@ def kind_layers(cfg) -> dict:
     kind (llm/kvcache.py init_pool)."""
     kinds = layer_kinds(cfg)
     return {k: tuple(i for i, x in enumerate(kinds) if x == k)
-            for k in (GLOBAL, WINDOW) if k in kinds}
+            for k in (GLOBAL, LATENT, WINDOW) if k in kinds}
 
 
 def window_of(cfg, kind: str):
@@ -289,6 +299,106 @@ def _run_layers(params, cfg, carry, body, per_layer=()):
     return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys), *pieces)
 
 
+def rope_tables(cfg, positions) -> tuple:
+    """What ``_layer`` rotates with at ``positions`` (b, s): (cos, sin),
+    and for a latent model YaRN's tables over the rotary part of a head
+    plus the positions' query scale a(t) (b, s) float32: 1 + beta *
+    ln(1 + floor(t / original)), 1 below the original length."""
+    if LATENT not in layer_kinds(cfg):
+        return _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    dim, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    if not cfg.rope_factor:
+        return (*_rope_tables(positions, dim, theta),
+                jnp.ones(positions.shape, jnp.float32))
+    cos, sin = _rope_tables_freqs(
+        positions,
+        yarn_inv_freq(dim, theta, cfg.rope_factor, cfg.rope_original_len,
+                      cfg.rope_beta_fast, cfg.rope_beta_slow),
+        yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+        / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    a = 1.0 + cfg.query_scale_beta * jnp.log1p(
+        (positions // cfg.rope_original_len).astype(jnp.float32))
+    return cos, sin, a
+
+
+def softmax_scale(cfg, kind: str) -> float:
+    """What a layer's scores q . k are multiplied by: head ** -0.5, for
+    a latent layer times YaRN's mscale(factor, mscale_all_dim) squared."""
+    if kind != LATENT:
+        return cfg.head_dim ** -0.5
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) \
+        if cfg.rope_factor else 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _latent_qkv(y, lp, cfg, rope):
+    """A latent layer's projections of normed rows y (b, s, d): (q (b,
+    s, h, nope + rope) with its rope part rotated and a(t) applied, the
+    positions' cache rows c (b, s, kv_lora_rank), normed, and kr (b, s,
+    rope), rotated)."""
+    cos, sin, a = rope
+    b, s = y.shape[:2]
+    eps, nope = cfg.norm_eps, cfg.qk_nope_head_dim
+    with jax.named_scope("mla.q"):
+        cq = _rmsnorm(y @ lp["wq_a"], lp["q_a_norm"], eps)
+        # fenced as _qkv's products are (the weight read where it lies)
+        q = lax.optimization_barrier(cq @ lp["wq_b"]).reshape(
+            b, s, cfg.n_heads, -1)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope_pairs(q[..., nope:], cos, sin)], axis=-1)
+        q = q * a[:, :, None, None].astype(q.dtype)
+    with jax.named_scope("mla.kv_down"):
+        ckr = lax.optimization_barrier(y @ lp["wkv_a"])
+        c = _rmsnorm(ckr[..., :cfg.kv_lora_rank], lp["kv_norm"], eps)
+        kr = _rope_pairs(ckr[..., cfg.kv_lora_rank:], cos, sin)
+    return q, c, kr
+
+
+def latent_expand(q, c, kr, lp, cfg):
+    """Materialise what a prefill attends: per-head keys [c Wk_b[h] |
+    kr] and values c Wv_b[h] from rows c (1, n, kv_lora_rank), kr (1, n,
+    rope), in q's dtype: (1, n, h, nope + rope) and (1, n, h, v)."""
+    with jax.named_scope("mla.expand"):
+        # an accumulator gathered from the pool has the pool's widths
+        c = c[..., :cfg.kv_lora_rank].astype(q.dtype)
+        kr = kr[..., :cfg.qk_rope_head_dim]
+        k_nope = jnp.einsum("bnc,hdc->bnhd", c, lp["wk_b"])
+        v = jnp.einsum("bnc,hcd->bnhd", c, lp["wv_b"])
+        kr = jnp.broadcast_to(kr.astype(q.dtype)[:, :, None, :],
+                              (*k_nope.shape[:3], kr.shape[-1]))
+        return jnp.concatenate([k_nope, kr], axis=-1), v
+
+
+def latent_absorb(q, lp, cfg):
+    """The absorbed query: q (..., h, nope + rope) -> (..., h,
+    kv_lora_rank + rope), [q_nope Wk_b[h] | q_rope], which scores a
+    cache row [c | kr] as q scores the key expanded from it."""
+    with jax.named_scope("mla.absorb"):
+        nope = cfg.qk_nope_head_dim
+        return jnp.concatenate(
+            [jnp.einsum("...hd,hdc->...hc", q[..., :nope], lp["wk_b"]),
+             q[..., nope:]], axis=-1)
+
+
+def latent_unabsorb(o, lp, cfg):
+    """Each head's weighted sum of c rows, o (..., h * kv_lora_rank)
+    float32, through Wv_b[h]: (..., h * v) in the weights' dtype."""
+    with jax.named_scope("mla.unabsorb"):
+        w = lp["wv_b"]
+        o = o.reshape(*o.shape[:-1], cfg.n_heads, -1).astype(w.dtype)
+        o = jnp.einsum("...hc,hcd->...hd", o, w)
+        return o.reshape(*o.shape[:-2], -1)
+
+
+def _attend_pool(attend, ref, lp, cfg, q, k, v, pool):
+    """A decode or verify forward's ``attend`` against the pool; a latent
+    layer's in the absorbed form around it."""
+    if ref.kind != LATENT:
+        return attend(ref, q, k, v, pool)
+    o, pool = attend(ref, latent_absorb(q, lp, cfg), k, v, pool)
+    return latent_unabsorb(o, lp, cfg), pool
+
+
 def _qkv(y, lp, cfg: LlamaConfig):
     b, s = y.shape[:2]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -332,14 +442,21 @@ def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
     ``rope`` the (cos, sin) tables of the positions; ``attend(q, k, v,
     wo)`` attends (the forwards differ in nothing else) and returns the
     projected output, shaped like x. Returns (x, k, v, expert counts or
-    None); k is as the cache keeps it (after RoPE)."""
+    None); k is as the cache keeps it (after RoPE). For a latent layer k
+    and v are the cache's rows c and kr (``_latent_qkv``)."""
     post = getattr(cfg, "post_norm", False)
     eps = cfg.norm_eps
     with jax.named_scope("attention." + ref.kind):
         y = x if post else _rmsnorm(x, lp["attn_norm"], eps)
-        q, k, v = _qkv(y, lp, cfg)
-        if ref.kind == WINDOW or getattr(cfg, "rope_layers", "all") == "all":
-            q, k = _rope(q, *rope), _rope(k, *rope)
+        if ref.kind == LATENT:
+            # k, v: the positions' cache rows c and kr (no head axis);
+            # the forward's ``attend`` expands or absorbs
+            q, k, v = _latent_qkv(y, lp, cfg, rope)
+        else:
+            q, k, v = _qkv(y, lp, cfg)
+            if ref.kind == WINDOW \
+                    or getattr(cfg, "rope_layers", "all") == "all":
+                q, k = _rope(q, *rope), _rope(k, *rope)
         a = attend(q, k, v, lp["wo"])
         x = x + (_rmsnorm(a, lp["attn_norm"], eps) if post else a)
     y = x if post else _rmsnorm(x, lp["mlp_norm"], eps)
@@ -413,7 +530,11 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
             cfg: LlamaConfig, max_len: int) -> Tuple[jax.Array, dict]:
     """One padded prompt. tokens: (s,) int32 (padded to a bucket);
     length: () actual prompt length. Returns (last-token logits (vocab,),
-    per-layer kv padded to max_len: k/v (layers, max_len, kvh, hd)).
+    per-layer kv padded to max_len: k/v (layers, max_len, kvh, hd); of a
+    latent model the cache's rows under the same two names, "k" the c
+    rows (layers, max_len, kv_lora_rank) and "v" the kr rows (layers,
+    max_len, qk_rope_head_dim), from which its attention materialised
+    per-head keys and values).
 
     Attention dispatches through ops.attention (cfg.attn_impl): the
     pallas flash kernel tiles long prompts on TPU instead of
@@ -426,12 +547,15 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
     s = tokens.shape[0]
     x = jnp.take(params["embed"], tokens[None], axis=0)  # (1, s, emb)
     positions = jnp.arange(s, dtype=jnp.int32)[None]
-    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = rope_tables(cfg, positions)
     h, hd = cfg.n_heads, cfg.head_dim
 
     def layer(x, lp, ref):
         def attend(q, k, v, wo):
-            o = _attention(q, k, v, causal=True, sm_scale=hd ** -0.5,
+            if ref.kind == LATENT:
+                k, v = latent_expand(q, k, v, lp, cfg)
+            o = _attention(q, k, v, causal=True,
+                           sm_scale=softmax_scale(cfg, ref.kind),
                            impl=_serve_attn_impl(cfg),
                            **_prefill_attn_kw(cfg, ref.kind))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
@@ -440,9 +564,11 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
 
     x, (ks, vs) = _run_layers(params, cfg, x, layer)
     logits = _head(x, params, cfg, length)
-    # pad kv (layers, s, kvh, hd) -> (layers, max_len, kvh, hd)
-    pad = [(0, 0), (0, max_len - s), (0, 0), (0, 0)]
-    return logits, {"k": jnp.pad(ks, pad), "v": jnp.pad(vs, pad)}
+    # pad the rows (layers, s, ...) -> (layers, max_len, ...)
+    def pad(rows):
+        return jnp.pad(rows, [(0, 0), (0, max_len - s)]
+                       + [(0, 0)] * (rows.ndim - 2))
+    return logits, {"k": pad(ks), "v": pad(vs)}
 
 
 def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
@@ -469,24 +595,50 @@ def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
     per distinct offset — offsets are chunk-size multiples, so at most
     ceil(max_len / chunk) variants); otherwise the dynamic-offset XLA
     path below compiles once."""
-    from ray_tpu.ops.attention import _on_tpu
-    impl = _serve_attn_impl(cfg)
-    if impl == "flash" or impl == "flash_interpret" or (
-            impl == "auto" and _on_tpu() and tokens.shape[0] >= 128):
-        if impl == "auto":
-            impl = "flash"
+    impl = _chunk_flash_impl(cfg, tokens.shape[0])
+    if impl is not None:
         return _prefill_chunk_flash(params, tokens, length, int(offset),
                                     acc, cfg, impl)
     return _prefill_chunk_dyn(params, tokens, length,
                               jnp.asarray(offset, jnp.int32), acc, cfg)
 
 
+def _chunk_flash_impl(cfg, s: int):
+    """The flash impl a chunk of ``s`` tokens takes, or None for the
+    dynamic-offset XLA path."""
+    from ray_tpu.ops.attention import _on_tpu
+    impl = _serve_attn_impl(cfg)
+    if impl in ("flash", "flash_interpret"):
+        return impl
+    return "flash" if impl == "auto" and _on_tpu() and s >= 128 else None
+
+
+def chunk_attended_rows(cfg, s: int, offset: int, acc_len: int) -> int:
+    """Rows of the accumulator one ``prefill_chunk`` of ``s`` tokens at
+    ``offset`` attends, and for a latent model EXPANDS to per-head keys
+    and values: the prefix and the chunk on the flash path (the offset
+    is static there), the whole accumulator on the dynamic-offset path
+    (masked after)."""
+    if _chunk_flash_impl(cfg, s) is not None:
+        return min(offset + s, acc_len)
+    return acc_len
+
+
+def chunk_expanded_rows(cfg, s: int, offset: int, acc_len: int) -> int:
+    """Cache rows that chunk expands to per-head keys and values: what
+    it attends for a latent model, none for a model that caches K and V
+    (the engine's ``llm_latent_rows_expanded_size``)."""
+    if LATENT not in layer_kinds(cfg):
+        return 0
+    return chunk_attended_rows(cfg, s, offset, acc_len)
+
+
 def _into_acc(acc, new, offset):
     """One layer's accumulator (L, kvh, hd) with the chunk's rows
-    (1, s, kvh, hd) written at ``offset``."""
+    (1, s, kvh, hd) written at ``offset`` (latent rows: (L, width))."""
     return lax.dynamic_update_slice(
         acc, new[0].astype(acc.dtype),
-        (jnp.int32(offset), jnp.int32(0), jnp.int32(0)))
+        (jnp.int32(offset),) + (jnp.int32(0),) * (acc.ndim - 1))
 
 
 @partial(jax.jit, static_argnames=("cfg", "offset", "impl"),
@@ -503,14 +655,22 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
     h, hd = cfg.n_heads, cfg.head_dim
     x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
-    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = rope_tables(cfg, positions)
 
     def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
         def attend(q, k, v, wo):
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
-            o = _attention(q, nk[None].astype(q.dtype),
-                           nv[None].astype(q.dtype), causal=True,
-                           sm_scale=hd ** -0.5, impl=impl, q_offset=offset,
+            if ref.kind == LATENT:
+                # the accumulator keeps latent rows; what this chunk
+                # attends (the prefix and itself) is expanded here
+                n = min(offset + s, nk.shape[0])
+                nk, nv = latent_expand(q, nk[None, :n], nv[None, :n], lp,
+                                       cfg)
+            else:
+                nk, nv = nk[None].astype(q.dtype), nv[None].astype(q.dtype)
+            o = _attention(q, nk, nv, causal=True,
+                           sm_scale=softmax_scale(cfg, ref.kind),
+                           impl=impl, q_offset=offset,
                            **_prefill_attn_kw(cfg, ref.kind))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
         x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
@@ -532,14 +692,14 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
     g = h // kvh
     x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
-    rope = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope = rope_tables(cfg, positions)
     q_pos = positions[0]                                    # (s,)
     k_pos = jnp.arange(L, dtype=jnp.int32)                  # (L,)
     # causal over ABSOLUTE positions (covers both earlier chunks and
     # intra-chunk order), limited to valid keys
     m = (k_pos[None, :] <= q_pos[:, None]) & \
         (k_pos[None, :] < offset + length)
-    masks = {GLOBAL: m}
+    masks = {GLOBAL: m, LATENT: m}
     if WINDOW in layer_kinds(cfg):
         masks[WINDOW] = m & (k_pos[None, :]
                              > q_pos[:, None] - cfg.sliding_window)
@@ -547,9 +707,13 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
     def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
         def attend(q, k, v, wo):
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
+            if ref.kind == LATENT:          # every row expanded, then masked
+                (nk,), (nv,) = latent_expand(q, nk[None], nv[None], lp, cfg)
             qg = q[0].reshape(s, kvh, g, hd).astype(jnp.float32)
             kf = nk.astype(jnp.float32)                     # (L, kvh, hd)
-            scores = jnp.einsum("skgd,lkd->kgsl", qg, kf) / jnp.sqrt(hd)
+            scores = jnp.einsum("skgd,lkd->kgsl", qg, kf)
+            scores = scores * softmax_scale(cfg, LATENT) \
+                if ref.kind == LATENT else scores / jnp.sqrt(hd)
             scores = jnp.where(masks[ref.kind][None, None], scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1)
             o = jnp.einsum("kgsl,lkd->skgd", probs,
@@ -649,12 +813,16 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     over the slot's table of that kind, the one thing callers differ
     in: the aliased writer and the kernel that walks the table itself
     (ops/pallas/paged_attention.py), or their reference, a scatter +
-    table_view + _gqa_attend_cached. Returns ((slots, vocab) f32
+    table_view + _gqa_attend_cached. A LATENT layer hands ``attend`` the
+    absorbed query (slots, 1, h, kv_lora_rank + rope) and the new rows c
+    and kr, and takes what comes back, (slots, h * kv_lora_rank), through
+    Wv_b (latent_absorb / latent_unabsorb: the same numbers as keys and
+    values expanded from the rows, with none expanded). Returns ((slots, vocab) f32
     logits, pool, the expert layers' counts summed over the layers or
     None: models/moe.py serve_block, live rows being the slots at a
     position > 0)."""
     x = jnp.take(params["embed"], tokens[:, None], axis=0)  # (b, 1, emb)
-    rope = _rope_tables(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    rope = rope_tables(cfg, positions[:, None])
     active = positions > 0 if has_experts(cfg) else None
 
     def layer(carry, lp, ref):
@@ -662,7 +830,8 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
 
         def attend_(q, k, v, wo):
             nonlocal pool
-            o, pool = attend(ref, q, k[:, 0], v[:, 0], pool)
+            o, pool = _attend_pool(attend, ref, lp, cfg, q, k[:, 0],
+                                   v[:, 0], pool)
             return (o.astype(x.dtype) @ wo)[:, None]
         x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active)
         return (x, pool, _add_counts(counts, stats)), None
@@ -723,14 +892,14 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
     b, w = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)           # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
-    rope = _rope_tables(pos, cfg.head_dim, cfg.rope_theta)
+    rope = rope_tables(cfg, pos)
 
     def layer(carry, lp, ref):
         x, pool = carry
 
         def attend_(q, k, v, wo):
             nonlocal pool
-            o, pool = attend(ref, q, k, v, pool)
+            o, pool = _attend_pool(attend, ref, lp, cfg, q, k, v, pool)
             return o.astype(x.dtype) @ wo
         x, _, _, _ = _layer(x, lp, cfg, ref, rope, attend_)
         return (x, pool), None

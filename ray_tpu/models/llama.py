@@ -209,6 +209,59 @@ def _rope(x, cos, sin):
         [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies of ``dim`` rotary dimensions, (dim // 2,)
+    float32 numpy (static: they depend on the configuration alone). A
+    frequency that turns more than ``beta_fast`` times over the
+    ``original`` positions keeps theta_i; one that turns fewer than
+    ``beta_slow`` times becomes theta_i / factor (position interpolation);
+    between the two correction dimensions the blend is linear."""
+    import math
+
+    import numpy as np
+    half = dim // 2
+    freqs = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def correction_dim(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return (freqs / factor * ramp + freqs * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction 0.1 * mscale * ln(factor) + 1."""
+    import math
+    return 1.0 if factor <= 1 or not mscale \
+        else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rope_tables_freqs(positions, inv_freq, factor: float = 1.0):
+    """cos/sin tables (b, s, half) f32 of given inverse frequencies,
+    carrying the attention ``factor`` (1 leaves them as they are)."""
+    angles = positions[:, :, None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
+
+
+def _rope_pairs(x, cos, sin):
+    """RoPE over INTERLEAVED pairs (2i, 2i + 1) of the last axis. x:
+    (b, s, d) or (b, s, h, d); cos/sin: (b, s, d // 2)."""
+    if x.ndim == 4:
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    c, s = cos.astype(x.dtype), sin.astype(x.dtype)
+    pairs = x.reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s],
+                     axis=-1).reshape(x.shape)
+
+
 def _attend(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh],
             axes: MeshAxes):
     impl = cfg.attn_impl

@@ -102,8 +102,19 @@ last ``sliding_window`` positions) and "global", ``n_dense_layers``
 leading layers with a dense SwiGLU of ``dense_ffn_dim``, ``post_norm``
 (the sub-layer norms on each sub-layer's OUTPUT: x + Norm(Attn(x))),
 ``rope_layers`` "window" (no RoPE on global layers) and "sigmoid" scoring
-(its selection bias has no training rule here). One expert layer serves
-both: ``serve_block`` and the train forward's ``_experts`` share ``_route``,
+(its selection bias has no training rule here), and ``layer_types`` of
+"latent": multi-head latent attention, whose cache keeps ONE row a token a
+layer, [c | kr] of ``kv_lora_rank + qk_rope_head_dim`` values and no head
+axis: q = (RMSNorm(x Wq_a) Wq_b) by head [nope | rope], [ckv | kr] = x
+Wkv_a, c = RMSNorm(ckv), RoPE over interleaved pairs on the rope parts
+(YaRN's frequencies, ``rope_factor`` over ``rope_original_len``
+positions), a head's key [c Wk_b[h] | kr] and value c Wv_b[h], scores
+scaled by head ** -0.5 * mscale ** 2 (mscale = 0.1 * ``rope_mscale_all_dim``
+* ln(factor) + 1) and the query of position t by 1 + ``query_scale_beta`` *
+ln(1 + floor(t / rope_original_len)). Prefill materialises keys and values
+from the rows it attends; decode absorbs ``wk_b`` into the query and
+``wv_b`` into the output and attends the rows themselves (``llm/model.py``).
+One expert layer serves both: ``serve_block`` and the train forward's ``_experts`` share ``_route``,
 ``_sort_by_expert``, ``_gated_sum`` and ``_shared``.
 """
 
@@ -163,7 +174,8 @@ class MoEConfig:
     # this device's slice of the experts: 0 = all of them
     experts_held: int = 0
     first_expert: int = 0
-    # per layer: "linear" | "full" (training), "window" | "global" (serving)
+    # per layer: "linear" | "full" (training), "window" | "global" or, every
+    # layer, "latent" (serving: llm/kvcache.py has the kinds' names)
     layer_types: tuple = ()
     qk_head_norm: bool = False
     # the full layers of a training configuration (module docstring)
@@ -183,6 +195,23 @@ class MoEConfig:
     dense_ffn_dim: int = 0
     post_norm: bool = False
     rope_layers: str = "all"
+    # "latent" layers (multi-head latent attention; module docstring): the
+    # low-rank widths of q and of the cached row, and a head's parts
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (``rope_parameters`` of type "yarn"): factor 0 = plain RoPE
+    rope_factor: float = 0.0
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # ``llama_4_scaling_beta``: the query of position t is scaled by
+    # 1 + beta * ln(1 + floor(t / rope_original_len))
+    query_scale_beta: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -200,6 +229,12 @@ class MoEConfig:
 
     def _attn_params(self) -> int:
         d, h, kvh, hd = self.dim, self.n_heads, self.n_kv_heads, self.head_dim
+        if "latent" in self.layer_types:
+            ql, kvl = self.q_lora_rank, self.kv_lora_rank
+            nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim)
+            return d * ql + ql + ql * h * (nope + rope) + d * (kvl + rope) \
+                + kvl + kvl * h * (nope + vd) + h * vd * d + 2 * d
         q = 2 if self.attn_output_gate else 1
         attn = q * d * h * hd + 2 * d * kvh * hd + h * hd * d + 2 * d
         if self.qk_norm:
@@ -307,6 +342,26 @@ def qwen3_next_80b_a3b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def mistral_small_4_119b(**kw) -> MoEConfig:
+    """mistralai/Mistral-Small-4-119B-2603 ``config.json`` (``model_type:
+    mistral4``), the language model: 36 latent-attention layers (32 heads
+    of nope 64 + rope 64 = 128 = v; q rank 1024; a cached row of 256 + 64),
+    YaRN factor 128 over 8,192 positions on interleaved pairs, 128
+    softmax-routed experts of width 2048, 4 a token, one shared expert."""
+    defaults = dict(
+        vocab_size=131072, dim=4096, n_layers=36, n_heads=32, n_kv_heads=32,
+        head_size=128, ffn_dim=2048, n_experts=128, experts_per_token=4,
+        norm_topk_prob=True, n_shared_experts=1, q_lora_rank=1024,
+        kv_lora_rank=256, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=128, rope_factor=128.0, rope_original_len=8192,
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, query_scale_beta=0.1,
+        max_seq_len=1048576, rope_theta=10000.0, norm_eps=1e-6)
+    defaults.update(kw)
+    defaults.setdefault("layer_types", ("latent",) * defaults["n_layers"])
+    return MoEConfig(**defaults)
+
+
 def tiny(**kw) -> MoEConfig:
     defaults = dict(vocab_size=512, dim=64, n_layers=2, n_heads=4,
                     n_kv_heads=2, ffn_dim=128, n_experts=4,
@@ -388,11 +443,42 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
     # are live and on the seed). No trained model routes so: the embedding
     # gets unit RMS, the attention's norm a weight of 0.25.
     embed_fan_in, attn_norm = (1, 0.25) if cfg.post_norm else (d, 1.0)
+    latent = "latent" in cfg.layer_types
+    if latent:
+        # A pre-norm stream is the embedding plus every sub-layer's output
+        # (of RMS 0.2-0.5 each at fan-in scaled weights): an embedding of
+        # d ** -0.5 is a thirtieth of the first layer's attention output,
+        # which at random weights is nearly the mean of the context's
+        # values, so again a request's tokens would all choose the same
+        # experts. Unit RMS keeps the token the largest part of what the
+        # router sees; the norms stay 1 (a pre-norm layer norms its INPUT).
+        embed_fan_in = 1
 
     def stack(*shape, fan_in):
         return _normal_stack(next(keys), shape, fan_in, dtype)
 
+    def latent_attn(L):
+        """The latent attention's leaves. ``wk_b`` and ``wv_b`` are the
+        published ``kv_b_proj`` by head, (heads, nope, latent) and
+        (heads, latent, v): the absorbed decode multiplies a head's query
+        and output with them as they lie."""
+        ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        return {"attn_norm": jnp.ones((L, d), dtype),
+                "wq_a": stack(L, d, ql, fan_in=d),
+                "q_a_norm": jnp.ones((L, ql), dtype),
+                "wq_b": stack(L, ql, h * (nope + rope), fan_in=ql),
+                "wkv_a": stack(L, d, kvl + rope, fan_in=d),
+                "kv_norm": jnp.ones((L, kvl), dtype),
+                "wk_b": stack(L, h, nope, kvl, fan_in=kvl),
+                "wv_b": stack(L, h, kvl, vd, fan_in=kvl),
+                "wo": stack(L, h * vd, d, fan_in=h * vd),
+                "mlp_norm": jnp.ones((L, d), dtype)}
+
     def attn(L):
+        if latent:
+            return latent_attn(L)
         out = {"attn_norm": jnp.full((L, d), attn_norm, dtype),
                "wq": stack(L, d, h * hd, fan_in=d),
                "wk": stack(L, d, kvh * hd, fan_in=d),
@@ -1284,9 +1370,11 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
     if _serving_only(cfg):
         raise NotImplementedError(
             "the train forward runs linear and full-attention layers of "
-            "softmax-routed experts; window / global layer_types, "
-            "n_dense_layers, post_norm, rope_layers and sigmoid scoring "
-            "are the serving forwards' (ray_tpu.llm.model)")
+            "softmax-routed experts; window / global / latent layer_types "
+            "(a latent layer has no training rule here: its absorbed and "
+            "materialised forms are the cache's), n_dense_layers, "
+            "post_norm, rope_layers and sigmoid scoring are the serving "
+            "forwards' (ray_tpu.llm.model)")
     b, s = tokens.shape
 
     def act_constraint(x, spec):

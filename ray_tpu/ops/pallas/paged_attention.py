@@ -42,9 +42,18 @@ cache manager frees them (llm/kvcache.py). The window is a static
 parameter; the kernel's operands are the same five, and the variant is
 named ``paged_decode_window``.
 
-``chunk_blocks`` is the one place that chooses C, from what the pool
-shows: about 128 positions a chunk (one lane-width of scores), capped
-so that the four buffers stay within 4 MB of VMEM.
+``chunk_blocks`` is the one place that chooses C, from what a position
+costs in the pool (a kind's row: llm/kvcache.py row_shapes): about 128
+positions a chunk (one lane-width of scores; 512 for a latent row, a
+sixth of the bytes), capped so that the buffers stay within 4 MB of VMEM.
+
+A LATENT layer (multi-head latent attention in its absorbed form) keeps
+one row a position, [c | kr], and no head axis: every head attends the
+same rows, the key being the whole row and the value its c part.
+``latent_decode`` is the same walk over such rows: one DMA a block for c
+and one for kr, the row fetched ONCE and used for both products;
+``latent_write`` is their ``kv_write``; ``latent_attention_reference``
+the plain twin (and the verify round's attention).
 
 (Until PR 29 the grid was ``(slots, kv_heads, table_width)`` with one
 ``(block_size, head_dim)`` tile a step, blocks past the last live one
@@ -94,15 +103,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 CHUNK_POSITIONS = 128           # one lane-width of scores a chunk
-BUFFER_BYTES = 4 * 1024 * 1024  # K and V, two buffers each
+# a latent row is 768 bytes in the pool (c 256 values, kr 64 in an array
+# 128 wide) where 8 KV heads' K and V are 4,096: four lane-widths a chunk,
+# so that a fetch is 384 KB (the K/V walk's is 512 KB). Reasoned, not
+# swept: no run has varied it (PERF.md section 7)
+LATENT_CHUNK_POSITIONS = 512
+BUFFER_BYTES = 4 * 1024 * 1024  # a kind's two arrays, two buffers each
 
 
-def chunk_blocks(kv_heads, block_size, head_dim, itemsize):
-    """C: the pool blocks one fetch of the walk brings in, from the
-    shape and dtype of the pool alone."""
-    block_bytes = kv_heads * block_size * head_dim * itemsize
-    return max(1, min(CHUNK_POSITIONS // block_size,
-                      BUFFER_BYTES // (4 * block_bytes)))
+def chunk_blocks(row_bytes, block_size, positions=CHUNK_POSITIONS):
+    """C: the pool blocks one fetch of the walk brings in, from what a
+    position costs in the walked pool alone (``row_bytes``: both of a
+    kind's arrays, llm/kvcache.py row_bytes) and the ``positions`` a
+    chunk aims at."""
+    return max(1, min(positions // block_size,
+                      BUFFER_BYTES // (2 * block_size * row_bytes)))
 
 
 def live_blocks(length, block_size):
@@ -264,7 +279,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         raise ValueError(
             f"pool heads/dim {(kvh_p, hd_p)} != query {(kvh, hd)}")
     w = tables.shape[1]
-    cb = min(chunk_blocks(kvh, bs, hd, k_pool.dtype.itemsize), w)
+    cb = min(chunk_blocks(2 * kvh * hd * k_pool.dtype.itemsize, bs), w)
 
     def _qmap(b_, t, ln):
         return (b_, 0, 0, 0)
@@ -462,3 +477,242 @@ def paged_attention_verify(q, k_pool, v_pool, tables, lengths):
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bwkgl,blkd->bwkgd", probs,
                       vv.astype(jnp.float32))
+
+
+# --- latent rows -----------------------------------------------------------
+
+
+def _latent_walk_kernel(tables_ref, lengths_ref, q_ref, c_hbm, r_hbm, o_ref,
+                        cbuf, rbuf, sems, *, bs, cb, width, sm_scale):
+    b_ = pl.program_id(0)
+    h = q_ref.shape[2]
+    lat = cbuf.shape[-1]
+    t = cb * bs                                 # positions a chunk
+    length = lengths_ref[b_]
+    live = jnp.minimum(live_blocks(length, bs), width)
+    n_chunks = (live + cb - 1) // cb
+
+    def fetch(i, buf, wait):
+        """Start (or wait for) the DMAs of chunk ``i`` into buffer
+        ``buf``: one a live pool block for c, one for kr."""
+        def entry(c, carry):
+            blk = 0 if wait else tables_ref[b_, i * cb + c]
+            rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
+            for s, (pool, dst) in enumerate(((c_hbm, cbuf), (r_hbm, rbuf))):
+                cp = pltpu.make_async_copy(
+                    pool.at[blk, 0], dst.at[buf, rows, :], sems.at[s, buf])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(live - i * cb, cb), entry, 0)
+
+    q = q_ref[0, 0]                             # (h, lat + rope)
+    if not (q.dtype == cbuf.dtype == jnp.bfloat16):
+        q = q.astype(jnp.float32)
+    qc, qr = q[:, :lat], q[:, lat:]
+    fetch(0, 0, wait=False)
+
+    def chunk(i, carry):
+        m_prev, l_prev, acc = carry
+        buf = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_chunks)
+        def _():
+            fetch(i + 1, 1 - buf, wait=False)
+
+        fetch(i, buf, wait=True)
+        c = cbuf[buf]                           # (t, lat): key AND value
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qc, c.astype(q.dtype), nt,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr, rbuf[buf].astype(q.dtype), nt,
+                                   preferred_element_type=jnp.float32)
+             ) * sm_scale                       # (h, t)
+        keep = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+            < length
+        s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        # rows past ``length`` hold whatever the pool or an earlier
+        # chunk left there: p is 0 for them, and 0 x NaN is not
+        rows = i * t + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0) \
+            < length
+        v = jnp.where(rows, c, jnp.zeros_like(c))
+        if v.dtype == jnp.bfloat16:
+            p = p.astype(jnp.bfloat16)          # one MXU pass, f32 sums
+        else:
+            v = v.astype(jnp.float32)
+        acc = acc * alpha + jnp.dot(p, v,
+                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, lat), jnp.float32)))
+    o_ref[0, 0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def latent_decode(q, c_pool, kr_pool, tables, lengths, *, sm_scale,
+                  interpret=False):
+    """Single-token decode attention over LATENT rows straight through
+    block tables: every head of a slot attends the same rows.
+
+    q: (slots, heads, kv_lora_rank + qk_rope_head_dim), the absorbed
+    queries [q_nope W_UK | q_rope]; c_pool: (num_blocks, block_size,
+    kv_lora_rank) and kr_pool: (num_blocks, block_size,
+    qk_rope_head_dim), ONE layer of the engine pool (no head axis);
+    tables, lengths as ``paged_attention``. Returns (slots, heads,
+    kv_lora_rank) float32: softmax_j(sm_scale * q . [c_j | kr_j]) c_j,
+    each head's weighted sum of the c rows, which the caller takes
+    through W_UV. The walk is ``paged_attention``'s (a run-time count of
+    live chunks, double-buffered DMAs, the running-max softmax); a row is
+    fetched once and is key and value both. The kernel sees the rows as
+    one shared KV head, (slots, 1, heads, width) against (num_blocks, 1,
+    block_size, width): multi-query attention, the same five operands as
+    the K/V walk. With bf16 rows the probabilities enter the second
+    product in bf16 (float32 sums), as in the flash kernels; float32
+    rows keep them float32."""
+    b, h, width = q.shape
+    nb, bs, lat = c_pool.shape
+    rope = kr_pool.shape[-1]
+    if kr_pool.shape[:2] != (nb, bs) or lat + rope != width:
+        raise ValueError(
+            f"rows {c_pool.shape} | {kr_pool.shape} do not take queries "
+            f"{q.shape}")
+    w = tables.shape[1]
+    cb = min(chunk_blocks((lat + rope) * c_pool.dtype.itemsize, bs,
+                          LATENT_CHUNK_POSITIONS), w)
+
+    def _qmap(b_, t, ln):
+        return (b_, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, 1, h, width), _qmap),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, h, lat), _qmap),
+        scratch_shapes=[
+            pltpu.VMEM((2, cb * bs, lat), c_pool.dtype),
+            pltpu.VMEM((2, cb * bs, rope), kr_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    kernel = functools.partial(_latent_walk_kernel, bs=bs, cb=cb, width=w,
+                               sm_scale=float(sm_scale))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, h, lat), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="latent_decode",
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q[:, None],
+      c_pool[:, None], kr_pool[:, None])[:, 0]
+
+
+def _latent_write_kernel(blocks_ref, rows_ref, c_new, r_new, c_in, r_in,
+                         c_out, r_out, cbuf, rbuf, sems):
+    del c_in, r_in                  # the same buffers as c_out, r_out
+    i = pl.program_id(0)
+    blk, row = blocks_ref[i], rows_ref[i]
+    pairs = ((c_out, cbuf, c_new), (r_out, rbuf, r_new))
+    reads = [pltpu.make_async_copy(pool.at[blk], buf, sems.at[s])
+             for s, (pool, buf, _) in enumerate(pairs)]
+    writes = [pltpu.make_async_copy(buf, pool.at[blk], sems.at[s])
+              for s, (pool, buf, _) in enumerate(pairs)]
+    for cp in reads:
+        cp.start()
+    for s, (_, buf, new) in enumerate(pairs):
+        reads[s].wait()
+        block = buf[...]                        # (bs, width)
+        here = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0) == row
+        buf[...] = jnp.where(here, new[0], block)
+        writes[s].start()
+    for cp in writes:
+        cp.wait()
+
+
+def latent_write(c_pool, kr_pool, blocks, rows, c_new, kr_new, *,
+                 interpret=False):
+    """``kv_write`` for latent rows: ``c_pool[blocks[i], rows[i]] =
+    c_new[i]`` and the same for kr, in order, IN PLACE (both pools
+    aliased in to out; each entry a read-modify-write of its block).
+    c_pool: (num_blocks, block_size, kv_lora_rank), kr_pool:
+    (num_blocks, block_size, qk_rope_head_dim), every layer of the
+    engine pool flattened along its first two axes; c_new: (n,
+    kv_lora_rank), kr_new: (n, qk_rope_head_dim)."""
+    n, lat = c_new.shape
+    nb, bs, lat_p = c_pool.shape
+    rope = kr_pool.shape[-1]
+    if lat_p != lat or kr_pool.shape[:2] != (nb, bs) \
+            or kr_new.shape != (n, rope):
+        raise ValueError(
+            f"pools {c_pool.shape}, {kr_pool.shape} do not take rows "
+            f"{c_new.shape}, {kr_new.shape}")
+    if {c_new.dtype, kr_new.dtype, kr_pool.dtype} != {c_pool.dtype}:
+        raise ValueError("pools and new rows must share one dtype")
+
+    def _row(i, blocks, rows):
+        return (i, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[
+            pl.BlockSpec((1, 1, lat), _row),
+            pl.BlockSpec((1, 1, rope), _row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            pltpu.VMEM((bs, lat), c_pool.dtype),
+            pltpu.VMEM((bs, rope), kr_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        _latent_write_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(c_pool.shape, c_pool.dtype),
+                   jax.ShapeDtypeStruct(kr_pool.shape, kr_pool.dtype)],
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_write",
+    )(blocks.astype(jnp.int32), rows.astype(jnp.int32), c_new[:, None],
+      kr_new[:, None], c_pool, kr_pool)
+
+
+def latent_attention_reference(q, c_pool, kr_pool, tables, lengths, *,
+                               sm_scale):
+    """Gather-then-softmax twin of ``latent_decode``, and the verify
+    round's attention: q (slots, heads, width) with lengths (slots,), or
+    w queries a slot, (slots, w, heads, width) with lengths (slots, w)
+    (query j attends positions < lengths[:, j]). float32 throughout;
+    returns q's shape with kv_lora_rank in place of width."""
+    single = q.ndim == 3
+    if single:
+        q, lengths = q[:, None], lengths[:, None]
+    b, w = tables.shape
+    rows = jnp.concatenate([c_pool[tables], kr_pool[tables]], axis=-1)
+    rows = rows.reshape(b, w * c_pool.shape[1], -1).astype(jnp.float32)
+    scores = jnp.einsum("bwhd,bld->bwhl", q.astype(jnp.float32),
+                        rows) * sm_scale
+    mask = jnp.arange(rows.shape[1])[None, None] < lengths[:, :, None]
+    scores = jnp.where(mask[:, :, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bwhl,bld->bwhd", probs,
+                     rows[..., :c_pool.shape[-1]])
+    return out[:, 0] if single else out

@@ -176,7 +176,7 @@ def main():
         print(json.dumps(row), flush=True)
         writes.append(row)
     doc = {"device": device, "shape": shp, "reps": a.reps,
-           "chunk_blocks": new.chunk_blocks(kvh, bs, hd, 2), "rows": rows,
+           "chunk_blocks": new.chunk_blocks(2 * kvh * hd * 2, bs), "rows": rows,
            "kv_write": writes}
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
     with open(a.out, "w") as f:

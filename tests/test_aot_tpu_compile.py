@@ -626,23 +626,27 @@ def test_the_hybrid_cells_decode_program_compiles_for_v5e(hybrid_decode):
 # a segment (13% of the hybrid cell's device time), a transposing copy a
 # layer in prefill. Nothing on a CPU shows it.
 
-def _assert_projections_in_place(compiled, params, tp=1):
+def _assert_projections_in_place(compiled, params, tp=1,
+                                 leaves=("wq", "wk", "wv"),
+                                 prefetch=("copy-start", "copy-done")):
     """No instruction of the compiled program has a result of the shape
-    of wq, wk or wv (under ``tp`` a shard's columns) - one layer or the
-    stack, as held or transposed - but what passes a buffer on, and the
-    compiler's prefetch: an asynchronous copy that keeps the held layout
-    and lands in the fast memory space S(1), the weight's ONE read."""
+    of wq, wk or wv (``leaves``; under ``tp`` a shard's columns) - one
+    layer or the stack, as held or with its last two axes turned - but
+    what passes a buffer on, and the compiler's prefetch: a copy
+    (``prefetch``: asynchronous) that keeps the held layout and lands in
+    the fast memory space S(1), the weight's ONE read."""
     shapes = set()
     for stack in ("layers", "dense_layers"):
-        for w in ("wq", "wk", "wv"):
+        for w in leaves:
             if w in params.get(stack, {}):
-                n, rows, cols = params[stack][w].shape
-                for dims in ((rows, cols // tp), (cols // tp, rows)):
+                n, *lead, rows, cols = params[stack][w].shape
+                for dims in ((*lead, rows, cols // tp),
+                             (*lead, cols // tp, rows)):
                     shapes |= {dims, (1, *dims), (n, *dims)}
     moved = []
     for op, ln in _with_result_of(compiled, shapes):
         code = op["opcode"]
-        if code in ("copy-start", "copy-done"):
+        if code in prefetch:
             result = ln.split(" " + code + "(")[0]
             orders = set(re.findall(r"\]\{([\d,]*)", result)) - {""}
             if "S(1)" in result and len(orders) == 1:
@@ -817,3 +821,103 @@ def test_the_linear_mixer_moves_no_float32_activation(chip):
     assert not moved, moved
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.cost_analysis()["bytes accessed"] < 1.15 * 34.9e9
+
+
+# --- latent attention: the pool's third kind (PR 51) --------------------------
+# the cell serve-mistral4-longdoc-open: 32 heads over ONE row a position,
+# c 256 | kr 64 in 128 lanes, blocks of 128 rows, 32 slots over tables of
+# 208 blocks, 9 layers of 2,373 blocks
+
+def _latent_walk(q, c, r, tables, lengths):
+    return pa.latent_decode(q, c, r, tables, lengths, sm_scale=0.195)
+
+
+LATENT_CASES = {
+    # name: (function, shapes, the kernel's name, the benchmark's class)
+    "latent_decode": (
+        _latent_walk,
+        (((32, 32, 384), _BF), ((9 * 2373, 128, 256), _BF),
+         ((9 * 2373, 128, 128), _BF), ((32, 208), _I32), ((32,), _I32)),
+        "latent_decode", "paged_decode"),
+    "latent_write": (
+        pa.latent_write,
+        (((9 * 2373, 128, 256), _BF), ((9 * 2373, 128, 128), _BF),
+         ((32,), _I32), ((32,), _I32), ((32, 256), _BF), ((32, 128), _BF)),
+        "latent_write", "unknown_kernel"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_CASES))
+def test_latent_kernels_compile_for_v5e(chip, name):
+    """The latent walk has the paged-decode kernel's operand signature
+    (tables, lengths, the queries, two pools seen as one shared KV head:
+    a program that runs it counts as decode in the benchmark's
+    reduction), the row writer falls into no flash class, and each
+    carries its own name."""
+    fn, shapes, kernel, cls = LATENT_CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    bench = _bench_kernels()
+    ops = [bench.parse_op(ln) for ln in _custom_calls(compiled)]
+    assert len(ops) == 1
+    assert bench.classify(ops[0]) == cls, ops[0]
+    assert re.search(kernel + r"(?=_|\.|$)", ops[0]["name"]), ops[0]["name"]
+
+
+def _latent_config(**kw):
+    return _cell_config("mistral-small-4-119b-serve-ep8.json", "mistral4",
+                        gmm_impl="pallas", **kw)
+
+
+LATENT_LEAVES = ("wq_a", "wq_b", "wkv_a", "wk_b", "wv_b", "wo")
+
+
+def test_the_latent_cells_decode_program_reads_rows_and_weights_in_place(
+        chip):
+    """paged_decode_steps (n = 8) at the cell's published widths and pool
+    geometry, on a described v5e: every layer calls the latent walk and
+    the row writer once a step and three decode-shape grouped matmuls;
+    the pool (c and kr) is the program's ONE pool-sized buffer, updated
+    in place; nothing but the products reads the latent projections (no
+    copy of a stack, no slice of one written out, no transpose: wk_b and
+    wv_b lie by head as the absorbed products take them); weights and
+    pool fit one chip beside the temporaries."""
+    import numpy as np
+    from ray_tpu.llm import kvcache
+    cfg = _latent_config()
+    params = _hybrid_params(chip, cfg)
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    pool = {"c": shape((9, 2373, 128, 256), _BF),
+            "kr": shape((9, 2373, 128, 128), _BF)}
+    ids = shape((32,), _I32)
+    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
+        params, pool, {"latent": shape((32, 208), _I32)}, ids, ids,
+        shape((32,), jnp.float32), shape((2,), jnp.uint32), cfg, 8, None,
+        None).compile()
+    ops = _kernel_ops(compiled)
+    names = {}
+    for op in ops:
+        name = re.sub(r"[._]*\d*$", "", op["name"])
+        names[name] = names.get(name, 0) + 1
+    assert names == {"latent_decode": 1, "latent_write": 1,
+                     "moe_gmm_decode": 3}, names     # one scanned layer
+    assert {op["class"] for op in ops} == {"paged_decode", "unknown_kernel"}
+    # (a) nothing but the kernels makes a pool-sized array
+    pool_shapes = {tuple(a.shape[i:]) for a in pool.values() for i in (0, 1)} \
+        | {(a.shape[0] * a.shape[1], *a.shape[2:]) for a in pool.values()}
+    held = [(op["opcode"], op["name"])
+            for op, _ in _with_result_of(compiled, pool_shapes)]
+    assert held and {code for code, _ in held} <= _PASS_ON, held
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(2 * int(np.prod(a.shape)) for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    # (b) the latent projections are read where they lie (the narrow
+    # wkv_a stack, 24 MB, is brought to fast memory once a program, as
+    # it lies: its one read in eight steps)
+    _assert_projections_in_place(
+        compiled, params, leaves=LATENT_LEAVES,
+        prefetch=("copy", "copy-start", "copy-done"))

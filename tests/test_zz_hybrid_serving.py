@@ -148,11 +148,39 @@ def test_the_expert_layer_computes_its_share(fam, first):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_the_shares_add_up_to_the_uncut_layer(fam):
+def _latent_cfg(**kw):
+    """The latent-attention family's tiny model
+    (tests/test_zz_latent_serving.py): softmax scores, 4 of 16 held."""
+    base = dict(vocab_size=256, dim=64, n_layers=3, n_heads=4, n_kv_heads=4,
+                head_size=32, ffn_dim=32, n_experts=16, experts_per_token=4,
+                experts_held=4, first_expert=4, q_lora_rank=24,
+                kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=16,
+                v_head_dim=32, rope_factor=4.0, rope_original_len=16,
+                dtype="float32", attn_impl="reference",
+                gmm_impl="ragged_dot")
+    base.update(kw)
+    return moe.mistral_small_4_119b(**base)
+
+
+# family of the reference -> the program's tiny config of it
+SHARED = {"exaone_moe": _cfg, "mistral4": _latent_cfg}
+
+
+@pytest.mark.parametrize("family", sorted(SHARED))
+def test_the_shares_add_up_to_the_uncut_layer(family):
     """The guide's share test: the four shares' routed parts (experts
     0-4, 4-8, 8-12, 12-16) plus the shared expert counted once are the
-    uncut reference's layer, in the program and in the reference."""
-    whole = _cfg(experts_held=0, first_expert=0)
+    uncut reference's layer, in the program and in the reference; for
+    the sigmoid-scored family with its selection bias and for the
+    softmax-scored one."""
+    sys.path.insert(0, BENCH)
+    try:
+        from harness import spec
+        fam = spec.family(family)
+    finally:
+        sys.path.remove(BENCH)
+    make = SHARED[family]
+    whole = make(experts_held=0, first_expert=0)
     lp = _layer(moe.init_params(jax.random.PRNGKey(2), whole))
     x = _rows()
     with jax.default_matmul_precision("highest"):
@@ -161,7 +189,7 @@ def test_the_shares_add_up_to_the_uncut_layer(fam):
             x, lp, whole)
         program, reference = shared, shared
         for first in range(0, 16, 4):
-            cfg = _cfg(first_expert=first)
+            cfg = make(first_expert=first)
             mine = {**lp, **{k: lp[k][first:first + 4]
                              for k in ("w_gate", "w_up", "w_down")}}
             program = program + moe.serve_block(x, mine, cfg)[0] - shared

@@ -178,7 +178,15 @@ def test_kernel_cow_forked_tables_diverge_mid_decode():
 # block 16 x 64-wide heads: chunk_blocks gives C = 8, 128 positions a
 # chunk; a table of 20 blocks is two whole chunks and a half one
 _BS, _W, _HD = 16, 20, 64
-_C = pa.chunk_blocks(2, _BS, _HD, 4)
+
+
+def _chunk_blocks(kv_heads, block_size, head_dim, itemsize):
+    """C of the K/V walk: ``pa.chunk_blocks`` takes what a position costs
+    in the walked pool (K and V of every KV head)."""
+    return pa.chunk_blocks(2 * kv_heads * head_dim * itemsize, block_size)
+
+
+_C = _chunk_blocks(2, _BS, _HD, 4)
 _EDGES = {"one": 1, "block": _BS, "block_plus_1": _BS + 1,
           "chunk": _C * _BS, "chunk_plus_1": _C * _BS + 1,
           "two_chunks": 2 * _C * _BS, "full_table": _W * _BS}
@@ -189,16 +197,16 @@ def test_chunk_rule_reads_the_pool_shape_only():
     the four buffers (K and V, two each) within 4 MB, never under one
     block."""
     assert _C == 8
-    assert pa.chunk_blocks(8, 16, 128, 2) == 8       # the chat cell: 1 MB
-    assert pa.chunk_blocks(32, 16, 128, 2) == 8      # MHA 7B: 4 MB
-    assert pa.chunk_blocks(32, 16, 128, 4) == 4      # f32 cache: capped
-    assert pa.chunk_blocks(8, 8, 128, 2) == 16
-    assert pa.chunk_blocks(8, 32, 128, 2) == 4
-    assert pa.chunk_blocks(8, 256, 128, 2) == 1
-    assert pa.chunk_blocks(64, 128, 256, 4) == 1     # never zero
+    assert _chunk_blocks(8, 16, 128, 2) == 8       # the chat cell: 1 MB
+    assert _chunk_blocks(32, 16, 128, 2) == 8      # MHA 7B: 4 MB
+    assert _chunk_blocks(32, 16, 128, 4) == 4      # f32 cache: capped
+    assert _chunk_blocks(8, 8, 128, 2) == 16
+    assert _chunk_blocks(8, 32, 128, 2) == 4
+    assert _chunk_blocks(8, 256, 128, 2) == 1
+    assert _chunk_blocks(64, 128, 256, 4) == 1     # never zero
     for kvh, bs, hd, size in ((8, 16, 128, 2), (32, 16, 128, 4),
                               (8, 8, 64, 4), (32, 32, 128, 2)):
-        c = pa.chunk_blocks(kvh, bs, hd, size)
+        c = _chunk_blocks(kvh, bs, hd, size)
         assert 4 * c * kvh * bs * hd * size <= pa.BUFFER_BYTES
 
 
@@ -247,7 +255,7 @@ def test_walk_head_layouts(kvh, g):
     enter the score product as stored, and the result is the f32
     reference's on the same bf16 values."""
     bs, w, hd = 16, 10, 128
-    assert pa.chunk_blocks(kvh, bs, hd, 2) == 8
+    assert _chunk_blocks(kvh, bs, hd, 2) == 8
     q, k, v, tables = _rand_case(12, b=3, w=w, bs=bs, kvh=kvh, g=g,
                                  hd=hd, nb=1 + 3 * w, dtype=jnp.bfloat16)
     lengths = jnp.asarray([1, 8 * bs + 1, w * bs], jnp.int32)
@@ -265,7 +273,7 @@ def test_walk_block_sizes_and_pool_dtypes(bs, dtype):
     and an f32 pool changes the score product's dtype; the walk is the
     same."""
     hd, w = 64, 160 // bs * 2 + 1               # two chunks and a block
-    c = pa.chunk_blocks(2, bs, hd, jnp.dtype(dtype).itemsize)
+    c = _chunk_blocks(2, bs, hd, jnp.dtype(dtype).itemsize)
     assert c == max(1, 128 // bs)
     q, k, v, tables = _rand_case(13, b=3, w=w, bs=bs, kvh=2, g=2,
                                  hd=hd, nb=1 + 3 * w,
