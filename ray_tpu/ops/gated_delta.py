@@ -16,9 +16,11 @@ computes the same outputs a chunk of ``c`` tokens at a time. Within a chunk
 and ``D_ij = exp(G_i - G_j)`` for ``i >= j``: the corrected values
 ``U = T (beta V)`` and ``W = T (beta exp(G) K)`` with ``T = (I + A)^-1``,
 ``A = strict_lower(beta_i k_i.k_j D_ij)``: one unit-lower-triangular system
-a chunk, inverted once by block recursion (twelve products of c x c matrices at
-c = 64) and applied by matmuls. Between chunks (``gdn.scan``) a ``lax.scan``
-carries the state: ``V' = U - W S``, ``o = (exp(G) Q) S + lower(Q K^T D) V'``,
+a chunk, inverted once (``gdn.solve``: its diagonal blocks of ``SOLVE_BLOCK``
+rows by forward substitution, the levels above them by block recursion, four
+products of c x c matrices at c = 64) and applied by matmuls. Between chunks
+(``gdn.scan``) a ``lax.scan`` carries the state: ``V' = U - W S``,
+``o = (exp(G) Q) S + lower(Q K^T D) V'``,
 ``S <- exp(G_c) S + (exp(G_c - G) K)^T V'``. Every exponent is <= 0.
 
 Layouts. ``chunk_gated_delta_rule`` takes and returns HEAD-MAJOR arrays,
@@ -33,12 +35,19 @@ memory in the order of its dimensions, on either side of both moves.
 Gates, decays and the carried state are float32; the matmuls take their
 operands in the dtype of ``q`` (bf16 in a bf16 model: the state is rounded
 for a product, never where it is carried) and accumulate in float32. The
-inverse is made in float32 at ``SOLVE_PRECISION`` (three bf16 passes a
-product on a TPU, about 2^-16: it is rounded to the operands' dtype next;
-at six passes the twelve products were a sixth of the linear layers' time).
+inverse is made in float32: the diagonal blocks elementwise (exact float32
+products), the recursion's products at ``SOLVE_PRECISION`` (three bf16
+passes a product on a TPU, about 2^-16: it is rounded to the operands' dtype
+next). A product over (64, 4, 32, 64, 64) moves 0.8 GB (the 64-wide minor
+dimension lies in 128-lane tiles: 268 MB an array) and takes 1.2-1.8 ms on a
+v5e at the rate of its HBM, so the levels are counted, not their FLOPs: at
+twelve products a pass (every level from single rows up) the inverse was
+97 ms of a 543-ms step.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +58,9 @@ from jax.experimental.layout import Layout, with_layout_constraint
 CHUNK = 64
 # of the products that build a chunk's inverse (float32 operands)
 SOLVE_PRECISION = lax.Precision.HIGH
+# rows of the diagonal blocks of a chunk's system that are solved directly;
+# the block recursion joins them from there
+SOLVE_BLOCK = 16
 
 
 def row_major(x):
@@ -70,11 +82,55 @@ def _solve(a, b):
     return jnp.matmul(a, b, precision=SOLVE_PRECISION)
 
 
+def _substituted(e):
+    """Strictly lower ``r x r`` blocks as (r rows, r columns, batch) ->
+    the blocks of ``(I + e)^-1`` in the same form, by forward substitution
+    a row a turn: ``T[i] = I[i] - sum_k e[i, k] T[k]`` over whole rows
+    (``e[i, k]`` is zero from ``k = i`` on, where T's rows are still the
+    identity's). Elementwise float32 on vectors of the batch: exact
+    products, none on the MXU; a loop of ``r - 1`` turns, so the traced
+    program does not grow with ``r``."""
+    def row(i, t):
+        e_i, t_i = (lax.dynamic_index_in_dim(x, i, 0, False) for x in (e, t))
+        return lax.dynamic_update_index_in_dim(
+            t, t_i - jnp.sum(e_i[:, None] * t, axis=0), i, 0)
+    r = e.shape[0]
+    eye = jnp.broadcast_to(jnp.eye(r, dtype=e.dtype)[:, :, None], e.shape)
+    return lax.fori_loop(1, r, row, eye)
+
+
+def _solved_blocks(a, r):
+    """The block diagonal of ``(I + a)^-1`` in blocks of ``r`` rows, (...,
+    c, c) like ``a``: the inverse of a diagonal block is the diagonal block
+    of the inverse. The blocks leave ``a`` as one (r, c) slab a system (a
+    masked sum over its row blocks: the blocks side by side), move to a
+    layout whose minor dimension is the batch (there an entry of all the
+    blocks is a dense vector, and the arrays are an ``r / c``-th of
+    ``a``), are solved by substitution and come back the same way. The
+    result is the slab under itself ``c / r`` times, masked: written so,
+    the compiler reads the slab inside the first product that takes the
+    result and stores no array of ``a``'s size (as a broadcast of the slab
+    it stores one, 0.41 ms a call on a v5e at (64, 4, 32, 64, 64))."""
+    c = a.shape[-1]
+    nb, n = c // r, math.prod(a.shape[:-2])
+    cols = jnp.arange(c)
+    diagonal = (cols[:, None] // r) == (cols[None, :] // r)
+    # masked after the split of the rows: masked before it, the compiler
+    # stores the masked copy of ``a``
+    slab = jnp.sum(jnp.where(diagonal.reshape(nb, r, c),
+                             a.reshape(n, nb, r, c), 0.0), axis=1)
+    e = slab.reshape(n, r, nb, r).transpose(1, 3, 2, 0)    # p, q, blk, n
+    t = _substituted(e.reshape(r, r, nb * n)).reshape(r, r, nb, n)
+    slab = row_major(t.transpose(3, 0, 2, 1).reshape(n, r, c))
+    return jnp.where(diagonal, jnp.concatenate([slab] * nb, axis=1),
+                     0.0).reshape(a.shape)
+
+
 def _inverse_recursion(a):
     c = a.shape[-1]
+    m = min(SOLVE_BLOCK, c)
     rows = jnp.arange(c)
-    inv = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
-    m = 1
+    inv = _solved_blocks(a, m)
     while m < c:
         # the blocks under the diagonal that join two m-blocks into one of
         # 2m: [[P, 0], [L, Q]]^-1 = [[P^-1, 0], [-Q^-1 L P^-1, Q^-1]]
@@ -88,10 +144,11 @@ def _inverse_recursion(a):
 @jax.custom_vjp
 def unit_lower_inverse(a):
     """``(I + a)^-1`` for ``a`` (..., c, c) strictly lower triangular, c a
-    power of two: the block recursion of forward substitution (every
-    intermediate is the inverse of a diagonal block, so it is as stable
-    as substitution; a Neumann product's powers are not), as 2 log2(c)
-    batched products. Its backward needs the inverse alone."""
+    power of two: forward substitution within diagonal blocks of
+    ``SOLVE_BLOCK`` rows, then its block recursion (every intermediate is
+    the inverse of a diagonal block, so it is as stable as substitution; a
+    Neumann product's powers are not), as 2 log2(c / SOLVE_BLOCK) batched
+    products. Its backward needs the inverse alone."""
     return _inverse_recursion(a)
 
 
@@ -138,7 +195,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
         kb = (k.astype(f32) * beta[..., None]).astype(cd)
         strict = jnp.tril(jnp.ones((c, c), bool), -1)
         a = jnp.where(strict, _mm(kb, k, "...id,...jd->...ij") * decay, 0.0)
-        t = unit_lower_inverse(a).astype(cd)
+        with jax.named_scope("gdn.solve"):
+            t = unit_lower_inverse(a)
+        t = t.astype(cd)
         u = _mm(t, (v.astype(f32) * beta[..., None]).astype(cd),
                 "...ij,...jd->...id")
         w = _mm(t, (kb.astype(f32) * jnp.exp(gc)[..., None]).astype(cd),

@@ -1,8 +1,9 @@
 """A train cell's step on the chip, outside the harness, by NAMED SCOPE:
 milliseconds a step of every ``jax.named_scope`` the models set
-(``gdn.chunk``, ``gdn.scan``, ``gdn.conv``, ``gdn.proj``, ``gdn.gate_norm``,
-``gdn.out``, ``attention``, ``attention.gate``, ``moe.route``,
-``moe.experts``, ``moe.combine``, ``moe.shared``, ``loss``, ``optimizer``)
+(``gdn.solve``, ``gdn.chunk``, ``gdn.scan``, ``gdn.conv``, ``gdn.proj``,
+``gdn.gate_norm``, ``gdn.out``, ``attention``, ``attention.gate``,
+``moe.route``, ``moe.experts``, ``moe.combine``, ``moe.shared``, ``loss``,
+``optimizer``)
 and of every Pallas kernel by its name, with the step's own metrics beside
 them (``moe_local_share``, ``moe_compact_share``: the expert layers that
 worked on their own rows only). The benchmark's own reduction
@@ -33,10 +34,10 @@ for p in (os.path.join(ROOT, "benchmarks"), ROOT):
         sys.path.insert(0, p)
 
 # innermost first: an instruction goes to the first scope its op_name holds
-SCOPES = ("gdn.chunk", "gdn.scan", "gdn.conv", "gdn.proj", "gdn.gate_norm",
-          "gdn.out", "attention.gate", "moe.route", "moe.experts",
-          "moe.combine", "moe.shared", "mlp", "loss", "optimizer",
-          "attention")
+SCOPES = ("gdn.solve", "gdn.chunk", "gdn.scan", "gdn.conv", "gdn.proj",
+          "gdn.gate_norm", "gdn.out", "attention.gate", "moe.route",
+          "moe.experts", "moe.combine", "moe.shared", "mlp", "loss",
+          "optimizer", "attention")
 _EVENT = re.compile(r"%?([\w.\-]+) = (.*?)\s([a-z][a-z\-]*)\(")
 _INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ")
 

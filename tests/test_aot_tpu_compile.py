@@ -785,6 +785,14 @@ def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
     assert {op["operands"][5] for op in ops
             if "moe_gmm_rows" in op["name"]} \
         == {("s32", (20480,)), ("s32", (163840,))}
+    # the chunk inverse starts from directly solved diagonal blocks: of its
+    # twelve float32 products a pass (78 a step: a layer's forward, the
+    # remat's forward and two in its backward, three layers) the levels
+    # under ``gated_delta.SOLVE_BLOCK`` are gone
+    solves = [ln for ln in compiled.as_text().splitlines()
+              if "operand_precision={high,high}" in ln
+              and re.search(r" = f32\[64,4,32,64,64\]", ln)]
+    assert 0 < len(solves) <= 42, len(solves)
 
 
 def test_the_linear_mixer_moves_no_float32_activation(chip):

@@ -128,19 +128,41 @@ def test_value_heads_that_share_a_key_head(inputs, which):
         jnp.abs(want).max()))
 
 
-def test_the_chunk_inverse_and_its_backward():
-    """(I + a)^-1 by block recursion, also where keys repeat (a of ones:
-    the case a Neumann product's powers lose), and the gradient its
-    custom rule gives against autodiff through a dense inverse."""
-    c = 64
+def _corner(c):
+    """Entries just inside and just outside the corners of the directly
+    solved diagonal blocks (``gd.SOLVE_BLOCK`` rows, or the whole of a
+    smaller system), and nothing else."""
+    r = min(gd.SOLVE_BLOCK, c)
+    a = jnp.zeros((c, c))
+    for blk in range(0, c, r):
+        a = a.at[blk + r - 1, blk].set(0.5)           # a block's own corner
+        if blk:
+            a = a.at[blk, blk - 1].set(-0.75)         # across the diagonal
+            a = a.at[blk, blk - r].set(1.25)          # under the corner
+            a = a.at[blk + r - 1, blk - 1].set(2.0)   # beside the corner
+    return a
+
+
+@pytest.mark.parametrize("c", [8, 16, 64])
+def test_the_chunk_inverse_and_its_backward(c):
+    """(I + a)^-1 from directly solved diagonal blocks and the block
+    recursion above them, for systems smaller than a block, of one block
+    and of several: also where keys repeat (a of ones: the case a Neumann
+    product's powers lose) and where the only entries sit at the blocks'
+    corners; and the gradient its custom rule gives against autodiff
+    through a dense inverse."""
     strict = jnp.tril(jnp.ones((c, c)), -1)
-    a = jax.random.normal(jax.random.PRNGKey(1), (3, c, c)) * 0.3 * strict
-    a = a.at[0].set(strict)
+    a = jax.random.normal(jax.random.PRNGKey(1), (4, c, c)) * 0.3 * strict
+    a = a.at[0].set(strict).at[3].set(_corner(c))
     eye = jnp.eye(c)
     got = gd.unit_lower_inverse(a)
     np.testing.assert_allclose(got, jnp.linalg.inv(eye + a), atol=1e-4)
     np.testing.assert_allclose(got[0], eye - jnp.eye(c, k=-1), atol=1e-6)
-    w = jax.random.normal(jax.random.PRNGKey(2), (3, c, c))
+    # the corners against a float64 inverse: float32 LU is itself 1e-6 off
+    np.testing.assert_allclose(
+        got[3], np.linalg.inv(np.eye(c) + np.asarray(a[3], np.float64)),
+        rtol=1e-6, atol=1e-6)
+    w = jax.random.normal(jax.random.PRNGKey(2), (4, c, c))
     got_g = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * w))(a)
     want_g = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(eye + a) * w))(a)
     np.testing.assert_allclose(got_g[1:], want_g[1:], rtol=1e-3, atol=1e-3)
