@@ -109,7 +109,18 @@ def make_train_step(cfg, mesh: Mesh,
     (default) or ray_tpu.models.moe. A family that also has
     ``loss_and_metrics`` (-> loss, {name: device scalar}) gets those
     scalars added to a step's metrics (moe: ``moe_aux_loss``,
-    ``moe_load_max_over_mean``)."""
+    ``moe_load_max_over_mean``).
+
+    ``step_fn`` CONSUMES the state it is handed (its first argument is
+    donated): every leaf of the old ``TrainState`` is deleted and the
+    returned one lives in its buffers, so rebind (``state, met =
+    step_fn(state, batch)``) and copy first (``jax.tree.map(jnp.copy,
+    state)``) what is to be read afterwards. Why: the TPU compiler
+    plans a step's memory as arguments + outputs + the temporaries'
+    peak, so undonated the params and both moments are held twice for
+    the whole program; at the train cells' sizes that left 2-4 GB of
+    16 for every temporary, and XLA's own rematerialisation answered
+    by computing whole FFN products again (``.remat`` instructions)."""
     opt = optimizer if optimizer is not None else default_optimizer()
     _loss = loss_fn if loss_fn is not None else (
         lambda p, b: model.loss_fn(p, b, cfg, mesh, axes))
@@ -131,7 +142,7 @@ def make_train_step(cfg, mesh: Mesh,
             pshard)
         return TrainState(params, opt_state, jnp.zeros((), jnp.int32))
 
-    @jax.jit
+    @jax.jit(donate_argnums=(0,))
     def step_fn(state: TrainState, batch: dict):
         batch = {k: jax.lax.with_sharding_constraint(v, batch_spec)
                  for k, v in batch.items()}

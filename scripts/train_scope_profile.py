@@ -54,7 +54,7 @@ def main() -> int:
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--out")
     a = ap.parse_args()
-    from harness import model as hmodel, spec
+    from harness import model as hmodel, spec, train_cell
     hmodel.compile_cache()
     import jax
     from jax.profiler import ProfileData
@@ -70,7 +70,10 @@ def main() -> int:
     seq, batch = int(hmodel.traffic(cell)["seq_len"]), int(dep["batch"])
     mesh = pmesh.make_mesh(pmesh.MeshSpec(data=1, context=1, **dep["mesh"]),
                            devices=jax.devices()[:cell["chips"]])
-    init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=fam.module())
+    # the cell's own optimizer (its peak rate, its frozen leaves): the
+    # routing regime of the profiled steps is then the cell's
+    init_fn, step_fn = pmesh.make_train_step(
+        cfg, mesh, model=fam.module(), optimizer=train_cell.optimizer(dep))
     key = jax.random.PRNGKey(a.seed % 2 ** 31)
     with mesh:
         state = init_fn(key)

@@ -720,37 +720,34 @@ def test_the_period_scan_reads_the_projections_stack_in_place(chip):
 
 # --- the linear-attention train cell's step ---------------------------------
 
-def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
-    """``train-qwen3next-ep16``'s whole train step (``make_train_step`` on a
-    mesh of one described chip, batch and rows as the cell runs them):
-    the flash kernels compile at head 256 with 16 / 2 heads, forward and
-    backward; the step fits the chip by the compiler's memory analysis and
-    is sized like a deployment's (11 GB or more); and it holds no custom
-    call the benchmark would not know: every one is a flash kernel by its
-    operand signature or a grouped matmul by its name, so that
-    ``moe_gmm_dev_ms.train`` (every unknown kernel's time) counts the
-    grouped matmuls alone. The chunked delta rule is plain XLA."""
+def _compile_cell_step(topo, file, model, **overrides):
+    """(compiled step, the state's bytes on one device, the deployment)
+    of ``benchmarks/configs/<file>``'s train step as its cell runs it:
+    ``make_train_step`` on the cell's mesh over the described chips, the
+    state's shardings those of ``init_fn``'s compiled outputs, batch and
+    rows the cell's, the flash kernels in."""
     import json
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ray_tpu.models import llama, moe
+    from ray_tpu.models import llama
     from ray_tpu.parallel import mesh as pmesh
-    file = "qwen3-next-80b-a3b-train-ep16.json"
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            os.pardir, "benchmarks", "configs", file)) as f:
         dep = json.load(f)["deployment"]
-    cfg = _cell_config(file, dep["family"], gmm_impl="pallas",
+    cfg = _cell_config(file, dep.get("family", "llama"), **overrides,
                        **dep["model_overrides"])
-    mesh = pmesh.make_mesh(pmesh.MeshSpec(data=1, context=1, **dep["mesh"]),
-                           devices=topo.devices[:1])
-    init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=moe)
-    here = NamedSharding(mesh, P())
-
-    def shapes(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=here), tree)
-    state = shapes(jax.eval_shape(init_fn, jax.random.PRNGKey(0)))
+    sizes = {"data": 1, "context": 1, **dep["mesh"]}
+    mesh = pmesh.make_mesh(pmesh.MeshSpec(**sizes),
+                           devices=topo.devices[:math.prod(sizes.values())])
+    init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=model)
+    with mesh:
+        init = init_fn.lower(jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)),
+        init.output_shardings)
+    rows = NamedSharding(mesh, P(("data", "fsdp"), "context"))
     batch = {k: jax.ShapeDtypeStruct((dep["batch"], 4096), jnp.int32,
-                                     sharding=here)
+                                     sharding=rows)
              for k in ("tokens", "targets")}
     was = llama._on_tpu
     llama._on_tpu = lambda: True      # flash: the kernel, not the fallback
@@ -759,7 +756,71 @@ def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
             compiled = step_fn.lower(state, batch).compile()
     finally:
         llama._on_tpu = was
+    held = sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+               for s in jax.tree.leaves(state))
+    return compiled, held, dep
+
+
+def _assert_state_aliased(compiled, held, dep):
+    """The compiled step's memory analysis, after asserting that every
+    byte handed in but the batch's is aliased into an output: the
+    donated ``TrainState`` (``held`` logical bytes a device; the
+    compiler counts each leaf in whole tiles, so no fewer)."""
     mem = compiled.memory_analysis()
+    batch = 2 * dep["batch"] // dep["mesh"]["fsdp"] * 4096 * 4
+    assert mem.alias_size_in_bytes >= held, (mem, held)
+    assert mem.argument_size_in_bytes - mem.alias_size_in_bytes == batch, mem
+    return mem
+
+
+def _remat_matmuls(compiled) -> list:
+    """Names of the matmuls XLA's own rematerialisation pass made
+    (``fusion.386.remat3`` and its like): the products it computes again
+    where a program does not fit its memory plan. A forward recomputed by
+    ``jax.checkpoint`` is an ordinary instruction and carries no such
+    suffix; a rematerialised bitcast, copy or broadcast costs next to
+    nothing and is let through."""
+    return [name for name, op, rest in re.findall(
+        r"%([\w.-]*remat[\w.-]*) = \S+ ([\w-]+)\(([^\n]*)",
+        compiled.as_text())
+        if op in ("fusion", "convolution", "dot") and re.search(
+            r"dot_general|convolution|kind=kOutput", rest)]
+
+
+@pytest.mark.parametrize("file", [
+    "mistral-7b-v0.3-train.json", "yi-1.5-34b-train-4chip.json"],
+    ids=["dense_1chip", "yi34b_4chip"])
+def test_the_dense_cells_steps_alias_their_state_and_recompute_nothing(
+        topo, file):
+    """``step_fn`` donates its ``TrainState``: the compiled step of
+    ``train-dense-1chip`` and of ``train-yi34b-4chip`` (a chip of the
+    four) aliases every byte of params, moments and counters into its
+    outputs, so its plan is the state ONCE plus the temporaries, fits the
+    chip, and the compiler rematerialises no matmul to make it fit
+    (undonated: 28 and 29 ``.remat`` instructions, three and ten of them
+    whole FFN / projection products a layer)."""
+    from ray_tpu.models import llama
+    compiled, held, dep = _compile_cell_step(topo, file, llama)
+    mem = _assert_state_aliased(compiled, held, dep)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9, mem
+    assert not _remat_matmuls(compiled)
+
+
+def test_the_linear_attention_cells_step_compiles_for_v5e(topo):
+    """``train-qwen3next-ep16``'s whole train step (``make_train_step`` on a
+    mesh of one described chip, batch and rows as the cell runs them):
+    the flash kernels compile at head 256 with 16 / 2 heads, forward and
+    backward; the step aliases its whole state (``step_fn`` donates it),
+    fits the chip by the compiler's memory analysis, the state counted
+    once, and is sized like a deployment's (11 GB or more); and it holds
+    no custom call the benchmark would not know: every one is a flash
+    kernel by its operand signature or a grouped matmul by its name, so
+    that ``moe_gmm_dev_ms.train`` (every unknown kernel's time) counts
+    the grouped matmuls alone. The chunked delta rule is plain XLA."""
+    from ray_tpu.models import moe
+    compiled, held, dep = _compile_cell_step(
+        topo, "qwen3-next-80b-a3b-train-ep16.json", moe, gmm_impl="pallas")
+    mem = _assert_state_aliased(compiled, held, dep)
     total = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     assert 11e9 <= total < 15.75e9, total

@@ -252,7 +252,9 @@ def test_a_train_step_learns_and_reports_its_local_share():
     toks = (first + 5 * jnp.arange(65)[None]) % 64
     batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
     with mesh:
-        state = start = init_fn(jax.random.PRNGKey(0))
+        state = init_fn(jax.random.PRNGKey(0))
+        # ``step_fn`` consumes the state it is handed: keep a copy
+        start = jax.tree.map(jnp.copy, state.params)
         losses = []
         for _ in range(10):
             state, met = step_fn(state, batch)
@@ -263,7 +265,7 @@ def test_a_train_step_learns_and_reports_its_local_share():
     assert 0.05 < float(met["moe_local_share"]) < 0.6   # 4 of 16 held
     # every leaf of the new kinds is trained
     moved = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
-                         state.params, start.params)
+                         state.params, start)
     for kind in ("linear_layers", "full_layers", "layers"):
         for name, by in moved[kind].items():
             assert by > 0, (kind, name)
