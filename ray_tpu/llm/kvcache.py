@@ -617,7 +617,9 @@ def _jx():
 # from the first position on and a prefix's blocks can be shared. An
 # array's width is a whole number of 128-lane tiles (LANES): the device
 # lays a narrower minor dimension out so anyway, and a kernel's DMA
-# cannot take part of a tile; kr's 64 values lie in 128, the rest zero.
+# cannot take part of a tile; the widths are the configuration's (c 256 or
+# 512 values, kr 64 in the served ones: 768 or 1,280 bytes a row in bf16),
+# and a kr of 64 values lies in 128, the rest zero.
 GLOBAL, WINDOW, LATENT = "global", "window", "latent"
 LANES = 128
 POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv"), LATENT: ("c", "kr")}

@@ -126,6 +126,9 @@ def _scan_layers(layer, x, xs):
 #                    whose leaves decide the feed-forward: a ``router``
 #                    means experts (models/moe.py serve_block)
 #   qk_norm, qk_head_norm, post_norm, rope_layers   (models/moe.py)
+#   hc_mult          n > 1: the residual stream is n copies a position,
+#                    (n, b, s, d) from the embedding to the final norm,
+#                    mixed by hyper-connections ("the mixed stream" below)
 
 
 def model_family(cfg):
@@ -437,16 +440,124 @@ def _feed_forward(y, lp, cfg, ref: LayerRef, active):
     return out.reshape(y.shape), stats
 
 
+# --- the mixed stream --------------------------------------------------------
+#
+# Manifold-constrained hyper-connections (mHC, arXiv:2512.24880, on
+# Hyper-Connections, arXiv:2409.19606). With ``hc_mult`` n > 1 a position's
+# residual is X, n rows of d, and a sub-layer F (with its own pre-norm)
+# reads one mix of them and writes back through a doubly stochastic matrix:
+#
+#   x~    = vec(X) * rsqrt(mean(vec(X)^2) + norm_eps)    (n d,), no weight
+#   z     = a * (x~ @ phi) + b        phi's columns [pre n | post n | res n n]
+#   Hpre  = sigmoid(z_pre)     Hpost = 2 sigmoid(z_post)
+#   M     = exp(clip(z_res, hc_res_clamp_min, hc_res_clamp_max))    (n, n)
+#   hc_sinkhorn_iters times:  M = M / (its columns' sums + hc_eps),
+#                             then M = M / (its rows' sums + hc_eps)
+#   X'    = M @ X + outer(Hpost, F(Hpre @ X))
+#
+# The embedding is copied to the n rows; they are summed before the final
+# norm. The coefficients are float32 from the stream to Hres (the product
+# with phi at ``highest`` precision); the stream itself stays in the
+# model's dtype. The forwards carry it as (n, b, s, d), the copies
+# outermost: a copy is then a plain (b, s, d) slab, and every mix is
+# elementwise on slabs with a (b, s) coefficient (n = 4 as a second-minor
+# dimension would lie in tiles of 8 or 16 rows). Sinkhorn runs on (n, n,
+# b, s), the positions on the lanes, its sums written as adds of slabs, so
+# the iterations are one elementwise chain.
+
+def _hc(cfg) -> int:
+    """The stream's copies a position; 0 for the plain residual."""
+    return getattr(cfg, "hc_copies", 0)
+
+
+def _embed(params, tokens, cfg):
+    """The stream the layers carry for ``tokens`` (...): their embeddings
+    (..., d), for a mixed stream copied to its n rows, (n, ..., d)."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    n = _hc(cfg)
+    return jnp.broadcast_to(x[None], (n, *x.shape)) if n else x
+
+
+def _final_norm(x, params, cfg):
+    """What the head multiplies: the final norm of the stream, a mixed
+    stream's n rows summed first."""
+    if _hc(cfg):
+        x = jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
+    return _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+
+
+def mhc_sinkhorn(m, iters: int, eps: float):
+    """m (n, n, ...) positive -> doubly stochastic to the iteration's
+    accuracy; its rows sum to 1 within eps (they are normalised last)."""
+    n = m.shape[0]
+    for _ in range(iters):
+        m = m / (sum(m[i] for i in range(n))[None] + eps)
+        m = m / (sum(m[:, j] for j in range(n))[:, None] + eps)
+    return m
+
+
+def mhc_coefficients(x, lp, sub: str, cfg):
+    """The stream x (n, b, s, d) -> (Hpre (n, b, s), Hpost (n, b, s), Hres
+    (n, n, b, s)) float32 of sub-layer ``sub`` ("attn" | "mlp")."""
+    with jax.named_scope("mhc.coeff"):
+        n, d = x.shape[0], x.shape[-1]
+        xf = x.astype(jnp.float32)
+        r = lax.rsqrt(jnp.mean(jnp.mean(xf * xf, axis=-1), axis=0)
+                      + cfg.norm_eps)                           # (b, s)
+        phi = lp[f"hc_{sub}_phi"].reshape(n, d, -1)
+        z = jnp.einsum("nbsd,ndk->kbs", xf, phi,
+                       precision=lax.Precision.HIGHEST) * r
+        a, b = lp[f"hc_{sub}_a"], lp[f"hc_{sub}_b"][:, None, None]
+        pre = jax.nn.sigmoid(a[0] * z[:n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * z[n:2 * n] + b[n:2 * n])
+        m = jnp.exp(jnp.clip(a[2] * z[2 * n:] + b[2 * n:],
+                             cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
+        res = mhc_sinkhorn(m.reshape(n, n, *m.shape[1:]),
+                           cfg.hc_sinkhorn_iters, cfg.hc_eps)
+        return pre, post, res
+
+
+def _mhc_read(x, pre):
+    """Hpre @ X: what the sub-layer reads, (b, s, d) in x's dtype."""
+    with jax.named_scope("mhc.read"):
+        return sum(pre[i][..., None] * x[i].astype(jnp.float32)
+                   for i in range(x.shape[0])).astype(x.dtype)
+
+
+def _mhc_write(x, res, post, f):
+    """Hres @ X + outer(Hpost, f): the stream after the sub-layer."""
+    with jax.named_scope("mhc.write"):
+        n = x.shape[0]
+        xf, ff = x.astype(jnp.float32), f.astype(jnp.float32)
+        return jnp.stack(
+            [sum(res[i, j][..., None] * xf[j] for j in range(n))
+             + post[i][..., None] * ff for i in range(n)]).astype(x.dtype)
+
+
+def _residual(x, lp, cfg, sub: str, f):
+    """The stream after sub-layer ``f`` (what it reads -> (its output,
+    what else it returns)): x + f(x), or the mixed stream's read and
+    write around it."""
+    if not _hc(cfg):
+        out, aux = f(x)
+        return x + out, aux
+    pre, post, res = mhc_coefficients(x, lp, sub, cfg)
+    out, aux = f(_mhc_read(x, pre))
+    return _mhc_write(x, res, post, out), aux
+
+
 def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
-    """One decoder layer of every serving forward. x (b, s, d);
-    ``rope`` the (cos, sin) tables of the positions; ``attend(q, k, v,
-    wo)`` attends (the forwards differ in nothing else) and returns the
-    projected output, shaped like x. Returns (x, k, v, expert counts or
-    None); k is as the cache keeps it (after RoPE). For a latent layer k
-    and v are the cache's rows c and kr (``_latent_qkv``)."""
+    """One decoder layer of every serving forward. x (b, s, d), or the
+    mixed stream (n, b, s, d); ``rope`` the (cos, sin) tables of the
+    positions; ``attend(q, k, v, wo)`` attends (the forwards differ in
+    nothing else) and returns the projected output, (b, s, d). Returns
+    (x, k, v, expert counts or None); k is as the cache keeps it (after
+    RoPE). For a latent layer k and v are the cache's rows c and kr
+    (``_latent_qkv``)."""
     post = getattr(cfg, "post_norm", False)
     eps = cfg.norm_eps
-    with jax.named_scope("attention." + ref.kind):
+
+    def attention(x):
         y = x if post else _rmsnorm(x, lp["attn_norm"], eps)
         if ref.kind == LATENT:
             # k, v: the positions' cache rows c and kr (no head axis);
@@ -458,10 +569,16 @@ def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
                     or getattr(cfg, "rope_layers", "all") == "all":
                 q, k = _rope(q, *rope), _rope(k, *rope)
         a = attend(q, k, v, lp["wo"])
-        x = x + (_rmsnorm(a, lp["attn_norm"], eps) if post else a)
-    y = x if post else _rmsnorm(x, lp["mlp_norm"], eps)
-    m, stats = _feed_forward(y, lp, cfg, ref, active)
-    x = x + (_rmsnorm(m, lp["mlp_norm"], eps) if post else m)
+        return (_rmsnorm(a, lp["attn_norm"], eps) if post else a), (k, v)
+
+    def feed_forward(x):
+        y = x if post else _rmsnorm(x, lp["mlp_norm"], eps)
+        m, stats = _feed_forward(y, lp, cfg, ref, active)
+        return (_rmsnorm(m, lp["mlp_norm"], eps) if post else m), stats
+
+    with jax.named_scope("attention." + ref.kind):
+        x, (k, v) = _residual(x, lp, cfg, "attn", attention)
+    x, stats = _residual(x, lp, cfg, "mlp", feed_forward)
     return x, k, v, stats
 
 
@@ -520,7 +637,7 @@ def _prefill_attn_kw(cfg, kind: str) -> dict:
 
 def _head(x, params, cfg, length):
     """Last valid row of x (1, s, d) -> (vocab,) float32 logits."""
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _final_norm(x, params, cfg)
     last = jnp.take(x[0], length - 1, axis=0)
     return (last @ params["lm_head"]).astype(jnp.float32)
 
@@ -545,7 +662,7 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
     is read)."""
     from ray_tpu.ops.attention import attention as _attention
     s = tokens.shape[0]
-    x = jnp.take(params["embed"], tokens[None], axis=0)  # (1, s, emb)
+    x = _embed(params, tokens[None], cfg)                # (1, s, emb)
     positions = jnp.arange(s, dtype=jnp.int32)[None]
     rope = rope_tables(cfg, positions)
     h, hd = cfg.n_heads, cfg.head_dim
@@ -653,7 +770,7 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
     from ray_tpu.ops.attention import attention as _attention
     s = tokens.shape[0]
     h, hd = cfg.n_heads, cfg.head_dim
-    x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
+    x = _embed(params, tokens[None], cfg)                   # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
     rope = rope_tables(cfg, positions)
 
@@ -690,7 +807,7 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
     L = acc["k"].shape[1]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = h // kvh
-    x = jnp.take(params["embed"], tokens[None], axis=0)     # (1, s, emb)
+    x = _embed(params, tokens[None], cfg)                   # (1, s, emb)
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
     rope = rope_tables(cfg, positions)
     q_pos = positions[0]                                    # (s,)
@@ -709,7 +826,7 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
             if ref.kind == LATENT:          # every row expanded, then masked
                 (nk,), (nv,) = latent_expand(q, nk[None], nv[None], lp, cfg)
-            qg = q[0].reshape(s, kvh, g, hd).astype(jnp.float32)
+            qg = q[0].reshape(s, kvh, g, -1).astype(jnp.float32)
             kf = nk.astype(jnp.float32)                     # (L, kvh, hd)
             scores = jnp.einsum("skgd,lkd->kgsl", qg, kf)
             scores = scores * softmax_scale(cfg, LATENT) \
@@ -821,7 +938,7 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     logits, pool, the expert layers' counts summed over the layers or
     None: models/moe.py serve_block, live rows being the slots at a
     position > 0)."""
-    x = jnp.take(params["embed"], tokens[:, None], axis=0)  # (b, 1, emb)
+    x = _embed(params, tokens[:, None], cfg)                # (b, 1, emb)
     rope = rope_tables(cfg, positions[:, None])
     active = positions > 0 if has_experts(cfg) else None
 
@@ -842,7 +959,7 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
                   for k in ("routed", "local", "experts_hit")}
     (x, pool, counts), _ = _run_layers(params, cfg, (x, pool, counts),
                                        layer)
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _final_norm(x, params, cfg)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, pool, counts
 
@@ -890,7 +1007,7 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
     ``attend(ref, q, k, v, pool) -> ((b, w, h*hd) f32, pool)`` takes q
     (b, w, h, hd) and the new rows k, v (b, w, kvh, hd)."""
     b, w = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)           # (b, w, emb)
+    x = _embed(params, tokens, cfg)                         # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
     rope = rope_tables(cfg, pos)
 
@@ -905,6 +1022,6 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
         return (x, pool), None
 
     (x, pool), _ = _run_layers(params, cfg, (x, pool), layer)
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _final_norm(x, params, cfg)
     logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
     return logits, pool

@@ -114,6 +114,16 @@ scaled by head ** -0.5 * mscale ** 2 (mscale = 0.1 * ``rope_mscale_all_dim``
 ln(1 + floor(t / rope_original_len)). Prefill materialises keys and values
 from the rows it attends; decode absorbs ``wk_b`` into the query and
 ``wv_b`` into the output and attends the rows themselves (``llm/model.py``).
+``hc_mult`` n > 1 (with ``hc_sinkhorn_iters``, ``hc_eps``,
+``hc_res_clamp_min`` / ``hc_res_clamp_max``) widens the residual stream to
+n copies a position, mixed by manifold-constrained hyper-connections
+(``llm/model.py`` has the equations): each sub-layer reads sigmoid-weighted
+rows of the stream and writes back through an n x n matrix that
+``hc_sinkhorn_iters`` alternating column / row normalisations of
+exp(clip(.)) make doubly stochastic, all from the position's own normed
+stream, in float32. Its leaves, a sub-layer (``hc_attn_*``, ``hc_mlp_*``):
+``phi`` (n * d, 2n + n * n) float32 with columns [pre | post | res],
+``b`` (2n + n * n,) and ``a`` (3,), the three learned scales.
 One expert layer serves both: ``serve_block`` and the train forward's ``_experts`` share ``_route``,
 ``_sort_by_expert``, ``_gated_sum`` and ``_shared``.
 """
@@ -212,10 +222,22 @@ class MoEConfig:
     # ``llama_4_scaling_beta``: the query of position t is scaled by
     # 1 + beta * ln(1 + floor(t / rope_original_len))
     query_scale_beta: float = 0.0
+    # manifold-constrained hyper-connections (module docstring): the
+    # residual stream's copies a position; 0 or 1 = the plain add
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.dim // self.n_heads
+
+    @property
+    def hc_copies(self) -> int:
+        """The mixed residual stream's copies a position; 0: the plain add."""
+        return self.hc_mult if self.hc_mult > 1 else 0
 
     @property
     def n_held(self) -> int:
@@ -258,6 +280,13 @@ class MoEConfig:
             + (d if self.shared_expert_gate else 0)
         return router + 3 * (experts + self.n_shared_experts) * d * f
 
+    def _mixing_params(self) -> int:
+        """A layer's hyper-connection leaves (two sub-layers)."""
+        n = self.hc_copies
+        if not n:
+            return 0
+        return 2 * ((n * self.dim + 1) * (2 * n + n * n) + 3)   # phi, b; a
+
     def _params(self, experts: int) -> int:
         dense = self._attn_params() + 3 * self.dim * self.dense_ffn_dim
         linear = self.layer_types.count("linear")
@@ -265,6 +294,7 @@ class MoEConfig:
             + (self.n_layers - self.n_dense_layers - linear) \
             * self._attn_params()
         return 2 * self.vocab_size * self.dim + self.dim \
+            + self.n_layers * self._mixing_params() \
             + self.n_dense_layers * dense + mixers \
             + (self.n_layers - self.n_dense_layers) \
             * self._layer_params(experts)
@@ -362,6 +392,32 @@ def mistral_small_4_119b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def xing4_0_29b_a4b(**kw) -> MoEConfig:
+    """XingChen-AGI/Xing4.0-29B-A4B ``config.json`` (``model_type:
+    xing4_0``): 40 latent-attention layers (32 heads of nope 128 + rope 64,
+    v 128; q rank 768; a cached row of 512 + 64), YaRN factor 64 over
+    4,096 positions, 2 leading dense layers of width 9216, then 64
+    sigmoid-routed experts of width 1024, 4 a token (scale 2), one shared
+    expert, and a residual stream of 4 copies mixed by hyper-connections
+    (20 Sinkhorn iterations). The multi-token-prediction block is not in
+    the tree."""
+    defaults = dict(
+        vocab_size=131072, dim=3584, n_layers=40, n_heads=32, n_kv_heads=32,
+        head_size=128, ffn_dim=1024, n_experts=64, experts_per_token=4,
+        norm_topk_prob=True, scoring="sigmoid", routed_scaling=2.0,
+        n_shared_experts=1, n_dense_layers=2, dense_ffn_dim=9216,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_factor=64.0,
+        rope_original_len=4096, rope_beta_fast=32.0, rope_beta_slow=1.0,
+        rope_mscale=1.0, rope_mscale_all_dim=1.0, hc_mult=4,
+        hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp_min=-30.0,
+        hc_res_clamp_max=30.0, max_seq_len=262144, rope_theta=10000.0,
+        norm_eps=1e-6)
+    defaults.update(kw)
+    defaults.setdefault("layer_types", ("latent",) * defaults["n_layers"])
+    return MoEConfig(**defaults)
+
+
 def tiny(**kw) -> MoEConfig:
     defaults = dict(vocab_size=512, dim=64, n_layers=2, n_heads=4,
                     n_kv_heads=2, ffn_dim=128, n_experts=4,
@@ -379,7 +435,8 @@ def _serving_only(cfg: MoEConfig) -> bool:
     """Whether ``cfg`` has a shape only the serving forwards run."""
     return bool(set(cfg.layer_types) - set(TRAIN_KINDS)
                 or cfg.n_dense_layers or cfg.post_norm
-                or cfg.rope_layers != "all" or cfg.scoring != "softmax")
+                or cfg.rope_layers != "all" or cfg.scoring != "softmax"
+                or cfg.hc_copies)
 
 
 def _kind_layers(cfg: MoEConfig) -> dict:
@@ -476,6 +533,28 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
                 "wo": stack(L, h * vd, d, fan_in=h * vd),
                 "mlp_norm": jnp.ones((L, d), dtype)}
 
+    def mixing(L, salt):
+        """The hyper-connections' leaves of a stack of L layers (module
+        docstring). The input-dependent part of a coefficient is of the
+        size of its bias: x~ has unit RMS, so phi at fan-in n * d gives
+        x~ @ phi a unit spread, the scales a are near 1 and b is a unit
+        normal; before the sigmoids and the exp each coefficient then
+        spreads by about 1.4, half of it from the position: Hres is
+        neither the identity nor uniform and differs by position."""
+        n = cfg.hc_copies
+        if not n:
+            return {}
+        ks = iter(jax.random.split(jax.random.fold_in(rng, salt), 6))
+        out, width = {}, 2 * n + n * n
+        for sub in ("attn", "mlp"):
+            out[f"hc_{sub}_phi"] = jax.random.normal(
+                next(ks), (L, n * d, width), jnp.float32) * (n * d) ** -0.5
+            out[f"hc_{sub}_b"] = jax.random.normal(
+                next(ks), (L, width), jnp.float32)
+            out[f"hc_{sub}_a"] = 1.0 + 0.1 * jax.random.normal(
+                next(ks), (L, 3), jnp.float32)
+        return out
+
     def attn(L):
         if latent:
             return latent_attn(L)
@@ -503,11 +582,11 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
     if Ld:
         fd = cfg.dense_ffn_dim
         params["dense_layers"] = {
-            **attn(Ld), "w_gate": stack(Ld, d, fd, fan_in=d),
+            **attn(Ld), **mixing(Ld, 3), "w_gate": stack(Ld, d, fd, fan_in=d),
             "w_up": stack(Ld, d, fd, fan_in=d),
             "w_down": stack(Ld, fd, d, fan_in=fd)}
     layers = {
-        **attn(Ls),
+        **attn(Ls), **mixing(Ls, 4),
         "router": jax.random.normal(next(keys), (Ls, d, E), jnp.float32)
         * (d ** -0.5),
         "w_gate": stack(Ls, held, d, f, fan_in=d),
@@ -1373,7 +1452,8 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
             "softmax-routed experts; window / global / latent layer_types "
             "(a latent layer has no training rule here: its absorbed and "
             "materialised forms are the cache's), n_dense_layers, "
-            "post_norm, rope_layers and sigmoid scoring are the serving "
+            "post_norm, rope_layers, sigmoid scoring and a residual "
+            "stream mixed by hyper-connections (hc_mult) are the serving "
             "forwards' (ray_tpu.llm.model)")
     b, s = tokens.shape
 

@@ -79,11 +79,13 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                q_offset=None, window=None):
-    if q_offset is not None or window is not None:
+    if q_offset is not None or window is not None \
+            or v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
-            "q_offset (chunked-prefill causal placement) and window are "
-            "inference-only paths; the backward kernels assume causal "
-            "queries that are the last rows")
+            "q_offset (chunked-prefill causal placement), window and "
+            "values of another width than the keys are inference-only "
+            "paths; the backward kernels assume causal queries that are "
+            "the last rows, and one head width")
     o, lse = _fa.flash_attention_fwd(q, k, v, sm_scale=sm_scale, causal=causal,
                                      block_q=block_q, block_k=block_k,
                                      interpret=interpret)
@@ -118,7 +120,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None) -> jax.Array:
     """Pallas flash attention, (b, s, h, d) layout, differentiable
     (except with q_offset, the inference-only chunked-prefill causal
-    placement, or window, a sliding-window layer's band)."""
+    placement, window, a sliding-window layer's band, or values (b, s, h,
+    dv) of another width than the keys, a latent layer's heads)."""
     b, sq, h, d = q.shape
     k = _repeat_kv(k, h)
     v = _repeat_kv(v, h)
@@ -127,10 +130,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # (b, s, h, d) -> (b*h, s, d)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, v.shape[-1])
     of = _flash(qf, kf, vf, scale, causal, block_q, block_k, interpret,
                 q_offset, window)
-    return of.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return of.reshape(b, h, sq, -1).transpose(0, 2, 1, 3)
 
 
 def _on_tpu() -> bool:

@@ -350,7 +350,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, plans, causal, block_q,
         m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]         # the values' width, which may not be q's
 
     @pl.when(ki == 0)
     def _init():
@@ -415,6 +415,9 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
                         interpret=False, with_lse=True, q_offset=None,
                         window=None):
     """q,k,v: (BH, S, D) -> (o: (BH, S, D), lse: (BH, 1, S) f32 | None).
+    v may be (BH, S, Dv) with Dv != D (a latent layer's heads: keys of
+    nope + rope, values of v_head_dim); o is then (BH, S, Dv). Forward
+    only: the backward kernels take one width.
 
     lse is the row logsumexp saved as a backward residual, one value a
     lane (a lane-replicated (BH, S, LANES) copy is 128 times the bytes,
@@ -431,6 +434,7 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
     keys up to i + q_offset only."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
+    dv = v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     offset = (sk - sq) if q_offset is None else int(q_offset)
@@ -445,8 +449,8 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
         with_lse=with_lse, **tiles)
     kv_map = _kv_map(nk, **tiles)
 
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct(qp.shape, q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((*qp.shape[:2], dv), q.dtype)]
     if with_lse:
         out_specs.append(
             pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)))
@@ -459,14 +463,14 @@ def flash_attention_fwd(q, k, v, *, sm_scale, causal, block_q=128, block_k=128,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -45,7 +45,8 @@ named ``paged_decode_window``.
 ``chunk_blocks`` is the one place that chooses C, from what a position
 costs in the pool (a kind's row: llm/kvcache.py row_shapes): about 128
 positions a chunk (one lane-width of scores; 512 for a latent row, a
-sixth of the bytes), capped so that the buffers stay within 4 MB of VMEM.
+sixth to a third of the bytes by the configuration's kv_lora_rank), capped
+so that the buffers stay within 4 MB of VMEM.
 
 A LATENT layer (multi-head latent attention in its absorbed form) keeps
 one row a position, [c | kr], and no head axis: every head attends the
@@ -103,10 +104,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 CHUNK_POSITIONS = 128           # one lane-width of scores a chunk
-# a latent row is 768 bytes in the pool (c 256 values, kr 64 in an array
-# 128 wide) where 8 KV heads' K and V are 4,096: four lane-widths a chunk,
-# so that a fetch is 384 KB (the K/V walk's is 512 KB). Reasoned, not
-# swept: no run has varied it (PERF.md section 7)
+# a latent row's bytes are its configuration's: 768 in the pool at c 256
+# values + kr 64 (in an array 128 wide), 1,280 at c 512 + kr 64, where 8 KV
+# heads' K and V are 4,096: four lane-widths a chunk, so that a fetch is
+# 384 KB / 640 KB (the K/V walk's is 512 KB). Reasoned at the first width,
+# not swept: no run has varied it (PERF.md section 7)
 LATENT_CHUNK_POSITIONS = 512
 BUFFER_BYTES = 4 * 1024 * 1024  # a kind's two arrays, two buffers each
 
