@@ -14,6 +14,7 @@ own for this; see SURVEY.md section 3.4 for the JaxTrainer north-star path).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -161,7 +162,12 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
 
 def param_shardings(cfg: LlamaConfig, axes: MeshAxes = MeshAxes()) -> dict:
     """PartitionSpec pytree matching init_params. Megatron-style tensor
-    sharding + FSDP on the complementary dim."""
+    sharding + FSDP on the complementary dim: wq / wk / wv and w_gate /
+    w_up are column-parallel over ``tensor`` (their products need no
+    sum forward and one, of the norm's cotangent, backward), wo and
+    w_down row-parallel (one sum forward, none backward); the norms'
+    outputs and the residual stream are replicated over ``tensor``.
+    Who makes those sums: ``_layer_products``."""
     t, fs = axes.tensor, axes.fsdp
     return {
         "embed": P(t, fs),
@@ -321,7 +327,7 @@ def _remat(layer, cfg: LlamaConfig):
     if cfg.remat_policy == "dots":
         policy = cp.save_from_both_policies(
             cp.checkpoint_dots_with_no_batch_dims,
-            cp.save_only_these_names("attn_out", "attn_lse"))
+            cp.save_only_these_names("attn_out", "attn_lse", "row_sum"))
     elif cfg.remat_policy == "attn":
         # Saves the flash kernel outputs (o + lse residuals) so backward
         # never re-runs the attention forward; "attn_res" covers the
@@ -332,12 +338,150 @@ def _remat(layer, cfg: LlamaConfig):
     return jax.checkpoint(layer, policy=policy)
 
 
+# --- a layer's sums over `tensor` ------------------------------------------
+
+def _tp_chunks(rows: int) -> int:
+    """How many chunks of the sequence a `tensor` pair exchanges a sum
+    in, for ``rows`` positions a chip: four where four divides them,
+    else two, else 0 (GSPMD's all-reduce). On four v5e chips at 4,096
+    rows of width 7,168 a step took 1,425.1 ms at 2 chunks, 1,420.3 at
+    4 and 1,444.0 at 8 (whose ``wo`` product, 0.5 ms, no longer covers
+    a chunk's flight), all under the all-reduce's 1,546.8: no size was
+    found at which the blocking sum wins, so none is kept for it
+    (PERF.md, PR 55)."""
+    return next((n for n in (4, 2) if rows % n == 0), 0)
+
+
+def _tensor_pair_products(mesh: Mesh, axis: str, n: int, rows: P):
+    """Megatron's column- and row-parallel products over a mesh
+    ``axis`` of TWO chips, ``(col, row)``, each with the one sum over
+    ``axis`` it owes made as ``n`` exchanges, a chunk of the sequence
+    at a time (``rows``: how an activation lies over the mesh's other
+    axes):
+
+    - ``row(a, w)``: ``a @ w`` with ``a``'s last and ``w``'s first
+      dimension sharded. Forward: chunk i's partial product, then
+      ``part + the other chip's part`` (Megatron's ``g``); backward
+      plain, no sum.
+    - ``col(y, *ws)``: ``y @ w`` for each ``w``, its last dimension
+      sharded. Forward plain; backward: chunk i's partial ``dy`` over
+      all of ``ws``, then the same sum (Megatron's ``f``).
+
+    A sum is ONE ``ppermute`` of the chunk (for a pair it moves the
+    bytes a ring all-reduce moves, and ``a + b`` is ``b + a``: both
+    chips hold the all-reduce's bits), which the TPU compiler starts
+    after chunk i's product and waits for after chunk i + 1's, where it
+    runs an activation's ``all-reduce`` blocking. Only the product next
+    to the sum is chunked: the weights' gradients stay one product
+    each (a chunk's partial gradient is as large as the whole).
+
+    The exchange runs in a ``shard_map`` over ``axis`` alone (every
+    other mesh axis stays GSPMD's, so fsdp's gathers are the plain
+    text's) that is never transposed: each product is a
+    ``jax.custom_vjp`` around it. JAX's transpose of a ``shard_map``
+    could not be used: with ``check_vma`` it has no type for "equal on
+    both chips by an exchange", without it sums a replicated
+    cotangent once more with a blocking ``psum``."""
+    rowwise, colwise = P(axis, None), P(None, axis)
+    wide = P(None, None, axis)      # an activation's last dimension sharded
+    rows = jax.sharding.NamedSharding(mesh, rows)
+
+    def keep(x):
+        # a chunk lies over the other axes as the whole does: left to
+        # itself GSPMD reshards chunks of 512 rows or fewer with a
+        # blocking all-to-all (not those of 1,024 or more)
+        return lax.with_sharding_constraint(x, rows)
+
+    def exchanged(products, acts, in_specs):
+        """``concat_i sum_over_axis(products(chunk i of the first
+        ``acts`` arguments, the rest))`` of arguments laid out by
+        ``in_specs``; the result is equal on the chips of ``axis``."""
+        def local(*args):
+            def summed(part):
+                with jax.named_scope("tp.exchange"):
+                    return part + lax.ppermute(part, axis, ((0, 1), (1, 0)))
+            chunks = zip(*(map(keep, jnp.split(x, n, axis=1))
+                           for x in args[:acts]))
+            return jnp.concatenate([keep(summed(products(*c, *args[acts:])))
+                                    for c in chunks], axis=1)
+        return jax.shard_map(local, mesh=mesh, axis_names={axis},
+                             in_specs=in_specs, out_specs=P(),
+                             check_vma=False)
+
+    @jax.custom_vjp
+    def row(a, w):
+        return exchanged(jnp.matmul, 1, (wide, rowwise))(a, w)
+
+    def row_bwd(res, ct):
+        a, w = res
+        return (jnp.einsum("bsm,km->bsk", ct, w),
+                jnp.einsum("bsk,bsm->km", a, ct))
+    # named for ``_remat``'s "dots": no policy sees a product in here
+    row.defvjp(lambda a, w: (_checkpoint_name(row(a, w), "row_sum"), (a, w)),
+               row_bwd)
+
+    @jax.custom_vjp
+    def col(y, *ws):
+        return tuple(y @ w for w in ws)
+
+    def col_bwd(res, cts):
+        y, ws = res
+        k = len(ws)
+        dy = exchanged(
+            lambda *c: sum(jnp.einsum("bsm,km->bsk", ct, w)
+                           for ct, w in zip(c[:k], c[k:])),
+            k, (wide,) * k + (colwise,) * k)(*cts, *ws)
+        return (dy, *(jnp.einsum("bsk,bsm->km", y, ct) for ct in cts))
+    col.defvjp(lambda y, *ws: (col(y, *ws), (y, ws)), col_bwd)
+    return col, row
+
+
+def _layer_products(cfg: LlamaConfig, mesh: Optional[Mesh], axes: MeshAxes,
+                    rows: int):
+    """``(col, row)`` for a layer's weight products on ``mesh``:
+    ``col(y, *ws)`` the products of a norm's output with weights whose
+    last dimension ``tensor`` shards (wq / wk / wv; w_gate / w_up),
+    ``row(a, w)`` the product with one whose first it shards (wo,
+    w_down).
+
+    Where the sums over ``tensor`` are made. The weights are Megatron's
+    (``param_shardings``), so a ``row`` leaves partial sums on the
+    chips of ``tensor`` and a ``col`` takes partial cotangents back:
+    five sums of the whole residual stream a layer a step under
+    ``remat_policy`` ``full`` (the MLP's recomputed ``row`` is dead).
+
+    - No mesh, or a ``tensor`` axis of one: there is no sum.
+    - A ``tensor`` axis of TWO (the sequence on one chip, in
+      ``_tp_chunks`` chunks): ``_tensor_pair_products`` makes each sum
+      as chunked exchanges beside the next chunk's matmul.
+    - Anything else (a wider ``tensor`` axis, a ``context`` axis, heads
+      or rows that two does not divide): GSPMD closes the plain
+      products with its own ``all-reduce``, which this compiler runs
+      blocking. A ring of ``ppermute``s over the chunks is what would
+      extend the exchange to a wider axis."""
+    sizes = mesh.shape if mesh is not None else {}
+    n = _tp_chunks(rows)
+    if (sizes.get(axes.tensor, 1) == 2 and n
+            and sizes.get(axes.context, 1) == 1
+            and cfg.n_kv_heads % 2 == cfg.n_heads % 2 == cfg.ffn_dim % 2 == 0):
+        return _tensor_pair_products(mesh, axes.tensor, n,
+                                     P(axes.batch, axes.context, None))
+    return (lambda y, *ws: tuple(y @ w for w in ws)), jnp.matmul
+
+
 def forward_hidden(params: dict, tokens: jax.Array, cfg: LlamaConfig,
                    mesh: Optional[Mesh] = None,
                    axes: MeshAxes = MeshAxes()) -> jax.Array:
     """tokens: (batch, seq) int32 -> final NORMED hidden states
     (batch, seq, dim) — the pre-lm_head activations (the fused CE
-    consumes these chunk by chunk instead of full logits)."""
+    consumes these chunk by chunk instead of full logits).
+
+    On a mesh the residual stream is held replicated over ``tensor``
+    (``act_constraint`` after each sub-layer). A layer's sums over
+    ``tensor`` are made inside its ``col`` / ``row`` products
+    (``_layer_products``: chunked exchanges on a ``tensor`` pair, else
+    GSPMD's all-reduce where the constraint closes a plain product);
+    the embedding's gather and the head's backward sum are GSPMD's."""
     b, s = tokens.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -352,23 +496,23 @@ def forward_hidden(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     rope_cos, rope_sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
+    col, row = _layer_products(cfg, mesh, axes, s)
+
     # the scopes only name the ops in a device trace (metadata)
     def layer(x, lp):
         with jax.named_scope("attention"):
             y = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-            q = (y @ lp["wq"]).reshape(b, s, h, hd)
-            k = (y @ lp["wk"]).reshape(b, s, kvh, hd)
-            v = (y @ lp["wv"]).reshape(b, s, kvh, hd)
-            q = _rope(q, rope_cos, rope_sin)
-            k = _rope(k, rope_cos, rope_sin)
+            q, k, v = col(y, lp["wq"], lp["wk"], lp["wv"])
+            q = _rope(q.reshape(b, s, h, hd), rope_cos, rope_sin)
+            k = _rope(k.reshape(b, s, kvh, hd), rope_cos, rope_sin)
+            v = v.reshape(b, s, kvh, hd)
             o = _attend(q, k, v, cfg, mesh, axes).astype(x.dtype)
-            x = x + (o.reshape(b, s, h * hd) @ lp["wo"])
+            x = x + row(o.reshape(b, s, h * hd), lp["wo"])
             x = act_constraint(x, P(axes.batch, axes.context, None))
         with jax.named_scope("mlp"):
             y = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-            gate = jax.nn.silu(y @ lp["w_gate"])
-            up = y @ lp["w_up"]
-            x = x + ((gate * up) @ lp["w_down"])
+            gate, up = col(y, lp["w_gate"], lp["w_up"])
+            x = x + row(jax.nn.silu(gate) * up, lp["w_down"])
             x = act_constraint(x, P(axes.batch, axes.context, None))
         return x, None
 

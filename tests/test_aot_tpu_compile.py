@@ -720,15 +720,10 @@ def test_the_period_scan_reads_the_projections_stack_in_place(chip):
 
 # --- the linear-attention train cell's step ---------------------------------
 
-def _compile_cell_step(topo, file, model, **overrides):
-    """(compiled step, the state's bytes on one device, the deployment)
-    of ``benchmarks/configs/<file>``'s train step as its cell runs it:
-    ``make_train_step`` on the cell's mesh over the described chips, the
-    state's shardings those of ``init_fn``'s compiled outputs, batch and
-    rows the cell's, the flash kernels in."""
+def _cell_on_mesh(topo, file, **overrides):
+    """(config, mesh over the described chips, deployment) of
+    ``benchmarks/configs/<file>``'s train cell."""
     import json
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from ray_tpu.models import llama
     from ray_tpu.parallel import mesh as pmesh
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            os.pardir, "benchmarks", "configs", file)) as f:
@@ -738,6 +733,19 @@ def _compile_cell_step(topo, file, model, **overrides):
     sizes = {"data": 1, "context": 1, **dep["mesh"]}
     mesh = pmesh.make_mesh(pmesh.MeshSpec(**sizes),
                            devices=topo.devices[:math.prod(sizes.values())])
+    return cfg, mesh, dep
+
+
+def _compile_cell_step(topo, file, model, **overrides):
+    """(compiled step, the state's bytes on one device, the deployment)
+    of ``benchmarks/configs/<file>``'s train step as its cell runs it:
+    ``make_train_step`` on the cell's mesh over the described chips, the
+    state's shardings those of ``init_fn``'s compiled outputs, batch and
+    rows the cell's, the flash kernels in."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import mesh as pmesh
+    cfg, mesh, dep = _cell_on_mesh(topo, file, **overrides)
     init_fn, step_fn = pmesh.make_train_step(cfg, mesh, model=model)
     with mesh:
         init = init_fn.lower(jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
@@ -787,6 +795,34 @@ def _remat_matmuls(compiled) -> list:
             r"dot_general|convolution|kind=kOutput", rest)]
 
 
+def _layer_loops(text: str) -> list:
+    """The scheduled instructions of each computation of a compiled
+    train step's ``text`` that calls a flash kernel: the layer loops'
+    bodies, forward and backward."""
+    bodies = re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \([^\n]*\) -> [^\n]*\{\n)",
+                      text)
+    return [b.splitlines() for b in bodies if "flash_fwd" in b
+            and "tpu_custom_call" in b]
+
+
+def _chunk_exchanges(body: list, shape: str) -> list:
+    """For each ``collective-permute-start`` of ``shape`` in a scheduled
+    computation, the matmul fusions between it and its ``-done``."""
+    out = []
+    for i, ln in enumerate(body):
+        start = re.match(
+            rf"\s*%(collective-permute-start[\w.]*) = \({re.escape(shape)}",
+            ln)
+        if start:
+            done = next(j for j in range(i, len(body)) if re.search(
+                rf"collective-permute-done\(%{re.escape(start.group(1))}\)",
+                body[j]))
+            out.append([m.group(1) for m in (
+                re.match(r"\s*%([\w.-]+) = .* fusion\(.*(?:dot_general|"
+                         r"convolution)", x) for x in body[i:done]) if m])
+    return out
+
+
 @pytest.mark.parametrize("file", [
     "mistral-7b-v0.3-train.json", "yi-1.5-34b-train-4chip.json"],
     ids=["dense_1chip", "yi34b_4chip"])
@@ -798,12 +834,77 @@ def test_the_dense_cells_steps_alias_their_state_and_recompute_nothing(
     outputs, so its plan is the state ONCE plus the temporaries, fits the
     chip, and the compiler rematerialises no matmul to make it fit
     (undonated: 28 and 29 ``.remat`` instructions, three and ten of them
-    whole FFN / projection products a layer)."""
+    whole FFN / projection products a layer).
+
+    The same compile, PR 55: over the four chips' ``tensor`` pair no
+    layer sums the residual stream with an ``all-reduce`` (this compiler
+    runs one blocking: 5.7 ms each, 25 a step); each of a loop body's
+    sums is ``_tp_chunks`` exchanges of a chunk, and all but a sum's
+    last chunk have a matmul between their start and their wait. One
+    chip's step has no exchange and no chunk."""
     from ray_tpu.models import llama
     compiled, held, dep = _compile_cell_step(topo, file, llama)
     mem = _assert_state_aliased(compiled, held, dep)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9, mem
     assert not _remat_matmuls(compiled)
+    n = llama._tp_chunks(4096)
+    rows = dep["batch"] // dep["mesh"].get("fsdp", 1)
+    text = compiled.as_text()
+    loops = _layer_loops(text)
+    assert len(loops) == 2
+    if dep["mesh"].get("tensor", 1) == 1:
+        assert "collective-permute" not in text
+        assert f"bf16[{rows},{4096 // n}," not in text
+        return
+    stream = f"bf16[{rows},4096,7168]"
+    assert not [ln for body in loops for ln in body
+                if re.search(rf"= {re.escape(stream)}\S* all-reduce\(", ln)
+                or " all-to-all(" in ln]     # nor reshards a chunk
+    exchanges = [_chunk_exchanges(body, f"bf16[{rows},{4096 // n},7168]")
+                 for body in loops]
+    # forward: o @ wo and the MLP's down product; backward: the first
+    # again (remat) and both norms' cotangents
+    assert sorted(len(e) for e in exchanges) == [2 * n, 3 * n], exchanges
+    for e in exchanges:
+        assert sum(not hidden for hidden in e) <= len(e) // n, e
+
+
+def test_the_parity_slices_forward_exchanges_its_sums_too(topo):
+    """What decides ``correct`` in ``train-yi34b-4chip`` runs the changed
+    code: the harness's parity forward (``benchmarks/harness/train_cell.py
+    parity``: ``llama.forward`` on the cell's mesh over ``parity_tokens``
+    positions of one row a shard) makes each layer's two sums as four
+    exchanges of 256 rows, with no ``all-reduce`` of the stream in its
+    layer loop and no ``all-to-all`` (left to itself GSPMD reshards
+    chunks this small: ``_tensor_pair_products keep``)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import mesh as pmesh
+    cfg, mesh, dep = _cell_on_mesh(topo, "yi-1.5-34b-train-4chip.json")
+    rows, s = dep["mesh"]["fsdp"], dep["parity_tokens"]
+    init_fn, _ = pmesh.make_train_step(cfg, mesh, model=llama)
+    with mesh:
+        init = init_fn.lower(jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+    params = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)),
+        init.output_shardings).params
+    tokens = jax.ShapeDtypeStruct(
+        (rows, s), jnp.int32,
+        sharding=NamedSharding(mesh, P(("data", "fsdp"), "context")))
+    was = llama._on_tpu
+    llama._on_tpu = lambda: True
+    try:
+        with mesh:
+            text = jax.jit(lambda p, t: llama.forward(p, t, cfg, mesh)).lower(
+                params, tokens).compile().as_text()
+    finally:
+        llama._on_tpu = was
+    n = llama._tp_chunks(s)
+    (loop,) = _layer_loops(text)
+    assert not [ln for ln in loop if re.search(
+        rf"= bf16\[1,{s},7168\]\S* all-reduce\(", ln) or " all-to-all(" in ln]
+    assert len(_chunk_exchanges(loop, f"bf16[1,{s // n},7168]")) == 2 * n
 
 
 def test_the_linear_attention_cells_step_compiles_for_v5e(topo):

@@ -1,5 +1,6 @@
 """Flagship model: forward/loss correctness and sharded training step."""
 
+import re
 import warnings
 
 import jax
@@ -185,3 +186,101 @@ def test_sharded_step_donates_every_leaf(mesh8, family):
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(state2))
     assert jax.tree.map(lambda a: a.sharding, state3) == shardings
     assert np.isfinite(float(met["loss"])) and int(state3.step) == 2
+
+
+# --- a layer's sums over a ``tensor`` pair (PR 55) ---------------------------
+
+def _permutes(text: str) -> int:
+    """``collective-permute`` instructions of a compiled program."""
+    return len(re.findall(r" collective-permute(?:-start)?\(", text))
+
+
+def _instructions(text: str) -> list:
+    """A compiled program's instructions without their source lines."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", ln)
+            for ln in text.splitlines() if " = " in ln]
+
+
+def _loss_and_grads(cfg, mesh, s, monkeypatch=None):
+    """(loss, grads, the compiled text) of ``llama.loss_fn`` on ``mesh``
+    over ``s`` positions, the params laid out by ``param_shardings``.
+    With ``monkeypatch`` the sums are left to GSPMD's own all-reduces
+    (``_tp_chunks`` 0: the parent's path, what the exchange is held
+    to)."""
+    from jax.sharding import NamedSharding
+    if monkeypatch is not None:
+        monkeypatch.setattr(llama, "_tp_chunks", lambda rows: 0)
+    params = jax.tree.map(
+        lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec)),
+        llama.init_params(jax.random.PRNGKey(0), cfg),
+        llama.param_shardings(cfg))
+    batch = _batch(jax.random.PRNGKey(1), cfg, b=4, s=s)
+    fn = jax.value_and_grad(
+        lambda p, b: llama.loss_fn(p, b, cfg, mesh))
+    with mesh:
+        compiled = jax.jit(fn).lower(params, batch).compile()
+        loss, grads = compiled(params, batch)
+    return loss, grads, compiled.as_text()
+
+
+def _tp_mesh(fsdp, tensor):
+    return make_mesh(MeshSpec(data=1, fsdp=fsdp, tensor=tensor, context=1),
+                     devices=jax.devices()[:fsdp * tensor])
+
+
+@pytest.mark.parametrize("remat", ["full", "none", "dots", "attn"])
+@pytest.mark.parametrize("chunks", [2, 4])
+@pytest.mark.parametrize("fsdp", [1, 2])
+def test_a_tensor_pair_exchanges_what_gspmd_all_reduces(
+        fsdp, chunks, remat, monkeypatch):
+    """With a ``tensor`` axis of two a layer's sums over it are chunked
+    ``ppermute`` exchanges under hand-written conjugate operators
+    (``check_vma`` is off, so neither JAX's convention for the
+    transpose of a replicated value is trusted): loss and every
+    gradient leaf are those of the same step through GSPMD's
+    all-reduces, and the compiled step exchanges five times a chunk
+    where the backward recomputes the products (``full``, ``attn``:
+    attention forward, its remat, MLP forward; two backward: the MLP's
+    remat is dead code) and four where it keeps them (``none``;
+    ``dots``, which saves an exchanged sum by its name as it saves
+    GSPMD's product)."""
+    cfg = llama.tiny(dtype="float32", n_heads=4, n_kv_heads=2,
+                     remat_policy=remat)
+    mesh = _tp_mesh(fsdp, 2)
+    s = {4: 64, 2: 66}[chunks]      # four divides the rows, or only two
+    assert llama._tp_chunks(s) == chunks
+    loss, grads, text = _loss_and_grads(cfg, mesh, s)
+    want_loss, want, plain = _loss_and_grads(cfg, mesh, s, monkeypatch)
+    # a scan's body is compiled once; GSPMD has permutes of its own
+    # where fsdp shards the batch
+    assert _permutes(text) - _permutes(plain) == chunks * (
+        5 if remat in ("full", "attn") else 4)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        assert g.sharding == w.sharding, path
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2e-5 * scale, err_msg=str(path))
+
+
+@pytest.mark.parametrize("tensor,s", [(1, 64), (4, 64), (2, 63)])
+def test_other_tensor_axes_keep_the_plain_text(tensor, s, monkeypatch):
+    """A ``tensor`` axis of one has no sum to make, one of four is
+    GSPMD's (a ring of exchanges is what would extend the pair's) and
+    so is a pair's over rows that two does not divide: what is compiled
+    is what is compiled with the exchange forced off."""
+    cfg = llama.tiny(dtype="float32", n_heads=4, n_kv_heads=4)
+    mesh = _tp_mesh(2, tensor)
+    loss, _, text = _loss_and_grads(cfg, mesh, s)
+    want_loss, _, plain = _loss_and_grads(cfg, mesh, s, monkeypatch)
+    assert _instructions(text) == _instructions(plain)
+    assert float(loss) == float(want_loss)
+
+
+def test_the_chunks_are_read_from_the_rows():
+    """Four chunks where four divides the rows, else two, else GSPMD's
+    all-reduce: the harness's parity slice (1,024 rows) and these
+    tests' 64 take the exchange as the cell's 4,096 do."""
+    assert [llama._tp_chunks(r) for r in (4096, 1024, 64, 2050, 66, 63,
+                                          1)] == [4, 4, 4, 2, 2, 0, 0]
