@@ -40,11 +40,11 @@ logger = logging.getLogger("ray_tpu.llm.engine")
 # (``engine.<phase>`` in the profiler's host plane) and one histogram
 # (``llm_loop_<phase>_s``); none encloses another, and what lies
 # between two of them is thread hops and other coroutines.
-PHASES = ("admit.alloc", "prefill.dispatch", "prefill.wait",
-          "prefill.sample", "decode.prepare", "decode.dispatch",
-          "decode.readback", "decode.account", "verify.prepare",
-          "verify.dispatch", "verify.readback", "verify.accept", "emit",
-          "yield", "idle")
+PHASES = ("admit.alloc", "prefill.dispatch", "prefill.behind",
+          "prefill.wait", "prefill.sample", "decode.prepare",
+          "decode.dispatch", "decode.readback", "decode.account",
+          "verify.prepare", "verify.dispatch", "verify.readback",
+          "verify.accept", "emit", "yield", "idle")
 # a phase (other than idle) over this long leaves a slow_phase event
 SLOW_PHASE_S = 1.0
 
@@ -81,7 +81,9 @@ def engine_metrics() -> dict:
       llm_ttft_device_s  prefill dispatch -> its results ready: the
                          prefill's device compute and, ahead of it on
                          the device, what is left of the decode block
-                         in flight it was enqueued behind
+                         in flight it was enqueued behind. That part is
+                         llm_loop_prefill_behind_s: the prefill's own
+                         device time is this less that
       llm_ttft_wall_s    submit -> first token, wall clock
       llm_tpot_s         decode wall time per output token
       llm_request_tpot_s (last emit - first emit) / (tokens - 1) of a
@@ -89,6 +91,12 @@ def engine_metrics() -> dict:
                          engine's clock: the inside twin of a client's
                          time per output token (also ``tpot_s`` on the
                          request's engine/generate span)
+      llm_request_tpot_stall_s  of that, what the request's slot
+                         stalled between blocks: the llm_decode_gap_s
+                         observed from its first block's dispatch to
+                         its last emit, over (tokens - 1) (also
+                         ``tpot_stall_s`` on the span, beside
+                         ``stall_s`` and ``stall_admit_s``)
       llm_batch_size     active decode slots per step block
       llm_stream_lag_s   a streamed token's wait between the loop's
                          emit and its generate_stream consumer
@@ -103,8 +111,13 @@ def engine_metrics() -> dict:
       llm_loop_<phase>_s       one per PHASES entry (dots as
                                underscores): seconds per span.
                                admit.alloc (block reservation),
-                               prefill.dispatch / prefill.wait /
-                               prefill.sample (executor thread),
+                               prefill.dispatch / prefill.behind /
+                               prefill.wait / prefill.sample (executor
+                               thread; prefill.behind is the wait for
+                               the decode block in flight the prefill
+                               was enqueued behind, entered only when
+                               there is one: the block's own time, not
+                               the prefill's),
                                decode.prepare (block size, inputs, the
                                window layers' blocks), decode.dispatch
                                / decode.readback (executor thread: the
@@ -116,14 +129,34 @@ def engine_metrics() -> dict:
                                emit (tokens to their requests), yield
                                (one turn of the event loop: the stream
                                consumers wake in it), idle (parked)
-      llm_decode_gap_s         previous block's read-back end -> this
-                               block's dispatch, while a request went
-                               on decoding: what a decoding slot stalls
-                               between blocks. 0 for a block enqueued
-                               before its predecessor was read back
-                               (the device went from one to the other)
+      llm_decode_gap_s         what a decoding slot stalls between two
+                               blocks, once a block that carries a
+                               request on: from the END of the block
+                               before on the device to the return of
+                               this block's launch. The end is known
+                               where an admission looked or waited for
+                               it (prefill.behind's exit, or a look
+                               during prefill.dispatch that found it
+                               ended: the prompt's prefill, the host's
+                               sampling, emit, prepare, hop and launch
+                               then lie between the two blocks); where
+                               nobody did, the block was enqueued
+                               behind its predecessor, the device went
+                               from one to the other, and the gap is 0.
+                               With nothing in flight (a slot that
+                               holds a drafter) it runs from the
+                               read-back's end
       llm_decode_gap_admit_s   of that gap, the part inside
                                engine.admit.* and engine.prefill.*
+                               (prefill.behind is not: it ends where
+                               the gap starts)
+      llm_decode_block_window_s  a block's own window: from its
+                               predecessor's read-back end (its own
+                               dispatch after idle) to its read-back
+                               end. Windows tile the time between
+                               read-backs, so over steps the sum is a
+                               step's device time + the gap + what is
+                               left around a step
       llm_decode_ahead_size    decode blocks enqueued and not yet read
                                back as a block is enqueued: 1 when it
                                went out ahead of its predecessor's
@@ -192,14 +225,21 @@ def engine_metrics() -> dict:
         **loop,
         "gap": m.Histogram(
             "llm_decode_gap_s",
-            "Previous decode block's read-back end to this block's "
-            "dispatch, observed when a request of the previous block "
-            "is still decoding; 0 when this block was enqueued before "
-            "that read-back", boundaries=seconds),
+            "The previous decode block's end on the device (learned by "
+            "the admission that looked or waited for it) to the return "
+            "of this block's launch, observed when a request of the "
+            "previous block is still decoding; 0 when nothing came "
+            "between the two blocks on the device", boundaries=seconds),
         "gap_admit": m.Histogram(
             "llm_decode_gap_admit_s",
             "The part of llm_decode_gap_s spent inside engine.admit.* "
-            "and engine.prefill.* spans", boundaries=seconds),
+            "and engine.prefill.* spans (engine.prefill.behind ends "
+            "where the gap starts)", boundaries=seconds),
+        "block_window": m.Histogram(
+            "llm_decode_block_window_s",
+            "A decode block's own window: its predecessor's read-back "
+            "end (its own dispatch after idle) to its read-back end",
+            boundaries=seconds),
         "decode_ahead": m.Histogram(
             "llm_decode_ahead_size",
             "Decode blocks enqueued and not yet read back as a block is "
@@ -288,6 +328,13 @@ def engine_metrics() -> dict:
             "request of two tokens or more, on the engine's clock",
             boundaries=(.0005, .001, .0025, .005, .01, .025, .05, .1,
                         .25, .5, 1, 2.5)),
+        "request_tpot_stall": m.Histogram(
+            "llm_request_tpot_stall_s",
+            "The llm_decode_gap_s seconds observed between a finished "
+            "request's first decode block's dispatch and its last emit, "
+            "over (tokens - 1): the stalled part of llm_request_tpot_s",
+            boundaries=(.0001, .00025, .0005, .001, .0025, .005, .01,
+                        .025, .05, .1, .25, .5, 1, 2.5)),
         "queue": m.Histogram(
             "llm_queue_s",
             "Wait from request submission to slot admission"),
@@ -296,7 +343,7 @@ def engine_metrics() -> dict:
             "Prefill dispatch to its results ready (forward + cache "
             "write, block_until_ready-bounded): the device time producing "
             "the first token, behind what is left of the decode block in "
-            "flight"),
+            "flight (llm_loop_prefill_behind_s)"),
         "ttft_wall": m.Histogram(
             "llm_ttft_wall_s",
             "Wall time from submission to first token"),
@@ -344,6 +391,11 @@ class _Request:
     # first_token_at, the engine's own time per output token
     last_token_at: Optional[float] = None
     emitted: int = 0
+    # the engine's stall totals (LLMEngine._stall) as the first decode
+    # block the request is a member of was dispatched: _finish reads
+    # them again at the last emit, and the difference is what the
+    # request's slot stalled between blocks
+    stall_mark: Optional[tuple] = None
     prefill_device_s: float = 0.0           # block_until_ready-bounded
     # request trace context ambient at submission (the serve replica
     # binds it before user code): engine queue/prefill/generate spans
@@ -395,6 +447,11 @@ class _Block:
     # stamped by the executor thread: dispatch start, read-back end
     t_disp: float = 0.0
     t_back: float = 0.0
+    # when the block ended on the device, as far as the host learned it
+    # (LLMEngine._block_ended: an admission looked, or waited, for it);
+    # 0.0 when nobody did: its successor was enqueued behind it and
+    # followed at once
+    t_end: float = 0.0
     # device results: (steps, slots) tokens, the expert layers' counts,
     # the last row (the successor's first tokens)
     out: object = None
@@ -625,10 +682,14 @@ class LLMEngine:
         # the stall between two decode blocks: where the last read-back
         # ended (None when no request carried over, or when the next
         # block was enqueued before it), the admit/prefill seconds
-        # since, and the device interval of the last block read back
+        # since then or since the block in flight ended (_Block.t_end),
+        # and the device interval of the last block read back
         self._gap_from: Optional[float] = None
         self._gap_admit = 0.0
         self._dev_span = (0.0, 0.0)
+        # every llm_decode_gap_s / llm_decode_gap_admit_s observation
+        # so far, summed: a request's marks are taken from these
+        self._stall = (0.0, 0.0)
         self._kv_account()
         self._requests = 0
         self._tokens_generated = 0
@@ -679,10 +740,13 @@ class LLMEngine:
         ``engine.<name>`` annotation, its ``llm_loop_*`` histogram and
         the stamps, all from tracing.phase's one pair of clock reads.
         Admission and prefill time also accrues to the decode gap it
-        sits in, and a phase over SLOW_PHASE_S names itself."""
+        sits in (not prefill.behind: that is the decode block's own
+        time, and the gap starts at its exit), and a phase over
+        SLOW_PHASE_S names itself."""
         with tracing.phase(*self._phases[name]) as ph:
             yield ph
-        if name.startswith(("admit.", "prefill.")):
+        if name.startswith(("admit.", "prefill.")) \
+                and name != "prefill.behind":
             self._gap_admit += ph.dur
         if ph.dur > SLOW_PHASE_S and name != "idle":
             active = sum(r is not None for r in self._slots)
@@ -1264,13 +1328,18 @@ class LLMEngine:
     def _record_block(self, slots: int, tokens_per_slot: float,
                       member_traces: List[str], first_ctx, **attrs):
         """What one decode block (or verify round) leaves behind, all
-        from the ONE interval its executor phases stamped (dispatch
-        start to read-back end): the batch-size and TPOT observations,
-        one span linked to every member trace (the block is shared
-        compute, so each member's waterfall pulls it in via the
-        links; it names the attention impl the block ran), and the
-        same interval as a device-compute window for the duty-cycle
-        estimator. The EXEMPLAR can only name one trace: the member
+        from the ONE interval ``_dev_span`` holds, the block's own
+        window (its predecessor's read-back end, or its own dispatch
+        start where nothing was in flight, to its read-back end; a
+        verify round's dispatch start to read-back end): the
+        batch-size and TPOT observations, the window itself
+        (``llm_decode_block_window_s``: llm_tpot_s sums per-block
+        ratios and cannot be divided by steps, this sum can), one span
+        linked to every member trace (the block is shared compute, so
+        each member's waterfall pulls it in via the links; it names
+        the attention impl the block ran), and the same interval as a
+        device-compute window for the duty-cycle estimator. The
+        EXEMPLAR can only name one trace: the member
         whose context was bound on the executor thread, so following
         it (`ray-tpu trace <id>`) shows any decode-path compile span
         stamped during this block, not a sibling's waterfall."""
@@ -1279,6 +1348,7 @@ class LLMEngine:
         self._m["batch"].observe(slots, exemplar=ex)
         self._m["tpot"].observe((t1 - t0) / tokens_per_slot,
                                 exemplar=ex)
+        self._m["block_window"].observe(t1 - t0)
         w0, w1 = tracing.wall(t0), tracing.wall(t1)
         tracing.record_batch_span(
             "engine", "decode", member_traces, w0, w1, slots=slots,
@@ -1343,6 +1413,7 @@ class LLMEngine:
         for kind, row in r.kv_alloc["tables"].items():
             self._tables[kind][slot] = row
         with self._phase("prefill.dispatch") as disp:
+            self._block_ended(disp.t0)
             if r.prefilled is not None:
                 # device TTFT for a disaggregated request is the
                 # handoff resolution + pool write on THIS engine
@@ -1384,7 +1455,7 @@ class LLMEngine:
                 self._count_prefill(
                     1, lm.chunk_expanded_rows(self.cfg, b, 0, b))
             else:
-                logits = self._prefill_into_blocks(r, hit, slot)
+                logits = self._prefill_into_blocks(r, hit, slot, disp.t0)
                 ran = n - self._prefill_start(hit)
         return self._first_token(slot, r, disp, logits, ran)
 
@@ -1420,8 +1491,23 @@ class LLMEngine:
         Dispatch is async, so the wall clock alone cannot attribute a
         slow first token to compute or to queueing: dispatch start to
         wait end bounds the DEVICE portion of TTFT. ``ran`` is how
-        many prompt tokens went through a prefill forward."""
+        many prompt tokens went through a prefill forward.
+
+        The prefill was enqueued behind the decode block in flight, if
+        there is one, so the wait is cut in two: ``prefill.behind``
+        waits for THAT block's outputs, and its exit is when the block
+        ended on the device, unless a look during the dispatch found it
+        ended already (``_block_ended``). ``llm_ttft_device_s`` still
+        runs from the dispatch to the results, so the prefill's own
+        device time is that less ``prefill.behind`` (and, for a chunked
+        prefill, less the part of the block that its first chunk's
+        launch sat out inside ``prefill.dispatch``)."""
         jax, _ = _jx()
+        fl = self._inflight
+        if fl is not None:
+            with self._phase("prefill.behind") as behind:
+                jax.block_until_ready((fl.out, fl.last))
+            self._block_ended(behind.t1, at=behind.t1)
         with self._phase("prefill.wait") as wait:
             logits_np = np.asarray(logits)
             jax.block_until_ready(kvcache.pool_k(self._pool))
@@ -1433,6 +1519,32 @@ class LLMEngine:
         self._slots[slot] = r
         with self._phase("prefill.sample"):
             return self._sample_one(logits_np, r)
+
+    def _block_ended(self, opened: float,
+                     at: Optional[float] = None) -> None:
+        """The host learns when the decode block in flight ended on the
+        device (``_Block.t_end``): where the stall of the slots that go
+        on decoding starts (``_dispatch``). Called by an admission
+        (executor thread) wherever it can know: with ``at`` the exit of
+        the ``prefill.behind`` that waited for the block; without, a
+        look that does not wait, at the opening of ``prefill.dispatch``
+        and after each chunk's launch. The stamp is late by at most the
+        launch before the look (6-9 ms for a chunk on a v5e, ~4 ms for
+        a one-forward prefill: the block may have ended under it), and
+        it is taken once a block: a second admission of the same turn
+        finds it taken. The gap's admission share starts with it: the
+        phase the look was made in (open since ``opened``) adds its
+        WHOLE duration to ``_gap_admit`` at its exit, so what came
+        before the stamp is taken off here."""
+        fl = self._inflight
+        if fl is None or fl.t_end:
+            return
+        if at is None:
+            if not fl.out.is_ready():
+                return
+            at = time.monotonic()
+        fl.t_end = at
+        self._gap_admit = opened - at
 
     def _acc_len(self) -> int:
         """Accumulator length for block-table prefill: the full table
@@ -1464,7 +1576,8 @@ class LLMEngine:
         chunk = self.buckets[-1]
         return (hit // chunk) * chunk
 
-    def _prefill_into_blocks(self, r: _Request, hit: int, slot: int):
+    def _prefill_into_blocks(self, r: _Request, hit: int, slot: int,
+                             t_disp: float):
         """Prefix-hit (and long-prompt) prefill: gather the table's
         cached blocks into a contiguous accumulator, run the suffix
         through lm.prefill_chunk at the prefix offset (pieces aligned
@@ -1472,7 +1585,16 @@ class LLMEngine:
         every suffix row identically — the bitwise-parity contract the
         tests pin), then scatter the NEW positions' KV back into the
         request's own blocks. Shared prefix blocks are never written
-        (their scatter targets are the trash block)."""
+        (their scatter targets are the trash block).
+
+        A chunk's launch returns only once the accumulator it is
+        donated is ready, that is once the program before it has run
+        (on a v5e: PERF.md, PR 56): the first chunk's launch sits out
+        what is left of the decode block in flight, and each later one
+        the chunk before it. So the block's end is looked for after
+        every launch (``_block_ended``; ``t_disp`` is where the
+        enclosing ``prefill.dispatch`` opened), and not first at the
+        end of a dispatch that lasts as long as the prefill."""
         _, jnp = _jx()
         n = len(r.tokens)
         chunk = self.buckets[-1]
@@ -1490,6 +1612,7 @@ class LLMEngine:
             logits, acc = lm.prefill_chunk(
                 self.params, jnp.asarray(padded),
                 jnp.int32(len(part)), jnp.int32(off), acc, self.cfg)
+            self._block_ended(t_disp)
             chunks += 1
             rows += lm.chunk_expanded_rows(self.cfg, b, off,
                                            self._acc_len())
@@ -1540,22 +1663,20 @@ class LLMEngine:
     def _dispatch(self, blk: _Block, fl: Optional[_Block]) -> None:
         """Enqueue ``blk`` (asynchronous: the call returns once the
         program is launched). ``fl`` is the block in flight, not read
-        back yet: the device starts ``blk`` the moment it ends."""
+        back yet: the device starts ``blk`` the moment it ends, unless
+        an admission's prefill was enqueued between them.
+
+        Once the launch has returned, the stall of the slots that go on
+        decoding is observed (``llm_decode_gap_s`` and its admission
+        share, once a block that carries a request on), added to the
+        engine's running totals, and every request new to a block takes
+        its mark of them (``_finish`` reads the totals again: a
+        request's marks leave out the tail of the one gap during which
+        it was itself admitted)."""
         jax, jnp = _jx()
+        # keep is set only for slots of the block in flight
+        carried = bool(blk.keep.any())
         with self._phase("decode.dispatch") as disp:
-            # keep is set only for slots of the block in flight
-            carried = bool(blk.keep.any())
-            if carried:
-                # a request of the block in flight goes on in this
-                # one, enqueued before that one is read back: the
-                # slot does not stall between them
-                self._m["gap"].observe(0.0)
-                self._m["gap_admit"].observe(0.0)
-            elif fl is None and self._gap_from is not None:
-                # a request of the last block has been waiting for
-                # this one since that block was read back
-                self._m["gap"].observe(disp.t0 - self._gap_from)
-                self._m["gap_admit"].observe(self._gap_admit)
             self._m["decode_ahead"].observe(0 if fl is None else 1)
             blk.t_disp = disp.t0
             self._step += blk.steps
@@ -1589,6 +1710,28 @@ class LLMEngine:
                     tp, tk)
             self._kvm["attn_steps"].inc(
                 blk.steps, tags={"impl": self._kv_impl})
+        gap = None
+        if carried:
+            # a request of the block in flight goes on in this one.
+            # Where an admission waited for that block the host knows
+            # when it ended, and the slot stalled from there until this
+            # launch returned; where nobody did, this block was
+            # enqueued behind it and the device went from one to the
+            # other
+            gap = (max(0.0, disp.t1 - fl.t_end), self._gap_admit) \
+                if fl.t_end else (0.0, 0.0)
+        elif fl is None and self._gap_from is not None:
+            # a request of the last block has been waiting for this one
+            # since that block was read back
+            gap = (max(0.0, disp.t1 - self._gap_from), self._gap_admit)
+        if gap is not None:
+            self._m["gap"].observe(gap[0])
+            self._m["gap_admit"].observe(gap[1])
+            self._stall = (self._stall[0] + gap[0],
+                           self._stall[1] + gap[1])
+        for r in blk.reqs.values():
+            if r.stall_mark is None:
+                r.stall_mark = self._stall
 
     def _readback(self, blk: _Block) -> None:
         """Wait for ``blk`` and copy its tokens (and the expert layers'
@@ -1749,25 +1892,23 @@ class LLMEngine:
                 or (r.eos_id is not None and tok == r.eos_id)):
             self._finish(r, slot)
 
-    def _record_done(self, r: _Request, error: bool,
-                     tpot_s: Optional[float] = None) -> None:
+    def _record_done(self, r: _Request, error: bool, **extra) -> None:
         """Terminal engine span for one request: submit -> done, with
-        the produced token count, the engine's own time per output
-        token (``tpot_s``, from _finish) and the request's KV
-        high-watermark (prompt + generated positions priced at the
-        cache's per-token bytes) — the trace drill-down shows what the
-        request cost in HBM, not just time. Recorded at most once
-        (finish, fail, and the loop's shutdown sweep can all reach a
-        request)."""
+        the produced token count, what _finish measured (``extra``: the
+        engine's own time per output token ``tpot_s`` and the stalled
+        part of it, ``stall_s`` / ``stall_admit_s`` / ``tpot_stall_s``)
+        and the request's KV high-watermark (prompt + generated
+        positions priced at the cache's per-token bytes) — the trace
+        drill-down shows what the request cost in HBM, not just time.
+        Recorded at most once (finish, fail, and the loop's shutdown
+        sweep can all reach a request)."""
         # the accept-rate gauge tracks every finished speculative
         # request, traced or not (the span extra below needs a trace)
         if r.spec_drafted and self._specm is not None:
             self._specm["accept_rate"].set(r.spec_accepted / r.spec_drafted)
         if r.trace is None:
             return
-        extra = {"prefix_hit_tokens": r.prefix_hit}
-        if tpot_s is not None:
-            extra["tpot_s"] = tpot_s
+        extra["prefix_hit_tokens"] = r.prefix_hit
         if r.handoff_bytes:
             extra["kv_handoff_bytes"] = r.handoff_bytes
         if r.spec_drafted:
@@ -1829,16 +1970,32 @@ class LLMEngine:
             release()
 
     def _finish(self, r: _Request, slot: Optional[int]):
-        # the engine's own measure of a token, from ONE pair of stamps
-        # to two sinks: the histogram and the request's generate span
-        # (a median over requests survives a stalled window, a
-        # histogram's sum does not)
-        tpot_s = None
+        """A request's normal end, at its last emit.
+
+        The engine's own measure of a token, from ONE pair of stamps to
+        two sinks: the histogram and the request's generate span (a
+        median over requests survives a stalled window, a histogram's
+        sum does not). Beside it, what of that time the request's slot
+        stalled between blocks: the engine's stall totals now less the
+        request's mark of them (``_dispatch``), whole (``stall_s``),
+        inside admissions (``stall_admit_s``) and a token
+        (``tpot_stall_s``, the same denominator as ``tpot_s``). The
+        marks leave out the tail of the one gap during which the
+        request itself was admitted, and a request that was in no decode
+        block (one token; a speculative engine's verify rounds) reads
+        0."""
+        mark = r.stall_mark or self._stall
+        done = {"stall_s": self._stall[0] - mark[0],
+                "stall_admit_s": self._stall[1] - mark[1]}
         if r.emitted >= 2:
-            tpot_s = (r.last_token_at - r.first_token_at) / (r.emitted - 1)
-            self._m["request_tpot"].observe(
-                tpot_s, exemplar=r.trace.trace_id if r.trace else None)
-        self._record_done(r, error=False, tpot_s=tpot_s)
+            ex = r.trace.trace_id if r.trace else None
+            n = r.emitted - 1
+            done["tpot_s"] = (r.last_token_at - r.first_token_at) / n
+            done["tpot_stall_s"] = done["stall_s"] / n
+            self._m["request_tpot"].observe(done["tpot_s"], exemplar=ex)
+            self._m["request_tpot_stall"].observe(done["tpot_stall_s"],
+                                                  exemplar=ex)
+        self._record_done(r, error=False, **done)
         self._free_kv(r, slot)
         if slot is not None and self._slots[slot] is r:
             self._slots[slot] = None

@@ -58,7 +58,13 @@ holds 8,192 events):
                    coming back for the next)
   engine/generate  ``tpot_s`` ((last emit - first emit) / (tokens - 1)
                    on the engine's clock; any request of two tokens or
-                   more)
+                   more); ``stall_s`` (what the request's slot stalled
+                   between decode blocks, from its first block's
+                   dispatch to its last emit: the llm_decode_gap_s
+                   observed meanwhile), ``stall_admit_s`` (the part of
+                   it inside other requests' admissions and prefills)
+                   and ``tpot_stall_s`` (``stall_s`` / (tokens - 1):
+                   the stalled part of ``tpot_s``)
 ``stream_attrs`` renders them as the one line `ray-tpu trace <id>`
 prints beside such a span.
 """
@@ -426,7 +432,8 @@ _REQUEST_SPAN_ARGS = ("trace", "span", "parent", "seg", "status",
                       "error", "links", "step", "block", "slots",
                       "tokens", "attempt", "replica", "kv_bytes",
                       "first_token_s", "get_s", "free_s", "write_s",
-                      "t_first", "t_last", "items", "push_s", "tpot_s")
+                      "t_first", "t_last", "items", "push_s", "tpot_s",
+                      "stall_s", "stall_admit_s", "tpot_stall_s")
 
 
 def stream_attrs(e: dict) -> str:
@@ -435,6 +442,9 @@ def stream_attrs(e: dict) -> str:
     out = []
     if e.get("tpot_s") is not None:
         out.append(f"{e['tpot_s'] * 1e3:.3f} ms/token")
+    if e.get("tpot_stall_s") is not None:
+        out.append(f"{e['tpot_stall_s'] * 1e3:.3f} of them stalled "
+                   "between blocks")
     if e.get("items"):
         out.append(f"{e['items']} items, push "
                    f"{e.get('push_s', 0.0) / e['items'] * 1e6:.0f} us each")

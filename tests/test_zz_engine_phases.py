@@ -1,8 +1,8 @@
 """The serving engine's loop on the profiler's clock: the one span
 primitive (util/tracing.phase), the flat engine.* leaf spans, and the
 work the loop counts where it is done (llm_decode_*, llm_prefill_tokens,
-llm_decode_gap_*). Counts are exact on the CPU; times are not speed
-results."""
+llm_decode_gap_*, a finished request's stalled share). Counts are exact
+on the CPU; times are not speed results."""
 
 import asyncio
 import glob
@@ -175,7 +175,9 @@ def test_every_loop_phase_is_observed_and_stream_lag_counts_tokens(
     assert d["stream_lag_count"] == 9 and d["stream_lag_sum"] >= 0
     for p in PHASES:
         key = "loop_" + p.replace(".", "_")
-        if p.startswith("verify."):         # no speculative decoding here
+        # no speculative decoding here, and the one admission found no
+        # decode block in flight to wait behind
+        if p.startswith("verify.") or p == "prefill.behind":
             assert d[key + "_count"] == 0
         else:
             assert d[key + "_count"] > 0, p
@@ -253,6 +255,147 @@ def test_a_block_in_flight_is_counted_once_a_block(tiny_model, spec,
     events.clear()
 
 
+# --- the stall between two blocks: a block, a step, a finished request ----
+
+
+@pytest.mark.parametrize("case", ["admitted_while_decoding", "alone",
+                                  "spec"])
+def test_a_slot_stalls_where_an_admission_came_between_two_blocks(
+        tiny_model, case, monkeypatch):
+    """PR 56. A request admitted while another decodes has its prefill
+    enqueued between the block in flight and the next: the admission
+    waits for that block (``prefill.behind``, once an admission that
+    found one), its exit is where the decoding slot's stall starts, and
+    the stall is counted a block (``gap`` / ``gap_admit``), inside the
+    blocks' windows (``block_window``) and on the finished request
+    (``stall_s`` / ``stall_admit_s`` / ``tpot_stall_s`` of its generate
+    span, ``request_tpot_stall``). A request that decodes alone stalls
+    nowhere, and an engine whose slots hold drafters (``spec``) leaves
+    nothing in flight: no admission waits behind a block, and its gaps
+    are the read-back-to-launch ones they were."""
+    from ray_tpu.llm import LLMEngine
+    found = []      # an admission: was a decode block in flight?
+    real = LLMEngine._first_token
+
+    def first_token(self, *a, **kw):
+        found.append(self._inflight is not None)
+        return real(self, *a, **kw)
+    monkeypatch.setattr(LLMEngine, "_first_token", first_token)
+    tids = {"long": "6a" * 16, "late": "6b" * 16}
+
+    async def traced(eng, who, prompt, new):
+        tok = tracing.set_request_context(
+            tracing.TraceContext(tids[who], tracing.new_span_id()))
+        try:
+            return await eng.generate(prompt, max_new_tokens=new)
+        finally:
+            tracing.reset_request_context(tok)
+
+    async def go():
+        eng = _engine(tiny_model, prefix_cache=False, spec=case == "spec")
+        await eng.generate([9, 8, 7], max_new_tokens=6)     # compile
+        found.clear()
+        events.clear()
+        before = _totals()
+        base = eng.stats["tokens_generated"]
+        long = asyncio.ensure_future(
+            traced(eng, "long", [3, 5, 7, 11], 58))
+        if case != "alone":
+            # the first block has been read back: from here to the long
+            # request's end a plain engine always has one in flight
+            while eng.stats["tokens_generated"] < base + 5:
+                await asyncio.sleep(0)
+            await traced(eng, "late", [2, 9, 4], 6)
+        await long
+        await eng.stop()
+        return _delta(before, _totals())
+
+    d = asyncio.run(go())
+    spans = {e["trace"]: e for e in events.dump()
+             if e.get("cat") == "request" and e.get("seg") == "generate"}
+    events.clear()
+    long, late = spans[tids["long"]], spans.get(tids["late"])
+    assert d["loop_prefill_behind_count"] == sum(found)
+    assert d["block_window_count"] == d["batch_count"] > 0
+    assert d["block_window_sum"] >= d["gap_sum"] >= d["gap_admit_sum"] >= 0
+    assert d["gap_count"] == d["gap_admit_count"]
+    for e in spans.values():
+        n = e["tokens"] - 1
+        assert 0 <= e["stall_admit_s"] <= e["stall_s"] <= d["gap_sum"] + 1e-9
+        # the stalls lie between the request's first and last emit
+        assert e["stall_s"] <= e["tpot_s"] * n + 1e-9
+        assert e["tpot_stall_s"] == pytest.approx(e["stall_s"] / n)
+        assert "stalled between blocks" in tracing.stream_attrs(e)
+    assert d["request_tpot_stall_count"] == len(spans)
+    assert d["request_tpot_stall_sum"] == pytest.approx(
+        sum(e["tpot_stall_s"] for e in spans.values()), abs=1e-9)
+    if case == "admitted_while_decoding":
+        assert found == [False, True] and d["gap_count"] > 0
+        assert 0 < d["gap_admit_sum"] < d["gap_sum"]
+        # every gap fell after the long request's mark; the late one's
+        # was taken after the one gap it was admitted in
+        assert long["stall_s"] == pytest.approx(d["gap_sum"], abs=1e-9)
+        assert long["stall_admit_s"] == pytest.approx(
+            d["gap_admit_sum"], abs=1e-9)
+        assert late["stall_s"] == 0 == late["tpot_stall_s"]
+    elif case == "alone":
+        assert found == [False] and late is None and d["gap_count"] > 0
+        assert d["gap_sum"] == 0 == d["loop_prefill_behind_sum"]
+        assert long["stall_s"] == 0 == long["tpot_stall_s"]
+        assert d["request_tpot_stall_sum"] == 0
+    else:
+        assert found == [False, False]
+        assert d["loop_prefill_behind_sum"] == 0
+
+
+@pytest.mark.parametrize("name, accrues", [
+    ("admit.alloc", True), ("prefill.dispatch", True),
+    ("prefill.behind", False), ("prefill.wait", True),
+    ("prefill.sample", True), ("decode.prepare", False)])
+def test_admission_phases_accrue_to_the_gap_but_the_wait_behind_a_block(
+        tiny_model, name, accrues):
+    """``prefill.behind`` is the decode block's own time: the gap starts
+    at its exit, so it is no part of the gap's admission share."""
+    eng = _engine(tiny_model, prefix_cache=False)
+    eng._gap_admit = 0.0
+    with eng._phase(name) as ph:
+        time.sleep(0.001)
+    assert eng._gap_admit == (ph.dur if accrues else 0.0)
+
+
+def test_the_block_s_end_is_looked_for_without_waiting_and_taken_once(
+        tiny_model):
+    """An admission looks whether the block in flight has ended (a
+    chunk's launch can sit it out inside ``prefill.dispatch``): a look
+    that finds it running changes nothing, one that finds it ended
+    stamps the block, and from there on the phase's seconds, not the
+    whole phase, count as the gap's admission share."""
+    import types
+
+    class Out:
+        ready = False
+
+        def is_ready(self):
+            return self.ready
+    eng = _engine(tiny_model, prefix_cache=False)
+    blk = eng._inflight = types.SimpleNamespace(out=Out(), t_end=0.0)
+    eng._gap_admit = 0.5
+    eng._block_ended(time.monotonic())
+    assert blk.t_end == 0.0 and eng._gap_admit == 0.5
+    blk.out.ready = True
+    with eng._phase("prefill.dispatch") as ph:
+        time.sleep(0.002)
+        eng._block_ended(ph.t0)
+        time.sleep(0.002)
+    assert ph.t0 < blk.t_end < ph.t1
+    assert eng._gap_admit == pytest.approx(ph.t1 - blk.t_end, abs=1e-9)
+    at = blk.t_end
+    eng._block_ended(0.0, at=at + 1.0)      # once a block
+    assert blk.t_end == at
+    eng._inflight = None
+    eng._block_ended(0.0)                   # nothing in flight: nothing
+
+
 def test_spec_round_uses_the_verify_phases(tiny_model):
     tid = "5e" * 16
 
@@ -302,8 +445,10 @@ def test_engine_spans_land_in_the_profiler_trace_and_do_not_nest(
         await eng.generate([9, 8, 7], max_new_tokens=6)     # compile
         jax.profiler.start_trace(str(tmp_path))
         try:
+            # of three requests on two slots the third is admitted when
+            # the shortest ends, behind the block the other decodes in
             await asyncio.gather(*[
-                eng.generate([3 + i, 5, 7, 9], max_new_tokens=10)
+                eng.generate([3 + i, 5, 7, 9], max_new_tokens=10 + 8 * i)
                 for i in range(3)])
         finally:
             jax.profiler.stop_trace()
@@ -325,7 +470,8 @@ def test_engine_spans_land_in_the_profiler_trace_and_do_not_nest(
                        if b[0] < a[1]]
     assert {"engine.decode.prepare", "engine.decode.dispatch",
             "engine.decode.readback", "engine.prefill.dispatch",
-            "engine.prefill.wait", "engine.prefill.sample",
+            "engine.prefill.behind", "engine.prefill.wait",
+            "engine.prefill.sample",
             "engine.emit", "engine.yield"} <= names, names
     assert not nested, nested[:3]
 
