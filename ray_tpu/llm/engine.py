@@ -169,12 +169,16 @@ def engine_metrics() -> dict:
                                whatever else its thread was running)
       llm_decode_block_steps   decode steps per block
       llm_decode_slot_steps    slots x steps per block
+      llm_decode_idle_slot_steps  slots that hold no request x steps per
+                               block: grid steps of the decode kernels
+                               that did nothing (ops/pallas/
+                               paged_attention.py)
       llm_decode_ctx_tokens    positions attended per block, summed
                                over its slots and steps
       llm_decode_kv_fetch_tokens  positions of K (and of V) the paged
                                kernel's walk fetched per block, summed
-                               over ALL slots (idle ones are walked
-                               too) and its steps
+                               over its slots and steps (a slot that
+                               is not in the block fetches nothing)
       llm_prefill_tokens       prompt tokens run through a prefill
                                forward per admit (prefix hits excluded)
       llm_prefill_chunks_size  prefill forwards per admit (a prompt past
@@ -259,6 +263,11 @@ def engine_metrics() -> dict:
             "llm_decode_slot_steps",
             "Active slots times decode steps per block",
             boundaries=(1, 4, 16, 64, 256, 1024)),
+        "idle_slot_steps": m.Histogram(
+            "llm_decode_idle_slot_steps",
+            "Slots that hold no request times decode steps per block: "
+            "grid steps the decode kernels skipped",
+            boundaries=(1, 4, 16, 64, 256, 1024)),
         "ctx_tokens": m.Histogram(
             "llm_decode_ctx_tokens",
             "Context positions attended per decode block, summed over "
@@ -268,8 +277,8 @@ def engine_metrics() -> dict:
         "kv_fetch_tokens": m.Histogram(
             "llm_decode_kv_fetch_tokens",
             "Positions of K (and of V) the paged-decode kernel's walk "
-            "fetched per decode block, summed over all slots, idle "
-            "ones included, and its steps",
+            "fetched per decode block, summed over the block's slots "
+            "and its steps",
             boundaries=(64, 256, 1024, 4096, 16384, 65536, 262144,
                         1048576)),
         "prefill_tokens": m.Histogram(
@@ -510,8 +519,6 @@ class LLMEngine:
         # what llm_decode_kv_fetch_tokens counts by
         self._walks = [(len(layers), lm.window_of(cfg, kind))
                        for kind, layers in self._layout]
-        self._idle_fetch: dict = {}     # block's steps -> what the walk
-                                        # fetches for one idle slot
         if mesh is not None and (windowed
                                  or kvcache.LATENT in dict(self._layout)
                                  or lm.has_experts(cfg)):
@@ -1236,7 +1243,7 @@ class LLMEngine:
                 tokens[i] = r.out[-1]
             lens.append(at)
             # the last emitted token's KV lands in the block's first
-            # step; empty slots write into the trash block
+            # step
             lengths[i] = at - 1
             temps[i] = r.temperature
             top_ps[i] = r.top_p
@@ -1246,7 +1253,8 @@ class LLMEngine:
         # the tables as they are NOW, copied: admission and _free_kv
         # write the live ones while this block is in flight. A slot that
         # is not in the block (idle, or ending inside the block in
-        # flight) writes to the trash block.
+        # flight) gets a row of TRASH: the program reads off it that the
+        # slot holds no request, and its kernels skip it.
         out = [i for i in range(n) if i not in reqs]
         tables = {kind: t.copy() for kind, t in self._tables.items()}
         for t in tables.values():
@@ -1292,20 +1300,14 @@ class LLMEngine:
         self._m["ctx_tokens"].observe(
             block * sum(lens) + n * block * (block - 1) // 2)
         if self._kv_impl == "paged_flash":
-            # every slot's live blocks at every step, an idle slot
-            # (length 1 + step) included; a window layer's from its
-            # window's first block on, so the mean a layer
+            # the live blocks of every slot of the block at every step
+            # (a slot that is not in it is not walked); a window layer's
+            # from its window's first block on, so the mean a layer
             from ray_tpu.ops.pallas.paged_attention import \
                 fetched_positions_run as run
             bs = self._block
-            if block not in self._idle_fetch:   # the same every block
-                self._idle_fetch[block] = sum(
-                    layers * run(1, block, bs, w)
-                    for layers, w in self._walks)
-            fetched = (self.max_slots - n) * self._idle_fetch[block]
-            for layers, w in self._walks:
-                fetched += layers * sum(run(a, block, bs, w)
-                                        for a in lens)
+            fetched = sum(layers * sum(run(a, block, bs, w) for a in lens)
+                          for layers, w in self._walks)
             self._m["kv_fetch_tokens"].observe(
                 fetched / self.cfg.n_layers)
         if counts is not None:
@@ -1710,6 +1712,8 @@ class LLMEngine:
                     tp, tk)
             self._kvm["attn_steps"].inc(
                 blk.steps, tags={"impl": self._kv_impl})
+            self._m["idle_slot_steps"].observe(
+                (self.max_slots - len(blk.reqs)) * blk.steps)
         gap = None
         if carried:
             # a request of the block in flight goes on in this one.

@@ -45,9 +45,12 @@ blocks are reserved for the full horizon as before. Prefix reuse is
 refused for such a model: a cached chain would have to keep every
 window block it freed.
 
-Physical block 0 is the TRASH block: writes for finished/empty slots
-and bucket-padding garbage are redirected there so freed blocks can be
-reallocated immediately without a device sync.
+Physical block 0 is the TRASH block: bucket-padding garbage and the
+gather reference's writes for finished/empty slots are redirected there
+so freed blocks can be reallocated immediately without a device sync.
+A decode step reads off a row of TRASH that its slot holds no request
+(``_live``), and the kernel path then skips the slot: no walk, no write
+(not even there), no row in an expert layer's groups.
 
 Host bookkeeping (``KVBlockManager``) is pure python/numpy — unit-
 testable without jax; device ops (pool init, gather/scatter, the paged
@@ -942,6 +945,17 @@ def _places(tables, pos, bs):
     return out
 
 
+def _entries(blocks, lens):
+    """The block ids the pool's writer gets, flat: ``blocks`` (slots,) of
+    a decode step, negative (no entry: ops/pallas/paged_attention.py
+    kv_write) where the slot attends 0 positions, or (slots, w) of a
+    verify round as they are."""
+    _, jnp = _jx()
+    if lens.ndim == 1:
+        blocks = jnp.where(lens > 0, blocks, -1)
+    return blocks.reshape(-1)
+
+
 def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     """The attention hook of lm.decode_logits_core / verify_tokens_core
     against the block pool, ``attend(ref, q, k, v, pool) -> (o, pool)``:
@@ -951,6 +965,10 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     positions, a window layer over the last ``sliding_window`` of them.
     ``at`` and ``lens`` are (slots,) for a decode step, (slots, w) for
     a verify round, which attends with the multi-query functions.
+    A decode step's slot with ``lens`` 0 holds no request (``_live``):
+    impl 'paged_flash' hands the writer a negative block for it
+    (``_entries``), so neither kernel moves a byte for such a slot, and
+    its row comes back zeros.
 
     Both impls see a layer as a WINDOW of its kind's flat pool (every
     layer's blocks in one (layers * blocks, kvh, block_size, hd) array:
@@ -1033,7 +1051,7 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
                 k.reshape(-1, kvh, hd).astype(kp.dtype),
                 v.reshape(-1, kvh, hd).astype(vp.dtype),
                 flat(kp), flat(vp), tables[ref.kind] + base,
-                (phys + base).reshape(-1), off.reshape(-1), lens)
+                _entries(phys + base, lens), off.reshape(-1), lens)
             return o.reshape(*lead, h * hd), {
                 **pool, kk: kf.reshape(kp.shape), vk: vf.reshape(vp.shape)}
     else:
@@ -1088,7 +1106,7 @@ def _latent_attend(cfg, tables, at, lens, flat, *, impl, interpret):
         c, kr = tiles(c, cp), tiles(kr, rp)
         if impl == "paged_flash":
             cf, rf = pa.latent_write(
-                flat(cp), flat(rp), (phys + l * cp.shape[1]).reshape(-1),
+                flat(cp), flat(rp), _entries(phys + l * cp.shape[1], lens),
                 off.reshape(-1), c.reshape(-1, c.shape[-1]).astype(cp.dtype),
                 kr.reshape(-1, kr.shape[-1]).astype(rp.dtype),
                 interpret=interpret)
@@ -1106,22 +1124,39 @@ def _latent_attend(cfg, tables, at, lens, flat, *, impl, interpret):
     return attend
 
 
+def _live(tables):
+    """(slots,) bool: the slots that hold a request, read off the tables.
+    A slot that is not in the decode block has a row of TRASH in every
+    kind (llm/engine.py _prepare); a request's row of the kind it holds
+    whole starts with a block of its own (a window layer's row gives its
+    first blocks back as the window passes them, so it cannot say)."""
+    return next(tb for kind, tb in tables.items()
+                if kind != WINDOW)[:, 0] != TRASH
+
+
 def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
                        axis="tensor"):
     """One decode step's (slots, vocab) f32 logits for every slot
     against the paged pool: lm.decode_logits_core with the new token's
     place in the pool worked out from the tables, and the write and
-    the attention over the table plugged in (_pool_attend). Returns
-    (logits, pool, the expert layers' counts or None)."""
+    the attention over the table plugged in (_pool_attend). ONE vector
+    says which slots hold a request (``_live``): the others attend 0
+    positions, write nothing and are no row of an expert layer's groups,
+    so they cost the step's kernels nothing (their logits are garbage
+    nobody reads). Returns (logits, pool, the expert layers' counts or
+    None)."""
+    _, jnp = _jx()
     from ray_tpu.llm.model import decode_logits_core
     bs = pool_k(pool).shape[-2]
     positions = lengths
+    live = _live(tables)
     return decode_logits_core(
         params, pool, tokens, positions, cfg,
         _pool_attend(cfg, tables, _places(tables, positions, bs),
-                     positions + 1, impl=impl, interpret=interpret,
-                     mesh=mesh, axis=axis))
+                     jnp.where(live, positions + 1, 0), impl=impl,
+                     interpret=interpret, mesh=mesh, axis=axis),
+        live)
 
 
 def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
@@ -1161,9 +1196,11 @@ def paged_decode_steps(params, pool, tables, lengths, tokens, temps,
     int32, pool). The pool is DONATED and is the carry of the step
     scan and of each step's layer scan: the program updates it in
     place (tests/test_aot_tpu_compile.py holds the compiled program to
-    that). Slots past their request produce discardable garbage in
-    the trash block; the caller masks on eos and bounds n by each
-    slot's horizon. ``impl``/``interpret``/``mesh`` are trace-time
+    that). A slot whose table row is TRASH holds no request: it
+    attends nothing, writes nothing (under impl='paged_flash' not even
+    the trash block) and its tokens are garbage to discard; the caller
+    masks on eos and bounds n by each slot's horizon.
+    ``impl``/``interpret``/``mesh`` are trace-time
     constants — each combination (x pool geometry) compiles its own
     variant, cached in _JITS. (The program itself, decode_steps_program,
     also returns the expert layers' counts a step; the engine reads

@@ -917,7 +917,8 @@ def _add_counts(total, stats):
 
 
 def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
-                       positions: jax.Array, cfg: LlamaConfig, attend):
+                       positions: jax.Array, cfg: LlamaConfig, attend,
+                       live: jax.Array):
     """THE decode-step transformer: one token for every slot against
     the KV pool (llm/kvcache.py init_pool: k/v (layers of a kind,
     blocks, kvh, block_size, hd) a layer kind). The pool is the layer
@@ -936,11 +937,13 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     Wv_b (latent_absorb / latent_unabsorb: the same numbers as keys and
     values expanded from the rows, with none expanded). Returns ((slots, vocab) f32
     logits, pool, the expert layers' counts summed over the layers or
-    None: models/moe.py serve_block, live rows being the slots at a
-    position > 0)."""
+    None: models/moe.py serve_block over the rows of ``live`` (slots,)
+    bool, the slots that hold a request. The caller reads it off the
+    tables: a position says nothing, an idle slot's is the step's index
+    in its block)."""
     x = _embed(params, tokens[:, None], cfg)                # (b, 1, emb)
     rope = rope_tables(cfg, positions[:, None])
-    active = positions > 0 if has_experts(cfg) else None
+    active = live if has_experts(cfg) else None
 
     def layer(carry, lp, ref):
         x, pool, counts = carry

@@ -1175,23 +1175,27 @@ def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
     ``lp`` is the layer's parameters; with ``stack`` and ``row`` the
     grouped matmuls read the layer's experts in place in the stacked
     leaves. ``active`` (T,) marks the rows that are live requests (a
-    decode step computes every slot): with it the counts come back,
-    device scalars ``{"routed", "local", "experts_hit"}``: assignments of
-    live rows, those of them on held experts, and the held experts that
-    any row reached (whose weights the step read)."""
+    decode step computes every slot): the other rows' assignments sort
+    into the tail no group covers, so the grouped matmuls read only the
+    experts a live row reached (such a row's part of the routed sum is
+    zeros), and the counts come back, device scalars ``{"routed",
+    "local", "experts_hit"}``: assignments of live rows, those of them on
+    held experts, and the held experts that a live row reached (whose
+    weights the step read): the groups the kernels get."""
     src, layer = (stack, row) if stack is not None else (lp, None)
     with jax.named_scope("moe.route"):
         gates, experts, _ = _route(y, lp["router"], lp.get("router_bias"),
                                    cfg)
+        if active is not None:
+            experts = jnp.where(active[:, None], experts, -1)   # nobody's
         mine, order, inverse, group_sizes = _sort_by_expert(
             experts, cfg.first_expert, cfg.n_held)
         stats = None
         if active is not None:
-            live = jnp.repeat(active, cfg.experts_per_token)
             stats = {
-                "routed": jnp.sum(live, dtype=jnp.int32),
-                "local": jnp.sum(live & (mine < cfg.n_held),
-                                 dtype=jnp.int32),
+                "routed": jnp.sum(active, dtype=jnp.int32)
+                * cfg.experts_per_token,
+                "local": jnp.sum(mine < cfg.n_held, dtype=jnp.int32),
                 "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32)}
     out = _gated_sum(y, gates, order, inverse, group_sizes, src, cfg, layer)
     if cfg.n_shared_experts:

@@ -29,8 +29,11 @@ past a slot's last live block are neither started nor waited for
 (``fetched_positions`` is the rule, exported for the engine's
 counter), so the kernel never reads a block the slot does not own;
 what an unfetched part of a buffer still holds is masked out of both
-products. An idle slot (length 1, table row = trash) costs one block
-of each pool and one turn.
+products. A slot that holds no request arrives with length 0 (PR 57;
+llm/kvcache.py _paged_logits_core reads it off the tables): its grid
+step fetches nothing, computes nothing and writes a row of zeros, and
+the writers below skip an entry whose block id is negative, so a decode
+step's kernels cost what its live slots cost.
 
 A WINDOW layer (``window=w``: the query at position ``length - 1``
 attends positions ``>= length - w`` only) starts its walk at the block
@@ -124,11 +127,11 @@ def chunk_blocks(row_bytes, block_size, positions=CHUNK_POSITIONS):
 
 def live_blocks(length, block_size):
     """Pool blocks the walk visits for a slot with ``length`` valid
-    positions: ``ceil(max(length, 1) / block_size)``; an idle slot
-    (length 0 or 1) still has one. The kernel's trip count and the
-    engine's counter both come from here, so it is written to work on
-    ints, numpy arrays and traced scalars alike."""
-    return (length + (length < 1) + block_size - 1) // block_size
+    positions: ``ceil(length / block_size)``, none for an idle slot
+    (length 0: one that holds no request). The kernel's trip count and
+    the engine's counter both come from here, so it is written to work
+    on ints, numpy arrays and traced scalars alike."""
+    return (length + block_size - 1) // block_size
 
 
 def first_block(length, block_size, window=None):
@@ -164,13 +167,13 @@ def _floor_sum(m, bs):
 def fetched_positions_run(length, steps, block_size, window=None):
     """``fetched_positions`` summed over ``steps`` consecutive decode
     steps of a slot that starts at ``length`` (lengths ``length ...
-    length + steps - 1``), in closed form on plain ints: the engine
-    counts a decode block's fetches with it, once a live slot."""
-    idle = min(steps, max(0, 1 - length))   # lengths under 1: one block
-    a, b = length + idle, length + steps - 1
-    blocks = idle
+    length + steps - 1``; one under 1 fetches nothing), in closed form
+    on plain ints: the engine counts a decode block's fetches with it,
+    once a slot that is in the block."""
+    a, b = max(length, 1), length + steps - 1
+    blocks = 0
     if b >= a:
-        blocks += _ceil_sum(b, block_size) - _ceil_sum(a - 1, block_size)
+        blocks = _ceil_sum(b, block_size) - _ceil_sum(a - 1, block_size)
     if window is not None and b > window:
         blocks -= _floor_sum(b - window, block_size) \
             - _floor_sum(max(a - 1 - window, 0), block_size)
@@ -183,82 +186,91 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
     kvh, g, hd = q_ref.shape[1:]
     t = cb * bs                                 # positions a chunk
     length = lengths_ref[b_]
-    live = jnp.minimum(live_blocks(length, bs), width)
-    n_chunks = (live + cb - 1) // cb
-    first = 0 if window is None else jnp.minimum(
-        first_block(length, bs, window), live - 1)
-    chunk0 = 0 if window is None else first // cb
-    floor = None if window is None else length - window
 
-    def fetch(i, buf, wait):
-        """Start (or wait for) the DMAs of chunk ``i`` into buffer
-        ``buf``: one a pool block for K, one for V, the live ones
-        only."""
-        def entry(c, carry):
-            # a wait only needs the copy's shape, not its source
-            blk = 0 if wait else tables_ref[b_, i * cb + c]
-            rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
-            for s, (pool, dst) in enumerate(((k_hbm, kbuf),
-                                             (v_hbm, vbuf))):
-                cp = pltpu.make_async_copy(
-                    pool.at[blk], dst.at[buf, :, rows, :],
-                    sems.at[s, buf])
-                cp.wait() if wait else cp.start()
-            return carry
+    @pl.when(length < 1)
+    def _():
+        # a slot that holds no request: no fetch, no chunk, and a
+        # finite row for the products that follow
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-        jax.lax.fori_loop(
-            0 if window is None else jnp.maximum(first - i * cb, 0),
-            jnp.minimum(live - i * cb, cb), entry, 0)
+    @pl.when(length >= 1)
+    def _():
+        live = jnp.minimum(live_blocks(length, bs), width)
+        n_chunks = (live + cb - 1) // cb
+        first = 0 if window is None else jnp.minimum(
+            first_block(length, bs, window), live - 1)
+        chunk0 = 0 if window is None else first // cb
+        floor = None if window is None else length - window
 
-    q = q_ref[0]                                # (kvh, g, hd)
-    if not (q.dtype == kbuf.dtype == jnp.bfloat16):
-        q = q.astype(jnp.float32)
-    if window is None:
-        fetch(0, 0, wait=False)
-    else:
-        fetch(chunk0, jax.lax.rem(chunk0, 2), wait=False)
+        def fetch(i, buf, wait):
+            """Start (or wait for) the DMAs of chunk ``i`` into buffer
+            ``buf``: one a pool block for K, one for V, the live ones
+            only."""
+            def entry(c, carry):
+                # a wait only needs the copy's shape, not its source
+                blk = 0 if wait else tables_ref[b_, i * cb + c]
+                rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
+                for s, (pool, dst) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf))):
+                    cp = pltpu.make_async_copy(
+                        pool.at[blk], dst.at[buf, :, rows, :],
+                        sems.at[s, buf])
+                    cp.wait() if wait else cp.start()
+                return carry
 
-    def chunk(i, carry):
-        m_prev, l_prev, acc = carry
-        buf = jax.lax.rem(i, 2)
+            jax.lax.fori_loop(
+                0 if window is None else jnp.maximum(first - i * cb, 0),
+                jnp.minimum(live - i * cb, cb), entry, 0)
 
-        @pl.when(i + 1 < n_chunks)
-        def _():
-            fetch(i + 1, 1 - buf, wait=False)
+        q = q_ref[0]                                # (kvh, g, hd)
+        if not (q.dtype == kbuf.dtype == jnp.bfloat16):
+            q = q.astype(jnp.float32)
+        if window is None:
+            fetch(0, 0, wait=False)
+        else:
+            fetch(chunk0, jax.lax.rem(chunk0, 2), wait=False)
 
-        fetch(i, buf, wait=True)
-        k = kbuf[buf].astype(q.dtype)           # (kvh, t, hd)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) / jnp.sqrt(
-                jnp.float32(hd))                # (kvh, g, t)
-        at = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        keep = at < length
-        if window is not None:
-            keep = jnp.logical_and(keep, at >= floor)
-        s = jnp.where(keep, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        # rows past ``length`` hold whatever the pool or an earlier
-        # chunk left there: p is 0 for them, and 0 x NaN is not
-        at = i * t + jax.lax.broadcasted_iota(jnp.int32, (kvh, t, hd), 1)
-        rows = at < length
-        if window is not None:
-            rows = jnp.logical_and(rows, at >= floor)
-        v = jnp.where(rows, vbuf[buf].astype(jnp.float32), 0.0)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)  # (kvh, g, hd)
-        return m_new, l_new, acc
+        def chunk(i, carry):
+            m_prev, l_prev, acc = carry
+            buf = jax.lax.rem(i, 2)
 
-    _, l, acc = jax.lax.fori_loop(
-        chunk0, n_chunks, chunk,
-        (jnp.full((kvh, g, 1), NEG_INF, jnp.float32),
-         jnp.zeros((kvh, g, 1), jnp.float32),
-         jnp.zeros((kvh, g, hd), jnp.float32)))
-    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            @pl.when(i + 1 < n_chunks)
+            def _():
+                fetch(i + 1, 1 - buf, wait=False)
+
+            fetch(i, buf, wait=True)
+            k = kbuf[buf].astype(q.dtype)           # (kvh, t, hd)
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) / jnp.sqrt(
+                    jnp.float32(hd))                # (kvh, g, t)
+            at = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            keep = at < length
+            if window is not None:
+                keep = jnp.logical_and(keep, at >= floor)
+            s = jnp.where(keep, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            # rows past ``length`` hold whatever the pool or an earlier
+            # chunk left there: p is 0 for them, and 0 x NaN is not
+            at = i * t + jax.lax.broadcasted_iota(jnp.int32, (kvh, t, hd), 1)
+            rows = at < length
+            if window is not None:
+                rows = jnp.logical_and(rows, at >= floor)
+            v = jnp.where(rows, vbuf[buf].astype(jnp.float32), 0.0)
+            acc = acc * alpha + jax.lax.dot_general(
+                p, v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)  # (kvh, g, hd)
+            return m_new, l_new, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            chunk0, n_chunks, chunk,
+            (jnp.full((kvh, g, 1), NEG_INF, jnp.float32),
+             jnp.zeros((kvh, g, 1), jnp.float32),
+             jnp.zeros((kvh, g, hd), jnp.float32)))
+        o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
@@ -269,7 +281,9 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     per slot; k_pool/v_pool: (num_blocks, kv_heads, block_size,
     head_dim) — ONE layer of the engine pool; tables: (slots, width)
     int32 physical block ids (trash-padded); lengths: (slots,) int32
-    valid positions per slot INCLUDING the current token (>= 1).
+    valid positions per slot INCLUDING the current token (>= 1), or 0
+    for a slot that holds no request: nothing of its table is read and
+    its output is zeros.
     Returns (slots, kv_heads, group, head_dim) float32 — the same
     value ``_gqa_attend_cached`` computes from the gathered view, with
     no gathered view. ``window`` (static): attend the last ``window``
@@ -323,20 +337,24 @@ def _kv_write_kernel(blocks_ref, rows_ref, k_new, v_new, k_in, v_in,
     i = pl.program_id(0)
     blk, row = blocks_ref[i], rows_ref[i]
     pools = (k_out, v_out)
-    reads = [pltpu.make_async_copy(pool.at[blk], buf.at[s], sems.at[s])
-             for s, pool in enumerate(pools)]
-    writes = [pltpu.make_async_copy(buf.at[s], pool.at[blk], sems.at[s])
-              for s, pool in enumerate(pools)]
-    for cp in reads:
-        cp.start()
-    for s, new in enumerate((k_new, v_new)):
-        reads[s].wait()
-        block = buf[s]                          # (kvh, bs, hd)
-        here = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == row
-        buf[s] = jnp.where(here, new[0][:, None, :], block)
-        writes[s].start()
-    for cp in writes:
-        cp.wait()
+
+    @pl.when(blk >= 0)              # a negative block: no entry here
+    def _():
+        reads = [pltpu.make_async_copy(pool.at[blk], buf.at[s], sems.at[s])
+                 for s, pool in enumerate(pools)]
+        writes = [pltpu.make_async_copy(buf.at[s], pool.at[blk], sems.at[s])
+                  for s, pool in enumerate(pools)]
+        for cp in reads:
+            cp.start()
+        for s, new in enumerate((k_new, v_new)):
+            reads[s].wait()
+            block = buf[s]                      # (kvh, bs, hd)
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, block.shape, 1) == row
+            buf[s] = jnp.where(here, new[0][:, None, :], block)
+            writes[s].start()
+        for cp in writes:
+            cp.wait()
 
 
 def kv_write(k_pool, v_pool, blocks, rows, k_new, v_new, *,
@@ -344,7 +362,9 @@ def kv_write(k_pool, v_pool, blocks, rows, k_new, v_new, *,
     """Write ``n`` new positions' K and V rows into the pools IN PLACE:
     ``pool[blocks[i], :, rows[i]] = new[i]`` for i = 0..n-1, in that
     order (a later entry for the same place wins, and two entries in
-    one block both land).
+    one block both land). An entry with ``blocks[i] < 0`` is no entry:
+    its grid step moves nothing (a slot that holds no request; the
+    decode steps no longer write the trash block).
 
     k_pool/v_pool: (num_blocks, kv_heads, block_size, head_dim) —
     every layer of the engine pool flattened along its first two axes,
@@ -491,71 +511,82 @@ def _latent_walk_kernel(tables_ref, lengths_ref, q_ref, c_hbm, r_hbm, o_ref,
     lat = cbuf.shape[-1]
     t = cb * bs                                 # positions a chunk
     length = lengths_ref[b_]
-    live = jnp.minimum(live_blocks(length, bs), width)
-    n_chunks = (live + cb - 1) // cb
 
-    def fetch(i, buf, wait):
-        """Start (or wait for) the DMAs of chunk ``i`` into buffer
-        ``buf``: one a live pool block for c, one for kr."""
-        def entry(c, carry):
-            blk = 0 if wait else tables_ref[b_, i * cb + c]
-            rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
-            for s, (pool, dst) in enumerate(((c_hbm, cbuf), (r_hbm, rbuf))):
-                cp = pltpu.make_async_copy(
-                    pool.at[blk, 0], dst.at[buf, rows, :], sems.at[s, buf])
-                cp.wait() if wait else cp.start()
-            return carry
+    @pl.when(length < 1)
+    def _():
+        # a slot that holds no request: no fetch, no chunk, and a
+        # finite row for the products that follow
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-        jax.lax.fori_loop(0, jnp.minimum(live - i * cb, cb), entry, 0)
+    @pl.when(length >= 1)
+    def _():
+        live = jnp.minimum(live_blocks(length, bs), width)
+        n_chunks = (live + cb - 1) // cb
 
-    q = q_ref[0, 0]                             # (h, lat + rope)
-    if not (q.dtype == cbuf.dtype == jnp.bfloat16):
-        q = q.astype(jnp.float32)
-    qc, qr = q[:, :lat], q[:, lat:]
-    fetch(0, 0, wait=False)
+        def fetch(i, buf, wait):
+            """Start (or wait for) the DMAs of chunk ``i`` into buffer
+            ``buf``: one a live pool block for c, one for kr."""
+            def entry(c, carry):
+                blk = 0 if wait else tables_ref[b_, i * cb + c]
+                rows = pl.ds(pl.multiple_of(c * bs, bs), bs)
+                for s, (pool, dst) in enumerate(((c_hbm, cbuf),
+                                                 (r_hbm, rbuf))):
+                    cp = pltpu.make_async_copy(
+                        pool.at[blk, 0], dst.at[buf, rows, :],
+                        sems.at[s, buf])
+                    cp.wait() if wait else cp.start()
+                return carry
 
-    def chunk(i, carry):
-        m_prev, l_prev, acc = carry
-        buf = jax.lax.rem(i, 2)
+            jax.lax.fori_loop(0, jnp.minimum(live - i * cb, cb), entry, 0)
 
-        @pl.when(i + 1 < n_chunks)
-        def _():
-            fetch(i + 1, 1 - buf, wait=False)
+        q = q_ref[0, 0]                             # (h, lat + rope)
+        if not (q.dtype == cbuf.dtype == jnp.bfloat16):
+            q = q.astype(jnp.float32)
+        qc, qr = q[:, :lat], q[:, lat:]
+        fetch(0, 0, wait=False)
 
-        fetch(i, buf, wait=True)
-        c = cbuf[buf]                           # (t, lat): key AND value
-        nt = (((1,), (1,)), ((), ()))
-        s = (jax.lax.dot_general(qc, c.astype(q.dtype), nt,
-                                 preferred_element_type=jnp.float32)
-             + jax.lax.dot_general(qr, rbuf[buf].astype(q.dtype), nt,
-                                   preferred_element_type=jnp.float32)
-             ) * sm_scale                       # (h, t)
-        keep = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-            < length
-        s = jnp.where(keep, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        # rows past ``length`` hold whatever the pool or an earlier
-        # chunk left there: p is 0 for them, and 0 x NaN is not
-        rows = i * t + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0) \
-            < length
-        v = jnp.where(rows, c, jnp.zeros_like(c))
-        if v.dtype == jnp.bfloat16:
-            p = p.astype(jnp.bfloat16)          # one MXU pass, f32 sums
-        else:
-            v = v.astype(jnp.float32)
-        acc = acc * alpha + jnp.dot(p, v,
-                                    preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+        def chunk(i, carry):
+            m_prev, l_prev, acc = carry
+            buf = jax.lax.rem(i, 2)
 
-    _, l, acc = jax.lax.fori_loop(
-        0, n_chunks, chunk,
-        (jnp.full((h, 1), NEG_INF, jnp.float32),
-         jnp.zeros((h, 1), jnp.float32),
-         jnp.zeros((h, lat), jnp.float32)))
-    o_ref[0, 0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            @pl.when(i + 1 < n_chunks)
+            def _():
+                fetch(i + 1, 1 - buf, wait=False)
+
+            fetch(i, buf, wait=True)
+            c = cbuf[buf]                           # (t, lat): key AND value
+            nt = (((1,), (1,)), ((), ()))
+            s = (jax.lax.dot_general(qc, c.astype(q.dtype), nt,
+                                     preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(qr, rbuf[buf].astype(q.dtype), nt,
+                                       preferred_element_type=jnp.float32)
+                 ) * sm_scale                       # (h, t)
+            keep = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+                < length
+            s = jnp.where(keep, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            # rows past ``length`` hold whatever the pool or an earlier
+            # chunk left there: p is 0 for them, and 0 x NaN is not
+            rows = i * t + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0) \
+                < length
+            v = jnp.where(rows, c, jnp.zeros_like(c))
+            if v.dtype == jnp.bfloat16:
+                p = p.astype(jnp.bfloat16)          # one MXU pass, f32 sums
+            else:
+                v = v.astype(jnp.float32)
+            acc = acc * alpha + jnp.dot(p, v,
+                                        preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, chunk,
+            (jnp.full((h, 1), NEG_INF, jnp.float32),
+             jnp.zeros((h, 1), jnp.float32),
+             jnp.zeros((h, lat), jnp.float32)))
+        o_ref[0, 0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def latent_decode(q, c_pool, kr_pool, tables, lengths, *, sm_scale,
@@ -627,27 +658,32 @@ def _latent_write_kernel(blocks_ref, rows_ref, c_new, r_new, c_in, r_in,
     i = pl.program_id(0)
     blk, row = blocks_ref[i], rows_ref[i]
     pairs = ((c_out, cbuf, c_new), (r_out, rbuf, r_new))
-    reads = [pltpu.make_async_copy(pool.at[blk], buf, sems.at[s])
-             for s, (pool, buf, _) in enumerate(pairs)]
-    writes = [pltpu.make_async_copy(buf, pool.at[blk], sems.at[s])
-              for s, (pool, buf, _) in enumerate(pairs)]
-    for cp in reads:
-        cp.start()
-    for s, (_, buf, new) in enumerate(pairs):
-        reads[s].wait()
-        block = buf[...]                        # (bs, width)
-        here = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0) == row
-        buf[...] = jnp.where(here, new[0], block)
-        writes[s].start()
-    for cp in writes:
-        cp.wait()
+
+    @pl.when(blk >= 0)              # a negative block: no entry here
+    def _():
+        reads = [pltpu.make_async_copy(pool.at[blk], buf, sems.at[s])
+                 for s, (pool, buf, _) in enumerate(pairs)]
+        writes = [pltpu.make_async_copy(buf, pool.at[blk], sems.at[s])
+                  for s, (pool, buf, _) in enumerate(pairs)]
+        for cp in reads:
+            cp.start()
+        for s, (_, buf, new) in enumerate(pairs):
+            reads[s].wait()
+            block = buf[...]                    # (bs, width)
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, block.shape, 0) == row
+            buf[...] = jnp.where(here, new[0], block)
+            writes[s].start()
+        for cp in writes:
+            cp.wait()
 
 
 def latent_write(c_pool, kr_pool, blocks, rows, c_new, kr_new, *,
                  interpret=False):
     """``kv_write`` for latent rows: ``c_pool[blocks[i], rows[i]] =
     c_new[i]`` and the same for kr, in order, IN PLACE (both pools
-    aliased in to out; each entry a read-modify-write of its block).
+    aliased in to out; each entry a read-modify-write of its block, an
+    entry with ``blocks[i] < 0`` nothing at all).
     c_pool: (num_blocks, block_size, kv_lora_rank), kr_pool:
     (num_blocks, block_size, qk_rope_head_dim), every layer of the
     engine pool flattened along its first two axes; c_new: (n,

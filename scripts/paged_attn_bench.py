@@ -9,14 +9,16 @@ bandwidth over the measured time (``benchmarks/harness/kernels.py
 paged_decode_bytes`` and ``peaks.py``: the cell's metric's own count).
 
 Slots that are not live are idle as the engine presents them: an
-all-trash table row and length 1. Live slots' tables name random pool
-blocks, so no two fetches are neighbours in HBM.
+all-trash table row and length 0 (PR 57: the walk skips them; a module
+from before it, ``--old``, walks the trash block for each). Live slots'
+tables name random pool blocks, so no two fetches are neighbours in HBM.
 
 Then the pool's block writer, ``kv_write``, alone (PR 31): the 32
 entries of one layer of a decode step, K and V, each a read-modify-write
-of a whole pool block; all 32 slots live on distinct blocks, the cell's
-mix of 12 live and 20 idle slots (the idle ones all write the trash
-block), and 160 entries as a verify round of width 5 writes them.
+of a whole pool block; all 32 slots live on distinct blocks, mixes of
+12 and of 2 live slots with the idle ones' entries negative (skipped)
+and, ``_trash``, pointed at the trash block as before PR 57, and 160
+entries as a verify round of width 5 writes them.
 
 ``--old PATH`` times a second module beside it (the parent commit's
 kernel, unpacked under ``.scratch/``), same inputs, and compares the
@@ -55,7 +57,9 @@ def _load(path):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--old", help="path of a second kernel module")
-    ap.add_argument("--reps", type=int, default=32,
+    # a program's launch and read-back cost the host ~1.5 ms: at 32 calls
+    # a program that was 50 us a call on top of a 2-us writer (PR 57)
+    ap.add_argument("--reps", type=int, default=512,
                     help="kernel calls chained in one program")
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true")
@@ -104,7 +108,7 @@ def main():
     rows = []
     for ctx in (64, 256, 1024, 4096) if not a.rehearse else (16, 256):
         for live in (8, 32) if not a.rehearse else (1, 4):
-            lengths = np.ones((slots,), np.int32)
+            lengths = np.zeros((slots,), np.int32)
             lengths[:live] = ctx
             tables = np.zeros((slots, w), np.int32)     # trash rows
             tables[:live] = rng.integers(1, nb, (live, w))
@@ -129,9 +133,10 @@ def main():
                     q, k_pool, v_pool, tb, ln, interpret=a.rehearse))
             ref = np.asarray(new.paged_attention_reference(
                 q, k_pool, v_pool, tb, ln))
-            for name, o in outs.items():
-                row[f"{name}_vs_reference"] = float(
-                    np.linalg.norm(o - ref) / np.linalg.norm(ref))
+            for name, o in outs.items():     # the live rows: idle ones
+                row[f"{name}_vs_reference"] = float(    # are nobody's
+                    np.linalg.norm(o[:live] - ref[:live])
+                    / np.linalg.norm(ref[:live]))
             if "old" in outs:
                 row["speedup"] = row["old_us"] / row["new_us"]
             print(json.dumps(row), flush=True)
@@ -146,10 +151,13 @@ def main():
 
     writes = []
     block_bytes = kvh * bs * hd * k_pool.dtype.itemsize
-    for what, n, live in (("all_live", slots, slots),
-                          ("cell_mix", slots, 3 * slots // 8),
-                          ("verify_w5", 5 * slots, 5 * slots)):
-        blocks = np.zeros((n,), np.int32)               # trash
+    for what, n, live, idle in (("all_live", slots, slots, -1),
+                                ("cell_mix", slots, 3 * slots // 8, -1),
+                                ("cell_mix_trash", slots, 3 * slots // 8, 0),
+                                ("chat_mix", slots, slots // 16, -1),
+                                ("chat_mix_trash", slots, slots // 16, 0),
+                                ("verify_w5", 5 * slots, 5 * slots, -1)):
+        blocks = np.full((n,), idle, np.int32)
         blocks[:live] = rng.permutation(np.arange(1, nb))[:live]
         at = jnp.asarray(rng.integers(0, bs, (n,)), jnp.int32)
         blocks = jnp.asarray(blocks)
@@ -157,8 +165,8 @@ def main():
             jax.random.PRNGKey(i), (n, kvh, hd), jnp.bfloat16)
             for i in (1, 2))
         # block 0 takes the idle entries in an order of its own
-        want = np.asarray(k_pool.at[blocks, :, at].set(k_new)[1:]
-                          .astype(jnp.float32))
+        want = np.asarray(k_pool.at[blocks[:live], :, at[:live]].set(
+            k_new[:live])[1:].astype(jnp.float32))
         ts = []
         for _ in range(a.rounds + 1):                   # first: compile
             t0 = time.perf_counter()

@@ -589,6 +589,21 @@ def _hybrid_decode(chip, cfg):
     return params, pool, compiled
 
 
+def _assert_decode_signatures(ops):
+    """What the benchmark's readers know the decode kernels by: a walk's
+    five operands (two of scalars, three of rank 4) and a writer's six.
+    A slot that holds no request travels inside them (PR 57: a length of
+    0, a negative block), not beside them."""
+    for op in ops:
+        ranks = [(d, len(s)) for d, s in op["operands"]]
+        if op["class"] == "paged_decode":
+            assert ranks == [("s32", 2), ("s32", 1)] + [("bf16", 4)] * 3, op
+        elif "write" in op["name"]:
+            assert ranks[:2] == [("s32", 1), ("s32", 1)] \
+                and len(ranks) == 6 and ranks[2] == ranks[3] \
+                and ranks[4] == ranks[5], op
+
+
 @pytest.fixture(scope="module")
 def hybrid_decode(chip):
     """The hybrid cell's decode program, compiled once for its tests."""
@@ -612,6 +627,7 @@ def test_the_hybrid_cells_decode_program_compiles_for_v5e(hybrid_decode):
                      "kv_write": 8, "moe_gmm_decode": 21}, names
     # the reduction's steps = paged calls / layers holds: 8 a step
     assert sum(op["class"] == "paged_decode" for op in ops) == 8
+    _assert_decode_signatures(ops)
     mem = compiled.memory_analysis()
     import numpy as np
     pool_bytes = sum(2 * int(np.prod(a.shape)) for a in pool.values())
@@ -1074,6 +1090,7 @@ def test_the_latent_cells_decode_program_reads_rows_and_weights_in_place(
     assert names == {"latent_decode": 1, "latent_write": 1,
                      "moe_gmm_decode": 3}, names     # one scanned layer
     assert {op["class"] for op in ops} == {"paged_decode", "unknown_kernel"}
+    _assert_decode_signatures(ops)
     # (a) nothing but the kernels makes a pool-sized array
     pool_shapes = {tuple(a.shape[i:]) for a in pool.values() for i in (0, 1)} \
         | {(a.shape[0] * a.shape[1], *a.shape[2:]) for a in pool.values()}

@@ -208,6 +208,44 @@ def test_the_layer_counts_what_it_routed():
     assert 0 <= int(stats["experts_hit"]) <= cfg.n_held
 
 
+@pytest.mark.parametrize("path", ["ragged_dot", "kernel_stack"])
+def test_the_layer_groups_the_live_rows_alone(path):
+    """PR 57. With ``active`` the rows that are no live request reach no
+    expert: ``experts_hit``, ``local`` and ``routed`` are those of the
+    live rows' assignments (what the grouped matmuls get as groups), a
+    live row's output is, bit for bit, the one the layer gave when every
+    row was grouped, and an idle row keeps the shared expert's part
+    alone. Without ``active`` (prefill, verify) nothing is masked: the
+    layer is the reference's share, as before."""
+    cfg = _cfg(**({} if path == "ragged_dot"
+                  else {"gmm_impl": "pallas_interpret"}))
+    params = moe.init_params(jax.random.PRNGKey(1), cfg)
+    lp, x = _layer(params), _rows()
+    kw = {} if path == "ragged_dot" else {"stack": params["layers"],
+                                          "row": 2}
+    active = (jnp.arange(24) % 12) == 5                      # 2 live rows
+    every, none = moe.serve_block(x, lp, cfg, **kw)
+    got, stats = moe.serve_block(x, lp, cfg, active=active, **kw)
+    assert none is None
+    np.testing.assert_array_equal(got[active], every[active])
+    np.testing.assert_array_equal(got[~active],
+                                  moe._shared(x, lp)[~active])
+    _, experts, _ = moe._route(x, lp["router"], lp.get("router_bias"), cfg)
+    mine = np.asarray(experts) - cfg.first_expert
+    held = (mine >= 0) & (mine < cfg.n_held)
+    live = held & np.asarray(active)[:, None]
+    assert int(stats["routed"]) == 2 * cfg.experts_per_token
+    assert int(stats["local"]) == live.sum() > 0
+    assert int(stats["experts_hit"]) == len(set(mine[live]))
+    # the idle rows alone reach held experts the live ones do not: the
+    # counts above are not those of every row
+    assert len(set(mine[held])) > len(set(mine[live]))
+    _, all_live = moe.serve_block(x, lp, cfg, active=jnp.ones(24, bool),
+                                  **kw)
+    assert int(all_live["local"]) == held.sum()
+    assert int(all_live["experts_hit"]) == len(set(mine[held]))
+
+
 @pytest.mark.parametrize("post_norm, embed_rms, attn_norm",
                          [(True, 1.0, 0.25), (False, 64 ** -0.5, 1.0)])
 def test_a_post_norm_stream_is_made_token_specific(params, post_norm,

@@ -5,6 +5,7 @@ reference benchmarks/families/mistral4.py at tiny widths in float32. The
 rotary's original length is 16 and its factor 4, so YaRN's ramp and the
 query scale a(t) are crossed inside every prompt."""
 import asyncio
+import functools
 import json
 import os
 import sys
@@ -306,6 +307,35 @@ def test_the_latent_walk_is_its_plain_twin(bs):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("bs", [8, 128])
+def test_the_latent_walk_skips_the_slots_that_hold_no_request(bs):
+    """Slots of length 0 over an all-trash row (PR 57) between live ones,
+    the trash block poisoned: the live rows are, bit for bit, what the
+    batch gave when idle slots were walked at length 1 + step (the
+    parent's operands), and an idle row is zeros."""
+    h, lat, rope = 4, 128, 128
+    live_lens, idle, live = [5 * bs + 3, 9 * bs], [0, 2, 4], [1, 3]
+    c, r = _latent_pools(24, bs, lat, rope, seed=bs)
+    c, r = c.at[0].set(jnp.nan), r.at[0].set(jnp.nan)      # trash
+    tables = np.zeros((5, 12), np.int32)
+    tables[1, :6] = 1 + np.arange(6)
+    tables[3, :9] = 7 + np.arange(9)
+    q = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (5, h, lat + rope)), jnp.float32)
+    walk = jax.jit(functools.partial(pa.latent_decode, sm_scale=0.11,
+                                     interpret=True))
+
+    def run(idle_len):
+        lengths = np.full((5,), idle_len, np.int32)
+        lengths[live] = live_lens
+        return np.asarray(walk(q, c, r, jnp.asarray(tables),
+                               jnp.asarray(lengths)))
+    before, got = run(3), run(0)
+    assert np.isnan(before[idle]).all() and np.isfinite(got).all()
+    assert np.array_equal(got[live], before[live])
+    assert not got[idle].any()
+
+
 def test_the_walks_chunk_follows_the_rows_bytes():
     """One rule for both walks: what a position costs in the walked pool
     and the positions a chunk aims at."""
@@ -332,6 +362,30 @@ def test_the_latent_writer_puts_rows_in_place():
     np.testing.assert_array_equal(r2, want_r)
     with pytest.raises(ValueError):
         pa.latent_write(c, r, blocks, rows, c_new[:, 0, :64], r_new[:, 0])
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_latent_writer_skips_the_entries_that_are_none(dtype):
+    """A negative block is no entry (PR 57: a slot that holds no request):
+    both pools are the scatter of the other entries alone, bit for bit,
+    the trash block, which idle slots used to write, included."""
+    dtype = jnp.dtype(dtype)
+    c, r = _latent_pools(6, 8, 128, 128, seed=5, dtype=dtype)
+    blocks = jnp.asarray([2, -1, 5, -1, 2], jnp.int32)
+    rows = jnp.asarray([0, 1, 7, 2, 3], jnp.int32)
+    c_new, r_new = _latent_pools(5, 1, 128, 128, seed=6, dtype=dtype)
+    c2, r2 = pa.latent_write(c, r, blocks, rows, c_new[:, 0], r_new[:, 0],
+                             interpret=True)
+    c1, r1 = pa.latent_write(c, r, jnp.maximum(blocks, 0), rows,
+                             c_new[:, 0], r_new[:, 0], interpret=True)
+    keep = np.asarray(blocks) >= 0
+    for got, old, pool, new in ((c2, c1, c, c_new), (r2, r1, r, r_new)):
+        want = pool.at[blocks[keep], rows[keep]].set(new[keep, 0])
+        got, old, want = (np.asarray(x.astype(jnp.float32))
+                          for x in (got, old, want))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got[1:], old[1:])
+        assert (old[0] != got[0]).any()
 
 
 # --- the pool's third kind ----------------------------------------------------

@@ -248,6 +248,39 @@ def test_walk_idle_slots_beside_full_ones():
                                 got[1].shape))
 
 
+@pytest.mark.parametrize("window", [None, 40])
+def test_walk_skips_the_slots_that_hold_no_request(window):
+    """Slots that hold no request (PR 57: an all-trash table row and a
+    length of 0) between live ones, over a POISONED trash block: the
+    live rows are, bit for bit, what the same batch gave when an idle
+    slot was walked as a length of 1 + step over the trash block (the
+    parent's operands); an idle row is zeros, so nothing of the trash
+    block was fetched into it; and the rule counts no block for it."""
+    b, idle, live = 5, [1, 3, 4], [0, 2]
+    q, k, v, tables = _rand_case(12, b=b, w=_W, bs=_BS, kvh=2, g=2,
+                                 hd=_HD, nb=1 + b * _W)
+    k, v = k.at[kc.TRASH].set(jnp.nan), v.at[kc.TRASH].set(jnp.nan)
+    tables = np.asarray(tables).copy()
+    tables[idle] = kc.TRASH
+    tables = jnp.asarray(tables)
+    walk = jax.jit(functools.partial(pa.paged_attention, interpret=True,
+                                     window=window))
+    before = np.asarray(walk(q, k, v, tables, jnp.asarray(
+        [_W * _BS, 1, 5 * _BS + 3, 4, 1], jnp.int32)))
+    lengths = jnp.asarray([_W * _BS, 0, 5 * _BS + 3, 0, 0], jnp.int32)
+    got = np.asarray(walk(q, k, v, tables, lengths))
+    assert np.isnan(before[idle]).all() and np.isfinite(got).all()
+    assert np.array_equal(got[live], before[live])
+    assert not got[idle].any()
+    np.testing.assert_allclose(got[live], np.asarray(
+        pa.paged_attention_reference(q, k, v, tables, lengths,
+                                     window=window))[live],
+                               rtol=2e-6, atol=2e-6)
+    assert pa.live_blocks(0, _BS) == 0
+    assert pa.fetched_positions(0, _BS, window) == 0
+    assert pa.fetched_positions_run(0, 1, _BS, window) == 0
+
+
 @pytest.mark.parametrize("kvh,g", [(8, 4), (32, 1), (2, 1)])
 def test_walk_head_layouts(kvh, g):
     """GQA as the chat cell has it (8 x 4), MHA (32 x 1) and a shard of
@@ -345,8 +378,9 @@ def test_fetch_rule_counts_the_blocks_the_walk_names(length):
     got = np.asarray(_walk(q, k, v, tables,
                            jnp.asarray([length], jnp.int32)))[0, 0, 0]
     if length == 0:
-        # the empty-row guard: one block walked, nothing attended
-        assert not got.any() and pa.fetched_positions(0, bs) == bs
+        # a slot that holds no request: nothing walked, nothing attended
+        assert not got.any() and pa.fetched_positions(0, bs) == 0
+        assert pa.live_blocks(0, bs) == 0
         return
     n = length
     named = np.flatnonzero(got)
@@ -468,6 +502,37 @@ def test_kv_write_block_sizes_dtypes_heads(bs, dtype, kvh):
     _check_kv_write(kp, vp, 1, [5, 2, 2, 0], [bs - 1, 0, 3, 1])
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kv_write_skips_the_entries_that_are_none(dtype):
+    """An entry with a negative block (PR 57: a slot that holds no
+    request) between live ones: both pools are, bit for bit, the
+    scatter of the live entries alone, the trash block, which the
+    same batch wrote when idle slots were pointed at it, included."""
+    kp, vp = _write_case(3, dtype=jnp.dtype(dtype))
+    nb, kvh, bs, hd = kp.shape[1:]
+    layer, phys, rows = 1, [3, -1, 5, -1, -1, 3], [2, 1, bs - 1, 1, 0, 3]
+    entries = np.asarray(phys) >= 0
+    rng = np.random.default_rng(9)
+    new = [jnp.asarray(rng.normal(size=(len(phys), kvh, hd))
+                       .astype(np.float32)).astype(kp.dtype) for _ in "kv"]
+    flat = [p.reshape(-1, *p.shape[2:]) for p in (kp, vp)]
+    write = jax.jit(functools.partial(pa.kv_write, interpret=True))
+    ids = jnp.asarray(phys, jnp.int32)
+    got = write(*flat, jnp.where(ids < 0, -1, layer * nb + ids),
+                jnp.asarray(rows, jnp.int32), *new)
+    before = write(*flat, layer * nb + jnp.maximum(ids, kc.TRASH),
+                   jnp.asarray(rows, jnp.int32), *new)
+    for g, old, pool, n in zip(got, before, (kp, vp), new):
+        want = pool.at[layer, ids[entries], :,
+                       jnp.asarray(rows)[entries]].set(n[entries])
+        g, old = (np.asarray(x.reshape(pool.shape).astype(jnp.float32))
+                  for x in (g, old))
+        np.testing.assert_array_equal(g, np.asarray(
+            want.astype(jnp.float32)))
+        np.testing.assert_array_equal(g[:, 1:], old[:, 1:])
+        assert (old[layer, kc.TRASH] != g[layer, kc.TRASH]).any()
+
+
 def test_kv_write_refuses_rows_the_pool_cannot_take():
     kp, vp = (p.reshape(-1, *p.shape[2:]) for p in _write_case(3))
     ids = jnp.zeros((2,), jnp.int32)
@@ -483,12 +548,14 @@ def test_kv_write_refuses_rows_the_pool_cannot_take():
 def test_decode_steps_kernel_matches_gather_in_place(tiny_model, n):
     """paged_decode_steps — the engine's one decode call — with the
     writer and the walk interpreted against the gather reference, n
-    steps (an idle slot writing the trash block beside live ones, a
-    slot crossing a block edge): the same tokens; the same pools — bit
-    for bit wherever no step wrote and in layer 0 (its rows come from
-    the tokens alone), to f32 rounding in the rows of layer 1 (the
-    online softmax is a refactoring of the reference's, not its
-    bits); and the donated pool consumed, not copied."""
+    steps (an idle slot beside live ones, a slot crossing a block
+    edge): the live slots' tokens the same; the same pools outside the
+    trash block — bit for bit wherever no step wrote and in layer 0
+    (its rows come from the tokens alone), to f32 rounding in the rows
+    of layer 1 (the online softmax is a refactoring of the reference's,
+    not its bits); the trash block, which the reference's scatter
+    writes for the idle slot, untouched by the kernels; and the donated
+    pool consumed, not copied."""
     cfg, params = tiny_model
     bs, w, slots = 8, 4, 4
     tables = np.zeros((slots, w), np.int32)     # slot 3 idle: trash
@@ -512,16 +579,19 @@ def test_decode_steps_kernel_matches_gather_in_place(tiny_model, n):
     want_toks, want = run(impl="gather")
     got_toks, got = run(impl="paged_flash", interpret=True)
     assert got_toks.shape == (n, slots)
-    np.testing.assert_array_equal(got_toks, want_toks)
-    pos = lengths[:, None] + np.arange(n)[None]             # (slots, n)
-    phys = np.take_along_axis(tables, pos // bs, axis=1)
+    np.testing.assert_array_equal(got_toks[:, :3], want_toks[:, :3])
+    pos = lengths[:3, None] + np.arange(n)[None]            # (live, n)
+    phys = np.take_along_axis(tables[:3], pos // bs, axis=1)
     wrote = np.zeros(fresh["k"].shape[1:4:2], bool)         # (nb, bs)
     wrote[phys, pos % bs] = True
     for k in "kv":
-        np.testing.assert_array_equal(got[k][0], want[k][0])
-        np.testing.assert_allclose(got[k], want[k], rtol=2e-5, atol=2e-5)
+        assert (want[k][:, kc.TRASH] != fresh[k][:, kc.TRASH]).any()
+        np.testing.assert_array_equal(got[k][0, 1:], want[k][0, 1:])
+        np.testing.assert_allclose(got[k][:, 1:], want[k][:, 1:],
+                                   rtol=2e-5, atol=2e-5)
         still = ~np.broadcast_to(wrote[None, :, None, :, None],
                                  got[k].shape)
+        assert still[:, kc.TRASH].all()
         np.testing.assert_array_equal(got[k][still], fresh[k][still])
         assert (got[k][~still] != fresh[k][~still]).any()
 
@@ -592,11 +662,11 @@ def test_engine_kernel_impl_matches_gather_impl(tiny_model):
 
 def test_engine_counts_what_the_walk_fetched(tiny_model):
     """llm_decode_kv_fetch_tokens: the kernel's fetch rule summed over
-    ALL slots (idle ones are walked too, at length 1 + step) and a
-    block's steps, observed beside llm_decode_ctx_tokens. One request
-    alone in three slots: output token i >= 1 of a P-token prompt
-    walks ceil((P + i) / block) blocks and each idle slot one. The
-    gather impl walks nothing and counts nothing."""
+    a block's slots and steps, observed beside llm_decode_ctx_tokens.
+    One request alone in three slots: output token i >= 1 of a P-token
+    prompt walks ceil((P + i) / block) blocks and the two slots that
+    hold no request none. The gather impl walks nothing and counts
+    nothing."""
     from ray_tpu.util import metrics as M
     cfg, params = tiny_model
     prompt, new, bs, slots = _prompt(130, 13), 9, 8, 3
@@ -618,10 +688,47 @@ def test_engine_counts_what_the_walk_fetched(tiny_model):
     assert sum(hist._sums.values()) == before
     asyncio.run(gen("paged_flash"))
     want = sum(pa.fetched_positions(len(prompt) + i, bs)
-               + (slots - 1) * bs for i in range(1, new))
+               for i in range(1, new))
     assert sum(hist._sums.values()) - before == want
     # 13 + i crosses a block edge at 17 and again at 25: not a constant
-    assert want > (new - 1) * (2 * bs + (slots - 1) * bs)
+    assert want > (new - 1) * 2 * bs
+
+
+@pytest.mark.parametrize("impl", ["gather", "paged_flash"])
+def test_engine_counts_the_slot_steps_that_held_no_request(tiny_model,
+                                                           impl):
+    """llm_decode_idle_slot_steps (PR 57): (slots - the block's
+    requests) x its steps, a block, observed as it is enqueued. Two
+    requests of different budgets in four slots: every decode step has
+    two or three slots with no request, and the idle and the live
+    slot-steps of the run add up to slots x steps."""
+    from ray_tpu.llm.engine import engine_metrics
+    cfg, params = tiny_model
+    slots, news = 4, (9, 5)
+
+    async def gen():
+        eng = LLMEngine(cfg, params, max_slots=slots, max_len=32,
+                        prefill_buckets=(16,), cache_dtype="float32",
+                        kv_block_size=8, prefix_cache=False, kv_impl=impl)
+        outs = await asyncio.gather(*[
+            eng.generate(_prompt(131 + i, 11), max_new_tokens=n)
+            for i, n in enumerate(news)])
+        await eng.stop()
+        return outs
+
+    def sums():
+        m = engine_metrics()
+        return {k: sum(m[k]._sums.values())
+                for k in ("idle_slot_steps", "slot_steps", "block_steps")}
+    before = sums()
+    outs = asyncio.run(gen())
+    assert [len(o["tokens"]) for o in outs] == list(news)
+    d = {k: v - before[k] for k, v in sums().items()}
+    # a reply's first token is the prefill's: one decode step each other
+    assert d["slot_steps"] == sum(n - 1 for n in news)
+    assert d["idle_slot_steps"] == slots * d["block_steps"] \
+        - d["slot_steps"]
+    assert d["idle_slot_steps"] >= (slots - 2) * (max(news) - 1)
 
 
 # --- tensor-parallel paged engines ------------------------------------
