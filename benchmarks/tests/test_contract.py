@@ -1,5 +1,6 @@
 """BENCHMARK.json against the contract's limits and against the files
 the harness finds by name."""
+import fnmatch
 import json
 import os
 import re
@@ -77,15 +78,72 @@ def test_every_cell_finds_its_files_and_reports_enough(bench):
         cell = spec.cell(w["name"], bench)
         e2e = [m["name"] for m in cell["end_to_end"]]
         assert "setup_s" in e2e and len(e2e) >= 2
-        assert cell["per_layer"]
-        for m in cell["per_layer"]:
-            # what a per-layer metric moves is reported in this cell
-            assert m["moves"] in e2e, (w["name"], m["name"])
-        for m in cell["end_to_end"] + cell["per_layer"]:
+        assert cell["per_layer"]    # each held to its file below
+        for m in cell["end_to_end"]:
             mf = spec.metric_file(m["name"])
             assert callable(spec.reader(mf["reader"]))
-            for key in ("unit", "better", "source", "layer", "moves"):
-                assert mf.get(key) == m.get(key), (m["name"], key)
+            for key in ("unit", "better", "source"):
+                assert mf[key] == m[key], (m["name"], key)
+
+
+def _prepared() -> list:
+    """The metric files README.md lists under "A prepared ..." (a name
+    or a ``*`` pattern in backquotes, ``metrics/<...>.json``): files
+    whose entries a later PR adds."""
+    with open(os.path.join(spec.BENCH_DIR, "README.md")) as f:
+        paras = f.read().split("\n\n")
+    return [name for para in paras if para.startswith("**A prepared")
+            for name in re.findall(r"`metrics/([^`]+)\.json`", para)]
+
+
+def _cells(entry, bench) -> list:
+    """The cells an entry is read in: its ``workloads``, or every cell."""
+    return entry.get("workloads", [w["name"] for w in bench["workloads"]])
+
+
+@pytest.mark.parametrize(
+    "entry", spec.benchmark()["per_layer"], ids=lambda m: m["name"])
+def test_a_per_layer_entry_its_file_its_reader_and_its_cells(bench, entry):
+    """One entry a metric where it may (PR 58): what decides a number is
+    the entry's file and reader, what decides WHERE it is read is the
+    entry's own ``workloads`` (``spec.cell`` reads no other list)."""
+    mf = spec.metric_file(entry["name"])
+    assert mf["name"] == entry["name"]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert mf[key] == entry[key], (entry["name"], key)
+    assert callable(spec.reader(mf["reader"]))
+    cells = {w["name"] for w in bench["workloads"]}
+    listed = _cells(entry, bench)
+    assert listed and len(listed) == len(set(listed))
+    for name in listed:
+        assert name in cells, (entry["name"], name)
+        cell = spec.cell(name, bench)
+        assert entry["moves"] in {m["name"] for m in cell["end_to_end"]}
+        assert entry in cell["per_layer"]
+    # a file that keeps a list of its own lists no cell the entry lacks
+    assert set(mf.get("workloads", listed)) <= set(listed)
+
+
+def test_the_list_has_room_and_no_file_is_an_orphan(bench):
+    assert len(bench["per_layer"]) <= 128
+    # the same reading under two names in one cell is one entry too many
+    # (a cell's own suffixes that tier-1 pins apart from another cell's
+    # are different cells: PERF.md section 7)
+    seen = {}
+    for m in bench["per_layer"]:
+        mf = spec.metric_file(m["name"])
+        key = json.dumps([mf["reader"], mf.get("args", {})], sort_keys=True)
+        for cell in _cells(m, bench):
+            assert seen.setdefault((key, cell), m["name"]) == m["name"]
+    entered = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    prepared = _prepared()
+    assert prepared
+    for f in sorted(os.listdir(os.path.join(spec.BENCH_DIR, "metrics"))):
+        name, ext = os.path.splitext(f)
+        assert ext == ".json", f
+        assert name in entered or any(
+            fnmatch.fnmatchcase(name, p) for p in prepared), \
+            f"metrics/{f} has no entry and README.md prepares none"
 
 
 def test_the_kept_result_is_the_contracts_last_line():

@@ -1,45 +1,44 @@
 """The per-layer metrics that read what the ENGINE counts and times
-(PR 24): the two readers that take their denominators from the engine's
+(PR 24): the reader that takes its denominator from the engine's
 counters over the traced seconds, and the counter-ratio metrics of the
 decode gap, the prefill and the stream. On a parent without the counters
-every one of them reads None and is left out of the line."""
+every one of them reads None and is left out of the line. Since PR 58
+the chat cell's and the hybrid cell's copies are one entry each
+(``.serve``); the kernel's counted roofline went with its reader, and the
+prefill's time a thousand tokens is read from the copy that stays."""
 import pytest
 
-from harness import kernels, peaks, spec
+from harness import spec
 
-NEW = {"engine_gap_ms_per_block.chat": "counter_ratio",
-       "engine_admit_ms_per_block.chat": "counter_ratio",
-       "engine_host_ms_per_block.chat": "counter_ratio",
-       "prefill_ms_per_ktok.chat": "counter_ratio",
-       "stream_lag_mean_ms.chat": "counter_ratio",
-       "decode_dev_ms_per_step_counted.chat":
-           "decode_dev_ms_per_counted_step",
-       "paged_decode_roofline_counted.chat":
-           "paged_decode_roofline_counted"}
-
-MODEL = {"num_hidden_layers": 8, "num_attention_heads": 32,
-         "num_key_value_heads": 8, "hidden_size": 4096}
+CELLS = ["serve-chat-open", "serve-exaone-reason-open"]
+NEW = {"engine_gap_ms_per_block.serve": "counter_ratio",
+       "engine_admit_ms_per_block.serve": "counter_ratio",
+       "engine_host_ms_per_block.serve": "counter_ratio",
+       "stream_lag_mean_ms.serve": "counter_ratio",
+       "decode_dev_ms_per_step_counted.serve":
+           "decode_dev_ms_per_counted_step"}
 
 
 def _ctx(trace_counters, window_counters=None):
-    trace = {"programs": {"decode": {"s": 7.5, "calls": 17}},
-             "kernels": {"paged_decode": {"s": 5.0, "calls": 400}}}
-    return {"trace": trace, "model": MODEL,
-            "info": {"device": {"kind": "TPU v5 lite"}},
+    trace = {"programs": {"decode": {"s": 7.5, "calls": 17}}, "kernels": {}}
+    return {"trace": trace,
             "counters": {"window": window_counters or {},
                          "trace": trace_counters}}
 
 
-def test_new_metrics_resolve_through_the_cell():
-    cell = spec.cell("serve-chat-open")
-    mine = {m["name"]: m for m in cell["per_layer"] if m["name"] in NEW}
+@pytest.mark.parametrize("cell", CELLS)
+def test_new_metrics_resolve_through_both_cells(cell):
+    mine = {m["name"]: m for m in spec.cell(cell)["per_layer"]
+            if m["name"] in NEW}
     assert set(mine) == set(NEW)
     for name, m in mine.items():
         mf = spec.metric_file(name)
         assert mf["reader"] == NEW[name]
         assert callable(spec.reader(mf["reader"]))
         assert m["moves"] == "tpot_p50_ms" == mf["moves"]
-        for key in ("unit", "better", "source", "layer", "workloads"):
+        # one entry for both cells; the list is BENCHMARK.json's alone
+        assert m["workloads"] == CELLS and "workloads" not in mf
+        for key in ("unit", "better", "source", "layer"):
             assert mf[key] == m[key], (name, key)
     # they were appended, in this order, and later entries after them
     names = [m["name"] for m in spec.benchmark()["per_layer"]]
@@ -58,31 +57,12 @@ def test_decode_ms_per_counted_step():
     assert read(untraced) is None
 
 
-def test_paged_roofline_from_counted_contexts():
-    read = spec.reader("paged_decode_roofline_counted")
-    c = {"ctx_tokens_sum": 400000.0, "slot_steps_sum": 1340.0}
-    kvh, g, hd = 8, 4, 128
-    need = 8 * kernels.paged_decode_bytes(400000.0, 1340.0, kvh, g, hd)
-    flops = 8 * kernels.paged_decode_flops(400000.0, kvh, g, hd)
-    pk = peaks.peaks("TPU v5 lite")
-    least = max(need / pk["hbm_bytes_per_s"], flops / pk["bf16_flops"])
-    assert least == need / pk["hbm_bytes_per_s"]    # bandwidth rules
-    assert read(_ctx(c)) == pytest.approx(100.0 * least / 5.0)
-    assert 0 < read(_ctx(c)) < 100
-    assert read({**_ctx(c), "trace": None}) is None
-    assert read(_ctx({})) is None               # the parent: no counters
-    assert read(_ctx({"ctx_tokens_sum": 1.0, "slot_steps_sum": 0})) is None
-    no_kernel = _ctx(c)
-    no_kernel["trace"] = {"programs": {}, "kernels": {}}
-    assert read(no_kernel) is None
-
-
 @pytest.mark.parametrize("name, want", [
-    ("engine_gap_ms_per_block.chat", 40.0),
-    ("engine_admit_ms_per_block.chat", 25.0),
-    ("engine_host_ms_per_block.chat", 15.0),
-    ("prefill_ms_per_ktok.chat", 125.0),
-    ("stream_lag_mean_ms.chat", 2.0)])
+    ("engine_gap_ms_per_block.serve", 40.0),
+    ("engine_admit_ms_per_block.serve", 25.0),
+    ("engine_host_ms_per_block.serve", 15.0),
+    ("prefill_ms_per_ktok.longdoc", 125.0),
+    ("stream_lag_mean_ms.serve", 2.0)])
 def test_counter_metrics_by_hand(name, want):
     window = {"gap_sum": 4.0, "gap_admit_sum": 2.5, "gap_count": 100.0,
               "ttft_device_sum": 2.0, "prefill_tokens_sum": 16000.0,
@@ -110,6 +90,6 @@ def test_admit_and_host_add_up_to_the_gap():
     got = {n: spec.reader("counter_ratio")(
         _ctx(c), **spec.metric_file(n)["args"])
         for n in NEW if n.startswith("engine_")}
-    assert got["engine_admit_ms_per_block.chat"] \
-        + got["engine_host_ms_per_block.chat"] \
-        == pytest.approx(got["engine_gap_ms_per_block.chat"])
+    assert got["engine_admit_ms_per_block.serve"] \
+        + got["engine_host_ms_per_block.serve"] \
+        == pytest.approx(got["engine_gap_ms_per_block.serve"])

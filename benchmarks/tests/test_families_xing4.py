@@ -172,7 +172,9 @@ def test_the_new_reader_reads_nothing_where_there_is_nothing(cell):
 
 def test_the_cells_metrics_resolve(cell):
     names = [m["name"] for m in cell["per_layer"]]
-    assert len(names) == len(set(names)) == 24
+    # 24 with the cell (PR 54); entries that list all four serve cells
+    # have joined since (PR 56's five, PR 58's one)
+    assert len(names) == len(set(names)) >= 24
     assert set(READERS) <= set(names)
     for name in names:      # every metric of the cell finds its files
         assert callable(spec.reader(spec.metric_file(name)["reader"]))

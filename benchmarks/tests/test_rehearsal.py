@@ -178,8 +178,12 @@ def test_a_later_pr_adds_files_only(tmp_path):
         "name": "throwaway-cell", "config": "throwaway-serve",
         "traffic": "throwaway-chat", "chips": 1, "why": "test"})
     bench["per_layer"].append(metric)
-    for m in bench["end_to_end"]:
-        if "workloads" in m and "serve-chat-open" in m["workloads"]:
+    # what an entry already reads with the same reader and arguments,
+    # the cell JOINS (PR 58): its name in the entry's list, as with an
+    # end-to-end metric; the metric file is not touched
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] == "decode_batch_mean.serve" or (
+                "bound" in m and "serve-chat-open" in m.get("workloads", ())):
             m["workloads"].append("throwaway-cell")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
@@ -187,6 +191,8 @@ def test_a_later_pr_adds_files_only(tmp_path):
     out = last_line(run_cell(str(root), "throwaway-cell", trace=1,
                              extra_env=env))
     assert out["metrics"]["throwaway_requests"]["value"] > 0
+    assert set(out["metrics"]) == {"throwaway_requests",
+                                   "decode_batch_mean.serve"}
     out = last_line(run_cell(str(root), "throwaway-cell", trace=0,
                              extra_env=env))
     assert {"tpot_p50_ms", "setup_s"} <= set(out["metrics"])

@@ -180,13 +180,15 @@ def test_frozen_leaves_stand_still_to_the_bit_and_the_rest_learns(
                               cfg.vocab_size, dtype=jnp.int32)
     batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
     with mesh:
-        state0 = init_fn(key)
-        state, kept = state0, []
+        state, kept = init_fn(key), []
+        # ``step_fn`` consumes the state it is handed (PR 53: donated),
+        # so what the leaves were is copied before the first step
+        params0 = jax.tree.map(jnp.copy, state.params)
         for _ in range(3):
             state, met = step_fn(state, batch)
             kept.append(met)
     # the optimizer's state is the default's tree, leaf for leaf
-    default = jax.eval_shape(pmesh.default_optimizer().init, state0.params)
+    default = jax.eval_shape(pmesh.default_optimizer().init, params0)
     assert jax.tree.structure(state.opt_state) == jax.tree.structure(default)
     assert [(x.shape, x.dtype) for x in jax.tree.leaves(state.opt_state)] \
         == [(x.shape, x.dtype) for x in jax.tree.leaves(default)]
@@ -194,7 +196,7 @@ def test_frozen_leaves_stand_still_to_the_bit_and_the_rest_learns(
     def by_path(tree):
         return {jax.tree_util.keystr(p): np.asarray(x) for p, x
                 in jax.tree_util.tree_leaves_with_path(tree)}
-    before, after = by_path(state0.params), by_path(state.params)
+    before, after = by_path(params0), by_path(state.params)
     routers = [p for p in before if "'router'" in p]
     assert routers
     for p in routers:

@@ -1,8 +1,11 @@
 """The per-layer metrics that read a served token's parts (PR 37): the
 four host parts of a decode block and the stream hop from the engine's
-counters, the launch around a decode step from counters and trace, and
-the medians over the program's request spans. On a parent without the
-spans and counters each reads None and is left out of the line."""
+counters, and the medians over the program's request spans. On a parent
+without the spans and counters each reads None and is left out of the
+line. The launch around a decode step went with its reader (PR 58:
+below zero by construction; ``decode_around_ms_per_step.serving`` reads
+what it was meant to); the two metrics PR 56 could not enter are read
+here by hand."""
 import pytest
 
 from harness import spec
@@ -12,7 +15,6 @@ NEW = {"engine_prepare_ms_per_block.serve": "counter_ratio",
        "engine_account_ms_per_block.serve": "counter_ratio",
        "engine_emit_ms_per_block.serve": "counter_ratio",
        "engine_yield_ms_per_block.serve": "counter_ratio",
-       "decode_launch_ms_per_step.serve": "decode_launch_ms_per_step",
        "engine_tpot_p50_ms.serve": "request_spans",
        "stream_consume_us_per_token.serve": "counter_ratio",
        "proxy_token_us.serve": "request_spans",
@@ -57,8 +59,6 @@ COUNTERS = {"block_steps_count": 20.0, "block_steps_sum": 130.0,
             "loop_decode_account_sum": 0.001,
             "loop_emit_sum": 0.008, "loop_yield_sum": 0.05,
             "decode_hop_sum": 0.012,
-            "loop_decode_dispatch_sum": 0.1,
-            "loop_decode_readback_sum": 1.3,
             "stream_consume_sum": 0.013, "stream_consume_count": 260.0}
 
 
@@ -75,8 +75,6 @@ def _ctx(trace_counters, decode_s=1.274):
     ("engine_yield_ms_per_block.serve", 2.5),
     ("engine_hop_ms_per_block.serve", 0.6),
     ("stream_consume_us_per_token.serve", 50.0),
-    # (0.1 + 1.3 - 1.274) s over 130 counted steps
-    ("decode_launch_ms_per_step.serve", 1e3 * 0.126 / 130),
 ])
 def test_counter_metrics(name, want):
     assert _read(name, _ctx(COUNTERS)) == pytest.approx(want)
@@ -96,15 +94,30 @@ def test_counter_metrics(name, want):
                              "stream_consume_count": 0.0})) is None
 
 
-def test_decode_launch_needs_the_trace_and_the_phase_histograms():
-    read = spec.reader("decode_launch_ms_per_step")
-    assert read({**_ctx(COUNTERS), "trace": None}) is None
-    no_decode = _ctx(COUNTERS)
-    no_decode["trace"]["programs"] = {}
-    assert read(no_decode) is None
-    before_pr24 = {k: v for k, v in COUNTERS.items()
-                   if not k.startswith("loop_")}
-    assert read(_ctx(before_pr24)) is None
+def test_the_wait_behind_the_block_in_flight_an_admission():
+    """``prefill_behind_ms_per_admit.serving``: an accepted reader on the
+    histogram PR 56 added, cut at the trace's edges, in all four serve
+    cells."""
+    name = "prefill_behind_ms_per_admit.serving"
+    mf = spec.metric_file(name)
+    assert (mf["reader"], mf["layer"]) == ("counter_ratio",
+                                           "serving forwards")
+    assert mf["args"] == {"num": "loop_prefill_behind_sum",
+                          "den": "loop_prefill_behind_count",
+                          "scale": 1000.0, "scope": "trace"}
+    entry = next(m for m in spec.benchmark()["per_layer"]
+                 if m["name"] == name)
+    assert entry["workloads"] == CELLS + ["serve-mistral4-longdoc-open",
+                                          "serve-xing4-rag-open"]
+    # 0.3 s waited by 12 admissions
+    c = {"loop_prefill_behind_sum": 0.3, "loop_prefill_behind_count": 12.0}
+    assert _read(name, _ctx(c)) == pytest.approx(25.0)
+    assert _read(name, _ctx({})) is None        # the parent of PR 56
+    assert _read(name, _ctx({**c, "loop_prefill_behind_count": 0.0})) \
+        is None                                 # no admission traced
+    untraced = _ctx(c)
+    del untraced["counters"]["trace"]
+    assert _read(name, untraced) is None
 
 
 # --- request spans ----------------------------------------------------------
@@ -189,6 +202,33 @@ def test_span_metrics_are_cut_at_the_traces_edges_where_there_are_any():
         == pytest.approx(4.0, abs=1e-3)
     ctx["trace_edges"] = (W[0] + 20.0, W[0] + 28.0)     # nothing ended
     assert _read("engine_tpot_p50_ms.serve", ctx) is None
+
+
+def test_a_requests_token_with_its_stalls_taken_out():
+    """``engine_tpot_unstalled_p50_ms.serve`` is ``engine_tpot_p50_ms``
+    less ``engine_tpot_stall_p50_ms`` request by request: the median of
+    the differences, not the difference of the medians."""
+    name = "engine_tpot_unstalled_p50_ms.serve"
+    mf = spec.metric_file(name)
+    assert mf["reader"] == "request_spans" and mf["args"] == {
+        "component": "engine", "seg": "generate", "num": ["tpot_s"],
+        "minus": ["tpot_stall_s"], "scale": 1000.0}
+    for cell in CELLS:
+        assert name in {m["name"] for m in spec.cell(cell)["per_layer"]}
+    evs = []
+    for i, (tpot, stall) in enumerate(((0.010, 0.001), (0.012, 0.004),
+                                       (0.020, 0.0005))):
+        for e in _request(f"in{i}", 5.0 + i, 51, tpot):
+            if e.get("seg") == "generate":
+                e = {**e, "tpot_stall_s": stall}
+            evs.append(e)
+    ctx = _spans_ctx(evs)
+    assert _read("engine_tpot_stall_p50_ms.serve", ctx) \
+        == pytest.approx(1.0)
+    # 9.0, 8.0, 19.5 -> 9.0 (12.0 - 1.0 would say 11.0)
+    assert _read(name, ctx) == pytest.approx(9.0)
+    # the parent of PR 56: a token's time, no stall beside it
+    assert _read(name, _spans_ctx(_request("p", 5.0, 51, 0.010))) is None
 
 
 def test_span_metrics_on_a_program_without_the_attributes():
