@@ -205,6 +205,7 @@ def engine_metrics() -> dict:
       llm_kv_blocks_global_size  pool blocks of the global layers in use
       llm_kv_blocks_window_size  pool blocks of the window layers in use
       llm_kv_blocks_latent_size  pool blocks of the latent layers in use
+      llm_kv_blocks_state_size   states (a slot's, all state layers) held
       llm_kv_window_freed_size   window-layer blocks freed for this
                                  block (their sequences' windows passed
                                  them)
@@ -306,6 +307,8 @@ def engine_metrics() -> dict:
              "decode block"),
             ("kv_blocks_latent", "Latent-layer pool blocks in use at a "
              "decode block"),
+            ("kv_blocks_state", "States (a slot's state and conv tail in "
+             "every state layer) held at a decode block"),
             ("latent_rows_expanded", "Cache rows of a latent layer "
              "expanded to per-head keys and values per admitted request's "
              "prefill (one layer's count: a prefix re-expanded for every "
@@ -515,11 +518,16 @@ class LLMEngine:
         # ids and a table a kind, each a dict by kind for every model
         self._layout = kvcache.pool_kinds(cfg)
         windowed = kvcache.WINDOW in dict(self._layout)
+        # state layers keep a state and a conv tail a SLOT: no block ids,
+        # no table, nothing the kernel walks
+        stateful = kvcache.STATE in dict(self._layout)
+        paged = [(kind, layers) for kind, layers in self._layout
+                 if kind != kvcache.STATE]
         # (layers, window) of each layer kind the paged kernel walks:
         # what llm_decode_kv_fetch_tokens counts by
         self._walks = [(len(layers), lm.window_of(cfg, kind))
-                       for kind, layers in self._layout]
-        if mesh is not None and (windowed
+                       for kind, layers in paged]
+        if mesh is not None and (windowed or stateful
                                  or kvcache.LATENT in dict(self._layout)
                                  or lm.has_experts(cfg)):
             raise NotImplementedError(
@@ -567,6 +575,16 @@ class LLMEngine:
                     "is not supported for a model whose window layers "
                     "free the blocks they have passed")
             prefix_cache = False
+        if stateful:
+            # a cached chain of blocks has no state to start its suffix
+            # from (no snapshot is kept at block edges). Asked for:
+            # refused; left to the Config's default: off
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with state layers: prefix reuse is "
+                    "not supported for a model with state-space layers, "
+                    "whose recurrent state is kept a slot and not a block")
+            prefix_cache = False
         if prefix_cache is None:
             prefix_cache = bool(getattr(_cfg, "kvcache_prefix_cache",
                                         True))
@@ -581,6 +599,11 @@ class LLMEngine:
             raise ValueError(
                 "speculative decoding is not supported with window "
                 "layers: the verify forward attends global layers only")
+        if spec and stateful:
+            raise ValueError(
+                "speculative decoding is not supported with state layers: "
+                "a rejected draft would have to roll a recurrent state "
+                "back, and no snapshot of it is kept")
         # Speculative decoding (llm/spec.py): draft-and-verify rides
         # the block-table verify forward
         self._spec = bool(spec)
@@ -620,7 +643,7 @@ class LLMEngine:
         block_bytes = {
             kind: len(layers) * self._block
             * kvcache.row_bytes(cfg, kind, cache_dtype)
-            for kind, layers in self._layout}
+            for kind, layers in paged}
         whole = self._layout[0][0]
         window = None
         if windowed:
@@ -630,14 +653,18 @@ class LLMEngine:
                       self.steps_per_sync)
         # the pool shards its kv heads over the tensor axis
         tp = mesh.shape[tensor_axis] if mesh is not None else 1
+        # sized exactly, like the window layers' rings: a state a slot
+        state_bytes = max_slots * kvcache.state_slot_bytes(
+            cfg, cache_dtype) if stateful else 0
         nb = kvcache.auto_pool_blocks(
             max_slots, self._table_w, block_bytes[whole] // tp,
             kv_pool_blocks,
-            reserved_bytes=(window[0] * block_bytes[kvcache.WINDOW]
-                            if window else 0))
+            reserved_bytes=state_bytes + (
+                window[0] * block_bytes[kvcache.WINDOW] if window else 0))
         self._pool = kvcache.init_pool(
             cfg, nb, self._block, jnp.dtype(cache_dtype),
-            window_blocks=window[0] if window else 0)
+            window_blocks=window[0] if window else 0,
+            state_slots=max_slots if stateful else 0)
         if mesh is not None:
             # pool shards its kv-head dim (Megatron layout); block ids
             # index dim 1, orthogonal to the shard, so
@@ -661,12 +688,18 @@ class LLMEngine:
         self._kv = kvcache.KVBlockManager(
             nb, self._block, table_width=self._table_w,
             prefix_cache=prefix_cache, metrics=self._kvm, window=window,
-            kind=whole)
+            kind=whole, state_slots=max_slots if stateful else 0)
         self._block_bytes = kvcache.kind_block_bytes(self._pool)
         # every slot's block table, by kind (each kind its own ids)
         self._tables = {kind: np.full((max_slots, self._table_w),
                                       kvcache.TRASH, np.int32)
-                        for kind, _ in self._layout}
+                        for kind, _ in paged}
+        self._stateful = stateful
+        self._state_admits = 0      # states started from zeros
+        # the layers with a router: what llm_moe_experts_held counts by
+        self._expert_layers = (
+            lm.layer_kinds(cfg).count(lm.EXPERTS) if lm.single_mixer(cfg)
+            else cfg.n_layers - getattr(cfg, "n_dense_layers", 0))
         self._freed: dict = {}      # kind -> freed_by_kind() last seen
         self._blocked: deque = deque()   # admits parked on pool
         self._seq_counter = 0
@@ -739,6 +772,13 @@ class LLMEngine:
             out["blocks_used" + tail] = used
         for kind, freed in self._kv.freed_by_kind().items():
             out[kind + "_blocks_freed"] = freed
+        if self._stateful:
+            per_slot = self._block_bytes[kvcache.STATE]
+            out.update(
+                state_layers=len(dict(self._layout)[kvcache.STATE]),
+                state_bytes=per_slot * self.max_slots,
+                state_bytes_per_slot=per_slot,
+                state_admits=self._state_admits)
         return out
 
     @contextlib.contextmanager
@@ -875,6 +915,11 @@ class LLMEngine:
         stop = [list(map(int, s)) for s in stop] if stop else None
         if stop and any(not s for s in stop):
             raise ValueError("empty stop sequence")
+        if prefilled is not None and self._stateful:
+            raise ValueError(
+                "a prefilled request (the prefill/decode hand-off) is not "
+                "supported with state layers: the payload carries K and V "
+                "rows and no recurrent state to decode from")
         if prefilled is not None:
             # validate at submission: a malformed payload must fail THIS
             # request, not blow up the shared scheduler loop mid-admit
@@ -1308,15 +1353,15 @@ class LLMEngine:
             bs = self._block
             fetched = sum(layers * sum(run(a, block, bs, w) for a in lens)
                           for layers, w in self._walks)
+            # over the layers that attend (every layer, but for a model
+            # whose layers are each one mixer)
             self._m["kv_fetch_tokens"].observe(
-                fetched / self.cfg.n_layers)
+                fetched / sum(layers for layers, _ in self._walks))
         if counts is not None:
             for key, per_step in counts.items():
                 self._m["moe_" + key].observe(int(per_step.sum()))
             self._m["moe_experts_held"].observe(
-                block * self.cfg.n_held
-                * (self.cfg.n_layers
-                   - getattr(self.cfg, "n_dense_layers", 0)))
+                block * self.cfg.n_held * self._expert_layers)
 
     def _members(self, active: List[int]):
         """(sorted trace ids of the batch's traced requests, the first
@@ -1453,6 +1498,7 @@ class LLMEngine:
                 self._pool = kvcache.scatter_bucket(
                     self._pool, kv, self._targets(slot, nb), nb,
                     self._layout)
+                self._take_state(slot, kv)
                 ran = n
                 self._count_prefill(
                     1, lm.chunk_expanded_rows(self.cfg, b, 0, b))
@@ -1460,6 +1506,18 @@ class LLMEngine:
                 logits = self._prefill_into_blocks(r, hit, slot, disp.t0)
                 ran = n - self._prefill_start(hit)
         return self._first_token(slot, r, disp, logits, ran)
+
+    def _take_state(self, slot: int, state: dict) -> None:
+        """An admitted request's state layers: what its prefill left at the
+        prompt's length (started from zeros: ``lm.prefill``, or the chunked
+        prefill's accumulator) becomes ``slot``'s, whole. Whatever the
+        slot's last request left there is gone, and the slot is this
+        request's until it is freed: a decode block touches it only while
+        the slot's table row names the request's blocks."""
+        if not self._stateful:
+            return
+        self._pool = kvcache.write_state(self._pool, state, slot)
+        self._state_admits += 1
 
     def _count_prefill(self, chunks: int, rows: int) -> None:
         """One admitted request's prefill forwards and the cache rows
@@ -1603,6 +1661,9 @@ class LLMEngine:
         acc = kvcache.gather_table(
             self._pool, self._targets(slot, self._table_w),
             self._acc_len(), self._layout)
+        # a state layer's part of the accumulator: zeros, handed on from
+        # chunk to chunk ({} for a model without state layers)
+        acc.update(kvcache.fresh_state(self._pool))
         off = self._prefill_start(hit)
         logits = None
         chunks = rows = 0
@@ -1623,6 +1684,7 @@ class LLMEngine:
         self._pool = kvcache.scatter_table(
             self._pool, acc, self._targets(slot, self._table_w, hit),
             self._layout)
+        self._take_state(slot, acc)
         return logits
 
     @staticmethod

@@ -32,6 +32,16 @@ TPU-native on the engine's static-shape rules:
   pool pressure (a parent evicted before its child would orphan the
   child: chain lookups walk from the root).
 
+A model with STATE layers (a state-space mixer, ops/ssm.py) keeps for
+each of them ONE recurrent state and one conv tail A SLOT, not a row a
+position: the kind ``state``'s two arrays are indexed (the kind's layers,
+slot, ...), sized from the engine's slots, written whole by a prefill at
+the prompt's length, updated in place by every decode step of a live
+slot, and the manager reports them beside the blocks (``state_slots``). A
+state cannot be rolled back or cut at a block edge, so prefix reuse,
+speculation and the prefill/decode hand-off are refused for such a model
+(llm/engine.py).
+
 A model with WINDOW layers (sliding-window attention: a query attends
 the last ``sliding_window`` positions) has a second, small pool for
 them, with block ids and a table of its own a sequence, accounted for
@@ -177,8 +187,11 @@ class KVBlockManager:
                  table_width: int, prefix_cache: bool = True,
                  metrics: Optional[dict] = None,
                  window: Optional[Tuple[int, int, int]] = None,
-                 kind: str = "global"):
-        """``window`` = (blocks of the window layers' pool, the sliding
+                 kind: str = "global", state_slots: int = 0):
+        """``state_slots``: the states a model with state layers has, one a
+        slot of the engine's, which bounds the sequences; here they are
+        only reported, a state a live sequence.
+        ``window`` = (blocks of the window layers' pool, the sliding
         window, the steps one decode dispatch runs at most), for a
         model with window layers. ``kind``: the layer kind whose pool
         the ``num_blocks`` ids are of, the one a sequence holds from its
@@ -191,6 +204,12 @@ class KVBlockManager:
                 "prefix caching is not supported with window layers: a "
                 "window layer frees the blocks its window has passed, "
                 "and a cached prefix would have to keep them")
+        if state_slots and prefix_cache:
+            raise ValueError(
+                "prefix caching is not supported with state layers: a "
+                "cached chain of blocks has no recurrent state to start "
+                "its suffix from")
+        self.state_slots = int(state_slots)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.table_width = int(table_width)
@@ -282,6 +301,8 @@ class KVBlockManager:
         used = {self.kind: self.used_blocks()}
         if self.window is not None:
             used[WINDOW] = self.window_used_blocks()
+        if self.state_slots:
+            used[STATE] = len(self.seqs)        # a state a sequence
         return used
 
     def free_by_kind(self) -> Dict[str, int]:
@@ -289,6 +310,8 @@ class KVBlockManager:
         free = {self.kind: len(self.free)}
         if self.window is not None:
             free[WINDOW] = len(self.wfree)
+        if self.state_slots:
+            free[STATE] = self.state_slots - len(self.seqs)
         return free
 
     def freed_by_kind(self) -> Dict[str, int]:
@@ -623,9 +646,15 @@ def _jx():
 # cannot take part of a tile; the widths are the configuration's (c 256 or
 # 512 values, kr 64 in the served ones: 768 or 1,280 bytes a row in bf16),
 # and a kr of 64 values lies in 128, the rest zero.
-GLOBAL, WINDOW, LATENT = "global", "window", "latent"
+#
+# A STATE layer (a state-space mixer) keeps no row a position: its pair of
+# arrays is one recurrent state (float32: it is carried, never rounded) and
+# one conv tail A SLOT, (the kind's layers, slots, ...). It has no block ids
+# and no table; the slot is the index.
+GLOBAL, WINDOW, LATENT, STATE = "global", "window", "latent", "state"
 LANES = 128
-POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv"), LATENT: ("c", "kr")}
+POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv"), LATENT: ("c", "kr"),
+             STATE: ("ssm", "conv")}
 _TABLE_NAMES = {GLOBAL: "table", WINDOW: "window_table",
                 LATENT: "table"}                            # alloc_seq
 
@@ -637,6 +666,11 @@ def pool_kinds(cfg) -> tuple:
     1)),)`` for the Llama family."""
     from ray_tpu.llm.model import kind_layers
     kinds = kind_layers(cfg)
+    if STATE in kinds and set(kinds) != {GLOBAL, STATE}:
+        raise NotImplementedError(
+            "state layers are served beside global layers only: a model "
+            "whose other layers are window or latent layers (or that has "
+            "none) is not served yet")
     if set(kinds) == {WINDOW}:
         raise NotImplementedError(
             "a model whose layers are all window layers is not served "
@@ -653,6 +687,9 @@ def row_shapes(cfg, kind: str) -> tuple:
     if kind == LATENT:
         return tuple((-(-w // LANES) * LANES,)
                      for w in (cfg.kv_lora_rank, cfg.qk_rope_head_dim))
+    if kind == STATE:       # a slot's, whatever the position
+        return ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                (cfg.ssm_conv_kernel - 1, cfg.ssm_widths[1]))
     return ((cfg.n_kv_heads, cfg.head_dim),) * 2
 
 
@@ -663,17 +700,41 @@ def row_bytes(cfg, kind: str, dtype) -> int:
         * jnp.dtype(dtype).itemsize
 
 
+def state_dtypes(dtype) -> tuple:
+    """The dtypes of a STATE layer's two arrays: the recurrent state is
+    float32 whatever the cache's dtype, the conv tail the cache's."""
+    _, jnp = _jx()
+    return jnp.dtype(jnp.float32), jnp.dtype(dtype)
+
+
+def state_slot_bytes(cfg, dtype) -> int:
+    """Bytes one slot's states and conv tails cost, all state layers."""
+    layers = dict(pool_kinds(cfg)).get(STATE, ())
+    return len(layers) * sum(
+        int(np.prod(shape)) * dt.itemsize for shape, dt in zip(
+            row_shapes(cfg, STATE), state_dtypes(dtype)))
+
+
 def init_pool(cfg, num_blocks: int, block_size: int, dtype,
-              window_blocks: int = 0) -> dict:
+              window_blocks: int = 0, state_slots: int = 0) -> dict:
     """The pool tensors, a pair a layer kind (``POOL_KEYS``), each (the
     kind's layers, its blocks, *a row's heads, block_size, a row's
     width): k/v (global layers, num_blocks, kv_heads, block_size,
     head_dim); for window layers wk/wv (window layers, window_blocks,
     ...); for latent layers c/kr (latent layers, num_blocks, block_size,
-    kv_lora_rank | qk_rope_head_dim)."""
+    kv_lora_rank | qk_rope_head_dim); for state layers ssm/conv (state
+    layers, ``state_slots``, *a slot's state | its conv tail)."""
     _, jnp = _jx()
     pool = {}
     for kind, layers in pool_kinds(cfg):
+        if kind == STATE:
+            if state_slots < 1:
+                raise ValueError("a model with state layers needs "
+                                 "state_slots >= 1: a state a slot")
+            for key, row, dt in zip(POOL_KEYS[kind], row_shapes(cfg, kind),
+                                    state_dtypes(dtype)):
+                pool[key] = jnp.zeros((len(layers), state_slots, *row), dt)
+            continue
         blocks = max(2, window_blocks) if kind == WINDOW else num_blocks
         for key, row in zip(POOL_KEYS[kind], row_shapes(cfg, kind)):
             pool[key] = jnp.zeros(
@@ -771,16 +832,24 @@ def _by_kind(ids, pool: dict) -> dict:
 
 def _layout(pool: dict, kinds=None) -> tuple:
     """``pool_kinds`` of the model ``pool`` was made for: the caller's,
-    which must agree with the pool, or read off a pool of one kind."""
+    which must agree with the pool, or read off a pool of one kind. What
+    comes back is the kinds that keep a row a POSITION, their layers as rows
+    of a prompt's token-order K/V (prefill's output, the chunked prefill's
+    accumulator): the model's layer indices, but for a model with state
+    layers, whose token-order rows are its global layers' alone."""
     held = tuple((kind, pool_k(pool, kind).shape[0])
                  for kind in _held_kinds(pool))
     kinds = tuple(kinds or ((kind, tuple(range(n))) for kind, n in held))
     layers = sorted(l for _, ls in kinds for l in ls)
-    if tuple((kind, len(ls)) for kind, ls in kinds) != held \
-            or layers != list(range(len(layers))):
+    # beside state layers a model has layers that cache nothing at all
+    whole = len(set(layers)) == len(layers) if STATE in dict(kinds) \
+        else layers == list(range(len(layers)))
+    if tuple((kind, len(ls)) for kind, ls in kinds) != held or not whole:
         raise ValueError(f"the pool holds (kind, layers) {held}, not the "
                          f"layout {kinds}: pass its model's pool_kinds(cfg)")
-    return kinds
+    rows = sorted(l for kind, ls in kinds if kind != STATE for l in ls)
+    return tuple((kind, tuple(rows.index(l) for l in ls))
+                 for kind, ls in kinds if kind != STATE)
 
 
 def _pad_last(x, width: int):
@@ -870,8 +939,16 @@ def _jit(name: str, pool: dict, kinds: tuple = ()):
         def fn(pool, src, dst):
             return {**pool, **{
                 key: pool[key].at[:, dst].set(pool[key][:, src])
-                for kind, keys in POOL_KEYS.items() if kind != WINDOW
+                for kind, keys in POOL_KEYS.items()
+                if kind not in (WINDOW, STATE)
                 for key in keys if key in pool}}
+    elif name == "write_state":
+        @partial(jax.jit, donate_argnums=(0,))
+        def fn(pool, state, slot):
+            return {**pool, **{
+                key: pool[key].at[:, slot].set(
+                    state[key].astype(pool[key].dtype))
+                for key in POOL_KEYS[STATE]}}
     else:
         raise KeyError(name)
     _JITS[key] = fn
@@ -906,6 +983,26 @@ def scatter_table(pool: dict, acc: dict, phys, kinds=None) -> dict:
     so shared blocks are never written). One compile a width."""
     return _jit("scatter_table", pool, _layout(pool, kinds))(
         pool, acc, _by_kind(phys, pool))
+
+
+def fresh_state(pool: dict) -> dict:
+    """What a request's state layers start from: a zero state and a zero
+    conv tail a layer, {"ssm": (state layers, ...), "conv": ...}; {} for a
+    model without state layers."""
+    _, jnp = _jx()
+    return {key: jnp.zeros((pool[key].shape[0], *pool[key].shape[2:]),
+                           pool[key].dtype)
+            for key in POOL_KEYS[STATE] if key in pool}
+
+
+def write_state(pool: dict, state: dict, slot: int) -> dict:
+    """A prefill's state layers' outputs ({"ssm", "conv"}: the state and
+    the conv tail a layer at the prompt's length) become ``slot``'s, whole:
+    whatever the slot held before is gone."""
+    _, jnp = _jx()
+    return _jit("write_state", pool)(
+        pool, {key: state[key] for key in POOL_KEYS[STATE]},
+        jnp.int32(slot))
 
 
 def copy_block(pool: dict, src: int, dst: int) -> dict:
@@ -1136,7 +1233,7 @@ def _live(tables):
 
 def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
                        impl="gather", interpret=False, mesh=None,
-                       axis="tensor"):
+                       axis="tensor", chosen=False):
     """One decode step's (slots, vocab) f32 logits for every slot
     against the paged pool: lm.decode_logits_core with the new token's
     place in the pool worked out from the tables, and the write and
@@ -1156,22 +1253,25 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
         _pool_attend(cfg, tables, _places(tables, positions, bs),
                      jnp.where(live, positions + 1, 0), impl=impl,
                      interpret=interpret, mesh=mesh, axis=axis),
-        live)
+        live, chosen)
 
 
 def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
                         impl="gather", interpret=False, mesh=None,
-                        axis="tensor"):
+                        axis="tensor", chosen=False):
     """The (slots, vocab) f32 logits of ONE decode step against the
     block pool, which is left untouched (not donated) — the parity
     entry point: the same step under impl='paged_flash' and
     impl='gather' must agree (chip_smoke.py checks that on the chip at
     real widths; tests/test_zz_paged_attn.py under the interpreter).
     ``tables``: (slots, width), or by layer kind for a model with
-    window layers."""
+    window layers. ``chosen`` (a model whose layers are each one mixer):
+    (logits, the experts every slot chose in each expert layer: lm.
+    decode_logits_core, the pool AFTER the step, a new one: a comparison
+    then goes on from exactly what the step of these logits wrote)."""
     impl = resolve_attn_impl(impl)
     key_ = ("paged_decode_logits", *_pool_key(pool), impl,
-            bool(interpret), mesh, axis)
+            bool(interpret), mesh, axis, *(("chosen",) if chosen else ()))
     fn = _JITS.get(key_)
     if fn is None:
         jax, _ = _jx()
@@ -1179,9 +1279,11 @@ def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,
         @partial(jax.jit, static_argnames=("cfg",))
         def paged_decode_logits(params, pool, tables, lengths, tokens,
                                 cfg):
-            return _paged_logits_core(
+            out = _paged_logits_core(
                 params, pool, _by_kind(tables, pool), lengths, tokens, cfg,
-                impl=impl, interpret=interpret, mesh=mesh, axis=axis)[0]
+                impl=impl, interpret=interpret, mesh=mesh, axis=axis,
+                chosen=chosen)
+            return (out[0], out[3], out[1]) if chosen else out[0]
         fn = _JITS[key_] = paged_decode_logits
     return fn(params, pool, tables, lengths, tokens, cfg)
 

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.llm.kvcache import GLOBAL, LATENT, WINDOW
+from ray_tpu.llm.kvcache import GLOBAL, LATENT, STATE, WINDOW
 from ray_tpu.models.llama import (LlamaConfig, _rmsnorm, _rope, _rope_pairs,
                                   _rope_tables, _rope_tables_freqs,
                                   yarn_inv_freq, yarn_mscale)
@@ -129,6 +129,20 @@ def _scan_layers(layer, x, xs):
 #   hc_mult          n > 1: the residual stream is n copies a position,
 #                    (n, b, s, d) from the embedding to the final norm,
 #                    mixed by hyper-connections ("the mixed stream" below)
+#
+# ``layer_types`` of "state" and "experts" (beside "global") make every
+# layer ONE mixer behind one norm, x + mixer(RMSNorm(x)) (``_mixer_layer``):
+# a state-space mixer (ops/ssm.py; the ``ssm_*`` fields), whose cache is a
+# recurrent state and a conv tail A SLOT and not a row a position; an expert
+# layer with no attention before it, which caches nothing; attention alone.
+# Each kind's parameters are a stack of its own, ``params[STACKS[kind]]``
+# (the train forward's representation of kinds, models/moe.py
+# ``_kind_layers``), and the same ``_segments`` cut them into scans: a
+# period takes each kind's rows from its own stack.
+
+EXPERTS = "experts"     # a layer that is an expert layer alone: no cache
+STACKS = {STATE: "state_layers", EXPERTS: "expert_layers",
+          GLOBAL: "attn_layers"}
 
 
 def model_family(cfg):
@@ -139,15 +153,24 @@ def model_family(cfg):
 
 
 def layer_kinds(cfg) -> tuple:
-    """The attention kind of every layer: "global" or "window", or all
-    of them "latent"."""
+    """The kind of every layer: "global" or "window" attention, or all of
+    them "latent"; or, each layer one mixer alone, "state", "experts" or
+    "global"."""
     kinds = tuple(getattr(cfg, "layer_types", ()) or ())
     if not kinds:
         return (GLOBAL,) * cfg.n_layers
-    if len(kinds) != cfg.n_layers or set(kinds) - {GLOBAL, WINDOW, LATENT}:
+    if len(kinds) != cfg.n_layers \
+            or set(kinds) - {GLOBAL, WINDOW, LATENT, STATE, EXPERTS}:
         raise ValueError(
             f"layer_types must name {cfg.n_layers} layers 'global', "
-            f"'window' or 'latent', got {kinds}")
+            f"'window' or 'latent' (or, each one mixer alone, 'state', "
+            f"'experts' or 'global'), got {kinds}")
+    if {STATE, EXPERTS} & set(kinds) and {WINDOW, LATENT} & set(kinds):
+        raise ValueError(
+            "state layers (and layers that are one mixer alone) beside "
+            "window or latent layers are not served: a window layer's ring "
+            "and a latent layer's rows have no single-mixer body, and a "
+            "state cannot give back what a window has passed")
     if WINDOW in kinds and getattr(cfg, "sliding_window", 0) < 1:
         raise ValueError("window layers need sliding_window >= 1")
     if LATENT in kinds and set(kinds) != {LATENT}:
@@ -160,11 +183,18 @@ def layer_kinds(cfg) -> tuple:
 
 def kind_layers(cfg) -> dict:
     """{kind: the layers of that kind, in order}; "global" first, a
-    kind with no layer left out. The KV pool keeps one pair of arrays a
-    kind (llm/kvcache.py init_pool)."""
+    kind with no layer left out, and no kind that caches nothing
+    ("experts"). The KV pool keeps one pair of arrays a kind
+    (llm/kvcache.py init_pool)."""
     kinds = layer_kinds(cfg)
     return {k: tuple(i for i, x in enumerate(kinds) if x == k)
-            for k in (GLOBAL, LATENT, WINDOW) if k in kinds}
+            for k in (GLOBAL, LATENT, WINDOW, STATE) if k in kinds}
+
+
+def single_mixer(cfg) -> bool:
+    """Whether every layer of ``cfg`` is one mixer behind one norm
+    (models/moe.py ``MoEConfig.single_mixer``)."""
+    return getattr(cfg, "single_mixer", False)
 
 
 def window_of(cfg, kind: str):
@@ -179,7 +209,9 @@ def has_experts(cfg) -> bool:
 @dataclasses.dataclass(frozen=True)
 class _Segment:
     """``repeats`` periods of layers whose kinds are ``kinds``, rows
-    ``row ...`` of ``params[stack]``, the first being layer ``layer0``."""
+    ``row ...`` of ``params[stack]``, the first being layer ``layer0``;
+    ``stack`` None: each kind's rows are its own stack's
+    (``STACKS``), from the count of its layers before ``layer0`` on."""
     stack: str
     row: int
     layer0: int
@@ -192,12 +224,15 @@ def _segments(cfg) -> tuple:
     smallest period whose repetition covers the most layers, then the
     same for what is left. Twelve periods of (window, window, window,
     global) behind a dense first layer are three segments; a Llama
-    model is one, of period one."""
+    model is one, of period one; four periods of (state, experts, state,
+    experts, state, global, experts) are one, whatever stack a kind's rows
+    lie in."""
     kinds = layer_kinds(cfg)
     n_dense = getattr(cfg, "n_dense_layers", 0)
     out = []
-    for stack, lo, hi in (("dense_layers", 0, n_dense),
-                          ("layers", n_dense, cfg.n_layers)):
+    for stack, lo, hi in (((None, 0, cfg.n_layers),) if single_mixer(cfg)
+                          else (("dense_layers", 0, n_dense),
+                                ("layers", n_dense, cfg.n_layers))):
         row = 0
         while lo + row < hi:
             rest = kinds[lo + row:hi]
@@ -237,7 +272,10 @@ def _run_layers(params, cfg, carry, body, per_layer=()):
     segment of one period unrolled. ``per_layer`` arrays have all the
     layers on their first axis and are handed to the body a layer at a
     time. Returns (carry, the ys with the layers on their first axis,
-    or None where the body returns none)."""
+    or None where the body returns none). A model whose kinds are stacks
+    of their own (``single_mixer``): ``_run_kind_stacks``."""
+    if single_mixer(cfg):
+        return _run_kind_stacks(params, cfg, carry, body, per_layer or {})
     kinds = layer_kinds(cfg)
     pieces = []
     for seg in _segments(cfg):
@@ -300,6 +338,63 @@ def _run_layers(params, cfg, carry, body, per_layer=()):
     if len(pieces) == 1:
         return carry, pieces[0]
     return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys), *pieces)
+
+
+def _run_kind_stacks(params, cfg, carry, body, per_layer: dict):
+    """``_run_layers`` where a kind's parameters are a stack of its own
+    (``STACKS``): a period takes each kind's rows from that kind's stack.
+    A layer's parameters are INDEXED in the stack by the layer's (traced)
+    row, one slice a consumer, and not handed to the scan as its ``xs``:
+    a turn of a scan over periods copies its period's rows of every leaf
+    the body reads (PERF.md section 7, "the period scan"), every weight
+    once a decode step. ``per_layer`` is {kind: arrays with THAT KIND's
+    layers on their first axis}, handed to the kind's bodies a layer at a
+    time; the ys come back the same way, {kind: that kind's ys, its layers
+    on their first axis} (a body's y has its kind's structure; None for a
+    kind that returns none)."""
+    kinds = layer_kinds(cfg)
+    pieces = []
+    for seg in _segments(cfg):
+        p, r = len(seg.kinds), seg.repeats
+        per = {k: seg.kinds.count(k) for k in set(seg.kinds)}
+        first = {k: kinds[:seg.layer0].count(k) for k in per}
+
+        def period(carry, extras, i, seg=seg, p=p, per=per, first=first):
+            outs = {k: [] for k in per}
+            for j, kind in enumerate(seg.kinds):
+                c = seg.kinds[:j].count(kind)
+                row = _affine(i, per[kind], first[kind] + c)
+                stack = params[STACKS[kind]]
+                lp = jax.tree.map(
+                    lambda w: w[row] if isinstance(row, int) else
+                    lax.dynamic_index_in_dim(w, row, keepdims=False), stack)
+                ref = LayerRef(kind, _affine(i, p, seg.layer0 + j), row,
+                               stack, row)
+                carry, y = body(carry, lp, ref,
+                                *(e[c] for e in extras.get(kind, ())))
+                outs[kind].append(y)
+            return carry, {k: jax.tree.map(lambda *ys: jnp.stack(ys), *ys)
+                           for k, ys in outs.items()}
+
+        # a kind's per-layer arrays, the rows this segment walks by period
+        extras = {k: tuple(
+            e[first[k]:first[k] + r * per[k]].reshape(r, per[k],
+                                                      *e.shape[1:])
+            for e in per_layer.get(k, ())) for k in per}
+        if r == 1:
+            carry, ys = period(carry, jax.tree.map(lambda e: e[0], extras), 0)
+        else:
+            def step(carry, xs, period=period):
+                return period(carry, *xs)
+            carry, ys = _scan_layers(
+                step, carry, (extras, jnp.arange(r, dtype=jnp.int32)))
+            ys = jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), ys)
+        pieces.append(ys)
+    out = {}
+    for k in dict.fromkeys(k for ys in pieces for k in ys):
+        out[k] = jax.tree.map(lambda *ys: jnp.concatenate(ys),
+                              *(ys[k] for ys in pieces if k in ys))
+    return carry, out
 
 
 def rope_tables(cfg, positions) -> tuple:
@@ -582,6 +677,82 @@ def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
     return x, k, v, stats
 
 
+def _mixer_layer(x, lp, cfg, ref: LayerRef, rope, attend, state,
+                 active=None):
+    """One layer of a model whose layers are each ONE mixer behind one norm
+    (``single_mixer``): x + mixer(RMSNorm(x)), x (b, s, d). An "experts"
+    layer is the expert layer alone; a "state" layer ``state(y, lp) ->
+    (out, what the forward keeps of it)`` (the forwards differ in where the
+    state comes from and goes to); a "global" layer attention alone, through
+    the forward's ``attend`` as in ``_layer``. Returns (x, what the layer
+    caches: (k, v), the state layer's, or for an expert layer, which caches
+    nothing, the experts each row chose, (b * s, k) int32, which a forward
+    drops unless it was asked for them (``prefill_routed``,
+    ``decode_logits_core(chosen=True)``); expert counts or None)."""
+    y = _rmsnorm(x, lp["norm"], cfg.norm_eps)
+    if ref.kind == EXPERTS:
+        from ray_tpu.models import moe
+        out, stats, chosen = moe.serve_block(
+            y.reshape(-1, y.shape[-1]), lp, cfg, stack=ref.stack,
+            row=ref.row, active=active, choice=True)
+        return x + out.reshape(y.shape), chosen, stats
+    if ref.kind == STATE:
+        out, kept = state(y, lp)
+        return x + out, kept, None
+    with jax.named_scope("attention." + ref.kind):
+        q, k, v = _qkv(y, lp, cfg)
+        if getattr(cfg, "rope_layers", "all") == "all":
+            q, k = _rope(q, *rope), _rope(k, *rope)
+        return x + attend(q, k, v, lp["wo"]), (k, v), None
+
+
+def _mixer_prefill_layer(x, lp, cfg, ref, rope, attend, length, start, kv):
+    """A prefill forward's layer of a single-mixer model, x (1, s, d):
+    ``start`` is what a state layer starts from, (state, conv tail), and it
+    hands on what it leaves at the row's ``length``; ``kv(k, v)`` makes an
+    attention layer's y of its new rows. Returns (x, the layer's y)."""
+    from ray_tpu.ops import ssm
+
+    def state(y, lp):
+        out, st, tail = ssm.mixer_prefill(y[0], lp, cfg, *start, length)
+        return out[None], (st, tail)
+    x, kept, _ = _mixer_layer(x, lp, cfg, ref, rope, attend, state)
+    return x, (kv(*kept) if ref.kind == GLOBAL else kept)   # EXPERTS: chosen
+
+
+def _state_step(y, lp, cfg, ref, pool, live):
+    """A decode step's state layer: y (slots, d) normed rows against the
+    layer's place (``ref.kind_index``) in the pool's states and conv tails,
+    which are the layer scans' carry. The slots of ``live`` move on one
+    token; another slot's state and tail stay as they are (its row of the
+    output is garbage nobody reads). Returns (out (slots, d), pool)."""
+    from ray_tpu.llm.kvcache import POOL_KEYS
+    from ray_tpu.ops import ssm
+    sk, tk = POOL_KEYS[STATE]
+    l = ref.kind_index
+    st = lax.dynamic_index_in_dim(pool[sk], l, keepdims=False)
+    tl = lax.dynamic_index_in_dim(pool[tk], l, keepdims=False)
+    out, st2, tl2 = ssm.mixer_step(y, lp, cfg, st, tl)
+    # under the step's scope: the write into the carry is the root of the
+    # fusion that makes the new state, and a trace reads a fusion's scope
+    # off its root
+    with jax.named_scope("ssm.step"):
+        st2 = jnp.where(live[:, None, None, None], st2, st)
+        states = lax.dynamic_update_index_in_dim(pool[sk], st2, l, 0)
+    with jax.named_scope("ssm.conv"):
+        tl2 = jnp.where(live[:, None, None], tl2, tl)
+        tails = lax.dynamic_update_index_in_dim(pool[tk], tl2, l, 0)
+    return out, {**pool, sk: states, tk: tails}
+
+
+def fresh_state(cfg, dtype) -> tuple:
+    """What a prompt's state layers start from: (a zero state (float32), a
+    zero conv tail) of one layer."""
+    from ray_tpu.llm.kvcache import row_shapes, state_dtypes
+    return tuple(jnp.zeros(shape, dt) for shape, dt in zip(
+        row_shapes(cfg, STATE), state_dtypes(dtype)))
+
+
 def _gqa_attend_cached(q, cache_k, cache_v, lengths, cfg: LlamaConfig,
                        window=None):
     """q: (b, h, hd) current-token queries; cache_k/v: (b, L, kvh, hd);
@@ -642,16 +813,19 @@ def _head(x, params, cfg, length):
     return (last @ params["lm_head"]).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("cfg", "max_len"))
-def prefill(params: dict, tokens: jax.Array, length: jax.Array,
-            cfg: LlamaConfig, max_len: int) -> Tuple[jax.Array, dict]:
+def _prefill(params: dict, tokens: jax.Array, length: jax.Array,
+             cfg: LlamaConfig, max_len: int) -> Tuple[jax.Array, dict, object]:
     """One padded prompt. tokens: (s,) int32 (padded to a bucket);
     length: () actual prompt length. Returns (last-token logits (vocab,),
     per-layer kv padded to max_len: k/v (layers, max_len, kvh, hd); of a
     latent model the cache's rows under the same two names, "k" the c
     rows (layers, max_len, kv_lora_rank) and "v" the kr rows (layers,
     max_len, qk_rope_head_dim), from which its attention materialised
-    per-head keys and values).
+    per-head keys and values). Of a model with state layers k/v are its
+    global layers' alone, and "ssm" / "conv" each state layer's state and
+    conv tail after position length - 1 (``_mixer_layer``); a third value,
+    the experts every position chose in each expert layer that is a mixer
+    alone, (expert layers, s, k) int32, or None.
 
     Attention dispatches through ops.attention (cfg.attn_impl): the
     pallas flash kernel tiles long prompts on TPU instead of
@@ -676,16 +850,46 @@ def prefill(params: dict, tokens: jax.Array, length: jax.Array,
                            impl=_serve_attn_impl(cfg),
                            **_prefill_attn_kw(cfg, ref.kind))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
+        if single_mixer(cfg):
+            return _mixer_prefill_layer(
+                x, lp, cfg, ref, rope, attend, length,
+                fresh_state(cfg, x.dtype), lambda k, v: (k[0], v[0]))
         x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
         return x, (k[0], v[0])
 
-    x, (ks, vs) = _run_layers(params, cfg, x, layer)
+    x, ys = _run_layers(params, cfg, x, layer)
     logits = _head(x, params, cfg, length)
     # pad the rows (layers, s, ...) -> (layers, max_len, ...)
     def pad(rows):
         return jnp.pad(rows, [(0, 0), (0, max_len - s)]
                        + [(0, 0)] * (rows.ndim - 2))
-    return logits, {"k": pad(ks), "v": pad(vs)}
+    if single_mixer(cfg):
+        # K and V of the global layers alone; a state layer's state and
+        # conv tail at the prompt's length
+        (ks, vs), (ssm, conv) = ys[GLOBAL], ys[STATE]
+        return logits, {"k": pad(ks), "v": pad(vs), "ssm": ssm,
+                        "conv": conv}, ys.get(EXPERTS)
+    ks, vs = ys
+    return logits, {"k": pad(ks), "v": pad(vs)}, None
+
+
+@partial(jax.jit, static_argnames=("cfg", "max_len"))
+def prefill(params: dict, tokens: jax.Array, length: jax.Array,
+            cfg: LlamaConfig, max_len: int) -> Tuple[jax.Array, dict]:
+    """``_prefill``'s (last-token logits, per-layer kv): what the engine
+    admits a request with."""
+    return _prefill(params, tokens, length, cfg, max_len)[:2]
+
+
+@partial(jax.jit, static_argnames=("cfg", "max_len"))
+def prefill_routed(params: dict, tokens: jax.Array, length: jax.Array,
+                   cfg: LlamaConfig, max_len: int):
+    """``prefill`` with the experts every position chose: ONE program gives
+    the logits and the routing behind them, for a comparison with a
+    reference that has to follow that routing (benchmarks/families/
+    nemotron_h.py; ``kvcache.paged_decode_logits(chosen=True)`` is the
+    decode step's). No serving path calls it."""
+    return _prefill(params, tokens, length, cfg, max_len)
 
 
 def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
@@ -705,7 +909,9 @@ def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
     in place with this chunk's. Returns (logits of the chunk's last
     valid token (vocab,), updated acc). Positions in acc beyond
     offset+length may hold pad garbage; every consumer masks by total
-    length, so it is never attended to.
+    length, so it is never attended to. Of a model with state layers acc
+    also holds "ssm" / "conv", each state layer's state and conv tail: the
+    chunk starts from them and hands on what it leaves at its ``length``.
 
     Dispatch: flash-capable impls route to the pallas kernel with the
     chunk's absolute offset placing the causal diagonal (one compile
@@ -774,7 +980,9 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
     positions = (offset + jnp.arange(s, dtype=jnp.int32))[None]
     rope = rope_tables(cfg, positions)
 
-    def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
+    def layer(x, lp, ref, *acc):        # ak/av: (L, kvh, hd) the layer's
+        ak, av = acc or (None, None)
+
         def attend(q, k, v, wo):
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
             if ref.kind == LATENT:
@@ -790,12 +998,10 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
                            impl=impl, q_offset=offset,
                            **_prefill_attn_kw(cfg, ref.kind))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
-        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
-        return x, (_into_acc(ak, k, offset), _into_acc(av, v, offset))
+        return _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc,
+                            offset)
 
-    x, (nk, nv) = _run_layers(params, cfg, x, layer,
-                              per_layer=(acc["k"], acc["v"]))
-    return _head(x, params, cfg, length), {"k": nk, "v": nv}
+    return _chunk_layers(params, cfg, x, layer, acc, length)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(4,))
@@ -821,7 +1027,9 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
         masks[WINDOW] = m & (k_pos[None, :]
                              > q_pos[:, None] - cfg.sliding_window)
 
-    def layer(x, lp, ref, ak, av):      # ak/av: (L, kvh, hd) the layer's
+    def layer(x, lp, ref, *acc):        # ak/av: (L, kvh, hd) the layer's
+        ak, av = acc or (None, None)
+
         def attend(q, k, v, wo):
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
             if ref.kind == LATENT:          # every row expanded, then masked
@@ -836,9 +1044,37 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
             o = jnp.einsum("kgsl,lkd->skgd", probs,
                            nv.astype(jnp.float32))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
-        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
-        return x, (_into_acc(ak, k, offset), _into_acc(av, v, offset))
+        return _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc,
+                            offset)
 
+    return _chunk_layers(params, cfg, x, layer, acc, length)
+
+
+def _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc, offset):
+    """One layer of a ``prefill_chunk`` forward: ``acc`` is the layer's part
+    of the accumulator (its K and V rows; a state layer's state and conv
+    tail, which the chunk starts from and hands on; nothing for a layer
+    that caches nothing). Returns (x, the layer's part after the chunk)."""
+    def into(k, v):
+        return _into_acc(acc[0], k, offset), _into_acc(acc[1], v, offset)
+    if single_mixer(cfg):
+        return _mixer_prefill_layer(x, lp, cfg, ref, rope, attend, length,
+                                    acc, into)
+    x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
+    return x, into(k, v)
+
+
+def _chunk_layers(params, cfg, x, layer, acc, length):
+    """A ``prefill_chunk`` forward's layers over the accumulator -> (the
+    chunk's last valid token's logits, the accumulator after it)."""
+    if single_mixer(cfg):
+        from ray_tpu.llm.kvcache import POOL_KEYS
+        sk, tk = POOL_KEYS[STATE]
+        x, ys = _run_layers(params, cfg, x, layer, per_layer={
+            GLOBAL: (acc["k"], acc["v"]), STATE: (acc[sk], acc[tk])})
+        (nk, nv), (st, tl) = ys[GLOBAL], ys[STATE]
+        return _head(x, params, cfg, length), {"k": nk, "v": nv, sk: st,
+                                               tk: tl}
     x, (nk, nv) = _run_layers(params, cfg, x, layer,
                               per_layer=(acc["k"], acc["v"]))
     return _head(x, params, cfg, length), {"k": nk, "v": nv}
@@ -918,7 +1154,7 @@ def _add_counts(total, stats):
 
 def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
                        positions: jax.Array, cfg: LlamaConfig, attend,
-                       live: jax.Array):
+                       live: jax.Array, chosen: bool = False):
     """THE decode-step transformer: one token for every slot against
     the KV pool (llm/kvcache.py init_pool: k/v (layers of a kind,
     blocks, kvh, block_size, hd) a layer kind). The pool is the layer
@@ -940,7 +1176,11 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     None: models/moe.py serve_block over the rows of ``live`` (slots,)
     bool, the slots that hold a request. The caller reads it off the
     tables: a position says nothing, an idle slot's is the step's index
-    in its block)."""
+    in its block). A STATE layer's state and conv tail are in the pool too,
+    a slot's at the slot's index: a live slot's move on one token in the
+    carry, another's stay (``_state_step``). ``chosen`` (a model whose
+    layers are each one mixer): a fourth value, the experts every slot chose
+    in each expert layer, (expert layers, slots, k) int32."""
     x = _embed(params, tokens[:, None], cfg)                # (b, 1, emb)
     rope = rope_tables(cfg, positions[:, None])
     active = live if has_experts(cfg) else None
@@ -953,17 +1193,30 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
             o, pool = _attend_pool(attend, ref, lp, cfg, q, k[:, 0],
                                    v[:, 0], pool)
             return (o.astype(x.dtype) @ wo)[:, None]
-        x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active)
-        return (x, pool, _add_counts(counts, stats)), None
+
+        def state(y, lp):
+            nonlocal pool
+            out, pool = _state_step(y[:, 0], lp, cfg, ref, pool, live)
+            return out[:, None], None
+        kept = None
+        if single_mixer(cfg):
+            x, kept, stats = _mixer_layer(x, lp, cfg, ref, rope, attend_,
+                                          state, active)
+        else:
+            x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active)
+        return (x, pool, _add_counts(counts, stats)), \
+            kept if ref.kind == EXPERTS else None
 
     counts = None
     if has_experts(cfg):
         counts = {k: jnp.int32(0)
                   for k in ("routed", "local", "experts_hit")}
-    (x, pool, counts), _ = _run_layers(params, cfg, (x, pool, counts),
-                                       layer)
+    (x, pool, counts), ys = _run_layers(params, cfg, (x, pool, counts),
+                                        layer)
     x = _final_norm(x, params, cfg)
     logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    if chosen:
+        return logits, pool, counts, ys[EXPERTS]
     return logits, pool, counts
 
 
@@ -1009,6 +1262,11 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
     distribution.
     ``attend(ref, q, k, v, pool) -> ((b, w, h*hd) f32, pool)`` takes q
     (b, w, h, hd) and the new rows k, v (b, w, kvh, hd)."""
+    if single_mixer(cfg):
+        raise NotImplementedError(
+            "the verify forward does not run state layers (or any layer "
+            "that is one mixer alone): a rejected draft would have to roll "
+            "a recurrent state back, and no snapshot of it is kept")
     b, w = tokens.shape
     x = _embed(params, tokens, cfg)                         # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]

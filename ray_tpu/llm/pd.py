@@ -43,6 +43,11 @@ class PrefillEngine:
                  max_len: int = 1024,
                  cache_dtype: str = "bfloat16",
                  block_size: Optional[int] = None):
+        if lm.STATE in lm.layer_kinds(cfg):
+            raise ValueError(
+                "the prefill/decode hand-off is not supported with state "
+                "layers: it ships K and V rows at block granularity, and a "
+                "recurrent state is no row of a block")
         self.cfg = cfg
         self.params = params
         self.max_len = max_len

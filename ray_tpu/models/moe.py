@@ -124,6 +124,12 @@ exp(clip(.)) make doubly stochastic, all from the position's own normed
 stream, in float32. Its leaves, a sub-layer (``hc_attn_*``, ``hc_mlp_*``):
 ``phi`` (n * d, 2n + n * n) float32 with columns [pre | post | res],
 ``b`` (2n + n * n,) and ``a`` (3,), the three learned scales.
+``layer_types`` of "state" and "experts" (beside "global") make every layer
+ONE mixer behind one norm, x + mixer(RMSNorm(x)): a Mamba-2 state-space
+mixer (``ops/ssm.py``; ``ssm_*``), an expert layer or attention alone, each
+kind's parameters a stack of its own (``single_mixer``); ``expert_act``
+"relu2" makes an expert down(relu(up x) ** 2), two matrices and no gate,
+routed and shared alike (``nemotron_3_nano_30b_a3b``).
 One expert layer serves both: ``serve_block`` and the train forward's ``_experts`` share ``_route``,
 ``_sort_by_expert``, ``_gated_sum`` and ``_shared``.
 """
@@ -144,6 +150,7 @@ from ray_tpu.models.llama import MeshAxes, _attend, _on_tpu, _rmsnorm, \
     _rope, _rope_tables
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.pallas import grouped_matmul
+from ray_tpu.ops.ssm import conv_taps
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,6 +236,17 @@ class MoEConfig:
     hc_eps: float = 1e-6
     hc_res_clamp_min: float = -30.0
     hc_res_clamp_max: float = 30.0
+    # an expert (routed and shared alike): "swiglu", down(silu(gate x) *
+    # up x), three matrices; "relu2", down(relu(up x) ** 2), two and no gate
+    expert_act: str = "swiglu"
+    # "state" layers (a Mamba-2 mixer, ops/ssm.py): heads of ``ssm_head_dim``
+    # channels with a state of ``ssm_state`` a channel, B and C by group
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
 
     @property
     def head_dim(self) -> int:
@@ -242,6 +260,26 @@ class MoEConfig:
     @property
     def n_held(self) -> int:
         return self.experts_held or self.n_experts
+
+    @property
+    def single_mixer(self) -> bool:
+        """Whether every layer is ONE mixer behind one norm (a state layer,
+        an expert layer or attention alone), its kinds' parameters stacks
+        of their own (``state_layers``, ``expert_layers``, ``attn_layers``:
+        ``llm/model.py STACKS``)."""
+        return bool({"state", "experts"} & set(self.layer_types))
+
+    @property
+    def ssm_widths(self) -> tuple:
+        """(inner width: what the gate and the output projection see, the
+        conv's channels [x | B | C]) of a state layer."""
+        inner = self.ssm_heads * self.ssm_head_dim
+        return inner, inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def expert_mats(self) -> int:
+        """Matrices an expert has: gate, up and down, or up and down."""
+        return {"swiglu": 3, "relu2": 2}[self.expert_act]
 
     @property
     def linear_widths(self) -> tuple:
@@ -278,7 +316,14 @@ class MoEConfig:
         router = d * self.n_experts \
             + (self.n_experts if self.scoring == "sigmoid" else 0) \
             + (d if self.shared_expert_gate else 0)
-        return router + 3 * (experts + self.n_shared_experts) * d * f
+        return router \
+            + self.expert_mats * (experts + self.n_shared_experts) * d * f
+
+    def _state_params(self) -> int:
+        """One state layer's mixer."""
+        inner, conv = self.ssm_widths
+        return self.dim * (inner + conv + self.ssm_heads) + inner * self.dim \
+            + conv * (self.ssm_conv_kernel + 1) + 3 * self.ssm_heads + inner
 
     def _mixing_params(self) -> int:
         """A layer's hyper-connection leaves (two sub-layers)."""
@@ -288,6 +333,14 @@ class MoEConfig:
         return 2 * ((n * self.dim + 1) * (2 * n + n * n) + 3)   # phi, b; a
 
     def _params(self, experts: int) -> int:
+        if self.single_mixer:
+            # every layer ONE mixer behind one norm (llm/model.py)
+            count = self.layer_types.count
+            return 2 * self.vocab_size * self.dim + self.dim \
+                + self.n_layers * self.dim \
+                + count("state") * self._state_params() \
+                + count("experts") * self._layer_params(experts) \
+                + count("global") * (self._attn_params() - 2 * self.dim)
         dense = self._attn_params() + 3 * self.dim * self.dense_ffn_dim
         linear = self.layer_types.count("linear")
         mixers = linear * self._linear_params() \
@@ -418,6 +471,36 @@ def xing4_0_29b_a4b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def nemotron_3_nano_30b_a3b(**kw) -> MoEConfig:
+    """nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 ``config.json``
+    (``model_type: nemotron_h``): 52 layers, each ONE mixer behind one norm,
+    by ``hybrid_override_pattern`` a Mamba-2 mixer (64 heads of 64, state
+    128, 8 groups, conv 4), an expert layer (128 sigmoid-routed non-gated
+    relu^2 experts of width 1856, 6 a token, scale 2.5, a shared expert of
+    3712) or attention (32 query / 2 KV heads of 128, no RoPE)."""
+    pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    defaults = dict(
+        vocab_size=131072, dim=2688, n_layers=52, n_heads=32, n_kv_heads=2,
+        head_size=128, ffn_dim=1856, n_experts=128, experts_per_token=6,
+        norm_topk_prob=True, scoring="sigmoid", routed_scaling=2.5,
+        n_shared_experts=2, expert_act="relu2", rope_layers="none",
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv_kernel=4, ssm_chunk=128, max_seq_len=262144,
+        rope_theta=10000.0, norm_eps=1e-5)
+    defaults.update(kw)
+    if "layer_types" not in defaults:
+        defaults["layer_types"] = pattern_kinds(
+            pattern[:defaults["n_layers"]])
+    return MoEConfig(**defaults)
+
+
+def pattern_kinds(pattern: str) -> tuple:
+    """``hybrid_override_pattern`` -> layer_types: M a state layer, E an
+    expert layer, * attention."""
+    return tuple({"M": "state", "E": "experts", "*": "global"}[c]
+                 for c in pattern)
+
+
 def tiny(**kw) -> MoEConfig:
     defaults = dict(vocab_size=512, dim=64, n_layers=2, n_heads=4,
                     n_kv_heads=2, ffn_dim=128, n_experts=4,
@@ -436,7 +519,7 @@ def _serving_only(cfg: MoEConfig) -> bool:
     return bool(set(cfg.layer_types) - set(TRAIN_KINDS)
                 or cfg.n_dense_layers or cfg.post_norm
                 or cfg.rope_layers != "all" or cfg.scoring != "softmax"
-                or cfg.hc_copies)
+                or cfg.hc_copies or cfg.expert_act != "swiglu")
 
 
 def _kind_layers(cfg: MoEConfig) -> dict:
@@ -487,6 +570,8 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
     """Parameters of a config with serving-only shapes: the leading dense
     layers and the expert layers are two stacks (``dense_layers``,
     ``layers``), the experts only those this device holds."""
+    if cfg.single_mixer:
+        return _init_single_mixer(rng, cfg)
     dtype = jnp.dtype(cfg.dtype)
     d, f, E, held = cfg.dim, cfg.ffn_dim, cfg.n_experts, cfg.n_held
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -601,6 +686,118 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
                       shared_up=stack(Ls, d, fs, fan_in=d),
                       shared_down=stack(Ls, fs, d, fan_in=fs))
     params["layers"] = layers
+    return params
+
+
+LANES = 128     # values a lane tile of the device holds
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "start"),
+                   donate_argnums=(0,))
+def _zero_tail(buf, *, axis, start):
+    """``buf`` with its entries from ``start`` on along ``axis`` zeroed."""
+    index = (slice(None),) * axis + (slice(start, None),)
+    return buf.at[index].set(0)
+
+
+def _init_single_mixer(rng: jax.Array, cfg: MoEConfig) -> dict:
+    """Parameters of a config whose layers are each one mixer
+    (``single_mixer``): a stack a kind, the experts only those this device
+    holds, an expert by its ``expert_act``. The embedding has unit RMS (a
+    pre-norm stream: ``_init_serving``'s reason). ``config.json`` has three
+    keys of the state layers' init and no more; it is Mamba-2's: ``dt_bias``
+    the inverse softplus of a step drawn log-uniform in [1e-3, 1e-1] and
+    floored at 1e-4, ``A_log`` = log U[1, 16], ``D`` = 1, the depthwise conv
+    and its bias uniform at the fan-in of its taps: random weights then
+    decay as a trained model's do, some heads within a few tokens, some over
+    a thousand."""
+    if set(cfg.layer_types) - {"state", "experts", "global"} \
+            or len(cfg.layer_types) != cfg.n_layers:
+        raise ValueError(
+            f"layer_types must name {cfg.n_layers} layers 'state', "
+            f"'experts' or 'global', got {cfg.layer_types}")
+    if cfg.expert_act not in ("swiglu", "relu2"):
+        raise ValueError(f"unknown expert_act: {cfg.expert_act!r}")
+    dtype = jnp.dtype(cfg.dtype)
+    d, f, E, held = cfg.dim, cfg.ffn_dim, cfg.n_experts, cfg.n_held
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(rng, 32))
+
+    def stack(*shape, fan_in):
+        return _normal_stack(next(keys), shape, fan_in, dtype)
+
+    def uniform(*shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+    count = cfg.layer_types.count
+    params = {"embed": stack(cfg.vocab_size, d, fan_in=1),
+              "final_norm": jnp.ones((d,), dtype),
+              "lm_head": stack(d, cfg.vocab_size, fan_in=d)}
+    L = count("state")
+    if L:
+        (inner, conv), H, K = cfg.ssm_widths, cfg.ssm_heads, \
+            cfg.ssm_conv_kernel
+        dt = jnp.maximum(jnp.exp(uniform(L, H, lo=jnp.log(1e-3),
+                                         hi=jnp.log(1e-1))), 1e-4)
+        params["state_layers"] = {
+            "norm": jnp.ones((L, d), dtype),
+            # the in-projection's columns [z | xBC | dt] as three leaves:
+            # a leaf 10,304 wide (no whole number of 128-lane tiles) lies
+            # transposed on the device, and every program that multiplies
+            # with it would first turn all of it back
+            "w_z": stack(L, d, inner, fan_in=d),
+            "w_xbc": stack(L, d, conv, fan_in=d),
+            "w_dt": stack(L, d, H, fan_in=d),
+            "conv": uniform(L, conv, K, lo=-K ** -0.5,
+                            hi=K ** -0.5).astype(dtype),
+            "conv_bias": uniform(L, conv, lo=-K ** -0.5,
+                                 hi=K ** -0.5).astype(dtype),
+            # float32, as the router: they set decays that compound
+            "A_log": jnp.log(uniform(L, H, lo=1.0, hi=16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "D": jnp.ones((L, H), jnp.float32),
+            "ssm_norm": jnp.ones((L, inner), dtype),
+            "w_out": stack(L, inner, d, fan_in=inner)}
+    L = count("experts")
+    if L:
+        layers = {"norm": jnp.ones((L, d), dtype),
+                  "router": jax.random.normal(next(keys), (L, d, E),
+                                              jnp.float32) * (d ** -0.5)}
+        if cfg.scoring == "sigmoid":
+            layers["router_bias"] = ROUTER_BIAS_SCALE * jax.random.normal(
+                next(keys), (L, E), jnp.float32)
+        fs = cfg.n_shared_experts * f
+        # an expert's width as it is STORED: whole 128-lane tiles, the
+        # columns (of w_gate / w_up; rows of w_down) past ``ffn_dim`` zeros,
+        # which add nothing to any product (relu(0) ** 2 = silu(0) = 0).
+        # The device lays a narrower minor dimension out in whole tiles
+        # anyway, or transposed (1,856 is 14.5 tiles), and the grouped
+        # matmul's program would then turn the whole stack back at its
+        # entry: 1.9 GB a decode block
+        fp = -(-f // LANES) * LANES
+
+        def experts(*shape, fan_in, axis):
+            return _zero_tail(stack(*shape, fan_in=fan_in), axis=axis,
+                              start=f) if fp != f else stack(
+                                  *shape, fan_in=fan_in)
+        if cfg.expert_act == "swiglu":
+            layers["w_gate"] = experts(L, held, d, fp, fan_in=d, axis=3)
+            if fs:
+                layers["shared_gate"] = stack(L, d, fs, fan_in=d)
+        layers.update(w_up=experts(L, held, d, fp, fan_in=d, axis=3),
+                      w_down=experts(L, held, fp, d, fan_in=f, axis=2))
+        if fs:
+            layers.update(shared_up=stack(L, d, fs, fan_in=d),
+                          shared_down=stack(L, fs, d, fan_in=fs))
+        params["expert_layers"] = layers
+    L = count("global")
+    if L:
+        params["attn_layers"] = {
+            "norm": jnp.ones((L, d), dtype),
+            "wq": stack(L, d, h * hd, fan_in=d),
+            "wk": stack(L, d, kvh * hd, fan_in=d),
+            "wv": stack(L, d, kvh * hd, fan_in=d),
+            "wo": stack(L, h * hd, d, fan_in=h * hd)}
     return params
 
 
@@ -924,7 +1121,16 @@ def _gated_sum(y, gates, order, inverse, group_sizes, w, cfg: MoEConfig,
                layer=None):
     """The routed rows of y through the experts ``w`` holds
     (``w["w_gate" | "w_up" | "w_down"]``: a layer's, or with ``layer`` the
-    stacks), summed per token by ``gates``."""
+    stacks; no ``w_gate`` under ``expert_act`` "relu2", two grouped matmuls),
+    summed per token by ``gates``."""
+    if cfg.expert_act == "relu2":
+        with jax.named_scope("moe.experts"):
+            up = _grouped(_dispatch(y, order, inverse), w["w_up"],
+                          group_sizes, cfg, layer)
+            rows = _grouped(jnp.square(jax.nn.relu(up)), w["w_down"],
+                            group_sizes, cfg, layer)
+        with jax.named_scope("moe.combine"):
+            return _combine(rows, gates, order, inverse)
     with jax.named_scope("moe.experts"):
         impl = _gmm_impl(cfg)
         if layer is None and impl != "ragged_dot":
@@ -1156,9 +1362,13 @@ def _experts(y, router, w_gate, w_up, w_down, cfg: MoEConfig,
 
 
 def _shared(y, lp):
-    """The shared expert: one SwiGLU every token goes through, weighed by
-    sigmoid(w . y) a token where the layer has that gate."""
+    """The shared expert: one SwiGLU every token goes through (without
+    ``shared_gate``, ``expert_act`` "relu2": down(relu(up y) ** 2)), weighed
+    by sigmoid(w . y) a token where the layer has that gate."""
     with jax.named_scope("moe.shared"):
+        if "shared_gate" not in lp:         # expert_act "relu2": no gate
+            return jnp.square(jax.nn.relu(y @ lp["shared_up"])) \
+                @ lp["shared_down"]
         out = (jax.nn.silu(y @ lp["shared_gate"]) * (y @ lp["shared_up"])) \
             @ lp["shared_down"]
         if "shared_expert_gate" in lp:
@@ -1167,10 +1377,12 @@ def _shared(y, lp):
 
 
 def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
-                active=None):
+                active=None, choice=False):
     """The expert layer of the serving forwards. y (T, d) normed rows ->
     (this device's part of the layer (T, d): its held experts' gated sum
-    plus the shared expert, and routing counts or None).
+    plus the shared expert, and routing counts or None; with ``choice``
+    also the experts each row chose, (T, k) int32, all of them, held here
+    or not).
 
     ``lp`` is the layer's parameters; with ``stack`` and ``row`` the
     grouped matmuls read the layer's experts in place in the stacked
@@ -1184,8 +1396,9 @@ def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
     weights the step read): the groups the kernels get."""
     src, layer = (stack, row) if stack is not None else (lp, None)
     with jax.named_scope("moe.route"):
-        gates, experts, _ = _route(y, lp["router"], lp.get("router_bias"),
-                                   cfg)
+        gates, chosen, _ = _route(y, lp["router"], lp.get("router_bias"),
+                                  cfg)
+        experts = chosen
         if active is not None:
             experts = jnp.where(active[:, None], experts, -1)   # nobody's
         mine, order, inverse, group_sizes = _sort_by_expert(
@@ -1200,7 +1413,7 @@ def serve_block(y, lp, cfg: MoEConfig, *, stack=None, row=None,
     out = _gated_sum(y, gates, order, inverse, group_sizes, src, cfg, layer)
     if cfg.n_shared_experts:
         out = out + _shared(y, lp)
-    return out, stats
+    return (out, stats, chosen) if choice else (out, stats)
 
 
 def _moe_block(y, lp, cfg: MoEConfig, mesh: Optional[Mesh],
@@ -1294,10 +1507,8 @@ def _conv_silu(x, w):
     along the tokens ``sum_j w[..., j] x[t - (K - 1) + j]``, zeros before
     the row; its sigmoid; its silu)."""
     s, taps = x.shape[2], w.shape[-1]
-    x = jnp.pad(x, ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    pre = sum(x[:, :, j:j + s].astype(jnp.float32) * w[:, None, :, j]
-              for j in range(taps))
+    pre = conv_taps(jnp.pad(x, ((0, 0), (0, 0), (taps - 1, 0), (0, 0))), w,
+                    s)
     sg = jax.nn.sigmoid(pre)
     return pre, sg, pre * sg
 
@@ -1458,7 +1669,10 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
             "materialised forms are the cache's), n_dense_layers, "
             "post_norm, rope_layers, sigmoid scoring and a residual "
             "stream mixed by hyper-connections (hc_mult) are the serving "
-            "forwards' (ray_tpu.llm.model)")
+            "forwards' (ray_tpu.llm.model); so are state-space ('state') "
+            "layers, layers that are one mixer alone ('experts') and "
+            "non-gated relu2 experts: no state-space layer has a backward "
+            "here")
     b, s = tokens.shape
 
     def act_constraint(x, spec):

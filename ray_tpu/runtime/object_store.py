@@ -516,6 +516,13 @@ class SharedObjectStore:
 
     def _evict(self, oid: ObjectID) -> None:
         e = self._entries[oid]
+        if e.spilled_remote and e.spilled_path:
+            # evicted before, restored since: a sealed object never
+            # changes, so the copy in storage still holds it (a second
+            # upload to the same key would also hide that copy from a
+            # delete that comes before the upload has run)
+            self._release_memory(e)
+            return
         if self._spill_storage is not None:
             # stage locally NOW (no network on the caller's thread);
             # the uploader promotes the entry to its storage path
