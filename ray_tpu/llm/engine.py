@@ -173,6 +173,14 @@ def engine_metrics() -> dict:
                                block: grid steps of the decode kernels
                                that did nothing (ops/pallas/
                                paged_attention.py)
+      llm_decode_state_slot_steps  slot states a block's steps moved a
+                               state layer, by the impl's own count
+                               (kvcache.state_slot_steps): the block's
+                               requests x its steps where the kernel
+                               walks the live slots, every slot x steps
+                               where the reference runs; over
+                               llm_decode_slot_steps 1.0 or slots / live
+                               (a model with state layers only)
       llm_decode_ctx_tokens    positions attended per block, summed
                                over its slots and steps
       llm_decode_kv_fetch_tokens  positions of K (and of V) the paged
@@ -268,6 +276,12 @@ def engine_metrics() -> dict:
             "llm_decode_idle_slot_steps",
             "Slots that hold no request times decode steps per block: "
             "grid steps the decode kernels skipped",
+            boundaries=(1, 4, 16, 64, 256, 1024)),
+        "state_slot_steps": m.Histogram(
+            "llm_decode_state_slot_steps",
+            "Slot states a decode block's steps moved a state layer, by "
+            "the implementation's own count: live slots times steps under "
+            "the kernel, every slot times steps under its reference",
             boundaries=(1, 4, 16, 64, 256, 1024)),
         "ctx_tokens": m.Histogram(
             "llm_decode_ctx_tokens",
@@ -696,6 +710,7 @@ class LLMEngine:
                         for kind, _ in paged}
         self._stateful = stateful
         self._state_admits = 0      # states started from zeros
+        self._state_slot_steps = 0  # slot states moved a state layer
         # the layers with a router: what llm_moe_experts_held counts by
         self._expert_layers = (
             lm.layer_kinds(cfg).count(lm.EXPERTS) if lm.single_mixer(cfg)
@@ -778,7 +793,8 @@ class LLMEngine:
                 state_layers=len(dict(self._layout)[kvcache.STATE]),
                 state_bytes=per_slot * self.max_slots,
                 state_bytes_per_slot=per_slot,
-                state_admits=self._state_admits)
+                state_admits=self._state_admits,
+                state_slot_steps=self._state_slot_steps)
         return out
 
     @contextlib.contextmanager
@@ -1337,8 +1353,9 @@ class LLMEngine:
         ``decode.account`` phase): steps, slot-steps, the positions
         each step attended (``lens`` at its start, one more a step; a
         slot that hits eos mid-block still ran its steps), what the
-        kernel's walk fetched for them, and the expert layers' device
-        scalars that came back with the tokens."""
+        kernel's walk fetched for them, the slot states a state layer's
+        rule moved, and the expert layers' device scalars that came back
+        with the tokens."""
         n = len(lens)
         self._m["block_steps"].observe(block)
         self._m["slot_steps"].observe(n * block)
@@ -1357,6 +1374,11 @@ class LLMEngine:
             # whose layers are each one mixer)
             self._m["kv_fetch_tokens"].observe(
                 fetched / sum(layers for layers, _ in self._walks))
+        if self._stateful:
+            moved = kvcache.state_slot_steps(self._kv_impl, self.max_slots,
+                                             n, block)
+            self._state_slot_steps += moved
+            self._m["state_slot_steps"].observe(moved)
         if counts is not None:
             for key, per_step in counts.items():
                 self._m["moe_" + key].observe(int(per_step.sum()))

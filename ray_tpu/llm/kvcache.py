@@ -37,7 +37,13 @@ each of them ONE recurrent state and one conv tail A SLOT, not a row a
 position: the kind ``state``'s two arrays are indexed (the kind's layers,
 slot, ...), sized from the engine's slots, written whole by a prefill at
 the prompt's length, updated in place by every decode step of a live
-slot, and the manager reports them beside the blocks (``state_slots``). A
+slot, and the manager reports them beside the blocks (``state_slots``).
+What a decode step moves of them: under impl 'paged_flash' a LIVE slot's
+state of a layer once in and once out (the kernel ``ssm_step`` walks the
+live slots; the stack is its aliased operand) and nothing of an idle
+slot's; under 'gather', its reference, every slot's state twice in and
+once out (``_pool_state_step``). The conv tails (a hundredth of the
+bytes) move whole either way, in plain XLA. A
 state cannot be rolled back or cut at a block edge, so prefix reuse,
 speculation and the prefill/decode hand-off are refused for such a model
 (llm/engine.py).
@@ -1221,6 +1227,60 @@ def _latent_attend(cfg, tables, at, lens, flat, *, impl, interpret):
     return attend
 
 
+def _pool_state_step(pool, live, *, impl, interpret):
+    """The state hook of lm.decode_logits_core against the pool's stack of
+    states, ``step(ref, x, dt, A, B, C, D, pool) -> (y (slots, h, p)
+    float32, pool)``: the slots of ``live`` (slots,) bool move on one token
+    in the layer's place (``ref.kind_index``) in ``pool["ssm"]``, the row
+    index being the slot; another slot's state stays bit for bit. None for a
+    pool without state layers: such a model's programs trace nothing of it.
+
+    impl='paged_flash': the kernel that walks the LIVE slots and moves each
+    one's state of the layer once, in place in the stack, which is its
+    aliased operand (ops/pallas/ssm_step.py): the list of live slots is made
+    here, once a step, outside the layer scan; an idle slot costs no byte of
+    state and its row of y is zeros. Nothing slices a layer out of the
+    stack or puts one back.
+
+    impl='gather': the reference, for the CPU and for parity. The layer's
+    states sliced out, ``ops/ssm.py ssd_step`` over every slot,
+    ``where(live, new, old)`` and the layer put back into the carry: every
+    slot's state read twice and written once whatever the load (an idle
+    slot's row of y is the rule's, garbage nobody reads)."""
+    sk = POOL_KEYS[STATE][0]
+    if sk not in pool:
+        return None
+    _, jnp = _jx()
+    from jax import lax
+    from ray_tpu.ops import ssm
+    if impl == "paged_flash":
+        from ray_tpu.ops.pallas import ssm_step as kernel
+        ids, count = kernel.live_slots(live)
+
+        def step(ref, x, dt, A, B, C, D, pool):
+            y, states = kernel.ssm_step(pool[sk], ref.kind_index, ids, count,
+                                        x, dt, A, B, C, D,
+                                        interpret=interpret)
+            return y, {**pool, sk: states}
+    else:
+        def step(ref, x, dt, A, B, C, D, pool):
+            l = ref.kind_index
+            st = lax.dynamic_index_in_dim(pool[sk], l, keepdims=False)
+            y, new = ssm.ssd_step(x, dt, A, B, C, D, st)
+            new = jnp.where(live[:, None, None, None], new, st)
+            return y, {**pool, sk: lax.dynamic_update_index_in_dim(
+                pool[sk], new, l, 0)}
+    return step
+
+
+def state_slot_steps(impl: str, slots: int, live: int, steps: int) -> int:
+    """Slot states ``steps`` decode steps with ``live`` of ``slots`` slots
+    holding a request move A STATE LAYER, by the implementation's own rule
+    (``_pool_state_step``; the engine's counter): the kernel walks the
+    live slots, its reference passes over every slot."""
+    return (live if impl == "paged_flash" else slots) * steps
+
+
 def _live(tables):
     """(slots,) bool: the slots that hold a request, read off the tables.
     A slot that is not in the decode block has a row of TRASH in every
@@ -1241,7 +1301,8 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
     says which slots hold a request (``_live``): the others attend 0
     positions, write nothing and are no row of an expert layer's groups,
     so they cost the step's kernels nothing (their logits are garbage
-    nobody reads). Returns (logits, pool, the expert layers' counts or
+    nobody reads); a state layer's rule moves the same slots' states
+    (_pool_state_step). Returns (logits, pool, the expert layers' counts or
     None)."""
     _, jnp = _jx()
     from ray_tpu.llm.model import decode_logits_core
@@ -1253,7 +1314,8 @@ def _paged_logits_core(params, pool, tables, lengths, tokens, cfg, *,
         _pool_attend(cfg, tables, _places(tables, positions, bs),
                      jnp.where(live, positions + 1, 0), impl=impl,
                      interpret=interpret, mesh=mesh, axis=axis),
-        live, chosen)
+        live, chosen,
+        _pool_state_step(pool, live, impl=impl, interpret=interpret))
 
 
 def paged_decode_logits(params, pool, tables, lengths, tokens, cfg, *,

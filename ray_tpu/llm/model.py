@@ -720,29 +720,30 @@ def _mixer_prefill_layer(x, lp, cfg, ref, rope, attend, length, start, kv):
     return x, (kv(*kept) if ref.kind == GLOBAL else kept)   # EXPERTS: chosen
 
 
-def _state_step(y, lp, cfg, ref, pool, live):
+def _state_step(y, lp, cfg, ref, pool, live, step):
     """A decode step's state layer: y (slots, d) normed rows against the
     layer's place (``ref.kind_index``) in the pool's states and conv tails,
     which are the layer scans' carry. The slots of ``live`` move on one
     token; another slot's state and tail stay as they are (its row of the
-    output is garbage nobody reads). Returns (out (slots, d), pool)."""
+    output is garbage nobody reads). ``step(ref, x, dt, A, B, C, D, pool)
+    -> (y, pool)`` is the one-token rule against the layer's states in the
+    pool (llm/kvcache.py _pool_state_step), as ``attend`` is an attention
+    layer's. Returns (out (slots, d), pool)."""
     from ray_tpu.llm.kvcache import POOL_KEYS
     from ray_tpu.ops import ssm
-    sk, tk = POOL_KEYS[STATE]
+    tk = POOL_KEYS[STATE][1]
     l = ref.kind_index
-    st = lax.dynamic_index_in_dim(pool[sk], l, keepdims=False)
     tl = lax.dynamic_index_in_dim(pool[tk], l, keepdims=False)
-    out, st2, tl2 = ssm.mixer_step(y, lp, cfg, st, tl)
-    # under the step's scope: the write into the carry is the root of the
-    # fusion that makes the new state, and a trace reads a fusion's scope
-    # off its root
-    with jax.named_scope("ssm.step"):
-        st2 = jnp.where(live[:, None, None, None], st2, st)
-        states = lax.dynamic_update_index_in_dim(pool[sk], st2, l, 0)
+
+    def rule(*rows):
+        nonlocal pool
+        y, pool = step(ref, *rows, pool)
+        return y
+    out, tl2 = ssm.mixer_step(y, lp, cfg, tl, rule)
     with jax.named_scope("ssm.conv"):
         tl2 = jnp.where(live[:, None, None], tl2, tl)
         tails = lax.dynamic_update_index_in_dim(pool[tk], tl2, l, 0)
-    return out, {**pool, sk: states, tk: tails}
+    return out, {**pool, tk: tails}
 
 
 def fresh_state(cfg, dtype) -> tuple:
@@ -1154,7 +1155,8 @@ def _add_counts(total, stats):
 
 def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
                        positions: jax.Array, cfg: LlamaConfig, attend,
-                       live: jax.Array, chosen: bool = False):
+                       live: jax.Array, chosen: bool = False,
+                       state_step=None):
     """THE decode-step transformer: one token for every slot against
     the KV pool (llm/kvcache.py init_pool: k/v (layers of a kind,
     blocks, kvh, block_size, hd) a layer kind). The pool is the layer
@@ -1178,7 +1180,10 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     tables: a position says nothing, an idle slot's is the step's index
     in its block). A STATE layer's state and conv tail are in the pool too,
     a slot's at the slot's index: a live slot's move on one token in the
-    carry, another's stay (``_state_step``). ``chosen`` (a model whose
+    carry, another's stay (``_state_step``), by ``state_step(ref, x, dt, A,
+    B, C, D, pool) -> (y, pool)``, the one-token rule against the layer's
+    states, handed in as ``attend`` is (a model without state layers never
+    calls it and may leave it out). ``chosen`` (a model whose
     layers are each one mixer): a fourth value, the experts every slot chose
     in each expert layer, (expert layers, slots, k) int32."""
     x = _embed(params, tokens[:, None], cfg)                # (b, 1, emb)
@@ -1196,7 +1201,8 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
 
         def state(y, lp):
             nonlocal pool
-            out, pool = _state_step(y[:, 0], lp, cfg, ref, pool, live)
+            out, pool = _state_step(y[:, 0], lp, cfg, ref, pool, live,
+                                    state_step)
             return out[:, None], None
         kept = None
         if single_mixer(cfg):

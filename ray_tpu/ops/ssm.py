@@ -1,5 +1,7 @@
 """A Mamba-2 state-space mixer for the serving forwards, in plain
-``jax.numpy`` / ``lax``: no kernel.
+``jax.numpy`` / ``lax``. The one kernel is the decode step's rule on the chip,
+``ops/pallas/ssm_step.py``, which a caller plugs into ``mixer_step``;
+``ssd_step`` here is that kernel's reference and what runs without it.
 
 Per head (P channels, a state ``h`` of P x N float32) and token ``t``, with
 an input ``x_t`` (P), a step ``dt_t > 0``, a decay rate ``A < 0`` a head,
@@ -9,7 +11,9 @@ and ``B_t``, ``C_t`` (N) of the head's GROUP (head h reads group
     h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T
     y_t = h_t C_t + D x_t
 
-``ssd_step`` is that, one token for every row of a batch. ``ssd_chunk_scan``
+``ssd_step`` is that, one token for every row of a batch (every row's state
+in and out: a decode step of a few live slots among many pays for all of
+them, which is what the kernel is for). ``ssd_chunk_scan``
 computes the same outputs for one row of ``s`` tokens, ``chunk`` tokens at a
 time (the state-space dual form): with ``a`` the running sum of ``dt A``
 from a chunk's start, within a chunk ``y_i += sum_{j <= i} (C_i . B_j)
@@ -179,10 +183,13 @@ def mixer_prefill(u, lp, cfg, state0, tail0, length):
     return _gate_norm_out(y, z, lp, cfg, u.dtype), state, tail
 
 
-def mixer_step(u, lp, cfg, state, tail):
-    """One token a slot through a state layer. u (b, d) normed rows, state
-    (b, h, p, n) float32, tail (b, K - 1, ch) -> (out (b, d), the state and
-    the tail after the token)."""
+def mixer_step(u, lp, cfg, tail, rule):
+    """One token a slot through a state layer. u (b, d) normed rows, tail
+    (b, K - 1, ch); ``rule(x, dt, A, B, C, D) -> y (b, h, p) float32`` is
+    the one-token rule against the slots' states, which its caller holds
+    (llm/kvcache.py _pool_state_step: ``ssd_step`` on the layer's states, or
+    the kernel that moves the live slots' in place) -> (out (b, d), the
+    tail after the token)."""
     with jax.named_scope("ssm.proj"):
         z, xbc, dt = _project(u, lp)
     with jax.named_scope("ssm.conv"):
@@ -190,6 +197,5 @@ def mixer_step(u, lp, cfg, state, tail):
         x, B, C = _xbc(conv_silu(ext, lp["conv"], lp["conv_bias"], 1)[:, 0],
                        cfg)
     with jax.named_scope("ssm.step"):
-        y, state = ssd_step(x, dt, -jnp.exp(lp["A_log"]), B, C, lp["D"],
-                            state)
-    return _gate_norm_out(y, z, lp, cfg, u.dtype), state, ext[:, 1:]
+        y = rule(x, dt, -jnp.exp(lp["A_log"]), B, C, lp["D"])
+    return _gate_norm_out(y, z, lp, cfg, u.dtype), ext[:, 1:]

@@ -1108,3 +1108,105 @@ def test_the_latent_cells_decode_program_reads_rows_and_weights_in_place(
     _assert_projections_in_place(
         compiled, params, leaves=LATENT_LEAVES,
         prefetch=("copy", "copy-start", "copy-done"))
+
+
+# --- the state-space cell: a decode step's states move in place -----------
+
+# nemotron-3-nano-30b-a3b-serve-ep8: 12 state layers x 64 slots, a state of
+# 64 heads x 64 x 128 float32 in 8 groups
+_STATES = (12, 64, 64, 64, 128)
+_STATE_SHAPES = {_STATES, _STATES[1:], (_STATES[0] * _STATES[1], *_STATES[2:])}
+
+
+def _ssm_config(**kw):
+    return _cell_config("nemotron-3-nano-30b-a3b-serve-ep8.json",
+                        "nemotron_h", gmm_impl="pallas", **kw)
+
+
+def _assert_state_step_signature(op):
+    """The state kernel as the benchmark's reduction meets it: by name, and
+    of NO class the readers count by (five operands of which the first two
+    are s32 and the last three of rank 4 would be a ``paged_decode``, and
+    ``scope_dev_ms_counted.steps_in_profile`` would count the state layers'
+    calls as decode steps)."""
+    assert re.search(r"ssm_step(?=_|\.|$)", op["name"]), op["name"]
+    assert op["class"] == "unknown_kernel", op
+    assert [(d, len(s)) for d, s in op["operands"]] == [
+        ("s32", 1), ("s32", 1), ("f32", 2), ("f32", 2), ("f32", 2),
+        ("f32", 3), ("f32", 3), ("f32", 3), ("f32", 4)], op
+
+
+def test_the_state_step_kernel_compiles_for_v5e(chip):
+    """``ssm_step`` at the cell's shapes with the layer's index traced: one
+    custom call, the stack of states aliased in to out (1.61 GB) and no
+    temporary of a layer's size beside it."""
+    import numpy as np
+    from ray_tpu.ops.pallas import ssm_step
+
+    def shape(s, d=jnp.float32):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    _, slots, h, p, n = _STATES
+    compiled = jax.jit(ssm_step.ssm_step, donate_argnums=(0,)).lower(
+        shape(_STATES), shape((), _I32), shape((slots,), _I32),
+        shape((), _I32), shape((slots, h, p)), shape((slots, h)),
+        shape((h,)), shape((slots, 8, n)), shape((slots, 8, n)),
+        shape((h,))).compile()
+    ops = _kernel_ops(compiled)
+    assert len(ops) == 1, [op["name"] for op in ops]
+    _assert_state_step_signature(ops[0])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * int(np.prod(_STATES))
+    assert mem.temp_size_in_bytes < 4 * int(np.prod(_STATES[2:]))
+    assert ssm_step.chunk_heads(h, p, n) == 8
+
+
+def test_the_state_cells_decode_program_moves_the_states_in_place(chip):
+    """paged_decode_steps (n = 8) at the cell's published widths and pool
+    geometry, on a described v5e (the twin of
+    test_decode_steps_update_the_pool_in_place for the fourth cache kind):
+    a turn of the layer scan (a period MEMEM*E) holds the state kernel
+    three times, 12 a step, under the name ``ssm_step`` and in no class the
+    readers count steps by; the stack of states goes from the program's
+    donated pool to its result through the kernels alone: no other
+    instruction makes an array of the stack's, a layer's or the flat
+    stack's shape (plain XLA made three a layer: the update's
+    ``select_dynamic-update-slice`` fusion over the stack and the
+    read-out's reduction, 4.8 GB a step)."""
+    import numpy as np
+    from ray_tpu.llm import kvcache
+    cfg = _ssm_config()
+    params = _hybrid_params(chip, cfg)
+    slots, width = 64, 2048 // 16
+    pool = _shapes_of(chip, jax.eval_shape(lambda: kvcache.init_pool(
+        cfg, 65 * width + 1, 16, _BF, state_slots=slots)))
+    assert pool["ssm"].shape == _STATES and pool["ssm"].dtype == jnp.float32
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    ids = shape((slots,), _I32)
+    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
+        params, pool, {"global": shape((slots, width), _I32)}, ids, ids,
+        shape((slots,), jnp.float32), shape((2,), jnp.uint32), cfg, 8, None,
+        None).compile()
+    ops = _kernel_ops(compiled)
+    names = {}
+    for op in ops:
+        name = re.sub(r"[._]*\d*$", "", op["name"])
+        names[name] = names.get(name, 0) + 1
+    # one scanned period: three state layers, three expert layers (two
+    # products each), one attention layer
+    assert names == {"ssm_step": 3, "moe_gmm_decode": 6, "kv_write": 1,
+                     "paged_decode": 1}, names
+    for op in ops:
+        if "ssm_step" in op["name"]:
+            _assert_state_step_signature(op)
+    assert sum(op["class"] == "paged_decode" for op in ops) == 1
+    _assert_decode_signatures(ops)
+    held = [(op["opcode"], op["name"])
+            for op, _ in _with_result_of(compiled, _STATE_SHAPES)]
+    assert held and {code for code, _ in held} <= _PASS_ON, held
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * int(np.prod(a.shape))
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 4 * int(np.prod(_STATES[1:]))

@@ -35,6 +35,11 @@ def fam():
         sys.path.remove(BENCH)
 
 
+# the decode step's kernels under the interpreter beside their reference
+IMPLS = pytest.mark.parametrize("kv_impl,interpret", [
+    ("gather", False), ("paged_flash", True)])
+
+
 def _cfg(**kw):
     """Two periods of MEMEM*E at tiny widths: 4 state heads of 16 with a
     state of 16 in 2 groups, chunks of 16 tokens; 4 of 16 experts held."""
@@ -218,8 +223,7 @@ def test_cold_prefill_is_the_reference(fam, params):
         params, toks, cfg, bf16=False), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("kv_impl,interpret", [("gather", False),
-                                               ("paged_flash", True)])
+@IMPLS
 def test_prefill_and_decode_through_the_state_are_the_reference(
         fam, params, kv_impl, interpret):
     """(c) ``serve_parity``: a prompt admitted to a USED slot, prefilled,
@@ -358,10 +362,13 @@ def test_a_chunked_prefill_is_a_whole_one(fam, params):
                                    atol=2e-5)
 
 
-def test_a_decode_step_leaves_an_idle_slots_state_alone(params):
+@IMPLS
+def test_a_decode_step_leaves_an_idle_slots_state_alone(params, kv_impl,
+                                                        interpret):
     """PR 57's rule for the fourth kind: a slot whose table row is TRASH
     holds no request, and its state and tail come out of the step as they
-    went in, bit for bit; the live slot's move."""
+    went in, bit for bit; the live slot's move. Under the kernel
+    (``paged_flash``) as under its reference."""
     cfg = _cfg()
     pool = kc.init_pool(cfg, 6, 8, jnp.float32, state_slots=2)
     pool = {**pool, "ssm": pool["ssm"] + 0.5, "conv": pool["conv"] - 0.25}
@@ -371,7 +378,8 @@ def test_a_decode_step_leaves_an_idle_slots_state_alone(params):
     out, after = kc.paged_decode_steps(
         params, pool, {"global": jnp.asarray(tables)},
         jnp.asarray([0, 3], jnp.int32), jnp.asarray([5, 7], jnp.int32),
-        jnp.zeros((2,)), jax.random.PRNGKey(0), cfg, 2)
+        jnp.zeros((2,)), jax.random.PRNGKey(0), cfg, 2, impl=kv_impl,
+        interpret=interpret)
     for key in ("ssm", "conv"):
         np.testing.assert_array_equal(after[key][:, 0], before[key][:, 0])
         assert not np.allclose(after[key][:, 1], before[key][:, 1])
@@ -396,17 +404,30 @@ def _greedy(fam, params, prompt, n):
     return toks[len(prompt):]
 
 
-def test_the_engine_serves_it_through_slots_that_are_used_again(fam, params):
+@pytest.mark.parametrize("kv_impl", ["gather", "paged_flash"])
+def test_the_engine_serves_it_through_slots_that_are_used_again(
+        fam, params, kv_impl):
     """(d) Three requests of different lengths into two slots, admitted at
     different steps (the third takes the slot the first left; the second is
     a chunked prefill): each reads exactly what it reads alone, by the
-    reference's greedy continuation."""
+    reference's greedy continuation. The kernel (``paged_flash``, which the
+    engine interprets off the chip) moves a state a live slot a step,
+    ``state_slot_steps`` = ``slot_steps``; its reference every slot's."""
+    from ray_tpu.llm.engine import engine_metrics
     rng = np.random.default_rng(0)
     prompts = [list(rng.integers(1, 256, size=n)) for n in (20, 45, 9)]
     new = (6, 12, 8)
 
+    def sums():
+        m = engine_metrics()
+        return {k: sum(m[k]._sums.values())
+                for k in ("state_slot_steps", "slot_steps", "block_steps")}
+    before = sums()
+
     async def run():
-        eng = _engine(params)
+        eng = _engine(params, kv_impl=kv_impl)
+        assert eng.stats["kv_impl"] == kv_impl
+        assert eng.stats["kv_interpret"] == (kv_impl == "paged_flash")
         assert eng.stats["state_layers"] == 6
         assert eng.stats["state_bytes_per_slot"] == 6 * (4096 + 1536)
         assert eng.stats["state_bytes"] == 2 * 6 * (4096 + 1536)
@@ -425,6 +446,12 @@ def test_the_engine_serves_it_through_slots_that_are_used_again(fam, params):
         assert out == _greedy(fam, params, p, n)
     assert st["state_admits"] == 3 and st["blocks_used_state"] == 0
     assert st["prefix_hit_tokens"] == 0
+    d = {k: v - before[k] for k, v in sums().items()}
+    # a reply's first token is the prefill's: one decode step each other
+    assert d["slot_steps"] == sum(n - 1 for n in new)
+    assert st["state_slot_steps"] == d["state_slot_steps"] == (
+        d["slot_steps"] if kv_impl == "paged_flash"
+        else 2 * d["block_steps"])
 
 
 # --- (e): the expert layer's share ------------------------------------------
