@@ -596,8 +596,9 @@ class LLMEngine:
             if prefix_cache:
                 raise ValueError(
                     "prefix_cache=True with state layers: prefix reuse is "
-                    "not supported for a model with state-space layers, "
-                    "whose recurrent state is kept a slot and not a block")
+                    "not supported for a model with state-space or "
+                    "short-convolution layers, whose recurrent state or "
+                    "conv tail is kept a slot and not a block")
             prefix_cache = False
         if prefix_cache is None:
             prefix_cache = bool(getattr(_cfg, "kvcache_prefix_cache",
@@ -616,8 +617,9 @@ class LLMEngine:
         if spec and stateful:
             raise ValueError(
                 "speculative decoding is not supported with state layers: "
-                "a rejected draft would have to roll a recurrent state "
-                "back, and no snapshot of it is kept")
+                "a rejected draft would have to roll a recurrent state (or "
+                "a short convolution's tail) back, and no snapshot of it is "
+                "kept")
         # Speculative decoding (llm/spec.py): draft-and-verify rides
         # the block-table verify forward
         self._spec = bool(spec)
@@ -935,7 +937,7 @@ class LLMEngine:
             raise ValueError(
                 "a prefilled request (the prefill/decode hand-off) is not "
                 "supported with state layers: the payload carries K and V "
-                "rows and no recurrent state to decode from")
+                "rows and no recurrent state or conv tail to decode from")
         if prefilled is not None:
             # validate at submission: a malformed payload must fail THIS
             # request, not blow up the shared scheduler loop mid-admit
@@ -1375,8 +1377,12 @@ class LLMEngine:
             self._m["kv_fetch_tokens"].observe(
                 fetched / sum(layers for layers, _ in self._walks))
         if self._stateful:
-            moved = kvcache.state_slot_steps(self._kv_impl, self.max_slots,
-                                             n, block)
+            # a conv tail alone has no kernel: every slot's passes through
+            # the step's ``where`` whatever the implementation
+            kernel = kvcache.POOL_KEYS[kvcache.STATE][0] in self._pool
+            moved = kvcache.state_slot_steps(
+                self._kv_impl if kernel else "gather", self.max_slots, n,
+                block)
             self._state_slot_steps += moved
             self._m["state_slot_steps"].observe(moved)
         if counts is not None:

@@ -656,7 +656,18 @@ def _jx():
 # A STATE layer (a state-space mixer) keeps no row a position: its pair of
 # arrays is one recurrent state (float32: it is carried, never rounded) and
 # one conv tail A SLOT, (the kind's layers, slots, ...). It has no block ids
-# and no table; the slot is the index.
+# and no table; the slot is the index. A state layer that is a gated SHORT
+# CONVOLUTION (ops/shortconv.py) keeps the tail ALONE, "conv" and no "ssm":
+# a pool holds the arrays of ``state_arrays``, and every walk over
+# ``POOL_KEYS[STATE]`` takes the keys the pool has.
+#
+# A K/V head NARROWER than the 128 lanes of a tile (64 values) shares a
+# pool row's lanes with its neighbour: ``row_shapes`` gives (kv_heads / 2,
+# 128), heads 2j and 2j + 1 side by side, the same bytes in the same order
+# as (kv_heads, 64) and no padding (a minor dimension of 64 bf16 values
+# would lie in whole 128-lane tiles, twice the bytes, and the walk's DMA
+# cannot take half a tile). ``_pool_attend`` packs a step's queries to
+# match and takes each head's half of what comes back.
 GLOBAL, WINDOW, LATENT, STATE = "global", "window", "latent", "state"
 LANES = 128
 POOL_KEYS = {GLOBAL: ("k", "v"), WINDOW: ("wk", "wv"), LATENT: ("c", "kr"),
@@ -694,9 +705,20 @@ def row_shapes(cfg, kind: str) -> tuple:
         return tuple((-(-w // LANES) * LANES,)
                      for w in (cfg.kv_lora_rank, cfg.qk_rope_head_dim))
     if kind == STATE:       # a slot's, whatever the position
-        return ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                (cfg.ssm_conv_kernel - 1, cfg.ssm_widths[1]))
-    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
+        return tuple(shape for shape, _ in state_arrays(cfg, None).values())
+    pack = heads_packed(cfg)
+    return ((cfg.n_kv_heads // pack, cfg.head_dim * pack),) * 2
+
+
+def heads_packed(cfg) -> int:
+    """K/V heads that share one pool row's 128 lanes (the config's
+    ``kv_row_heads``): 1, or 2 for heads of 64 values."""
+    pack = getattr(cfg, "kv_row_heads", 1) or 1
+    if pack > 1 and (cfg.n_kv_heads % pack or cfg.head_dim * pack > LANES):
+        raise ValueError(
+            f"kv_row_heads={pack}: {cfg.n_kv_heads} K/V heads of "
+            f"{cfg.head_dim} do not lie {pack} a row of {LANES} lanes")
+    return pack
 
 
 def row_bytes(cfg, kind: str, dtype) -> int:
@@ -713,12 +735,26 @@ def state_dtypes(dtype) -> tuple:
     return jnp.dtype(jnp.float32), jnp.dtype(dtype)
 
 
+def state_arrays(cfg, dtype) -> dict:
+    """{pool key: (a slot's shape of ONE state layer, its dtype; None
+    without ``dtype``)}: a Mamba-2 mixer's float32 state and its conv tail
+    (the last K - 1 rows of xBC), or a gated short convolution's tail alone,
+    flat (ops/shortconv.py)."""
+    sk, tk = POOL_KEYS[STATE]
+    f32, dt = state_dtypes(dtype) if dtype is not None else (None, None)
+    if getattr(cfg, "shortconv_kernel", 0):
+        from ray_tpu.ops.shortconv import tail_shape
+        return {tk: (tail_shape(cfg), dt)}
+    return {sk: ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), f32),
+            tk: ((cfg.ssm_conv_kernel - 1, cfg.ssm_widths[1]), dt)}
+
+
 def state_slot_bytes(cfg, dtype) -> int:
     """Bytes one slot's states and conv tails cost, all state layers."""
     layers = dict(pool_kinds(cfg)).get(STATE, ())
     return len(layers) * sum(
-        int(np.prod(shape)) * dt.itemsize for shape, dt in zip(
-            row_shapes(cfg, STATE), state_dtypes(dtype)))
+        int(np.prod(shape)) * dt.itemsize
+        for shape, dt in state_arrays(cfg, dtype).values())
 
 
 def init_pool(cfg, num_blocks: int, block_size: int, dtype,
@@ -737,8 +773,7 @@ def init_pool(cfg, num_blocks: int, block_size: int, dtype,
             if state_slots < 1:
                 raise ValueError("a model with state layers needs "
                                  "state_slots >= 1: a state a slot")
-            for key, row, dt in zip(POOL_KEYS[kind], row_shapes(cfg, kind),
-                                    state_dtypes(dtype)):
+            for key, (row, dt) in state_arrays(cfg, dtype).items():
                 pool[key] = jnp.zeros((len(layers), state_slots, *row), dt)
             continue
         blocks = max(2, window_blocks) if kind == WINDOW else num_blocks
@@ -752,15 +787,15 @@ def pool_k(pool: dict, kind: Optional[str] = None):
     """A kind's first array, (its layers, its blocks, ..., block_size,
     width): what a geometry is read off (the block size is axis -2 for
     every kind). Without ``kind``: of the first kind the pool holds."""
-    return pool[POOL_KEYS[kind or _held_kinds(pool)[0]][0]]
+    return pool[_held_keys(pool, kind or _held_kinds(pool)[0])[0]]
 
 
 def kind_block_bytes(pool: dict) -> dict:
     """{kind: device bytes one block id of that kind costs (k + v, all
     the kind's layers)}."""
     return {kind: sum(pool[key].nbytes // pool[key].shape[1]
-                      for key in keys)
-            for kind, keys in POOL_KEYS.items() if keys[0] in pool}
+                      for key in _held_keys(pool, kind))
+            for kind in _held_kinds(pool)}
 
 
 def window_ring_blocks(window: int, block_size: int, steps: int) -> int:
@@ -817,7 +852,7 @@ def _pool_key(pool: dict) -> tuple:
     """Cache-key component identifying one pool's compiled geometry:
     the first array's shape, the dtype, then every other array's shape."""
     shapes = [tuple(pool[key].shape) for kind in _held_kinds(pool)
-              for key in POOL_KEYS[kind]]
+              for key in _held_keys(pool, kind)]
     return (shapes[0], str(pool_k(pool).dtype), *shapes[1:])
 
 
@@ -827,9 +862,14 @@ def _pool_key(pool: dict) -> tuple:
 # the engine hand them, a bare array for such a model and no layout:
 # these two functions, at an entry's first line, are all that knows it.
 
+def _held_keys(pool: dict, kind: str) -> tuple:
+    """The kind's arrays that ``pool`` has (a STATE kind's may be its conv
+    tail alone)."""
+    return tuple(key for key in POOL_KEYS[kind] if key in pool)
+
+
 def _held_kinds(pool: dict) -> tuple:
-    return tuple(kind for kind, keys in POOL_KEYS.items()
-                 if keys[0] in pool)
+    return tuple(kind for kind in POOL_KEYS if _held_keys(pool, kind))
 
 
 def _by_kind(ids, pool: dict) -> dict:
@@ -954,7 +994,7 @@ def _jit(name: str, pool: dict, kinds: tuple = ()):
             return {**pool, **{
                 key: pool[key].at[:, slot].set(
                     state[key].astype(pool[key].dtype))
-                for key in POOL_KEYS[STATE]}}
+                for key in _held_keys(pool, STATE)}}
     else:
         raise KeyError(name)
     _JITS[key] = fn
@@ -1007,7 +1047,7 @@ def write_state(pool: dict, state: dict, slot: int) -> dict:
     whatever the slot held before is gone."""
     _, jnp = _jx()
     return _jit("write_state", pool)(
-        pool, {key: state[key] for key in POOL_KEYS[STATE]},
+        pool, {key: state[key] for key in _held_keys(pool, STATE)},
         jnp.int32(slot))
 
 
@@ -1110,6 +1150,40 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     if multi and WINDOW in tables:
         raise NotImplementedError(
             "the verify forward attends global layers only")
+    # heads of 64 values lie two a pool row (``row_shapes``): the walk sees
+    # kvh / pack heads of pack * hd lanes, each with pack * group queries
+    # that are zero outside their own head's lanes, so a score is the
+    # head's own q . k and its lanes of the output the head's own sum
+    pack = heads_packed(cfg)
+    if pack > 1 and multi:
+        raise NotImplementedError(
+            "the verify forward attends K/V heads of a whole lane tile "
+            "only: heads packed two a pool row have no multi-query walk")
+    own = np.eye(pack, dtype=bool)[:, None, :, None]    # (pack, 1, pack, 1)
+
+    def packed(qg):
+        """(slots, kvh, g, hd) -> (slots, kvh / pack, pack * g, pack * hd)"""
+        if pack == 1:
+            return qg
+        _, jnp = _jx()
+        g = qg.shape[-2]
+        qg = qg.reshape(-1, kvh // pack, pack, g, 1, hd)
+        return jnp.where(own, qg, 0).reshape(
+            -1, kvh // pack, pack * g, pack * hd)
+
+    def unpacked(o):
+        """``packed`` back for the walk's output: each head's own lanes."""
+        if pack == 1:
+            return o
+        _, jnp = _jx()
+        g = o.shape[-2] // pack
+        o = o.reshape(-1, kvh // pack, pack, g, pack, hd)
+        return jnp.sum(jnp.where(own, o, 0), axis=-2)
+
+    def row(x, pool):
+        """A step's new rows (n, kvh, hd) as the pool keeps them."""
+        return x.reshape(-1, *pool.shape[2:3], pool.shape[-1]).astype(
+            pool.dtype)
 
     def flat(pool):
         return pool.reshape(-1, *pool.shape[2:])
@@ -1121,6 +1195,7 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
     def window_kw(kind):
         return {} if kind == GLOBAL else {"window": cfg.sliding_window}
 
+    scale = {"head_dim": hd} if pack > 1 else {}
     if impl == "paged_flash":
         def writer(kind):
             def write_attend(qg, k, v, kf, vf, tb, blocks, rows, ln):
@@ -1129,7 +1204,7 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
                 o = (pa.paged_attention_verify(qg, kf, vf, tb, ln) if multi
                      else pa.paged_attention(qg, kf, vf, tb, ln,
                                              interpret=interpret,
-                                             **window_kw(kind)))
+                                             **window_kw(kind), **scale))
                 return o, kf, vf
 
             if mesh is None:
@@ -1150,12 +1225,11 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
             phys, off = at[ref.kind]
             base = ref.kind_index * kp.shape[1]
             o, kf, vf = write_attend[ref.kind](
-                q.reshape(*lead, kvh, h // kvh, hd),
-                k.reshape(-1, kvh, hd).astype(kp.dtype),
-                v.reshape(-1, kvh, hd).astype(vp.dtype),
+                packed(q.reshape(*lead, kvh, h // kvh, hd)),
+                row(k, kp), row(v, vp),
                 flat(kp), flat(vp), tables[ref.kind] + base,
                 _entries(phys + base, lens), off.reshape(-1), lens)
-            return o.reshape(*lead, h * hd), {
+            return unpacked(o).reshape(*lead, h * hd), {
                 **pool, kk: kf.reshape(kp.shape), vk: vf.reshape(vp.shape)}
     else:
         attn = lm._gqa_attend_multi if multi else lm._gqa_attend_cached
@@ -1165,13 +1239,17 @@ def _pool_attend(cfg, tables, at, lens, *, impl, interpret, mesh, axis):
             kp, vp = pool[kk], pool[vk]
             phys, off = at[ref.kind]
             l = ref.kind_index
-            kp = kp.at[l, phys, :, off].set(k.astype(kp.dtype))
-            vp = vp.at[l, phys, :, off].set(v.astype(vp.dtype))
+            kp = kp.at[l, phys, :, off].set(
+                k.reshape(*lead, *kp.shape[2:3], -1).astype(kp.dtype))
+            vp = vp.at[l, phys, :, off].set(
+                v.reshape(*lead, *vp.shape[2:3], -1).astype(vp.dtype))
             tb = tables[ref.kind] + l * kp.shape[1]
-            o = attn(q.reshape(*lead, h * hd),
-                     pa.table_view(flat(kp), tb),
-                     pa.table_view(flat(vp), tb), lens, cfg,
-                     **window_kw(ref.kind))
+
+            def view(pool):     # (slots, positions, kvh, hd), heads apart
+                g = pa.table_view(flat(pool), tb)
+                return g.reshape(*g.shape[:2], kvh, hd)
+            o = attn(q.reshape(*lead, h * hd), view(kp), view(vp), lens,
+                     cfg, **window_kw(ref.kind))
             return o, {**pool, kk: kp, vk: vp}
     return attend
 

@@ -140,6 +140,16 @@ def _scan_layers(layer, x, xs):
 # ``_kind_layers``), and the same ``_segments`` cut them into scans: a
 # period takes each kind's rows from its own stack.
 
+#
+# ``shortconv_kernel`` > 0 puts a "state" layer INSIDE the two-sub-layer
+# block: its first sub-layer is a gated short convolution (ops/shortconv.py)
+# where a "global" layer's is attention, the second the dense or the expert
+# feed-forward as ever, behind ``n_dense_layers`` that are such layers too.
+# What a slot keeps of it is the conv's tail alone. The two operators' leaves
+# are stacks of their own (``STACKS``, a row the layer's place among its
+# kind); ``dense_layers`` and ``layers`` keep what every layer has
+# (``operator_stacks``).
+
 EXPERTS = "experts"     # a layer that is an expert layer alone: no cache
 STACKS = {STATE: "state_layers", EXPERTS: "expert_layers",
           GLOBAL: "attn_layers"}
@@ -167,10 +177,15 @@ def layer_kinds(cfg) -> tuple:
             f"'experts' or 'global'), got {kinds}")
     if {STATE, EXPERTS} & set(kinds) and {WINDOW, LATENT} & set(kinds):
         raise ValueError(
-            "state layers (and layers that are one mixer alone) beside "
+            "state layers (a state-space mixer, a gated short convolution; "
+            "and layers that are one mixer alone) beside "
             "window or latent layers are not served: a window layer's ring "
             "and a latent layer's rows have no single-mixer body, and a "
             "state cannot give back what a window has passed")
+    if operator_stacks(cfg) and EXPERTS in kinds:
+        raise ValueError(
+            "a short-convolution model's layers have two sub-layers: "
+            "'state' or 'global', not 'experts'")
     if WINDOW in kinds and getattr(cfg, "sliding_window", 0) < 1:
         raise ValueError("window layers need sliding_window >= 1")
     if LATENT in kinds and set(kinds) != {LATENT}:
@@ -195,6 +210,13 @@ def single_mixer(cfg) -> bool:
     """Whether every layer of ``cfg`` is one mixer behind one norm
     (models/moe.py ``MoEConfig.single_mixer``)."""
     return getattr(cfg, "single_mixer", False)
+
+
+def operator_stacks(cfg) -> bool:
+    """Whether a layer's FIRST sub-layer (attention, or a gated short
+    convolution) has its parameters in its kind's own stack (``STACKS``)
+    beside the two-sub-layer stacks: a model with ``shortconv_kernel``."""
+    return bool(getattr(cfg, "shortconv_kernel", 0))
 
 
 def window_of(cfg, kind: str):
@@ -274,7 +296,7 @@ def _run_layers(params, cfg, carry, body, per_layer=()):
     time. Returns (carry, the ys with the layers on their first axis,
     or None where the body returns none). A model whose kinds are stacks
     of their own (``single_mixer``): ``_run_kind_stacks``."""
-    if single_mixer(cfg):
+    if single_mixer(cfg) or operator_stacks(cfg):
         return _run_kind_stacks(params, cfg, carry, body, per_layer or {})
     kinds = layer_kinds(cfg)
     pieces = []
@@ -340,6 +362,13 @@ def _run_layers(params, cfg, carry, body, per_layer=()):
     return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys), *pieces)
 
 
+def _stack_row(stack, row):
+    """Row ``row`` (a Python int, or traced) of every leaf of ``stack``."""
+    return jax.tree.map(
+        lambda w: w[row] if isinstance(row, int) else
+        lax.dynamic_index_in_dim(w, row, keepdims=False), stack)
+
+
 def _run_kind_stacks(params, cfg, carry, body, per_layer: dict):
     """``_run_layers`` where a kind's parameters are a stack of its own
     (``STACKS``): a period takes each kind's rows from that kind's stack.
@@ -351,12 +380,17 @@ def _run_kind_stacks(params, cfg, carry, body, per_layer: dict):
     layers on their first axis}, handed to the kind's bodies a layer at a
     time; the ys come back the same way, {kind: that kind's ys, its layers
     on their first axis} (a body's y has its kind's structure; None for a
-    kind that returns none)."""
+    kind that returns none). With ``operator_stacks`` a kind's stack holds
+    the layers' first sub-layers alone: the body's ``lp`` is that row beside
+    the layer's own row of its segment's stack (``dense_layers`` /
+    ``layers``), which is ``ref.stack`` and ``ref.row`` (where the grouped
+    matmuls read the experts in place)."""
     kinds = layer_kinds(cfg)
     pieces = []
     for seg in _segments(cfg):
         p, r = len(seg.kinds), seg.repeats
-        per = {k: seg.kinds.count(k) for k in set(seg.kinds)}
+        # in the period's own order (a set's hangs on the process's hash seed)
+        per = {k: seg.kinds.count(k) for k in dict.fromkeys(seg.kinds)}
         first = {k: kinds[:seg.layer0].count(k) for k in per}
 
         def period(carry, extras, i, seg=seg, p=p, per=per, first=first):
@@ -365,11 +399,13 @@ def _run_kind_stacks(params, cfg, carry, body, per_layer: dict):
                 c = seg.kinds[:j].count(kind)
                 row = _affine(i, per[kind], first[kind] + c)
                 stack = params[STACKS[kind]]
-                lp = jax.tree.map(
-                    lambda w: w[row] if isinstance(row, int) else
-                    lax.dynamic_index_in_dim(w, row, keepdims=False), stack)
+                lp = _stack_row(stack, row)
                 ref = LayerRef(kind, _affine(i, p, seg.layer0 + j), row,
                                stack, row)
+                if seg.stack is not None:       # operator_stacks
+                    own, at = params[seg.stack], _affine(i, p, seg.row + j)
+                    lp = {**_stack_row(own, at), **lp}
+                    ref = dataclasses.replace(ref, stack=own, row=at)
                 carry, y = body(carry, lp, ref,
                                 *(e[c] for e in extras.get(kind, ())))
                 outs[kind].append(y)
@@ -519,19 +555,28 @@ def _qkv(y, lp, cfg: LlamaConfig):
         heads("wv", kvh)
 
 
-def _feed_forward(y, lp, cfg, ref: LayerRef, active):
+def _feed_forward(y, lp, cfg, ref: LayerRef, active, routed=None):
     """The layer's feed-forward on normed rows y (b, s, d): the dense
     SwiGLU, or for a layer with a router this device's part of the
     expert layer (models/moe.py serve_block). Returns (out, the expert
-    layer's counts or None)."""
+    layer's counts or None). ``routed``: a list that takes the experts
+    every row chose, (b * s, k) int32, -1 in a layer without a router (a
+    comparison's entries ask for them: ``prefill_chunk_routed``,
+    ``decode_logits_core(chosen=True)``; no serving path does)."""
     if "router" not in lp:
+        if routed is not None:
+            routed.append(jnp.full(
+                (y.size // y.shape[-1], cfg.experts_per_token), -1,
+                jnp.int32))
         with jax.named_scope("mlp"):
             return ((jax.nn.silu(y @ lp["w_gate"]) * (y @ lp["w_up"]))
                     @ lp["w_down"]), None
     from ray_tpu.models import moe
-    out, stats = moe.serve_block(
+    out, stats, *chosen = moe.serve_block(
         y.reshape(-1, y.shape[-1]), lp, cfg, stack=ref.stack, row=ref.row,
-        active=active)
+        active=active, choice=routed is not None)
+    if routed is not None:
+        routed.extend(chosen)
     return out.reshape(y.shape), stats
 
 
@@ -641,19 +686,45 @@ def _residual(x, lp, cfg, sub: str, f):
     return _mhc_write(x, res, post, out), aux
 
 
-def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
+def _as_cached(rows, cfg):
+    """K or V rows (b, s, kv heads, head) as the pool keeps them: heads
+    narrower than a lane tile lie ``kv_row_heads`` a row (llm/kvcache.py
+    row_shapes), the same values in the same order."""
+    from ray_tpu.llm.kvcache import heads_packed
+    pack = heads_packed(cfg)
+    return rows if pack == 1 else rows.reshape(
+        *rows.shape[:2], cfg.n_kv_heads // pack, -1)
+
+
+def _heads_apart(rows, cfg):
+    """``_as_cached`` back: (..., rows' heads, lanes) -> (..., kv heads,
+    head)."""
+    from ray_tpu.llm.kvcache import heads_packed
+    return rows if heads_packed(cfg) == 1 else rows.reshape(
+        *rows.shape[:-2], cfg.n_kv_heads, cfg.head_dim)
+
+
+def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None,
+           state=None, routed=None):
     """One decoder layer of every serving forward. x (b, s, d), or the
     mixed stream (n, b, s, d); ``rope`` the (cos, sin) tables of the
     positions; ``attend(q, k, v, wo)`` attends (the forwards differ in
     nothing else) and returns the projected output, (b, s, d). Returns
     (x, k, v, expert counts or None); k is as the cache keeps it (after
-    RoPE). For a latent layer k and v are the cache's rows c and kr
-    (``_latent_qkv``)."""
+    RoPE; ``_as_cached``). For a latent layer k and v are the cache's rows
+    c and kr (``_latent_qkv``). A STATE layer's first sub-layer is a gated
+    short convolution, ``state(y, lp) -> (out, what the forward keeps of
+    it)``, handed in as ``attend`` is (the forwards differ in where the
+    conv's tail comes from and goes to): k is what it keeps, v None.
+    ``routed``: ``_feed_forward``'s."""
     post = getattr(cfg, "post_norm", False)
     eps = cfg.norm_eps
 
     def attention(x):
         y = x if post else _rmsnorm(x, lp["attn_norm"], eps)
+        if ref.kind == STATE:
+            out, kept = state(y, lp)
+            return out, (kept, None)
         if ref.kind == LATENT:
             # k, v: the positions' cache rows c and kr (no head axis);
             # the forward's ``attend`` expands or absorbs
@@ -664,11 +735,13 @@ def _layer(x, lp, cfg, ref: LayerRef, rope, attend, active=None):
                     or getattr(cfg, "rope_layers", "all") == "all":
                 q, k = _rope(q, *rope), _rope(k, *rope)
         a = attend(q, k, v, lp["wo"])
+        if ref.kind != LATENT:
+            k, v = _as_cached(k, cfg), _as_cached(v, cfg)
         return (_rmsnorm(a, lp["attn_norm"], eps) if post else a), (k, v)
 
     def feed_forward(x):
         y = x if post else _rmsnorm(x, lp["mlp_norm"], eps)
-        m, stats = _feed_forward(y, lp, cfg, ref, active)
+        m, stats = _feed_forward(y, lp, cfg, ref, active, routed)
         return (_rmsnorm(m, lp["mlp_norm"], eps) if post else m), stats
 
     with jax.named_scope("attention." + ref.kind):
@@ -720,6 +793,18 @@ def _mixer_prefill_layer(x, lp, cfg, ref, rope, attend, length, start, kv):
     return x, (kv(*kept) if ref.kind == GLOBAL else kept)   # EXPERTS: chosen
 
 
+def _tail_prefill(cfg, start: tuple, length):
+    """A prefill forward's ``state`` hook of ``_layer``: the gated short
+    convolution over one row x (1, s, d) from the tail ``start`` = (tail,),
+    handing on the tail at the row's ``length``."""
+    from ray_tpu.ops import shortconv
+
+    def state(y, lp):
+        out, tail = shortconv.prefill(y[0], lp, cfg, start[0], length)
+        return out[None], tail
+    return state
+
+
 def _state_step(y, lp, cfg, ref, pool, live, step):
     """A decode step's state layer: y (slots, d) normed rows against the
     layer's place (``ref.kind_index``) in the pool's states and conv tails,
@@ -746,12 +831,30 @@ def _state_step(y, lp, cfg, ref, pool, live, step):
     return out, {**pool, tk: tails}
 
 
+def _tail_step(y, lp, cfg, ref, pool, live):
+    """A decode step's gated short convolution: y (slots, d) normed rows
+    against the layer's place (``ref.kind_index``) in the pool's conv tails,
+    which are the layer scans' carry. The slots of ``live`` move on by one
+    row; another slot's tail stays as it is (its row of the output is
+    garbage nobody reads). Returns (out (slots, d), pool)."""
+    from ray_tpu.llm.kvcache import POOL_KEYS
+    from ray_tpu.ops import shortconv
+    tk = POOL_KEYS[STATE][1]
+    l = ref.kind_index
+    tl = lax.dynamic_index_in_dim(pool[tk], l, keepdims=False)
+    out, tl2 = shortconv.step(y, lp, cfg, tl)
+    with jax.named_scope("shortconv.conv"):
+        tl2 = jnp.where(live[:, None], tl2, tl)
+        tails = lax.dynamic_update_index_in_dim(pool[tk], tl2, l, 0)
+    return out, {**pool, tk: tails}
+
+
 def fresh_state(cfg, dtype) -> tuple:
     """What a prompt's state layers start from: (a zero state (float32), a
     zero conv tail) of one layer."""
-    from ray_tpu.llm.kvcache import row_shapes, state_dtypes
-    return tuple(jnp.zeros(shape, dt) for shape, dt in zip(
-        row_shapes(cfg, STATE), state_dtypes(dtype)))
+    from ray_tpu.llm.kvcache import state_arrays
+    return tuple(jnp.zeros(shape, dt)
+                 for shape, dt in state_arrays(cfg, dtype).values())
 
 
 def _gqa_attend_cached(q, cache_k, cache_v, lengths, cfg: LlamaConfig,
@@ -807,11 +910,21 @@ def _prefill_attn_kw(cfg, kind: str) -> dict:
     return kw
 
 
+def _logits(x, params):
+    """Normed rows x (..., d) through the head -> (..., vocab) float32; a
+    model with tied embeddings has no ``lm_head`` leaf and multiplies with
+    the embedding as it lies, (vocab, d)."""
+    if "lm_head" in params:
+        return (x @ params["lm_head"]).astype(jnp.float32)
+    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(
+        jnp.float32)
+
+
 def _head(x, params, cfg, length):
     """Last valid row of x (1, s, d) -> (vocab,) float32 logits."""
     x = _final_norm(x, params, cfg)
     last = jnp.take(x[0], length - 1, axis=0)
-    return (last @ params["lm_head"]).astype(jnp.float32)
+    return _logits(last, params)
 
 
 def _prefill(params: dict, tokens: jax.Array, length: jax.Array,
@@ -855,8 +968,11 @@ def _prefill(params: dict, tokens: jax.Array, length: jax.Array,
             return _mixer_prefill_layer(
                 x, lp, cfg, ref, rope, attend, length,
                 fresh_state(cfg, x.dtype), lambda k, v: (k[0], v[0]))
-        x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
-        return x, (k[0], v[0])
+        x, k, v, _ = _layer(
+            x, lp, cfg, ref, rope, attend,
+            state=_tail_prefill(cfg, fresh_state(cfg, x.dtype), length)
+            if ref.kind == STATE else None)
+        return x, (k if ref.kind == STATE else (k[0], v[0]))
 
     x, ys = _run_layers(params, cfg, x, layer)
     logits = _head(x, params, cfg, length)
@@ -870,6 +986,13 @@ def _prefill(params: dict, tokens: jax.Array, length: jax.Array,
         (ks, vs), (ssm, conv) = ys[GLOBAL], ys[STATE]
         return logits, {"k": pad(ks), "v": pad(vs), "ssm": ssm,
                         "conv": conv}, ys.get(EXPERTS)
+    if operator_stacks(cfg):
+        # K and V of the global layers alone; a short convolution's tail
+        # at the prompt's length
+        from ray_tpu.llm.kvcache import POOL_KEYS
+        ks, vs = ys[GLOBAL]
+        return logits, {"k": pad(ks), "v": pad(vs),
+                        POOL_KEYS[STATE][1]: ys[STATE]}, None
     ks, vs = ys
     return logits, {"k": pad(ks), "v": pad(vs)}, None
 
@@ -927,6 +1050,25 @@ def prefill_chunk(params: dict, tokens: jax.Array, length: jax.Array,
                               jnp.asarray(offset, jnp.int32), acc, cfg)
 
 
+def prefill_chunk_routed(params, tokens, length, offset, acc, cfg):
+    """``prefill_chunk`` with the experts every row of the chunk chose in
+    each layer, (layers, s, k) int32 in the model's layer order, -1 in a
+    layer without a router: (logits, acc, chosen). ONE program gives the
+    chunk's logits, what it leaves and the routing behind them, for a
+    comparison with a reference that has to follow that routing where
+    rounding decides it (benchmarks/families/lfm2_moe.py;
+    ``decode_logits_core(chosen=True)`` is the decode step's). No serving
+    path calls it; a model whose operators lie in stacks of their own
+    (``operator_stacks``) alone."""
+    impl = _chunk_flash_impl(cfg, tokens.shape[0])
+    if impl is not None:
+        return _prefill_chunk_flash(params, tokens, length, int(offset),
+                                    acc, cfg, impl, routed=True)
+    return _prefill_chunk_dyn(params, tokens, length,
+                              jnp.asarray(offset, jnp.int32), acc, cfg,
+                              routed=True)
+
+
 def _chunk_flash_impl(cfg, s: int):
     """The flash impl a chunk of ``s`` tokens takes, or None for the
     dynamic-offset XLA path."""
@@ -965,11 +1107,11 @@ def _into_acc(acc, new, offset):
         (jnp.int32(offset),) + (jnp.int32(0),) * (acc.ndim - 1))
 
 
-@partial(jax.jit, static_argnames=("cfg", "offset", "impl"),
+@partial(jax.jit, static_argnames=("cfg", "offset", "impl", "routed"),
          donate_argnums=(4,))
 def _prefill_chunk_flash(params: dict, tokens: jax.Array,
                          length: jax.Array, offset: int, acc: dict,
-                         cfg: LlamaConfig, impl: str):
+                         cfg: LlamaConfig, impl: str, routed: bool = False):
     """Flash chunked prefill: the kernel's q_offset places the causal
     diagonal at the chunk's absolute position, so no O(s x L) mask or
     score tensor is materialized. Causal alone is exact for every USED
@@ -982,9 +1124,11 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
     rope = rope_tables(cfg, positions)
 
     def layer(x, lp, ref, *acc):        # ak/av: (L, kvh, hd) the layer's
-        ak, av = acc or (None, None)
+        ak, av = acc if len(acc) == 2 else (None, None)
 
         def attend(q, k, v, wo):
+            if ref.kind != LATENT:
+                k, v = _as_cached(k, cfg), _as_cached(v, cfg)
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
             if ref.kind == LATENT:
                 # the accumulator keeps latent rows; what this chunk
@@ -993,22 +1137,24 @@ def _prefill_chunk_flash(params: dict, tokens: jax.Array,
                 nk, nv = latent_expand(q, nk[None, :n], nv[None, :n], lp,
                                        cfg)
             else:
-                nk, nv = nk[None].astype(q.dtype), nv[None].astype(q.dtype)
+                nk = _heads_apart(nk[None], cfg).astype(q.dtype)
+                nv = _heads_apart(nv[None], cfg).astype(q.dtype)
             o = _attention(q, nk, nv, causal=True,
                            sm_scale=softmax_scale(cfg, ref.kind),
                            impl=impl, q_offset=offset,
                            **_prefill_attn_kw(cfg, ref.kind))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
         return _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc,
-                            offset)
+                            offset, routed)
 
-    return _chunk_layers(params, cfg, x, layer, acc, length)
+    return _chunk_layers(params, cfg, x, layer, acc, length, routed)
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(4,))
+@partial(jax.jit, static_argnames=("cfg", "routed"), donate_argnums=(4,))
 def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
                        length: jax.Array, offset: jax.Array, acc: dict,
-                       cfg: LlamaConfig) -> Tuple[jax.Array, dict]:
+                       cfg: LlamaConfig,
+                       routed: bool = False) -> Tuple[jax.Array, dict]:
     """Dynamic-offset XLA path (single compile; O(s x L) scores)."""
     s = tokens.shape[0]
     L = acc["k"].shape[1]
@@ -1029,12 +1175,16 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
                              > q_pos[:, None] - cfg.sliding_window)
 
     def layer(x, lp, ref, *acc):        # ak/av: (L, kvh, hd) the layer's
-        ak, av = acc or (None, None)
+        ak, av = acc if len(acc) == 2 else (None, None)
 
         def attend(q, k, v, wo):
+            if ref.kind != LATENT:
+                k, v = _as_cached(k, cfg), _as_cached(v, cfg)
             nk, nv = _into_acc(ak, k, offset), _into_acc(av, v, offset)
             if ref.kind == LATENT:          # every row expanded, then masked
                 (nk,), (nv,) = latent_expand(q, nk[None], nv[None], lp, cfg)
+            else:
+                nk, nv = _heads_apart(nk, cfg), _heads_apart(nv, cfg)
             qg = q[0].reshape(s, kvh, g, -1).astype(jnp.float32)
             kf = nk.astype(jnp.float32)                     # (L, kvh, hd)
             scores = jnp.einsum("skgd,lkd->kgsl", qg, kf)
@@ -1046,28 +1196,48 @@ def _prefill_chunk_dyn(params: dict, tokens: jax.Array,
                            nv.astype(jnp.float32))
             return o.reshape(1, s, h * hd).astype(x.dtype) @ wo
         return _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc,
-                            offset)
+                            offset, routed)
 
-    return _chunk_layers(params, cfg, x, layer, acc, length)
+    return _chunk_layers(params, cfg, x, layer, acc, length, routed)
 
 
-def _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc, offset):
+def _chunk_layer(x, lp, cfg, ref, rope, attend, length, acc, offset,
+                 routed=False):
     """One layer of a ``prefill_chunk`` forward: ``acc`` is the layer's part
     of the accumulator (its K and V rows; a state layer's state and conv
     tail, which the chunk starts from and hands on; nothing for a layer
-    that caches nothing). Returns (x, the layer's part after the chunk)."""
+    that caches nothing). Returns (x, the layer's part after the chunk;
+    with ``routed``, (that, the experts the chunk's rows chose))."""
     def into(k, v):
         return _into_acc(acc[0], k, offset), _into_acc(acc[1], v, offset)
     if single_mixer(cfg):
         return _mixer_prefill_layer(x, lp, cfg, ref, rope, attend, length,
                                     acc, into)
-    x, k, v, _ = _layer(x, lp, cfg, ref, rope, attend)
-    return x, into(k, v)
+    picked = [] if routed else None
+    x, k, v, _ = _layer(
+        x, lp, cfg, ref, rope, attend,
+        state=_tail_prefill(cfg, acc, length) if ref.kind == STATE else None,
+        routed=picked)
+    y = k if ref.kind == STATE else into(k, v)
+    return x, ((y, picked[0]) if routed else y)
 
 
-def _chunk_layers(params, cfg, x, layer, acc, length):
+def _in_layer_order(cfg, by_kind: dict):
+    """{kind: arrays with that kind's layers on their first axis} -> one
+    array with the model's layers on it, in their order."""
+    import numpy as np
+    order = [i for kind in by_kind for i in kind_layers(cfg)[kind]]
+    return jnp.concatenate(list(by_kind.values()))[np.argsort(order)]
+
+
+def _chunk_layers(params, cfg, x, layer, acc, length, routed=False):
     """A ``prefill_chunk`` forward's layers over the accumulator -> (the
-    chunk's last valid token's logits, the accumulator after it)."""
+    chunk's last valid token's logits, the accumulator after it; with
+    ``routed`` also the experts every row chose in each layer)."""
+    if routed and not operator_stacks(cfg):
+        raise NotImplementedError(
+            "prefill_chunk_routed runs a model whose operators lie in "
+            "stacks of their own (operator_stacks) alone")
     if single_mixer(cfg):
         from ray_tpu.llm.kvcache import POOL_KEYS
         sk, tk = POOL_KEYS[STATE]
@@ -1076,6 +1246,19 @@ def _chunk_layers(params, cfg, x, layer, acc, length):
         (nk, nv), (st, tl) = ys[GLOBAL], ys[STATE]
         return _head(x, params, cfg, length), {"k": nk, "v": nv, sk: st,
                                                tk: tl}
+    if operator_stacks(cfg):
+        from ray_tpu.llm.kvcache import POOL_KEYS
+        tk = POOL_KEYS[STATE][1]
+        x, ys = _run_layers(params, cfg, x, layer, per_layer={
+            GLOBAL: (acc["k"], acc["v"]), STATE: (acc[tk],)})
+        chosen = ()
+        if routed:
+            chosen = (_in_layer_order(
+                cfg, {kind: y[1] for kind, y in ys.items()}),)
+            ys = {kind: y[0] for kind, y in ys.items()}
+        nk, nv = ys[GLOBAL]
+        return (_head(x, params, cfg, length),
+                {"k": nk, "v": nv, tk: ys[STATE]}, *chosen)
     x, (nk, nv) = _run_layers(params, cfg, x, layer,
                               per_layer=(acc["k"], acc["v"]))
     return _head(x, params, cfg, length), {"k": nk, "v": nv}
@@ -1185,7 +1368,11 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     states, handed in as ``attend`` is (a model without state layers never
     calls it and may leave it out). ``chosen`` (a model whose
     layers are each one mixer): a fourth value, the experts every slot chose
-    in each expert layer, (expert layers, slots, k) int32."""
+    in each expert layer, (expert layers, slots, k) int32; of a model whose
+    operators lie in stacks of their own (``operator_stacks``) in EVERY
+    layer, (layers, slots, k), -1 in a layer without a router. A short
+    convolution's tail is the pool's too, a slot's at the slot's index,
+    moved on by a row for the slots of ``live`` (``_tail_step``)."""
     x = _embed(params, tokens[:, None], cfg)                # (b, 1, emb)
     rope = rope_tables(cfg, positions[:, None])
     active = live if has_experts(cfg) else None
@@ -1204,14 +1391,21 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
             out, pool = _state_step(y[:, 0], lp, cfg, ref, pool, live,
                                     state_step)
             return out[:, None], None
+        def tail(y, lp):
+            nonlocal pool
+            out, pool = _tail_step(y[:, 0], lp, cfg, ref, pool, live)
+            return out[:, None], None
         kept = None
         if single_mixer(cfg):
             x, kept, stats = _mixer_layer(x, lp, cfg, ref, rope, attend_,
                                           state, active)
+            kept = kept if ref.kind == EXPERTS else None
         else:
-            x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active)
-        return (x, pool, _add_counts(counts, stats)), \
-            kept if ref.kind == EXPERTS else None
+            picked = [] if chosen else None
+            x, _, _, stats = _layer(x, lp, cfg, ref, rope, attend_, active,
+                                    tail, picked)
+            kept = picked[0] if chosen else None
+        return (x, pool, _add_counts(counts, stats)), kept
 
     counts = None
     if has_experts(cfg):
@@ -1220,9 +1414,10 @@ def decode_logits_core(params: dict, pool: dict, tokens: jax.Array,
     (x, pool, counts), ys = _run_layers(params, cfg, (x, pool, counts),
                                         layer)
     x = _final_norm(x, params, cfg)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    logits = _logits(x[:, 0], params)
     if chosen:
-        return logits, pool, counts, ys[EXPERTS]
+        return logits, pool, counts, ys[EXPERTS] if single_mixer(cfg) \
+            else _in_layer_order(cfg, ys)
     return logits, pool, counts
 
 
@@ -1268,11 +1463,13 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
     distribution.
     ``attend(ref, q, k, v, pool) -> ((b, w, h*hd) f32, pool)`` takes q
     (b, w, h, hd) and the new rows k, v (b, w, kvh, hd)."""
-    if single_mixer(cfg):
+    if single_mixer(cfg) or STATE in layer_kinds(cfg):
         raise NotImplementedError(
-            "the verify forward does not run state layers (or any layer "
+            "the verify forward does not run state layers (a state-space "
+            "mixer, a gated short convolution; or any layer "
             "that is one mixer alone): a rejected draft would have to roll "
-            "a recurrent state back, and no snapshot of it is kept")
+            "a recurrent state or a conv tail back, and no snapshot of it "
+            "is kept")
     b, w = tokens.shape
     x = _embed(params, tokens, cfg)                         # (b, w, emb)
     pos = positions[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
@@ -1290,5 +1487,5 @@ def verify_tokens_core(params: dict, pool: dict, tokens: jax.Array,
 
     (x, pool), _ = _run_layers(params, cfg, (x, pool), layer)
     x = _final_norm(x, params, cfg)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)    # (b, w, V)
+    logits = _logits(x, params)                             # (b, w, V)
     return logits, pool

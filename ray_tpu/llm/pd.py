@@ -47,7 +47,8 @@ class PrefillEngine:
             raise ValueError(
                 "the prefill/decode hand-off is not supported with state "
                 "layers: it ships K and V rows at block granularity, and a "
-                "recurrent state is no row of a block")
+                "recurrent state (or a short convolution's tail) is no row "
+                "of a block")
         self.cfg = cfg
         self.params = params
         self.max_len = max_len

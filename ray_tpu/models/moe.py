@@ -247,6 +247,18 @@ class MoEConfig:
     ssm_groups: int = 0
     ssm_conv_kernel: int = 4
     ssm_chunk: int = 128
+    # > 0: a "state" layer is a gated SHORT CONVOLUTION of this many taps
+    # (ops/shortconv.py), the first sub-layer of a two-sub-layer block whose
+    # second is the dense or the expert feed-forward; what a request keeps
+    # of it is the conv's tail alone, ``shortconv_kernel - 1`` rows
+    shortconv_kernel: int = 0
+    # added to the chosen gates' sum before ``norm_topk_prob`` divides by it
+    route_eps: float = 0.0
+    # the head multiplies with the embedding's transpose: no ``lm_head`` leaf
+    tie_embeddings: bool = False
+    # K/V heads that share one row of the paged pool's 128 lanes: 2 for
+    # heads of 64 values (llm/kvcache.py row_shapes)
+    kv_row_heads: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -266,8 +278,10 @@ class MoEConfig:
         """Whether every layer is ONE mixer behind one norm (a state layer,
         an expert layer or attention alone), its kinds' parameters stacks
         of their own (``state_layers``, ``expert_layers``, ``attn_layers``:
-        ``llm/model.py STACKS``)."""
-        return bool({"state", "experts"} & set(self.layer_types))
+        ``llm/model.py STACKS``). Not a model whose state layers are short
+        convolutions (``shortconv_kernel``): its layers have two sub-layers."""
+        return bool({"state", "experts"} & set(self.layer_types)) \
+            and not self.shortconv_kernel
 
     @property
     def ssm_widths(self) -> tuple:
@@ -325,6 +339,12 @@ class MoEConfig:
         return self.dim * (inner + conv + self.ssm_heads) + inner * self.dim \
             + conv * (self.ssm_conv_kernel + 1) + 3 * self.ssm_heads + inner
 
+    def _shortconv_params(self) -> int:
+        """One gated short convolution: the in-projection [B | C | X], the
+        taps and the out-projection."""
+        d = self.dim
+        return d * 3 * d + d * self.shortconv_kernel + d * d
+
     def _mixing_params(self) -> int:
         """A layer's hyper-connection leaves (two sub-layers)."""
         n = self.hc_copies
@@ -341,6 +361,18 @@ class MoEConfig:
                 + count("state") * self._state_params() \
                 + count("experts") * self._layer_params(experts) \
                 + count("global") * (self._attn_params() - 2 * self.dim)
+        if self.shortconv_kernel:
+            # an operator a layer (attention or a short convolution), the
+            # leading dense layers' included, behind the two norms
+            conv = self.layer_types.count("state")
+            embed = (1 if self.tie_embeddings else 2) * self.vocab_size \
+                * self.dim
+            return embed + self.dim \
+                + conv * (self._shortconv_params() + 2 * self.dim) \
+                + (self.n_layers - conv) * self._attn_params() \
+                + self.n_dense_layers * 3 * self.dim * self.dense_ffn_dim \
+                + (self.n_layers - self.n_dense_layers) \
+                * self._layer_params(experts)
         dense = self._attn_params() + 3 * self.dim * self.dense_ffn_dim
         linear = self.layer_types.count("linear")
         mixers = linear * self._linear_params() \
@@ -494,6 +526,30 @@ def nemotron_3_nano_30b_a3b(**kw) -> MoEConfig:
     return MoEConfig(**defaults)
 
 
+def lfm2_24b_a2b(**kw) -> MoEConfig:
+    """LiquidAI/LFM2-24B-A2B ``config.json`` (``model_type: lfm2_moe``): 40
+    two-sub-layer blocks whose first sub-layer is, by ``layer_types``, a
+    gated short convolution of 3 taps ("conv": served as a "state" layer
+    whose state is the conv's two-row tail alone) or attention (32 query / 8
+    KV heads of 64, a norm a head on q and k before RoPE at theta 1e6); 2
+    leading dense layers of width 11776, then 64 sigmoid-routed experts of
+    width 1536, 4 a token chosen by score + bias, gates normed over (their
+    sum + 1e-6), no shared expert; tied embeddings."""
+    defaults = dict(
+        vocab_size=65536, dim=2048, n_layers=40, n_heads=32, n_kv_heads=8,
+        head_size=64, ffn_dim=1536, n_experts=64, experts_per_token=4,
+        norm_topk_prob=True, scoring="sigmoid", routed_scaling=1.0,
+        route_eps=1e-6, n_dense_layers=2, dense_ffn_dim=11776,
+        qk_head_norm=True, shortconv_kernel=3, tie_embeddings=True,
+        kv_row_heads=2, max_seq_len=128000, rope_theta=1e6, norm_eps=1e-5)
+    defaults.update(kw)
+    if "layer_types" not in defaults:
+        defaults["layer_types"] = tuple(
+            "global" if i >= 2 and (i - 2) % 4 == 0 else "state"
+            for i in range(defaults["n_layers"]))
+    return MoEConfig(**defaults)
+
+
 def pattern_kinds(pattern: str) -> tuple:
     """``hybrid_override_pattern`` -> layer_types: M a state layer, E an
     expert layer, * attention."""
@@ -640,38 +696,76 @@ def _init_serving(rng: jax.Array, cfg: MoEConfig) -> dict:
                 next(ks), (L, 3), jnp.float32)
         return out
 
-    def attn(L):
+    def attn(L, norms=True):
         if latent:
             return latent_attn(L)
-        out = {"attn_norm": jnp.full((L, d), attn_norm, dtype),
-               "wq": stack(L, d, h * hd, fan_in=d),
+        out = {"wq": stack(L, d, h * hd, fan_in=d),
                "wk": stack(L, d, kvh * hd, fan_in=d),
                "wv": stack(L, d, kvh * hd, fan_in=d),
-               "wo": stack(L, h * hd, d, fan_in=h * hd),
-               "mlp_norm": jnp.ones((L, d), dtype)}
+               "wo": stack(L, h * hd, d, fan_in=h * hd)}
+        if norms:
+            out = {"attn_norm": jnp.full((L, d), attn_norm, dtype), **out,
+                   "mlp_norm": jnp.ones((L, d), dtype)}
         if cfg.qk_head_norm:
             # normed q and k of weight 1 would score q.k / sqrt(hd) with
             # a spread of sqrt(hd): a softmax that is one-hot, which no
             # trained model has; hd ** -0.25 each gives a unit spread
-            out["q_norm"] = jnp.full((L, hd), hd ** -0.25, dtype)
-            out["k_norm"] = jnp.full((L, hd), hd ** -0.25, dtype)
+            # (a short-convolution model's norms are 1, as its
+            # configuration states: its scores then spread by 1)
+            w = 1.0 if cfg.shortconv_kernel else hd ** -0.25
+            out["q_norm"] = jnp.full((L, hd), w, dtype)
+            out["k_norm"] = jnp.full((L, hd), w, dtype)
         elif cfg.qk_norm:
             out["q_norm"] = jnp.ones((L, h * hd), dtype)
             out["k_norm"] = jnp.ones((L, kvh * hd), dtype)
         return out
 
     Ld, Ls = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
+    if cfg.shortconv_kernel:
+        # a pre-norm stream, as the latent configurations' (above)
+        embed_fan_in = 1
     params = {"embed": stack(cfg.vocab_size, d, fan_in=embed_fan_in),
-              "final_norm": jnp.ones((d,), dtype),
-              "lm_head": stack(d, cfg.vocab_size, fan_in=d)}
+              "final_norm": jnp.ones((d,), dtype)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = stack(d, cfg.vocab_size, fan_in=d)
+    if cfg.shortconv_kernel:
+        # A layer's FIRST sub-layer is attention or a gated short
+        # convolution: the operators' leaves are a stack a kind
+        # (``llm/model.py STACKS``), indexed by the layer's place among its
+        # kind, the leading dense layers' included; ``dense_layers`` and
+        # ``layers`` keep what every layer has, the two norms and the
+        # feed-forward. The taps uniform at their fan-in, as a Mamba-2
+        # conv's (``_init_single_mixer``).
+        K, count = cfg.shortconv_kernel, cfg.layer_types.count
+        if set(cfg.layer_types) - {"state", "global"} \
+                or len(cfg.layer_types) != cfg.n_layers:
+            raise ValueError(
+                f"layer_types of a short-convolution model name "
+                f"{cfg.n_layers} layers 'state' or 'global', got "
+                f"{cfg.layer_types}")
+        if count("state"):
+            params["state_layers"] = {
+                "w_in": stack(count("state"), d, 3 * d, fan_in=d),
+                "conv": jax.random.uniform(
+                    next(keys), (count("state"), d, K), jnp.float32,
+                    -K ** -0.5, K ** -0.5).astype(dtype),
+                "w_out": stack(count("state"), d, d, fan_in=d)}
+        if count("global"):
+            params["attn_layers"] = attn(count("global"), norms=False)
+
+        def own(L):
+            return {"attn_norm": jnp.ones((L, d), dtype),
+                    "mlp_norm": jnp.ones((L, d), dtype)}
+    else:
+        own = attn      # the first sub-layer's leaves lie in the layer's stack
     if Ld:
         fd = cfg.dense_ffn_dim
         params["dense_layers"] = {
-            **attn(Ld), **mixing(Ld, 3), "w_gate": stack(Ld, d, fd, fan_in=d),
+            **own(Ld), **mixing(Ld, 3), "w_gate": stack(Ld, d, fd, fan_in=d),
             "w_up": stack(Ld, d, fd, fan_in=d),
             "w_down": stack(Ld, fd, d, fan_in=fd)}
     layers = {
-        **attn(Ls), **mixing(Ls, 4),
+        **own(Ls), **mixing(Ls, 4),
         "router": jax.random.normal(next(keys), (Ls, d, E), jnp.float32)
         * (d ** -0.5),
         "w_gate": stack(Ls, held, d, f, fan_in=d),
@@ -1093,7 +1187,8 @@ def _route(y, router, bias, cfg: MoEConfig):
     else:
         raise ValueError(f"unknown scoring: {cfg.scoring!r}")
     if cfg.norm_topk_prob:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + cfg.route_eps if cfg.route_eps else total)
     if cfg.routed_scaling != 1.0:
         gates = gates * cfg.routed_scaling
     return gates, experts, probs
@@ -1672,7 +1767,7 @@ def _forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
             "forwards' (ray_tpu.llm.model); so are state-space ('state') "
             "layers, layers that are one mixer alone ('experts') and "
             "non-gated relu2 experts: no state-space layer has a backward "
-            "here")
+            "here, nor has a gated short convolution (shortconv_kernel)")
     b, s = tokens.shape
 
     def act_constraint(x, spec):

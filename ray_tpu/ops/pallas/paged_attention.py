@@ -181,7 +181,8 @@ def fetched_positions_run(length, steps, block_size, window=None):
 
 
 def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
-                 kbuf, vbuf, sems, *, bs, cb, width, window=None):
+                 kbuf, vbuf, sems, *, bs, cb, width, window=None,
+                 head_dim=None):
     b_ = pl.program_id(0)
     kvh, g, hd = q_ref.shape[1:]
     t = cb * bs                                 # positions a chunk
@@ -243,7 +244,7 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             s = jax.lax.dot_general(
                 q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) / jnp.sqrt(
-                    jnp.float32(hd))                # (kvh, g, t)
+                    jnp.float32(head_dim or hd))    # (kvh, g, t)
             at = i * t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
             keep = at < length
             if window is not None:
@@ -274,7 +275,7 @@ def _walk_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
-                    window=None, interpret=False):
+                    window=None, interpret=False, head_dim=None):
     """Single-token decode attention straight through block tables.
 
     q: (slots, kv_heads, group, head_dim) — grouped queries, one token
@@ -288,6 +289,9 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     value ``_gqa_attend_cached`` computes from the gathered view, with
     no gathered view. ``window`` (static): attend the last ``window``
     positions only, walking from the block that holds the first of them.
+    ``head_dim``: the width the scores are scaled by where it is not the
+    rows' (heads narrower than a lane tile packed two a row, the queries
+    zero outside their own head's lanes: llm/kvcache.py _pool_attend).
     """
     b, kvh, g, hd = q.shape
     nb, kvh_p, bs, hd_p = k_pool.shape
@@ -316,7 +320,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         ],
     )
     kernel = functools.partial(_walk_kernel, bs=bs, cb=cb, width=w,
-                               window=window)
+                               window=window, head_dim=head_dim)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -1210,3 +1210,84 @@ def test_the_state_cells_decode_program_moves_the_states_in_place(chip):
                      for a in pool.values())
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 4 * int(np.prod(_STATES[1:]))
+
+
+def _shortconv_config(**kw):
+    return _cell_config("lfm2-24b-a2b-serve-pp5.json", "lfm2_moe",
+                        gmm_impl="pallas", **kw)
+
+
+# the mixed cell's pool: 2 attention layers' K/V, two heads of 64 a row of
+# 128 lanes, blocks of 32 positions; 7 conv layers' tails, flat, a slot
+_LFM2_SLOTS, _LFM2_WIDTH, _LFM2_BLOCKS = 128, 17408 // 32, 10001
+
+
+def _shortconv_pool(chip, cfg):
+    from ray_tpu.llm import kvcache
+    return _shapes_of(chip, jax.eval_shape(lambda: kvcache.init_pool(
+        cfg, _LFM2_BLOCKS, 32, _BF, state_slots=_LFM2_SLOTS)))
+
+
+def test_the_shortconv_cells_decode_program_compiles_for_v5e(chip):
+    """paged_decode_steps (n = 8) at the mixed cell's published widths and
+    pool geometry on a described v5e: 128 slots, K/V heads of 64 packed two
+    a pool row (the walk at 4 rows of 128 lanes and 8 queries a row), a
+    table of 544 blocks of 32, the conv tails (7, 128, 4096) beside the K/V
+    pool. The scanned period (attention, conv, conv, conv, each with 64
+    experts) holds the walk and the writer once and the grouped matmuls
+    three times a layer; the dense lead runs unrolled before it with no
+    kernel. The whole pool, tails included, goes from the donated argument
+    to the result in place."""
+    import numpy as np
+    from ray_tpu.llm import kvcache
+    cfg = _shortconv_config()
+    params = _hybrid_params(chip, cfg)
+    assert "lm_head" not in params          # tied: the embedding is the head
+    pool = _shortconv_pool(chip, cfg)
+    assert pool["k"].shape == (2, _LFM2_BLOCKS, 4, 32, HD)
+    assert pool["conv"].shape == (7, _LFM2_SLOTS, 4096) and "ssm" not in pool
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    ids = shape((_LFM2_SLOTS,), _I32)
+    compiled = kvcache.decode_steps_program(pool, impl="paged_flash").lower(
+        params, pool, {"global": shape((_LFM2_SLOTS, _LFM2_WIDTH), _I32)},
+        ids, ids, shape((_LFM2_SLOTS,), jnp.float32),
+        shape((2,), jnp.uint32), cfg, 8, None, None).compile()
+    ops = _kernel_ops(compiled)
+    names = {}
+    for op in ops:
+        name = re.sub(r"[._]*\d*$", "", op["name"])
+        names[name] = names.get(name, 0) + 1
+    assert names == {"moe_gmm_decode": 12, "kv_write": 1,
+                     "paged_decode": 1}, names
+    _assert_decode_signatures(ops)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * int(np.prod(a.shape))
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # no copy of the experts (9.7 GB), of a layer's (1.2 GB) or of the pool
+    assert mem.temp_size_in_bytes < 400 * 2 ** 20, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13e9
+
+
+def test_the_shortconv_cells_last_chunk_compiles_for_v5e(chip):
+    """The chunked prefill's program at the longest prompt's last chunk
+    (4,096 tokens at offset 12,288 against an accumulator of 21,504 rows):
+    flash at heads of 64 over the rows unpacked from the accumulator, the
+    conv from a tail to a tail, the grouped matmuls over 256 rows an
+    expert, within the memory that is left beside the weights."""
+    from ray_tpu.llm import model as lm
+    cfg = _shortconv_config()
+    params = _hybrid_params(chip, cfg)
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=chip)
+    rows = (17408 // 4096 + 2) * 4096       # engine._acc_len()
+    acc = {"k": shape((2, rows, 4, HD), _BF), "v": shape((2, rows, 4, HD), _BF),
+           "conv": shape((7, 4096), _BF)}
+    compiled = lm._prefill_chunk_flash.lower(
+        params, shape((4096,), _I32), shape((), _I32), 12288, acc, cfg,
+        "flash").compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes

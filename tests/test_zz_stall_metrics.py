@@ -111,10 +111,13 @@ def test_the_metric_file_resolves_and_names_what_the_engine_carries(
     entry = next(m for m in spec.benchmark()["per_layer"]
                  if m["name"] == name)
     assert mf["reader"] == reader and callable(spec.reader(reader))
-    assert entry["layer"] == layer and entry["workloads"] == cells
+    # held by its reader, its arguments (below) and the cells' MEMBERSHIP:
+    # a later cell may join the entry's list
+    assert entry["layer"] == layer and set(entry["workloads"]) >= set(cells)
     assert entry["moves"] == "tpot_p50_ms" == mf["moves"]
-    for key in ("unit", "better", "source", "layer", "workloads"):
+    for key in ("unit", "better", "source", "layer"):
         assert mf[key] == entry[key], (name, key)
+    assert set(cells) <= set(mf["workloads"]) <= set(entry["workloads"])
     for cell in cells:      # one file, every cell that lists it
         assert name in {m["name"] for m in spec.cell(cell)["per_layer"]}
     args = mf.get("args", {})
